@@ -236,7 +236,7 @@ def bench_gang_restart(results):
     rank mid-run; report detect->restore->next-step wall time, plus the
     cold vs post-restart compile time of the jitted train step (the
     persistent XLA compilation cache makes the restart recompile warm —
-    train/worker_group.py _enable_compilation_cache)."""
+    _private/device_plane.py enable_compilation_cache)."""
     import shutil
     import tempfile
 
@@ -249,9 +249,11 @@ def bench_gang_restart(results):
     # see only jax-written cache files
     trace_dir = tempfile.mkdtemp(prefix="envelope_gangtrace_")
     trace = os.path.join(trace_dir, "trace.jsonl")
-    # workers read THEIR OWN config from env — mutating the driver's
-    # global_config would not reach them
-    os.environ["RAY_TPU_MESH_COMPILE_CACHE_DIR"] = cache_dir
+    # a fresh directory so the entry counts are this run's; workers
+    # inherit the variable and jax reads it itself
+    # (device_plane.enable_compilation_cache sets no directory then)
+    prev_cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     ray.init(num_cpus=4)
     try:
         def train_fn(config):
@@ -278,9 +280,10 @@ def bench_gang_restart(results):
 
             @jax.jit
             def step_fn(w, x):
-                # big enough that cold XLA compile is measurable vs the
-                # persistent-cache warm path
-                for i in range(12):
+                # big enough that the cold XLA compile clears the
+                # persistent cache's 0.2 s floor on an idle host too
+                # (device_plane.enable_compilation_cache)
+                for i in range(64):
                     x = jnp.tanh(x @ w) + jax.nn.gelu(x) * (0.1 * i)
                 return jax.nn.softmax(x, axis=-1)
 
@@ -344,7 +347,10 @@ def bench_gang_restart(results):
                                            and cold_added > 0),
             restarts=len(deaths)))
     finally:
-        os.environ.pop("RAY_TPU_MESH_COMPILE_CACHE_DIR", None)
+        if prev_cache_dir is None:
+            os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+        else:
+            os.environ["JAX_COMPILATION_CACHE_DIR"] = prev_cache_dir
         ray.shutdown()
         shutil.rmtree(cache_dir, ignore_errors=True)
         shutil.rmtree(trace_dir, ignore_errors=True)

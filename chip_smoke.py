@@ -1,0 +1,610 @@
+"""The quickest proof that ray_tpu still starts on the chip.
+
+    python chip_smoke.py             # one chip: runtime, serve, train
+    python chip_smoke.py --chips 4   # one four-chip host: the two paths
+                                     # that exist only across chips
+
+Everything goes through the entry points a user calls: ``ray_tpu.init``,
+``serve.run`` + the deployment handle + the HTTP proxy, ``Trainer.fit``.
+This process never imports jax: a chip belongs to one process at a time,
+and here that process is the serve replica, then the train gang worker.
+Whatever the smoke compares with is computed by the process that holds
+the chip.
+
+Each phase prints one JSON line as it ends; the first failure ends the
+run with a non-zero exit. Timings on those lines are for the reader, not
+metrics. The last line is ``{"ok": true, "device": {...}}`` with the
+device as the chip's holder reported it; without a TPU there is no such
+line and the exit code is not 0.
+"""
+
+from __future__ import annotations
+
+import argparse
+import concurrent.futures
+import json
+import os
+import shutil
+import sys
+import time
+import urllib.request
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+RUNS_DIR = os.path.join(HERE, ".smoke_runs")   # Trainer storage; removed at exit
+
+VOCAB = 128256   # Llama-3's vocabulary, shared by the 8b and 1b configs
+
+# --- one chip ---------------------------------------------------------------
+# Llama-3-8B at its published widths, int8 weights (8.0 GB on a 16 GB v5e)
+SERVE = dict(
+    model="8b", quantize="int8",
+    # the page pool covers every slot at max_seq_len: 8 x 1024 / 64 + dump page
+    engine_config=dict(max_num_seqs=8, page_size=64, num_pages=129,
+                       max_seq_len=1024, decode_burst=8))
+# every prompt pads to the 512 prefill bucket and ends inside 8 pages, and
+# 1 + 3 x decode_burst tokens keep every burst full: two compiled programs
+# serve all requests, and equal prompts meet equal shapes whatever else is
+# in the batch
+PROMPT_LENS = (301, 333, 365, 397, 429, 448)
+MAX_TOKENS = 25
+# the largest config one 16 GB chip trains with adamw state (batch 8 needs 21 GB)
+TRAIN = dict(model="1b", batch=4, seq=2048, steps=4, kernel_parity=True)
+
+# --- four chips (--chips 4) ---------------------------------------------------
+SHARDED = dict(model="1b", batch=4, seq=2048, steps=4, mesh=dict(fsdp=2, tp=2))
+REPLICAS = dict(
+    model="1b", quantize=None, num_replicas=4,
+    engine_config=dict(max_num_seqs=8, page_size=64, num_pages=129,
+                       max_seq_len=1024, decode_burst=8))
+
+
+def _hbm(device_stats, key: str):
+    """One counter of ``device.memory_stats()`` (None where the backend
+    keeps no such statistics; the TPU does)."""
+    return (device_stats or {}).get(key)
+
+
+def emit(phase: str, **fields) -> None:
+    print(json.dumps({"phase": phase, **fields}), flush=True)
+
+
+def seeded_prompt(seed: int, index: int, length: int) -> list:
+    import random
+
+    rng = random.Random(seed * 1000 + index)
+    return [rng.randrange(1, VOCAB) for _ in range(length)]
+
+
+# ------------------------------------------------------------------ runtime
+def phase_runtime(chips: int) -> None:
+    """Build the C++ core from the sources git has, start the runtime,
+    and hold its chip detection to the machine."""
+    shutil.rmtree(os.path.join(HERE, "ray_tpu", "_native", "build"),
+                  ignore_errors=True)
+    t0 = time.time()
+    import ray_tpu
+    from ray_tpu import _native
+
+    if _native.get_lib() is None:
+        raise SystemExit("native core unavailable: "
+                         f"{_native.native_unavailable_reason()}")
+    build_s = time.time() - t0
+    ray_tpu.init()
+    detected = ray_tpu.cluster_resources().get("TPU", 0.0)
+    if detected != float(chips):
+        raise SystemExit(
+            f"ray_tpu.init() reports TPU={detected} but this run needs "
+            f"exactly {chips} local chip(s)")
+    emit("runtime", native_build_s=round(build_s, 2), tpu=detected,
+         cpu=ray_tpu.cluster_resources().get("CPU"))
+
+
+# -------------------------------------------------------------------- serve
+def _completion(out: dict) -> list:
+    return out["choices"][0]["token_ids"]
+
+
+def _http_completion(port: int, name: str, payload: dict) -> list:
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}/{name}", data=json.dumps(payload).encode(),
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=180) as resp:
+        if not payload.get("stream"):
+            return _completion(json.loads(resp.read())["result"])
+        tokens, finished = [], False
+        for line in resp:            # SSE-style "data: {...}" chunks
+            line = line.strip()
+            if line.startswith(b"data:"):
+                chunk = json.loads(line[len(b"data:"):])
+                tokens.append(chunk["token"])
+                finished = chunk["finished"]
+        if not finished:
+            raise AssertionError("stream ended without a finished chunk")
+        return tokens
+
+
+def _replica_call(replica, method: str, *args):
+    """One call to ONE replica (the deployment handle would pick any)."""
+    import ray_tpu
+
+    return ray_tpu.get(replica.handle.remote(method, args, {}), timeout=900)
+
+
+def _replicas_of(name: str) -> list:
+    import ray_tpu
+    from ray_tpu.serve.controller import CONTROLLER_NAME
+
+    _version, replicas = ray_tpu.get(
+        ray_tpu.get_actor(CONTROLLER_NAME).get_replicas.remote(name),
+        timeout=60)
+    return replicas
+
+
+def _wait_gone(pids, timeout_s: float = 120.0) -> float:
+    """The chip is free again only once its holder's process is gone."""
+    from ray_tpu._private.device_plane import process_alive
+
+    t0 = time.time()
+    while any(process_alive(p) for p in pids):
+        if time.time() - t0 > timeout_s:
+            raise AssertionError(f"replica processes {pids} still alive "
+                                 f"{timeout_s}s after serve.shutdown()")
+        time.sleep(0.1)
+    return time.time() - t0
+
+
+def _check_tokens(tokens: list) -> None:
+    if len(tokens) != MAX_TOKENS or not all(
+            isinstance(t, int) and 0 <= t < VOCAB for t in tokens):
+        raise AssertionError(f"want {MAX_TOKENS} token ids in [0, {VOCAB}), "
+                             f"got {tokens}")
+
+
+def _check_device(info: dict, count: int = 1) -> None:
+    """The chip's holder is another process than this one, and jax there
+    reports ``count`` TPU devices: its lease's chips and no others."""
+    if info["platform"] != "tpu" or info["device_count"] != count:
+        raise AssertionError(f"not on {count} TPU chip(s): {info}")
+    if info["pid"] == os.getpid():
+        raise AssertionError("the driver holds the chip")
+
+
+def _check_distinct(infos: list) -> None:
+    """Each replica is a process of its own holding a chip of its own."""
+    if len({i["pid"] for i in infos}) != len(infos) \
+            or len({tuple(i["chip_ids"]) for i in infos}) != len(infos):
+        raise AssertionError(f"replicas share a process or a chip: {infos}")
+
+
+def phase_serve(seed: int, spec: dict) -> dict:
+    """Llama-3-8B int8 behind serve.run: the same greedy requests through
+    the deployment handle and through the HTTP proxy, several in flight."""
+    import ray_tpu
+    from ray_tpu import serve
+    from ray_tpu.llm import build_llm_deployment
+
+    name = "llm"
+    t0 = time.time()
+    handle = serve.run(build_llm_deployment(
+        spec["model"], name=name, quantize=spec["quantize"], init="random",
+        seed=seed, engine_config=spec["engine_config"]))
+    completions = handle.options(method_name="completions")
+    port = serve.start()
+    payloads = [{"prompt_ids": seeded_prompt(seed, i, n),
+                 "temperature": 0.0, "max_tokens": MAX_TOKENS}
+                for i, n in enumerate(PROMPT_LENS)]
+
+    # the first request waits for the replica: weights made on the chip,
+    # then the prefill and decode programs compiled (or read from the cache)
+    first = _completion(ray_tpu.get(completions.remote(payloads[0]),
+                                    timeout=600))
+    cold_s = time.time() - t0
+
+    t1 = time.time()
+    refs = [completions.remote(p) for p in payloads]     # all in flight
+    stats = handle.options(method_name="stats")
+    max_running, pending = 0, refs
+    while pending:
+        max_running = max(max_running, ray_tpu.get(
+            stats.remote(), timeout=60)["running"])
+        _done, pending = ray_tpu.wait(refs, num_returns=len(refs),
+                                      timeout=0.02)
+    by_handle = [_completion(o) for o in ray_tpu.get(refs, timeout=600)]
+    handle_s = time.time() - t1
+
+    t2 = time.time()
+    with concurrent.futures.ThreadPoolExecutor(len(payloads)) as pool:
+        by_http = list(pool.map(
+            lambda ip: _http_completion(
+                port, name, {**ip[1], "stream": ip[0] % 2 == 0}),
+            enumerate(payloads)))
+    http_s = time.time() - t2
+
+    for tokens in by_handle + by_http:
+        _check_tokens(tokens)
+    if first != by_handle[0]:
+        raise AssertionError("the same prompt gave other tokens the second "
+                             f"time: {first} vs {by_handle[0]}")
+    if by_handle != by_http:
+        raise AssertionError("handle and HTTP routes disagree: "
+                             f"{by_handle} vs {by_http}")
+    if max_running < 2:
+        raise AssertionError("requests never shared a decode batch "
+                             f"(max running slots seen: {max_running})")
+
+    info = ray_tpu.get(handle.options(method_name="device_info").remote(),
+                       timeout=60)
+    _check_device(info)
+    serve.shutdown()
+    gone_s = _wait_gone([info["pid"]])
+    n_tokens = MAX_TOKENS * len(payloads)
+    emit("serve", model=spec["model"], quantize=spec["quantize"],
+         platform=info["platform"], device_kind=info["device_kind"],
+         replica_pid=info["pid"], chip_ids=info["chip_ids"],
+         peak_hbm_bytes=_hbm(info["memory_stats"], "peak_bytes_in_use"),
+         hbm_limit_bytes=_hbm(info["memory_stats"], "bytes_limit"),
+         prefill_attention=info["prefill_attention"],
+         compile_cache_dir=info["compile_cache_dir"],
+         compile_cache=info["compile_cache"],
+         first_request_s=round(cold_s, 1), max_running=max_running,
+         handle_round_s=round(handle_s, 2), http_round_s=round(http_s, 2),
+         tokens_per_round=n_tokens, replica_exit_s=round(gone_s, 2))
+    return info
+
+
+# -------------------------------------------------------------------- train
+def _kernel_parity(seed: int) -> dict:
+    """Flash kernels against the blockwise oracle on the device, forward
+    and backward (GQA 8/4 heads of 128, causal, bf16). Runs in the gang
+    worker, which already holds the chip."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops.attention import attention, blockwise_attention
+
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(ks[0], (1, 512, 8, 128), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (1, 512, 4, 128), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, 512, 4, 128), jnp.bfloat16)
+    g = jax.random.normal(ks[3], (1, 512, 8, 128), jnp.float32)
+
+    def run(fn):
+        def loss(q, k, v):
+            return (fn(q, k, v).astype(jnp.float32) * g).sum()
+
+        out = jax.jit(fn)(q, k, v)
+        grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+        return [np.asarray(x, np.float32) for x in (out, *grads)]
+
+    got = run(lambda q, k, v: attention(q, k, v, causal=True,
+                                        use_pallas=True))
+    want = run(lambda q, k, v: blockwise_attention(q, k, v, causal=True))
+    errors = {}
+    for label, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        if not np.isfinite(a).all():
+            raise AssertionError(f"kernel {label} is not finite")
+        # bf16 carries 8 bits of mantissa; errors are held to a few ulp of
+        # the largest value in play
+        errors[label] = float(np.abs(a - b).max() / np.abs(b).max())
+        if errors[label] > 3e-2:
+            raise AssertionError(
+                f"flash {label} is off the oracle by {errors[label]:.3g} "
+                f"of its range")
+    return errors
+
+
+def _run_steps(cfg, mesh, spec: dict, seed: int, on_step=None) -> dict:
+    """``spec['steps']`` adamw steps of ``cfg`` on ``mesh`` over one seeded
+    batch. Returns losses, timings, each device's bytes in use and the
+    number of ``tpu_custom_call``s in the compiled step."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from ray_tpu._private import device_plane
+    from ray_tpu.models import init_params, lm_loss, param_logical_axes
+    from ray_tpu.train import make_train_step
+
+    t0 = time.time()
+    init_fn, step_fn, place_batch = make_train_step(
+        lambda p, b: lm_loss(p, b, cfg, mesh=mesh),
+        optax.adamw(3e-4, weight_decay=0.1), mesh, param_logical_axes(cfg))
+    state = init_fn(init_params(jax.random.PRNGKey(seed), cfg))
+    data = place_batch({"tokens": jax.random.randint(
+        jax.random.PRNGKey(seed + 1), (spec["batch"], spec["seq"]), 0,
+        cfg.vocab, jnp.int32)})
+    losses, step_s = [], []
+    for i in range(spec["steps"]):
+        t = time.time()
+        state, metrics = step_fn(state, data)
+        losses.append(float(metrics["loss"]))     # waits for the device
+        step_s.append(time.time() - t)
+        if i == 0:
+            to_first_step_s = time.time() - t0
+            cache_after_first = device_plane.compilation_cache_stats()
+        if on_step is not None:
+            on_step(i, losses)
+    bytes_in_use = [_hbm(d.memory_stats(), "bytes_in_use")
+                    for d in mesh.devices.flat]
+    text = step_fn.lower(state, data).compile().as_text()
+    del state, data
+    return {"losses": losses,
+            "to_first_step_s": round(to_first_step_s, 2),
+            "first_step_s": round(step_s[0], 2),
+            "step_ms": round(1e3 * min(step_s[1:]), 1),
+            "compile_cache_after_first_step": cache_after_first,
+            "bytes_in_use": bytes_in_use,
+            "tpu_custom_calls": text.count("tpu_custom_call")}
+
+
+def _device_report() -> dict:
+    import jax
+
+    from ray_tpu import get_tpu_chip_ids
+
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "device_kind": devs[0].device_kind,
+            "device_count": len(devs), "pid": os.getpid(),
+            "chip_ids": get_tpu_chip_ids(),
+            "compile_cache_dir": jax.config.jax_compilation_cache_dir}
+
+
+def train_fn(config: dict) -> None:
+    """Runs in the gang worker Trainer.fit started: kernel parity first,
+    then the train steps, one ``train.report`` per step. The last report
+    carries everything the driver checks."""
+    import jax
+
+    from ray_tpu import train
+    from ray_tpu.models import LLAMA_CONFIGS
+    from ray_tpu.parallel import MeshSpec, build_mesh
+
+    report = {"device": _device_report()}
+    if config["kernel_parity"]:
+        report["kernel_parity"] = _kernel_parity(config["seed"])
+    cfg = LLAMA_CONFIGS[config["model"]]
+    mesh = build_mesh(MeshSpec(), jax.devices()[:1])
+    report.update(_run_steps(
+        cfg, mesh, config, config["seed"],
+        on_step=lambda i, losses: train.report(
+            {**report, "step": i + 1, "losses": list(losses)})))
+    report["peak_hbm_bytes"] = _hbm(jax.devices()[0].memory_stats(),
+                                    "peak_bytes_in_use")
+    train.report({**report, "step": config["steps"] + 1, "final": True})
+
+
+def sharded_train_fn(config: dict) -> None:
+    """One gang worker holding the whole host: the same seeded steps on a
+    one-device mesh, then on the fsdp x tp mesh over all local chips."""
+    import gc
+
+    import jax
+
+    from ray_tpu import train
+    from ray_tpu.models import LLAMA_CONFIGS
+    from ray_tpu.parallel import MeshSpec, build_mesh
+
+    cfg = LLAMA_CONFIGS[config["model"]]
+    devices = jax.devices()
+    report = {"device": _device_report()}
+    single = _run_steps(cfg, build_mesh(MeshSpec(), devices[:1]), config,
+                        config["seed"])
+    gc.collect()
+    report["single"] = single
+    report["bytes_in_use_between"] = [
+        _hbm(d.memory_stats(), "bytes_in_use") for d in devices]
+    train.report({**report, "step": 1})
+    report["sharded"] = _run_steps(
+        cfg, build_mesh(MeshSpec(**config["mesh"]), devices), config,
+        config["seed"])
+    train.report({**report, "step": 2, "final": True})
+
+
+def _fit(fn, config: dict, tpus: int, name: str) -> dict:
+    from ray_tpu.train import RunConfig, ScalingConfig, Trainer
+
+    t0 = time.time()
+    result = Trainer(
+        fn, train_loop_config=config,
+        scaling_config=ScalingConfig(
+            num_workers=1, use_tpu=True,
+            resources_per_worker={"CPU": 1, "TPU": tpus}),
+        run_config=RunConfig(name=name, storage_path=RUNS_DIR)).fit()
+    if result.error is not None:
+        raise AssertionError(f"Trainer.fit failed: {result.error}")
+    if not result.metrics.get("final"):
+        raise AssertionError(f"no final report from the gang worker: "
+                             f"{result.metrics}")
+    return {**result.metrics, "fit_s": round(time.time() - t0, 1)}
+
+
+def _check_losses(losses: list) -> None:
+    import math
+
+    if not all(math.isfinite(x) for x in losses) or not losses[-1] < losses[0]:
+        raise AssertionError(f"losses must be finite and falling: {losses}")
+
+
+def phase_train(seed: int, spec: dict) -> dict:
+    m = _fit(train_fn, {**spec, "seed": seed}, 1, "chip_smoke_train")
+    dev = m["device"]
+    _check_device(dev)
+    if spec["kernel_parity"]:
+        emit("kernel_parity", shape="1x512, 8/4 heads of 128, causal, bf16",
+             max_error_over_range=m["kernel_parity"])
+    _check_losses(m["losses"])
+    if m["tpu_custom_calls"] < 1:
+        raise AssertionError("the compiled train step holds no "
+                             "tpu_custom_call: flash kernels fell back")
+    emit("train", model=spec["model"], batch=spec["batch"], seq=spec["seq"],
+         platform=dev["platform"], device_kind=dev["device_kind"],
+         worker_pid=dev["pid"], chip_ids=dev["chip_ids"],
+         losses=[round(x, 4) for x in m["losses"]],
+         tpu_custom_calls=m["tpu_custom_calls"],
+         to_first_step_s=m["to_first_step_s"],
+         first_step_s=m["first_step_s"], step_ms=m["step_ms"],
+         compile_cache_dir=dev["compile_cache_dir"],
+         compile_cache=m["compile_cache_after_first_step"],
+         peak_hbm_bytes=m["peak_hbm_bytes"], fit_s=m["fit_s"])
+    return dev
+
+
+# --------------------------------------------------------------- four chips
+def phase_sharded_train(seed: int, spec: dict) -> dict:
+    m = _fit(sharded_train_fn, {**spec, "seed": seed}, 4,
+             "chip_smoke_sharded")
+    dev = m["device"]
+    _check_device(dev, 4)
+    single, sharded = m["single"], m["sharded"]
+    _check_losses(sharded["losses"])
+    for a, b in zip(single["losses"], sharded["losses"]):
+        # bf16 matmuls reduced in another order: a few parts in a thousand
+        if abs(a - b) > 2e-2 * abs(a):
+            raise AssertionError(
+                f"sharded losses leave the one-device run: "
+                f"{sharded['losses']} vs {single['losses']}")
+    per_dev = sharded["bytes_in_use"]
+    whole = single["bytes_in_use"][0]
+    # parameters and optimizer state spread over the mesh: about a quarter
+    # on each device, none of them holding the whole state
+    if not all(0.15 * whole < b < 0.4 * whole for b in per_dev):
+        raise AssertionError(
+            f"state is not spread over the four chips: {per_dev} bytes in "
+            f"use against {whole} on one device")
+    emit("sharded_train", model=spec["model"], mesh=spec["mesh"],
+         platform=dev["platform"], device_kind=dev["device_kind"],
+         losses_single=[round(x, 4) for x in single["losses"]],
+         losses_sharded=[round(x, 4) for x in sharded["losses"]],
+         bytes_in_use_single=whole, bytes_in_use_sharded=per_dev,
+         bytes_in_use_between=m["bytes_in_use_between"],
+         tpu_custom_calls=sharded["tpu_custom_calls"],
+         step_ms_single=single["step_ms"], step_ms_sharded=sharded["step_ms"],
+         first_step_s_sharded=sharded["first_step_s"], fit_s=m["fit_s"])
+    return dev
+
+
+def phase_replicas(seed: int, spec: dict) -> None:
+    """Four replicas behind the router, each a process holding one chip."""
+    import ray_tpu
+    from ray_tpu import serve
+    from ray_tpu.llm import build_llm_deployment
+
+    name = "llm4"
+    t0 = time.time()
+    handle = serve.run(build_llm_deployment(
+        spec["model"], name=name, num_replicas=spec["num_replicas"],
+        quantize=spec["quantize"], init="random", seed=seed,
+        engine_config=spec["engine_config"]))
+    payload = {"prompt_ids": seeded_prompt(seed, 0, PROMPT_LENS[0]),
+               "temperature": 0.0, "max_tokens": MAX_TOKENS}
+    replicas = _replicas_of(name)
+    if len(replicas) != spec["num_replicas"]:
+        raise AssertionError(f"want {spec['num_replicas']} replicas, "
+                             f"got {len(replicas)}")
+    # every replica answers the same prompt itself (and so is up)
+    answers = [_completion(_replica_call(r, "completions", payload))
+               for r in replicas]
+    up_s = time.time() - t0
+    for tokens in answers:
+        _check_tokens(tokens)
+    if any(a != answers[0] for a in answers):
+        raise AssertionError(f"replicas disagree on one prompt: {answers}")
+    infos = [_replica_call(r, "device_info") for r in replicas]
+    for info in infos:
+        _check_device(info)
+    _check_distinct(infos)
+
+    # a burst through the router: who answered?
+    completions = handle.options(method_name="completions")
+    burst = [{"prompt_ids": seeded_prompt(seed, i % len(PROMPT_LENS),
+                                          PROMPT_LENS[i % len(PROMPT_LENS)]),
+              "temperature": 0.0, "max_tokens": MAX_TOKENS}
+             for i in range(16)]
+    t1 = time.time()
+    routed = [completions.route(p) for p in burst]
+    outs = ray_tpu.get([ref for ref, _ in routed], timeout=900)
+    burst_s = time.time() - t1
+    for out in outs:
+        _check_tokens(_completion(out))
+    if _completion(outs[0]) != answers[0]:
+        raise AssertionError("the router's answer differs from the "
+                             "replicas' own")
+    served_by = {replica._actor_id for _, replica in routed}
+    if len(served_by) < 2:
+        raise AssertionError("one replica answered the whole burst")
+    serve.shutdown()
+    gone_s = _wait_gone([i["pid"] for i in infos])
+    emit("replicas", model=spec["model"], num_replicas=len(infos),
+         devices=[{k: i[k] for k in ("pid", "platform", "device_kind",
+                                     "device_id", "chip_ids")}
+                  for i in infos],
+         peak_hbm_bytes=[_hbm(i["memory_stats"], "peak_bytes_in_use")
+                         for i in infos],
+         replicas_answering_burst=len(served_by), all_up_s=round(up_s, 1),
+         burst_s=round(burst_s, 2), replica_exit_s=round(gone_s, 2))
+
+
+# --------------------------------------------------------------------- main
+def _dump_worker_logs(tail_bytes: int = 3000) -> None:
+    """On failure, the end of every worker log of this session that
+    recorded a traceback, to stderr: the cause is rarely in this process."""
+    import glob
+
+    import ray_tpu
+    from ray_tpu._private.config import session_log_dir
+
+    node = ray_tpu._worker_api.node()
+    if node is None:
+        return
+    for path in sorted(glob.glob(os.path.join(
+            session_log_dir(node.session_name), "worker-*.log"))):
+        with open(path, "rb") as f:
+            f.seek(max(0, os.path.getsize(path) - tail_bytes))
+            tail = f.read().decode(errors="replace")
+        if "Traceback" in tail or "Error" in tail:
+            print(f"--- {path}\n{tail}", file=sys.stderr, flush=True)
+
+
+def run(chips: int, seed: int) -> dict:
+    """All phases for ``chips``; returns the device for the last line."""
+    import ray_tpu
+
+    try:
+        phase_runtime(chips)
+        if chips == 1:
+            info = phase_serve(seed, SERVE)
+            dev = phase_train(seed, TRAIN)
+            if (dev["platform"], dev["device_kind"]) != (
+                    info["platform"], info["device_kind"]):
+                raise AssertionError(f"replica and gang worker saw "
+                                     f"different devices: {info} vs {dev}")
+        else:
+            dev = phase_sharded_train(seed, SHARDED)
+            phase_replicas(seed, REPLICAS)
+    except BaseException:
+        _dump_worker_logs()   # the chip's holder failed in another process
+        raise
+    finally:
+        ray_tpu.shutdown()
+        shutil.rmtree(RUNS_DIR, ignore_errors=True)
+    return {"platform": dev["platform"], "kind": dev["device_kind"],
+            "count": dev["device_count"]}
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    parser.add_argument("--seed", type=int, default=0)
+    args = parser.parse_args(argv)
+    device = run(args.chips, args.seed)
+    if "jax" in sys.modules:
+        raise AssertionError("the driver imported jax")
+    if device["platform"] != "tpu" or device["count"] != args.chips:
+        raise AssertionError(f"not a {args.chips}-chip TPU run: {device}")
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -368,7 +368,6 @@ class Config:
     # from peer hosts — "" = loopback (single host). TPU pods set the
     # node's DCN-reachable IP.
     device_transfer_host: str = ""
-    mesh_compile_cache_dir: str = ""
     default_device_platform: str = ""         # "" = jax default
     ici_mesh_auto_axis_order: bool = True
 

@@ -949,6 +949,15 @@ class CoreWorker:
             await asyncio.sleep(delay)
             delay = min(delay * 2, 0.1)
 
+    def _pending_here(self, oids) -> set:
+        """Objects whose creating task is in flight from THIS worker
+        (fast lane, asyncio path or stream): they complete into the
+        local memory store."""
+        return {oid for oid in oids
+                if oid in self._lane_events
+                or oid.task_id() in self._inflight
+                or oid.task_id() in self._streams}
+
     async def _get(self, oids: List[ObjectID], timeout: Optional[float],
                    owners: Optional[Dict[ObjectID, str]] = None) -> List[Any]:
         """Resolution order per object: local stores → (owned, task in
@@ -962,15 +971,19 @@ class CoreWorker:
         delay = 0.002
         gone_strikes: Dict[ObjectID, int] = {}
         while True:
+            # snapshot BEFORE looking in the stores: a fast-lane reply
+            # thread stores the value and only then un-registers the
+            # task, so read in the other order a reply landing between
+            # the two reads is neither stored nor pending, and the get
+            # parks on the raylet directory for an object that never
+            # enters it
+            pending_here = self._pending_here(oids)
             missing = [oid for oid in oids
                        if not self.memory_store.contains(oid)
                        and not self.store.contains(oid)]
             if not missing:
                 return [self._load_object(oid) for oid in oids]
-            pending_here = {oid for oid in missing
-                            if oid in self._lane_events
-                            or oid.task_id() in self._inflight
-                            or oid.task_id() in self._streams}
+            pending_here.intersection_update(missing)
             foreign = [oid for oid in missing if oid not in pending_here]
             progressed = False
             plasma_wait = []
@@ -1133,6 +1146,7 @@ class CoreWorker:
         lost_here: set = set()
         gone_strikes: Dict[ObjectID, int] = {}
         while True:
+            pending_here = self._pending_here(oids)  # first: see _get
             ready = [oid for oid in oids
                      if oid in lost_here
                      or self.memory_store.contains(oid)
@@ -1140,11 +1154,7 @@ class CoreWorker:
             if len(ready) >= num_returns:
                 return ready
             ready_set = set(ready)
-            pending_here = {oid for oid in oids
-                            if oid not in ready_set
-                            and (oid in self._lane_events
-                                 or oid.task_id() in self._inflight
-                                 or oid.task_id() in self._streams)}
+            pending_here.difference_update(ready_set)
             owner_served = [oid for oid in oids
                             if oid not in ready_set
                             and oid not in pending_here

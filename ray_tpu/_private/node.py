@@ -39,14 +39,26 @@ def default_resources() -> Dict[str, float]:
 
 
 def _detect_tpu_chips() -> int:
+    """Local TPU chips, counted from device nodes so the driver never
+    initialises jax (that would take the chip from the worker that is
+    leased it). Hosts with the accel driver expose one ``/dev/accelN``
+    per chip; VFIO hosts expose one numbered IOMMU group per chip under
+    ``/dev/vfio`` next to the ``vfio`` control node, which is not a chip.
+    The TPU_* topology variables are no guide: a one-chip machine cut
+    from a four-chip host still exports the host's 2x2 bounds."""
     if os.environ.get("RAY_TPU_FAKE_CHIPS"):
         return int(os.environ["RAY_TPU_FAKE_CHIPS"])
-    try:
-        import glob
+    return (_count_numbered("/dev", "accel")
+            or _count_numbered("/dev/vfio", ""))
 
-        return len(glob.glob("/dev/accel*")) or len(glob.glob("/dev/vfio/*"))
-    except Exception:
+
+def _count_numbered(directory: str, prefix: str) -> int:
+    try:
+        names = os.listdir(directory)
+    except OSError:
         return 0
+    return sum(1 for n in names
+               if n.startswith(prefix) and n[len(prefix):].isdigit())
 
 
 def _detect_accelerator_type() -> str:

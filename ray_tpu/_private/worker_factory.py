@@ -164,6 +164,12 @@ def main() -> None:
     # socket is the readiness signal, so every fork after it is warm.
     from . import worker_main  # noqa: F401
 
+    if "jax" in sys.modules:
+        # every forked worker would inherit this process's jax state, and
+        # one of them will be leased a chip (device_plane.claim_chips
+        # needs a process that has not imported jax yet)
+        raise RuntimeError("the worker factory must not import jax")
+
     try:
         os.unlink(sock_path)
     except FileNotFoundError:

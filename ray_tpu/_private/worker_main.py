@@ -449,22 +449,18 @@ class TaskExecutor:
         apply_runtime_env(self.core, spec.runtime_env, self._applied_env)
 
     def _apply_chip_visibility(self, spec: TaskSpec) -> None:
-        """Export the lease's physical chip set before user code runs
-        (ref: accelerators/tpu.py:31 TPU_VISIBLE_CHIPS — here the ids
-        come from the raylet's per-lease chip accounting, so two
-        fractional-host leases on one machine see disjoint chips).
-        Effective for code that initializes jax after this point; the
-        pool worker itself stays CPU-pinned for the control plane."""
+        """The one rule for who gets the device plane, applied before
+        user code runs: a lease that holds chips gets the TPU backend,
+        confined to its chips (the ids come from the raylet's per-lease
+        chip accounting, so two leases on one host see disjoint chips);
+        a lease that holds none keeps the raylet's CPU pin
+        (device_plane.py)."""
+        from . import device_plane
+
         if spec.chip_ids is None:
-            # chipless task on a reused pool worker: stale visibility
-            # from a PREVIOUS lease must not leak (the chips may belong
-            # to someone else now)
-            os.environ.pop("TPU_VISIBLE_CHIPS", None)
-            os.environ.pop("RAY_TPU_CHIP_IDS", None)
-            return
-        ids = ",".join(str(i) for i in spec.chip_ids)
-        os.environ["TPU_VISIBLE_CHIPS"] = ids
-        os.environ["RAY_TPU_CHIP_IDS"] = ids
+            device_plane.release_chips()
+        else:
+            device_plane.claim_chips(spec.chip_ids)
 
     def execute_normal(self, spec: TaskSpec) -> dict:
         try:

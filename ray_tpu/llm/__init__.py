@@ -1,14 +1,31 @@
 """ray_tpu.llm: TPU-native LLM inference — paged KV cache, continuous
 batching, serving (ref: python/ray/llm/ — which delegates to vLLM; here
-the engine is native jax/XLA, SURVEY §2.4)."""
+the engine is native jax/XLA, SURVEY §2.4).
 
-from .cache import KVCache, PageAllocator, SequenceTable, init_kv_cache
-from .engine import EngineConfig, LLMEngine, StepOutput
-from .sampling import SamplingParams
-from .serve import LLMServer, build_llm_deployment
+Names resolve on first use (PEP 562): a driver that only builds a
+deployment (``build_llm_deployment``) must not import jax — on a TPU host
+the chip belongs to the replica process, and the driver stays off it."""
 
-__all__ = [
-    "LLMEngine", "EngineConfig", "StepOutput", "SamplingParams",
-    "KVCache", "PageAllocator", "SequenceTable", "init_kv_cache",
-    "LLMServer", "build_llm_deployment",
-]
+import importlib
+
+_EXPORTS = {
+    "KVCache": ".cache", "PageAllocator": ".cache",
+    "SequenceTable": ".cache", "init_kv_cache": ".cache",
+    "EngineConfig": ".engine", "LLMEngine": ".engine",
+    "StepOutput": ".engine",
+    "SamplingParams": ".sampling",
+    "LLMServer": ".serve", "build_llm_deployment": ".serve",
+}
+
+__all__ = list(_EXPORTS)
+
+
+def __getattr__(name):
+    try:
+        module = _EXPORTS[name]
+    except KeyError:
+        raise AttributeError(
+            f"module {__name__!r} has no attribute {name!r}") from None
+    value = getattr(importlib.import_module(module, __name__), name)
+    globals()[name] = value
+    return value

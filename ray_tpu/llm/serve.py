@@ -19,11 +19,10 @@ from __future__ import annotations
 import asyncio
 import json
 import os
-from typing import Any, Dict, List, Optional
+from typing import TYPE_CHECKING, Any, Dict, List, Optional
 
-from ..models.llama import LLAMA_CONFIGS, LlamaConfig, init_params
-from .engine import EngineConfig, LLMEngine
-from .sampling import SamplingParams
+if TYPE_CHECKING:  # this module stays importable without jax (llm/__init__)
+    from .sampling import SamplingParams
 
 
 class LLMServer:
@@ -37,6 +36,19 @@ class LLMServer:
                  speculation: Optional[dict] = None):
         import jax
 
+        from .. import get_tpu_chip_ids
+        from .._private import device_plane
+        from ..models.llama import LLAMA_CONFIGS, init_params
+        from .engine import EngineConfig, LLMEngine
+
+        chip_ids = get_tpu_chip_ids()
+        self._device = jax.devices()[0]
+        if chip_ids and self._device.platform != "tpu":
+            raise RuntimeError(
+                f"LLMServer replica holds TPU chips {chip_ids} but jax "
+                f"started on {self._device.platform!r}: refusing to "
+                f"serve a chip lease from another backend")
+        self._compile_cache_dir = device_plane.enable_compilation_cache()
         self.model_name = model
         if model in LLAMA_CONFIGS:
             cfg = LLAMA_CONFIGS[model]
@@ -75,15 +87,24 @@ class LLMServer:
                 params = pickle.load(f)
             params = jax.device_put(params)
         elif init == "random":
-            if quantize is not None:
-                raise ValueError(
-                    "quantize applies to HF-checkpoint loading only "
-                    "(init='hf' / a checkpoint-dir model)")
-            params = init_params(jax.random.PRNGKey(seed), cfg)
+            if quantize is None:
+                params = init_params(jax.random.PRNGKey(seed), cfg)
+            elif quantize == "int8":
+                # seeded int8 weights made on the device: the only way a
+                # machine without a checkpoint holds Llama-3-8B on one
+                # 16 GB chip (ops/quant.py)
+                from ..ops.quant import init_params_quantized
+
+                params = init_params_quantized(jax.random.PRNGKey(seed),
+                                               cfg)
+            else:
+                raise ValueError(f"unknown quantize {quantize!r}")
         else:
             raise ValueError(f"unknown init {init!r}")
         ecfg = EngineConfig(**(engine_config or {}))
         self.engine = LLMEngine(params, cfg, ecfg)
+        # whole-prompt prefill buckets served so far (device_info)
+        self._prefill_buckets: set = set()
         self.tokenizer = None
         if tokenizer:
             from transformers import AutoTokenizer
@@ -422,6 +443,10 @@ class LLMServer:
         rid = self.engine.add_request(prompt_ids, params,
                                       request_id=rid_in,
                                       model_id=model_id)
+        from .runner import prefill_bucket
+
+        self._prefill_buckets.add(prefill_bucket(
+            len(prompt_ids), self.engine.ecfg.max_seq_len))
         tenant = current_tenant_id()
         if tenant:
             self._tenants[rid] = tenant
@@ -438,6 +463,8 @@ class LLMServer:
         else:
             raise ValueError(
                 "need 'prompt_ids' (or 'prompt' with a tokenizer configured)")
+        from .sampling import SamplingParams
+
         params = SamplingParams(
             temperature=float(payload.get("temperature", 1.0)),
             top_k=int(payload.get("top_k", 0)),
@@ -680,6 +707,8 @@ class LLMServer:
             meta["v"] = vs[0] if len(vs) == 1 else np.concatenate(
                 vs, axis=1)
         s = payload.get("sampling") or {}
+        from .sampling import SamplingParams
+
         params = SamplingParams(
             temperature=float(s.get("temperature", 1.0)),
             top_k=int(s.get("top_k", 0)),
@@ -773,17 +802,62 @@ class LLMServer:
         out["pool"] = self._pool
         return out
 
+    async def device_info(self, _payload=None) -> Dict[str, Any]:
+        """What this replica really runs on, as jax reports it: nothing
+        here is inferred from the lease. ``prefill_attention`` maps each
+        whole-prompt prefill bucket served so far to the implementation
+        the attention dispatcher picked for it (ops.attention
+        attention_path); chunked prefill attends with plain einsums."""
+        import jax
+
+        from .. import get_tpu_chip_ids
+        from .._private import device_plane
+        from ..ops.attention import attention_path
+
+        dev = self._device
+        cfg = self.engine.cfg
+        if self.engine.ecfg.prefill_chunk > 0:
+            prefill_attention: Any = "einsum (chunked prefill)"
+        else:
+            prefill_attention = {
+                str(b): attention_path(b, b, cfg.head_dim,
+                                       dev.platform == "tpu")
+                for b in sorted(self._prefill_buckets)}
+        return {
+            "pid": os.getpid(),
+            "platform": dev.platform,
+            "device_kind": dev.device_kind,
+            "device_id": dev.id,
+            "device_count": len(jax.devices()),
+            "chip_ids": get_tpu_chip_ids(),
+            "memory_stats": dev.memory_stats(),
+            "prefill_attention": prefill_attention,
+            "compile_cache_dir": self._compile_cache_dir,
+            "compile_cache": device_plane.compilation_cache_stats(),
+        }
+
 
 def build_llm_deployment(model: str = "tiny", *, num_replicas: int = 1,
                          name: str = "llm",
-                         pools: Optional[dict] = None, **server_kwargs):
+                         pools: Optional[dict] = None,
+                         ray_actor_options: Optional[dict] = None,
+                         **server_kwargs):
     """An Application running LLMServer replicas (ref: ray.llm
     build_openai_app). ``pools={"prefill": n, "decode": m}`` deploys
     disaggregated prefill/decode pools instead of ``num_replicas``
-    monolithic replicas (fleet KV plane)."""
-    from .. import serve
+    monolithic replicas (fleet KV plane).
 
+    Each replica is a process of its own and, on a cluster that has TPU
+    chips, holds one of them (``num_tpus=1``): the chip lease is what
+    gives the replica the TPU backend (_private/device_plane.py), and a
+    replica that holds a chip refuses to serve from any other backend.
+    Pass ``ray_actor_options`` to ask for something else."""
+    from .. import cluster_resources, serve
+
+    if ray_actor_options is None and cluster_resources().get("TPU", 0) >= 1:
+        ray_actor_options = {"num_tpus": 1}
     dep = serve.deployment(LLMServer, name=name,
                            num_replicas=num_replicas,
-                           pools=pools)
+                           pools=pools,
+                           ray_actor_options=ray_actor_options)
     return dep.bind(model, **server_kwargs)

@@ -24,10 +24,11 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from ..util.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops import apply_rotary, attention, ring_attention, rms_norm, rope_frequencies
+from ..ops.attention import attention_path
 from ..parallel.sharding import DEFAULT_RULES, with_sharding_constraint_logical
 
 
@@ -186,6 +187,18 @@ def _attn(x, lp, cfg: LlamaConfig, cos, sin, mesh: Optional[Mesh], rules):
         spec = P(("dp", "fsdp"), "sp", "tp", None)
         out = shard_map(
             partial(ring_attention, axis="sp", causal=True),
+            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False)(q, k, v)
+    elif (mesh is not None and mesh.size > 1 and attention_path(
+            s, s, cfg.head_dim,
+            mesh.devices.flat[0].platform == "tpu") == "pallas"):
+        # GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+        # shard_map"): each device runs the flash kernels on its own
+        # batch rows and heads. Attention mixes neither, so no
+        # collective is needed; batch and heads must divide their axes.
+        spec = P(("dp", "fsdp"), None, "tp", None)
+        out = shard_map(
+            partial(attention, causal=True, use_pallas=True),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False)(q, k, v)
     else:

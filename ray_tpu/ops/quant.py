@@ -149,9 +149,8 @@ def init_params_quantized(key, cfg) -> Dict:
     1/sqrt(fan_in) init (uniform int8 has RMS ≈ 74, so
     s = fan_in**-0.5 / 74 gives unit-variance-scaled projections).
 
-    The whole init is ONE jitted program: eagerly it would dispatch
-    ~50 single-op executables, and on remote-attached backends every
-    loaded executable has real server-side cost."""
+    The whole init is ONE jitted program: eagerly it would dispatch and
+    load ~50 single-op executables."""
     if getattr(cfg, "n_experts", 0):
         raise NotImplementedError("quantized init for MoE not wired up")
     return _init_params_quantized_jit(key, cfg)

@@ -18,7 +18,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..util.jax_compat import axis_size
+from jax.lax import axis_size
 
 from .attention import NEG_INF
 

@@ -26,9 +26,10 @@ from typing import Optional, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..exceptions import CollectiveTimeoutError
-from ..util.jax_compat import axis_size, shard_map
 
 AxisName = Union[str, tuple]
 

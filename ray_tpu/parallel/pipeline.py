@@ -19,7 +19,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from ..util.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

@@ -19,6 +19,7 @@ from ..exceptions import RayTpuError
 
 CONTROLLER_NAME = "SERVE::controller"
 HEALTH_PERIOD_S = 2.0
+HEALTH_TIMEOUT_S = 15.0
 
 # What best-effort calls against a possibly-dead replica/proxy can
 # raise (transport loss, timeouts, the actor already being gone).
@@ -187,12 +188,26 @@ class ServeController:
         # concurrent health checks: one hung replica must not stall the
         # control loop for 15s per replica (NB: awaiting ObjectRefs — a
         # blocking get() would stall this actor's loop)
+        ready = dep.setdefault("_ready", set())
+
         async def _check(entry):
+            # stale code OR a pool dropped from config = replace
+            current = entry[1] == code_version and entry[2] in targets
+            aid = entry[0]._actor_id
             try:
                 await asyncio.wait_for(
-                    _await_ref(entry[0].health_check.remote()), 15)
-                # stale code OR a pool dropped from config = replace
-                return entry[1] == code_version and entry[2] in targets
+                    _await_ref(entry[0].health_check.remote()),
+                    HEALTH_TIMEOUT_S)
+                ready.add(aid)
+                return current
+            except asyncio.TimeoutError:
+                # STARTING, not unhealthy (ref: deployment_state.py replica
+                # states): a constructor that loads weights onto a chip
+                # takes minutes, and its health check only queues behind
+                # it. A replica is held to the deadline once it has
+                # answered one check; a constructor that fails kills the
+                # actor, which raises below instead of timing out.
+                return current and aid not in ready
             except _REMOTE_ERRORS:
                 return False
 
@@ -233,6 +248,7 @@ class ServeController:
                 changed = True
             replicas.extend(entries)
         dep["replicas"] = replicas
+        ready &= {e[0]._actor_id for e in replicas}
         if changed:
             self._version += 1
             self._publish_version()

@@ -185,6 +185,10 @@ def make_train_step(
             batch_shape=sig)
         return out
 
+    # ahead-of-time access to the jitted step for callers that read the
+    # compiled program (chip_smoke.py looks for the flash kernels in it)
+    instrumented_step.lower = step_fn.lower
+
     def place_batch(batch):
         session = _sess._session
         if session is None or not session.telemetry_on:

@@ -90,7 +90,12 @@ class TrainWorker:
         )
         self._session = _init_session(context)
         self._maybe_init_jax_distributed(context, use_tpu)
-        self._enable_compilation_cache()
+        # a restarted gang's train step is byte-identical to the one the
+        # dead gang compiled: the fresh processes must find it on disk
+        # instead of re-running XLA (SURVEY §7.4 fast gang restart)
+        from .._private import device_plane
+
+        device_plane.enable_compilation_cache()
         train_fn = cloudpickle.loads(train_fn_blob)
 
         def _run():
@@ -148,62 +153,25 @@ class TrainWorker:
         except Exception:  # graftlint: ignore[swallow] — telemetry
             pass  # must never fail a gang start
 
-    def _enable_compilation_cache(self) -> None:
-        """Persistent XLA compilation cache (SURVEY §7.4 fast gang
-        restart). Elastic SPMD restart = re-shard + RECOMPILE + restore;
-        the recompile dominates restart-to-next-step latency, and a
-        restarted gang's train step is byte-identical to the one the
-        dead gang compiled — so the fresh worker processes must find it
-        on disk instead of re-running XLA. Cache dir comes from
-        config.mesh_compile_cache_dir (default: a shared /tmp dir).
-        Harmless if jax was already initialized — the flags apply to
-        subsequent compiles."""
-        from .._private.config import global_config
-
-        # per-uid default path: a fixed shared /tmp dir breaks when a
-        # second user's workers can't write the first user's 0755 dir
-        path = (global_config().mesh_compile_cache_dir
-                or f"/tmp/ray_tpu_compile_cache_{os.getuid()}")
-        try:
-            import jax
-
-            os.makedirs(path, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", path)
-            # cache only compiles that cost real time — sub-second ones
-            # would grow the dir without bounding restart latency
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.2)
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", -1)
-        except Exception:
-            # an exotic jax build without the cache is a slow restart,
-            # not a broken one
-            pass
-
     def _maybe_init_jax_distributed(self, context: TrainContext,
                                     use_tpu: bool) -> None:
         """Multi-host SPMD bring-up (the NCCL-rendezvous analog, ref:
         train/torch/config.py:66 _setup_torch_process_group → here
-        jax.distributed over the gang's rank-0 coordinator). Gated on the
-        ScalingConfig's use_tpu — NOT on JAX_PLATFORMS, which the raylet
-        sets to "cpu" for every pool worker it spawns; a TPU worker must
-        first reclaim the device plane."""
+        jax.distributed over the gang's rank-0 coordinator). A
+        ``use_tpu`` rank must run under a lease that holds chips: that
+        lease, not this flag, is what took the raylet's CPU pin off this
+        process (device_plane.claim_chips, at actor creation)."""
         if not use_tpu:
             return
-        # undo the pool-worker CPU pin so jax sees the host's chips — but
-        # only if jax hasn't initialized yet in this process: a reused pool
-        # worker whose earlier task touched jax is pinned to CPU for good,
-        # and silently training a "TPU" gang on CPU must not happen
-        import sys
+        from .. import get_tpu_chip_ids
+        from .._private import device_plane
 
-        if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-            if "jax" in sys.modules:
-                raise RuntimeError(
-                    "TPU train worker landed in a process where jax was "
-                    "already initialized under JAX_PLATFORMS=cpu; the device "
-                    "plane cannot be reclaimed. Schedule TPU gangs onto "
-                    "fresh workers (dedicated PG bundles).")
-            os.environ.pop("JAX_PLATFORMS", None)
+        chip_ids = get_tpu_chip_ids()
+        if not chip_ids:
+            raise RuntimeError(
+                "use_tpu train worker holds no TPU chips: its "
+                "resources_per_worker must request \"TPU\"")
+        device_plane.claim_chips(chip_ids)   # raises if jax beat us here
         if context.world_size <= 1 or not context.coordinator_address:
             return
         try:
@@ -265,8 +233,16 @@ class WorkerGroup:
     placement group."""
 
     def __init__(self, scaling: ScalingConfig, experiment_name: str):
+        from .. import nodes
+
         self.scaling = scaling
         self.experiment_name = experiment_name
+        host_chips = 0.0
+        if scaling.use_tpu:
+            host_chips = max((n["Resources"].get("TPU", 0.0)
+                              for n in nodes() if n["Alive"]), default=0.0)
+        # raises for a use_tpu bundle no host can ever grant
+        self.bundle = scaling.worker_resources(host_chips)
         self.pg = None
         self.workers: List[Any] = []
         self.coordinator_address = ""
@@ -276,7 +252,7 @@ class WorkerGroup:
         from ..util import placement_group, PlacementGroupSchedulingStrategy
 
         n = self.scaling.num_workers
-        bundle = self.scaling.worker_resources()
+        bundle = self.bundle
         self.pg = placement_group([dict(bundle) for _ in range(n)],
                                   strategy=self.scaling.placement_strategy)
         if not self.pg.wait(timeout_seconds=120):

@@ -216,3 +216,44 @@ def test_object_store_concurrent_get(tmp_path):
         assert b.used_bytes() == 4096, b.used_bytes()
     finally:
         a.destroy()
+
+
+@pytest.mark.parametrize("via", ["get", "wait"])
+def test_async_get_survives_a_reply_landing_between_its_two_reads(
+        ray_start_regular, via):
+    """A fast-lane reply thread stores a task's value and only then
+    un-registers the task. An await that read "not stored" and then "not
+    pending" around that moment took the object for a foreign one and
+    parked on the raylet directory forever (small returns never enter
+    it): one streamed serve response in a few dozen hung that way."""
+    import threading
+
+    from ray_tpu._private import serialization as ser
+    from ray_tpu._private.ids import ObjectID
+
+    core = ray_tpu._worker_api.core()
+    oid = ObjectID.from_random()
+    core._lane_events[oid] = threading.Event()   # in flight on a lane
+    real_contains = core.memory_store.contains
+    landed = []
+
+    def contains(o):
+        if o == oid and not landed:
+            # the reply lands right AFTER this read says "not stored"
+            landed.append(True)
+            core.memory_store.put(oid, ser.serialize("late"))
+            core._lane_events.pop(oid, None)
+            return False
+        return real_contains(o)
+
+    core.memory_store.contains = contains
+    try:
+        if via == "get":
+            assert core.io.run(core._get([oid], None), timeout=20) == ["late"]
+        else:
+            assert core.io.run(core._wait([oid], 1, None),
+                               timeout=20) == [oid]
+    finally:
+        core.memory_store.contains = real_contains
+        core._lane_events.pop(oid, None)
+        core.memory_store.delete(oid)

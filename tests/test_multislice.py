@@ -9,7 +9,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from ray_tpu.util.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.parallel import (MeshSpec, build_mesh, build_multislice_mesh,

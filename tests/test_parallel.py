@@ -11,7 +11,7 @@ from ray_tpu.parallel import (
     broadcast, local_mesh, logical_sharding, pgroup, reducescatter, send,
     slice_topology,
 )
-from ray_tpu.util.jax_compat import shard_map
+from jax import shard_map
 
 
 def test_mesh_spec_factor():

@@ -159,6 +159,30 @@ def test_init_params_quantized_structure_and_engine_smoke():
     assert all(0 <= t < cfg.vocab for t in out)
 
 
+def test_llm_server_builds_seeded_int8_weights():
+    """init="random" + quantize="int8" (the only 8B weights a machine
+    without a checkpoint has): the server's engine is the engine built
+    directly from the same seed."""
+    import asyncio
+
+    from ray_tpu.llm import LLMServer
+
+    ecfg = dict(max_num_seqs=2, page_size=4, num_pages=32, max_seq_len=32,
+                decode_burst=4)
+    server = LLMServer("tiny", init="random", quantize="int8", seed=5,
+                       engine_config=ecfg)
+    assert is_quantized(server.engine.params["layers"]["wq"])
+    out = asyncio.run(server.completions(
+        {"prompt_ids": [1, 2, 3], "temperature": 0.0, "max_tokens": 6}))
+    direct = LLMEngine(init_params_quantized(jax.random.PRNGKey(5), CFG),
+                       CFG, EngineConfig(**ecfg))
+    want = direct.generate([[1, 2, 3]], SamplingParams(
+        temperature=0.0, max_tokens=6))[0]
+    assert out["choices"][0]["token_ids"] == want
+    with pytest.raises(ValueError, match="unknown quantize"):
+        LLMServer("tiny", init="random", quantize="int4")
+
+
 def test_moe_quantization_rejected():
     cfg = dataclasses.replace(CFG, n_experts=4)
     with pytest.raises(NotImplementedError):

@@ -221,6 +221,28 @@ def test_replica_death_recovers(serve_cluster):
         raise AssertionError(f"service never recovered: {last_err}")
 
 
+def test_slow_constructor_is_starting_not_unhealthy(serve_cluster):
+    """A replica that loads weights onto a chip constructs for minutes;
+    its first health check only queues behind the constructor. The
+    controller must wait for it, not kill and replace it every health
+    period (which no slow-starting replica would ever survive)."""
+    from ray_tpu.serve.controller import HEALTH_TIMEOUT_S
+
+    @serve.deployment
+    class SlowStart:
+        def __init__(self, seconds):
+            time.sleep(seconds)
+            self.pid = os.getpid()
+
+        def __call__(self, _=None):
+            return self.pid
+
+    handle = serve.run(SlowStart.bind(HEALTH_TIMEOUT_S + 4))
+    pid = ray_tpu.get(handle.remote(None), timeout=120)
+    time.sleep(3)   # a few more health periods: still the same replica
+    assert ray_tpu.get(handle.remote(None), timeout=60) == pid
+
+
 def test_autoscaling_scales_with_load(serve_cluster):
     """Queue-driven replica autoscaling (ref: serve autoscaling tests):
     a burst of slow requests grows the replica set toward max_replicas;

@@ -1,0 +1,207 @@
+"""Ahead-of-time compiles for a described TPU v5e (no chip needed).
+
+The TPU compiler is installed wherever jax[tpu] is, and compiles for a
+topology that is described, not attached. These cases hand it the main
+path's kernels at their real widths, so what Mosaic or XLA would refuse
+on the chip (a tile that is not aligned, too much VMEM, a kernel GSPMD
+cannot partition, a program that does not fit HBM) fails here, at no chip
+time. A compile that passes is not a chip run: nothing executes, so these
+say nothing about results or speed (chip_smoke.py does).
+
+Everything that touches the topology lives in module-scoped fixtures of
+THIS file: only one process at a time may load libtpu, pytest-xdist
+workers each import every test file, and a second file's fixture would
+find the library taken.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+V5E_HBM_BYTES = 16 * 1024**3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    # compile what the program runs: another test file raises the
+    # process-wide matmul precision as it is imported, and Mosaic refuses
+    # the flash kernels' bf16 operands at fp32 contract precision
+    precision = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", None)
+    try:
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no libtpu, or another process holds it
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+    finally:
+        jax.config.update("jax_default_matmul_precision", precision)
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _custom_calls(fn, *args) -> int:
+    return jax.jit(fn).lower(*args).compile().as_text().count(
+        "tpu_custom_call")
+
+
+# (batch, seq, q heads, kv heads) at head_dim 128
+FLASH_SHAPES = {
+    "1b-train-4x2048": (4, 2048, 16, 8),
+    "8b-prefill-512": (1, 512, 32, 8),
+    "400m-8k": (1, 8192, 8, 4),
+}
+
+
+def _qkv(shape, sharding):
+    b, s, hq, hkv = shape
+    return (_sds((b, s, hq, 128), jnp.bfloat16, sharding),
+            _sds((b, s, hkv, 128), jnp.bfloat16, sharding),
+            _sds((b, s, hkv, 128), jnp.bfloat16, sharding))
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES.values(), ids=FLASH_SHAPES)
+def test_flash_forward_compiles_for_v5e(one_chip, shape):
+    from ray_tpu.ops.attention import flash_attention_tpu
+
+    fwd = functools.partial(flash_attention_tpu, causal=True)
+    assert _custom_calls(fwd, *_qkv(shape, one_chip)) == 1
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES.values(), ids=FLASH_SHAPES)
+def test_flash_forward_backward_compiles_for_v5e(one_chip, shape):
+    from ray_tpu.ops.attention import attention
+
+    def loss(q, k, v):
+        return attention(q, k, v, causal=True,
+                         use_pallas=True).astype(jnp.float32).sum()
+
+    # forward, dq, and dk/dv: three kernels
+    assert _custom_calls(jax.grad(loss, argnums=(0, 1, 2)),
+                         *_qkv(shape, one_chip)) == 3
+
+
+# (batch, kv heads, q heads per kv head, context pages of 64, burst tail)
+PAGED_SHAPES = {
+    "8b-B8-ctx1024": (8, 8, 4, 16, 8),
+    "400m-8k-B4": (4, 4, 2, 128, 32),
+}
+
+
+@pytest.mark.parametrize("shape", PAGED_SHAPES.values(), ids=PAGED_SHAPES)
+def test_paged_decode_compiles_for_v5e(one_chip, shape):
+    """``interpret=False`` must reach Mosaic even though this process's
+    default backend is the CPU."""
+    from ray_tpu.ops.paged_attention import paged_decode_attention
+
+    b, kvh, rep, n_pages, tail = shape
+    page, hd = 64, 128
+    pool = _sds((1 + b * n_pages, page, kvh, hd), jnp.bfloat16, one_chip)
+    new = _sds((b, tail, kvh, hd), jnp.bfloat16, one_chip)
+    lens = _sds((b,), jnp.int32, one_chip)
+    kernel = functools.partial(paged_decode_attention, page_size=page,
+                               interpret=False)
+    assert _custom_calls(
+        kernel, _sds((b, kvh, rep, hd), jnp.bfloat16, one_chip), pool, pool,
+        new, new, _sds((b, n_pages), jnp.int32, one_chip), lens, lens) == 1
+
+
+def test_decode_burst_8b_int8_fits_one_v5e(one_chip):
+    """The whole decode program chip_smoke.py serves with: Llama-3-8B,
+    int8 weights, 8 slots, from shapes alone."""
+    from ray_tpu.llm.runner import decode_burst
+    from ray_tpu.models import LLAMA_CONFIGS
+    from ray_tpu.ops import rope_frequencies
+    from ray_tpu.ops.quant import init_params_quantized
+
+    cfg = LLAMA_CONFIGS["8b"]
+    B, K, page, n_pages, max_seq = 8, 8, 64, 129, 1024
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params_quantized(jax.random.PRNGKey(0), cfg)))
+    cos, sin = on_chip(jax.eval_shape(
+        lambda: rope_frequencies(cfg.head_dim, max_seq, cfg.rope_theta)))
+    cache = _sds((cfg.n_layers, n_pages, page, cfg.n_kv_heads,
+                  cfg.head_dim), cfg.dtype, one_chip)
+    i32 = _sds((B,), jnp.int32, one_chip)
+    f32 = _sds((B,), jnp.float32, one_chip)
+    compiled = decode_burst.lower(
+        params, cache, cache, i32, i32,
+        _sds((B, 8), jnp.int32, one_chip), _sds((B,), jnp.bool_, one_chip),
+        cos, sin, i32, f32, i32, f32, None, cfg=cfg, n_steps=K,
+        paged_kernel=False, greedy=True).compile()
+    mem = compiled.memory_analysis()
+    # the donated cache aliases its output; everything else is live at once
+    live = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert 8 * 1024**3 < live < V5E_HBM_BYTES, mem
+
+
+def test_sharded_train_step_keeps_flash_kernels(topo):
+    """fsdp=2 x tp=2 over the four described chips: GSPMD cannot partition
+    a Mosaic kernel, so the model runs attention under shard_map there.
+    The step must compile, keep the kernels, and spread its state."""
+    import optax
+
+    from ray_tpu.models import LlamaConfig, init_params, lm_loss
+    from ray_tpu.models import param_logical_axes
+    from ray_tpu.parallel import MeshSpec, build_mesh
+    from ray_tpu.parallel.sharding import DEFAULT_RULES, shard_pytree
+    from ray_tpu.train import make_train_step
+    from ray_tpu.train.step import (TrainState, _batch_sharding,
+                                    opt_state_shardings)
+
+    # 1b's head layout (16/8 heads of 128) at a fraction of its depth
+    cfg = LlamaConfig(vocab=32768, dim=2048, n_layers=2, n_heads=16,
+                      n_kv_heads=8, mlp_dim=4096, max_seq=2048)
+    mesh = build_mesh(MeshSpec(fsdp=2, tp=2), topo.devices)
+    optimizer = optax.adamw(3e-4)
+    axes = param_logical_axes(cfg)
+    _init, step_fn, _place = make_train_step(
+        lambda p, b: lm_loss(p, b, cfg, mesh=mesh), optimizer, mesh, axes)
+
+    def placed(tree, shardings):
+        return jax.tree.map(
+            lambda a, s: _sds(a.shape, a.dtype, s), tree, shardings)
+
+    params = jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg))
+    param_sh = shard_pytree(params, axes, mesh, DEFAULT_RULES)
+    state = TrainState(
+        step=_sds((), jnp.int32, NamedSharding(mesh, P())),
+        params=placed(params, param_sh),
+        opt_state=placed(
+            jax.eval_shape(optimizer.init, params),
+            opt_state_shardings(optimizer, params, param_sh, mesh)))
+    batch = {"tokens": _sds((4, 2048), jnp.int32,
+                            _batch_sharding(mesh, DEFAULT_RULES))}
+    compiled = step_fn.lower(state, batch).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    whole = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    assert per_device < 0.3 * whole, (per_device, whole)
