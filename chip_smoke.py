@@ -232,8 +232,12 @@ def phase_serve(seed: int, spec: dict) -> dict:
         raise AssertionError("requests never shared a decode batch "
                              f"(max running slots seen: {max_running})")
 
-    info = ray_tpu.get(handle.options(method_name="device_info").remote(),
-                       timeout=60)
+    # what the prefill these prompts ran was compiled to, read from the
+    # program's text by the replica (every prompt pads to one bucket)
+    t3 = time.time()
+    info = ray_tpu.get(handle.options(method_name="device_info").remote(
+        {"prompt_len": max(PROMPT_LENS)}), timeout=600)
+    info_s = time.time() - t3
     _check_device(info)
     serve.shutdown()
     gone_s = _wait_gone([info["pid"]])
@@ -244,6 +248,7 @@ def phase_serve(seed: int, spec: dict) -> dict:
          peak_hbm_bytes=_hbm(info["memory_stats"], "peak_bytes_in_use"),
          hbm_limit_bytes=_hbm(info["memory_stats"], "bytes_limit"),
          prefill_attention=info["prefill_attention"],
+         prefill_read_s=round(info_s, 2),
          compile_cache_dir=info["compile_cache_dir"],
          compile_cache=info["compile_cache"],
          first_request_s=round(cold_s, 1), max_running=max_running,

@@ -418,6 +418,11 @@ class CoreWorker:
         plane), landing it sealed in ITS node store where every local
         worker shares it."""
         oid = payload["object_id"]
+        # first, for the reason _get gives: a reply landing between a
+        # "not stored" and a "not pending" read would answer "gone", and
+        # a borrower resolving task arguments then parks on the raylet
+        # directory, which a small object never enters
+        pending = bool(self._pending_here([oid]))
         data = self.memory_store.get(oid)
         if data is None:
             view = self.store.get(oid)
@@ -428,8 +433,7 @@ class CoreWorker:
                 data = bytes(view)
         if data is not None:
             return {"status": "ok", "data": data}
-        if (oid in self._lane_events or oid.task_id() in self._inflight
-                or oid.task_id() in self._streams):
+        if pending:
             return {"status": "pending", "data": None}
         return {"status": "gone", "data": None}
 

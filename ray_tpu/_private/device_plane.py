@@ -41,18 +41,32 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 DEFAULT_CACHE_DIR = os.path.join(_REPO_ROOT, ".jax_cache")
 
+# node label: what the node's own environment asks of jax
+JAX_PLATFORMS_LABEL = "jax_platforms"
+
 _cache_events: "collections.Counter[str]" = collections.Counter()
+
+
+def node_jax_platforms() -> str:
+    """What this node's environment asks of jax ("" when unset). A node
+    publishes it as its ``JAX_PLATFORMS_LABEL`` label and hands it to its
+    workers (``pinned_worker_env``)."""
+    return os.environ.get("JAX_PLATFORMS", "")
+
+
+def allows_tpu(jax_platforms: str) -> bool:
+    """Whether a process started under this ``JAX_PLATFORMS`` may take a
+    TPU: "tpu,cpu" on a TPU host makes jax FAIL at start-up when it
+    cannot take the chip; under "cpu" (the test suite) it never takes
+    one, whatever it leases; unset leaves the choice to jax."""
+    return not jax_platforms or "tpu" in jax_platforms.split(",")
 
 
 def pinned_worker_env(node_chips: int) -> Dict[str, str]:
     """What the raylet adds to every worker's environment: the CPU pin,
     and what ``claim_chips`` needs to take it off again."""
     return {"JAX_PLATFORMS": CPU_PIN,
-            # the node's own setting: "tpu,cpu" on a TPU host makes jax
-            # FAIL at start-up when it cannot take the chip; a node
-            # started under "cpu" (the test suite) never takes one,
-            # whatever it leases; unset leaves the choice to jax
-            "RAY_TPU_NODE_JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", ""),
+            "RAY_TPU_NODE_JAX_PLATFORMS": node_jax_platforms(),
             "RAY_TPU_NODE_CHIPS": str(node_chips)}
 
 

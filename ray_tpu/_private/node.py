@@ -17,6 +17,7 @@ import time
 import uuid
 from typing import Dict, Optional
 
+from . import device_plane
 from .config import global_config
 from .gcs import GcsServer
 from .ids import NodeID
@@ -152,6 +153,10 @@ class Node:
         acc_type = _detect_accelerator_type()
         if acc_type and "accelerator_type" not in node_labels:
             node_labels["accelerator_type"] = acc_type
+        # whether this node's workers may take a TPU at all: deployments
+        # that want a chip per replica ask here (llm/serve.py)
+        node_labels.setdefault(device_plane.JAX_PLATFORMS_LABEL,
+                               device_plane.node_jax_platforms())
         self.raylet = Raylet(
             node_id=self.node_id,
             session_name=self.session_name,

@@ -30,6 +30,7 @@ import collections
 import json
 import os
 import random
+import signal
 import subprocess
 import sys
 import time
@@ -53,6 +54,11 @@ from .task_spec import (
 )
 
 
+# how long a retired chip worker may take to exit before it is killed
+# (_release_chips_when_gone)
+_CHIP_EXIT_GRACE_S = 30.0
+
+
 @dataclass
 class WorkerHandle:
     worker_id: WorkerID
@@ -63,9 +69,10 @@ class WorkerHandle:
     lease: Optional["Lease"] = None
     actor_id: Optional[ActorID] = None  # dedicated actor worker
     alive: bool = True
-    # chips of finished leases this process may still hold open; they
-    # are granted again only once it is gone (_retire_chip_worker)
-    held_chips: List[tuple] = field(default_factory=list)
+    # never leased yet: user code has not run here, so jax is not
+    # imported. Only such a worker may take a lease that holds chips
+    # (_pop_worker)
+    fresh: bool = True
 
 
 @dataclass
@@ -90,6 +97,17 @@ class Lease:
     # resolution (ref: NotifyDirectCallTaskBlocked in node_manager.cc —
     # without this, a gang of dep-waiting workers deadlocks the node)
     blocked_cpu: Optional[ResourceSet] = None
+
+
+@dataclass
+class _ChipHold:
+    """The TPU of a finished lease — chips and scalar — while the process
+    that claimed it may still hold the device open
+    (_retire_chip_worker)."""
+    pid: int
+    chips: List[tuple]
+    tpu: float
+    pg_key: Optional[tuple]
 
 
 @dataclass
@@ -282,6 +300,8 @@ class Raylet:
         # TPU_VISIBLE_CHIPS isolation + GPU fractional semantics)
         self._chip_used: List[float] = \
             [0.0] * int(self.resources.total.get("TPU", 0))
+        # TPU of finished leases whose workers have not exited yet
+        self._chip_holds: List[_ChipHold] = []
         # smoothed NTP-style estimate of (GCS clock - local clock);
         # None until the first clock-sync round completes
         self._clock_offset: Optional[float] = None
@@ -1300,9 +1320,6 @@ class Raylet:
             pass
         if worker in self._idle:
             self._idle.remove(worker)
-        if worker.held_chips:
-            held, worker.held_chips = worker.held_chips, []
-            background(self._release_chips_when_gone(worker.pid, held))
         if worker.lease is not None:
             lease = worker.lease
             self._forget_rid(lease.lease_id)
@@ -1344,23 +1361,39 @@ class Raylet:
             await self._report_resources()
         await self._pump_pending()
 
-    async def _pop_worker(self, dedicated: bool = False) -> Optional[WorkerHandle]:
-        while self._idle:
-            worker = self._idle.pop()
-            if worker.alive:
+    async def _pop_worker(self, dedicated: bool = False,
+                          fresh: bool = False) -> Optional[WorkerHandle]:
+        """An idle worker for a new lease, or None after asking for one
+        to be spawned. ``fresh``: the lease holds chips, so its worker
+        must not have run anything yet — a worker whose jax started under
+        the CPU pin can never reach the chip (device_plane.claim_chips).
+        Chip workers are retired with their lease; this makes them born
+        with it too."""
+        if fresh:
+            worker = next((w for w in self._idle if w.alive and w.fresh),
+                          None)
+            if worker is not None:
+                self._idle.remove(worker)
                 return worker
-        if dedicated:
-            # an actor pins its worker for life, so the pool soft limit
-            # must not gate it — the limit sizes the REUSABLE pool, and a
-            # pinned worker never returns to it (ref: worker_pool.h —
-            # dedicated workers bypass the soft cap). Spawns are bounded
-            # by actual dedicated demand (this request + queued actor
-            # leases) and burst-throttled so 1k queued creations don't
-            # fork-storm — without the demand bound, every pump pass
-            # during one worker's startup window would fork another.
+        else:
+            while self._idle:
+                worker = self._idle.pop()
+                if worker.alive:
+                    return worker
+        if dedicated or fresh:
+            # an actor pins its worker for life and a chip worker dies
+            # with its lease, so the pool soft limit must not gate them —
+            # the limit sizes the REUSABLE pool, and neither ever returns
+            # to it (ref: worker_pool.h — dedicated workers bypass the
+            # soft cap). Spawns are bounded by actual demand (this
+            # request + queued actor and chip leases) and burst-throttled
+            # so 1k queued creations don't fork-storm — without the
+            # demand bound, every pump pass during one worker's startup
+            # window would fork another.
             demand = 1 + sum(
                 1 for p in self._pending_leases
-                if p.payload.get("actor_id") is not None
+                if (p.payload.get("actor_id") is not None
+                    or p.resources.get("TPU") > 0)
                 and not p.future.done())
             if self._starting < min(self.cfg.worker_spawn_burst, demand):
                 self._spawn_worker()
@@ -1485,7 +1518,8 @@ class Raylet:
         elif not self.resources.try_allocate(resources):
             return None
         worker = await self._pop_worker(
-            dedicated=payload.get("actor_id") is not None)
+            dedicated=payload.get("actor_id") is not None,
+            fresh=resources.get("TPU") > 0)
         if worker is None:
             if alloc_key is not None:
                 self._pg_bundles[alloc_key].release(resources)
@@ -1508,6 +1542,7 @@ class Raylet:
                       conn=payload.get("_conn"), chips=chips)
         self._next_lease_id += 1
         worker.lease = lease
+        worker.fresh = False
         if payload.get("actor_id") is not None:
             worker.actor_id = payload["actor_id"]
         self._leases[lease.lease_id] = lease
@@ -1747,14 +1782,22 @@ class Raylet:
     def _release_lease_resources(self, lease: Lease) -> None:
         """Return a finished lease's resources to the bundle it drew from, or
         to the node pool. A canceled bundle already released its whole
-        reservation, so its leases return nothing."""
-        self._retire_chip_worker(lease)
-        if lease.pg_key is not None:
-            bundle = self._pg_bundles.get(lease.pg_key)
+        reservation, so its leases return nothing. The TPU of a lease
+        that held chips follows once its worker is gone
+        (_retire_chip_worker)."""
+        resources = lease.resources
+        if lease.chips:
+            resources = self._retire_chip_worker(lease)
+        self._release_resources(lease.pg_key, resources)
+
+    def _release_resources(self, pg_key: Optional[tuple],
+                           resources: ResourceSet) -> None:
+        if pg_key is not None:
+            bundle = self._pg_bundles.get(pg_key)
             if bundle is not None:
-                bundle.release(lease.resources)
+                bundle.release(resources)
             return
-        self.resources.release(lease.resources)
+        self.resources.release(resources)
 
     # -------------------------------------------------- per-lease TPU chips
     def _allocate_chips(self, amount: float) -> Optional[List[tuple]]:
@@ -1798,37 +1841,47 @@ class Raylet:
             if 0 <= i < len(self._chip_used):
                 self._chip_used[i] = max(0.0, self._chip_used[i] - f)
 
-    def _retire_chip_worker(self, lease: Lease) -> None:
+    def _retire_chip_worker(self, lease: Lease) -> ResourceSet:
         """End of a lease that held chips. Its worker claimed the TPU
         backend (device_plane.claim_chips) and may hold libtpu and the
         device until its process exits, so it never returns to the idle
-        pool, and its chips are granted again only once it is gone — the
+        pool, and the lease's TPU — the chips and the scalar resource
+        together — is granted again only once that process is gone: the
         next holder's libtpu would otherwise fail or hang on a busy
-        device. Scalar resources are released by the caller as before;
-        until the chips follow, _try_grant keeps a new chip lease
-        queued."""
-        chips, lease.chips = lease.chips, []
-        if not chips:
-            return
+        device. Returns what of the lease's resources is free now."""
         worker = lease.worker
-        if worker.worker_id not in self._workers:   # already disconnected
-            background(self._release_chips_when_gone(worker.pid, chips))
-            return
-        worker.held_chips.extend(chips)
+        hold = _ChipHold(worker.pid, lease.chips, lease.resources.get("TPU"),
+                         lease.pg_key)
+        lease.chips = []
+        self._chip_holds.append(hold)
+        background(self._release_chips_when_gone(hold))
         if worker.alive:
             worker.alive = False
             self._expected_exits.add(worker.pid)
             if worker.conn is not None:
                 background(worker.conn.push("shutdown", {}))
+        rest = lease.resources.to_dict()
+        rest.pop("TPU", None)
+        return ResourceSet(rest)
 
-    async def _release_chips_when_gone(self, pid: int,
-                                       chips: List[tuple]) -> None:
-        """The connection drops a moment before the kernel has closed the
-        rest of the dying process's files, the device among them."""
-        deadline = time.monotonic() + 30.0
-        while device_plane.process_alive(pid) and time.monotonic() < deadline:
+    async def _release_chips_when_gone(self, hold: _ChipHold) -> None:
+        """Nothing is released under a live process: a holder that has
+        not exited ``_CHIP_EXIT_GRACE_S`` after it was told to is
+        killed, and the wait goes on until the kernel has closed its
+        files, the device among them."""
+        kill_at = time.monotonic() + _CHIP_EXIT_GRACE_S
+        while device_plane.process_alive(hold.pid):
+            if kill_at is not None and time.monotonic() > kill_at:
+                kill_at = None
+                try:
+                    os.kill(hold.pid, signal.SIGKILL)
+                except OSError:
+                    pass   # gone between the two looks
             await asyncio.sleep(0.05)
-        self._release_chips(chips)
+        self._chip_holds.remove(hold)
+        self._release_chips(hold.chips)
+        self._release_resources(hold.pg_key, ResourceSet({"TPU": hold.tpu}))
+        await self._report_resources()
         await self._pump_pending()
 
     def _return_worker_to_pool(self, worker: WorkerHandle) -> None:
@@ -1885,16 +1938,23 @@ class Raylet:
             if lease.pg_key == key:
                 self._leases.pop(lease.lease_id, None)
                 self._forget_rid(lease.lease_id)
-                # bundle resources die with the reservation below, but
-                # chip accounting is node-global and must be returned
-                self._retire_chip_worker(lease)
+                if lease.chips:
+                    self._retire_chip_worker(lease)
                 worker = lease.worker
                 worker.lease = None
                 worker.alive = False
                 self._expected_exits.add(worker.pid)
                 if worker.conn is not None:
                     await worker.conn.push("shutdown", {})
-        self.resources.release(reserved.total)
+        # the reservation goes back to the node, less the TPU that chip
+        # workers of this bundle still hold: that follows them, straight
+        # to the node pool
+        freed = reserved.total.copy()
+        for hold in self._chip_holds:
+            if hold.pg_key == key:
+                hold.pg_key = None
+                freed.subtract(ResourceSet({"TPU": hold.tpu}))
+        self.resources.release(freed)
         # queued leases waiting on this PG with no bundle left here would wait
         # forever: fail them so the submitter re-resolves (and learns of
         # removal from the GCS directory)

@@ -487,6 +487,31 @@ class LLMEngine:
             state.first_token_t = time.perf_counter()
         return [self._append_token(state, tok)]
 
+    def compile_prefill(self, prompt_len: int):
+        """``(bucket, compiled)``: the whole-prompt prefill program a
+        greedy prompt of ``prompt_len`` tokens runs, compiled from
+        abstract arguments (read back from the compile cache where it
+        has run before). For reading what was really built —
+        ``compiled.as_text()``, ``memory_analysis()`` — not for
+        running."""
+        def abstract(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype)
+
+        def row(dtype):
+            return jax.ShapeDtypeStruct((1,), dtype)
+
+        bucket = prefill_bucket(prompt_len, self.ecfg.max_seq_len)
+        params, ck, cv, cos, sin = jax.tree.map(
+            abstract, (self.params, self.cache.k, self.cache.v,
+                       self.cos, self.sin))
+        tables = self.seq_table.block_tables
+        return bucket, prefill_sample.lower(
+            params, ck, cv, jax.ShapeDtypeStruct((1, bucket), jnp.int32),
+            row(jnp.int32),
+            jax.ShapeDtypeStruct((1, tables.shape[1]), tables.dtype),
+            cos, sin, 0, row(jnp.float32), row(jnp.int32),
+            row(jnp.float32), None, cfg=self.cfg, greedy=True).compile()
+
     def _run_prefill_chunk(self, state: RequestState, seq: List[int],
                            L: int, C: int) -> List[StepOutput]:
         from .runner import prefill_chunk, sample_logits

@@ -103,8 +103,6 @@ class LLMServer:
             raise ValueError(f"unknown init {init!r}")
         ecfg = EngineConfig(**(engine_config or {}))
         self.engine = LLMEngine(params, cfg, ecfg)
-        # whole-prompt prefill buckets served so far (device_info)
-        self._prefill_buckets: set = set()
         self.tokenizer = None
         if tokenizer:
             from transformers import AutoTokenizer
@@ -443,10 +441,6 @@ class LLMServer:
         rid = self.engine.add_request(prompt_ids, params,
                                       request_id=rid_in,
                                       model_id=model_id)
-        from .runner import prefill_bucket
-
-        self._prefill_buckets.add(prefill_bucket(
-            len(prompt_ids), self.engine.ecfg.max_seq_len))
         tenant = current_tenant_id()
         if tenant:
             self._tenants[rid] = tenant
@@ -802,27 +796,52 @@ class LLMServer:
         out["pool"] = self._pool
         return out
 
-    async def device_info(self, _payload=None) -> Dict[str, Any]:
+    @staticmethod
+    def replica_actor_options() -> Dict[str, Any]:
+        """What each replica asks of the cluster it is deployed to
+        (serve.run): one chip, where some node has chips that its workers
+        may take. The chip lease is what gives the replica the TPU
+        backend (_private/device_plane.py), and a replica that holds a
+        chip refuses to serve from any other backend. A cluster held to
+        the CPU (``JAX_PLATFORMS=cpu``, the test suite) serves from the
+        CPU whatever device nodes its hosts show."""
+        from .. import nodes
+        from .._private import device_plane
+
+        for node in nodes():
+            if (node["Alive"] and node["Resources"].get("TPU", 0.0) >= 1
+                    and device_plane.allows_tpu(node["Labels"].get(
+                        device_plane.JAX_PLATFORMS_LABEL, ""))):
+                return {"num_tpus": 1}
+        return {}
+
+    async def device_info(self, payload: Optional[dict] = None
+                          ) -> Dict[str, Any]:
         """What this replica really runs on, as jax reports it: nothing
-        here is inferred from the lease. ``prefill_attention`` maps each
-        whole-prompt prefill bucket served so far to the implementation
-        the attention dispatcher picked for it (ops.attention
-        attention_path); chunked prefill attends with plain einsums."""
+        here is inferred from the lease. With ``{"prompt_len": n}``,
+        ``prefill_attention`` says what the prefill program for a prompt
+        of that length was compiled to: ``"pallas"`` where its text holds
+        the flash kernels' ``tpu_custom_call``s, else ``"blockwise"``
+        (chunked prefill attends with plain einsums and compiles no
+        whole-prompt program)."""
         import jax
 
         from .. import get_tpu_chip_ids
         from .._private import device_plane
-        from ..ops.attention import attention_path
 
-        dev = self._device
-        cfg = self.engine.cfg
-        if self.engine.ecfg.prefill_chunk > 0:
-            prefill_attention: Any = "einsum (chunked prefill)"
-        else:
+        prefill_attention = None
+        prompt_len = (payload or {}).get("prompt_len")
+        if prompt_len is not None and self.engine.ecfg.prefill_chunk > 0:
+            prefill_attention = {"path": "einsum (chunked prefill)"}
+        elif prompt_len is not None:
+            # compiling must not stall the replica's event loop
+            bucket, compiled = await asyncio.get_event_loop().run_in_executor(
+                None, self.engine.compile_prefill, int(prompt_len))
+            calls = compiled.as_text().count("tpu_custom_call")
             prefill_attention = {
-                str(b): attention_path(b, b, cfg.head_dim,
-                                       dev.platform == "tpu")
-                for b in sorted(self._prefill_buckets)}
+                "bucket": bucket, "tpu_custom_calls": calls,
+                "path": "pallas" if calls else "blockwise"}
+        dev = self._device
         return {
             "pid": os.getpid(),
             "platform": dev.platform,
@@ -839,25 +858,18 @@ class LLMServer:
 
 def build_llm_deployment(model: str = "tiny", *, num_replicas: int = 1,
                          name: str = "llm",
-                         pools: Optional[dict] = None,
-                         ray_actor_options: Optional[dict] = None,
-                         **server_kwargs):
+                         pools: Optional[dict] = None, **server_kwargs):
     """An Application running LLMServer replicas (ref: ray.llm
     build_openai_app). ``pools={"prefill": n, "decode": m}`` deploys
     disaggregated prefill/decode pools instead of ``num_replicas``
     monolithic replicas (fleet KV plane).
 
-    Each replica is a process of its own and, on a cluster that has TPU
-    chips, holds one of them (``num_tpus=1``): the chip lease is what
-    gives the replica the TPU backend (_private/device_plane.py), and a
-    replica that holds a chip refuses to serve from any other backend.
-    Pass ``ray_actor_options`` to ask for something else."""
-    from .. import cluster_resources, serve
+    Each replica is a process of its own and, on a cluster whose nodes
+    have TPU chips, holds one of them: ``serve.run`` asks
+    ``LLMServer.replica_actor_options`` once the cluster is known."""
+    from .. import serve
 
-    if ray_actor_options is None and cluster_resources().get("TPU", 0) >= 1:
-        ray_actor_options = {"num_tpus": 1}
     dep = serve.deployment(LLMServer, name=name,
                            num_replicas=num_replicas,
-                           pools=pools,
-                           ray_actor_options=ray_actor_options)
+                           pools=pools)
     return dep.bind(model, **server_kwargs)

@@ -19,6 +19,7 @@ The reference has no native model code (tensors delegated to torch/vLLM
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
@@ -29,7 +30,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops import apply_rotary, attention, ring_attention, rms_norm, rope_frequencies
 from ..ops.attention import attention_path
-from ..parallel.sharding import DEFAULT_RULES, with_sharding_constraint_logical
+from ..parallel.sharding import (DEFAULT_RULES, logical_sharding,
+                                 with_sharding_constraint_logical)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -174,6 +176,44 @@ def init_params(key, cfg: LlamaConfig):
 # ---------------------------------------------------------------------------
 
 
+def sharded_attention(q, k, v, mesh: Mesh, rules=DEFAULT_RULES, *,
+                      use_pallas: Optional[bool] = True):
+    """Causal attention under a multi-device mesh, one ``attention`` call
+    per device on its own batch rows and heads. GSPMD cannot partition a
+    Mosaic kernel ("wrap the call in a shard_map"); attention mixes
+    neither batch rows nor heads, so no collective is needed. The layout
+    is the one ``rules`` give activations (batch over dp x fsdp, heads
+    and kv heads over tp by default). Each sharded dimension must divide
+    evenly — kv heads too, or a device's query heads would attend to
+    another device's kv heads."""
+    q_spec = logical_sharding(
+        mesh, ("batch", "seq", "heads", "head_dim"), rules).spec
+    kv_spec = logical_sharding(
+        mesh, ("batch", "seq", "kv_heads", "head_dim"), rules).spec
+    for name, x, spec in (("q", q, q_spec), ("k/v", k, kv_spec)):
+        for dim, axes in enumerate(spec):
+            if axes is None:
+                continue
+            if dim == 1:
+                raise ValueError(
+                    f"sharded_attention keeps the sequence whole, but the "
+                    f"rules shard it over {axes!r}: sequence parallelism "
+                    f"goes through ring attention (an 'sp' mesh axis)")
+            ways = math.prod(mesh.shape[a] for a in (
+                (axes,) if isinstance(axes, str) else axes))
+            if x.shape[dim] % ways:
+                raise ValueError(
+                    f"attention under mesh {dict(mesh.shape)}: {name} "
+                    f"dimension {dim} of {x.shape} (batch, seq, heads, "
+                    f"head_dim) does not divide over {axes!r} = {ways} "
+                    f"devices; choose a mesh whose axes divide the batch "
+                    f"and the kv heads")
+    return shard_map(
+        partial(attention, causal=True, use_pallas=use_pallas),
+        mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec), out_specs=q_spec,
+        check_vma=False)(q, k, v)
+
+
 def _attn(x, lp, cfg: LlamaConfig, cos, sin, mesh: Optional[Mesh], rules):
     b, s, d = x.shape
     q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"])
@@ -192,15 +232,7 @@ def _attn(x, lp, cfg: LlamaConfig, cos, sin, mesh: Optional[Mesh], rules):
     elif (mesh is not None and mesh.size > 1 and attention_path(
             s, s, cfg.head_dim,
             mesh.devices.flat[0].platform == "tpu") == "pallas"):
-        # GSPMD cannot partition a Mosaic kernel ("wrap the call in a
-        # shard_map"): each device runs the flash kernels on its own
-        # batch rows and heads. Attention mixes neither, so no
-        # collective is needed; batch and heads must divide their axes.
-        spec = P(("dp", "fsdp"), None, "tp", None)
-        out = shard_map(
-            partial(attention, causal=True, use_pallas=True),
-            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_vma=False)(q, k, v)
+        out = sharded_attention(q, k, v, mesh, rules)
     else:
         out = attention(q, k, v, causal=True)
     out = jnp.einsum("bshk,hkd->bsd", out, lp["wo"])

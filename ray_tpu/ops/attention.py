@@ -484,9 +484,10 @@ def _on_tpu(x) -> bool:
 def attention_path(sq: int, skv: int, head_dim: int, on_tpu: bool) -> str:
     """The implementation ``attention`` picks for these shapes:
     ``"pallas"`` (the flash kernels) or ``"blockwise"`` (plain jax).
-    The kernels need a TPU, an MXU-wide head and lane-aligned blocks;
-    callers that must not lose them silently (chip_smoke, the serve
-    replica's device report) ask here and print the answer."""
+    The kernels need a TPU, an MXU-wide head and lane-aligned blocks.
+    The multi-device model path asks here too, with the platform of its
+    mesh (models/llama.py). What a program was really compiled to is
+    read from its text (``tpu_custom_call``), not from this predicate."""
     if (on_tpu and head_dim % 128 == 0
             and _pick_block(sq, 512) is not None
             and _pick_block(skv, 512) is not None):

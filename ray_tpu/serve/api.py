@@ -151,11 +151,19 @@ def run(app: Application, *, name: Optional[str] = None,
     dep = app.deployment
     dep_name = name or dep.name
     controller = _get_or_create_controller()
+    config = dep.config
+    # a class may say what its replicas ask of the cluster it is deployed
+    # to (LLMServer: a chip each, where nodes have chips). Decided here,
+    # where the cluster is known, and not when the deployment was built;
+    # ray_actor_options given to the deployment stand.
+    default_options = getattr(dep._cls, "replica_actor_options", None)
+    if default_options is not None and config.get("ray_actor_options") is None:
+        config = {**config, "ray_actor_options": default_options()}
     ray_tpu.get(controller.deploy.remote(
         dep_name,
         cloudpickle.dumps(dep._cls),
         cloudpickle.dumps((app.init_args, app.init_kwargs)),
-        dep.config,
+        config,
     ), timeout=120)
     return DeploymentHandle(dep_name)
 

@@ -20,12 +20,42 @@ from ..exceptions import RayTpuError
 CONTROLLER_NAME = "SERVE::controller"
 HEALTH_PERIOD_S = 2.0
 HEALTH_TIMEOUT_S = 15.0
+# the longest a replica's constructor may run before it is replaced:
+# weights made on a chip plus cold compiles take minutes, never this long
+REPLICA_STARTUP_TIMEOUT_S = 1800.0
+# GCS actor states in which the constructor has not returned yet
+_CONSTRUCTING = ("PENDING_CREATION", "RESTARTING")
 
 # What best-effort calls against a possibly-dead replica/proxy can
 # raise (transport loss, timeouts, the actor already being gone).
 # Anything outside this set is a controller bug and must surface.
 _REMOTE_ERRORS = (asyncio.TimeoutError, ConnectionError, OSError,
                   RuntimeError, ValueError, RpcError, RayTpuError)
+
+
+def _still_starting(actor_state: Optional[str], unanswered_s: float) -> bool:
+    """STARTING, not unhealthy (ref: deployment_state.py replica states):
+    a health check that times out while the GCS says the replica's
+    constructor is still running only queued behind that constructor.
+    Any other state timing out — ALIVE above all — is a hung replica, and
+    so is a constructor that has run ``REPLICA_STARTUP_TIMEOUT_S``."""
+    return (actor_state in _CONSTRUCTING
+            and unanswered_s < REPLICA_STARTUP_TIMEOUT_S)
+
+
+async def _actor_state(actor_id) -> Optional[str]:
+    """The actor's lifecycle state as the GCS has it (None: not known)."""
+    from .._worker_api import core
+
+    c = core()
+    try:
+        info = await asyncio.wait_for(asyncio.wrap_future(
+            asyncio.run_coroutine_threadsafe(
+                c.gcs.call("get_actor", {"actor_id": actor_id}),
+                c.io.loop)), 5)
+    except _REMOTE_ERRORS:
+        return None
+    return None if info is None else info.state
 
 
 async def _await_ref(ref):
@@ -185,10 +215,13 @@ class ServeController:
             targets = {None: dep["config"].get("num_replicas", 1)}
         code_version = dep["code_version"]
 
+        # when each replica last answered a check (or was created)
+        answered = dep.setdefault("_answered", {})
+        now = time.monotonic()
+
         # concurrent health checks: one hung replica must not stall the
         # control loop for 15s per replica (NB: awaiting ObjectRefs — a
         # blocking get() would stall this actor's loop)
-        ready = dep.setdefault("_ready", set())
 
         async def _check(entry):
             # stale code OR a pool dropped from config = replace
@@ -198,16 +231,11 @@ class ServeController:
                 await asyncio.wait_for(
                     _await_ref(entry[0].health_check.remote()),
                     HEALTH_TIMEOUT_S)
-                ready.add(aid)
+                answered[aid] = time.monotonic()
                 return current
             except asyncio.TimeoutError:
-                # STARTING, not unhealthy (ref: deployment_state.py replica
-                # states): a constructor that loads weights onto a chip
-                # takes minutes, and its health check only queues behind
-                # it. A replica is held to the deadline once it has
-                # answered one check; a constructor that fails kills the
-                # actor, which raises below instead of timing out.
-                return current and aid not in ready
+                return current and _still_starting(
+                    await _actor_state(aid), now - answered.get(aid, now))
             except _REMOTE_ERRORS:
                 return False
 
@@ -226,6 +254,7 @@ class ServeController:
             while len(entries) < target:
                 entries.append((await self._make_replica(dep, pool),
                                 code_version, pool))
+                answered[entries[-1][0]._actor_id] = time.monotonic()
                 changed = True
             if len(entries) > target:
                 # downscale the IDLEST replicas first: killing a replica
@@ -248,7 +277,8 @@ class ServeController:
                 changed = True
             replicas.extend(entries)
         dep["replicas"] = replicas
-        ready &= {e[0]._actor_id for e in replicas}
+        for aid in answered.keys() - {e[0]._actor_id for e in replicas}:
+            del answered[aid]
         if changed:
             self._version += 1
             self._publish_version()

@@ -257,3 +257,40 @@ def test_async_get_survives_a_reply_landing_between_its_two_reads(
         core.memory_store.contains = real_contains
         core._lane_events.pop(oid, None)
         core.memory_store.delete(oid)
+
+
+def test_owner_never_calls_an_object_gone_while_its_reply_lands(
+        ray_start_regular):
+    """The serving side of the same race: an owner that read "not
+    stored" and then "not pending" around a landing reply answered
+    "gone", and the borrower — a worker resolving a task's arguments —
+    parked on the raylet directory for good (an actor call that takes
+    another actor's fresh return hung about one time in four under
+    load)."""
+    import threading
+
+    from ray_tpu._private import serialization as ser
+    from ray_tpu._private.ids import ObjectID
+
+    core = ray_tpu._worker_api.core()
+    oid = ObjectID.from_random()
+    core._lane_events[oid] = threading.Event()   # in flight on a lane
+    real_get = core.memory_store.get
+
+    def get(o):
+        if o == oid and core._lane_events.pop(oid, None) is not None:
+            # the reply lands right AFTER this read says "not stored"
+            core.memory_store.put(oid, ser.serialize("late"))
+            return None
+        return real_get(o)
+
+    core.memory_store.get = get
+    try:
+        fetch = lambda: core.io.run(  # noqa: E731
+            core._handle_fetch_object({"object_id": oid}, None), timeout=20)
+        assert fetch()["status"] == "pending"    # the borrower asks again
+        assert fetch()["status"] == "ok"
+    finally:
+        core.memory_store.get = real_get
+        core._lane_events.pop(oid, None)
+        core.memory_store.delete(oid)

@@ -82,6 +82,91 @@ def test_chip_lease_gets_device_plane_and_chipless_keeps_pin(one_fake_chip):
             held["pid"], again["pid"])
 
 
+def _import_jax():
+    import os
+
+    import jax
+
+    jax.devices()    # the backend starts, under the pool worker's CPU pin
+    return os.getpid()
+
+
+def test_chip_lease_never_lands_on_a_worker_that_ran_jax(monkeypatch):
+    """Chip workers are born with their lease: the worker that just ran a
+    jax task on the CPU is first in line for the next lease, and the chip
+    lease passes it over for a process that never imported jax."""
+    monkeypatch.setenv("RAY_TPU_FAKE_CHIPS", "1")
+    monkeypatch.setenv("JAX_PLATFORMS", TPU_HOST_PLATFORMS)
+    # no fast lanes: every task's worker goes back to the pool at once
+    monkeypatch.setenv("RAY_TPU_FASTLANE", "0")
+    ray_tpu.init(num_cpus=4)
+    try:
+        run_jax = ray_tpu.remote(_import_jax)
+        view = ray_tpu.remote(_lease_view)
+        tainted = ray_tpu.get(run_jax.remote(), timeout=120)
+        assert ray_tpu.get(view.remote(), timeout=60)["pid"] == tainted
+        for _ in range(2):           # a restarted holder is born fresh too
+            held = ray_tpu.get(view.options(num_tpus=1).remote(), timeout=60)
+            assert held["pid"] != tainted and not held["jax_loaded"]
+            assert held["platforms"] == TPU_HOST_PLATFORMS
+        # the pool keeps its worker for chipless work
+        assert ray_tpu.get(run_jax.remote(), timeout=60) == tainted
+    finally:
+        ray_tpu.shutdown()
+
+
+def _slow_to_exit(seconds):
+    """A chip lease whose worker lingers ``seconds`` after it is told to
+    shut down (worker_main leaves through os._exit)."""
+    import os
+    import time
+
+    leave = os._exit
+
+    def linger(code):
+        time.sleep(seconds)
+        leave(code)
+
+    os._exit = linger
+    return os.getpid()
+
+
+@pytest.mark.parametrize("linger_s,grace_s", [
+    (1.5, 30.0),     # leaves by itself, late
+    (60.0, 0.5),     # does not leave: killed when its grace is over
+])
+def test_tpu_is_granted_again_only_when_its_holder_is_gone(
+        one_fake_chip, monkeypatch, linger_s, grace_s):
+    """The scalar TPU resource comes back together with the chip, once
+    the retired worker's process has exited, never while it may still
+    hold the device; a holder that will not leave is killed first."""
+    import time
+
+    from ray_tpu._private import raylet
+
+    monkeypatch.setattr(raylet, "_CHIP_EXIT_GRACE_S", grace_s)
+    pid = ray_tpu.get(ray_tpu.remote(_slow_to_exit).options(
+        num_tpus=1).remote(linger_s), timeout=60)
+    seen_alive_without_tpu = False
+    deadline = time.time() + 20
+    while True:
+        free = ray_tpu.available_resources().get("TPU", 0.0)
+        alive = device_plane.process_alive(pid)
+        if free >= 1.0:
+            # (alive was read after free: a holder that was gone then
+            # is gone now)
+            assert not alive, "TPU handed out under a live holder"
+            break
+        seen_alive_without_tpu |= alive
+        assert time.time() < deadline, "the TPU never came back"
+        time.sleep(0.01)
+    assert seen_alive_without_tpu
+    # and the next chip lease is granted
+    again = ray_tpu.get(ray_tpu.remote(_lease_view).options(
+        num_tpus=1).remote(), timeout=60)
+    assert again["chip_ids"] == [0] and again["pid"] != pid
+
+
 def test_use_tpu_gang_no_host_can_grant_fails_with_a_message(one_fake_chip):
     from ray_tpu.train import ScalingConfig, Trainer
 
@@ -222,6 +307,63 @@ def test_driver_and_worker_imports_stay_off_jax():
         "from ray_tpu.train import Trainer, ScalingConfig, RunConfig\n"
         "assert 'jax' not in sys.modules, 'jax was imported'\n")
     subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+
+@pytest.mark.parametrize("fake_chips,node_platforms,want", [
+    (None, "cpu", {}),                           # no chips at all
+    ("1", "cpu", {}),                            # chips, node held to the CPU
+    ("1", TPU_HOST_PLATFORMS, {"num_tpus": 1}),
+    ("1", None, {"num_tpus": 1}),                # unset: jax's own choice
+])
+def test_llm_replicas_ask_for_a_chip_only_where_a_node_can_take_one(
+        monkeypatch, fake_chips, node_platforms, want):
+    from ray_tpu.llm.serve import LLMServer
+
+    monkeypatch.delenv("RAY_TPU_FAKE_CHIPS", raising=False)
+    if fake_chips is not None:
+        monkeypatch.setenv("RAY_TPU_FAKE_CHIPS", fake_chips)
+    if node_platforms is None:
+        monkeypatch.delenv("JAX_PLATFORMS")
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", node_platforms)
+    ray_tpu.init(num_cpus=2)
+    try:
+        assert LLMServer.replica_actor_options() == want
+    finally:
+        ray_tpu.shutdown()
+
+
+def test_llm_deployment_serves_from_the_cpu_on_a_node_held_to_it(monkeypatch):
+    """A host that shows chip device nodes, started under
+    JAX_PLATFORMS=cpu (this suite on a TPU machine): serve.run asks for
+    no chip, so the replicas neither refuse their backend nor queue on
+    the TPU resource, and the replica reads its prefill path from the
+    program it compiled."""
+    from ray_tpu import serve
+    from ray_tpu.llm import build_llm_deployment
+
+    monkeypatch.setenv("RAY_TPU_FAKE_CHIPS", "1")
+    ray_tpu.init(num_cpus=4)
+    try:
+        assert ray_tpu.cluster_resources()["TPU"] == 1.0
+        handle = serve.run(build_llm_deployment(
+            "tiny", num_replicas=2,
+            engine_config={"max_num_seqs": 2, "page_size": 4,
+                           "num_pages": 64, "max_seq_len": 64}))
+        out = ray_tpu.get(handle.options(method_name="completions").remote(
+            {"prompt_ids": [5, 17, 99, 3], "temperature": 0.0,
+             "max_tokens": 3}), timeout=300)
+        assert len(out["choices"][0]["token_ids"]) == 3
+        info = ray_tpu.get(handle.options(method_name="device_info").remote(
+            {"prompt_len": 4}), timeout=300)
+        assert info["platform"] == "cpu" and info["chip_ids"] == []
+        assert info["prefill_attention"] == {
+            "bucket": 16, "tpu_custom_calls": 0, "path": "blockwise"}
+        assert ray_tpu.available_resources()["TPU"] == 1.0
+        assert serve.status()[0]["num_replicas"] == 2
+    finally:
+        serve.shutdown()
+        ray_tpu.shutdown()
 
 
 def test_llm_server_refuses_a_chip_lease_on_another_backend(monkeypatch):

@@ -107,3 +107,69 @@ def test_reducescatter_and_alltoall(cpu_mesh8):
     out = jax.jit(shard_map(a2a, mesh=mesh, in_specs=P("dp"),
                             out_specs=P(None, "dp"), check_vma=False))(x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(x))
+
+
+def _qkv(batch=4, seq=64, heads=8, kv_heads=4, head_dim=16):
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (batch, seq, heads, head_dim), jnp.float32)
+    k = jax.random.normal(ks[1], (batch, seq, kv_heads, head_dim),
+                          jnp.float32)
+    v = jax.random.normal(ks[2], (batch, seq, kv_heads, head_dim),
+                          jnp.float32)
+    return q, k, v
+
+
+@pytest.mark.parametrize("spec", [
+    MeshSpec(fsdp=2, tp=2),           # the four-chip train mesh
+    MeshSpec(dp=2, fsdp=2, tp=2),
+    MeshSpec(tp=4),                   # one kv head per device
+])
+def test_sharded_attention_matches_unsharded(cpu_mesh8, spec):
+    """The per-device attention the multi-chip TPU path runs under
+    shard_map (there with the flash kernels, here with the plain path):
+    batch rows and GQA head groups land on the device that holds their kv
+    heads, forward and backward."""
+    from ray_tpu.models.llama import sharded_attention
+    from ray_tpu.ops.attention import attention
+
+    mesh = build_mesh(spec, cpu_mesh8[:spec.size])
+    q, k, v = _qkv()
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+
+    def sharded(q, k, v):
+        return sharded_attention(q, k, v, mesh, use_pallas=False)
+
+    def plain(q, k, v):
+        return attention(q, k, v, causal=True, use_pallas=False)
+
+    np.testing.assert_allclose(jax.jit(sharded)(q, k, v), plain(q, k, v),
+                               rtol=1e-5, atol=1e-5)
+    got = jax.jit(jax.grad(loss(sharded), argnums=(0, 1, 2)))(q, k, v)
+    want = jax.grad(loss(plain), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("spec,shape,match", [
+    (MeshSpec(tp=8), {}, "k/v dimension 2"),           # 4 kv heads over 8
+    (MeshSpec(fsdp=4, tp=2), {"batch": 2}, "q dimension 0"),
+])
+def test_sharded_attention_names_what_does_not_divide(cpu_mesh8, spec, shape,
+                                                      match):
+    from ray_tpu.models.llama import sharded_attention
+
+    mesh = build_mesh(spec, cpu_mesh8)
+    with pytest.raises(ValueError, match=match):
+        sharded_attention(*_qkv(**shape), mesh, use_pallas=False)
+
+
+def test_sharded_attention_refuses_a_sharded_sequence(cpu_mesh8):
+    from ray_tpu.models.llama import sharded_attention
+
+    mesh = build_mesh(MeshSpec(fsdp=2, tp=2), cpu_mesh8[:4])
+    rules = tuple(("seq", "fsdp") if name == "seq" else (name, axes)
+                  for name, axes in DEFAULT_RULES if name != "batch")
+    with pytest.raises(ValueError, match="keeps the sequence whole"):
+        sharded_attention(*_qkv(), mesh, rules, use_pallas=False)

@@ -105,6 +105,9 @@ def test_profile_cluster_names_hot_function(ray_cluster):
             x += 1
         return x
 
+    # a worker is up and has the function before the clock starts: on a
+    # slow host the first worker registers later than the sleep below
+    assert ray_tpu.get(spin_hot.remote(0.0), timeout=60) == 0
     ref = spin_hot.remote(4.0)
     time.sleep(0.5)  # let the worker pick it up
     prof = state.profile_cluster(duration_s=1.0, hz=50.0)

@@ -243,6 +243,48 @@ def test_slow_constructor_is_starting_not_unhealthy(serve_cluster):
     assert ray_tpu.get(handle.remote(None), timeout=60) == pid
 
 
+def test_constructed_replica_that_stops_answering_is_replaced(serve_cluster):
+    """The STARTING excuse ends with the constructor: a replica the GCS
+    calls ALIVE that misses a health check is hung, and is replaced."""
+    @serve.deployment
+    class Wedge:
+        def __init__(self):
+            self.pid = os.getpid()
+
+        async def __call__(self, wedge):
+            if wedge:
+                time.sleep(3600)      # blocks the replica's event loop
+            return self.pid
+
+    handle = serve.run(Wedge.bind())
+    pid = ray_tpu.get(handle.remote(False), timeout=60)
+    handle.remote(True)
+    deadline = time.time() + 90
+    while True:
+        try:
+            if ray_tpu.get(handle.remote(False), timeout=5) != pid:
+                break
+        except Exception:  # noqa: BLE001 — the wedged replica's callers
+            pass
+        assert time.time() < deadline, "the hung replica was never replaced"
+
+
+@pytest.mark.parametrize("actor_state,unanswered_s,starting", [
+    ("PENDING_CREATION", 120.0, True),    # the constructor is running
+    ("RESTARTING", 120.0, True),          # ... again, after a death
+    ("PENDING_CREATION", 1e6, False),     # a constructor that hangs
+    ("ALIVE", 20.0, False),               # constructed, and not answering
+    ("DEAD", 20.0, False),
+    (None, 20.0, False),                  # the GCS does not know it
+])
+def test_only_a_running_constructor_excuses_a_missed_health_check(
+        actor_state, unanswered_s, starting):
+    from ray_tpu.serve import controller
+
+    assert controller.REPLICA_STARTUP_TIMEOUT_S < 1e6
+    assert controller._still_starting(actor_state, unanswered_s) is starting
+
+
 def test_autoscaling_scales_with_load(serve_cluster):
     """Queue-driven replica autoscaling (ref: serve autoscaling tests):
     a burst of slow requests grows the replica set toward max_replicas;
