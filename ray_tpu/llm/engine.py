@@ -24,6 +24,7 @@ from __future__ import annotations
 import collections
 import itertools
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Deque, Dict, List, Optional
 
@@ -33,6 +34,7 @@ import numpy as np
 
 from ..models.llama import LlamaConfig
 from ..ops import rope_frequencies
+from ..util import tracing
 from .cache import (KVCache, PageAllocator, PrefixCache, SequenceTable,
                     init_kv_cache)
 from .runner import (decode_burst, prefill_bucket, prefill_sample,
@@ -102,8 +104,22 @@ class RequestState:
     model_id: Optional[str] = None # LoRA adapter name (None = base)
     finished: bool = False
     finish_reason: Optional[str] = None
+    # perf_counter clocks, 0.0 until reached; each is set once, so
+    # arrival_t <= admit_t <= prefill_start_t <= first_token_t <= emit_t:
+    # queue wait, wait for the prefill turn, prefill, hand-over
     arrival_t: float = 0.0
+    admit_t: float = 0.0          # first slot + pages (_admit)
+    prefill_start_t: float = 0.0  # first prefill work unit began
     first_token_t: float = 0.0
+    emit_t: float = 0.0           # first StepOutput queued (LLMServer._pump)
+    preemptions: int = 0
+
+
+# the phases that partition a round: the last parts of the span names
+# (rt.engine.<phase>) and the keys of stats()["counters"]["host_s"]
+PHASES = ("schedule", "prefill.dispatch", "prefill.sync",
+          "decode.dispatch", "decode.sync", "append")
+_SPAN_NAMES = {phase: "rt.engine." + phase for phase in PHASES}
 
 
 @dataclass
@@ -201,6 +217,24 @@ class LLMEngine:
         # table mutates (saves one H2D upload per decode step)
         self._bt_device = None
         self._bt_version = -1
+        # always-on round counters (stats()["counters"]): an integer add
+        # or a clock difference each, no allocation per token. Decode
+        # rounds are the plain bursts (speculative rounds count in
+        # stats()["spec"]); a prefill is one dispatch (a whole prompt,
+        # or one chunk); host_s is by phase, from the spans' clocks.
+        self._counters: Dict[str, Any] = {
+            "rounds": 0, "decode_steps": 0,
+            "width_hist": [0] * (self.ecfg.decode_burst + 1),
+            "active_slot_steps": 0, "prefills": 0, "prefill_tokens": 0,
+            "preemptions": 0, "host_s": dict.fromkeys(PHASES, 0.0)}
+
+    @contextmanager
+    def _phase(self, phase: str):
+        """One phase of the round: a span on the profiler's clock, and
+        the same interval added to ``host_s``."""
+        with tracing.span(_SPAN_NAMES[phase]) as sp:
+            yield
+        self._counters["host_s"][phase] += sp.seconds
 
     # --- public API ---
 
@@ -260,24 +294,29 @@ class LLMEngine:
         the admission/prefill phase (TTFT measurement, draining a
         prefill backlog before decoding)."""
         outputs: List[StepOutput] = []
-        # purge stale entries (aborted/preempted mid-queue) FIRST: they
-        # must neither count toward the admission cap nor linger
-        if any(s.slot < 0 or s.finished for s in self._prefill_queue):
-            self._prefill_queue = collections.deque(
-                s for s in self._prefill_queue
-                if s.slot >= 0 and not s.finished)
-        # admission never blocks on prefill, but the queue is capped:
-        # admission reserves the WHOLE sequence's pages, so admitting
-        # every waiting request up front would pin pages that running
-        # streams need (recompute-preemption cost). Whole-prompt mode
-        # caps at 1 — exactly the old admit-and-prefill-per-step pace.
-        cap = 1 if self.ecfg.prefill_chunk <= 0 else 2
-        while len(self._prefill_queue) < cap:
-            admitted = self._admit()
-            if admitted is None:
-                break
-            self._prefill_queue.append(admitted)
-        pref = self._next_prefill()
+        # The phases below are siblings; no span covers the whole round
+        # (it would name every device idle gap in the round after
+        # itself): the round's total goes to the JSONL sink alone
+        wall0 = time.time() if tracing.tracing_enabled() else 0.0
+        with self._phase("schedule"):
+            # purge stale entries (aborted/preempted mid-queue) FIRST:
+            # they must neither count toward the admission cap nor linger
+            if any(s.slot < 0 or s.finished for s in self._prefill_queue):
+                self._prefill_queue = collections.deque(
+                    s for s in self._prefill_queue
+                    if s.slot >= 0 and not s.finished)
+            # admission never blocks on prefill, but the queue is capped:
+            # admission reserves the WHOLE sequence's pages, so admitting
+            # every waiting request up front would pin pages that running
+            # streams need (recompute-preemption cost). Whole-prompt mode
+            # caps at 1 — exactly the old admit-and-prefill-per-step pace.
+            cap = 1 if self.ecfg.prefill_chunk <= 0 else 2
+            while len(self._prefill_queue) < cap:
+                admitted = self._admit()
+                if admitted is None:
+                    break
+                self._prefill_queue.append(admitted)
+            pref = self._next_prefill()
         if pref is not None:
             outputs.extend(self._run_prefill(pref))
             if pref.ctx_len > 0 or pref.slot < 0 or pref.finished:
@@ -289,6 +328,9 @@ class LLMEngine:
         if not skip_decode and any(
                 s is not None and s.ctx_len > 0 for s in self.slots):
             outputs.extend(self._run_decode())
+        if wall0:
+            tracing.record_lane_event("engine", "rt.engine.round", wall0,
+                                      time.time())
         return outputs
 
     # consecutive work units a queued prefill may be passed over before
@@ -396,6 +438,8 @@ class LLMEngine:
         state.slot = slot
         state.cached_tokens = len(cached_pages) * self.ecfg.page_size
         state.prefill_pos = state.cached_tokens
+        if not state.admit_t:
+            state.admit_t = time.perf_counter()
         self.slots[slot] = state
         self.seq_table.assign(slot, pages)
         return state
@@ -460,32 +504,40 @@ class LLMEngine:
         sample the next token. Whole-prompt mode fuses everything in one
         dispatch; chunked mode advances ONE chunk and only samples after
         the final chunk."""
+        if not state.prefill_start_t:
+            state.prefill_start_t = time.perf_counter()
         seq = state.prompt + state.output
         L = len(seq)
         C = self.ecfg.prefill_chunk
         if C > 0:
             return self._run_prefill_chunk(state, seq, L, C)
-        bucket = prefill_bucket(L, self.ecfg.max_seq_len)
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, :L] = seq
-        seed, temp, top_k, top_p, greedy = self._sampling_arrays([state])
-        lora = None
-        if self.lora_pool is not None:
-            lora = self.lora_pool.select(
-                [self.lora_pool.slot_of(state.model_id)])
-        toks, ck, cv = prefill_sample(
-            self.params, self.cache.k, self.cache.v,
-            jnp.asarray(tokens), jnp.asarray([L], jnp.int32),
-            jnp.asarray(self.seq_table.block_tables[
-                state.slot:state.slot + 1]),
-            self.cos, self.sin, seed, temp, top_k, top_p, lora,
-            cfg=self.cfg, greedy=greedy)
+        with self._phase("prefill.dispatch"):
+            bucket = prefill_bucket(L, self.ecfg.max_seq_len)
+            tokens = np.zeros((1, bucket), np.int32)
+            tokens[0, :L] = seq
+            seed, temp, top_k, top_p, greedy = self._sampling_arrays(
+                [state])
+            lora = None
+            if self.lora_pool is not None:
+                lora = self.lora_pool.select(
+                    [self.lora_pool.slot_of(state.model_id)])
+            toks, ck, cv = prefill_sample(
+                self.params, self.cache.k, self.cache.v,
+                jnp.asarray(tokens), jnp.asarray([L], jnp.int32),
+                jnp.asarray(self.seq_table.block_tables[
+                    state.slot:state.slot + 1]),
+                self.cos, self.sin, seed, temp, top_k, top_p, lora,
+                cfg=self.cfg, greedy=greedy)
         self.cache = KVCache(ck, cv)
         state.ctx_len = L
-        tok = int(np.asarray(toks)[0])
+        self._counters["prefills"] += 1
+        self._counters["prefill_tokens"] += L
+        with self._phase("prefill.sync"):
+            tok = int(np.asarray(toks)[0])
         if not state.output:
             state.first_token_t = time.perf_counter()
-        return [self._append_token(state, tok)]
+        with self._phase("append"):
+            return [self._append_token(state, tok)]
 
     def compile_prefill(self, prompt_len: int):
         """``(bucket, compiled)``: the whole-prompt prefill program a
@@ -516,42 +568,52 @@ class LLMEngine:
                            L: int, C: int) -> List[StepOutput]:
         from .runner import prefill_chunk, sample_logits
 
-        start = state.prefill_pos
-        n = min(C, L - start)
-        tokens = np.zeros((1, C), np.int32)
-        tokens[0, :n] = seq[start:start + n]
-        # table span bucketed over the pages this chunk can touch, so a
-        # handful of executables serve every prompt length
-        span = self._span_bucket(-(-(start + n) // self.ecfg.page_size))
-        bt = jnp.asarray(
-            self.seq_table.block_tables[state.slot:state.slot + 1, :span])
-        logits, ck, cv = prefill_chunk(
-            self.params, self.cache.k, self.cache.v, jnp.asarray(tokens),
-            jnp.int32(start), jnp.int32(n), bt, self.cos, self.sin,
-            cfg=self.cfg)
+        with self._phase("prefill.dispatch"):
+            start = state.prefill_pos
+            n = min(C, L - start)
+            tokens = np.zeros((1, C), np.int32)
+            tokens[0, :n] = seq[start:start + n]
+            # table span bucketed over the pages this chunk can touch, so
+            # a handful of executables serve every prompt length
+            span = self._span_bucket(
+                -(-(start + n) // self.ecfg.page_size))
+            bt = jnp.asarray(self.seq_table.block_tables[
+                state.slot:state.slot + 1, :span])
+            logits, ck, cv = prefill_chunk(
+                self.params, self.cache.k, self.cache.v,
+                jnp.asarray(tokens), jnp.int32(start), jnp.int32(n), bt,
+                self.cos, self.sin, cfg=self.cfg)
         self.cache = KVCache(ck, cv)
         state.prefill_pos = start + n
+        self._counters["prefills"] += 1
+        self._counters["prefill_tokens"] += n
         if state.prefill_pos < L:
             return []  # more chunks to go; decode interleaves meanwhile
-        if self.prefix_cache is not None and state.prompt_page_keys:
-            # prompt pages are now fully written: publish them for
-            # future requests sharing the prefix
-            table = self.seq_table.block_tables[state.slot]
-            self.prefix_cache.insert(
-                state.prompt_page_keys,
-                [int(p) for p in table[:len(state.prompt_page_keys)]])
-        seed, temp, top_k, top_p, _greedy = self._sampling_arrays([state])
-        tok = int(np.asarray(sample_logits(
-            logits, seed, temp, top_k, top_p))[0])
+        with self._phase("prefill.dispatch"):
+            if self.prefix_cache is not None and state.prompt_page_keys:
+                # prompt pages are now fully written: publish them for
+                # future requests sharing the prefix
+                table = self.seq_table.block_tables[state.slot]
+                self.prefix_cache.insert(
+                    state.prompt_page_keys,
+                    [int(p) for p in table[:len(state.prompt_page_keys)]])
+            seed, temp, top_k, top_p, _greedy = self._sampling_arrays(
+                [state])
+            toks = sample_logits(logits, seed, temp, top_k, top_p)
+        with self._phase("prefill.sync"):
+            tok = int(np.asarray(toks)[0])
         state.ctx_len = L
         if not state.output:
             state.first_token_t = time.perf_counter()
-        return [self._append_token(state, tok)]
+        with self._phase("append"):
+            return [self._append_token(state, tok)]
 
     def _preempt(self, state: RequestState) -> None:
         """Recompute-preemption (vLLM style): release the sequence's
         pages and put it back at the head of the waiting queue; its
         generated-so-far tokens re-prefill on readmission."""
+        state.preemptions += 1
+        self._counters["preemptions"] += 1
         if self.spec is not None:
             self.spec.drop(state.slot)   # drafter KV dies with the pages
         self.allocator.free(self.seq_table.pages_of(state.slot))
@@ -612,52 +674,64 @@ class LLMEngine:
             if outs is not None:
                 return outs
         B = self.ecfg.max_num_seqs
-        K = self._burst_width()
-        for s in [s for s in self.slots
-                  if s is not None and s.ctx_len > 0]:
-            if s.slot < 0:
-                continue  # preempted as a victim earlier this round
-            self._provision_pages(s, s.ctx_len + K)
-        # mid-prefill slots (chunked) hold pages but don't decode yet
-        active_states = [s for s in self.slots
-                         if s is not None and s.ctx_len > 0]
-        if not active_states:
-            return []
-        tokens = np.zeros(B, np.int32)
-        positions = np.zeros(B, np.int32)
-        active = np.zeros(B, bool)
-        for s in active_states:
-            last = s.output[-1] if s.output else s.prompt[-1]
-            tokens[s.slot] = last
-            positions[s.slot] = s.ctx_len
-            active[s.slot] = True
-        seed, temp, top_k, top_p, greedy = self._sampling_arrays(
-            self.slots, advance=K)
-        lora = None
-        if self.lora_pool is not None:
-            ids = [0] * self.ecfg.max_num_seqs
-            for s2 in active_states:
-                ids[s2.slot] = self.lora_pool.slot_of(s2.model_id)
-            lora = self.lora_pool.select(ids)
-        span = self._active_span()
-        use_paged = self._paged_kernel or (
-            self._paged_min_pages > 0 and span >= self._paged_min_pages)
-        toks, ck, cv = decode_burst(
-            self.params, self.cache.k, self.cache.v,
-            jnp.asarray(tokens), jnp.asarray(positions),
-            self._bt(span),
-            jnp.asarray(active), self.cos, self.sin,
-            seed, temp, top_k, top_p, lora, cfg=self.cfg, n_steps=K,
-            paged_kernel=use_paged, greedy=greedy)
+        counters = self._counters
+        with self._phase("decode.dispatch"):
+            # the ONE call of _burst_width a round: the benchmark's
+            # replica wraps it on the instance to watch the rounds
+            K = self._burst_width()
+            counters["rounds"] += 1
+            counters["decode_steps"] += K
+            counters["width_hist"][K] += 1
+            for s in [s for s in self.slots
+                      if s is not None and s.ctx_len > 0]:
+                if s.slot < 0:
+                    continue  # preempted as a victim earlier this round
+                self._provision_pages(s, s.ctx_len + K)
+            # mid-prefill slots (chunked) hold pages but don't decode yet
+            active_states = [s for s in self.slots
+                             if s is not None and s.ctx_len > 0]
+            if not active_states:
+                return []
+            counters["active_slot_steps"] += K * len(active_states)
+            tokens = np.zeros(B, np.int32)
+            positions = np.zeros(B, np.int32)
+            active = np.zeros(B, bool)
+            for s in active_states:
+                last = s.output[-1] if s.output else s.prompt[-1]
+                tokens[s.slot] = last
+                positions[s.slot] = s.ctx_len
+                active[s.slot] = True
+            seed, temp, top_k, top_p, greedy = self._sampling_arrays(
+                self.slots, advance=K)
+            lora = None
+            if self.lora_pool is not None:
+                ids = [0] * self.ecfg.max_num_seqs
+                for s2 in active_states:
+                    ids[s2.slot] = self.lora_pool.slot_of(s2.model_id)
+                lora = self.lora_pool.select(ids)
+            span = self._active_span()
+            use_paged = self._paged_kernel or (
+                self._paged_min_pages > 0
+                and span >= self._paged_min_pages)
+            toks, ck, cv = decode_burst(
+                self.params, self.cache.k, self.cache.v,
+                jnp.asarray(tokens), jnp.asarray(positions),
+                self._bt(span),
+                jnp.asarray(active), self.cos, self.sin,
+                seed, temp, top_k, top_p, lora, cfg=self.cfg, n_steps=K,
+                paged_kernel=use_paged, greedy=greedy)
         self.cache = KVCache(ck, cv)
-        sampled = np.asarray(toks)  # [K, B]
+        with self._phase("decode.sync"):
+            sampled = np.asarray(toks)  # [K, B]
         outs = []
-        for s in active_states:
-            for k in range(K):
-                s.ctx_len += 1
-                outs.append(self._append_token(s, int(sampled[k, s.slot])))
-                if s.finished:
-                    break
+        with self._phase("append"):
+            for s in active_states:
+                for k in range(K):
+                    s.ctx_len += 1
+                    outs.append(
+                        self._append_token(s, int(sampled[k, s.slot])))
+                    if s.finished:
+                        break
         return outs
 
     # --- speculative decoding (spec_decode.py; Leviathan et al.) ---
@@ -688,99 +762,102 @@ class LLMEngine:
                     and s.ctx_len + kd <= self.ecfg.max_seq_len - 1
                     and s.params.max_tokens - len(s.output) >= 2)
 
-        if not any(s is not None and s.ctx_len > 0 and can_draft(s)
-                   for s in self.slots):
-            return None
-        # provision BEFORE array assembly — may preempt victims, so
-        # drafted/active sets are derived again afterwards
-        for s in [s for s in self.slots
-                  if s is not None and s.ctx_len > 0]:
-            if s.slot < 0:
-                continue  # preempted as a victim earlier this round
-            upto = s.ctx_len + (kd + 1 if can_draft(s) else 1)
-            self._provision_pages(s, upto)
-        active_states = [s for s in self.slots
-                         if s is not None and s.ctx_len > 0]
-        if not active_states:
-            return []
-        drafted_states = [s for s in active_states if can_draft(s)]
-        if not drafted_states:
-            return None
-        # lazy drafter warm-up: first drafted round for a slot (or the
-        # first after a drop) prefills the draft KV for its sequence
-        for s in drafted_states:
-            if s.slot not in spec.ready:
-                seq = s.prompt + s.output
-                spec.prefill(seq[:s.ctx_len],
-                             self.seq_table.block_tables[
-                                 s.slot:s.slot + 1])
-                spec.ready.add(s.slot)
-        span = self._active_span()
-        bt = self._bt(span)
-        items = []
-        for s in drafted_states:
-            seq = s.prompt + s.output
-            p = s.ctx_len
-            items.append((s.slot, seq[p - 1], seq[p], p))
-        drafts = spec.draft(items, bt)
-        # fleet mode: ship (KV snapshot, draft) to a prefill-class
-        # verifier racing the local verify below; by the greedy-
-        # continuation equivalence both compute the same emission, so
-        # the remote result is corroboration + placement, never truth
-        remote: Dict[int, List[int]] = {}
-        if self._spec_remote_verify is not None:
+        with self._phase("decode.dispatch"):
+            if not any(s is not None and s.ctx_len > 0 and can_draft(s)
+                       for s in self.slots):
+                return None
+            # provision BEFORE array assembly — may preempt victims, so
+            # drafted/active sets are derived again afterwards
+            for s in [s for s in self.slots
+                      if s is not None and s.ctx_len > 0]:
+                if s.slot < 0:
+                    continue  # preempted as a victim earlier this round
+                upto = s.ctx_len + (kd + 1 if can_draft(s) else 1)
+                self._provision_pages(s, upto)
+            active_states = [s for s in self.slots
+                             if s is not None and s.ctx_len > 0]
+            if not active_states:
+                return []
+            drafted_states = [s for s in active_states if can_draft(s)]
+            if not drafted_states:
+                return None
+            # lazy drafter warm-up: first drafted round for a slot (or the
+            # first after a drop) prefills the draft KV for its sequence
             for s in drafted_states:
-                try:
-                    payload = self.snapshot_kv_request(s.request_id)
-                    res = self._spec_remote_verify(payload,
-                                                   drafts[s.slot])
-                except Exception:
-                    res = None
-                if res is not None:
-                    remote[s.slot] = [int(t) for t in res]
-        B = self.ecfg.max_num_seqs
-        S = kd + 1
-        tok = np.zeros((B, S), np.int32)
-        pos = np.full((B, S), -1, np.int32)
-        for s in active_states:
-            tok[s.slot, 0] = s.output[-1] if s.output else s.prompt[-1]
-            pos[s.slot, 0] = s.ctx_len
-            d = drafts.get(s.slot)
-            if d:
-                tok[s.slot, 1:1 + len(d)] = d
-                pos[s.slot, 1:1 + len(d)] = (
-                    s.ctx_len + 1 + np.arange(len(d)))
-        seed, temp, top_k, top_p, greedy = self._sampling_arrays(
-            self.slots, advance=1)
-        t0 = time.perf_counter()
-        tgt, samp0, ck, cv = verify_step(
-            self.params, self.cache.k, self.cache.v, jnp.asarray(tok),
-            jnp.asarray(pos), bt, self.cos, self.sin, seed, temp,
-            top_k, top_p, cfg=self.cfg, greedy=greedy)
+                if s.slot not in spec.ready:
+                    seq = s.prompt + s.output
+                    spec.prefill(seq[:s.ctx_len],
+                                 self.seq_table.block_tables[
+                                     s.slot:s.slot + 1])
+                    spec.ready.add(s.slot)
+            span = self._active_span()
+            bt = self._bt(span)
+            items = []
+            for s in drafted_states:
+                seq = s.prompt + s.output
+                p = s.ctx_len
+                items.append((s.slot, seq[p - 1], seq[p], p))
+            drafts = spec.draft(items, bt)
+            # fleet mode: ship (KV snapshot, draft) to a prefill-class
+            # verifier racing the local verify below; by the greedy-
+            # continuation equivalence both compute the same emission, so
+            # the remote result is corroboration + placement, never truth
+            remote: Dict[int, List[int]] = {}
+            if self._spec_remote_verify is not None:
+                for s in drafted_states:
+                    try:
+                        payload = self.snapshot_kv_request(s.request_id)
+                        res = self._spec_remote_verify(payload,
+                                                       drafts[s.slot])
+                    except Exception:
+                        res = None
+                    if res is not None:
+                        remote[s.slot] = [int(t) for t in res]
+            B = self.ecfg.max_num_seqs
+            S = kd + 1
+            tok = np.zeros((B, S), np.int32)
+            pos = np.full((B, S), -1, np.int32)
+            for s in active_states:
+                tok[s.slot, 0] = s.output[-1] if s.output else s.prompt[-1]
+                pos[s.slot, 0] = s.ctx_len
+                d = drafts.get(s.slot)
+                if d:
+                    tok[s.slot, 1:1 + len(d)] = d
+                    pos[s.slot, 1:1 + len(d)] = (
+                        s.ctx_len + 1 + np.arange(len(d)))
+            seed, temp, top_k, top_p, greedy = self._sampling_arrays(
+                self.slots, advance=1)
+            t0 = time.perf_counter()
+            tgt, samp0, ck, cv = verify_step(
+                self.params, self.cache.k, self.cache.v, jnp.asarray(tok),
+                jnp.asarray(pos), bt, self.cos, self.sin, seed, temp,
+                top_k, top_p, cfg=self.cfg, greedy=greedy)
         self.cache = KVCache(ck, cv)
-        tgt = np.asarray(tgt)
-        samp0 = np.asarray(samp0)
+        with self._phase("decode.sync"):
+            tgt = np.asarray(tgt)
+            samp0 = np.asarray(samp0)
         spec.verify_times.append(time.perf_counter() - t0)
         outs: List[StepOutput] = []
-        for s in active_states:
-            if s.slot < 0 or s.finished:
-                continue
-            d = drafts.get(s.slot)
-            if d:
-                emitted = accept_prefix(d, tgt[s.slot].tolist())
-                spec.on_round(len(d), len(emitted) - 1)
-                r = remote.get(s.slot)
-                if r is not None:
-                    spec.remote_rounds_total += 1
-                    if r == emitted:
-                        spec.remote_agree_total += 1
-            else:
-                emitted = [int(samp0[s.slot])]
-            for t in emitted:
-                s.ctx_len += 1
-                outs.append(self._append_token(s, t))
-                if s.finished:
-                    break
+        with self._phase("append"):
+            for s in active_states:
+                if s.slot < 0 or s.finished:
+                    continue
+                d = drafts.get(s.slot)
+                if d:
+                    emitted = accept_prefix(d, tgt[s.slot].tolist())
+                    spec.on_round(len(d), len(emitted) - 1)
+                    r = remote.get(s.slot)
+                    if r is not None:
+                        spec.remote_rounds_total += 1
+                        if r == emitted:
+                            spec.remote_agree_total += 1
+                else:
+                    emitted = [int(samp0[s.slot])]
+                for t in emitted:
+                    s.ctx_len += 1
+                    outs.append(self._append_token(s, t))
+                    if s.finished:
+                        break
         return outs
 
     def verify_request(self, request_id: str,
@@ -997,7 +1074,9 @@ class LLMEngine:
         state.ctx_len = ctx_len
         state.prefill_pos = ctx_len
         if not state.first_token_t:
-            state.first_token_t = time.perf_counter()
+            # prefilled elsewhere: no queue or prefill to wait for here
+            state.admit_t = state.prefill_start_t = state.first_token_t = (
+                time.perf_counter())
         self.slots[slot] = state
         self.seq_table.assign(slot, pages)
         if self.prefix_cache is not None:
@@ -1052,4 +1131,7 @@ class LLMEngine:
         }
         if self.spec is not None:
             out["spec"] = self.spec.stats()
+        out["counters"] = {**self._counters,
+                           "width_hist": list(self._counters["width_hist"]),
+                           "host_s": dict(self._counters["host_s"])}
         return out

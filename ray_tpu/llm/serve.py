@@ -19,7 +19,10 @@ from __future__ import annotations
 import asyncio
 import json
 import os
+import time
 from typing import TYPE_CHECKING, Any, Dict, List, Optional
+
+from ..util import tracing
 
 if TYPE_CHECKING:  # this module stays importable without jax (llm/__init__)
     from .sampling import SamplingParams
@@ -147,6 +150,15 @@ class LLMServer:
             "llm_tpot_seconds", "Time per output token (decode) "
             "per request", boundaries=metrics.LATENCY_BUCKETS,
             tag_keys=("model", "pool", "tenant")).set_default_tags(tags)
+        self._m_queue_wait = metrics.Histogram(
+            "llm_queue_wait_seconds", "Arrival to first slot and pages: "
+            "the part of TTFT spent waiting to be admitted",
+            boundaries=metrics.LATENCY_BUCKETS,
+            tag_keys=("model", "pool", "tenant")).set_default_tags(tags)
+        self._m_preemptions = metrics.Counter(
+            "llm_preemptions_total",
+            "Recompute-preemptions of running requests (KV pool ran dry)",
+            tag_keys=("model", "pool", "tenant")).set_default_tags(tags)
         self._m_e2e = metrics.Histogram(
             "llm_request_e2e_seconds", "Arrival-to-finish request latency",
             boundaries=metrics.LATENCY_BUCKETS,
@@ -209,7 +221,8 @@ class LLMServer:
         self._pool = pool or "mono"
         self._dep_name = deployment_name
         tags = {"model": self.model_name, "pool": self._pool}
-        for m in (self._m_ttft, self._m_tpot, self._m_e2e, self._m_queue,
+        for m in (self._m_ttft, self._m_tpot, self._m_queue_wait,
+                  self._m_preemptions, self._m_e2e, self._m_queue,
                   self._m_occupancy, self._m_kv_util, self._m_cache_hit,
                   self._m_prompt, self._m_generated, self._m_spec_drafted,
                   self._m_spec_accepted, self._m_spec_ratio,
@@ -376,33 +389,44 @@ class LLMServer:
                 skip_decode=(self._pool == "prefill"))
 
     async def _pump(self) -> None:
-        import time
-
         loop = asyncio.get_event_loop()
+        requests = self.engine.requests
         while self.engine.has_unfinished():
             outs = await loop.run_in_executor(None, self._step_engine)
-            for out in outs:
-                q = self._queues.get(out.request_id)
-                if q is not None:
-                    q.put_nowait(out)
-                if out.finished:
-                    # the reader holds its queue reference; drop ours and
-                    # the engine's state so a long-lived replica doesn't
-                    # accumulate every past request
-                    self._queues.pop(out.request_id, None)
-                    state = self.engine.requests.pop(out.request_id, None)
-                    if state is not None:
-                        self._observe_finished(state,
-                                               time.perf_counter())
-            stats = self.engine.stats()
-            self._drain_spec_stats()
-            self._m_queue.set(stats["waiting"])
-            self._m_occupancy.set(
-                stats["running"] / max(1, self.engine.ecfg.max_num_seqs))
-            self._m_kv_util.set(
-                1.0 - stats["free_pages"] / max(1, stats["total_pages"]))
+            # everything between one round's return and the next
+            # submission: the device waits for it
+            with tracing.span("rt.pump.fanout"):
+                for out in outs:
+                    q = self._queues.get(out.request_id)
+                    if q is not None:
+                        q.put_nowait(out)
+                    if out.text_offset == 0:
+                        # the request's first token leaves the engine's
+                        # hands here, a decode round after it was sampled
+                        first = requests.get(out.request_id)
+                        if first is not None:
+                            first.emit_t = time.perf_counter()
+                    if out.finished:
+                        # the reader holds its queue reference; drop ours
+                        # and the engine's state so a long-lived replica
+                        # doesn't accumulate every past request
+                        self._queues.pop(out.request_id, None)
+                        state = requests.pop(out.request_id, None)
+                        if state is not None:
+                            self._observe_finished(state,
+                                                   time.perf_counter())
+                stats = self.engine.stats()
+                self._drain_spec_stats()
+                self._m_queue.set(stats["waiting"])
+                self._m_occupancy.set(
+                    stats["running"]
+                    / max(1, self.engine.ecfg.max_num_seqs))
+                self._m_kv_util.set(
+                    1.0 - stats["free_pages"]
+                    / max(1, stats["total_pages"]))
             if not outs:
-                await asyncio.sleep(0.002)
+                with tracing.span("rt.pump.idle"):
+                    await asyncio.sleep(0.002)
 
     def _observe_finished(self, state, now: float) -> None:
         """Fold one finished request into the latency histograms.
@@ -416,12 +440,28 @@ class LLMServer:
             tags["tenant"] = tenant
         tags = tags or None
         n_out = len(state.output)
+        if state.admit_t:
+            self._m_queue_wait.observe(state.admit_t - state.arrival_t,
+                                       tags)
+        if state.preemptions:
+            self._m_preemptions.inc(state.preemptions, tags)
         if state.first_token_t:
             self._m_ttft.observe(state.first_token_t - state.arrival_t,
                                  tags)
             if n_out > 1:
                 self._m_tpot.observe(
                     (now - state.first_token_t) / (n_out - 1), tags)
+            if tracing.tracing_enabled():
+                # the request's life in the engine on the `llm` lane,
+                # under the id the proxy minted (the `serve` lane's)
+                wall = time.time() - time.perf_counter()
+                for name, t0, t1 in (
+                        ("queue", state.arrival_t, state.admit_t),
+                        ("prefill", state.admit_t, state.first_token_t),
+                        ("decode", state.first_token_t, now)):
+                    tracing.record_lane_event(
+                        "llm", name, wall + t0, wall + t1,
+                        request_id=state.request_id)
         self._m_e2e.observe(now - state.arrival_t, tags)
         if state.cached_tokens:
             self._m_cache_hit.inc(state.cached_tokens, tags)

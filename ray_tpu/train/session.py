@@ -11,9 +11,10 @@ Goodput plane: the session owns this rank's :class:`StepTimeline` — a
 "step" is the interval between consecutive ``report()`` calls, so
 ``report()`` closes the step, attributes the unaccounted remainder
 (``init`` before the first report, ``idle`` after), observes the
-``train_step_seconds{phase=...}`` histograms, emits Perfetto train
-lanes, and queues a :class:`TrainStepTelemetry` record for the
-controller to forward to the GCS goodput ledger."""
+``train_step_seconds{phase=...}`` histograms (the phases themselves are
+``rt.train.*`` spans, born in the timeline), and queues a
+:class:`TrainStepTelemetry` record for the controller to forward to the
+GCS goodput ledger."""
 
 from __future__ import annotations
 
@@ -23,6 +24,7 @@ from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
+from ..util import tracing
 from ._checkpoint import Checkpoint
 from .telemetry import StepTimeline, TrainStepTelemetry
 
@@ -77,6 +79,7 @@ class _Session:
 
         self.telemetry_on = bool(global_config().train_telemetry_enabled)
         self.timeline = StepTimeline()
+        self.timeline.step = self._step + 1
         self._node_id = os.environ.get("RAY_TPU_NODE_ID", "")
         self._first_closed = False
         # per-step stats accumulated by the instrumented step factory
@@ -108,8 +111,10 @@ class _Session:
                checkpoint: Optional[Checkpoint]) -> None:
         with self.lock:
             self._step += 1
-            telemetry = (self._close_step(self._step)
-                         if self.telemetry_on else None)
+            telemetry = None
+            if self.telemetry_on:
+                with tracing.span("rt.train.report", step=self._step):
+                    telemetry = self._close_step(self._step)
             self.reports.append(
                 _Report(dict(metrics), checkpoint, self._step, telemetry))
 
@@ -118,7 +123,7 @@ class _Session:
         # init, sharding, jax.distributed — its remainder is init badput
         remainder_as = "idle" if self._first_closed else "init"
         self._first_closed = True
-        start, end, phases, intervals = self.timeline.close(remainder_as)
+        start, end, phases, _intervals = self.timeline.close(remainder_as)
         rec = TrainStepTelemetry(
             rank=self.context.rank, step=step, node_id=self._node_id,
             start_t=start, end_t=end, phases=phases,
@@ -129,24 +134,18 @@ class _Session:
         self._compile_kind, self._recompile = "", False
         self._batch_shape = ""
         try:
-            self._observe(rec, intervals)
+            self._observe(rec)
         except Exception:  # graftlint: ignore[swallow] — telemetry
             pass  # must never fail a training step
         return rec
 
-    def _observe(self, rec: TrainStepTelemetry, intervals) -> None:
+    def _observe(self, rec: TrainStepTelemetry) -> None:
         step_hist = _step_histogram()
         job = self.context.experiment_name
         for name, secs in rec.phases.items():
             step_hist.observe(secs, tags={"job": job, "phase": name})
         step_hist.observe(max(0.0, rec.end_t - rec.start_t),
                           tags={"job": job, "phase": "total"})
-        from ..util.tracing import record_lane_event, tracing_enabled
-
-        if tracing_enabled():
-            for name, t0, t1 in intervals:
-                record_lane_event("train", f"s{rec.step}:{name}", t0, t1,
-                                  step=rec.step, rank=rec.rank, phase=name)
 
     def drain(self) -> List[_Report]:
         """Hand pending reports to the poller and forget them — a long run

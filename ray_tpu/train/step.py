@@ -22,6 +22,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..parallel.sharding import DEFAULT_RULES, logical_sharding, shard_pytree
+from ..util import tracing
 from .telemetry import StepInstrumenter, estimate_flops_per_token  # noqa: F401
 from . import session as _sess
 
@@ -170,8 +171,13 @@ def make_train_step(
         if session is None or not session.telemetry_on:
             return step_fn(state, batch)
         sig = _batch_signature(batch)
-        out = instrumenter.run(lambda: step_fn(state, batch), sig,
-                               block=jax.block_until_ready)
+        step = session.timeline.step
+        # the timeline learns the phase after the fact (compile or
+        # compute), so the span is opened here, around the work
+        with tracing.step_span("rt.train.step", step), \
+                tracing.span("rt.train.compute", step=step):
+            out = instrumenter.run(lambda: step_fn(state, batch), sig,
+                                   block=jax.block_until_ready)
         last = instrumenter.last
         session.timeline.record_interval(last["phase"], last["t0"],
                                          last["t1"])
@@ -194,8 +200,10 @@ def make_train_step(
         if session is None or not session.telemetry_on:
             return jax.device_put(batch, _batch_sharding(mesh, rules))
         t0 = time.time()
-        placed = jax.block_until_ready(
-            jax.device_put(batch, _batch_sharding(mesh, rules)))
+        with tracing.span("rt.train.host_to_device",
+                          step=session.timeline.step):
+            placed = jax.block_until_ready(
+                jax.device_put(batch, _batch_sharding(mesh, rules)))
         session.timeline.record_interval("host_to_device", t0, time.time())
         return placed
 

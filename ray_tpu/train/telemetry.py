@@ -26,7 +26,9 @@ pure core of that plane:
   tok/s/chip from the step factory's model-flops estimate.
 
 Everything here is stdlib-only and clock-injectable: the GCS imports it
-without pulling jax, and tests drive it with synthetic clocks.
+without pulling jax, and tests drive it with synthetic clocks. Phases are
+also ``util.tracing`` spans (``rt.train.<phase>``): in a process that has
+jax they land in a running profiler trace, beside the device's work.
 """
 
 from __future__ import annotations
@@ -36,6 +38,8 @@ import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
+
+from ..util import tracing
 
 # canonical per-step phases (the train_step_seconds{phase=...} label set;
 # "total" is reserved for the whole-step wall histogram)
@@ -140,16 +144,21 @@ class StepTimeline:
     Single-threaded by design (lives on the train_fn thread). Phases may
     nest — time accrues to the innermost open phase, so the partition
     never double-counts. ``close()`` attributes the unaccounted
-    remainder and resets for the next step."""
+    remainder and resets for the next step. Every phase entered here is
+    a ``tracing.span("rt.train.<name>", step=...)`` from enter to exit
+    (an outer phase's span stays open under an inner one); intervals
+    told after the fact (``record_interval``) are spanned where the work
+    runs, in ``train/step.py``."""
 
-    MAX_INTERVALS = 256            # per-step Perfetto lane bound
+    MAX_INTERVALS = 256            # per-step interval bound
 
     def __init__(self, clock: Callable[[], float] = time.time):
         self._clock = clock
         self._start = clock()
         self._acc: Dict[str, float] = {}
-        self._stack: List[List] = []        # [name, resume_t]
+        self._stack: List[List] = []        # [name, resume_t, span]
         self.intervals: List[Tuple[str, float, float]] = []
+        self.step = 0              # the step in progress; close() counts
 
     @contextmanager
     def phase(self, name: str):
@@ -165,13 +174,16 @@ class StepTimeline:
             top = self._stack[-1]
             self._accrue(top[0], top[1], now)
             top[1] = now
-        self._stack.append([name, now])
+        span = tracing.span("rt.train." + name, step=self.step)
+        span.__enter__()
+        self._stack.append([name, now, span])
 
     def exit(self) -> None:
         if not self._stack:
             return
         now = self._clock()
-        name, resume = self._stack.pop()
+        name, resume, span = self._stack.pop()
+        span.__exit__(None, None, None)
         self._accrue(name, resume, now)
         if self._stack:                     # resume the outer phase
             self._stack[-1][1] = now
@@ -210,6 +222,7 @@ class StepTimeline:
         self._start = now
         self._acc = {}
         self.intervals = []
+        self.step += 1
         return start, end, phases, intervals
 
 

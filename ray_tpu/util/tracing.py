@@ -3,8 +3,24 @@ hooks + `ray timeline` chrome-trace export; device-plane profiling maps
 to jax.profiler, whose traces open in Perfetto/XProf).
 
     ray_tpu.util.tracing.timeline("/tmp/timeline.json")  # chrome trace
-    with ray_tpu.util.tracing.profile("/tmp/jax_trace"):  # device trace
-        train_step(...)
+    with ray_tpu.util.tracing.span("rt.engine.schedule"):  # one phase
+        ...
+
+``span`` is the one primitive every phase of the serving round and the
+train step goes through. It writes the interval in two places: into a
+running ``jax.profiler`` trace (a ``TraceAnnotation`` on the profiler's
+clock, beside the device's operations), and, with ``RAY_TPU_TRACING=1``,
+into the JSONL sink that ``timeline()`` renders. Names are fixed
+(``rt.<lane>.<phase>``); the benchmark's readers match on them:
+
+    rt.engine.{schedule,prefill.dispatch,prefill.sync,
+               decode.dispatch,decode.sync,append}     llm/engine.py
+    rt.pump.{fanout,idle}                              llm/serve.py
+    rt.train.step, rt.train.<phase>, rt.train.report   train/
+
+This module never imports jax: the benchmark's driver imports ray_tpu
+and may not touch the chip. A process that has not imported jax gets
+only the JSONL half.
 """
 
 from __future__ import annotations
@@ -12,6 +28,7 @@ from __future__ import annotations
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -33,13 +50,6 @@ _ctx: contextvars.ContextVar = contextvars.ContextVar(
     "ray_tpu_trace_ctx", default=None)   # (trace_id, span_id) | None
 _sink_lock = threading.Lock()
 _sink = None  # opened spans-<pid>.jsonl file
-
-
-def setup_tracing() -> None:
-    """Enable span tracing for this driver and every worker spawned
-    after this call (propagates via the environment, the reference's
-    --tracing-startup-hook analog). Call before ray_tpu.init()."""
-    os.environ[_TRACE_ENV] = "1"
 
 
 def tracing_enabled() -> bool:
@@ -290,25 +300,60 @@ def timeline(filename: Optional[str] = None) -> List[Dict[str, Any]]:
     return events
 
 
-@contextmanager
-def profile(log_dir: str):
-    """Device-plane profiler pass-through: traces XLA execution on the
-    chip (open in XProf/Perfetto). Host-side events still come from
-    timeline()."""
-    import jax
+class span:
+    """One named host interval, ``with span("rt.engine.schedule"):``.
 
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
+    Written as a ``jax.profiler.TraceAnnotation`` (on the profiler's
+    clock whenever a trace is running; a flag check when none is) and,
+    only when ``tracing_enabled()``, as a lane record in the JSONL sink
+    with ``args`` (``request_id``, ``step``) so ``timeline()`` shows it.
+    The lane is the name's second part (``rt.<lane>.<phase>``).
+    ``seconds`` holds the interval's length after exit: callers that
+    keep a counter of the same phase read it and take no clock of
+    their own. Names are constants; nothing is formatted per call."""
+
+    __slots__ = ("name", "args", "seconds", "_t0", "_wall0", "_annotation")
+
+    def __init__(self, name: str, **args):
+        self.name = name
+        self.args = args
+        self.seconds = 0.0
+
+    def _annotate(self, profiler):
+        return profiler.TraceAnnotation(self.name, **self.args)
+
+    def __enter__(self) -> "span":
+        # jax is used where the process already has it, never imported
+        profiler = sys.modules.get("jax.profiler")
+        self._annotation = None
+        if hasattr(profiler, "TraceAnnotation"):
+            self._annotation = self._annotate(profiler)
+            self._annotation.__enter__()
+        self._wall0 = time.time() if tracing_enabled() else 0.0
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.seconds = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        if self._wall0:
+            parts = self.name.split(".")
+            record_lane_event(parts[1] if len(parts) > 2 else self.name,
+                              self.name, self._wall0,
+                              self._wall0 + self.seconds, **self.args)
+        return False
 
 
-@contextmanager
-def span(name: str):
-    """Annotate a host-side region so it shows up in device traces
-    (jax.profiler.TraceAnnotation passthrough)."""
-    import jax
+class step_span(span):
+    """``span`` for one training step: a ``StepTraceAnnotation``, so the
+    profiler's step view groups the device's work by ``step``."""
 
-    with jax.profiler.TraceAnnotation(name):
-        yield
+    __slots__ = ()
+
+    def __init__(self, name: str, step: int):
+        super().__init__(name, step=step)
+
+    def _annotate(self, profiler):
+        return profiler.StepTraceAnnotation(self.name,
+                                            step_num=self.args["step"])
