@@ -42,12 +42,12 @@ def serve(config: dict, topo) -> None:
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from benchmarks.harness.llm_server import llama_config_of
+    from benchmarks.harness.families import family_of
     from ray_tpu.llm.runner import decode_burst, prefill_sample
     from ray_tpu.ops import rope_frequencies
-    from ray_tpu.ops.quant import init_params_quantized
 
-    cfg = llama_config_of(config)
+    family = family_of(config)
+    cfg = family.program_config(config)
     e = config["engine"]
     one = SingleDeviceSharding(topo.devices[0])
 
@@ -58,7 +58,7 @@ def serve(config: dict, topo) -> None:
         return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
 
     params = on_chip(jax.eval_shape(
-        lambda: init_params_quantized(jax.random.PRNGKey(0), cfg)))
+        lambda: family.served_params(jax.random.PRNGKey(0), config)))
     cos, sin = on_chip(jax.eval_shape(lambda: rope_frequencies(
         cfg.head_dim, cfg.max_seq, cfg.rope_theta)))
     cache = sds((cfg.n_layers, e["num_pages"], e["page_size"],
@@ -86,26 +86,27 @@ def train(config: dict, topo, batch: int, seq: int) -> None:
     import optax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from benchmarks.harness.llm_server import llama_config_of
-    from ray_tpu.models import init_params, lm_loss, param_logical_axes
+    from benchmarks.harness.families import family_of
     from ray_tpu.parallel import MeshSpec, build_mesh
     from ray_tpu.parallel.sharding import DEFAULT_RULES, shard_pytree
     from ray_tpu.train import make_train_step
     from ray_tpu.train.step import (TrainState, _batch_sharding,
                                     opt_state_shardings)
 
-    cfg = llama_config_of(config)
+    family = family_of(config)
+    cfg = family.program_config(config)
+    make_params, loss_of, logical_axes = family.training()
     mesh = build_mesh(MeshSpec(**config["mesh"]), topo.devices)
     optimizer = optax.adamw(3e-4, weight_decay=0.1)
-    axes = param_logical_axes(cfg)
+    axes = logical_axes(cfg)
     _init, step_fn, _place = make_train_step(
-        lambda p, b: lm_loss(p, b, cfg, mesh=mesh), optimizer, mesh, axes)
+        lambda p, b: loss_of(p, b, cfg, mesh=mesh), optimizer, mesh, axes)
 
     def placed(tree, shardings):
         return jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=s), tree, shardings)
 
-    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    params = jax.eval_shape(lambda: make_params(jax.random.PRNGKey(0), cfg))
     param_sh = shard_pytree(params, axes, mesh, DEFAULT_RULES)
     state = TrainState(
         step=jax.ShapeDtypeStruct((), jnp.int32,

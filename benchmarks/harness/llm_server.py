@@ -1,14 +1,13 @@
-"""The replica the serve cells deploy: ``LLMServer`` with the
-configuration registered under a name, and the benchmark's eyes.
+"""The replica the serve cells deploy: the class of the program that the
+configuration's family names, under the benchmark's eyes.
 
-``LLMServer`` takes a model only as a key of ``LLAMA_CONFIGS``; the
-replica process registers the configuration file's widths there before it
-calls ``super().__init__``, so the program is not edited. Everything a
-request meets is ``LLMServer``'s own code. What this class adds only
-watches: the engine-side clocks of finished requests, the width and
-occupancy of every decode round, the compile counters, the profiler
-(only the chip's holder can trace it) and the reference check (only the
-chip's holder has the weights).
+The family (``benchmarks/families/<name>.py``) says which class that is
+(``LLMServer`` today) and with which arguments it is made; everything a
+request meets is that class's own code. What ``Watchers`` adds only
+watches, written once for every family: the engine-side clocks of
+finished requests, the width and occupancy of every decode round, the
+compile counters, the profiler (only the chip's holder can trace it) and
+the reference check (only the chip's holder has the weights).
 """
 
 from __future__ import annotations
@@ -19,44 +18,28 @@ import os
 import time
 from typing import Any, Dict, Optional
 
-from ray_tpu.llm.serve import LLMServer
+from . import families
 
 
-def llama_config_of(config: dict):
-    """The program's ``LlamaConfig`` for a published ``config.json``."""
-    import jax.numpy as jnp
-
-    from ray_tpu.models.llama import LlamaConfig
-
-    if config["hidden_size"] != (config["num_attention_heads"]
-                                 * config["head_dim"]):
-        raise ValueError("LlamaConfig derives head_dim as hidden_size / "
-                         "num_attention_heads; this configuration's "
-                         "head_dim differs")
-    return LlamaConfig(
-        vocab=config["vocab_size"], dim=config["hidden_size"],
-        n_layers=config["num_hidden_layers"],
-        n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"],
-        mlp_dim=config["intermediate_size"],
-        max_seq=config["max_position_embeddings"],
-        rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]),
-        dtype=jnp.bfloat16 if not config.get("rehearsal") else jnp.float32,
-        remat=not config.get("rehearsal"))
+def replica_class(config: dict) -> type:
+    """The class a serve cell deploys for this configuration: the
+    watchers over the class its family names. It is made here and so is
+    no attribute of a module: the replica's process gets it by value."""
+    return type("BenchReplica",
+                (Watchers, families.family_of(config).server_class()), {})
 
 
-class BenchLLMServer(LLMServer):
+class Watchers:
+    """Mixed in before the family's class: makes it with the family's
+    arguments, then watches it."""
+
     def __init__(self, config_path: str, *, seed: int = 0):
-        from ray_tpu.models.llama import LLAMA_CONFIGS
-
         with open(config_path) as f:
             self.bench_config = json.load(f)
-        name = self.bench_config["name"]
-        LLAMA_CONFIGS[name] = llama_config_of(self.bench_config)
-        super().__init__(name, init="random", seed=seed,
-                         quantize=self.bench_config.get("quantize"),
-                         engine_config=dict(self.bench_config["engine"]))
+        self.bench_family = families.family_of(self.bench_config)
+        args, kwargs = self.bench_family.server_arguments(
+            self.bench_config, seed)
+        super().__init__(*args, **kwargs)
         self._finished: list = []     # engine-side clocks, one row a request
         self._rounds: list = []       # (t, width, active slots, live tokens)
         self._occupancy: list = []    # (t, running slots, compiles), 10 Hz
@@ -162,20 +145,19 @@ class BenchLLMServer(LLMServer):
 
     async def bench_reference(self, payload: dict) -> Dict[str, Any]:
         """Margins of the tokens the engine chose for the probe prompts,
-        against the plain reference on this replica's own weights."""
+        against the family's plain reference on this replica's own
+        weights."""
         import jax.numpy as jnp
         import numpy as np
-
-        from . import reference
 
         prompts = jnp.asarray(payload["prompts"], jnp.int32)
         answers = jnp.asarray(payload["answers"], jnp.int32)
 
         def run():
             with self._engine_lock:
-                return np.asarray(reference.chosen_token_margins(
-                    self.engine.params, prompts, answers,
-                    self.bench_config)).tolist()
+                return np.asarray(families.chosen_token_margins(
+                    self.bench_family.forward_logits, self.engine.params,
+                    prompts, answers, self.bench_config)).tolist()
 
         t0 = time.perf_counter()
         margins = await asyncio.get_event_loop().run_in_executor(None, run)

@@ -23,14 +23,12 @@ def train_fn(config: dict) -> None:
 
     from ray_tpu import get_tpu_chip_ids, train
     from ray_tpu._private import device_plane
-    from ray_tpu.models import init_params, lm_loss, param_logical_axes
     from ray_tpu.parallel import MeshSpec, build_mesh
     from ray_tpu.parallel.sharding import DEFAULT_RULES, shard_pytree
     from ray_tpu.train import make_train_step
     from ray_tpu.train.step import make_eval_step
 
-    from . import reference, trace
-    from .llm_server import llama_config_of
+    from . import families, trace
 
     model, mix, seed = config["model"], config["mix"], config["seed"]
     phases = {"train_fn": time.time()}   # unix times, for set-up's notes
@@ -41,31 +39,33 @@ def train_fn(config: dict) -> None:
         "count": len(devices), "pid": os.getpid(),
         "chip_ids": get_tpu_chip_ids(),
         "compile_cache_dir": jax.config.jax_compilation_cache_dir}}
-    cfg = llama_config_of(model)
+    family = families.family_of(model)
+    cfg, vocab = family.program_config(model), int(model["vocab_size"])
+    make_params, loss_of, logical_axes = family.training()
     mesh = build_mesh(MeshSpec(**model["mesh"]), devices)
-    axes = param_logical_axes(cfg)
+    axes = logical_axes(cfg)
     init_fn, step_fn, place_batch = make_train_step(
-        lambda p, b: lm_loss(p, b, cfg, mesh=mesh),
+        lambda p, b: loss_of(p, b, cfg, mesh=mesh),
         optax.adamw(3e-4, weight_decay=0.1), mesh, axes)
 
     # weights straight onto the mesh, in the type they are trained in
     shardings = shard_pytree(
-        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)),
+        jax.eval_shape(lambda: make_params(jax.random.PRNGKey(0), cfg)),
         axes, mesh, DEFAULT_RULES)
-    params = jax.jit(lambda key: init_params(key, cfg),
+    params = jax.jit(lambda key: make_params(key, cfg),
                      out_shardings=shardings)(jax.random.PRNGKey(seed))
     jax.block_until_ready(params)
     phases["weights"] = time.time()
 
     # the reference's verdict on the loss at the initial parameters
     probe = np.random.default_rng(seed + 1).integers(
-        0, cfg.vocab, (mix["probe"]["batch"], mix["probe"]["seq"]),
+        0, vocab, (mix["probe"]["batch"], mix["probe"]["seq"]),
         dtype=np.int32)
     t_ref = time.perf_counter()
     loss_sys = float(make_eval_step(
-        lambda p, b: lm_loss(p, b, cfg, mesh=mesh))(
+        lambda p, b: loss_of(p, b, cfg, mesh=mesh))(
             params, place_batch({"tokens": probe})))
-    loss_ref = float(reference.next_token_loss(
+    loss_ref = float(family.next_token_loss(
         params, jnp.asarray(probe), model, z_loss=1e-4))
     report["probe"] = {"loss": loss_sys, "reference": loss_ref,
                        "seconds": time.perf_counter() - t_ref}
@@ -76,7 +76,7 @@ def train_fn(config: dict) -> None:
 
     def one_step(i: int):
         tokens = np.random.default_rng((seed + 2, i)).integers(
-            0, cfg.vocab, (mix["batch"], mix["seq"]), dtype=np.int32)
+            0, vocab, (mix["batch"], mix["seq"]), dtype=np.int32)
         nonlocal state
         state, metrics = step_fn(state, place_batch({"tokens": tokens}))
         loss = float(metrics["loss"])       # ends the step: a value read

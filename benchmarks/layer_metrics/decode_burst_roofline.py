@@ -1,11 +1,12 @@
 """Kernels: how close a decode step comes to the chip's memory
 bandwidth. The bytes a step must read (the weights once, int8, and the
-keys and values of every live position: ``counts.decode_step_bytes``)
-over the published HBM bandwidth is the least time a step can take; its
-share of the measured ``decode_step_ms``. Decode at these batch sizes is
-bandwidth-bound: 16 rows per weight read."""
+keys and values of every live position: the ``decode_step_bytes`` of the
+configuration's family) over the published HBM bandwidth is the least
+time a step can take; its share of the measured ``decode_step_ms``.
+Decode at these batch sizes is bandwidth-bound: 16 rows per weight
+read."""
 
-from benchmarks.harness import counts, peaks, readers
+from benchmarks.harness import families, peaks, readers
 
 NAME, UNIT, SOURCE = "decode_burst_roofline", "%", "device_trace"
 LAYER, MOVES, KINDS = "Kernels", "tpot_p95_ms", ("serve",)
@@ -18,8 +19,8 @@ def compute(run):
     if not decode:
         return None
     weight_bytes = 1 if run["config"].get("quantize") == "int8" else 2
-    needed = counts.decode_step_bytes(run["config"], decode["live_tokens"],
-                                      weight_bytes)
+    needed = families.family_of(run["config"]).decode_step_bytes(
+        run["config"], decode["live_tokens"], weight_bytes)
     least_s = needed / peaks.peaks_of(
         run["device"]["kind"])["hbm_bytes_per_s"]
     return 100.0 * least_s / decode["step_s"]

@@ -113,8 +113,12 @@ def main(argv=None) -> int:
         import ray_tpu
     except ImportError as e:
         raise SystemExit(f"the system under test is not here: {e}") from None
-    from benchmarks.harness import runtime, serve_cell, train_cell
+    from benchmarks.harness import families, runtime, serve_cell, train_cell
 
+    try:
+        families.family_of(config)   # before anything is started
+    except ValueError as e:
+        raise SystemExit(str(e)) from None
     scratch = tempfile.mkdtemp(prefix="ray_tpu_bench_")
     kind = {"serve": serve_cell, "train": train_cell}[cell["kind"]]
     # a driver that stops this run sends SIGTERM: leave by the same door

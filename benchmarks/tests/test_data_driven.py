@@ -1,6 +1,7 @@
-"""A later PR adds a cell, a traffic mix, a configuration and a per-layer
-metric as new files, and edits none that is there: shown on a temporary
-copy of the benchmark's directory, run end to end at toy size on the CPU.
+"""A later PR adds a cell, a traffic mix, a configuration, its family and
+a per-layer metric as new files, and edits none that is there: shown on
+a temporary copy of the benchmark's directory, run end to end at toy
+size on the CPU.
 """
 
 import json
@@ -22,6 +23,16 @@ def compute(run):
     return float(len(run["engine"]["finished"]))
 '''
 
+# what a ``model_config`` PR brings for another architecture; here the
+# dense block under another name, with a key and a limit of its own
+NEW_FAMILY = '''"""The dense family again, as a later PR's file."""
+from benchmarks.families.llama_dense import *  # noqa: F401,F403
+from benchmarks.families import llama_dense
+
+CONFIG_KEYS = llama_dense.CONFIG_KEYS | {"sliding_window"}
+MARGIN_LIMIT = 0.125      # its own, so that the run shows whose it used
+'''
+
 
 def test_new_files_are_found_and_run(tmp_path):
     copy = tmp_path / "benchmarks"
@@ -30,7 +41,9 @@ def test_new_files_are_found_and_run(tmp_path):
     before = {p: p.read_bytes() for p in copy.rglob("*") if p.is_file()}
 
     config = json.loads((copy / "configs" / "tiny-rehearsal.json").read_text())
-    config.update(name="tiny-other", num_hidden_layers=3)
+    config.update(name="tiny-other", num_hidden_layers=3,
+                  family="other_family", sliding_window=None)
+    (copy / "families" / "other_family.py").write_text(NEW_FAMILY)
     (copy / "configs" / "tiny-other.json").write_text(json.dumps(config))
     mix = json.loads((copy / "traffic" / "tiny-chat.json").read_text())
     mix.update(name="tiny-bursty", arrivals="gamma", arrival_cv=3.0,
@@ -57,8 +70,29 @@ def test_new_files_are_found_and_run(tmp_path):
     assert result["device"]["platform"] == "cpu"     # and it says so
     assert result["metrics"]["finished_requests"]["value"] >= 18
     assert "window_compiles" in result["metrics"]
+    assert result["notes"]["probes"]["margin_limit"] == 0.125
     # nothing that was there was edited
     assert all(p.read_bytes() == data for p, data in before.items())
+
+
+def test_a_family_that_is_not_there_is_named(tmp_path):
+    copy = tmp_path / "benchmarks"
+    shutil.copytree(BENCH, copy, ignore=shutil.ignore_patterns(
+        "__pycache__", "tests"))
+    config = json.loads((copy / "configs" / "tiny-rehearsal.json").read_text())
+    config.update(name="tiny-lost", family="not_written_yet")
+    (copy / "configs" / "tiny-lost.json").write_text(json.dumps(config))
+    cell = json.loads((copy / "workloads" / "tiny-chat.json").read_text())
+    cell.update(name="tiny-lost-chat", config="tiny-lost")
+    (copy / "workloads" / "tiny-lost-chat.json").write_text(json.dumps(cell))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=ROOT + os.pathsep + os.environ.get("PYTHONPATH", ""))
+    out = subprocess.run(
+        [sys.executable, str(copy / "run.py"), "--workload",
+         "tiny-lost-chat", "--seconds", "1"],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=120)
+    assert out.returncode != 0 and not out.stdout.strip()
+    assert "benchmarks/families/not_written_yet.py" in out.stderr
 
 
 def test_no_result_without_the_system(tmp_path):
