@@ -227,6 +227,30 @@ class LLMEngine:
             "width_hist": [0] * (self.ecfg.decode_burst + 1),
             "active_slot_steps": 0, "prefills": 0, "prefill_tokens": 0,
             "preemptions": 0, "host_s": dict.fromkeys(PHASES, 0.0)}
+        if cfg.n_experts:
+            # counted on the device by the routed layer, summed over
+            # layers and programs: the (token, expert) rows the expert
+            # products were given, and the experts with at least one row
+            self._counters.update(expert_rows=0, experts_touched=0)
+        # expert counts of chunked-prefill dispatches nobody waited for
+        # yet: read back with the next sampled tokens
+        self._pending_counts: List[Any] = []
+
+    def _read_back(self, toks, counts=None):
+        """The sampled tokens on the host (the round's one sync). An
+        expert config's counts ride the same ``device_get``: outputs of
+        the program the tokens come from, so nothing more is waited
+        for."""
+        if counts is None and not self._pending_counts:
+            return np.asarray(toks)
+        pending, self._pending_counts = self._pending_counts, []
+        if counts is not None:
+            pending.append(counts)
+        sampled, pending = jax.device_get((toks, pending))
+        for rows, touched in pending:
+            self._counters["expert_rows"] += int(rows)
+            self._counters["experts_touched"] += int(touched)
+        return sampled
 
     @contextmanager
     def _phase(self, phase: str):
@@ -521,7 +545,7 @@ class LLMEngine:
             if self.lora_pool is not None:
                 lora = self.lora_pool.select(
                     [self.lora_pool.slot_of(state.model_id)])
-            toks, ck, cv = prefill_sample(
+            toks, ck, cv, counts = prefill_sample(
                 self.params, self.cache.k, self.cache.v,
                 jnp.asarray(tokens), jnp.asarray([L], jnp.int32),
                 jnp.asarray(self.seq_table.block_tables[
@@ -533,7 +557,7 @@ class LLMEngine:
         self._counters["prefills"] += 1
         self._counters["prefill_tokens"] += L
         with self._phase("prefill.sync"):
-            tok = int(np.asarray(toks)[0])
+            tok = int(self._read_back(toks, counts)[0])
         if not state.output:
             state.first_token_t = time.perf_counter()
         with self._phase("append"):
@@ -579,11 +603,13 @@ class LLMEngine:
                 -(-(start + n) // self.ecfg.page_size))
             bt = jnp.asarray(self.seq_table.block_tables[
                 state.slot:state.slot + 1, :span])
-            logits, ck, cv = prefill_chunk(
+            logits, ck, cv, counts = prefill_chunk(
                 self.params, self.cache.k, self.cache.v,
                 jnp.asarray(tokens), jnp.int32(start), jnp.int32(n), bt,
                 self.cos, self.sin, cfg=self.cfg)
         self.cache = KVCache(ck, cv)
+        if counts is not None:
+            self._pending_counts.append(counts)
         state.prefill_pos = start + n
         self._counters["prefills"] += 1
         self._counters["prefill_tokens"] += n
@@ -601,7 +627,7 @@ class LLMEngine:
                 [state])
             toks = sample_logits(logits, seed, temp, top_k, top_p)
         with self._phase("prefill.sync"):
-            tok = int(np.asarray(toks)[0])
+            tok = int(self._read_back(toks)[0])
         state.ctx_len = L
         if not state.output:
             state.first_token_t = time.perf_counter()
@@ -713,7 +739,7 @@ class LLMEngine:
             use_paged = self._paged_kernel or (
                 self._paged_min_pages > 0
                 and span >= self._paged_min_pages)
-            toks, ck, cv = decode_burst(
+            toks, ck, cv, counts = decode_burst(
                 self.params, self.cache.k, self.cache.v,
                 jnp.asarray(tokens), jnp.asarray(positions),
                 self._bt(span),
@@ -722,7 +748,7 @@ class LLMEngine:
                 paged_kernel=use_paged, greedy=greedy)
         self.cache = KVCache(ck, cv)
         with self._phase("decode.sync"):
-            sampled = np.asarray(toks)  # [K, B]
+            sampled = self._read_back(toks, counts)  # [K, B]
         outs = []
         with self._phase("append"):
             for s in active_states:
@@ -828,13 +854,13 @@ class LLMEngine:
             seed, temp, top_k, top_p, greedy = self._sampling_arrays(
                 self.slots, advance=1)
             t0 = time.perf_counter()
-            tgt, samp0, ck, cv = verify_step(
+            tgt, samp0, ck, cv, counts = verify_step(
                 self.params, self.cache.k, self.cache.v, jnp.asarray(tok),
                 jnp.asarray(pos), bt, self.cos, self.sin, seed, temp,
                 top_k, top_p, cfg=self.cfg, greedy=greedy)
         self.cache = KVCache(ck, cv)
         with self._phase("decode.sync"):
-            tgt = np.asarray(tgt)
+            tgt = self._read_back(tgt, counts)
             samp0 = np.asarray(samp0)
         spec.verify_times.append(time.perf_counter() - t0)
         outs: List[StepOutput] = []
@@ -904,12 +930,12 @@ class LLMEngine:
             self.slots, advance=1)
         span = self._span_bucket(int(self.seq_table.n_pages[state.slot]))
         t0 = time.perf_counter()
-        tgt, _s0, ck, cv = verify_step(
+        tgt, _s0, ck, cv, counts = verify_step(
             self.params, self.cache.k, self.cache.v, jnp.asarray(tok),
             jnp.asarray(pos), self._bt(span), self.cos, self.sin,
             seed, temp, top_k, top_p, cfg=self.cfg, greedy=True)
         self.cache = KVCache(ck, cv)
-        row = np.asarray(tgt)[state.slot].tolist()
+        row = self._read_back(tgt, counts)[state.slot].tolist()
         if self.spec is not None:
             self.spec.verify_times.append(time.perf_counter() - t0)
         emitted = accept_prefix(draft, row)

@@ -25,27 +25,54 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..models.llama import LlamaConfig
+from ..models.llama import LlamaConfig, qk_norm
 from ..ops import apply_rotary, attention, rms_norm, rope_frequencies
 from ..ops.quant import embed_lookup, is_quantized, weight_einsum
 from .cache import KVCache
 
 
-def _mlp(h, lp, cfg: LlamaConfig):
-    """Serving MLP: dense SwiGLU, or EXACT top-k MoE for expert configs.
-    Inference must route drop-free (capacity-factor dispatch drops
-    tokens batch-dependently, silently changing generations), so MoE
-    uses the dense all-expert mixture — E-fold MLP FLOPs, the right
-    trade at small E / decode batch sizes; see ops.moe.moe_mlp_dense
-    for the large-E upgrade path."""
-    if cfg.n_experts:
-        from ..ops.moe import moe_mlp_dense
+_EXPERT_STACKS = ("w_gate", "w_up", "w_down")
 
-        return moe_mlp_dense(h, lp["router"], lp["w_gate"], lp["w_up"],
-                             lp["w_down"], top_k=cfg.top_k)
+
+def _split_layers(layers, cfg: LlamaConfig):
+    """(what a scan over layers slices, what it must not). An expert
+    config's expert matrices stay whole stacks that the routed layer
+    addresses by the layer's index: a scan that sliced them would copy a
+    layer's experts (0.4 GB int8 at OLMoE's widths) in every iteration,
+    where a decode step needs a few of them."""
+    if not cfg.n_experts:
+        return layers, None
+    sliced = {k: v for k, v in layers.items() if k not in _EXPERT_STACKS}
+    sliced["layer"] = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+    return sliced, {k: layers[k] for k in _EXPERT_STACKS}
+
+
+def _mlp(h, lp, cfg: LlamaConfig, valid=None, experts=None):
+    """Serving MLP: dense SwiGLU, or for expert configs the one dropless
+    routed layer (``ops.moe.moe_mlp_routed``): no capacity, so a
+    sequence's answer does not change with its batch, and rows that are
+    not tokens (``valid`` [B, S] False: bucket padding, inactive slots)
+    are given to no expert; ``lp`` and ``experts`` are ``_split_layers``'
+    two halves. Returns (out, counts): the layer's
+    (expert rows, experts touched) int32 [2], None for a dense config."""
+    if cfg.n_experts:
+        from ..ops.moe import moe_mlp_routed
+
+        return moe_mlp_routed(
+            h, lp["router"], experts["w_gate"], experts["w_up"],
+            experts["w_down"], top_k=cfg.top_k,
+            norm_topk_prob=cfg.norm_topk_prob, valid=valid,
+            layer=lp["layer"])
     g = weight_einsum("bsd,dm->bsm", h, lp["w_gate"])
     u = weight_einsum("bsd,dm->bsm", h, lp["w_up"])
-    return weight_einsum("bsm,md->bsd", jax.nn.silu(g) * u, lp["w_down"])
+    return weight_einsum("bsm,md->bsd", jax.nn.silu(g) * u,
+                         lp["w_down"]), None
+
+
+def _total(counts):
+    """Per-layer (or per-step) expert counts stacked by a scan -> their
+    sum; None stays None (a dense config counts nothing)."""
+    return None if counts is None else counts.sum(0)
 
 
 def _lm_logits(x_last, params, cfg: LlamaConfig):
@@ -87,7 +114,8 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
     tokens: [B, S] right-padded; prompt_lens: [B]; block_tables: [B, Pmax].
     ``lora``: per-slot batched adapters from LoRAPool.select(ids) —
     low-rank deltas on wq/wv (llm/lora.py), empty/None = base model.
-    Returns (logits [B, vocab], cache_k, cache_v).
+    Returns (logits [B, vocab], cache_k, cache_v, expert counts: see
+    ``_mlp``; None for a dense config).
     """
     from .lora import lora_delta
 
@@ -111,6 +139,7 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
                                cfg.n_heads, cfg.head_dim)
             v = v + lora_delta(h, lr["a_v"], lr["b_v"], lora["scale"],
                                cfg.n_kv_heads, cfg.head_dim)
+        q, k = qk_norm(q, k, lp, cfg)
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
         ck = _write_pages(ck, k, block_tables, write_pos, ck.shape[1])
@@ -120,16 +149,17 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
         o = attention(q, k, v, causal=True)
         x = x + weight_einsum("bshk,hkd->bsd", o, lp["wo"])
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + _mlp(h, lp, cfg)
-        return x, (ck, cv)
+        m, n = _mlp(h, lp, cfg, write_pos >= 0, experts)
+        return x + m, (ck, cv, n)
 
-    x, (cache_k, cache_v) = jax.lax.scan(
-        layer, x, (params["layers"], cache_k, cache_v, lora_xs))
+    layers, experts = _split_layers(params["layers"], cfg)
+    x, (cache_k, cache_v, counts) = jax.lax.scan(
+        layer, x, (layers, cache_k, cache_v, lora_xs))
     x_last = jnp.take_along_axis(
         x, jnp.maximum(prompt_lens - 1, 0)[:, None, None], axis=1)[:, 0]
     x_last = rms_norm(x_last, params["final_norm"], cfg.norm_eps)
     logits = _lm_logits(x_last, params, cfg)
-    return logits, cache_k, cache_v
+    return logits, cache_k, cache_v, _total(counts)
 
 
 def prefill_bucket(seq_len: int, max_seq: int, floor: int = 16) -> int:
@@ -153,7 +183,7 @@ def prefill_chunk(params, cache_k, cache_v, tokens, start_pos, chunk_len,
     long prompt no longer stalls running streams for its whole prefill.
 
     Returns (logits [1, vocab] of the chunk's LAST VALID token,
-    cache_k, cache_v).
+    cache_k, cache_v, expert counts as ``prefill``).
     """
     B, C = tokens.shape
     page_size = cache_k.shape[2]
@@ -173,6 +203,7 @@ def prefill_chunk(params, cache_k, cache_v, tokens, start_pos, chunk_len,
         q = weight_einsum("bsd,dhk->bshk", h, lp["wq"])
         k = weight_einsum("bsd,dhk->bshk", h, lp["wk"])
         v = weight_einsum("bsd,dhk->bshk", h, lp["wv"])
+        q, k = qk_norm(q, k, lp, cfg)
         q = apply_rotary(q, cos, sin, positions=pos_grid)
         k = apply_rotary(k, cos, sin, positions=pos_grid)
         ck = _write_pages(ck, k, block_tables, write_pos, page_size)
@@ -203,17 +234,18 @@ def prefill_chunk(params, cache_k, cache_v, tokens, start_pos, chunk_len,
         o = o.reshape(B, C, cfg.n_heads, hd).astype(x.dtype)
         x = x + weight_einsum("bshk,hkd->bsd", o, lp["wo"])
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + _mlp(h, lp, cfg)
-        return x, (ck, cv)
+        m, n = _mlp(h, lp, cfg, valid, experts)
+        return x + m, (ck, cv, n)
 
-    x, (cache_k, cache_v) = jax.lax.scan(
-        layer, x, (params["layers"], cache_k, cache_v))
+    layers, experts = _split_layers(params["layers"], cfg)
+    x, (cache_k, cache_v, counts) = jax.lax.scan(
+        layer, x, (layers, cache_k, cache_v))
     idx = jnp.broadcast_to(jnp.maximum(chunk_len - 1, 0).reshape(1, 1, 1),
                            (B, 1, 1))
     x_last = jnp.take_along_axis(x, idx, axis=1)[:, 0]
     x_last = rms_norm(x_last, params["final_norm"], cfg.norm_eps)
     logits = _lm_logits(x_last, params, cfg)
-    return logits, cache_k, cache_v
+    return logits, cache_k, cache_v, _total(counts)
 
 
 @partial(jax.jit, static_argnames=("cfg", "greedy"),
@@ -236,7 +268,7 @@ def verify_step(params, cache_k, cache_v, tokens, positions, block_tables,
 
     Returns (argmax tokens [B, S] — index j predicts the token AFTER
     window position j, sampled position-0 token [B] for rows that
-    aren't greedy, cache_k, cache_v).
+    aren't greedy, cache_k, cache_v, expert counts as ``prefill``).
     """
     from .sampling import sample_from_logits
 
@@ -258,6 +290,7 @@ def verify_step(params, cache_k, cache_v, tokens, positions, block_tables,
         q = weight_einsum("bsd,dhk->bshk", h, lp["wq"])
         k = weight_einsum("bsd,dhk->bshk", h, lp["wk"])
         v = weight_einsum("bsd,dhk->bshk", h, lp["wv"])
+        q, k = qk_norm(q, k, lp, cfg)
         q = apply_rotary(q, cos, sin, positions=qpos)
         k = apply_rotary(k, cos, sin, positions=qpos)
         ck = _write_pages(ck, k, block_tables, positions, page_size)
@@ -275,11 +308,12 @@ def verify_step(params, cache_k, cache_v, tokens, positions, block_tables,
         o = o.reshape(B, S, cfg.n_heads, hd).astype(x.dtype)
         x = x + weight_einsum("bshk,hkd->bsd", o, lp["wo"])
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + _mlp(h, lp, cfg)
-        return x, (ck, cv)
+        m, n = _mlp(h, lp, cfg, positions >= 0, experts)
+        return x + m, (ck, cv, n)
 
-    x, (cache_k, cache_v) = jax.lax.scan(
-        layer, x, (params["layers"], cache_k, cache_v))
+    layers, experts = _split_layers(params["layers"], cfg)
+    x, (cache_k, cache_v, counts) = jax.lax.scan(
+        layer, x, (layers, cache_k, cache_v))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     lm = params["lm_head"]
     if not is_quantized(lm):
@@ -292,7 +326,7 @@ def verify_step(params, cache_k, cache_v, tokens, positions, block_tables,
     else:
         samp0 = sample_from_logits(logits[:, 0], seed, temperature,
                                    top_k, top_p)
-    return tgt, samp0, cache_k, cache_v
+    return tgt, samp0, cache_k, cache_v, _total(counts)
 
 
 @jax.jit
@@ -321,7 +355,7 @@ def prefill_sample(params, cache_k, cache_v, tokens, prompt_lens,
     pays on a locally attached chip is not measured yet (ROADMAP D5)."""
     from .sampling import sample_from_logits
 
-    logits, cache_k, cache_v = prefill.__wrapped__(
+    logits, cache_k, cache_v, counts = prefill.__wrapped__(
         params, cache_k, cache_v, tokens, prompt_lens, block_tables,
         cos, sin, lora, cfg=cfg)
     if greedy:
@@ -329,7 +363,7 @@ def prefill_sample(params, cache_k, cache_v, tokens, prompt_lens,
     else:
         toks = sample_from_logits(logits, seed, temperature, top_k,
                                   top_p)
-    return toks, cache_k, cache_v
+    return toks, cache_k, cache_v, counts
 
 
 @partial(jax.jit,
@@ -355,7 +389,8 @@ def decode_burst(params, cache_k, cache_v, tokens, positions,
     longest active context, so KV read traffic scales with real context,
     not max_seq_len.
 
-    Returns (tokens [n_steps, B], cache_k, cache_v). The host must have
+    Returns (tokens [n_steps, B], cache_k, cache_v, expert counts over
+    all steps and layers as ``prefill``). The host must have
     pre-provisioned pages for positions .. positions+n_steps-1.
     """
     from .sampling import sample_from_logits
@@ -389,6 +424,7 @@ def decode_burst(params, cache_k, cache_v, tokens, positions,
         k2: jnp.swapaxes(v2, 0, 1) for k2, v2 in lora.items()
         if k2 != "scale"}
     old_mask = jnp.arange(Sold)[None, :] < positions[:, None]  # [B, Sold]
+    layers, experts = _split_layers(params["layers"], cfg)
 
     def step(carry, i):
         toks, sk, sv = carry
@@ -433,6 +469,7 @@ def decode_burst(params, cache_k, cache_v, tokens, positions,
                                    lora["scale"], cfg.n_heads, hd)
                 v = v + lora_delta(h, lr["a_v"], lr["b_v"],
                                    lora["scale"], kvh, hd)
+            q, k = qk_norm(q, k, lp, cfg)
             q = apply_rotary(q, cos, sin, positions=pos_i[:, None])[:, 0]
             k = apply_rotary(k, cos, sin, positions=pos_i[:, None])[:, 0]
             nk = jax.lax.dynamic_update_index_in_dim(
@@ -447,29 +484,30 @@ def decode_burst(params, cache_k, cache_v, tokens, positions,
             o = o.reshape(B, 1, cfg.n_heads, hd).astype(x.dtype)
             x = x + weight_einsum("bshk,hkd->bsd", o, lp["wo"])
             h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-            x = x + _mlp(h, lp, cfg)
-            return x, (nk, nv)
+            m, n = _mlp(h, lp, cfg, active[:, None], experts)
+            return x + m, (nk, nv, n)
 
         if use_paged_kernel:
             # UNROLLED layers: a lax.scan over the cache would dynamic-
             # slice the whole [L, P, ...] page pool per (step, layer) —
             # measured 2.6x slower than the gather path. Static slices
             # in an unrolled loop let XLA alias into the donated pool.
-            sks, svs = [], []
+            sks, svs, ns = [], [], []
             for li in range(L):
-                lp_l = jax.tree.map(lambda a: a[li], params["layers"])
+                lp_l = jax.tree.map(lambda a: a[li], layers)
                 lr_l = {k2: v2[li] for k2, v2 in lora_xs.items()}
-                x, (nk_l, nv_l) = layer(
+                x, (nk_l, nv_l, n_l) = layer(
                     x, (lp_l, cache_k[li], cache_v[li], sk[li], sv[li],
                         lr_l))
                 sks.append(nk_l)
                 svs.append(nv_l)
+                ns.append(n_l)
             sk = jnp.stack(sks)
             sv = jnp.stack(svs)
+            counts = jnp.stack(ns) if cfg.n_experts else None
         else:
-            x, (sk, sv) = jax.lax.scan(
-                layer, x, (params["layers"], old_k, old_v, sk, sv,
-                           lora_xs))
+            x, (sk, sv, counts) = jax.lax.scan(
+                layer, x, (layers, old_k, old_v, sk, sv, lora_xs))
         h = rms_norm(x[:, 0], params["final_norm"], cfg.norm_eps)
         logits = _lm_logits(h, params, cfg)
         if greedy:   # see prefill_sample: argmax-only epilogue
@@ -478,9 +516,9 @@ def decode_burst(params, cache_k, cache_v, tokens, positions,
             newt = sample_from_logits(logits, seed + i, temperature,
                                       top_k, top_p)
         newt = jnp.where(active, newt, toks)
-        return (newt, sk, sv), newt
+        return (newt, sk, sv), (newt, _total(counts))
 
-    (_, scratch_k, scratch_v), out = jax.lax.scan(
+    (_, scratch_k, scratch_v), (out, counts) = jax.lax.scan(
         step, (tokens, scratch_k, scratch_v), jnp.arange(K))
 
     # one scatter of the whole burst into the paged cache (donated ->
@@ -496,4 +534,4 @@ def decode_burst(params, cache_k, cache_v, tokens, positions,
         scratch_k.reshape(L, B * K, kvh, hd), mode="drop")
     cache_v = cache_v.at[:, fp, fo].set(
         scratch_v.reshape(L, B * K, kvh, hd), mode="drop")
-    return out, cache_k, cache_v
+    return out, cache_k, cache_v, _total(counts)

@@ -31,7 +31,7 @@ if TYPE_CHECKING:  # this module stays importable without jax (llm/__init__)
 class LLMServer:
     """Serve deployment class hosting one engine replica."""
 
-    def __init__(self, model: str = "tiny", *, init: str = "random",
+    def __init__(self, model="tiny", *, init: str = "random",
                  params_path: Optional[str] = None,
                  engine_config: Optional[dict] = None,
                  tokenizer: Optional[str] = None, seed: int = 0,
@@ -41,7 +41,7 @@ class LLMServer:
 
         from .. import get_tpu_chip_ids
         from .._private import device_plane
-        from ..models.llama import LLAMA_CONFIGS, init_params
+        from ..models.llama import LLAMA_CONFIGS, LlamaConfig, init_params
         from .engine import EngineConfig, LLMEngine
 
         chip_ids = get_tpu_chip_ids()
@@ -52,14 +52,20 @@ class LLMServer:
                 f"started on {self._device.platform!r}: refusing to "
                 f"serve a chip lease from another backend")
         self._compile_cache_dir = device_plane.enable_compilation_cache()
-        self.model_name = model
-        if model in LLAMA_CONFIGS:
+        if isinstance(model, LlamaConfig):
+            # a configuration itself (random or pickled weights): no
+            # entry in LLAMA_CONFIGS is needed, and none is written
+            cfg, model = model, (
+                f"llama-{model.n_layers}x{model.dim}"
+                + (f"-{model.n_experts}e" if model.n_experts else ""))
+        elif model in LLAMA_CONFIGS:
             cfg = LLAMA_CONFIGS[model]
         elif os.path.isdir(model):
             cfg = None  # an HF checkpoint directory IS the model source
         else:
             raise ValueError(f"unknown model {model!r}: not a named "
                              f"config or an HF checkpoint dir")
+        self.model_name = model
         if cfg is None or init == "hf":
             # real weights: HF safetensors directory (hf_interop.py) —
             # the vLLM-engine weight-loading analog
@@ -896,11 +902,12 @@ class LLMServer:
         }
 
 
-def build_llm_deployment(model: str = "tiny", *, num_replicas: int = 1,
+def build_llm_deployment(model="tiny", *, num_replicas: int = 1,
                          name: str = "llm",
                          pools: Optional[dict] = None, **server_kwargs):
     """An Application running LLMServer replicas (ref: ray.llm
-    build_openai_app). ``pools={"prefill": n, "decode": m}`` deploys
+    build_openai_app). ``model`` is a name in ``LLAMA_CONFIGS``, an HF
+    checkpoint directory, or a ``LlamaConfig`` itself. ``pools={"prefill": n, "decode": m}`` deploys
     disaggregated prefill/decode pools instead of ``num_replicas``
     monolithic replicas (fleet KV plane).
 

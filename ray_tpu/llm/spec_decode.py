@@ -192,7 +192,7 @@ class SpecDecoder:
         bucket = prefill_bucket(L, self.ecfg.max_seq_len)
         tok = np.zeros((1, bucket), np.int32)
         tok[0, :L] = tokens
-        _logits, ck, cv = _runner_prefill(
+        _logits, ck, cv, _n = _runner_prefill(
             self.params, self.cache.k, self.cache.v, jnp.asarray(tok),
             jnp.asarray([L], jnp.int32), jnp.asarray(block_row),
             self.cos, self.sin, None, cfg=self.dcfg)
@@ -220,13 +220,13 @@ class SpecDecoder:
         zf = jnp.zeros((B,), jnp.float32)
         zi = jnp.zeros((B,), jnp.int32)
         of = jnp.ones((B,), jnp.float32)
-        tgt2, _s0, ck, cv = verify_step(
+        tgt2, _s0, ck, cv, _n = verify_step(
             self.params, self.cache.k, self.cache.v, jnp.asarray(tok2),
             jnp.asarray(pos2), bt, self.cos, self.sin, 0, zf, zi, of,
             cfg=self.dcfg, greedy=True)
         d1 = tgt2[:, 1]
         if self.k > 1:
-            toks, ck, cv = decode_burst(
+            toks, ck, cv, _n = decode_burst(
                 self.params, ck, cv, d1, jnp.asarray(pos1), bt,
                 jnp.asarray(active), self.cos, self.sin, 0, of, zi, of,
                 None, cfg=self.dcfg, n_steps=self.k - 1,

@@ -53,6 +53,12 @@ class LlamaConfig:
     top_k: int = 2
     capacity_factor: float = 2.0
     aux_loss_coef: float = 0.01
+    # the chosen experts' router probabilities are renormalised to sum
+    # to 1 (False: used as the router gave them, as OLMoE does)
+    norm_topk_prob: bool = True
+    # RMSNorm of the projected queries and keys over their whole width,
+    # before the split into heads and the rotary embedding (OLMoE)
+    qk_norm: bool = False
     # "full": recompute everything (max HBM savings, ~1/3 extra FLOPs);
     # "dots": save matmul outputs, recompute elementwise only — the right
     # trade when HBM fits it (ref: jax checkpoint_policies)
@@ -69,6 +75,8 @@ class LlamaConfig:
         mlp = 3 * d * self.mlp_dim
         if self.n_experts:
             mlp = self.n_experts * mlp + d * self.n_experts  # experts+router
+        if self.qk_norm:
+            attn += (self.n_heads + self.n_kv_heads) * self.head_dim
         return self.vocab * d * 2 + L * (attn + mlp + 2 * d) + d
 
 
@@ -111,6 +119,8 @@ def param_logical_axes(cfg: LlamaConfig):
             "w_up": ("layers", "embed", "mlp"),
             "w_down": ("layers", "mlp", "embed"),
         }
+    if cfg.qk_norm:
+        mlp_axes.update(q_norm=("layers", None), k_norm=("layers", None))
     return {
         "embed": ("vocab", "embed"),
         "layers": {
@@ -155,6 +165,9 @@ def init_params(key, cfg: LlamaConfig):
             "w_up": norm(ks[6], (L, d, m), d),
             "w_down": norm(ks[7], (L, m, d), m),
         }
+    if cfg.qk_norm:
+        mlp_params.update(q_norm=jnp.ones((L, h * hd), cfg.dtype),
+                          k_norm=jnp.ones((L, hkv * hd), cfg.dtype))
     return {
         "embed": norm(ks[0], (cfg.vocab, d), d),
         "layers": {
@@ -214,11 +227,28 @@ def sharded_attention(q, k, v, mesh: Mesh, rules=DEFAULT_RULES, *,
         check_vma=False)(q, k, v)
 
 
+def qk_norm(q, k, lp, cfg: LlamaConfig):
+    """Where ``cfg.qk_norm``: RMSNorm of the projected queries
+    (..., h, hd) and keys (..., hkv, hd), each over its WHOLE projected
+    width (all heads together, one learned weight ``q_norm`` (h * hd) /
+    ``k_norm`` (hkv * hd)), after the projection and before the rotary
+    embedding. The one seam every copy of the block calls."""
+    if not cfg.qk_norm:
+        return q, k
+
+    def whole(x, weight):
+        flat = x.reshape(*x.shape[:-2], x.shape[-2] * x.shape[-1])
+        return rms_norm(flat, weight, cfg.norm_eps).reshape(x.shape)
+
+    return whole(q, lp["q_norm"]), whole(k, lp["k_norm"])
+
+
 def _attn(x, lp, cfg: LlamaConfig, cos, sin, mesh: Optional[Mesh], rules):
     b, s, d = x.shape
     q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, lp["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, lp["wv"])
+    q, k = qk_norm(q, k, lp, cfg)
     q = apply_rotary(q, cos, sin)
     k = apply_rotary(k, cos, sin)
     if mesh is not None and mesh.shape.get("sp", 1) > 1:
@@ -245,7 +275,8 @@ def _mlp(x, lp, cfg: LlamaConfig, csl):
 
         out, aux = moe_mlp(
             x, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"],
-            top_k=cfg.top_k, capacity_factor=cfg.capacity_factor, csl=csl)
+            top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
+            norm_topk_prob=cfg.norm_topk_prob, csl=csl)
         return out, aux
     # SwiGLU; gate/up fuse into one pass over x in XLA.
     g = jnp.einsum("bsd,dm->bsm", x, lp["w_gate"])

@@ -10,7 +10,7 @@ from .norms import rms_norm
 from .rotary import apply_rotary, rope_frequencies
 from .attention import attention, flash_attention_tpu, naive_attention
 from .ring_attention import ring_attention
-from .moe import moe_dispatch, moe_mlp, moe_mlp_oracle
+from .moe import moe_dispatch, moe_mlp, moe_mlp_oracle, moe_mlp_routed
 from .quant import (
     dequantize_weight, embed_lookup, init_params_quantized,
     quantize_params, quantize_weight, weight_einsum)
@@ -19,6 +19,7 @@ __all__ = [
     "rms_norm", "apply_rotary", "rope_frequencies",
     "attention", "flash_attention_tpu", "naive_attention",
     "ring_attention", "moe_dispatch", "moe_mlp", "moe_mlp_oracle",
+    "moe_mlp_routed",
     "quantize_weight", "dequantize_weight", "weight_einsum",
     "embed_lookup", "quantize_params", "init_params_quantized",
 ]

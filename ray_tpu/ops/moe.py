@@ -1,11 +1,15 @@
 """Mixture-of-Experts with expert parallelism, TPU-first.
 
-GShard/Switch-style DENSE dispatch: routing is expressed as one-hot
-einsums with a static per-expert capacity, so the whole layer is three
-batched matmuls + masks — fully static shapes, MXU-friendly, and GSPMD
-inserts the token all-to-alls automatically when the expert axis is
-sharded over the "ep" mesh axis (logical axis "expert"). This replaces
-ragged/dynamic dispatch, which XLA cannot tile.
+Training (``moe_mlp``): GShard/Switch-style DENSE dispatch: routing is
+expressed as one-hot einsums with a static per-expert capacity, so the
+whole layer is three batched matmuls + masks — fully static shapes,
+MXU-friendly, and GSPMD inserts the token all-to-alls automatically when
+the expert axis is sharded over the "ep" mesh axis (logical axis
+"expert"). Tokens over an expert's capacity are dropped.
+
+Serving (``moe_mlp_routed``): one dropless routed layer: the (token,
+expert) rows sorted by expert into ragged groups, the expert products as
+grouped matrix multiplications over them (a Pallas kernel on a TPU).
 
 The reference has no MoE of its own (SURVEY §2.3: EP listed as "not
 implemented", placement groups only as the placement substrate) — this is
@@ -32,7 +36,8 @@ def _top_k_mask(probs: jnp.ndarray, k: int) -> jnp.ndarray:
     return mask
 
 
-def moe_dispatch(gates: jnp.ndarray, top_k: int, capacity: int
+def moe_dispatch(gates: jnp.ndarray, top_k: int, capacity: int,
+                 norm_topk_prob: bool = True
                  ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Build dispatch/combine tensors from router probabilities.
 
@@ -49,10 +54,13 @@ def moe_dispatch(gates: jnp.ndarray, top_k: int, capacity: int
     pos_hot = jax.nn.one_hot(position.astype(jnp.int32), capacity,
                              dtype=gates.dtype)            # (T, E, C)
     dispatch = pos_hot * in_capacity[..., None].astype(gates.dtype)
-    # combine weights: renormalized top-k gate probs
+    # combine weights: the top-k gate probs, renormalized to sum to 1
+    # unless the router's own are wanted (norm_topk_prob False)
     selected = gates * mask
-    denom = jnp.maximum(selected.sum(-1, keepdims=True), 1e-9)
-    combine = dispatch * (selected / denom)[..., None]
+    if norm_topk_prob:
+        selected = selected / jnp.maximum(
+            selected.sum(-1, keepdims=True), 1e-9)
+    combine = dispatch * selected[..., None]
     # Switch aux loss: E * sum_e f_e * p_e  (f: token fraction routed to e,
     # p: mean router prob) — pushes toward uniform load. f is divided by
     # top_k so the uniform-load floor is 1.0 regardless of k (the
@@ -66,6 +74,7 @@ def moe_dispatch(gates: jnp.ndarray, top_k: int, capacity: int
 def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, w_gate: jnp.ndarray,
             w_up: jnp.ndarray, w_down: jnp.ndarray, *,
             top_k: int = 2, capacity_factor: float = 1.25,
+            norm_topk_prob: bool = True,
             csl=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """SwiGLU expert MLP over a routed token subset.
 
@@ -85,7 +94,8 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, w_gate: jnp.ndarray,
                    router_w.astype(jnp.float32)), axis=-1)
     capacity = max(int(top_k * T / E * capacity_factor), 1)
     capacity = -(-capacity // 8) * 8  # sublane-aligned buffers
-    dispatch, combine, aux = moe_dispatch(gates, top_k, capacity)
+    dispatch, combine, aux = moe_dispatch(gates, top_k, capacity,
+                                          norm_topk_prob)
     dispatch = dispatch.astype(x.dtype)
     combine = combine.astype(x.dtype)
 
@@ -102,16 +112,21 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, w_gate: jnp.ndarray,
     return out.reshape(B, S, D), aux
 
 
-def moe_mlp_oracle(x, router_w, w_gate, w_up, w_down, *, top_k=2):
+def moe_mlp_oracle(x, router_w, w_gate, w_up, w_down, *, top_k=2,
+                   norm_topk_prob=True):
     """Per-token reference (no capacity drops): for each token, sum over
-    its top-k experts of renormalized_prob * SwiGLU_e(x). Test oracle —
-    and the serving path's exact dense mixture (see moe_mlp_dense)."""
+    its top-k experts of prob * SwiGLU_e(x), the chosen probabilities
+    renormalised to sum to 1 where ``norm_topk_prob``. The test oracle
+    of ``moe_mlp`` and ``moe_mlp_routed``: it computes every expert on
+    every token, in float32."""
     B, S, D = x.shape
     xt = x.reshape(-1, D).astype(jnp.float32)
     gates = jax.nn.softmax(xt @ router_w.astype(jnp.float32), axis=-1)
     mask = _top_k_mask(gates, top_k)
-    selected = gates * mask
-    weights = selected / jnp.maximum(selected.sum(-1, keepdims=True), 1e-9)
+    weights = gates * mask
+    if norm_topk_prob:
+        weights = weights / jnp.maximum(
+            weights.sum(-1, keepdims=True), 1e-9)
     # compute EVERY expert on every token, weight, and sum
     g = jnp.einsum("td,edm->etm", xt, w_gate.astype(jnp.float32))
     u = jnp.einsum("td,edm->etm", xt, w_up.astype(jnp.float32))
@@ -121,11 +136,222 @@ def moe_mlp_oracle(x, router_w, w_gate, w_up, w_down, *, top_k=2):
     return out.reshape(B, S, D).astype(x.dtype)
 
 
-# Inference alias: exact (drop-free) routing via a dense all-expert
-# mixture. Deliberate tradeoff: for small expert counts this keeps the
-# MXU on large dense matmuls (a gather/segment dispatch beats it only
-# when E >> top_k); for large-E serving the upgrade path is a ragged
-# all-to-all dispatch kernel without the training path's capacity cap —
-# capacity-based dispatch is unusable at inference because drops change
-# generations batch-dependently.
-moe_mlp_dense = moe_mlp_oracle
+# ---------------------------------------------------------------------------
+# Serving: one dropless routed expert layer.
+# ---------------------------------------------------------------------------
+# The (token, expert) rows are sorted by expert into ragged groups and the
+# three expert products run as grouped matrix multiplications over those
+# groups. No capacity exists, so nothing is dropped and a sequence's
+# output does not depend on what else is in the batch; rows that are not
+# tokens (a prefill bucket's padding, inactive decode slots) sort behind
+# every group and are multiplied with nothing; int8 expert weights are
+# read as int8 and widened a tile at a time, their per-output-channel
+# scales applied to the product by each row's expert.
+
+
+# The grouped kernel's tiles: rows, contracted width, output width (the
+# last two the largest of these that divide the matrix). Read on a v5e at
+# OLMoE's widths (PR 28, PERF.md section 6): a row tile no larger than a
+# group (a 1,024-token prompt gives an expert 128 rows) is not computed
+# again for every group that shares it, and a whole contracted width in
+# one step needs no second pass over the accumulator.
+_TILE_M, _TILE_K, _TILE_N = 128, 2048, 1024
+
+
+def _pick_tile(size: int, largest: int) -> Optional[int]:
+    """The largest power-of-two multiple of 128, at most ``largest``,
+    that divides ``size``; None where 128 does not."""
+    tile = largest
+    while tile >= 128:
+        if size % tile == 0:
+            return tile
+        tile //= 2
+    return None
+
+
+def _gmm_xla(lhs, w, scale, layer, row_expert, group_sizes, out_dtype):
+    """Grouped product in plain jax (the CPU's path, and a TPU's where
+    the widths are not lane-aligned): ``lax.ragged_dot`` over the
+    groups, rows behind the last group left at zero."""
+    w = w[layer]
+    out = jax.lax.ragged_dot(lhs, w.astype(lhs.dtype), group_sizes,
+                             preferred_element_type=jnp.float32)
+    if scale is not None:
+        scale = scale[layer]
+        out = out * scale[jnp.minimum(row_expert, scale.shape[0] - 1)]
+    return out.astype(out_dtype)
+
+
+def _gmm_tpu(lhs, w, scale, layer, group_sizes, out_dtype,
+             interpret=False):
+    """Grouped product as a Pallas kernel, after megablox's ``gmm``
+    (whose group bookkeeping it reuses): the weight tile arrives in VMEM
+    as it is stored (int8 or bf16) and is widened there, so no wide copy
+    of the experts is ever written to HBM; the float32 accumulator is
+    scaled by the group's per-output-channel scales as it is stored.
+    ``w`` is the whole stack [L, E, K, N] and ``layer`` picks its layer
+    in the tile's address: slicing a layer out first would copy that
+    layer's experts (134 MB a matrix at OLMoE's widths) every step.
+    Only row tiles that hold a group's rows are visited (the grid's
+    extent is computed from ``group_sizes``): rows behind the last group
+    cost nothing and are left unwritten."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import (
+        make_group_metadata)
+
+    m, k = lhs.shape
+    n_layers, groups, _, n = w.shape
+    tm = min(m, _TILE_M)
+    tk, tn = _pick_tile(k, _TILE_K), _pick_tile(n, _TILE_N)
+    tiles_k = k // tk
+    (offsets, group_ids, m_tile_ids), active_tiles = make_group_metadata(
+        group_sizes=group_sizes, m=m, tm=tm, start_group=jnp.int32(0),
+        num_nonzero_groups=groups, visit_empty_groups=False)
+    if scale is None:
+        scale = jnp.ones((n_layers, groups, n), jnp.float32)
+
+    def kernel(layer, offsets, group_ids, m_tile_ids, lhs_ref, w_ref,
+               s_ref, out_ref, acc):
+        tile, k_i = pl.program_id(1), pl.program_id(2)
+
+        @pl.when(k_i == 0)
+        def _():
+            acc[...] = jnp.zeros_like(acc)
+
+        acc[...] += jnp.dot(
+            lhs_ref[...],
+            w_ref[...].astype(jnp.float32).astype(lhs_ref.dtype),
+            preferred_element_type=jnp.float32)
+
+        @pl.when(k_i == tiles_k - 1)
+        def _():
+            # a row tile on a group boundary is visited once per group:
+            # store this group's rows, keep what the others stored
+            group = group_ids[tile]
+            rows = m_tile_ids[tile] * tm + jax.lax.broadcasted_iota(
+                jnp.int32, (tm, tn), 0)
+            mine = (rows >= offsets[group]) & (rows < offsets[group + 1])
+            out_ref[...] = jnp.where(
+                mine, acc[...] * s_ref[...],
+                out_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            in_specs=[
+                pl.BlockSpec((tm, tk), lambda n_i, t, k_i, lay, off, gid,
+                             mid: (mid[t], k_i)),
+                pl.BlockSpec((None, None, tk, tn),
+                             lambda n_i, t, k_i, lay, off, gid, mid:
+                             (lay[0], gid[t], k_i, n_i)),
+                pl.BlockSpec((None, None, 1, tn),
+                             lambda n_i, t, k_i, lay, off, gid, mid:
+                             (lay[0], gid[t], 0, n_i)),
+            ],
+            out_specs=pl.BlockSpec(
+                (tm, tn), lambda n_i, t, k_i, lay, off, gid, mid:
+                (mid[t], n_i)),
+            grid=(n // tn, active_tiles, tiles_k),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), offsets, group_ids,
+      m_tile_ids, lhs, w, scale[:, :, None, :])
+
+
+def grouped_matmul(lhs, w, row_expert, group_sizes, layer=None):
+    """``lhs[rows of group e] @ w[e]`` for every group: ``lhs`` [M, K]
+    sorted by group, ``w`` [E, K, N] raw or quantized (``{"q", "s"}``
+    with ``s`` [E, N]), ``row_expert`` [M] each row's group (E for a row
+    behind the last group), ``group_sizes`` [E]. With ``layer`` (a
+    traced index), ``w`` is a stack [L, E, K, N] (``s`` [L, E, N]) and
+    that layer's experts are used, without a copy of them being made.
+    Rows behind the last group hold nothing a caller may read. The
+    kernel runs where the program is lowered for a TPU and the widths
+    are lane-aligned."""
+    q, scale = (w["q"], w["s"]) if isinstance(w, dict) else (w, None)
+    if layer is None:
+        layer = jnp.int32(0)
+        q, scale = q[None], (None if scale is None else scale[None])
+    m, k = lhs.shape
+    n = q.shape[-1]
+    operands = (lhs, q, scale, layer, row_expert, group_sizes)
+
+    def xla(lhs, q, scale, layer, row_expert, group_sizes):
+        return _gmm_xla(lhs, q, scale, layer, row_expert, group_sizes,
+                        lhs.dtype)
+
+    if (_pick_tile(k, _TILE_K) is None or _pick_tile(n, _TILE_N) is None
+            or m % 16 or m % min(m, _TILE_M)):
+        return xla(*operands)
+
+    def tpu(lhs, q, scale, layer, row_expert, group_sizes):
+        return _gmm_tpu(lhs, q, scale, layer, group_sizes, lhs.dtype)
+
+    return jax.lax.platform_dependent(*operands, tpu=tpu, default=xla)
+
+
+def moe_mlp_routed(x, router_w, w_gate, w_up, w_down, *, top_k: int,
+                   norm_topk_prob: bool = True, valid=None, layer=None):
+    """Dropless top-k SwiGLU expert layer, the serving path's one.
+
+    x (B, S, D); router_w (D, E) float32; w_gate/w_up (E, D, M) and
+    w_down (E, M, D), raw or int8 (``ops/quant.py``); ``valid`` (B, S)
+    bool, False for rows that are not tokens (absent: all are). With
+    ``layer`` the three expert weights are whole stacks with a leading
+    layers axis (see ``grouped_matmul``). The
+    chosen experts' probabilities are used as the router gave them, or
+    renormalised to sum to 1 where ``norm_topk_prob``.
+
+    Returns (out (B, S, D), zero at rows that are not tokens; counts
+    int32 [2]: the (token, expert) rows the expert products were given,
+    and the experts with at least one row).
+    """
+    B, S, D = x.shape
+    T, E = B * S, router_w.shape[-1]
+    xt = x.reshape(T, D)
+    with jax.named_scope("rt.moe.route"):
+        # the router in float32 at full precision: a tie between the
+        # k-th and the next expert decides everything after it
+        probs = jax.nn.softmax(jnp.einsum(
+            "td,de->te", xt.astype(jnp.float32),
+            router_w.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST), axis=-1)
+        weight, chosen = jax.lax.top_k(probs, top_k)            # [T, k]
+        if norm_topk_prob:
+            weight = weight / jnp.maximum(
+                weight.sum(-1, keepdims=True), 1e-9)
+        expert = chosen.reshape(T * top_k)
+        if valid is not None:
+            # rows that are not tokens sort behind every group
+            expert = jnp.where(jnp.repeat(valid.reshape(T), top_k),
+                               expert, E)
+        order = jnp.argsort(expert, stable=True)
+        row_expert = expert[order]
+        group_sizes = jnp.sum(
+            expert[:, None] == jnp.arange(E, dtype=expert.dtype)[None, :],
+            axis=0, dtype=jnp.int32)
+        rows = xt[order // top_k]                               # [T*k, D]
+    with jax.named_scope("rt.moe.experts"):
+        gate = grouped_matmul(rows, w_gate, row_expert, group_sizes, layer)
+        up = grouped_matmul(rows, w_up, row_expert, group_sizes, layer)
+        hidden = (jax.nn.silu(gate.astype(jnp.float32))
+                  * up.astype(jnp.float32)).astype(x.dtype)
+        down = grouped_matmul(hidden, w_down, row_expert, group_sizes,
+                              layer)
+    with jax.named_scope("rt.moe.combine"):
+        # rows behind the last group were never written (they may hold
+        # anything, NaN too): mask them, which also zeroes the output of
+        # rows that are not tokens
+        down = jnp.where((row_expert < E)[:, None], down, 0)
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(T * top_k, dtype=order.dtype), unique_indices=True)
+        out = jnp.einsum("tk,tkd->td", weight,
+                         down[back].reshape(T, top_k, D).astype(jnp.float32))
+    counts = jnp.stack([group_sizes.sum(),
+                        jnp.sum(group_sizes > 0, dtype=jnp.int32)])
+    return out.reshape(B, S, D).astype(x.dtype), counts

@@ -117,19 +117,27 @@ _LLAMA_LAYER_CONTRACT = {
     "w_up": (1,),
     "w_down": (1,),  # (L, m, d)       contract m
 }
+# expert configs: the feed-forward has an "expert" axis after "layers",
+# never contracted either: per-expert per-output-channel scales (L, E, n),
+# which the routed layer applies by each row's expert (ops/moe.py)
+_EXPERT_LAYER_CONTRACT = {
+    "w_gate": (2,),  # (L, E, d, m)    contract d
+    "w_up": (2,),
+    "w_down": (2,),  # (L, E, m, d)    contract m
+}
 
 
 def quantize_params(params: Dict, cfg=None) -> Dict:
     """Quantize a dense-Llama param tree for serving: all projection
-    matrices + embedding (per-row) + lm_head go int8; norms stay as-is
-    (tiny, precision-sensitive). MoE configs keep expert weights
-    unquantized for now (the dense-mixture serving path would need
-    per-expert scale plumbing) — raise rather than silently skip."""
-    if cfg is not None and getattr(cfg, "n_experts", 0):
-        raise NotImplementedError(
-            "int8 quantization for MoE expert weights is not wired up")
+    matrices + embedding (per-row) + lm_head go int8; norms (and an
+    expert config's float32 router: its ties decide everything after
+    it) stay as-is (tiny, precision-sensitive). Expert matrices get
+    per-expert per-output-channel scales."""
     layers = dict(params["layers"])
-    for name, axes in _LLAMA_LAYER_CONTRACT.items():
+    contract = dict(_LLAMA_LAYER_CONTRACT)
+    if "router" in layers:
+        contract.update(_EXPERT_LAYER_CONTRACT)
+    for name, axes in contract.items():
         if name in layers:
             layers[name] = quantize_weight(layers[name], axes)
     return {
@@ -151,8 +159,6 @@ def init_params_quantized(key, cfg) -> Dict:
 
     The whole init is ONE jitted program: eagerly it would dispatch and
     load ~50 single-op executables."""
-    if getattr(cfg, "n_experts", 0):
-        raise NotImplementedError("quantized init for MoE not wired up")
     return _init_params_quantized_jit(key, cfg)
 
 
@@ -162,26 +168,54 @@ def _init_params_quantized_jit(key, cfg) -> Dict:
     h, hkv, m = cfg.n_heads, cfg.n_kv_heads, cfg.mlp_dim
     ks = iter(jax.random.split(key, 16))
 
-    def qrand(shape, fan_in, out_dims: Tuple[int, ...]):
+    def qrand(shape, fan_in, out_dims: Tuple[int, ...], spread=False):
         bits = jax.random.bits(next(ks), shape, jnp.uint8)
         q = jax.lax.bitcast_convert_type(bits, jnp.int8)
         s_shape = tuple(shape[i] for i in out_dims)
         s = jnp.full(s_shape, (fan_in ** -0.5) / 74.0, jnp.float32)
+        if spread:
+            # scales that differ by expert and channel (x 0.5 to 1.5):
+            # a product scaled by another expert's scales shows
+            s = s * jax.random.uniform(next(ks), s_shape, jnp.float32,
+                                       0.5, 1.5)
         return {"q": q, "s": s}
 
+    # made in this order: each qrand takes the next key, and a dense
+    # config's weights for a seed are what they always were
+    embed = qrand((cfg.vocab, d), d, (0,))
+    layers = {
+        "attn_norm": jnp.ones((L, d), jnp.bfloat16),
+        "wq": qrand((L, d, h, hd), d, (0, 2, 3)),
+        "wk": qrand((L, d, hkv, hd), d, (0, 2, 3)),
+        "wv": qrand((L, d, hkv, hd), d, (0, 2, 3)),
+        "wo": qrand((L, h, hd, d), h * hd, (0, 3)),
+        "mlp_norm": jnp.ones((L, d), jnp.bfloat16),
+    }
+    if cfg.n_experts:
+        E = cfg.n_experts
+        # the router stays float32, as init_params makes it
+        layers["router"] = jax.random.normal(
+            next(ks), (L, d, E), jnp.float32) * (d ** -0.5)
+        layers.update(
+            w_gate=qrand((L, E, d, m), d, (0, 1, 3), spread=True),
+            w_up=qrand((L, E, d, m), d, (0, 1, 3), spread=True),
+            w_down=qrand((L, E, m, d), m, (0, 1, 3), spread=True))
+    else:
+        layers.update(
+            w_gate=qrand((L, d, m), d, (0, 2)),
+            w_up=qrand((L, d, m), d, (0, 2)),
+            w_down=qrand((L, m, d), m, (0, 2)))
+    if cfg.qk_norm:
+        # learned gains scattered about 1, so that a norm over the wrong
+        # width or with the wrong weight shows against a reference
+        def gain(width):
+            return (1.0 + 0.25 * jax.random.normal(
+                next(ks), (L, width), jnp.float32)).astype(jnp.bfloat16)
+
+        layers.update(q_norm=gain(h * hd), k_norm=gain(hkv * hd))
     return {
-        "embed": qrand((cfg.vocab, d), d, (0,)),
-        "layers": {
-            "attn_norm": jnp.ones((L, d), jnp.bfloat16),
-            "wq": qrand((L, d, h, hd), d, (0, 2, 3)),
-            "wk": qrand((L, d, hkv, hd), d, (0, 2, 3)),
-            "wv": qrand((L, d, hkv, hd), d, (0, 2, 3)),
-            "wo": qrand((L, h, hd, d), h * hd, (0, 3)),
-            "mlp_norm": jnp.ones((L, d), jnp.bfloat16),
-            "w_gate": qrand((L, d, m), d, (0, 2)),
-            "w_up": qrand((L, d, m), d, (0, 2)),
-            "w_down": qrand((L, m, d), m, (0, 2)),
-        },
+        "embed": embed,
+        "layers": layers,
         "final_norm": jnp.ones((d,), jnp.bfloat16),
         "lm_head": qrand((d, cfg.vocab), d, (1,)),
     }

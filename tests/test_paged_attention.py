@@ -88,7 +88,7 @@ def test_decode_burst_kernel_path_matches_gather_path():
     cv0 = rng.standard_normal((L, P, page, kvh, hd)).astype(np.float32) * .1
     outs = {}
     for flag in (True, False):
-        toks, k2, v2 = decode_burst(
+        toks, k2, v2, _ = decode_burst(
             params, jnp.asarray(ck0), jnp.asarray(cv0),
             jnp.asarray([3, 5], jnp.int32), jnp.asarray([20, 7], jnp.int32),
             jnp.asarray([[1, 2], [3, 4]], jnp.int32),

@@ -84,7 +84,7 @@ def _prefill_logits(params):
     cos, sin = rope_frequencies(CFG.head_dim, CFG.max_seq, CFG.rope_theta)
     tokens = jnp.asarray([PROMPT], jnp.int32)
     bt = jnp.asarray([[1, 2]], jnp.int32)
-    logits, _, _ = prefill(params, cache.k, cache.v, tokens,
+    logits, _, _, _ = prefill(params, cache.k, cache.v, tokens,
                            jnp.asarray([len(PROMPT)], jnp.int32), bt,
                            cos, sin, cfg=CFG)
     return np.asarray(logits[0], np.float64)
@@ -121,7 +121,7 @@ def test_quantized_decode_matches_quantized_prefill_oracle():
         arr = np.zeros((1, pad), np.int32)
         arr[0, :len(tokens)] = tokens
         bt = jnp.asarray([list(range(1, 9))], jnp.int32)
-        logits, _, _ = prefill(params, cache.k, cache.v,
+        logits, _, _, _ = prefill(params, cache.k, cache.v,
                                jnp.asarray(arr),
                                jnp.asarray([len(tokens)], jnp.int32), bt,
                                cos, sin, cfg=CFG)
@@ -183,12 +183,40 @@ def test_llm_server_builds_seeded_int8_weights():
         LLMServer("tiny", init="random", quantize="int4")
 
 
-def test_moe_quantization_rejected():
-    cfg = dataclasses.replace(CFG, n_experts=4)
-    with pytest.raises(NotImplementedError):
-        quantize_params({}, cfg)
-    with pytest.raises(NotImplementedError):
-        init_params_quantized(jax.random.PRNGKey(0), cfg)
+def test_moe_quantization_round_trip():
+    """Quantized experts: int8 with per-expert per-output-channel scales,
+    router left float32; the dequantized expert product lies within
+    int8's error of the full-precision one (half a step a weight, summed
+    over the contracted width like a random walk)."""
+    cfg = dataclasses.replace(CFG, n_experts=4, top_k=2, qk_norm=True)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    q = quantize_params(params, cfg)["layers"]
+    L, E, d, m = cfg.n_layers, 4, cfg.dim, cfg.mlp_dim
+    assert q["w_gate"]["q"].shape == (L, E, d, m)
+    assert q["w_gate"]["q"].dtype == jnp.int8
+    assert q["w_gate"]["s"].shape == (L, E, m)
+    assert q["w_down"]["s"].shape == (L, E, d)
+    assert q["router"].dtype == jnp.float32 and not is_quantized(q["router"])
+    assert not is_quantized(q["q_norm"])
+    x = jax.random.normal(jax.random.PRNGKey(1), (8, d), jnp.float32)
+    for name in ("w_gate", "w_down"):
+        w = params["layers"][name][0, 1].astype(jnp.float32)   # [k, n]
+        lhs = x if name == "w_gate" else jax.random.normal(
+            jax.random.PRNGKey(2), (8, m), jnp.float32)
+        deq = (q[name]["q"][0, 1].astype(jnp.float32)
+               * q[name]["s"][0, 1][None, :])
+        step = q[name]["s"][0, 1]
+        assert jnp.all(jnp.abs(deq - w) <= 0.5 * step[None, :] + 1e-7)
+        err = jnp.abs(lhs @ deq - lhs @ w)
+        bound = 0.5 * step[None, :] * jnp.linalg.norm(
+            lhs, axis=1, keepdims=True) * 4.0
+        assert jnp.all(err <= bound)
+    # the seeded init makes the same tree on the device
+    made = init_params_quantized(jax.random.PRNGKey(0), cfg)["layers"]
+    assert set(made) == set(q)
+    assert made["w_up"]["q"].shape == (L, E, d, m)
+    assert made["w_up"]["s"].shape == (L, E, m)
+    assert made["router"].dtype == jnp.float32
 
 
 def test_hf_load_quantized(tmp_path):

@@ -205,3 +205,39 @@ def test_sharded_train_step_keeps_flash_kernels(topo):
     whole = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
     per_device = compiled.memory_analysis().argument_size_in_bytes
     assert per_device < 0.3 * whole, (per_device, whole)
+
+
+@pytest.mark.parametrize("rows", [64, 16384], ids=["decode", "prefill"])
+def test_routed_expert_layer_reads_int8_experts_without_a_wide_copy(
+        one_chip, rows):
+    """The dropless layer at OLMoE's widths (64 experts of 1024 on 2048,
+    8 a token): three grouped Pallas products, and the experts stay the
+    int8 stacks they are stored as: no bf16 or float32 tensor of a
+    layer's experts, and no int8 copy of one layer, in the program."""
+    import re
+
+    from ray_tpu.ops.moe import moe_mlp_routed
+
+    L, E, d, m, k = 2, 64, 2048, 1024, 8
+
+    def quantized(shape):
+        return {"q": _sds(shape, jnp.int8, one_chip),
+                "s": _sds(shape[:2] + shape[3:], jnp.float32, one_chip)}
+
+    def layer(x, router, w_gate, w_up, w_down, valid, index):
+        return moe_mlp_routed(x, router, w_gate, w_up, w_down, top_k=k,
+                              norm_topk_prob=False, valid=valid,
+                              layer=index)
+
+    tokens = rows // k
+    text = jax.jit(layer).lower(
+        _sds((1, tokens, d), jnp.bfloat16, one_chip),
+        _sds((d, E), jnp.float32, one_chip), quantized((L, E, d, m)),
+        quantized((L, E, d, m)), quantized((L, E, m, d)),
+        _sds((1, tokens), jnp.bool_, one_chip),
+        _sds((), jnp.int32, one_chip)).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    experts = r"\[(?:%d,)?%d,(?:%d,%d|%d,%d)\]" % (L, E, d, m, m, d)
+    assert not re.findall(r"(?:bf16|f32)" + experts, text)
+    assert set(re.findall(r"s8" + experts, text)) == {
+        "s8[2,64,2048,1024]", "s8[2,64,1024,2048]"}
