@@ -8,7 +8,14 @@ TPU-first shape discipline (everything static under jit):
     slots masked (their writes land on dump page 0) — one executable for
     the life of the engine;
   * cache buffers are donated, so XLA updates pages in place (no
-    O(cache) copy per step).
+    O(cache) copy per step) — and, HBM discipline, the big cache stays
+    out of the scans that would copy it: a scan's stacked output is not
+    aliased to its stacked input, so a pool that rides one is rewritten
+    whole. ``prefill`` (whole prompts) and ``decode_burst`` hand their
+    new K/V rows out of their scans and scatter them into the donated
+    pools once at the end; ``prefill_chunk`` and ``verify_step`` still
+    take the pool through their layer scans (they read back the pages
+    they write, layer by layer: ROADMAP S10).
 
 The decode attention gathers pages with jnp.take (XLA fuses the gather
 into the attention when it can); a Pallas in-place kernel is the upgrade
@@ -114,6 +121,18 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
     tokens: [B, S] right-padded; prompt_lens: [B]; block_tables: [B, Pmax].
     ``lora``: per-slot batched adapters from LoRAPool.select(ids) —
     low-rank deltas on wq/wv (llm/lora.py), empty/None = base model.
+
+    HBM discipline (as ``decode_burst``): the big cache never rides the
+    layer scan. A scan's stacked output is not aliased to its stacked
+    input, so a pool passed through as xs/ys is read and rewritten whole
+    (every page of every layer, a second pool among the temporaries) to
+    write one prompt's rows. Attention here runs on the prompt's own
+    k and v and never reads a page, so each layer only hands its K and V
+    rows out of the scan ([L, B, S, kvh, hd], in the cache's dtype) and
+    they scatter into the donated pools ONCE at the end, in place.
+    Padding rows (position >= prompt_len) carry an out-of-range page
+    index and are dropped: they change no page, the dump page neither.
+
     Returns (logits [B, vocab], cache_k, cache_v, expert counts: see
     ``_mlp``; None for a dense config).
     """
@@ -122,14 +141,14 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
     B, S = tokens.shape
     x = embed_lookup(params["embed"], tokens, cfg.dtype)
     pos_grid = jnp.arange(S)[None, :].repeat(B, 0)
-    write_pos = jnp.where(pos_grid < prompt_lens[:, None], pos_grid, -1)
+    valid = pos_grid < prompt_lens[:, None]                    # [B, S]
     # adapters ride the layer scan as xs: [B, L, ...] -> [L, B, ...]
     lora_xs = {} if not lora else {
         k2: jnp.swapaxes(v2, 0, 1) for k2, v2 in lora.items()
         if k2 != "scale"}
 
     def layer(x, inputs):
-        lp, ck, cv, lr = inputs
+        lp, lr = inputs
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
         q = weight_einsum("bsd,dhk->bshk", h, lp["wq"])
         k = weight_einsum("bsd,dhk->bshk", h, lp["wk"])
@@ -142,19 +161,32 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
         q, k = qk_norm(q, k, lp, cfg)
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
-        ck = _write_pages(ck, k, block_tables, write_pos, ck.shape[1])
-        cv = _write_pages(cv, v, block_tables, write_pos, cv.shape[1])
         # right padding is safe under the causal mask: a real position
         # only attends to earlier (real) positions
         o = attention(q, k, v, causal=True)
         x = x + weight_einsum("bshk,hkd->bsd", o, lp["wo"])
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        m, n = _mlp(h, lp, cfg, write_pos >= 0, experts)
-        return x + m, (ck, cv, n)
+        m, n = _mlp(h, lp, cfg, valid, experts)
+        return x + m, (k.astype(cache_k.dtype), v.astype(cache_v.dtype), n)
 
     layers, experts = _split_layers(params["layers"], cfg)
-    x, (cache_k, cache_v, counts) = jax.lax.scan(
-        layer, x, (layers, cache_k, cache_v, lora_xs))
+    x, (rows_k, rows_v, counts) = jax.lax.scan(layer, x, (layers, lora_xs))
+
+    # one scatter of all layers' rows into the paged cache (donated ->
+    # in-place); page index num_pages is out of range, so mode="drop"
+    # writes nothing for a padding row
+    n_pages, page_size = cache_k.shape[1:3]
+    page_idx = jnp.take_along_axis(block_tables, pos_grid // page_size,
+                                   axis=1)
+    fp = jnp.where(valid, page_idx, n_pages).reshape(-1)       # [B*S]
+    fo = (pos_grid % page_size).reshape(-1)
+
+    def put(cache, rows):                      # rows: [L, B, S, kvh, hd]
+        return cache.at[:, fp, fo].set(
+            rows.reshape(rows.shape[0], B * S, *rows.shape[3:]),
+            mode="drop")
+
+    cache_k, cache_v = put(cache_k, rows_k), put(cache_v, rows_v)
     x_last = jnp.take_along_axis(
         x, jnp.maximum(prompt_lens - 1, 0)[:, None, None], axis=1)[:, 0]
     x_last = rms_norm(x_last, params["final_norm"], cfg.norm_eps)

@@ -3,6 +3,7 @@
 reference inherits by delegating to vLLM; native here)."""
 
 import asyncio
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -498,3 +499,129 @@ def test_lora_pool_lifecycle(tiny_params):
         LLMEngine(tiny_params, CFG, EngineConfig(
             max_num_seqs=2, page_size=4, num_pages=64, max_seq_len=64,
             lora_rank=4, enable_prefix_caching=True))
+
+
+# --- whole-prompt prefill: where the rows go ---
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _prefill_layer_by_layer(params, cache_k, cache_v, tokens, prompt_lens,
+                            block_tables, cos, sin, lora, cfg):
+    """The plain reference for ``runner.prefill``, and the program it
+    replaced: every layer takes ITS pool through the scan and writes its
+    K and V rows there with ``_write_pages`` (padding to dump page 0),
+    which copies the whole pool to write one prompt."""
+    from ray_tpu.llm import runner
+    from ray_tpu.llm.lora import lora_delta
+    from ray_tpu.models.llama import qk_norm
+    from ray_tpu.ops import apply_rotary, attention, rms_norm
+    from ray_tpu.ops.quant import embed_lookup, weight_einsum
+
+    B, S = tokens.shape
+    x = embed_lookup(params["embed"], tokens, cfg.dtype)
+    pos = jnp.arange(S)[None, :].repeat(B, 0)
+    write_pos = jnp.where(pos < prompt_lens[:, None], pos, -1)
+    lora_xs = {} if not lora else {
+        k: jnp.swapaxes(v, 0, 1) for k, v in lora.items() if k != "scale"}
+    layers, experts = runner._split_layers(params["layers"], cfg)
+
+    def layer(x, inputs):
+        lp, ck, cv, lr = inputs
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        q = weight_einsum("bsd,dhk->bshk", h, lp["wq"])
+        k = weight_einsum("bsd,dhk->bshk", h, lp["wk"])
+        v = weight_einsum("bsd,dhk->bshk", h, lp["wv"])
+        if lr:
+            q = q + lora_delta(h, lr["a_q"], lr["b_q"], lora["scale"],
+                               cfg.n_heads, cfg.head_dim)
+            v = v + lora_delta(h, lr["a_v"], lr["b_v"], lora["scale"],
+                               cfg.n_kv_heads, cfg.head_dim)
+        q, k = qk_norm(q, k, lp, cfg)
+        q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
+        ck = runner._write_pages(ck, k, block_tables, write_pos, ck.shape[1])
+        cv = runner._write_pages(cv, v, block_tables, write_pos, cv.shape[1])
+        o = attention(q, k, v, causal=True)
+        x = x + weight_einsum("bshk,hkd->bsd", o, lp["wo"])
+        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+        return x + runner._mlp(h, lp, cfg, write_pos >= 0, experts)[0], (
+            ck, cv)
+
+    x, (cache_k, cache_v) = jax.lax.scan(
+        layer, x, (layers, cache_k, cache_v, lora_xs))
+    last = jnp.take_along_axis(
+        x, (prompt_lens - 1)[:, None, None], axis=1)[:, 0]
+    last = rms_norm(last, params["final_norm"], cfg.norm_eps)
+    return runner._lm_logits(last, params, cfg), cache_k, cache_v
+
+
+@pytest.mark.parametrize("case", ["dense", "experts-qk-norm", "lora-slot"])
+def test_prefill_scatters_its_rows_once_and_touches_no_other_page(
+        tiny_params, case):
+    """Whole-prompt prefill keeps the page pool out of its layer scan:
+    the layers hand their rows out and one scatter writes them. Against
+    the layer-by-layer reference, for two right-padded prompts of
+    different lengths in a pool that holds other sequences' pages:
+    (a) every page but the dump page is bit-equal to ``_write_pages``';
+    (b) no page outside the two block tables changed, and padding rows
+    are dropped outright: the dump page did not change either;
+    (c) the logits are the reference's."""
+    import dataclasses
+
+    from ray_tpu.llm.lora import LoRAPool, init_lora_adapter
+    from ray_tpu.llm.runner import prefill
+    from ray_tpu.ops import rope_frequencies
+
+    cfg, params, lora = CFG, tiny_params, None
+    if case == "experts-qk-norm":
+        cfg = dataclasses.replace(CFG, n_experts=8, top_k=2,
+                                  norm_topk_prob=False, qk_norm=True)
+        params = init_params(jax.random.PRNGKey(3), cfg)
+        layers = dict(params["layers"])
+        for i, name in enumerate(("q_norm", "k_norm")):
+            layers[name] = 1 + 0.3 * jax.random.normal(
+                jax.random.PRNGKey(4 + i), layers[name].shape)
+        params = dict(params, layers=layers)
+    if case == "lora-slot":
+        adapter = init_lora_adapter(jax.random.PRNGKey(3), cfg, 4,
+                                    dtype=cfg.dtype)
+        for i, name in enumerate(("b_q", "b_v")):
+            adapter[name] = 0.3 * jax.random.normal(
+                jax.random.PRNGKey(4 + i), adapter[name].shape, cfg.dtype)
+        pool = LoRAPool(cfg, 4, 2, dtype=cfg.dtype)
+        lora = pool.select([pool.add("tuned", adapter), 0])
+
+    page, n_pages, S = 4, 12, 16
+    lens = jnp.asarray([9, 6], jnp.int32)       # 3 pages (1 row in the
+    tables = jnp.asarray([[3, 5, 9, 0],         # last), 2 pages (2 rows)
+                          [2, 6, 0, 0]], jnp.int32)
+    tokens = jax.random.randint(jax.random.PRNGKey(7), (2, S), 0, cfg.vocab)
+    shape = (cfg.n_layers, n_pages, page, cfg.n_kv_heads, cfg.head_dim)
+    # every page already holds something: another sequence's rows
+    before_k = np.asarray(jax.random.normal(jax.random.PRNGKey(8), shape))
+    before_v = np.asarray(jax.random.normal(jax.random.PRNGKey(9), shape))
+    cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
+
+    want_logits, want_k, want_v = _prefill_layer_by_layer(
+        params, jnp.asarray(before_k), jnp.asarray(before_v), tokens, lens,
+        tables, cos, sin, lora, cfg=cfg)
+    logits, got_k, got_v, counts = prefill(
+        params, jnp.asarray(before_k), jnp.asarray(before_v), tokens, lens,
+        tables, cos, sin, lora, cfg=cfg)
+
+    mine = [2, 3, 5, 6, 9]
+    others = [p for p in range(1, n_pages) if p not in mine]
+    for got, want, before in ((got_k, want_k, before_k),
+                              (got_v, want_v, before_v)):
+        got, want = np.asarray(got), np.asarray(want)
+        assert got.dtype == before.dtype
+        np.testing.assert_array_equal(got[:, 1:], want[:, 1:])      # (a)
+        np.testing.assert_array_equal(got[:, others], before[:, others])
+        np.testing.assert_array_equal(got[:, 0], before[:, 0])      # (b)
+        # the reference did write: 9 + 6 rows a layer differ from before
+        changed = (got != before).any(axis=(-1, -2))      # [L, P, page]
+        assert changed[:, mine].sum() == cfg.n_layers * 15
+        assert (want[:, 0] != before[:, 0]).any()   # its padding: page 0
+    np.testing.assert_array_equal(np.asarray(logits),
+                                  np.asarray(want_logits))          # (c)
+    assert (counts is None) == (case != "experts-qk-norm")
+    if counts is not None:      # real tokens x top_k x layers, no padding
+        assert int(counts[0]) == 15 * cfg.top_k * cfg.n_layers

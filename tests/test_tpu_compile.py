@@ -61,6 +61,12 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _on_chip(tree, sharding):
+    """A pytree of arrays or shapes -> the same shapes placed on the
+    described chip."""
+    return jax.tree.map(lambda a: _sds(a.shape, a.dtype, sharding), tree)
+
+
 def _custom_calls(fn, *args) -> int:
     return jax.jit(fn).lower(*args).compile().as_text().count(
         "tpu_custom_call")
@@ -138,14 +144,10 @@ def test_decode_burst_8b_int8_fits_one_v5e(one_chip):
     cfg = LLAMA_CONFIGS["8b"]
     B, K, page, n_pages, max_seq = 8, 8, 64, 129, 1024
 
-    def on_chip(tree):
-        return jax.tree.map(
-            lambda a: _sds(a.shape, a.dtype, one_chip), tree)
-
-    params = on_chip(jax.eval_shape(
-        lambda: init_params_quantized(jax.random.PRNGKey(0), cfg)))
-    cos, sin = on_chip(jax.eval_shape(
-        lambda: rope_frequencies(cfg.head_dim, max_seq, cfg.rope_theta)))
+    params = _on_chip(jax.eval_shape(
+        lambda: init_params_quantized(jax.random.PRNGKey(0), cfg)), one_chip)
+    cos, sin = _on_chip(jax.eval_shape(lambda: rope_frequencies(
+        cfg.head_dim, max_seq, cfg.rope_theta)), one_chip)
     cache = _sds((cfg.n_layers, n_pages, page, cfg.n_kv_heads,
                   cfg.head_dim), cfg.dtype, one_chip)
     i32 = _sds((B,), jnp.int32, one_chip)
@@ -160,6 +162,54 @@ def test_decode_burst_8b_int8_fits_one_v5e(one_chip):
     live = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert 8 * 1024**3 < live < V5E_HBM_BYTES, mem
+
+
+# the two served configurations of BENCHMARK.json at their published
+# widths and depths: LlamaConfig fields, then the engine's prefill bucket
+# (its max_seq_len); both pools are 257 pages of 64
+SERVED_PREFILLS = {
+    "mistral-7b-int8-bucket1024": (dict(
+        vocab=32768, dim=4096, n_layers=32, n_heads=32, n_kv_heads=8,
+        mlp_dim=14336, max_seq=32768, rope_theta=1e6), 1024),
+    "olmoe-1b-7b-int8-bucket2048": (dict(
+        vocab=50304, dim=2048, n_layers=16, n_heads=16, n_kv_heads=16,
+        mlp_dim=1024, max_seq=4096, rope_theta=1e4, n_experts=64, top_k=8,
+        norm_topk_prob=False, qk_norm=True), 2048),
+}
+
+
+@pytest.mark.parametrize("widths, bucket", SERVED_PREFILLS.values(),
+                         ids=SERVED_PREFILLS)
+def test_prefill_sample_keeps_the_page_pool_out_of_its_layer_scan(
+        one_chip, widths, bucket):
+    """Whole-prompt prefill writes one prompt's rows: the donated pools
+    must alias their outputs, and the program may hold no temporary of a
+    pool's size (a pool that rides the layer scan as xs/ys is copied
+    whole: 2.36 GB of temporaries at OLMoE's bucket, PERF.md section 4)."""
+    from ray_tpu.llm.runner import prefill_sample
+    from ray_tpu.models import LlamaConfig
+    from ray_tpu.ops import rope_frequencies
+    from ray_tpu.ops.quant import init_params_quantized
+
+    cfg = LlamaConfig(**widths)
+    page, n_pages = 64, 257
+
+    params = _on_chip(jax.eval_shape(
+        lambda: init_params_quantized(jax.random.PRNGKey(0), cfg)), one_chip)
+    cos, sin = _on_chip(jax.eval_shape(lambda: rope_frequencies(
+        cfg.head_dim, cfg.max_seq, cfg.rope_theta)), one_chip)
+    cache = _sds((cfg.n_layers, n_pages, page, cfg.n_kv_heads,
+                  cfg.head_dim), cfg.dtype, one_chip)
+    pool = cache.size * cache.dtype.itemsize
+    assert pool == 257 * 64 * 128 * 1024 // 2      # 128 KiB a token, K or V
+    one_i = _sds((1,), jnp.int32, one_chip)
+    one_f = _sds((1,), jnp.float32, one_chip)
+    mem = prefill_sample.lower(
+        params, cache, cache, _sds((1, bucket), jnp.int32, one_chip), one_i,
+        _sds((1, bucket // page), jnp.int32, one_chip), cos, sin, 0, one_f,
+        one_i, one_f, None, cfg=cfg, greedy=True).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * pool, mem
+    assert mem.temp_size_in_bytes < pool, mem
 
 
 def test_sharded_train_step_keeps_flash_kernels(topo):
