@@ -45,9 +45,8 @@ import sys
 import time
 
 QUICK = "--quick" in sys.argv
-# --moderate: the depths bench.py embeds (bounded wall clock inside the
-# driver's bench run); the standalone full-depth record is
-# ENVELOPE_r05.json, produced by running this script with no flag
+# --moderate: shallower depths for a bounded wall clock; no flag runs
+# every family at full depth (a CPU script run by hand, no record kept)
 MODERATE = "--moderate" in sys.argv
 FAMILIES = [a for a in sys.argv[1:] if not a.startswith("--")]
 

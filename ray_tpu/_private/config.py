@@ -335,34 +335,6 @@ class Config:
     # 0 leaves MFU unreported (v5p ~459e12, v5e ~197e12)
     train_peak_flops_per_chip: float = 0.0
     # --- device plane ---
-    # Serving decode attention: stream KV pages through the Pallas
-    # paged-attention kernel (ops/paged_attention.py) instead of the
-    # XLA jnp.take gather. Measured r3 on 1x v5e (llama-400m, B=16,
-    # burst=32, ~300-token contexts): kernel ~400 tok/s vs gather
-    # ~1050-1130 tok/s, with both a scanned and an UNROLLED layer loop —
-    # at short contexts (~5 pages/seq) the kernel's per-page sequential
-    # DMAs and skinny [rep, page] matmuls lose to one big fused gather
-    # einsum. Re-measured r3 on 1x v5e across ctx 512..8192 (B=4,
-    # burst=32): the gather path wins at EVERY length — our kernel is
-    # 0.69x..0.18x of gather, and even jax's production
-    # pallas.ops.tpu.paged_attention (multi-page compute blocks,
-    # pipelined DMA) is 0.8x of gather at ctx=8192 (5.6 vs 6.9 ms per
-    # 24-layer step). The burst design gathers ONCE per 32-step burst,
-    # so per-step attention reads a contiguous layout at streaming
-    # bandwidth; paged kernels only pay off when the gather copy itself
-    # is unaffordable (HBM headroom), not for speed at these shapes.
-    llm_paged_kernel: bool = False
-    # Auto-select: when llm_paged_kernel is off, a decode round whose
-    # bucketed block-table span is >= this many pages uses the Pallas
-    # kernel anyway (0 disables auto-select). The span is a static shape
-    # (engine buckets it), so each (span, path) pair is its own compiled
-    # executable — flipping per round costs nothing at steady state.
-    # Re-measured r4 at TRUE 8k occupancy (400m, B=4, ctx=7650, 120/120
-    # pages resident, v5e): gather 486 tok/s vs paged kernel 127 tok/s
-    # — the burst design's once-per-32-steps contiguous gather beats
-    # per-step paged DMA at every feasible occupancy on this chip, so
-    # auto-select stays disabled BY MEASUREMENT, not by default.
-    llm_paged_kernel_min_ctx_pages: int = 0
     # bind host for the per-process PJRT transfer server backing
     # DeviceChannel (experimental/device_channel.py); must be routable
     # from peer hosts — "" = loopback (single host). TPU pods set the

@@ -7,8 +7,9 @@ STATIC shape [layers, pages, page_size, kv_heads, head_dim] living in HBM
 — XLA-friendly (no dynamic allocation inside jit) with all paging
 decisions made host-side by a free-list allocator.
 
-Page 0 is reserved as the *dump page*: padded scatter lanes write there so
-the jitted kernels never branch on validity; it is never handed out.
+Page 0 is reserved and never handed out: block tables are padded with 0,
+so the gathers read it (under a mask). Nothing writes to it: rows that are
+not tokens are dropped by the one scatter (``runner._write_rows``).
 """
 
 from __future__ import annotations

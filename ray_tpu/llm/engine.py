@@ -46,7 +46,7 @@ from .sampling import SamplingParams
 class EngineConfig:
     max_num_seqs: int = 8           # decode slots (static batch width)
     page_size: int = 16
-    num_pages: int = 512            # incl. reserved dump page 0
+    num_pages: int = 512            # incl. the reserved page 0
     max_seq_len: int = 2048
     kv_dtype: Any = None            # default: model dtype
     # decode steps fused into one device dispatch (multi-step
@@ -57,14 +57,12 @@ class EngineConfig:
     # prompts in chunks of this many tokens, interleaving decode bursts
     # between chunks so a long prompt doesn't stall running streams for
     # its whole prefill; also one compiled executable per (chunk, span)
-    # instead of per pow-2 prompt bucket. Measured r3 on 1x v5e
-    # (llama-400m, 3.5k prompt arriving into a live decode stream,
-    # chunk=512): running stream's worst inter-token gap ~5800ms -> ~370ms
-    # (novel-shape prefill compiles are the big spike chunking removes),
-    # long prompt's own TTFT ~320ms -> ~1300ms. Chunked vs whole-prompt
-    # logits agree to bf16 precision (argmax/top-5 identical; greedy
-    # token streams may diverge after many steps, as between any two
-    # correct bf16 attention implementations). 0 = whole-prompt.
+    # instead of per pow-2 prompt bucket. What it buys a running stream
+    # and costs the long prompt: not measured (no cell runs it, ROADMAP
+    # S10). Chunked vs whole-prompt logits agree to bf16 precision
+    # (argmax/top-5 identical; greedy token streams may diverge after
+    # many steps, as between any two correct bf16 attention
+    # implementations). 0 = whole-prompt.
     prefill_chunk: int = 0
     # finished RequestStates kept for inspection before FIFO eviction
     # (callers that stream from step() outputs never need them)
@@ -135,19 +133,10 @@ class LLMEngine:
     def __init__(self, params, cfg: LlamaConfig,
                  engine_config: Optional[EngineConfig] = None):
         self.cfg = cfg
-        from .._private.config import global_config
-
-        # resolved once per engine: a static jit arg, so the flag is
-        # part of every decode executable's cache key
-        self._paged_kernel = bool(global_config().llm_paged_kernel)
-        # auto-select threshold (pages): long-context rounds stream
-        # pages through the Pallas kernel even when the flag is off
-        self._paged_min_pages = int(
-            getattr(global_config(), "llm_paged_kernel_min_ctx_pages", 0))
         self.ecfg = engine_config or EngineConfig()
         if self.ecfg.max_seq_len > cfg.max_seq:
             raise ValueError("engine max_seq_len exceeds model max_seq")
-        usable = self.ecfg.num_pages - 1  # page 0 is the dump page
+        usable = self.ecfg.num_pages - 1  # page 0 is reserved
         need = -(-self.ecfg.max_seq_len // self.ecfg.page_size)
         if need > usable:
             # guarantees a lone running sequence can always grow to
@@ -735,17 +724,13 @@ class LLMEngine:
                 for s2 in active_states:
                     ids[s2.slot] = self.lora_pool.slot_of(s2.model_id)
                 lora = self.lora_pool.select(ids)
-            span = self._active_span()
-            use_paged = self._paged_kernel or (
-                self._paged_min_pages > 0
-                and span >= self._paged_min_pages)
             toks, ck, cv, counts = decode_burst(
                 self.params, self.cache.k, self.cache.v,
                 jnp.asarray(tokens), jnp.asarray(positions),
-                self._bt(span),
+                self._bt(self._active_span()),
                 jnp.asarray(active), self.cos, self.sin,
                 seed, temp, top_k, top_p, lora, cfg=self.cfg, n_steps=K,
-                paged_kernel=use_paged, greedy=greedy)
+                greedy=greedy)
         self.cache = KVCache(ck, cv)
         with self._phase("decode.sync"):
             sampled = self._read_back(toks, counts)  # [K, B]

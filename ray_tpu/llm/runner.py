@@ -1,44 +1,61 @@
-"""Model runner: jitted prefill + single-token decode over a paged KV
-cache, for the Llama family.
+"""Model runner: the serving decoder layer, written once, and the four
+jitted programs that run it over a paged KV cache.
 
-TPU-first shape discipline (everything static under jit):
-  * prefill pads the prompt to a power-of-2 bucket — one compiled
-    executable per bucket, reused across requests;
-  * decode runs the WHOLE slot batch [max_seqs] every step, inactive
-    slots masked (their writes land on dump page 0) — one executable for
-    the life of the engine;
-  * cache buffers are donated, so XLA updates pages in place (no
-    O(cache) copy per step) — and, HBM discipline, the big cache stays
-    out of the scans that would copy it: a scan's stacked output is not
-    aliased to its stacked input, so a pool that rides one is rewritten
-    whole. ``prefill`` (whole prompts) and ``decode_burst`` hand their
-    new K/V rows out of their scans and scatter them into the donated
-    pools once at the end; ``prefill_chunk`` and ``verify_step`` still
-    take the pool through their layer scans (they read back the pages
-    they write, layer by layer: ROADMAP S10).
+One block. ``_block`` is the decoder layer: norm, the three projections
+(plus a slot's LoRA deltas where adapter rows are given), QK-norm,
+rotary, attention, ``wo``, norm, the feed-forward (dense SwiGLU or the
+dropless routed experts); ``_layers`` makes the one ``lax.scan`` of it
+over the stacked layers. A program supplies ``attend(q, k, v, state)``,
+how the layer reaches the cache, and nothing else reaches it:
+  * ``prefill`` (whole prompts; ``prefill_sample`` fuses the sampler)
+    attends over the prompt's own k and v, reads no page, and hands the
+    rows out of the scan; one scatter writes all layers' rows at the end.
+  * ``prefill_chunk`` (one chunk of a long prompt) writes the chunk's
+    rows into the layer's pages, gathers the table's span, and attends
+    over (the span below the chunk's start; the chunk's own rows).
+  * ``verify_step`` (a speculative window) writes the window's rows,
+    gathers the span, and attends over it under key position <= query
+    position: the window sees its own keys through the pages.
+  * ``decode_burst`` (n fused decode+sample steps) gathers the span once
+    a burst for all layers; step i puts its row into a burst scratch and
+    attends over (span; scratch up to i); one scatter writes the scratch
+    at the end.
+A scan's stacked output is not aliased to its stacked input, so a pool
+that rides a layer scan is rewritten whole: ``prefill`` and
+``decode_burst`` keep it out, ``prefill_chunk`` and ``verify_step``
+still take it through (ROADMAP S10: a change to their two closures).
 
-The decode attention gathers pages with jnp.take (XLA fuses the gather
-into the attention when it can); a Pallas in-place kernel is the upgrade
-path once shapes are pinned. Reference analog: the vLLM paged-attention
-CUDA kernels behind ray.llm's vllm_engine (SURVEY §2.4) — rebuilt here
-natively since the reference delegates all device work to vLLM.
+One scatter, one convention. ``_write_rows`` is the only place a page is
+written. A row that is not a token (bucket padding, a chunk's tail, a
+window's -1 positions, an inactive slot) carries the out-of-range page
+index ``num_pages`` and ``mode="drop"`` writes nothing for it. Page 0
+stays reserved: block tables are padded with 0 and the gathers read it
+under a mask; nothing writes to it.
+
+Static shapes throughout: prefill pads a prompt to a power-of-2 bucket
+(one executable a bucket), decode runs the whole slot batch every step
+with inactive slots masked, and the cache buffers are donated, so the
+scatters update pages in place. Reference analog: the vLLM
+paged-attention CUDA kernels behind ray.llm's vllm_engine (SURVEY §2.4),
+rebuilt natively since the reference delegates all device work to vLLM.
 """
 
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..models.llama import LlamaConfig, qk_norm
-from ..ops import apply_rotary, attention, rms_norm, rope_frequencies
+from ..ops import apply_rotary, attention, rms_norm
 from ..ops.quant import embed_lookup, is_quantized, weight_einsum
-from .cache import KVCache
+from .lora import lora_delta
+from .sampling import sample_from_logits
 
 
 _EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+_POOLS = ("cache_k", "cache_v")       # donated: the scatters run in place
 
 
 def _split_layers(layers, cfg: LlamaConfig):
@@ -82,116 +99,175 @@ def _total(counts):
     return None if counts is None else counts.sum(0)
 
 
-def _lm_logits(x_last, params, cfg: LlamaConfig):
-    """Final-norm'd hidden -> f32 logits, raw or int8 lm_head. bf16
-    operands on the MXU with f32 accumulation either way."""
+def _head(x, params, cfg: LlamaConfig):
+    """Hidden [..., d] -> final norm -> f32 logits [..., vocab], raw or
+    int8 lm_head. bf16 operands on the MXU with f32 accumulation either
+    way."""
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     lm = params["lm_head"]
     if not is_quantized(lm):
         lm = lm.astype(cfg.dtype)
-    return weight_einsum("bd,dv->bv", x_last.astype(cfg.dtype), lm,
+    return weight_einsum("...d,dv->...v", x.astype(cfg.dtype), lm,
                          preferred_element_type=jnp.float32)
 
 
-def _write_pages(cache_layer, new, block_tables, positions, page_size):
-    """Scatter per-token K or V rows into their pages.
+def _pick(logits, greedy, seed, temperature, top_k, top_p):
+    """``greedy=True`` (every request temperature==0) compiles an
+    argmax-only epilogue: bit-identical results for greedy requests, and
+    a program without the top_k/sort/categorical sampler. Whether the
+    fork pays is not measured (ROADMAP R6 prices it)."""
+    if greedy:
+        return jnp.argmax(logits, axis=-1)
+    return sample_from_logits(logits, seed, temperature, top_k, top_p)
 
-    cache_layer: [P, page, kvh, hd]; new: [B, S, kvh, hd];
-    block_tables: [B, max_pages]; positions: [B, S] absolute positions
-    (negative = padding -> routed to dump page 0).
+
+def _write_rows(pools, rows, block_tables, positions, valid):
+    """THE scatter: K and V rows into their pages (in place when the
+    pools are donated).
+
+    pools: the (K, V) pair, each [..., P, page, kvh, hd]; rows: the
+    (K, V) pair, each [..., B, S, kvh, hd] with the pools' leading
+    dimensions (none: one layer's pools; L: all layers at once);
+    block_tables: [B, max_pages]; positions: [B, S] absolute; valid:
+    broadcastable to [B, S]. A row that is not a token is given the
+    out-of-range page P and dropped: it changes no page.
     """
-    B, S = new.shape[:2]
-    page_idx = jnp.take_along_axis(
-        block_tables, jnp.maximum(positions, 0) // page_size, axis=1)
-    valid = positions >= 0
-    page_idx = jnp.where(valid, page_idx, 0)           # dump page
-    offset = jnp.where(valid, positions % page_size, 0)
-    flat_pages = page_idx.reshape(-1)                  # [B*S]
-    flat_off = offset.reshape(-1)
-    flat_new = new.reshape(B * S, *new.shape[2:])
-    return cache_layer.at[flat_pages, flat_off].set(
-        flat_new.astype(cache_layer.dtype), mode="drop")
+    n_pages, page_size = pools[0].shape[-4:-2]
+    page = jnp.take_along_axis(block_tables, positions // page_size, axis=1)
+    fp = jnp.where(valid, page, n_pages).reshape(-1)           # [B*S]
+    fo = (positions % page_size).reshape(-1)
+    return tuple(
+        pool.at[..., fp, fo, :, :].set(
+            r.reshape(*r.shape[:-4], -1, *r.shape[-2:]).astype(pool.dtype),
+            mode="drop")
+        for pool, r in zip(pools, rows))
 
 
-@partial(jax.jit, static_argnames=("cfg",), donate_argnames=("cache_k",
-                                                             "cache_v"))
-def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
-            cos, sin, lora=None, *, cfg: LlamaConfig):
-    """Process full prompts, fill their pages, return last-token logits.
+def _gather_span(pool, block_tables):
+    """The pages a table lists, side by side: pool [..., P, page, kvh,
+    hd] -> [..., B, max_pages * page, kvh, hd]. A table's unused slots
+    are 0 and read page 0: the caller masks by position."""
+    lead = pool.shape[:-4]
+    B, n = block_tables.shape
+    return jnp.take(pool, block_tables, axis=len(lead)).reshape(
+        *lead, B, n * pool.shape[-3], *pool.shape[-2:])
 
-    tokens: [B, S] right-padded; prompt_lens: [B]; block_tables: [B, Pmax].
-    ``lora``: per-slot batched adapters from LoRAPool.select(ids) —
-    low-rank deltas on wq/wv (llm/lora.py), empty/None = base model.
 
-    HBM discipline (as ``decode_burst``): the big cache never rides the
-    layer scan. A scan's stacked output is not aliased to its stacked
-    input, so a pool passed through as xs/ys is read and rewritten whole
-    (every page of every layer, a second pool among the temporaries) to
-    write one prompt's rows. Attention here runs on the prompt's own
-    k and v and never reads a page, so each layer only hands its K and V
-    rows out of the scan ([L, B, S, kvh, hd], in the cache's dtype) and
-    they scatter into the donated pools ONCE at the end, in place.
-    Padding rows (position >= prompt_len) carry an out-of-range page
-    index and are dropped: they change no page, the dump page neither.
+def _attend(q, *segments):
+    """Grouped-query attention over keys that lie in segments (a cached
+    span; rows the cache does not hold yet), one softmax over all.
 
-    Returns (logits [B, vocab], cache_k, cache_v, expert counts: see
-    ``_mlp``; None for a dense config).
+    q: [B, ..., heads, hd], with or without a query axis; a segment:
+    (keys [B, S, kvh, hd], values, mask broadcastable to [B, ..., S]).
+    The operands go to the MXU in their own dtype with f32 accumulation.
+    Returns f32, in q's shape.
     """
-    from .lora import lora_delta
+    hd = q.shape[-1]
+    kvh = segments[0][0].shape[2]
+    qg = q.reshape(*q.shape[:-2], kvh, q.shape[-2] // kvh, hd)
+    s = jnp.concatenate([
+        jnp.where(mask[..., None, None, :],
+                  jnp.einsum("b...grd,bsgd->b...grs", qg, keys,
+                             preferred_element_type=jnp.float32)
+                  * hd ** -0.5, -jnp.inf)
+        for keys, _, mask in segments], axis=-1)
+    p = jax.nn.softmax(s, axis=-1).astype(segments[0][0].dtype)
+    outs, at = [], 0
+    for _, values, _ in segments:
+        end = at + values.shape[1]
+        outs.append(jnp.einsum("b...grs,bsgd->b...grd", p[..., at:end],
+                               values, preferred_element_type=jnp.float32))
+        at = end
+    return sum(outs[1:], outs[0]).reshape(q.shape)
 
-    B, S = tokens.shape
-    x = embed_lookup(params["embed"], tokens, cfg.dtype)
-    pos_grid = jnp.arange(S)[None, :].repeat(B, 0)
-    valid = pos_grid < prompt_lens[:, None]                    # [B, S]
+
+def _block(x, inputs, *, cfg: LlamaConfig, cos, sin, positions, valid,
+           attend, experts, lora_scale):
+    """The decoder layer, once, as the body of a scan over layers.
+
+    x: [B, S, d]; inputs: (the layer's weights, the layer's slice of the
+    program's own state, the layer's per-slot adapter rows: low-rank
+    deltas on wq/wv, llm/lora.py, empty = base model); positions: [B, S]
+    rotary positions, None = 0..S-1; valid: [B, S], the rows that are
+    tokens; ``attend(q, k, v, state) -> (o [B, S, heads, hd], kept)``.
+    Returns (x, (kept, expert counts: see ``_mlp``)).
+    """
+    lp, state, lr = inputs
+    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    q = weight_einsum("bsd,dhk->bshk", h, lp["wq"])
+    k = weight_einsum("bsd,dhk->bshk", h, lp["wk"])
+    v = weight_einsum("bsd,dhk->bshk", h, lp["wv"])
+    if lr:
+        q = q + lora_delta(h, lr["a_q"], lr["b_q"], lora_scale,
+                           cfg.n_heads, cfg.head_dim)
+        v = v + lora_delta(h, lr["a_v"], lr["b_v"], lora_scale,
+                           cfg.n_kv_heads, cfg.head_dim)
+    q, k = qk_norm(q, k, lp, cfg)
+    q = apply_rotary(q, cos, sin, positions=positions)
+    k = apply_rotary(k, cos, sin, positions=positions)
+    o, kept = attend(q, k, v, state)
+    x = x + weight_einsum("bshk,hkd->bsd", o.astype(x.dtype), lp["wo"])
+    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+    m, counts = _mlp(h, lp, cfg, valid, experts)
+    return x + m, (kept, counts)
+
+
+def _layers(params, cfg: LlamaConfig, cos, sin, lora=None):
+    """-> ``run(x, state, attend, *, positions, valid)``: ONE
+    ``lax.scan`` of ``_block`` over the stacked layers, returning (x,
+    what ``attend`` kept [L, ...], expert counts over the layers).
+    ``state``: the program's own per-layer pytree (leading dimension L),
+    or None. ``lora``: per-slot batched adapters from
+    ``LoRAPool.select(ids)``, empty/None = base model. What every call
+    shares is made here once: a burst calls ``run`` at every step."""
+    layers, experts = _split_layers(params["layers"], cfg)
     # adapters ride the layer scan as xs: [B, L, ...] -> [L, B, ...]
     lora_xs = {} if not lora else {
         k2: jnp.swapaxes(v2, 0, 1) for k2, v2 in lora.items()
         if k2 != "scale"}
 
-    def layer(x, inputs):
-        lp, lr = inputs
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = weight_einsum("bsd,dhk->bshk", h, lp["wq"])
-        k = weight_einsum("bsd,dhk->bshk", h, lp["wk"])
-        v = weight_einsum("bsd,dhk->bshk", h, lp["wv"])
-        if lr:
-            q = q + lora_delta(h, lr["a_q"], lr["b_q"], lora["scale"],
-                               cfg.n_heads, cfg.head_dim)
-            v = v + lora_delta(h, lr["a_v"], lr["b_v"], lora["scale"],
-                               cfg.n_kv_heads, cfg.head_dim)
-        q, k = qk_norm(q, k, lp, cfg)
-        q = apply_rotary(q, cos, sin)
-        k = apply_rotary(k, cos, sin)
+    def run(x, state, attend, *, positions, valid):
+        x, (kept, counts) = jax.lax.scan(
+            partial(_block, cfg=cfg, cos=cos, sin=sin, positions=positions,
+                    valid=valid, attend=attend, experts=experts,
+                    lora_scale=lora["scale"] if lora else None),
+            x, (layers, state, lora_xs))
+        return x, kept, _total(counts)
+
+    return run
+
+
+@partial(jax.jit, static_argnames=("cfg",), donate_argnames=_POOLS)
+def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
+            cos, sin, lora=None, *, cfg: LlamaConfig):
+    """Process full prompts, fill their pages, return last-token logits.
+
+    tokens: [B, S] right-padded; prompt_lens: [B]; block_tables: [B, Pmax];
+    ``lora``: see ``_layers``. The layers hand their K and V rows out of
+    the scan ([L, B, S, kvh, hd], in the cache's dtype); padding rows
+    (position >= prompt_len) are dropped.
+
+    Returns (logits [B, vocab], cache_k, cache_v, expert counts: see
+    ``_mlp``; None for a dense config).
+    """
+    B, S = tokens.shape
+    x = embed_lookup(params["embed"], tokens, cfg.dtype)
+    pos_grid = jnp.arange(S)[None, :].repeat(B, 0)
+    valid = pos_grid < prompt_lens[:, None]                    # [B, S]
+
+    def attend(q, k, v, _):
         # right padding is safe under the causal mask: a real position
         # only attends to earlier (real) positions
-        o = attention(q, k, v, causal=True)
-        x = x + weight_einsum("bshk,hkd->bsd", o, lp["wo"])
-        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        m, n = _mlp(h, lp, cfg, valid, experts)
-        return x + m, (k.astype(cache_k.dtype), v.astype(cache_v.dtype), n)
+        return attention(q, k, v, causal=True), (
+            k.astype(cache_k.dtype), v.astype(cache_v.dtype))
 
-    layers, experts = _split_layers(params["layers"], cfg)
-    x, (rows_k, rows_v, counts) = jax.lax.scan(layer, x, (layers, lora_xs))
-
-    # one scatter of all layers' rows into the paged cache (donated ->
-    # in-place); page index num_pages is out of range, so mode="drop"
-    # writes nothing for a padding row
-    n_pages, page_size = cache_k.shape[1:3]
-    page_idx = jnp.take_along_axis(block_tables, pos_grid // page_size,
-                                   axis=1)
-    fp = jnp.where(valid, page_idx, n_pages).reshape(-1)       # [B*S]
-    fo = (pos_grid % page_size).reshape(-1)
-
-    def put(cache, rows):                      # rows: [L, B, S, kvh, hd]
-        return cache.at[:, fp, fo].set(
-            rows.reshape(rows.shape[0], B * S, *rows.shape[3:]),
-            mode="drop")
-
-    cache_k, cache_v = put(cache_k, rows_k), put(cache_v, rows_v)
+    x, rows, counts = _layers(params, cfg, cos, sin, lora)(
+        x, None, attend, positions=None, valid=valid)
+    cache_k, cache_v = _write_rows((cache_k, cache_v), rows, block_tables,
+                                   pos_grid, valid)
     x_last = jnp.take_along_axis(
         x, jnp.maximum(prompt_lens - 1, 0)[:, None, None], axis=1)[:, 0]
-    x_last = rms_norm(x_last, params["final_norm"], cfg.norm_eps)
-    logits = _lm_logits(x_last, params, cfg)
-    return logits, cache_k, cache_v, _total(counts)
+    return _head(x_last, params, cfg), cache_k, cache_v, counts
 
 
 def prefill_bucket(seq_len: int, max_seq: int, floor: int = 16) -> int:
@@ -202,86 +278,43 @@ def prefill_bucket(seq_len: int, max_seq: int, floor: int = 16) -> int:
     return min(b, max_seq)
 
 
-@partial(jax.jit, static_argnames=("cfg",), donate_argnames=("cache_k",
-                                                             "cache_v"))
+@partial(jax.jit, static_argnames=("cfg",), donate_argnames=_POOLS)
 def prefill_chunk(params, cache_k, cache_v, tokens, start_pos, chunk_len,
                   block_tables, cos, sin, *, cfg: LlamaConfig):
     """One CHUNK of a long prompt (vLLM's chunked prefill, rebuilt for
-    static shapes): tokens [1, C] are positions
-    [start_pos, start_pos+chunk_len), attending causally within the
-    chunk AND over the pages written by earlier chunks. One compiled
-    executable per (C, table-span) pair serves prompts of every length —
-    and decode bursts for other requests interleave between chunks, so a
-    long prompt no longer stalls running streams for its whole prefill.
+    static shapes): tokens [1, C] are positions [start_pos,
+    start_pos+chunk_len), attending causally within the chunk AND over
+    the pages written by earlier chunks. One compiled executable per (C,
+    table-span) pair serves prompts of every length, and decode bursts
+    for other requests interleave between chunks.
 
     Returns (logits [1, vocab] of the chunk's LAST VALID token,
     cache_k, cache_v, expert counts as ``prefill``).
     """
     B, C = tokens.shape
-    page_size = cache_k.shape[2]
-    Spast = block_tables.shape[1] * page_size
+    Spast = block_tables.shape[1] * cache_k.shape[2]
     x = embed_lookup(params["embed"], tokens, cfg.dtype)
     pos_grid = start_pos + jnp.arange(C)[None, :]          # [1, C]
     valid = jnp.arange(C)[None, :] < chunk_len
-    write_pos = jnp.where(valid, pos_grid, -1)
     # past pages hold positions < start_pos (written by earlier chunks)
-    past_mask = jnp.arange(Spast)[None, :] < start_pos     # [1, Spast]
+    past_mask = jnp.arange(Spast)[None, None, :] < start_pos
     chunk_mask = (jnp.arange(C)[None, :, None]
                   >= jnp.arange(C)[None, None, :]) & valid[:, None, :]
 
-    def layer(x, inputs):
-        lp, ck, cv = inputs
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = weight_einsum("bsd,dhk->bshk", h, lp["wq"])
-        k = weight_einsum("bsd,dhk->bshk", h, lp["wk"])
-        v = weight_einsum("bsd,dhk->bshk", h, lp["wv"])
-        q, k = qk_norm(q, k, lp, cfg)
-        q = apply_rotary(q, cos, sin, positions=pos_grid)
-        k = apply_rotary(k, cos, sin, positions=pos_grid)
-        ck = _write_pages(ck, k, block_tables, write_pos, page_size)
-        cv = _write_pages(cv, v, block_tables, write_pos, page_size)
-        pk = jnp.take(ck, block_tables, axis=0).reshape(
-            B, Spast, *k.shape[2:])
-        pv = jnp.take(cv, block_tables, axis=0).reshape(
-            B, Spast, *v.shape[2:])
-        kvh, hd = cfg.n_kv_heads, cfg.head_dim
-        rep = cfg.n_heads // kvh
-        qg = q.reshape(B, C, kvh, rep, hd)
-        scale = hd ** -0.5
-        s_past = jnp.einsum("bcgrd,bsgd->bcgrs", qg, pk,
-                            preferred_element_type=jnp.float32)
-        s_self = jnp.einsum("bcgrd,btgd->bcgrt", qg, k,
-                            preferred_element_type=jnp.float32)
-        s_past = jnp.where(past_mask[:, None, None, None, :],
-                           s_past * scale, -jnp.inf)
-        s_self = jnp.where(chunk_mask[:, :, None, None, :],
-                           s_self * scale, -jnp.inf)
-        p = jax.nn.softmax(
-            jnp.concatenate([s_past, s_self], axis=-1), axis=-1
-        ).astype(pk.dtype)
-        o = (jnp.einsum("bcgrs,bsgd->bcgrd", p[..., :Spast], pv,
-                        preferred_element_type=jnp.float32)
-             + jnp.einsum("bcgrt,btgd->bcgrd", p[..., Spast:], v,
-                          preferred_element_type=jnp.float32))
-        o = o.reshape(B, C, cfg.n_heads, hd).astype(x.dtype)
-        x = x + weight_einsum("bshk,hkd->bsd", o, lp["wo"])
-        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        m, n = _mlp(h, lp, cfg, valid, experts)
-        return x + m, (ck, cv, n)
+    def attend(q, k, v, pools):
+        pools = _write_rows(pools, (k, v), block_tables, pos_grid, valid)
+        pk, pv = (_gather_span(pool, block_tables) for pool in pools)
+        return _attend(q, (pk, pv, past_mask), (k, v, chunk_mask)), pools
 
-    layers, experts = _split_layers(params["layers"], cfg)
-    x, (cache_k, cache_v, counts) = jax.lax.scan(
-        layer, x, (layers, cache_k, cache_v))
+    x, (cache_k, cache_v), counts = _layers(params, cfg, cos, sin)(
+        x, (cache_k, cache_v), attend, positions=pos_grid, valid=valid)
     idx = jnp.broadcast_to(jnp.maximum(chunk_len - 1, 0).reshape(1, 1, 1),
                            (B, 1, 1))
     x_last = jnp.take_along_axis(x, idx, axis=1)[:, 0]
-    x_last = rms_norm(x_last, params["final_norm"], cfg.norm_eps)
-    logits = _lm_logits(x_last, params, cfg)
-    return logits, cache_k, cache_v, _total(counts)
+    return _head(x_last, params, cfg), cache_k, cache_v, counts
 
 
-@partial(jax.jit, static_argnames=("cfg", "greedy"),
-         donate_argnames=("cache_k", "cache_v"))
+@partial(jax.jit, static_argnames=("cfg", "greedy"), donate_argnames=_POOLS)
 def verify_step(params, cache_k, cache_v, tokens, positions, block_tables,
                 cos, sin, seed, temperature, top_k, top_p, *,
                 cfg: LlamaConfig, greedy: bool = False):
@@ -291,279 +324,126 @@ def verify_step(params, cache_k, cache_v, tokens, positions, block_tables,
 
     tokens: [B, S] window tokens (row = [last_emitted, d_1 .. d_k]);
     positions: [B, S] absolute per-token positions, -1 = padding (rows
-    with shorter windows, undrafted slots) — padded writes land on dump
-    page 0. Every valid window token's KV is WRITTEN first, then
-    attention gathers the pages, masked by key_pos <= query_pos: the
-    window's own keys are visible through the pages (write-then-gather,
-    same discipline as prefill_chunk), stale rows from a previous
-    rejected window sit at positions > query_pos and never score.
+    with shorter windows, undrafted slots), written nowhere. Every valid
+    window token's KV is WRITTEN first, then attention gathers the
+    pages, masked by key_pos <= query_pos: the window's own keys are
+    visible through the pages, and stale rows from a previous rejected
+    window sit at positions > query_pos and never score.
 
     Returns (argmax tokens [B, S] — index j predicts the token AFTER
     window position j, sampled position-0 token [B] for rows that
     aren't greedy, cache_k, cache_v, expert counts as ``prefill``).
     """
-    from .sampling import sample_from_logits
-
-    B, S = tokens.shape
-    page_size = cache_k.shape[2]
-    Sall = block_tables.shape[1] * page_size
-    kvh, hd = cfg.n_kv_heads, cfg.head_dim
-    rep = cfg.n_heads // kvh
+    Sall = block_tables.shape[1] * cache_k.shape[2]
     x = embed_lookup(params["embed"], tokens, cfg.dtype)
+    valid = positions >= 0
     qpos = jnp.maximum(positions, 0)                       # [B, S]
-    # unused table slots are 0 (dump page) but sit past the row's
+    # unused table slots are 0 (the reserved page) but sit past the row's
     # provisioned span, so their key positions exceed every query's
     kmask = (jnp.arange(Sall)[None, None, :]
              <= qpos[:, :, None])                          # [B, S, Sall]
 
-    def layer(x, inputs):
-        lp, ck, cv = inputs
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = weight_einsum("bsd,dhk->bshk", h, lp["wq"])
-        k = weight_einsum("bsd,dhk->bshk", h, lp["wk"])
-        v = weight_einsum("bsd,dhk->bshk", h, lp["wv"])
-        q, k = qk_norm(q, k, lp, cfg)
-        q = apply_rotary(q, cos, sin, positions=qpos)
-        k = apply_rotary(k, cos, sin, positions=qpos)
-        ck = _write_pages(ck, k, block_tables, positions, page_size)
-        cv = _write_pages(cv, v, block_tables, positions, page_size)
-        pk = jnp.take(ck, block_tables, axis=0).reshape(B, Sall, kvh, hd)
-        pv = jnp.take(cv, block_tables, axis=0).reshape(B, Sall, kvh, hd)
-        qg = q.reshape(B, S, kvh, rep, hd)
-        s = jnp.einsum("bsgrd,btgd->bsgrt", qg, pk,
-                       preferred_element_type=jnp.float32)
-        s = jnp.where(kmask[:, :, None, None, :], s * (hd ** -0.5),
-                      -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1).astype(pk.dtype)
-        o = jnp.einsum("bsgrt,btgd->bsgrd", p, pv,
-                       preferred_element_type=jnp.float32)
-        o = o.reshape(B, S, cfg.n_heads, hd).astype(x.dtype)
-        x = x + weight_einsum("bshk,hkd->bsd", o, lp["wo"])
-        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        m, n = _mlp(h, lp, cfg, positions >= 0, experts)
-        return x + m, (ck, cv, n)
+    def attend(q, k, v, pools):
+        pools = _write_rows(pools, (k, v), block_tables, positions, valid)
+        pk, pv = (_gather_span(pool, block_tables) for pool in pools)
+        return _attend(q, (pk, pv, kmask)), pools
 
-    layers, experts = _split_layers(params["layers"], cfg)
-    x, (cache_k, cache_v, counts) = jax.lax.scan(
-        layer, x, (layers, cache_k, cache_v))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    lm = params["lm_head"]
-    if not is_quantized(lm):
-        lm = lm.astype(cfg.dtype)
-    logits = weight_einsum("bsd,dv->bsv", x.astype(cfg.dtype), lm,
-                           preferred_element_type=jnp.float32)
+    x, (cache_k, cache_v), counts = _layers(params, cfg, cos, sin)(
+        x, (cache_k, cache_v), attend, positions=qpos, valid=valid)
+    logits = _head(x, params, cfg)
     tgt = jnp.argmax(logits, axis=-1)                      # [B, S]
-    if greedy:
-        samp0 = tgt[:, 0]
-    else:
-        samp0 = sample_from_logits(logits[:, 0], seed, temperature,
-                                   top_k, top_p)
-    return tgt, samp0, cache_k, cache_v, _total(counts)
+    samp0 = tgt[:, 0] if greedy else sample_from_logits(
+        logits[:, 0], seed, temperature, top_k, top_p)
+    return tgt, samp0, cache_k, cache_v, counts
 
 
 @jax.jit
 def sample_logits(logits, seed, temperature, top_k, top_p):
     """Standalone sampler dispatch (the chunked-prefill tail — the
     whole-prompt path fuses sampling into prefill_sample instead)."""
-    from .sampling import sample_from_logits
-
     return sample_from_logits(logits, seed, temperature, top_k, top_p)
 
 
-# --- fused step functions: model + sampler in ONE dispatch ------------------
-# Every dispatch pays a host round trip; fusing sampling into the step
-# saves one per token (not re-measured on a locally attached chip yet).
+# --- fused step functions: model + sampler in ONE dispatch, which saves a
+# host round trip per token (what that is worth: not measured) ---
 
-@partial(jax.jit, static_argnames=("cfg", "greedy"),
-         donate_argnames=("cache_k", "cache_v"))
+@partial(jax.jit, static_argnames=("cfg", "greedy"), donate_argnames=_POOLS)
 def prefill_sample(params, cache_k, cache_v, tokens, prompt_lens,
-                   block_tables, cos, sin, seed, temperature, top_k,
-                   top_p, lora=None, *, cfg: LlamaConfig,
-                   greedy: bool = False):
-    """``greedy=True`` (every request temperature==0) compiles an
-    argmax-only epilogue — bit-identical results for greedy requests,
-    and a materially simpler program than the top_k/sort/categorical
-    sampler fused behind multi-GiB weight args. Whether the fork still
-    pays on a locally attached chip is not measured yet (ROADMAP D5)."""
-    from .sampling import sample_from_logits
-
+                   block_tables, cos, sin, seed, temperature, top_k, top_p,
+                   lora=None, *, cfg: LlamaConfig, greedy: bool = False):
+    """``prefill`` with the sampler behind it (``greedy``: see ``_pick``)."""
     logits, cache_k, cache_v, counts = prefill.__wrapped__(
         params, cache_k, cache_v, tokens, prompt_lens, block_tables,
         cos, sin, lora, cfg=cfg)
-    if greedy:
-        toks = jnp.argmax(logits, axis=-1)
-    else:
-        toks = sample_from_logits(logits, seed, temperature, top_k,
-                                  top_p)
+    toks = _pick(logits, greedy, seed, temperature, top_k, top_p)
     return toks, cache_k, cache_v, counts
 
 
-@partial(jax.jit,
-         static_argnames=("cfg", "n_steps", "paged_kernel", "greedy"),
-         donate_argnames=("cache_k", "cache_v"))
-def decode_burst(params, cache_k, cache_v, tokens, positions,
-                 block_tables, active, cos, sin, seed, temperature,
-                 top_k, top_p, lora=None, *, cfg: LlamaConfig,
-                 n_steps: int, paged_kernel: bool = None,
-                 greedy: bool = False):
+@partial(jax.jit, donate_argnames=_POOLS,
+         static_argnames=("cfg", "n_steps", "paged_kernel", "greedy"))
+def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
+                 active, cos, sin, seed, temperature, top_k, top_p,
+                 lora=None, *, cfg: LlamaConfig, n_steps: int,
+                 paged_kernel: bool = None, greedy: bool = False):
     """n_steps fused decode+sample steps, sampled tokens fed back
     ON-DEVICE (multi-step scheduling, vLLM's --num-scheduler-steps
-    analog). One host round trip yields n_steps tokens per slot, which
-    hides per-step dispatch overhead; the best depth on a locally
-    attached chip is not measured yet (ROADMAP D5).
+    analog). One host round trip yields n_steps tokens per slot; the
+    best depth is not measured (ROADMAP R6).
 
-    HBM discipline: the big cache never rides the step-scan carry (that
-    would copy it every step). The burst's new KV rows accumulate in a
-    [L, B, K] scratch; attention runs over (pages gathered once per
-    burst) + (scratch, causally masked per step); the scratch scatters
-    into the paged cache ONCE at the end. ``block_tables`` may be a
-    narrowed slice of the full table — the engine buckets it to the
-    longest active context, so KV read traffic scales with real context,
-    not max_seq_len.
+    The big cache never rides the step-scan carry (that would copy it
+    every step): the burst's rows accumulate in a [L, B, K] scratch and
+    scatter ONCE at the end, inactive slots' rows dropped.
+    ``block_tables`` may be a narrowed slice of the full table: the
+    engine buckets it to the longest active context, so KV read traffic
+    scales with real context, not max_seq_len.
 
     Returns (tokens [n_steps, B], cache_k, cache_v, expert counts over
     all steps and layers as ``prefill``). The host must have
     pre-provisioned pages for positions .. positions+n_steps-1.
     """
-    from .sampling import sample_from_logits
-
-    from .._private.config import global_config
-    from .lora import lora_delta
-
-    # static jit arg (None -> config default) so flag flips retrace
-    use_paged_kernel = (global_config().llm_paged_kernel
-                        if paged_kernel is None else paged_kernel)
-    B = tokens.shape[0]
-    K = n_steps
-    L = cfg.n_layers
-    kvh, hd = cfg.n_kv_heads, cfg.head_dim
-    rep = cfg.n_heads // cfg.n_kv_heads
-    page_size = cache_k.shape[2]
-    Sold = block_tables.shape[1] * page_size
-    if use_paged_kernel:
-        # pages stream straight through the Pallas kernel per layer —
-        # no materialized [L, B, Sold] gather copy in HBM
-        old_k = old_v = jnp.zeros((L, 0), cache_k.dtype)
-    else:
-        # old context gathered ONCE per burst (read-only during burst)
-        old_k = jnp.take(cache_k, block_tables, axis=1).reshape(
-            L, B, Sold, kvh, hd)
-        old_v = jnp.take(cache_v, block_tables, axis=1).reshape(
-            L, B, Sold, kvh, hd)
-    scratch_k = jnp.zeros((L, B, K, kvh, hd), cache_k.dtype)
-    scratch_v = jnp.zeros((L, B, K, kvh, hd), cache_v.dtype)
-    lora_xs = {} if not lora else {
-        k2: jnp.swapaxes(v2, 0, 1) for k2, v2 in lora.items()
-        if k2 != "scale"}
-    old_mask = jnp.arange(Sold)[None, :] < positions[:, None]  # [B, Sold]
-    layers, experts = _split_layers(params["layers"], cfg)
+    if paged_kernel:
+        # the keyword stays only because benchmarks/aot_fit.py:73 passes
+        # paged_kernel=False; it goes when a benchmark PR drops it there
+        raise ValueError("decode_burst has one attention path: the paged "
+                         "kernel was deleted (PR 31)")
+    B, K = tokens.shape[0], n_steps
+    # old context gathered ONCE per burst (read-only during burst), and
+    # the burst's own rows: [L, B, Sold, kvh, hd] and [L, B, K, kvh, hd]
+    old_k, old_v = (_gather_span(c, block_tables) for c in (cache_k, cache_v))
+    scratch_k, scratch_v = (
+        jnp.zeros((cfg.n_layers, B, K, *c.shape[3:]), c.dtype)
+        for c in (cache_k, cache_v))
+    old_mask = jnp.arange(old_k.shape[2])[None, :] < positions[:, None]
+    layers = _layers(params, cfg, cos, sin, lora)
 
     def step(carry, i):
         toks, sk, sv = carry
-        pos_i = positions + i
         x = embed_lookup(params["embed"], toks, cfg.dtype)[:, None, :]
         new_mask = jnp.arange(K)[None, :] <= i                 # [1, K]
 
-        def attend_gathered(qg, ok, ov, nk, nv):
-            # bf16 operands straight onto the MXU, f32 accumulation
-            s_old = jnp.einsum("bgrd,bsgd->bgrs", qg, ok,
-                               preferred_element_type=jnp.float32)
-            s_new = jnp.einsum("bgrd,bkgd->bgrk", qg, nk,
-                               preferred_element_type=jnp.float32)
-            scale = hd ** -0.5
-            s_old = jnp.where(old_mask[:, None, None, :], s_old * scale,
-                              -jnp.inf)
-            s_new = jnp.where(new_mask[None, None, :, :], s_new * scale,
-                              -jnp.inf)
-            s_all = jnp.concatenate([s_old, s_new], axis=-1)
-            p_all = jax.nn.softmax(s_all, axis=-1).astype(ok.dtype)
-            return (jnp.einsum("bgrs,bsgd->bgrd", p_all[..., :Sold], ov,
-                               preferred_element_type=jnp.float32)
-                    + jnp.einsum("bgrk,bkgd->bgrd", p_all[..., Sold:], nv,
-                                 preferred_element_type=jnp.float32))
-
-        def attend_paged(qg, ck_l, cv_l, nk, nv):
-            from ..ops.paged_attention import paged_decode_attention
-
-            return paged_decode_attention(
-                qg, ck_l, cv_l, nk, nv, block_tables, positions,
-                jnp.full((B,), i + 1, jnp.int32),
-                page_size=page_size).astype(jnp.float32)
-
-        def layer(x, inputs):
-            lp, ok, ov, nk, nv, lr = inputs
-            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-            q = weight_einsum("bsd,dhk->bshk", h, lp["wq"])
-            k = weight_einsum("bsd,dhk->bshk", h, lp["wk"])
-            v = weight_einsum("bsd,dhk->bshk", h, lp["wv"])
-            if lr:
-                q = q + lora_delta(h, lr["a_q"], lr["b_q"],
-                                   lora["scale"], cfg.n_heads, hd)
-                v = v + lora_delta(h, lr["a_v"], lr["b_v"],
-                                   lora["scale"], kvh, hd)
-            q, k = qk_norm(q, k, lp, cfg)
-            q = apply_rotary(q, cos, sin, positions=pos_i[:, None])[:, 0]
-            k = apply_rotary(k, cos, sin, positions=pos_i[:, None])[:, 0]
-            nk = jax.lax.dynamic_update_index_in_dim(
+        def attend(q, k, v, state):
+            ok, ov, nk, nv = state
+            nk = jax.lax.dynamic_update_slice_in_dim(
                 nk, k.astype(nk.dtype), i, 1)
-            nv = jax.lax.dynamic_update_index_in_dim(
-                nv, v[:, 0].astype(nv.dtype), i, 1)
-            qg = q.reshape(B, kvh, rep, hd)
-            if use_paged_kernel:
-                o = attend_paged(qg, ok, ov, nk, nv)
-            else:
-                o = attend_gathered(qg, ok, ov, nk, nv)
-            o = o.reshape(B, 1, cfg.n_heads, hd).astype(x.dtype)
-            x = x + weight_einsum("bshk,hkd->bsd", o, lp["wo"])
-            h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-            m, n = _mlp(h, lp, cfg, active[:, None], experts)
-            return x + m, (nk, nv, n)
+            nv = jax.lax.dynamic_update_slice_in_dim(
+                nv, v.astype(nv.dtype), i, 1)
+            # one query a slot: attend without the length-1 axis
+            o = _attend(q[:, 0], (ok, ov, old_mask), (nk, nv, new_mask))
+            return o[:, None], (nk, nv)
 
-        if use_paged_kernel:
-            # UNROLLED layers: a lax.scan over the cache would dynamic-
-            # slice the whole [L, P, ...] page pool per (step, layer) —
-            # measured 2.6x slower than the gather path. Static slices
-            # in an unrolled loop let XLA alias into the donated pool.
-            sks, svs, ns = [], [], []
-            for li in range(L):
-                lp_l = jax.tree.map(lambda a: a[li], layers)
-                lr_l = {k2: v2[li] for k2, v2 in lora_xs.items()}
-                x, (nk_l, nv_l, n_l) = layer(
-                    x, (lp_l, cache_k[li], cache_v[li], sk[li], sv[li],
-                        lr_l))
-                sks.append(nk_l)
-                svs.append(nv_l)
-                ns.append(n_l)
-            sk = jnp.stack(sks)
-            sv = jnp.stack(svs)
-            counts = jnp.stack(ns) if cfg.n_experts else None
-        else:
-            x, (sk, sv, counts) = jax.lax.scan(
-                layer, x, (layers, old_k, old_v, sk, sv, lora_xs))
-        h = rms_norm(x[:, 0], params["final_norm"], cfg.norm_eps)
-        logits = _lm_logits(h, params, cfg)
-        if greedy:   # see prefill_sample: argmax-only epilogue
-            newt = jnp.argmax(logits, axis=-1)
-        else:
-            newt = sample_from_logits(logits, seed + i, temperature,
-                                      top_k, top_p)
+        x, (sk, sv), counts = layers(
+            x, (old_k, old_v, sk, sv), attend,
+            positions=(positions + i)[:, None], valid=active[:, None])
+        newt = _pick(_head(x[:, 0], params, cfg), greedy, seed + i,
+                     temperature, top_k, top_p)
         newt = jnp.where(active, newt, toks)
-        return (newt, sk, sv), (newt, _total(counts))
+        return (newt, sk, sv), (newt, counts)
 
     (_, scratch_k, scratch_v), (out, counts) = jax.lax.scan(
         step, (tokens, scratch_k, scratch_v), jnp.arange(K))
-
-    # one scatter of the whole burst into the paged cache (donated ->
-    # in-place); inactive slots land on dump page 0
+    # one scatter of the whole burst into the paged cache
     p_grid = positions[:, None] + jnp.arange(K)[None, :]       # [B, K]
-    page_idx = jnp.take_along_axis(block_tables, p_grid // page_size,
-                                   axis=1)
-    valid = active[:, None]
-    page_idx = jnp.where(valid, page_idx, 0)
-    offset = jnp.where(valid, p_grid % page_size, 0)
-    fp, fo = page_idx.reshape(-1), offset.reshape(-1)          # [B*K]
-    cache_k = cache_k.at[:, fp, fo].set(
-        scratch_k.reshape(L, B * K, kvh, hd), mode="drop")
-    cache_v = cache_v.at[:, fp, fo].set(
-        scratch_v.reshape(L, B * K, kvh, hd), mode="drop")
+    cache_k, cache_v = _write_rows(
+        (cache_k, cache_v), (scratch_k, scratch_v), block_tables, p_grid,
+        active[:, None])
     return out, cache_k, cache_v, _total(counts)

@@ -229,8 +229,7 @@ class SpecDecoder:
             toks, ck, cv, _n = decode_burst(
                 self.params, ck, cv, d1, jnp.asarray(pos1), bt,
                 jnp.asarray(active), self.cos, self.sin, 0, of, zi, of,
-                None, cfg=self.dcfg, n_steps=self.k - 1,
-                paged_kernel=False, greedy=True)
+                None, cfg=self.dcfg, n_steps=self.k - 1, greedy=True)
             rest = np.asarray(toks)                        # [k-1, B]
         else:
             rest = np.zeros((0, B), np.int32)

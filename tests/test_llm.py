@@ -501,15 +501,51 @@ def test_lora_pool_lifecycle(tiny_params):
             lora_rank=4, enable_prefix_caching=True))
 
 
-# --- whole-prompt prefill: where the rows go ---
+# --- where the rows go: one scatter, and rows that are not tokens ---
+
+def _pool_of_other_rows(cfg, n_pages, page, scale=1.0):
+    """K and V pools in which every page already holds something, on the
+    host: the programs donate their pools, so each call gets a copy
+    (``_fresh``)."""
+    shape = (cfg.n_layers, n_pages, page, cfg.n_kv_heads, cfg.head_dim)
+    return tuple(scale * np.asarray(jax.random.normal(
+        jax.random.PRNGKey(seed), shape)) for seed in (8, 9))
+
+
+def _fresh(pools):
+    return tuple(jnp.asarray(np.asarray(pool)) for pool in pools)
+
+
+def _with_qk_norm_gains(params):
+    """QK-norm gains away from 1, so that the norm's weight matters."""
+    layers = dict(params["layers"])
+    for i, name in enumerate(("q_norm", "k_norm")):
+        layers[name] = 1 + 0.3 * jax.random.normal(
+            jax.random.PRNGKey(4 + i), layers[name].shape)
+    return dict(params, layers=layers)
+
+
+def _write_pages_to_dump_page(cache_layer, new, block_tables, positions,
+                              page_size):
+    """The scatter as it was before the runner had one (PR 31), kept for
+    the reference below: rows at negative positions go to dump page 0."""
+    page_idx = jnp.take_along_axis(
+        block_tables, jnp.maximum(positions, 0) // page_size, axis=1)
+    valid = positions >= 0
+    page_idx = jnp.where(valid, page_idx, 0).reshape(-1)
+    offset = jnp.where(valid, positions % page_size, 0).reshape(-1)
+    flat = new.reshape(-1, *new.shape[2:]).astype(cache_layer.dtype)
+    return cache_layer.at[page_idx, offset].set(flat, mode="drop")
+
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def _prefill_layer_by_layer(params, cache_k, cache_v, tokens, prompt_lens,
                             block_tables, cos, sin, lora, cfg):
     """The plain reference for ``runner.prefill``, and the program it
-    replaced: every layer takes ITS pool through the scan and writes its
-    K and V rows there with ``_write_pages`` (padding to dump page 0),
-    which copies the whole pool to write one prompt."""
+    replaced: the layer written out (it must not call the runner's
+    block), every layer taking ITS pool through the scan and writing its
+    K and V rows there, padding to dump page 0, which copies the whole
+    pool to write one prompt."""
     from ray_tpu.llm import runner
     from ray_tpu.llm.lora import lora_delta
     from ray_tpu.models.llama import qk_norm
@@ -537,8 +573,10 @@ def _prefill_layer_by_layer(params, cache_k, cache_v, tokens, prompt_lens,
                                cfg.n_kv_heads, cfg.head_dim)
         q, k = qk_norm(q, k, lp, cfg)
         q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
-        ck = runner._write_pages(ck, k, block_tables, write_pos, ck.shape[1])
-        cv = runner._write_pages(cv, v, block_tables, write_pos, cv.shape[1])
+        ck = _write_pages_to_dump_page(ck, k, block_tables, write_pos,
+                                       ck.shape[1])
+        cv = _write_pages_to_dump_page(cv, v, block_tables, write_pos,
+                                       cv.shape[1])
         o = attention(q, k, v, causal=True)
         x = x + weight_einsum("bshk,hkd->bsd", o, lp["wo"])
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
@@ -549,8 +587,7 @@ def _prefill_layer_by_layer(params, cache_k, cache_v, tokens, prompt_lens,
         layer, x, (layers, cache_k, cache_v, lora_xs))
     last = jnp.take_along_axis(
         x, (prompt_lens - 1)[:, None, None], axis=1)[:, 0]
-    last = rms_norm(last, params["final_norm"], cfg.norm_eps)
-    return runner._lm_logits(last, params, cfg), cache_k, cache_v
+    return runner._head(last, params, cfg), cache_k, cache_v
 
 
 @pytest.mark.parametrize("case", ["dense", "experts-qk-norm", "lora-slot"])
@@ -560,7 +597,7 @@ def test_prefill_scatters_its_rows_once_and_touches_no_other_page(
     the layers hand their rows out and one scatter writes them. Against
     the layer-by-layer reference, for two right-padded prompts of
     different lengths in a pool that holds other sequences' pages:
-    (a) every page but the dump page is bit-equal to ``_write_pages``';
+    (a) every page but the dump page is bit-equal to the reference's;
     (b) no page outside the two block tables changed, and padding rows
     are dropped outright: the dump page did not change either;
     (c) the logits are the reference's."""
@@ -574,12 +611,7 @@ def test_prefill_scatters_its_rows_once_and_touches_no_other_page(
     if case == "experts-qk-norm":
         cfg = dataclasses.replace(CFG, n_experts=8, top_k=2,
                                   norm_topk_prob=False, qk_norm=True)
-        params = init_params(jax.random.PRNGKey(3), cfg)
-        layers = dict(params["layers"])
-        for i, name in enumerate(("q_norm", "k_norm")):
-            layers[name] = 1 + 0.3 * jax.random.normal(
-                jax.random.PRNGKey(4 + i), layers[name].shape)
-        params = dict(params, layers=layers)
+        params = _with_qk_norm_gains(init_params(jax.random.PRNGKey(3), cfg))
     if case == "lora-slot":
         adapter = init_lora_adapter(jax.random.PRNGKey(3), cfg, 4,
                                     dtype=cfg.dtype)
@@ -594,10 +626,7 @@ def test_prefill_scatters_its_rows_once_and_touches_no_other_page(
     tables = jnp.asarray([[3, 5, 9, 0],         # last), 2 pages (2 rows)
                           [2, 6, 0, 0]], jnp.int32)
     tokens = jax.random.randint(jax.random.PRNGKey(7), (2, S), 0, cfg.vocab)
-    shape = (cfg.n_layers, n_pages, page, cfg.n_kv_heads, cfg.head_dim)
-    # every page already holds something: another sequence's rows
-    before_k = np.asarray(jax.random.normal(jax.random.PRNGKey(8), shape))
-    before_v = np.asarray(jax.random.normal(jax.random.PRNGKey(9), shape))
+    before_k, before_v = _pool_of_other_rows(cfg, n_pages, page)
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
 
     want_logits, want_k, want_v = _prefill_layer_by_layer(
@@ -625,3 +654,165 @@ def test_prefill_scatters_its_rows_once_and_touches_no_other_page(
     assert (counts is None) == (case != "experts-qk-norm")
     if counts is not None:      # real tokens x top_k x layers, no padding
         assert int(counts[0]) == 15 * cfg.top_k * cfg.n_layers
+
+
+@pytest.mark.parametrize("program",
+                         ["prefill_chunk", "verify_step", "decode_burst"])
+def test_rows_that_are_not_tokens_change_no_page(tiny_params, program):
+    """The three programs that reach the cache during their step share
+    the one scatter. In a pool whose every page holds another sequence's
+    rows: rows that are not tokens (a chunk's tail, a short window's -1
+    positions, an inactive slot) change no page, page 0 included; and
+    the pages of real rows hold what whole-prompt ``prefill`` writes for
+    the same tokens (which the test above holds to its reference)."""
+    from ray_tpu.llm.runner import (decode_burst, prefill, prefill_chunk,
+                                    verify_step)
+    from ray_tpu.ops import rope_frequencies
+
+    cfg, params, page, n_pages = CFG, tiny_params, 4, 12
+    tables = jnp.asarray([[3, 5, 9, 0], [2, 6, 0, 0]], jnp.int32)
+    tokens = jax.random.randint(jax.random.PRNGKey(7), (2, 16), 0, cfg.vocab)
+    before = _pool_of_other_rows(cfg, n_pages, page)
+    cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
+    zf, zi = jnp.zeros(2, jnp.float32), jnp.zeros(2, jnp.int32)
+
+    def prefilled(tokens, lens, rows=slice(None)):
+        out = prefill(params, *_fresh(before), tokens[rows],
+                      jnp.asarray(lens, jnp.int32), tables[rows], cos, sin,
+                      cfg=cfg)
+        return np.asarray(out[1]), np.asarray(out[2])
+
+    if program == "prefill_chunk":
+        # sequence 0 alone: a whole chunk of 8, then 3 tokens and a tail
+        # of 5 rows whose positions run past the table's three pages
+        base, (ck, cv) = before, _fresh(before)
+        for start, n in ((0, 8), (8, 3)):
+            _, ck, cv, _ = prefill_chunk(
+                params, ck, cv, tokens[:1, start:start + 8],
+                jnp.int32(start), jnp.int32(n), tables[:1], cos, sin,
+                cfg=cfg)
+        want, new_rows = prefilled(tokens, [11], slice(0, 1)), 11
+    else:
+        base = prefilled(tokens, [9, 6])
+        if program == "verify_step":
+            # slot 0: a window of 2 and one -1; slot 1: no window at all
+            pos = jnp.asarray([[9, 10, -1], [-1, -1, -1]], jnp.int32)
+            _, _, ck, cv, _ = verify_step(
+                params, *_fresh(base), tokens[:, 9:12], pos, tables, cos,
+                sin, 0, zf, zi, zf + 1, cfg=cfg, greedy=True)
+        else:
+            # slot 0 decodes two tokens; slot 1 is inactive
+            out, ck, cv, _ = decode_burst(
+                params, *_fresh(base), tokens[:, 9],
+                jnp.asarray([9, 6], jnp.int32),
+                tables, jnp.asarray([True, False]), cos, sin, 0, zf, zi,
+                zf + 1, cfg=cfg, n_steps=2, greedy=True)
+            tokens = tokens.at[0, 10].set(out[0, 0])
+        want, new_rows = prefilled(tokens, [11, 6]), 2
+
+    mine = [3, 5, 9]
+    others = [p for p in range(n_pages) if p not in mine]    # page 0 too
+    for got, want, base in zip((ck, cv), want, base):
+        got, want, base = (np.asarray(a) for a in (got, want, base))
+        np.testing.assert_allclose(got[:, mine], want[:, mine], atol=1e-5)
+        np.testing.assert_array_equal(got[:, others], base[:, others])
+        changed = (got != base).any(axis=(-1, -2))        # [L, P, page]
+        assert changed.sum() == cfg.n_layers * new_rows
+
+
+@pytest.mark.parametrize("ctx, steps, noise", [
+    (1, 3, False), (4, 3, False), (6, 4, False), (6, 4, True)],
+    ids=["context-1", "exactly-one-page", "crosses-into-the-last-page",
+         "noise-on-page-0-and-unwritten-rows"])
+def test_decode_burst_at_the_edges_samples_the_full_forward_tokens(
+        tiny_params, ctx, steps, noise):
+    """The gather path at the contexts where a page-streaming kernel
+    would break: one token of context, a context that ends with its
+    page, and a burst that crosses into the table's last page. A table's
+    unprovisioned slots are 0, and whatever page 0 and the rows not yet
+    written hold must not leak into attention."""
+    from ray_tpu.llm.runner import decode_burst, prefill
+    from ray_tpu.ops import rope_frequencies
+
+    page, n_pages = 4, 8
+    prompt = [5, 17, 99, 3, 42, 7][:ctx]
+    want = _reference_greedy(tiny_params, prompt, steps + 1)
+    cache = _pool_of_other_rows(CFG, n_pages, page, 1e4 if noise else 1.0)
+    # three pages hold ctx + steps <= 12 positions; the fourth slot is
+    # unprovisioned
+    tables = jnp.asarray([[6, 2, 5, 0]], jnp.int32)
+    cos, sin = rope_frequencies(CFG.head_dim, CFG.max_seq, CFG.rope_theta)
+    row = jnp.zeros((1, 16), jnp.int32).at[0, :ctx].set(jnp.asarray(prompt))
+    params = tiny_params
+    logits, ck, cv, _ = prefill(params, *_fresh(cache), row,
+                                jnp.asarray([ctx], jnp.int32), tables, cos,
+                                sin, cfg=CFG)
+    first = jnp.argmax(logits, axis=-1)
+    one = jnp.ones(1, jnp.float32)
+    out, _, _, _ = decode_burst(
+        params, ck, cv, first, jnp.asarray([ctx], jnp.int32), tables,
+        jnp.asarray([True]), cos, sin, 0, 0 * one, jnp.zeros(1, jnp.int32),
+        one, cfg=CFG, n_steps=steps, greedy=True)
+    assert [int(first[0])] + np.asarray(out)[:, 0].tolist() == want
+
+
+def test_decode_burst_refuses_the_deleted_paged_kernel(tiny_params):
+    """``paged_kernel`` is a vestige that benchmarks/aot_fit.py still
+    passes as False; asking for the kernel is an error, not a fallback."""
+    from ray_tpu.llm.runner import decode_burst
+
+    one = jnp.ones(1, jnp.float32)
+    cache = _pool_of_other_rows(CFG, 4, 4)
+    with pytest.raises(ValueError, match="paged kernel"):
+        decode_burst(tiny_params, *_fresh(cache), jnp.zeros(1, jnp.int32),
+                     jnp.ones(1, jnp.int32), jnp.asarray([[1]], jnp.int32),
+                     jnp.asarray([True]), None, None, 0, one,
+                     jnp.zeros(1, jnp.int32), one, cfg=CFG, n_steps=1,
+                     paged_kernel=True)
+
+
+def test_qk_norm_four_programs_agree_with_the_full_forward_pass():
+    """One block serves the four programs: with QK-norm on (gains away
+    from 1) each of them agrees with ``models.llama.forward`` on one
+    prompt: whole-prompt prefill and two chunks by their logits, a
+    verification window and a decode burst by the greedy tokens."""
+    import dataclasses
+
+    from ray_tpu.llm.runner import (decode_burst, prefill, prefill_chunk,
+                                    verify_step)
+    from ray_tpu.ops import rope_frequencies
+
+    cfg = dataclasses.replace(CFG, qk_norm=True)
+    params = _with_qk_norm_gains(init_params(jax.random.PRNGKey(3), cfg))
+    tokens = jax.random.randint(jax.random.PRNGKey(7), (1, 16), 1, cfg.vocab)
+    want = forward(params, tokens, cfg)[0]                 # [16, vocab]
+    greedy = np.asarray(jnp.argmax(want, axis=-1))
+    page = 4
+    cache = _pool_of_other_rows(cfg, 8, page)
+    tables = jnp.asarray([[6, 2, 5, 1]], jnp.int32)
+    cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
+    one, zi = jnp.ones(1, jnp.float32), jnp.zeros(1, jnp.int32)
+
+    logits, ck, cv, _ = prefill(params, *_fresh(cache), tokens,
+                                jnp.asarray([10], jnp.int32), tables, cos,
+                                sin, cfg=cfg)
+    np.testing.assert_allclose(logits[0], want[9], atol=1e-4)
+    # positions 10..12 as a window over the prefilled pages
+    tgt, _, _, _, _ = verify_step(
+        params, ck + 0, cv + 0, tokens[:, 10:13],
+        jnp.asarray([[10, 11, 12]], jnp.int32), tables, cos, sin, 0,
+        0 * one, zi, one, cfg=cfg, greedy=True)
+    assert np.asarray(tgt)[0].tolist() == greedy[10:13].tolist()
+    # the same prompt in two chunks of 8, the second with 2 tokens
+    chunk_k, chunk_v = _fresh(cache)
+    for start, n in ((0, 8), (8, 2)):
+        logits, chunk_k, chunk_v, _ = prefill_chunk(
+            params, chunk_k, chunk_v, tokens[:, start:start + 8],
+            jnp.int32(start), jnp.int32(n), tables, cos, sin, cfg=cfg)
+    np.testing.assert_allclose(logits[0], want[9], atol=1e-4)
+    # a burst of one step from position 10 predicts position 11's token
+    out, _, _, _ = decode_burst(
+        params, ck, cv, tokens[:, 10], jnp.asarray([10], jnp.int32), tables,
+        jnp.asarray([True]), cos, sin, 0, 0 * one, zi, one, cfg=cfg,
+        n_steps=1, greedy=True)
+    assert int(out[0, 0]) == int(greedy[10])

@@ -108,31 +108,6 @@ def test_flash_forward_backward_compiles_for_v5e(one_chip, shape):
                          *_qkv(shape, one_chip)) == 3
 
 
-# (batch, kv heads, q heads per kv head, context pages of 64, burst tail)
-PAGED_SHAPES = {
-    "8b-B8-ctx1024": (8, 8, 4, 16, 8),
-    "400m-8k-B4": (4, 4, 2, 128, 32),
-}
-
-
-@pytest.mark.parametrize("shape", PAGED_SHAPES.values(), ids=PAGED_SHAPES)
-def test_paged_decode_compiles_for_v5e(one_chip, shape):
-    """``interpret=False`` must reach Mosaic even though this process's
-    default backend is the CPU."""
-    from ray_tpu.ops.paged_attention import paged_decode_attention
-
-    b, kvh, rep, n_pages, tail = shape
-    page, hd = 64, 128
-    pool = _sds((1 + b * n_pages, page, kvh, hd), jnp.bfloat16, one_chip)
-    new = _sds((b, tail, kvh, hd), jnp.bfloat16, one_chip)
-    lens = _sds((b,), jnp.int32, one_chip)
-    kernel = functools.partial(paged_decode_attention, page_size=page,
-                               interpret=False)
-    assert _custom_calls(
-        kernel, _sds((b, kvh, rep, hd), jnp.bfloat16, one_chip), pool, pool,
-        new, new, _sds((b, n_pages), jnp.int32, one_chip), lens, lens) == 1
-
-
 def test_decode_burst_8b_int8_fits_one_v5e(one_chip):
     """The whole decode program chip_smoke.py serves with: Llama-3-8B,
     int8 weights, 8 slots, from shapes alone."""
@@ -156,7 +131,7 @@ def test_decode_burst_8b_int8_fits_one_v5e(one_chip):
         params, cache, cache, i32, i32,
         _sds((B, 8), jnp.int32, one_chip), _sds((B,), jnp.bool_, one_chip),
         cos, sin, i32, f32, i32, f32, None, cfg=cfg, n_steps=K,
-        paged_kernel=False, greedy=True).compile()
+        greedy=True).compile()
     mem = compiled.memory_analysis()
     # the donated cache aliases its output; everything else is live at once
     live = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
