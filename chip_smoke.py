@@ -41,12 +41,25 @@ SERVE = dict(
     # the page pool covers every slot at max_seq_len: 8 x 1024 / 64 + dump page
     engine_config=dict(max_num_seqs=8, page_size=64, num_pages=129,
                        max_seq_len=1024, decode_burst=8))
-# every prompt pads to the 512 prefill bucket and ends inside 8 pages, and
-# 1 + 3 x decode_burst tokens keep every burst full: two compiled programs
-# serve all requests, and equal prompts meet equal shapes whatever else is
-# in the batch
+# every prompt pads to the 512 prefill bucket: one prefill program, and a
+# request's first token (a prefill's, alone in its dispatch) is the same
+# whatever else the replica serves. The tokens after it need not be: since
+# PR 32 a decode burst scores ONE list of every decoding slot's live
+# pages, so a slot's sums run over its own keys in an order that depends
+# on where its pages sit in that list, and with random weights two logits
+# tie within bf16 rounding every few tokens. So the two routes have to
+# agree to the token on a request that decodes ALONE both times and on
+# every first token; and EVERY token of the requests decoded together, by
+# either route, is judged by the harness's plain float32 reference on the
+# replica's own weights (``judge_tokens``): a slot that scored another
+# slot's keys, or missed its own, picks tokens the reference scores like
+# any other, deviations below its first choice
 PROMPT_LENS = (301, 333, 365, 397, 429, 448)
 MAX_TOKENS = 25
+# benchmarks/harness/serve_cell.py's MARGIN_LIMIT and its argument: bf16
+# activations through 32 layers move a logit by a few hundredths of a
+# position's logit deviation; a wrong key, page or position by about 4
+MARGIN_LIMIT = 0.15
 # the largest config one 16 GB chip trains with adamw state (batch 8 needs 21 GB)
 TRAIN = dict(model="1b", batch=4, seq=2048, steps=4, kernel_parity=True)
 
@@ -176,9 +189,48 @@ def _check_distinct(infos: list) -> None:
         raise AssertionError(f"replicas share a process or a chip: {infos}")
 
 
+def judge_tokens(config: dict) -> dict:
+    """Runs under a chip lease once the replica has let go of the chip:
+    the replica's weights again from its seed, and for every (prompt,
+    answer) the plain float32 reference's verdict on every token of the
+    answer, teacher-forced: how far the chosen token's reference logit
+    lies below that position's largest, in that position's logit
+    deviations (``benchmarks/harness/reference.py``, which shares no code
+    with ``ray_tpu``; 0 where the reference agrees). Sequences are padded
+    on the right to one length, which a causal model never sees."""
+    sys.path.insert(0, config["root"])
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks.harness import reference
+    from ray_tpu.models import LLAMA_CONFIGS
+    from ray_tpu.ops.quant import init_params_quantized
+
+    cfg = LLAMA_CONFIGS[config["model"]]
+    params = init_params_quantized(jax.random.PRNGKey(config["seed"]), cfg)
+    published = dict(num_hidden_layers=cfg.n_layers,
+                     num_attention_heads=cfg.n_heads,
+                     num_key_value_heads=cfg.n_kv_heads,
+                     rope_theta=cfg.rope_theta, rms_norm_eps=cfg.norm_eps)
+    width = max(len(p) + len(a) for p, a in config["pairs"])
+    worst = []
+    for prompt, answer in config["pairs"]:
+        tokens = prompt + answer + [0] * (width - len(prompt) - len(answer))
+        logits = reference.forward_logits(
+            params, jnp.asarray([tokens], jnp.int32), published)[0]
+        at = logits[len(prompt) - 1:len(prompt) + len(answer) - 1]
+        chosen = jnp.take_along_axis(
+            at, jnp.asarray(answer)[:, None], -1)[:, 0]
+        worst.append(float(np.asarray(
+            (at.max(-1) - chosen) / at.std(-1)).max()))
+    return {"device": _device_report(), "margin_worst": worst}
+
+
 def phase_serve(seed: int, spec: dict) -> dict:
     """Llama-3-8B int8 behind serve.run: the same greedy requests through
-    the deployment handle and through the HTTP proxy, several in flight."""
+    the deployment handle and through the HTTP proxy, several in flight,
+    and the reference's verdict on every token they were given."""
     import ray_tpu
     from ray_tpu import serve
     from ray_tpu.llm import build_llm_deployment
@@ -220,17 +272,25 @@ def phase_serve(seed: int, spec: dict) -> dict:
             enumerate(payloads)))
     http_s = time.time() - t2
 
-    for tokens in by_handle + by_http:
+    # alone again, through the proxy: the same batch of one, the same bits
+    alone_http = _http_completion(port, name, {**payloads[0], "stream": True})
+    for tokens in by_handle + by_http + [alone_http]:
         _check_tokens(tokens)
-    if first != by_handle[0]:
-        raise AssertionError("the same prompt gave other tokens the second "
-                             f"time: {first} vs {by_handle[0]}")
-    if by_handle != by_http:
-        raise AssertionError("handle and HTTP routes disagree: "
-                             f"{by_handle} vs {by_http}")
-    if max_running < 2:
-        raise AssertionError("requests never shared a decode batch "
-                             f"(max running slots seen: {max_running})")
+    if first != alone_http:
+        raise AssertionError("handle and HTTP routes disagree on a request "
+                             f"served alone: {first} vs {alone_http}")
+    firsts = [[tokens[0] for tokens in route]
+              for route in (by_handle, by_http)]
+    if firsts[0] != firsts[1] or firsts[0][0] != first[0]:
+        raise AssertionError("a request's first token changed with its "
+                             f"route or its company: {first[0]}, {firsts}")
+    counters = ray_tpu.get(stats.remote(), timeout=60)["counters"]
+    if max_running < 2 or (counters["active_slot_steps"]
+                           <= counters["decode_steps"]):
+        raise AssertionError(
+            "requests never shared a decode batch (max running slots "
+            f"seen: {max_running}; slot-steps {counters['active_slot_steps']}"
+            f" in {counters['decode_steps']} steps)")
 
     # what the prefill these prompts ran was compiled to, read from the
     # program's text by the replica (every prompt pads to one bucket)
@@ -241,6 +301,26 @@ def phase_serve(seed: int, spec: dict) -> dict:
     _check_device(info)
     serve.shutdown()
     gone_s = _wait_gone([info["pid"]])
+
+    # the chip is free: the reference takes it, on the replica's weights
+    t4 = time.time()
+    prompts = [p["prompt_ids"] for p in payloads]
+    judged = ray_tpu.get(ray_tpu.remote(judge_tokens).options(
+        num_tpus=1).remote(dict(
+            root=HERE, model=spec["model"], seed=seed,
+            pairs=list(zip(prompts * 2 + prompts[:1],
+                           by_handle + by_http + [first])))), timeout=900)
+    _check_device(judged["device"])
+    margins = judged["margin_worst"]
+    if max(margins) > MARGIN_LIMIT:
+        raise AssertionError(
+            "a token of a request decoded beside others lies "
+            f"{max(margins):.3f} deviations below the reference's first "
+            f"choice (limit {MARGIN_LIMIT}); by request, handle then HTTP "
+            f"then alone: {margins}")
+    together = by_handle + by_http
+    agree = [sum(a == b for a, b in zip(x, y))
+             for x, y in zip(by_handle, by_http)]
     n_tokens = MAX_TOKENS * len(payloads)
     emit("serve", model=spec["model"], quantize=spec["quantize"],
          platform=info["platform"], device_kind=info["device_kind"],
@@ -252,8 +332,14 @@ def phase_serve(seed: int, spec: dict) -> dict:
          compile_cache_dir=info["compile_cache_dir"],
          compile_cache=info["compile_cache"],
          first_request_s=round(cold_s, 1), max_running=max_running,
+         slots_a_decode_step=round(counters["active_slot_steps"]
+                                   / counters["decode_steps"], 2),
+         page_list_rounds=counters["gather_hist"],
          handle_round_s=round(handle_s, 2), http_round_s=round(http_s, 2),
-         tokens_per_round=n_tokens, replica_exit_s=round(gone_s, 2))
+         tokens_per_round=n_tokens, replica_exit_s=round(gone_s, 2),
+         margin_worst=round(max(margins), 4), margin_limit=MARGIN_LIMIT,
+         margin_worst_together=round(max(margins[:len(together)]), 4),
+         tokens_routes_agree=agree, reference_s=round(time.time() - t4, 1))
     return info
 
 

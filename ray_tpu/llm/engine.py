@@ -5,9 +5,11 @@ Reference analog: the vLLM engine loop ray.llm wraps
 queue -> schedule -> {prefill | decode} -> sample -> stream. Rebuilt
 TPU-first:
 
-  * decode batch has a FIXED width (``max_num_seqs`` slots) so one
-    compiled decode executable serves the engine's whole lifetime —
-    continuous batching = host-side slot assignment, not shape changes;
+  * decode batch has a FIXED width (``max_num_seqs`` slots) and a
+    burst's steps are an operand, so a decode executable a page-list
+    bucket (a handful: ``decode_buckets``) serves the engine's whole
+    lifetime: continuous batching = host-side slot assignment and the
+    list of the live pages, not shape changes;
   * prefills are bucketed (power-of-2 padding) and run one request per
     step between decode steps (chunked-prefill-lite: bounded TTFT impact
     on running streams);
@@ -129,6 +131,24 @@ class StepOutput:
     text_offset: int = 0
 
 
+def burst_gather(tables: np.ndarray, page_size: int, bucket: int,
+                 held) -> np.ndarray:
+    """``runner.decode_burst``'s ``gather``, int32 [3, bucket]: ONE list
+    of the pages that hold old context of the decoding slots, slot after
+    slot, each with its (page, owner slot, first position); the rest is
+    owned by nobody (-1). ``tables``: the block tables [B, max_pages];
+    ``held``: (slot, pages that hold its old context) of those slots."""
+    out = np.zeros((3, bucket), np.int32)
+    out[1] = -1
+    at = 0
+    for slot, n in held:
+        out[0, at:at + n] = tables[slot, :n]
+        out[1, at:at + n] = slot
+        out[2, at:at + n] = np.arange(n) * page_size
+        at += n
+    return out
+
+
 class LLMEngine:
     def __init__(self, params, cfg: LlamaConfig,
                  engine_config: Optional[EngineConfig] = None):
@@ -215,7 +235,12 @@ class LLMEngine:
             "rounds": 0, "decode_steps": 0,
             "width_hist": [0] * (self.ecfg.decode_burst + 1),
             "active_slot_steps": 0, "prefills": 0, "prefill_tokens": 0,
-            "preemptions": 0, "host_s": dict.fromkeys(PHASES, 0.0)}
+            "preemptions": 0, "host_s": dict.fromkeys(PHASES, 0.0),
+            # the bursts' page lists, summed over rounds: pages that hold
+            # old context of decoding slots, pages the burst copied
+            # (their ratio: the share of the copy that was needed), and
+            # the rounds by the list's bucket
+            "live_pages": 0, "gathered_pages": 0, "gather_hist": {}}
         if cfg.n_experts:
             # counted on the device by the routed layer, summed over
             # layers and programs: the (token, expert) rows the expert
@@ -457,9 +482,9 @@ class LLMEngine:
         self.seq_table.assign(slot, pages)
         return state
 
-    # block-table span bucket width, in pages: bounds compiled decode
-    # variants to max_pages/span while letting KV reads scale with the
-    # longest ACTIVE context instead of max_seq_len
+    # smallest block-table span bucket, in pages: a rectangle of a row
+    # a slot (a speculative round) reads the longest ACTIVE context's
+    # bucket, not max_seq_len
     _SPAN_PAGES = 4
 
     def _bt(self, span: Optional[int] = None):
@@ -474,20 +499,83 @@ class LLMEngine:
 
     def _span_bucket(self, pages: int) -> int:
         """Power-of-2 page-span bucket, capped at the table width."""
-        b = self._SPAN_PAGES
-        while b < pages:
-            b *= 2
-        return min(b, self.seq_table.block_tables.shape[1])
+        return prefill_bucket(pages, self.seq_table.block_tables.shape[1],
+                              self._SPAN_PAGES)
 
     def _active_span(self) -> int:
-        """Pages covering the longest DECODING sequence, bucketed.
+        """Pages covering the longest DECODING sequence, bucketed (a
+        speculative round's table: its window is written through it).
         Mid-prefill slots (ctx_len 0) hold their full page allocation up
-        front — counting them would balloon every interleaved decode
-        burst's KV gather to the long prompt's whole table."""
+        front: counting them would balloon the round's KV gather to the
+        long prompt's whole table."""
         longest = max((int(self.seq_table.n_pages[s.slot])
                        for s in self.slots
                        if s is not None and s.ctx_len > 0), default=1)
         return self._span_bucket(longest)
+
+    # --- the burst's page list (``burst_gather``) ---
+
+    # A plain burst always lists flat: alone on the chip at fixed shapes
+    # ONE list of the live pages costs what a rectangle of as many pages
+    # (a row a slot at the longest's span) costs, or less (Mistral-7B, 16
+    # slots: 103.4 against 103.8 ms a burst at 128 pages, 122.4 against
+    # 122.5 at 256; OLMoE, 8 slots: 50.4 against 53.2 and 65.5 against
+    # 70.9; PERF.md, PR 32), and the live pages are never more than that
+    # rectangle's. Smallest bucket, in pages: below it a list is a few
+    # hundred KB a layer and a finer bucket buys nothing
+    _FLAT_PAGES = 16
+
+    def _listable_pages(self) -> int:
+        """The most pages a flat list can hold: every slot's whole table,
+        or the pool where no page is shared (a page two slots share, the
+        prefix cache's, is listed once for each)."""
+        B, top = self.seq_table.block_tables.shape
+        if self.prefix_cache is not None:
+            return B * top
+        return min(B * top, self.ecfg.num_pages - 1)
+
+    def _flat_bucket(self, pages: int) -> int:
+        """Power-of-2 bucket of a flat list, capped at what can be
+        listed (a pool of 384 pages tops out there, not at 512)."""
+        return prefill_bucket(pages, self._listable_pages(),
+                              self._FLAT_PAGES)
+
+    def decode_buckets(self):
+        """Every bucket ``_flat_bucket`` can return for this engine: the
+        decode programs a server loads before it is ready."""
+        top = self._listable_pages()
+        buckets = [min(self._FLAT_PAGES, top)]
+        while buckets[-1] < top:
+            buckets.append(min(2 * buckets[-1], top))
+        return buckets
+
+    def load_decode_programs(self) -> int:
+        """Run ``decode_burst`` once in every shape a plain greedy round
+        can take, with no slot active: every row is dropped and no page
+        changes, and the program is compiled (or read from the compile
+        cache) before the first request needs it. A width is an operand,
+        so the shapes are ``decode_buckets()``; a round with a sampled
+        request compiles its sampler on first use, as before. For a
+        server to call before it reports ready, not for ``__init__``: a
+        bare engine (the tests build dozens) compiles what it meets.
+        Returns the number of programs."""
+        B = self.ecfg.max_num_seqs
+        zi, zf = jnp.zeros(B, jnp.int32), jnp.ones(B, jnp.float32)
+        lora = None
+        if self.lora_pool is not None:
+            lora = self.lora_pool.select([0] * B)
+        buckets = self.decode_buckets()
+        for bucket in buckets:
+            _toks, ck, cv, _counts = decode_burst(
+                self.params, self.cache.k, self.cache.v, zi, zi,
+                self._bt(), jnp.zeros(B, bool), self.cos, self.sin, 0, zf,
+                zi, zf, lora, jnp.asarray(burst_gather(
+                    self.seq_table.block_tables, self.ecfg.page_size,
+                    bucket, ())), jnp.int32(1), cfg=self.cfg,
+                n_steps=self.ecfg.decode_burst, greedy=True)
+            self.cache = KVCache(ck, cv)
+        jax.block_until_ready(self.cache.k)
+        return len(buckets)
 
     def _sampling_arrays(self, row_states, advance: int = 1):
         n = len(row_states)
@@ -724,13 +812,23 @@ class LLMEngine:
                 for s2 in active_states:
                     ids[s2.slot] = self.lora_pool.slot_of(s2.model_id)
                 lora = self.lora_pool.select(ids)
+            page = self.ecfg.page_size
+            held = [-(-s.ctx_len // page) for s in active_states]
+            bucket = self._flat_bucket(sum(held))
+            gather = burst_gather(
+                self.seq_table.block_tables, page, bucket,
+                zip((s.slot for s in active_states), held))
+            counters["live_pages"] += sum(held)
+            counters["gathered_pages"] += bucket
+            hist = counters["gather_hist"]
+            hist[bucket] = hist.get(bucket, 0) + 1
             toks, ck, cv, counts = decode_burst(
                 self.params, self.cache.k, self.cache.v,
-                jnp.asarray(tokens), jnp.asarray(positions),
-                self._bt(self._active_span()),
+                jnp.asarray(tokens), jnp.asarray(positions), self._bt(),
                 jnp.asarray(active), self.cos, self.sin,
-                seed, temp, top_k, top_p, lora, cfg=self.cfg, n_steps=K,
-                greedy=greedy)
+                seed, temp, top_k, top_p, lora, jnp.asarray(gather),
+                jnp.int32(K), cfg=self.cfg,
+                n_steps=self.ecfg.decode_burst, greedy=greedy)
         self.cache = KVCache(ck, cv)
         with self._phase("decode.sync"):
             sampled = self._read_back(toks, counts)  # [K, B]
@@ -1144,5 +1242,7 @@ class LLMEngine:
             out["spec"] = self.spec.stats()
         out["counters"] = {**self._counters,
                            "width_hist": list(self._counters["width_hist"]),
-                           "host_s": dict(self._counters["host_s"])}
+                           "host_s": dict(self._counters["host_s"]),
+                           "gather_hist": dict(
+                               self._counters["gather_hist"])}
         return out

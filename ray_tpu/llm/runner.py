@@ -16,10 +16,12 @@ how the layer reaches the cache, and nothing else reaches it:
   * ``verify_step`` (a speculative window) writes the window's rows,
     gathers the span, and attends over it under key position <= query
     position: the window sees its own keys through the pages.
-  * ``decode_burst`` (n fused decode+sample steps) gathers the span once
-    a burst for all layers; step i puts its row into a burst scratch and
-    attends over (span; scratch up to i); one scatter writes the scratch
-    at the end.
+  * ``decode_burst`` (up to n fused decode+sample steps, the number an
+    operand) copies the pages that hold old context once a burst for all
+    layers: one flat list of the live pages that every slot scores under
+    its own mask, or the rectangle of a row a slot; step i puts its row
+    into a burst scratch and attends over (the copy; scratch up to i);
+    one scatter writes the scratch at the end.
 A scan's stacked output is not aliased to its stacked input, so a pool
 that rides a layer scan is rewritten whole: ``prefill`` and
 ``decode_burst`` keep it out, ``prefill_chunk`` and ``verify_step``
@@ -29,13 +31,20 @@ One scatter, one convention. ``_write_rows`` is the only place a page is
 written. A row that is not a token (bucket padding, a chunk's tail, a
 window's -1 positions, an inactive slot) carries the out-of-range page
 index ``num_pages`` and ``mode="drop"`` writes nothing for it. Page 0
-stays reserved: block tables are padded with 0 and the gathers read it
-under a mask; nothing writes to it.
+stays reserved: block tables and page lists are padded with 0 and the
+gathers read it under a mask; nothing writes to it. A burst's gather,
+``_gather_span``, copies the listed pages of all layers once, straight
+into the layer-major array the layer scan slices (no transposition,
+select or fill behind it); inside a layer scan one layer's pool is
+gathered by ``_take_span``, a plain ``jnp.take``, which is the faster
+there (both measured alone on the chip: PERF.md, PR 32).
 
 Static shapes throughout: prefill pads a prompt to a power-of-2 bucket
 (one executable a bucket), decode runs the whole slot batch every step
-with inactive slots masked, and the cache buffers are donated, so the
-scatters update pages in place. Reference analog: the vLLM
+with inactive slots masked over a page list padded to a power-of-2
+bucket (one executable a bucket, whatever the burst's width), and the
+cache buffers are donated, so the scatters update pages in place.
+Reference analog: the vLLM
 paged-attention CUDA kernels behind ray.llm's vllm_engine (SURVEY §2.4),
 rebuilt natively since the reference delegates all device work to vLLM.
 """
@@ -143,14 +152,49 @@ def _write_rows(pools, rows, block_tables, positions, valid):
         for pool, r in zip(pools, rows))
 
 
-def _gather_span(pool, block_tables):
-    """The pages a table lists, side by side: pool [..., P, page, kvh,
-    hd] -> [..., B, max_pages * page, kvh, hd]. A table's unused slots
-    are 0 and read page 0: the caller masks by position."""
-    lead = pool.shape[:-4]
+def _take_span(pool, block_tables):
+    """The pages a table lists, side by side, of ONE layer's pool (inside
+    a layer scan): pool [P, page, kvh, hd] -> [B, max_pages * page, kvh,
+    hd]. A table's unused slots are 0 and read page 0: the caller masks
+    by position. With no layer axis in front ``jnp.take`` is one gather;
+    ``_gather_span``'s loop in its place ran ``verify_step`` 5 to 42%
+    and ``prefill_chunk`` 3 to 7% slower (PERF.md, PR 32, call 9)."""
     B, n = block_tables.shape
-    return jnp.take(pool, block_tables, axis=len(lead)).reshape(
-        *lead, B, n * pool.shape[-3], *pool.shape[-2:])
+    return jnp.take(pool, block_tables, axis=0).reshape(
+        B, n * pool.shape[1], *pool.shape[2:])
+
+
+def _gather_span(pool, pages):
+    """The listed pages of every layer side by side, copied ONCE and
+    straight to where the burst's layer scan slices them. pool [..., P,
+    page, kvh, hd], the layers in front; pages int32 [G, T], a row a
+    query row -> [..., G, T * page, kvh, hd]; or int32 [T], ONE list for
+    every query row -> [..., kvh, T * page, hd], heads first, as the
+    shared product wants its keys (``_attend``). A loop of
+    one page's slice and its update in place: no transposition behind it
+    (``jnp.take`` gathers page-major and a whole second copy turns it
+    layer-major), no select (``dynamic_slice`` clamps, and a page index
+    is never out of range) and no fill (the loop writes every row). A
+    list's unused entries are 0 and read page 0: the caller masks by
+    position or owner."""
+    axis = pool.ndim - 4
+    lead, (page, kvh, hd) = pool.shape[:axis], pool.shape[-3:]
+    flat = pages.reshape(-1)
+    shared = pages.ndim == 1
+
+    def copy(t, out):
+        rows = jax.lax.dynamic_slice_in_dim(pool, flat[t], 1, axis)
+        if shared:
+            return jax.lax.dynamic_update_slice_in_dim(
+                out, jnp.swapaxes(rows.reshape(*lead, page, kvh, hd),
+                                  -2, -3), t * page, axis + 1)
+        return jax.lax.dynamic_update_slice_in_dim(out, rows, t, axis)
+
+    out = jax.lax.fori_loop(0, flat.size, copy, jax.lax.empty(
+        (*lead, kvh, flat.size * page, hd) if shared
+        else (*lead, flat.size, page, kvh, hd), pool.dtype))
+    return out if shared else out.reshape(
+        *lead, pages.shape[0], pages.shape[1] * page, kvh, hd)
 
 
 def _attend(q, *segments):
@@ -159,24 +203,33 @@ def _attend(q, *segments):
 
     q: [B, ..., heads, hd], with or without a query axis; a segment:
     (keys [B, S, kvh, hd], values, mask broadcastable to [B, ..., S]).
-    The operands go to the MXU in their own dtype with f32 accumulation.
-    Returns f32, in q's shape.
+    Keys [kvh, S, hd], with no row axis, are ONE list that every query
+    row scores, each under its own mask. The operands go to the MXU in
+    their own dtype with f32 accumulation. Returns f32, in q's shape.
     """
     hd = q.shape[-1]
-    kvh = segments[0][0].shape[2]
+    kvh = segments[0][0].shape[-2 if segments[0][0].ndim == 4 else 0]
     qg = q.reshape(*q.shape[:-2], kvh, q.shape[-2] // kvh, hd)
+
+    def product(lhs, rows, spec):
+        # spec: the axis summed over, the axis kept. ``rows`` with no row
+        # axis is the one shared list, and goes first: XLA's CPU backend
+        # runs a bf16 product into f32 only in that order of operands
+        if rows.ndim == 3:
+            return jnp.einsum(f"gsd,b...gr{spec[0]}->b...gr{spec[1]}",
+                              rows, lhs, preferred_element_type=jnp.float32)
+        return jnp.einsum(f"b...gr{spec[0]},bsgd->b...gr{spec[1]}", lhs,
+                          rows, preferred_element_type=jnp.float32)
+
     s = jnp.concatenate([
         jnp.where(mask[..., None, None, :],
-                  jnp.einsum("b...grd,bsgd->b...grs", qg, keys,
-                             preferred_element_type=jnp.float32)
-                  * hd ** -0.5, -jnp.inf)
+                  product(qg, keys, "ds") * hd ** -0.5, -jnp.inf)
         for keys, _, mask in segments], axis=-1)
     p = jax.nn.softmax(s, axis=-1).astype(segments[0][0].dtype)
     outs, at = [], 0
     for _, values, _ in segments:
-        end = at + values.shape[1]
-        outs.append(jnp.einsum("b...grs,bsgd->b...grd", p[..., at:end],
-                               values, preferred_element_type=jnp.float32))
+        end = at + values.shape[-3 if values.ndim == 4 else -2]
+        outs.append(product(p[..., at:end], values, "sd"))
         at = end
     return sum(outs[1:], outs[0]).reshape(q.shape)
 
@@ -303,7 +356,7 @@ def prefill_chunk(params, cache_k, cache_v, tokens, start_pos, chunk_len,
 
     def attend(q, k, v, pools):
         pools = _write_rows(pools, (k, v), block_tables, pos_grid, valid)
-        pk, pv = (_gather_span(pool, block_tables) for pool in pools)
+        pk, pv = (_take_span(pool, block_tables) for pool in pools)
         return _attend(q, (pk, pv, past_mask), (k, v, chunk_mask)), pools
 
     x, (cache_k, cache_v), counts = _layers(params, cfg, cos, sin)(
@@ -345,7 +398,7 @@ def verify_step(params, cache_k, cache_v, tokens, positions, block_tables,
 
     def attend(q, k, v, pools):
         pools = _write_rows(pools, (k, v), block_tables, positions, valid)
-        pk, pv = (_gather_span(pool, block_tables) for pool in pools)
+        pk, pv = (_take_span(pool, block_tables) for pool in pools)
         return _attend(q, (pk, pv, kmask)), pools
 
     x, (cache_k, cache_v), counts = _layers(params, cfg, cos, sin)(
@@ -383,23 +436,38 @@ def prefill_sample(params, cache_k, cache_v, tokens, prompt_lens,
          static_argnames=("cfg", "n_steps", "paged_kernel", "greedy"))
 def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
                  active, cos, sin, seed, temperature, top_k, top_p,
-                 lora=None, *, cfg: LlamaConfig, n_steps: int,
-                 paged_kernel: bool = None, greedy: bool = False):
-    """n_steps fused decode+sample steps, sampled tokens fed back
+                 lora=None, gather=None, steps=None, *, cfg: LlamaConfig,
+                 n_steps: int, paged_kernel: bool = None,
+                 greedy: bool = False):
+    """Up to n_steps fused decode+sample steps, sampled tokens fed back
     ON-DEVICE (multi-step scheduling, vLLM's --num-scheduler-steps
-    analog). One host round trip yields n_steps tokens per slot; the
+    analog). One host round trip yields a burst of tokens per slot; the
     best depth is not measured (ROADMAP R6).
 
-    The big cache never rides the step-scan carry (that would copy it
-    every step): the burst's rows accumulate in a [L, B, K] scratch and
-    scatter ONCE at the end, inactive slots' rows dropped.
-    ``block_tables`` may be a narrowed slice of the full table: the
-    engine buckets it to the longest active context, so KV read traffic
-    scales with real context, not max_seq_len.
+    The big cache never rides the step loop's carry (that would copy it
+    every step): the old context is copied ONCE a burst, the burst's rows
+    accumulate in a [L, B, n_steps] scratch and scatter once at the end,
+    rows of inactive slots and of steps not run dropped.
 
-    Returns (tokens [n_steps, B], cache_k, cache_v, expert counts over
-    all steps and layers as ``prefill``). The host must have
-    pre-provisioned pages for positions .. positions+n_steps-1.
+    ``gather``: int32 [3, T], ONE flat list of the LIVE pages, those that
+    hold old context of decoding slots: each one's (page, owner slot,
+    first position); a page two slots share is listed once for each, an
+    entry that lists nothing has owner -1 (and page 0). Every slot scores
+    every listed key and keeps its own, so cache traffic follows the live
+    context. None: the rectangle of the whole of ``block_tables``, row b
+    slot b's pages and a slot scoring only its row: the worst case.
+    Either way a slot's keys are those it owns at positions below its
+    own: one softmax over them and the burst's rows.
+
+    ``steps``: int32 scalar, the steps to run (<= n_steps, which is only
+    the capacity: scratch rows and the returned [n_steps, B]); None runs
+    them all. A width is an operand, not a program.
+
+    Returns (tokens [n_steps, B], rows past ``steps`` 0; cache_k,
+    cache_v; expert counts over all steps and layers as ``prefill``).
+    The host must have pre-provisioned pages for positions ..
+    positions+steps-1 in ``block_tables`` (the full-width table: only
+    ``_write_rows`` reads it).
     """
     if paged_kernel:
         # the keyword stays only because benchmarks/aot_fit.py:73 passes
@@ -407,17 +475,30 @@ def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
         raise ValueError("decode_burst has one attention path: the paged "
                          "kernel was deleted (PR 31)")
     B, K = tokens.shape[0], n_steps
-    # old context gathered ONCE per burst (read-only during burst), and
-    # the burst's own rows: [L, B, Sold, kvh, hd] and [L, B, K, kvh, hd]
-    old_k, old_v = (_gather_span(c, block_tables) for c in (cache_k, cache_v))
+    page_size = cache_k.shape[2]
+    # old context copied ONCE a burst (read-only during it), and who may
+    # score it: [L, B, n * page, kvh, hd] for the table's rectangle, or
+    # [L, kvh, T * page, hd] for one flat list; the burst's own rows are
+    # [L, B, K, kvh, hd]
+    if gather is None:
+        pages = block_tables
+        old_mask = (jnp.arange(pages.shape[1] * page_size)[None, :]
+                    < positions[:, None])                      # [B, S]
+    else:
+        pages, owner, first = gather
+        key_pos = (first[:, None] + jnp.arange(page_size)).reshape(-1)
+        old_mask = ((jnp.repeat(owner, page_size)[None, :]
+                     == jnp.arange(B)[:, None])
+                    & (key_pos[None, :] < positions[:, None]))  # [B, S]
+    old_k, old_v = (_gather_span(c, pages) for c in (cache_k, cache_v))
     scratch_k, scratch_v = (
         jnp.zeros((cfg.n_layers, B, K, *c.shape[3:]), c.dtype)
         for c in (cache_k, cache_v))
-    old_mask = jnp.arange(old_k.shape[2])[None, :] < positions[:, None]
     layers = _layers(params, cfg, cos, sin, lora)
+    n_run = K if steps is None else steps
 
-    def step(carry, i):
-        toks, sk, sv = carry
+    def step(i, carry):
+        toks, sk, sv, out, total = carry
         x = embed_lookup(params["embed"], toks, cfg.dtype)[:, None, :]
         new_mask = jnp.arange(K)[None, :] <= i                 # [1, K]
 
@@ -436,14 +517,18 @@ def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
             positions=(positions + i)[:, None], valid=active[:, None])
         newt = _pick(_head(x[:, 0], params, cfg), greedy, seed + i,
                      temperature, top_k, top_p)
-        newt = jnp.where(active, newt, toks)
-        return (newt, sk, sv), (newt, counts)
+        newt = jnp.where(active, newt, toks).astype(out.dtype)
+        out = jax.lax.dynamic_update_slice_in_dim(out, newt[None], i, 0)
+        return (newt, sk, sv, out,
+                None if counts is None else total + counts)
 
-    (_, scratch_k, scratch_v), (out, counts) = jax.lax.scan(
-        step, (tokens, scratch_k, scratch_v), jnp.arange(K))
+    _, scratch_k, scratch_v, out, counts = jax.lax.fori_loop(
+        0, n_run, step,
+        (tokens, scratch_k, scratch_v, jnp.zeros((K, B), tokens.dtype),
+         jnp.zeros(2, jnp.int32) if cfg.n_experts else None))
     # one scatter of the whole burst into the paged cache
     p_grid = positions[:, None] + jnp.arange(K)[None, :]       # [B, K]
     cache_k, cache_v = _write_rows(
         (cache_k, cache_v), (scratch_k, scratch_v), block_tables, p_grid,
-        active[:, None])
-    return out, cache_k, cache_v, _total(counts)
+        active[:, None] & (jnp.arange(K)[None, :] < n_run))
+    return out, cache_k, cache_v, counts

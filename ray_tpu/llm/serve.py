@@ -223,9 +223,14 @@ class LLMServer:
         """Replica hook: learn this replica's role in a disaggregated
         deployment. Prefill replicas skip decode in their pump and ship
         finished prompt KV to the decode pool; metrics re-tag so
-        TTFT/TPOT split by pool."""
+        TTFT/TPOT split by pool. Called in the replica's constructor, so
+        before it reports ready: a replica that decodes loads every shape
+        a decode round can take here (lone warm-up requests reach only
+        the smallest page lists); a prefill replica never runs one."""
         self._pool = pool or "mono"
         self._dep_name = deployment_name
+        if pool != "prefill":
+            self.engine.load_decode_programs()
         tags = {"model": self.model_name, "pool": self._pool}
         for m in (self._m_ttft, self._m_tpot, self._m_queue_wait,
                   self._m_preemptions, self._m_e2e, self._m_queue,

@@ -525,6 +525,22 @@ def _with_qk_norm_gains(params):
     return dict(params, layers=layers)
 
 
+def _tuned_lora_rows(cfg, tuned):
+    """Per-slot adapter rows (``LoRAPool.select``): slots with ``tuned``
+    1 wear an adapter whose b matrices are away from 0, the rest the
+    base model."""
+    from ray_tpu.llm.lora import LoRAPool, init_lora_adapter
+
+    adapter = init_lora_adapter(jax.random.PRNGKey(3), cfg, 4,
+                                dtype=cfg.dtype)
+    for i, name in enumerate(("b_q", "b_v")):
+        adapter[name] = 0.3 * jax.random.normal(
+            jax.random.PRNGKey(4 + i), adapter[name].shape, cfg.dtype)
+    pool = LoRAPool(cfg, 4, 2, dtype=cfg.dtype)
+    slot = pool.add("tuned", adapter)
+    return pool.select([slot if t else 0 for t in tuned])
+
+
 def _write_pages_to_dump_page(cache_layer, new, block_tables, positions,
                               page_size):
     """The scatter as it was before the runner had one (PR 31), kept for
@@ -603,7 +619,6 @@ def test_prefill_scatters_its_rows_once_and_touches_no_other_page(
     (c) the logits are the reference's."""
     import dataclasses
 
-    from ray_tpu.llm.lora import LoRAPool, init_lora_adapter
     from ray_tpu.llm.runner import prefill
     from ray_tpu.ops import rope_frequencies
 
@@ -613,13 +628,7 @@ def test_prefill_scatters_its_rows_once_and_touches_no_other_page(
                                   norm_topk_prob=False, qk_norm=True)
         params = _with_qk_norm_gains(init_params(jax.random.PRNGKey(3), cfg))
     if case == "lora-slot":
-        adapter = init_lora_adapter(jax.random.PRNGKey(3), cfg, 4,
-                                    dtype=cfg.dtype)
-        for i, name in enumerate(("b_q", "b_v")):
-            adapter[name] = 0.3 * jax.random.normal(
-                jax.random.PRNGKey(4 + i), adapter[name].shape, cfg.dtype)
-        pool = LoRAPool(cfg, 4, 2, dtype=cfg.dtype)
-        lora = pool.select([pool.add("tuned", adapter), 0])
+        lora = _tuned_lora_rows(cfg, [1, 0])
 
     page, n_pages, S = 4, 12, 16
     lens = jnp.asarray([9, 6], jnp.int32)       # 3 pages (1 row in the
@@ -816,3 +825,146 @@ def test_qk_norm_four_programs_agree_with_the_full_forward_pass():
         jnp.asarray([True]), cos, sin, 0, 0 * one, zi, one, cfg=cfg,
         n_steps=1, greedy=True)
     assert int(out[0, 0]) == int(greedy[10])
+
+
+# --- the burst's page list: one flat list of the live pages, or the
+# rectangle; and the steps to run as an operand ---
+
+def _flat_list(tables, ctx, page, bucket):
+    """The flat page list as the engine builds it for slots with ``ctx``
+    tokens of old context (0: the slot does not decode)."""
+    from ray_tpu.llm.engine import burst_gather
+
+    held = [(b, -(-n // page)) for b, n in enumerate(ctx) if n]
+    return jnp.asarray(burst_gather(np.asarray(tables), page, bucket, held))
+
+
+def _burst_case(case):
+    """cfg, params, lora, the slots' contexts, their tables and the flat
+    bucket of one case of the two tests below: three slots over pages of
+    4 in a pool of 24."""
+    import dataclasses
+
+    cfg, lora = CFG, None
+    ctx = [9, 6, 11]        # 3 + 2 + 3 pages hold them; 8 more tokens fit
+    tables = [[3, 5, 9, 14, 18], [2, 6, 15, 17, 0], [7, 11, 12, 16, 19]]
+    flat_bucket = 16
+    if case == "dense-bf16":
+        cfg = dataclasses.replace(CFG, dtype=jnp.bfloat16)
+    if case == "experts-qk-norm":
+        cfg = dataclasses.replace(CFG, n_experts=8, top_k=2,
+                                  norm_topk_prob=False, qk_norm=True)
+    if case == "shared-prefix-page":
+        tables[2][0] = 3          # slots 0 and 2 share their first page
+    if case == "inactive-slot-between":
+        ctx[1], tables[1] = 0, [0] * 5
+    if case == "list-fills-its-bucket":
+        flat_bucket = 8           # 3 + 2 + 3 live pages: no padding
+    params = init_params(jax.random.PRNGKey(3), cfg)
+    if cfg.qk_norm:
+        params = _with_qk_norm_gains(params)
+    if case == "lora-slot":
+        lora = _tuned_lora_rows(cfg, [1, 0, 0])
+    return cfg, params, lora, ctx, jnp.asarray(tables, jnp.int32), flat_bucket
+
+
+def _prefilled_burst_inputs(cfg, params, lora, ctx, tables, page=4):
+    """Pools in which the slots' contexts are prefilled (every other page
+    holds another sequence's rows), and the burst's first tokens."""
+    from ray_tpu.llm.runner import prefill
+    from ray_tpu.ops import rope_frequencies
+
+    cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
+    tokens = jax.random.randint(jax.random.PRNGKey(7), (3, 16), 1, cfg.vocab)
+    tokens = tokens.at[2, :4].set(tokens[0, :4])      # a prefix to share
+    before = tuple(a.astype(cfg.dtype) for a in
+                   _pool_of_other_rows(cfg, 24, page))
+    logits, ck, cv, _ = prefill(
+        params, *(jnp.asarray(a, cfg.dtype) for a in before), tokens,
+        jnp.asarray(ctx, jnp.int32), tables, cos, sin, lora, cfg=cfg)
+    return (cos, sin, jnp.argmax(logits, axis=-1).astype(jnp.int32),
+            (np.asarray(ck), np.asarray(cv)))
+
+
+@pytest.mark.parametrize("case", [
+    "dense-float32", "dense-bf16", "experts-qk-norm", "lora-slot",
+    "shared-prefix-page", "inactive-slot-between", "list-fills-its-bucket"])
+def test_flat_page_list_decodes_what_the_rectangle_decodes(case):
+    """``decode_burst`` over ONE flat list of the live pages (each with
+    its owner and first position; every slot scores every listed key and
+    keeps its own) against the rectangle of every slot at the longest
+    span, which is what the table alone (``gather=None``, the call
+    ``aot_fit.py`` makes) lowers to: the same greedy tokens; every page
+    the burst does not write bit-equal, page 0 and a shared prefix page
+    too; the rows it writes within the file's tolerance (a layer's rows
+    are the layer below's attention, summed in another order over
+    another number of keys), layer 0's bit-equal."""
+    from ray_tpu.llm.runner import decode_burst
+
+    cfg, params, lora, ctx, tables, flat_bucket = _burst_case(case)
+    cos, sin, first, pools = _prefilled_burst_inputs(cfg, params, lora, ctx,
+                                                     tables)
+    active = jnp.asarray([n > 0 for n in ctx])
+    positions = jnp.asarray(ctx, jnp.int32)
+    zf, zi = jnp.zeros(3, jnp.float32), jnp.zeros(3, jnp.int32)
+    flat = _flat_list(tables, ctx, 4, flat_bucket)
+    assert flat.shape == (3, flat_bucket)
+
+    def burst(block_tables, gather):
+        out = decode_burst(params, *_fresh(pools), first, positions,
+                           block_tables, active, cos, sin, 0, zf, zi,
+                           zf + 1, lora, gather, cfg=cfg, n_steps=4,
+                           greedy=True)
+        return [np.asarray(a, np.float32) for a in out[:3]]
+
+    want, got = burst(tables[:, :4], None), burst(tables, flat)
+    live = np.asarray(active)
+    np.testing.assert_array_equal(got[0][:, live], want[0][:, live])
+    tol = 1e-5 if cfg.dtype == jnp.float32 else 0.05
+    for g, w, before in zip(got[1:], want[1:], pools):
+        changed = (w != np.asarray(before, np.float32)).any(axis=(-1, -2))
+        assert changed.sum() == cfg.n_layers * 4 * int(live.sum())
+        np.testing.assert_array_equal(g[~changed], w[~changed])
+        np.testing.assert_array_equal(g[0], w[0])
+        np.testing.assert_allclose(g, w, atol=tol)
+
+
+@pytest.mark.parametrize("width", [1, 2, 3, 4, 5, 6, 7, 8])
+def test_burst_width_is_an_operand_of_one_program(tiny_params, width):
+    """One compiled ``decode_burst`` (capacity 8, a flat list) run at
+    ``steps`` = width gives the tokens and the pages of the program
+    compiled for that width alone (``n_steps=width``, the table's
+    rectangle: the parent's call): rows past ``steps`` are dropped and
+    the returned rows past it are 0."""
+    from ray_tpu.llm.runner import decode_burst
+
+    cfg, params, lora, ctx, tables, flat_bucket = _burst_case("dense-float32")
+    params = tiny_params
+    cos, sin, first, pools = _prefilled_burst_inputs(cfg, params, lora, ctx,
+                                                     tables)
+    active, positions = jnp.ones(3, bool), jnp.asarray(ctx, jnp.int32)
+    zf, zi = jnp.zeros(3, jnp.float32), jnp.zeros(3, jnp.int32)
+    flat = _flat_list(tables, ctx, 4, flat_bucket)
+
+    def run(steps):
+        return decode_burst(params, *_fresh(pools), first, positions, tables,
+                            active, cos, sin, 0, zf, zi, zf + 1, None, flat,
+                            jnp.int32(steps), cfg=cfg, n_steps=8,
+                            greedy=True)
+
+    run(9 - width)          # another width: the program is compiled here
+    size = decode_burst._cache_size()
+    got = run(width)
+    assert decode_burst._cache_size() == size
+    want = decode_burst(params, *_fresh(pools), first, positions, tables,
+                        active, cos, sin, 0, zf, zi, zf + 1, cfg=cfg,
+                        n_steps=width, greedy=True)
+    np.testing.assert_array_equal(np.asarray(got[0])[:width],
+                                  np.asarray(want[0]))
+    assert not np.asarray(got[0])[width:].any()
+    for g, w, before in zip(got[1:3], want[1:3], pools):
+        g, w = np.asarray(g), np.asarray(w)
+        changed = (g != before).any(axis=(-1, -2))
+        assert changed.sum() == cfg.n_layers * width * 3
+        np.testing.assert_allclose(g, w, atol=1e-5)
+        np.testing.assert_array_equal(g[0], w[0])

@@ -119,14 +119,108 @@ def test_round_counters(served):
     assert c["host_s"]["decode.dispatch"] > 0.0
 
 
+@pytest.mark.parametrize("slots, num_pages, prompts, rounds, bucket", [
+    # (prompt tokens, max_tokens) a request; a round: the old context of
+    # each decoding slot, whose pages of 4 the burst lists
+    (2, 13, [(5, 6), (9, 3)], [[5], [9, 9], [10]], 12),
+    (8, 64, [(18, 6), (21, 3)], [[18], [22, 21], [22]], 16)],
+    ids=["two-slots-short-small-pool", "eight-slots-longer"])
+def test_gather_counters_by_hand(tiny_params, slots, num_pages, prompts,
+                                 rounds, bucket):
+    """``live_pages``, ``gathered_pages`` and ``gather_hist`` of a
+    scripted run, counted by hand. Whole-prompt mode prefills one
+    request a step and decodes after it: step 1 prefills A and runs a
+    burst of 4 (A wants 5 more), step 2 prefills B and runs a burst of
+    1 (A wants 1 more), step 3 runs B's last step alone. A round lists
+    the pages below each decoding slot's context in one flat list, at
+    the smallest bucket the engine has for that many (``_flat_bucket``:
+    16, or all the engine can list where that is fewer)."""
+    engine = LLMEngine(tiny_params, CFG, EngineConfig(
+        max_num_seqs=slots, page_size=4, num_pages=num_pages,
+        max_seq_len=32, decode_burst=4))
+    calls = _watch_burst_width(engine)
+    for i, (n, max_tokens) in enumerate(prompts):
+        engine.add_request(list(range(1 + i, 1 + i + n)), SamplingParams(
+            temperature=0.0, max_tokens=max_tokens))
+    while engine.has_unfinished():
+        engine.step()
+    c = engine.stats()["counters"]
+    assert calls == [4, 1, 1] and c["rounds"] == len(rounds)
+    held = [[-(-ctx // 4) for ctx in round_] for round_ in rounds]
+    assert c["live_pages"] == sum(map(sum, held))
+    assert c["gather_hist"] == {bucket: len(rounds)}
+    assert c["gathered_pages"] == bucket * len(rounds) >= c["live_pages"]
+    assert engine.decode_buckets()[0] == bucket
+
+
+def test_a_loaded_engine_compiles_no_decode_program(tiny_params):
+    """``load_decode_programs`` (what ``LLMServer`` calls before it is
+    ready) runs every bucket a page list can take, with no slot active:
+    no page changes, no counter moves, ``_burst_width`` is not called;
+    and a mixed run after it (contexts of 3 to 40 tokens, 1 to 3 slots
+    decoding, bursts of 1 to 4) adds no entry to ``decode_burst``'s
+    compile cache: a width is an operand, a bucket is loaded. Three
+    slots of 12 pages: powers of two from 16, and the 36 they hold."""
+    import numpy as np
+
+    from ray_tpu.llm.runner import decode_burst
+
+    engine = LLMEngine(tiny_params, CFG, EngineConfig(
+        max_num_seqs=3, page_size=4, num_pages=48, max_seq_len=48,
+        decode_burst=4))
+    calls = _watch_burst_width(engine)
+    before = (np.asarray(engine.cache.k), engine.stats()["counters"])
+    buckets = engine.decode_buckets()
+    assert buckets == [16, 32, 36]
+    assert {engine._flat_bucket(n) for n in range(1, 37)} == set(buckets)
+    assert engine.load_decode_programs() == len(buckets)
+    assert not calls and engine.stats()["counters"] == before[1]
+    np.testing.assert_array_equal(np.asarray(engine.cache.k), before[0])
+    size = decode_burst._cache_size()
+    for i, n in enumerate([40, 38, 36, 3, 17, 9, 5, 24]):
+        engine.add_request([1 + (i + j) % 200 for j in range(n)],
+                           SamplingParams(temperature=0.0,
+                                          max_tokens=8 - i % 7))
+    while engine.has_unfinished():
+        engine.step()
+    c = engine.stats()["counters"]
+    assert c["rounds"] == len(calls) > 3 and len(set(calls)) > 1
+    assert decode_burst._cache_size() == size
+    assert set(c["gather_hist"]) <= set(buckets)
+    assert len(c["gather_hist"]) > 1
+
+
+@pytest.mark.parametrize("pool, loads", [
+    (None, 1), ("decode", 1), ("prefill", 0)])
+def test_a_replica_loads_its_decode_programs_with_its_role(pool, loads):
+    """``serve``'s ``Replica`` calls ``configure_pool`` in its
+    constructor, so before the replica reports ready: a replica that
+    decodes (no pools, or the decode pool) loads every decode program
+    there, once; a prefill replica, which never runs a decode round,
+    loads none."""
+    from ray_tpu.llm.serve import LLMServer
+
+    server = LLMServer("tiny", engine_config={
+        "max_num_seqs": 2, "page_size": 4, "num_pages": 32,
+        "max_seq_len": 32, "decode_burst": 4})
+    calls = []
+    load = server.engine.load_decode_programs
+    server.engine.load_decode_programs = lambda: calls.append(load())
+    server.configure_pool(pool, "llm")
+    assert len(calls) == loads
+    assert calls == [len(server.engine.decode_buckets())] * loads
+
+
 def test_counters_are_a_copy(served, tiny_params):
     engine = LLMEngine(tiny_params, CFG, EngineConfig(
         max_num_seqs=1, page_size=4, num_pages=32, max_seq_len=32))
     snapshot = engine.stats()["counters"]
     snapshot["width_hist"][1] = 99
     snapshot["host_s"]["append"] = 99.0
+    snapshot["gather_hist"][16] = 99
     fresh = engine.stats()["counters"]
     assert fresh["width_hist"][1] == 0 and fresh["host_s"]["append"] == 0.0
+    assert fresh["gather_hist"] == {}
     # the keys the harness and the pump read stay where they were
     assert {"running", "waiting", "free_pages", "total_pages",
             "counters"} <= set(engine.stats())

@@ -108,9 +108,17 @@ def test_flash_forward_backward_compiles_for_v5e(one_chip, shape):
                          *_qkv(shape, one_chip)) == 3
 
 
-def test_decode_burst_8b_int8_fits_one_v5e(one_chip):
+@pytest.mark.parametrize("layout", ["table-rectangle", "flat-list-64"])
+def test_decode_burst_8b_int8_fits_one_v5e(one_chip, layout):
     """The whole decode program chip_smoke.py serves with: Llama-3-8B,
-    int8 weights, 8 slots, from shapes alone."""
+    int8 weights, 8 slots, from shapes alone: over the table's rectangle
+    (no list: the call ``benchmarks/aot_fit.py`` makes) and over one flat
+    list of 64 live pages with the steps as an operand (what the engine
+    runs). Either copies the listed pages ONCE: the compiled text holds
+    the uninitialised buffers the loop fills and none of ``jnp.take``'s
+    second and third passes (the zero fill was a ``broadcast``, the
+    transposition a ``copy_select_fusion``), nor a ``copy`` of the
+    loop's result."""
     from ray_tpu.llm.runner import decode_burst
     from ray_tpu.models import LLAMA_CONFIGS
     from ray_tpu.ops import rope_frequencies
@@ -127,16 +135,25 @@ def test_decode_burst_8b_int8_fits_one_v5e(one_chip):
                   cfg.head_dim), cfg.dtype, one_chip)
     i32 = _sds((B,), jnp.int32, one_chip)
     f32 = _sds((B,), jnp.float32, one_chip)
+    listed = () if layout == "table-rectangle" else (
+        _sds((3, 64), jnp.int32, one_chip), _sds((), jnp.int32, one_chip))
     compiled = decode_burst.lower(
         params, cache, cache, i32, i32,
-        _sds((B, 8), jnp.int32, one_chip), _sds((B,), jnp.bool_, one_chip),
-        cos, sin, i32, f32, i32, f32, None, cfg=cfg, n_steps=K,
-        greedy=True).compile()
+        _sds((B, 8 if not listed else max_seq // page), jnp.int32, one_chip),
+        _sds((B,), jnp.bool_, one_chip), cos, sin, i32, f32, i32, f32, None,
+        *listed, cfg=cfg, n_steps=K, greedy=True).compile()
     mem = compiled.memory_analysis()
     # the donated cache aliases its output; everything else is live at once
     live = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert 8 * 1024**3 < live < V5E_HBM_BYTES, mem
+    text = compiled.as_text()
+    assert text.count('custom_call_target="AllocateBuffer"') >= 2
+    assert "copy_select_fusion" not in text
+    assert " copy(%while" not in text
+    gathered = f"bf16[{cfg.n_layers},64,{page},"      # the copy, page-major
+    assert not [line for line in text.splitlines()
+                if " broadcast(" in line and gathered in line]
 
 
 # the two served configurations of BENCHMARK.json at their published
