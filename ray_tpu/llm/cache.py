@@ -10,6 +10,20 @@ decisions made host-side by a free-list allocator.
 Page 0 is reserved and never handed out: block tables are padded with 0,
 so the gathers read it (under a mask). Nothing writes to it: rows that are
 not tokens are dropped by the one scatter (``runner._write_rows``).
+
+Layer groups. Layers whose keys live equally long share a pool, an
+allocator and a table (``LlamaConfig.kv_groups``: the layers that see the
+whole sequence; the layers that see a window). A configuration with one
+kind of layer has one group and everything here is what it was: one pair
+of arrays, one allocator, one table. With two, ``KVCache.k`` and ``.v``
+are tuples of arrays, one a group, each with its group's layers in front
+and its own number of pages; the tables are indexed by a position's page
+all the same (position // page_size), and a window group's sequence gives
+its oldest pages back as they leave the window
+(``SequenceTable.release_front``): their entries go back to 0, and a row
+whose entry is 0 is written nowhere. The window group's pool is sized by
+what can be live at once (``window_group_pages``), not by the longest
+sequence.
 """
 
 from __future__ import annotations
@@ -25,26 +39,48 @@ import numpy as np
 
 @dataclass
 class KVCache:
-    """Device-side paged cache (one pair of stacked-layer arrays)."""
+    """Device-side paged cache: one pair of stacked-layer arrays, or,
+    for a configuration with several layer groups, a pair of tuples of
+    them (one array a group), the form the runner's programs take."""
 
-    k: jax.Array  # [L, num_pages, page_size, kv_heads, head_dim]
-    v: jax.Array
+    k: Any  # [L, num_pages, page_size, kv_heads, head_dim] (a group)
+    v: Any
 
     @property
     def num_pages(self) -> int:
-        return self.k.shape[1]
+        return jax.tree.leaves(self.k)[0].shape[1]
 
     @property
     def page_size(self) -> int:
-        return self.k.shape[2]
+        return jax.tree.leaves(self.k)[0].shape[2]
 
 
-def init_kv_cache(cfg, num_pages: int, page_size: int,
-                  dtype=None) -> KVCache:
+def window_group_pages(slots: int, window: int, page_size: int,
+                       burst: int) -> int:
+    """Pages of a window group's pool, the reserved page 0 among them:
+    for every slot the window, a page (the window's edges fall inside
+    pages) and a burst's rows."""
+    return slots * -(-(window + page_size + burst) // page_size) + 1
+
+
+def init_kv_cache(cfg, num_pages, page_size: int, dtype=None) -> KVCache:
+    """``num_pages``: an int for a one-group configuration, else one
+    number a group of ``cfg.kv_groups``."""
     dtype = dtype or cfg.dtype
-    shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads,
-             cfg.head_dim)
-    return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+
+    def pools(layers: int, pages: int):
+        return jnp.zeros((layers, pages, page_size, cfg.n_kv_heads,
+                          cfg.head_dim), dtype)
+
+    if isinstance(num_pages, int):
+        if len(cfg.kv_groups) > 1:
+            raise ValueError(f"{len(cfg.kv_groups)} layer groups need a "
+                             f"number of pages each")
+        return KVCache(pools(cfg.n_layers, num_pages),
+                       pools(cfg.n_layers, num_pages))
+    sizes = [(cfg.group_layers(g), n) for g, n in enumerate(num_pages)]
+    return KVCache(tuple(pools(*s) for s in sizes),
+                   tuple(pools(*s) for s in sizes))
 
 
 class PageAllocator:
@@ -221,15 +257,33 @@ class SequenceTable:
 
     def __init__(self, max_seqs: int, max_pages_per_seq: int):
         self.block_tables = np.zeros((max_seqs, max_pages_per_seq), np.int32)
+        # a slot's pages are the entries [first, n_pages): a window
+        # group's sequence has given the ones before ``first`` back
         self.n_pages = np.zeros(max_seqs, np.int32)
+        self.first = np.zeros(max_seqs, np.int32)
         # bumped on every mutation so the engine can cache the device copy
         self.version = 0
 
-    def assign(self, slot: int, pages: List[int]) -> None:
+    def assign(self, slot: int, pages: List[int], first: int = 0) -> None:
+        """``pages`` hold the positions from ``first * page_size`` on."""
         self.block_tables[slot, :] = 0
-        self.block_tables[slot, :len(pages)] = pages
-        self.n_pages[slot] = len(pages)
+        self.block_tables[slot, first:first + len(pages)] = pages
+        self.first[slot] = first
+        self.n_pages[slot] = first + len(pages)
         self.version += 1
+
+    def release_front(self, slot: int, upto: int) -> List[int]:
+        """Take the slot's pages before entry ``upto`` out of the table
+        (their entries read 0 again) and return them, for the allocator."""
+        first = int(self.first[slot])
+        upto = min(upto, int(self.n_pages[slot]))
+        if upto <= first:
+            return []
+        pages = [int(p) for p in self.block_tables[slot, first:upto]]
+        self.block_tables[slot, first:upto] = 0
+        self.first[slot] = upto
+        self.version += 1
+        return pages
 
     def append_page(self, slot: int, page: int) -> None:
         idx = int(self.n_pages[slot])
@@ -241,10 +295,11 @@ class SequenceTable:
         self.version += 1
 
     def pages_of(self, slot: int) -> List[int]:
-        return [int(p) for p in
-                self.block_tables[slot, :int(self.n_pages[slot])]]
+        return [int(p) for p in self.block_tables[
+            slot, int(self.first[slot]):int(self.n_pages[slot])]]
 
     def clear(self, slot: int) -> None:
         self.block_tables[slot, :] = 0
         self.n_pages[slot] = 0
+        self.first[slot] = 0
         self.version += 1

@@ -14,7 +14,14 @@ TPU-first:
     step between decode steps (chunked-prefill-lite: bounded TTFT impact
     on running streams);
   * all paging is host-side (PageAllocator); the device never sees an
-    allocation decision, only block tables.
+    allocation decision, only block tables;
+  * layers whose keys live equally long share a pool, an allocator and a
+    table (``cache.py``, "Layer groups"): a configuration of full and
+    window layers has two of each, a request is admitted when every
+    group can hold it, and a window group's sequence gives its pages
+    back as they leave the window (``_release``), after its prefill and
+    after every burst. With one kind of layer there is one group and
+    ``allocator``, ``seq_table`` and ``cache`` are what they were.
 
 The engine is synchronous and single-threaded by design — an actor wraps
 it for serving (ray_tpu.llm.serve) the way vLLM's AsyncLLMEngine wraps
@@ -38,7 +45,7 @@ from ..models.llama import LlamaConfig
 from ..ops import rope_frequencies
 from ..util import tracing
 from .cache import (KVCache, PageAllocator, PrefixCache, SequenceTable,
-                    init_kv_cache)
+                    init_kv_cache, window_group_pages)
 from .runner import (decode_burst, prefill_bucket, prefill_sample,
                      verify_step)
 from .sampling import SamplingParams
@@ -48,7 +55,10 @@ from .sampling import SamplingParams
 class EngineConfig:
     max_num_seqs: int = 8           # decode slots (static batch width)
     page_size: int = 16
-    num_pages: int = 512            # incl. the reserved page 0
+    # incl. the reserved page 0. Of the layers that see the whole
+    # sequence; a window group's pool is sized by what can be live in
+    # it: cache.window_group_pages(slots, window, page, burst)
+    num_pages: int = 512
     max_seq_len: int = 2048
     kv_dtype: Any = None            # default: model dtype
     # decode steps fused into one device dispatch (multi-step
@@ -118,7 +128,7 @@ class RequestState:
 # the phases that partition a round: the last parts of the span names
 # (rt.engine.<phase>) and the keys of stats()["counters"]["host_s"]
 PHASES = ("schedule", "prefill.dispatch", "prefill.sync",
-          "decode.dispatch", "decode.sync", "append")
+          "decode.dispatch", "release", "decode.sync", "append")
 _SPAN_NAMES = {phase: "rt.engine." + phase for phase in PHASES}
 
 
@@ -133,20 +143,24 @@ class StepOutput:
 
 def burst_gather(tables: np.ndarray, page_size: int, bucket: int,
                  held) -> np.ndarray:
-    """``runner.decode_burst``'s ``gather``, int32 [3, bucket]: ONE list
-    of the pages that hold old context of the decoding slots, slot after
-    slot, each with its (page, owner slot, first position); the rest is
-    owned by nobody (-1). ``tables``: the block tables [B, max_pages];
-    ``held``: (slot, pages that hold its old context) of those slots."""
+    """``runner.decode_burst``'s ``gather`` of one layer group, int32
+    [3, bucket]: ONE list of the pages that hold old context of the
+    decoding slots, slot after slot, each with its (page, owner slot,
+    first position); the rest is owned by nobody (-1). ``tables``: the
+    group's block tables [B, max_pages]; ``held``: (slot, pages that
+    hold its old context) of those slots, with a third number where the
+    first of them is not the table's entry 0 (a window group's)."""
     out = np.zeros((3, bucket), np.int32)
     out[1] = -1
     at = 0
-    for slot, n in held:
-        out[0, at:at + n] = tables[slot, :n]
+    for slot, n, *first in held:
+        first = first[0] if first else 0
+        out[0, at:at + n] = tables[slot, first:first + n]
         out[1, at:at + n] = slot
-        out[2, at:at + n] = np.arange(n) * page_size
+        out[2, at:at + n] = (first + np.arange(n)) * page_size
         at += n
     return out
+
 
 
 class LLMEngine:
@@ -166,11 +180,23 @@ class LLMEngine:
                 f"max_seq_len={self.ecfg.max_seq_len} sequence "
                 f"({need} pages needed, {usable} usable)")
         self.params = params
-        self.cache = init_kv_cache(cfg, self.ecfg.num_pages,
-                                   self.ecfg.page_size,
-                                   self.ecfg.kv_dtype)
-        self.allocator = PageAllocator(self.ecfg.num_pages,
-                                       self.ecfg.page_size)
+        # the layer groups (cache.py): their windows, names and pools
+        self.windows = cfg.kv_groups
+        self.group_names = ["full" if w is None else "window"
+                            for w in self.windows]
+        grouped = len(self.windows) > 1
+        if grouped:
+            self._refuse_with_groups()
+        pool_pages = [
+            self.ecfg.num_pages if w is None else window_group_pages(
+                self.ecfg.max_num_seqs, w, self.ecfg.page_size,
+                self.ecfg.decode_burst) for w in self.windows]
+        self.cache = init_kv_cache(
+            cfg, pool_pages if grouped else pool_pages[0],
+            self.ecfg.page_size, self.ecfg.kv_dtype)
+        self.allocators = [PageAllocator(n, self.ecfg.page_size)
+                           for n in pool_pages]
+        self.allocator = self.allocators[0]
         self.lora_pool = None
         if self.ecfg.lora_rank > 0:
             from .lora import LoRAPool
@@ -199,7 +225,18 @@ class LLMEngine:
                     self.ecfg,
                     prefill_chunk=min(512, self.ecfg.max_seq_len))
         max_pages = self.allocator.pages_needed(self.ecfg.max_seq_len)
-        self.seq_table = SequenceTable(self.ecfg.max_num_seqs, max_pages)
+        if grouped and self.ecfg.prefill_chunk > 0 and any(
+                a.num_pages - 1 < max_pages for a in self.allocators):
+            # a chunked prefill holds every page of its prompt until the
+            # prompt ends (release between chunks: ROADMAP M4)
+            raise ValueError(
+                f"prefill_chunk with layer groups holds a whole prompt's "
+                f"pages in every group until it ends: a group's pool is "
+                f"smaller than one max_seq_len={self.ecfg.max_seq_len} "
+                f"sequence ({max_pages} pages)")
+        self.seq_tables = [SequenceTable(self.ecfg.max_num_seqs, max_pages)
+                           for _ in self.windows]
+        self.seq_table = self.seq_tables[0]
         cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq,
                                     cfg.rope_theta)
         self.cos, self.sin = jax.device_put(cos), jax.device_put(sin)
@@ -226,6 +263,8 @@ class LLMEngine:
         # table mutates (saves one H2D upload per decode step)
         self._bt_device = None
         self._bt_version = -1
+        self._tables_device = None
+        self._tables_version = None
         # always-on round counters (stats()["counters"]): an integer add
         # or a clock difference each, no allocation per token. Decode
         # rounds are the plain bursts (speculative rounds count in
@@ -240,7 +279,14 @@ class LLMEngine:
             # old context of decoding slots, pages the burst copied
             # (their ratio: the share of the copy that was needed), and
             # the rounds by the list's bucket
-            "live_pages": 0, "gathered_pages": 0, "gather_hist": {}}
+            "live_pages": 0, "gathered_pages": 0, "gather_hist": {},
+            # the same a layer group, with the pages its sequences gave
+            # back behind the window and the admissions put off because
+            # this group had too few free pages
+            "groups": {name: {"live_pages": 0, "gathered_pages": 0,
+                              "released_pages": 0,
+                              "deferred_admissions": 0}
+                       for name in self.group_names}}
         if cfg.n_experts:
             # counted on the device by the routed layer, summed over
             # layers and programs: the (token, expert) rows the expert
@@ -249,6 +295,35 @@ class LLMEngine:
         # expert counts of chunked-prefill dispatches nobody waited for
         # yet: read back with the next sampled tokens
         self._pending_counts: List[Any] = []
+
+    def _refuse_with_groups(self) -> None:
+        """What a cache of several layer groups cannot do yet (ROADMAP
+        M4), refused by the option's name before anything is built
+        (``speculation``: by ``enable_speculation``, whoever calls it)."""
+        reasons = {
+            "enable_prefix_caching": (
+                self.ecfg.enable_prefix_caching,
+                "a cached prompt page would have to be shared in every "
+                "group, and a window group gives its pages back"),
+            "lora_rank": (
+                self.ecfg.lora_rank > 0,
+                "adapters ride a scan over layers, not over periods of "
+                "a layer pattern, and no test runs both"),
+        }
+        for option, (asked, why) in reasons.items():
+            if asked:
+                raise ValueError(
+                    f"EngineConfig.{option} is not supported with "
+                    f"{len(self.windows)} layer groups (full and window "
+                    f"layers side by side): {why}")
+
+    def _refuse_kv_transfer(self, what: str) -> None:
+        if len(self.windows) > 1:
+            raise ValueError(
+                f"{what} is not supported with {len(self.windows)} layer "
+                f"groups: a KV payload is one stack of pages for all "
+                f"layers, and a window group holds only the pages inside "
+                f"its window")
 
     def _read_back(self, toks, counts=None):
         """The sampled tokens on the host (the round's one sync). An
@@ -306,6 +381,11 @@ class LLMEngine:
         drafter's random init (a trained 400m draft checkpoint)."""
         from .spec_decode import SpecDecoder
 
+        if len(self.windows) > 1:
+            raise ValueError(
+                "EngineConfig.speculation is not supported with "
+                f"{len(self.windows)} layer groups: the drafter mirrors "
+                "ONE page pool and one block table")
         if self.lora_pool is not None:
             raise ValueError("speculation is incompatible with "
                              "lora_rank > 0 (drafter has no adapters)")
@@ -458,6 +538,22 @@ class LLMEngine:
                 self.allocator.free([hits.pop()])
             cached_pages = hits
         fresh_tokens = seq_len + 1 - len(cached_pages) * self.ecfg.page_size
+        # a window group holds the pages from the one the first key the
+        # next query sees lies on (a chunked prefill fills, and holds,
+        # them all until the prompt ends)
+        firsts = [0 if w is None or self.ecfg.prefill_chunk > 0
+                  else self._first_live_page(seq_len, w)
+                  for w in self.windows]
+        end = self.allocator.pages_needed(seq_len + 1)
+        short = [name for name, allocator, first in list(zip(
+            self.group_names, self.allocators, firsts))[1:]
+            if allocator.free_pages < end - first]
+        if short:
+            for name in short:
+                self._counters["groups"][name]["deferred_admissions"] += 1
+            if cached_pages:
+                self.allocator.free(cached_pages)
+            return None
         if not self.allocator.can_allocate(fresh_tokens):
             if self.prefix_cache is not None:
                 need = self.allocator.pages_needed(fresh_tokens)
@@ -469,6 +565,8 @@ class LLMEngine:
             if not self.allocator.can_allocate(fresh_tokens):
                 if cached_pages:
                     self.allocator.free(cached_pages)
+                self._counters["groups"][self.group_names[0]][
+                    "deferred_admissions"] += 1
                 return None
         self.waiting.popleft()
         pages = cached_pages + self.allocator.allocate(
@@ -480,7 +578,30 @@ class LLMEngine:
             state.admit_t = time.perf_counter()
         self.slots[slot] = state
         self.seq_table.assign(slot, pages)
+        for allocator, table, first in list(zip(
+                self.allocators, self.seq_tables, firsts))[1:]:
+            table.assign(slot, allocator.allocate(end - first), first)
         return state
+
+    def _first_live_page(self, ctx_len: int, window: int) -> int:
+        """The table entry of the oldest key a window layer's next query
+        (at position ``ctx_len``) sees: every page before it has left the
+        window."""
+        return max(ctx_len - window + 1, 0) // self.ecfg.page_size
+
+    def _release(self, state: RequestState) -> None:
+        """Give back the pages of a decoding sequence's window groups
+        that have left the window (reference counts as they are: a page
+        something else holds survives)."""
+        for g, window in enumerate(self.windows):
+            if window is None or state.slot < 0:
+                continue
+            pages = self.seq_tables[g].release_front(
+                state.slot, self._first_live_page(state.ctx_len, window))
+            if pages:
+                self.allocators[g].free(pages)
+                self._counters["groups"][self.group_names[g]][
+                    "released_pages"] += len(pages)
 
     # smallest block-table span bucket, in pages: a rectangle of a row
     # a slot (a speculative round) reads the longest ACTIVE context's
@@ -496,6 +617,23 @@ class LLMEngine:
             self._bt_device = jnp.asarray(table)
             self._bt_version = key
         return self._bt_device
+
+    def _tables(self):
+        """The block tables on the device as the programs take them: the
+        one group's, or a tuple with one a group."""
+        key = tuple(t.version for t in self.seq_tables)
+        if self._tables_version != key:
+            self._tables_device = tuple(
+                jnp.asarray(t.block_tables) for t in self.seq_tables)
+            self._tables_version = key
+        tables = self._tables_device
+        return tables if len(tables) > 1 else tables[0]
+
+    def _rows(self, slot: int):
+        """One slot's rows of the block tables, as ``_tables``."""
+        rows = tuple(jnp.asarray(t.block_tables[slot:slot + 1])
+                     for t in self.seq_tables)
+        return rows if len(rows) > 1 else rows[0]
 
     def _span_bucket(self, pages: int) -> int:
         """Power-of-2 page-span bucket, capped at the table width."""
@@ -525,29 +663,69 @@ class LLMEngine:
     # hundred KB a layer and a finer bucket buys nothing
     _FLAT_PAGES = 16
 
-    def _listable_pages(self) -> int:
-        """The most pages a flat list can hold: every slot's whole table,
-        or the pool where no page is shared (a page two slots share, the
-        prefix cache's, is listed once for each)."""
-        B, top = self.seq_table.block_tables.shape
-        if self.prefix_cache is not None:
-            return B * top
-        return min(B * top, self.ecfg.num_pages - 1)
+    def _slot_pages(self, g: int):
+        """(fewest, most) pages of group ``g`` that can hold old context
+        of one long decoding slot: the table's width, or for a window
+        group the window's pages, one more where its edges fall inside
+        pages."""
+        top, window = self.seq_table.block_tables.shape[1], self.windows[g]
+        if window is None:
+            return top, top
+        page = self.ecfg.page_size
+        return min(top, window // page), min(top, -(-window // page) + 1)
 
-    def _flat_bucket(self, pages: int) -> int:
+    def _listable_pages(self, g: int = 0) -> int:
+        """The most pages a flat list of group ``g`` can hold: every
+        slot's whole table (or window), or the pool where no page is
+        shared (a page two slots share, the prefix cache's, is listed
+        once for each)."""
+        B = self.seq_table.block_tables.shape[0]
+        most = B * self._slot_pages(g)[1]
+        if self.prefix_cache is not None:
+            return most
+        return min(most, self.allocators[g].num_pages - 1)
+
+    def _flat_bucket(self, pages: int, g: int = 0) -> int:
         """Power-of-2 bucket of a flat list, capped at what can be
         listed (a pool of 384 pages tops out there, not at 512)."""
-        return prefill_bucket(pages, self._listable_pages(),
+        return prefill_bucket(pages, self._listable_pages(g),
                               self._FLAT_PAGES)
 
-    def decode_buckets(self):
-        """Every bucket ``_flat_bucket`` can return for this engine: the
-        decode programs a server loads before it is ready."""
-        top = self._listable_pages()
+    def _ladder(self, g: int):
+        top = self._listable_pages(g)
         buckets = [min(self._FLAT_PAGES, top)]
         while buckets[-1] < top:
             buckets.append(min(2 * buckets[-1], top))
         return buckets
+
+    def decode_buckets(self):
+        """Every shape a burst's page lists can take for this engine:
+        the decode programs a server loads before it is ready. One group:
+        every bucket ``_flat_bucket`` can return. Several: tuples of a
+        bucket a group, those that can meet. A slot with ``f`` pages of
+        old context lists ``f`` of them in a window group too until the
+        window is full, then the window's, so the groups' lists grow
+        together and part only for long slots: with ``F`` pages listed
+        in the full group, a window group lists at least what the
+        fewest, longest slots would and at most min(F, all windows)."""
+        if len(self.windows) == 1:
+            return self._ladder(0)
+        top = self.seq_table.block_tables.shape[1]
+        B = self.seq_table.block_tables.shape[0]
+        shapes, lowest = [], 1
+        for bucket in self._ladder(0):
+            choices = []
+            for g in range(1, len(self.windows)):
+                least, most = self._slot_pages(g)
+                fewest = (lowest // top) * least + min(lowest % top, least)
+                choices.append([b for b in self._ladder(g) if
+                                self._flat_bucket(fewest, g) <= b
+                                <= self._flat_bucket(
+                                    min(bucket, B * most), g)])
+            shapes.extend((bucket, *rest)
+                          for rest in itertools.product(*choices))
+            lowest = bucket + 1
+        return shapes
 
     def load_decode_programs(self) -> int:
         """Run ``decode_burst`` once in every shape a plain greedy round
@@ -565,14 +743,17 @@ class LLMEngine:
         if self.lora_pool is not None:
             lora = self.lora_pool.select([0] * B)
         buckets = self.decode_buckets()
-        for bucket in buckets:
+        for shape in buckets:
+            lists = tuple(jnp.asarray(burst_gather(
+                t.block_tables, self.ecfg.page_size, bucket, ()))
+                for t, bucket in zip(self.seq_tables, (
+                    shape if isinstance(shape, tuple) else (shape,))))
             _toks, ck, cv, _counts = decode_burst(
                 self.params, self.cache.k, self.cache.v, zi, zi,
-                self._bt(), jnp.zeros(B, bool), self.cos, self.sin, 0, zf,
-                zi, zf, lora, jnp.asarray(burst_gather(
-                    self.seq_table.block_tables, self.ecfg.page_size,
-                    bucket, ())), jnp.int32(1), cfg=self.cfg,
-                n_steps=self.ecfg.decode_burst, greedy=True)
+                self._tables(), jnp.zeros(B, bool), self.cos, self.sin, 0,
+                zf, zi, zf, lora,
+                lists if len(lists) > 1 else lists[0], jnp.int32(1),
+                cfg=self.cfg, n_steps=self.ecfg.decode_burst, greedy=True)
             self.cache = KVCache(ck, cv)
         jax.block_until_ready(self.cache.k)
         return len(buckets)
@@ -625,8 +806,7 @@ class LLMEngine:
             toks, ck, cv, counts = prefill_sample(
                 self.params, self.cache.k, self.cache.v,
                 jnp.asarray(tokens), jnp.asarray([L], jnp.int32),
-                jnp.asarray(self.seq_table.block_tables[
-                    state.slot:state.slot + 1]),
+                self._rows(state.slot),
                 self.cos, self.sin, seed, temp, top_k, top_p, lora,
                 cfg=self.cfg, greedy=greedy)
         self.cache = KVCache(ck, cv)
@@ -657,11 +837,9 @@ class LLMEngine:
         params, ck, cv, cos, sin = jax.tree.map(
             abstract, (self.params, self.cache.k, self.cache.v,
                        self.cos, self.sin))
-        tables = self.seq_table.block_tables
         return bucket, prefill_sample.lower(
             params, ck, cv, jax.ShapeDtypeStruct((1, bucket), jnp.int32),
-            row(jnp.int32),
-            jax.ShapeDtypeStruct((1, tables.shape[1]), tables.dtype),
+            row(jnp.int32), jax.tree.map(abstract, self._rows(0)),
             cos, sin, 0, row(jnp.float32), row(jnp.int32),
             row(jnp.float32), None, cfg=self.cfg, greedy=True).compile()
 
@@ -678,8 +856,9 @@ class LLMEngine:
             # a handful of executables serve every prompt length
             span = self._span_bucket(
                 -(-(start + n) // self.ecfg.page_size))
-            bt = jnp.asarray(self.seq_table.block_tables[
-                state.slot:state.slot + 1, :span])
+            bt = tuple(jnp.asarray(t.block_tables[
+                state.slot:state.slot + 1, :span]) for t in self.seq_tables)
+            bt = bt if len(bt) > 1 else bt[0]
             logits, ck, cv, counts = prefill_chunk(
                 self.params, self.cache.k, self.cache.v,
                 jnp.asarray(tokens), jnp.int32(start), jnp.int32(n), bt,
@@ -708,6 +887,8 @@ class LLMEngine:
         state.ctx_len = L
         if not state.output:
             state.first_token_t = time.perf_counter()
+        with self._phase("release"):
+            self._release(state)     # the chunks held the whole prompt
         with self._phase("append"):
             return [self._append_token(state, tok)]
 
@@ -719,8 +900,7 @@ class LLMEngine:
         self._counters["preemptions"] += 1
         if self.spec is not None:
             self.spec.drop(state.slot)   # drafter KV dies with the pages
-        self.allocator.free(self.seq_table.pages_of(state.slot))
-        self.seq_table.clear(state.slot)
+        self._free_slot_pages(state.slot)
         self.slots[state.slot] = None
         state.slot = -1
         state.ctx_len = 0
@@ -731,6 +911,11 @@ class LLMEngine:
         except ValueError:
             pass
         self.waiting.appendleft(state)
+
+    def _free_slot_pages(self, slot: int) -> None:
+        for allocator, table in zip(self.allocators, self.seq_tables):
+            allocator.free(table.pages_of(slot))
+            table.clear(slot)
 
     def _pick_victim(self, exclude: RequestState) -> Optional[RequestState]:
         candidates = [s for s in self.slots
@@ -753,23 +938,23 @@ class LLMEngine:
         return max(1, K)
 
     def _provision_pages(self, s: RequestState, upto: int) -> None:
-        """Ensure slot pages cover positions [0, upto); preempt youngest
-        others when the pool runs dry (init guarantees a lone sequence
-        always fits)."""
-        while int(self.seq_table.n_pages[s.slot]) * self.ecfg.page_size \
-                < upto:
-            if self.allocator.free_pages < 1 and self.prefix_cache:
-                self.prefix_cache.evict(1)   # cache before victims
-            if self.allocator.free_pages >= 1:
-                self.seq_table.append_page(
-                    s.slot, self.allocator.allocate(1)[0])
-                continue
-            victim = self._pick_victim(exclude=s)
-            if victim is None:
-                raise MemoryError(
-                    "single sequence exhausted the KV cache — "
-                    "num_pages/max_seq_len misconfigured")
-            self._preempt(victim)
+        """Ensure slot pages cover positions [0, upto) in every group
+        (from its first live page on); preempt youngest others when a
+        pool runs dry (init guarantees a lone sequence always fits)."""
+        for allocator, table in zip(self.allocators, self.seq_tables):
+            while s.slot >= 0 and (int(table.n_pages[s.slot])
+                                   * self.ecfg.page_size < upto):
+                if allocator.free_pages < 1 and self.prefix_cache:
+                    self.prefix_cache.evict(1)   # cache before victims
+                if allocator.free_pages >= 1:
+                    table.append_page(s.slot, allocator.allocate(1)[0])
+                    continue
+                victim = self._pick_victim(exclude=s)
+                if victim is None:
+                    raise MemoryError(
+                        "single sequence exhausted the KV cache — "
+                        "num_pages/max_seq_len misconfigured")
+                self._preempt(victim)
 
     def _run_decode(self) -> List[StepOutput]:
         if self.spec is not None:
@@ -812,21 +997,36 @@ class LLMEngine:
                 for s2 in active_states:
                     ids[s2.slot] = self.lora_pool.slot_of(s2.model_id)
                 lora = self.lora_pool.select(ids)
-            page = self.ecfg.page_size
-            held = [-(-s.ctx_len // page) for s in active_states]
-            bucket = self._flat_bucket(sum(held))
-            gather = burst_gather(
-                self.seq_table.block_tables, page, bucket,
-                zip((s.slot for s in active_states), held))
-            counters["live_pages"] += sum(held)
-            counters["gathered_pages"] += bucket
+        with self._phase("release"):
+            # a group's list: the pages that hold old context its layers
+            # can still see
+            page, lists, shape = self.ecfg.page_size, [], []
+            for g, window in enumerate(self.windows):
+                held = []
+                for s in active_states:
+                    first = 0 if window is None else \
+                        self._first_live_page(s.ctx_len, window)
+                    held.append((s.slot, -(-s.ctx_len // page) - first,
+                                 first))
+                live = sum(n for _slot, n, _first in held)
+                bucket = self._flat_bucket(live, g)
+                lists.append(jnp.asarray(burst_gather(
+                    self.seq_tables[g].block_tables, page, bucket, held)))
+                shape.append(bucket)
+                for c in (counters, counters["groups"][self.group_names[g]]):
+                    c["live_pages"] += live
+                    c["gathered_pages"] += bucket
             hist = counters["gather_hist"]
-            hist[bucket] = hist.get(bucket, 0) + 1
+            shape = shape[0] if len(shape) == 1 else "/".join(
+                map(str, shape))
+            hist[shape] = hist.get(shape, 0) + 1
+        with self._phase("decode.dispatch"):
             toks, ck, cv, counts = decode_burst(
                 self.params, self.cache.k, self.cache.v,
-                jnp.asarray(tokens), jnp.asarray(positions), self._bt(),
+                jnp.asarray(tokens), jnp.asarray(positions), self._tables(),
                 jnp.asarray(active), self.cos, self.sin,
-                seed, temp, top_k, top_p, lora, jnp.asarray(gather),
+                seed, temp, top_k, top_p, lora,
+                tuple(lists) if len(lists) > 1 else lists[0],
                 jnp.int32(K), cfg=self.cfg,
                 n_steps=self.ecfg.decode_burst, greedy=greedy)
         self.cache = KVCache(ck, cv)
@@ -841,6 +1041,9 @@ class LLMEngine:
                         self._append_token(s, int(sampled[k, s.slot])))
                     if s.finished:
                         break
+        with self._phase("release"):
+            for s in active_states:
+                self._release(s)
         return outs
 
     # --- speculative decoding (spec_decode.py; Leviathan et al.) ---
@@ -1052,8 +1255,7 @@ class LLMEngine:
         if state.slot >= 0:
             if self.spec is not None:
                 self.spec.drop(state.slot)
-            self.allocator.free(self.seq_table.pages_of(state.slot))
-            self.seq_table.clear(state.slot)
+            self._free_slot_pages(state.slot)
             self.slots[state.slot] = None
             state.slot = -1
         elif state in self.waiting:
@@ -1078,6 +1280,7 @@ class LLMEngine:
         finishes the request locally (reason "handoff" — its slot and
         pages free immediately for the next prompt) and returns a
         payload :meth:`inject_request` accepts on the decode engine."""
+        self._refuse_kv_transfer("export_kv_request")
         payload = self.snapshot_kv_request(request_id)
         self._finish(self.requests[request_id], "handoff")
         return payload
@@ -1088,6 +1291,7 @@ class LLMEngine:
         snapshots to a prefill-class verifier while local decode
         continues — both compute the identical emission (spec_decode.py
         module docstring), so nothing is handed off."""
+        self._refuse_kv_transfer("snapshot_kv_request")
         state = self.requests.get(request_id)
         if state is None:
             raise ValueError(f"unknown request {request_id!r}")
@@ -1118,6 +1322,7 @@ class LLMEngine:
         page-size mismatch, pool pressure, malformed/missing arrays)
         the request joins the waiting queue and recomputes its prefill
         locally (recompute-preemption semantics): slower, never wrong."""
+        self._refuse_kv_transfer("inject_request")
         prompt = [int(t) for t in payload["prompt"]]
         output = [int(t) for t in payload.get("output") or ()]
         ctx_len = int(payload["ctx_len"])
@@ -1235,8 +1440,8 @@ class LLMEngine:
         out = {
             "running": sum(s is not None for s in self.slots),
             "waiting": len(self.waiting),
-            "free_pages": self.allocator.free_pages,
-            "total_pages": self.allocator.num_pages - 1,
+            "free_pages": sum(a.free_pages for a in self.allocators),
+            "total_pages": sum(a.num_pages - 1 for a in self.allocators),
         }
         if self.spec is not None:
             out["spec"] = self.spec.stats()
@@ -1244,5 +1449,11 @@ class LLMEngine:
                            "width_hist": list(self._counters["width_hist"]),
                            "host_s": dict(self._counters["host_s"]),
                            "gather_hist": dict(
-                               self._counters["gather_hist"])}
+                               self._counters["gather_hist"]),
+                           "groups": {
+                               name: {**group, "free_pages": a.free_pages,
+                                      "total_pages": a.num_pages - 1}
+                               for (name, group), a in zip(
+                                   self._counters["groups"].items(),
+                                   self.allocators)}}
         return out

@@ -39,6 +39,19 @@ select or fill behind it); inside a layer scan one layer's pool is
 gathered by ``_take_span``, a plain ``jnp.take``, which is the faster
 there (both measured alone on the chip: PERF.md, PR 32).
 
+Layer groups. A configuration whose layers differ (``LlamaConfig.
+layer_pattern``: with or without the rotary embedding, the whole sequence
+or a window of it) still has the one ``_block``: ``_layers`` scans over
+PERIODS of the pattern and the body runs the period's layers in turn,
+each with its kind. Layers whose keys live equally long share a page
+pool (``llm/cache.py``), so a program takes its pools, its block tables
+and a burst's page lists one a group (a tuple; one given bare is the one
+group's, and a one-group configuration lowers to what it always did);
+``attend`` is told the layer's window and finds its group's state by it.
+A window layer's mask has a lower bound (key position > query position -
+window); a window group's row whose table entry is the reserved page 0
+(a page that left the window and was given back) is written nowhere.
+
 Static shapes throughout: prefill pads a prompt to a power-of-2 bucket
 (one executable a bucket), decode runs the whole slot batch every step
 with inactive slots masked over a page list padded to a power-of-2
@@ -56,8 +69,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ..models.llama import LlamaConfig, qk_norm
+from ..models.llama import LlamaConfig, qk_norm, rotated, windowed
 from ..ops import apply_rotary, attention, rms_norm
+from ..ops.moe import router_logits
 from ..ops.quant import embed_lookup, is_quantized, weight_einsum
 from .lora import lora_delta
 from .sampling import sample_from_logits
@@ -80,14 +94,36 @@ def _split_layers(layers, cfg: LlamaConfig):
     return sliced, {k: layers[k] for k in _EXPERT_STACKS}
 
 
-def _mlp(h, lp, cfg: LlamaConfig, valid=None, experts=None):
+def _groups(x):
+    """Pools, block tables or page lists, one a layer group: given bare,
+    the one group's."""
+    return x if isinstance(x, tuple) else (x,)
+
+
+def _pools(cache_k, cache_v):
+    """The (K, V) pair of pools of each layer group."""
+    return tuple(zip(_groups(cache_k), _groups(cache_v)))
+
+
+def _ungrouped(pools, like):
+    """(cache_k, cache_v) out of the groups' (K, V) pairs, in the form
+    ``like`` came in: tuples a group, or the one group's bare arrays."""
+    cache_k, cache_v = zip(*pools)
+    if isinstance(like, tuple):
+        return cache_k, cache_v
+    return cache_k[0], cache_v[0]
+
+
+def _mlp(h, lp, cfg: LlamaConfig, valid=None, experts=None, logits=None):
     """Serving MLP: dense SwiGLU, or for expert configs the one dropless
     routed layer (``ops.moe.moe_mlp_routed``): no capacity, so a
     sequence's answer does not change with its batch, and rows that are
     not tokens (``valid`` [B, S] False: bucket padding, inactive slots)
     are given to no expert; ``lp`` and ``experts`` are ``_split_layers``'
-    two halves. Returns (out, counts): the layer's
-    (expert rows, experts touched) int32 [2], None for a dense config."""
+    two halves; ``logits``: the router's, where ``_block`` computed them
+    before attention (``cfg.router_input``). Returns (out, counts): the
+    layer's (expert rows, experts touched) int32 [2], None for a dense
+    config."""
     if cfg.n_experts:
         from ..ops.moe import moe_mlp_routed
 
@@ -95,7 +131,7 @@ def _mlp(h, lp, cfg: LlamaConfig, valid=None, experts=None):
             h, lp["router"], experts["w_gate"], experts["w_up"],
             experts["w_down"], top_k=cfg.top_k,
             norm_topk_prob=cfg.norm_topk_prob, valid=valid,
-            layer=lp["layer"])
+            layer=lp["layer"], logits=logits, activation=cfg.expert_act)
     g = weight_einsum("bsd,dm->bsm", h, lp["w_gate"])
     u = weight_einsum("bsd,dm->bsm", h, lp["w_up"])
     return weight_einsum("bsm,md->bsd", jax.nn.silu(g) * u,
@@ -234,19 +270,25 @@ def _attend(q, *segments):
     return sum(outs[1:], outs[0]).reshape(q.shape)
 
 
-def _block(x, inputs, *, cfg: LlamaConfig, cos, sin, positions, valid,
+def _block(x, inputs, *, cfg: LlamaConfig, kind, cos, sin, positions, valid,
            attend, experts, lora_scale):
     """The decoder layer, once, as the body of a scan over layers.
 
     x: [B, S, d]; inputs: (the layer's weights, the layer's slice of the
     program's own state, the layer's per-slot adapter rows: low-rank
-    deltas on wq/wv, llm/lora.py, empty = base model); positions: [B, S]
-    rotary positions, None = 0..S-1; valid: [B, S], the rows that are
-    tokens; ``attend(q, k, v, state) -> (o [B, S, heads, hd], kept)``.
+    deltas on wq/wv, llm/lora.py, empty = base model); ``kind``: the
+    layer's (``LlamaConfig.layer_pattern``: rotated or not, windowed or
+    not); positions: [B, S] rotary positions, None = 0..S-1; valid:
+    [B, S], the rows that are tokens; ``attend(q, k, v, state, window)
+    -> (o [B, S, heads, hd], kept)``, ``window`` the layer's or None.
     Returns (x, (kept, expert counts: see ``_mlp``)).
     """
     lp, state, lr = inputs
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    # a router that reads the attention's input: its logits are known a
+    # whole attention before the experts need them
+    logits = router_logits(h, lp["router"]) if (
+        cfg.n_experts and cfg.router_input == "attention") else None
     q = weight_einsum("bsd,dhk->bshk", h, lp["wq"])
     k = weight_einsum("bsd,dhk->bshk", h, lp["wk"])
     v = weight_einsum("bsd,dhk->bshk", h, lp["wv"])
@@ -256,38 +298,98 @@ def _block(x, inputs, *, cfg: LlamaConfig, cos, sin, positions, valid,
         v = v + lora_delta(h, lr["a_v"], lr["b_v"], lora_scale,
                            cfg.n_kv_heads, cfg.head_dim)
     q, k = qk_norm(q, k, lp, cfg)
-    q = apply_rotary(q, cos, sin, positions=positions)
-    k = apply_rotary(k, cos, sin, positions=positions)
-    o, kept = attend(q, k, v, state)
+    if rotated(kind):
+        q = apply_rotary(q, cos, sin, positions=positions)
+        k = apply_rotary(k, cos, sin, positions=positions)
+    with jax.named_scope("rt.attn.window" if windowed(kind)
+                         else "rt.attn.full"):
+        o, kept = attend(q, k, v, state,
+                         cfg.window if windowed(kind) else None)
     x = x + weight_einsum("bshk,hkd->bsd", o.astype(x.dtype), lp["wo"])
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    m, counts = _mlp(h, lp, cfg, valid, experts)
+    m, counts = _mlp(h, lp, cfg, valid, experts, logits)
     return x + m, (kept, counts)
 
 
 def _layers(params, cfg: LlamaConfig, cos, sin, lora=None):
     """-> ``run(x, state, attend, *, positions, valid)``: ONE
     ``lax.scan`` of ``_block`` over the stacked layers, returning (x,
-    what ``attend`` kept [L, ...], expert counts over the layers).
-    ``state``: the program's own per-layer pytree (leading dimension L),
-    or None. ``lora``: per-slot batched adapters from
-    ``LoRAPool.select(ids)``, empty/None = base model. What every call
-    shares is made here once: a burst calls ``run`` at every step."""
+    what ``attend`` kept, a tuple with [L_g, ...] a layer group, expert
+    counts over the layers). ``state``: the program's own per-layer
+    state, a tuple with one pytree a layer group (leading dimension L_g,
+    the group's layers), or None. ``lora``: per-slot batched adapters
+    from ``LoRAPool.select(ids)``, empty/None = base model. What every
+    call shares is made here once: a burst calls ``run`` at every step.
+
+    A configuration with a layer pattern of ``n`` kinds is scanned a
+    period at a time: the body runs the period's ``n`` layers in turn,
+    each with its kind, its weights and its place in its group's state,
+    all taken by the layer's index."""
     layers, experts = _split_layers(params["layers"], cfg)
     # adapters ride the layer scan as xs: [B, L, ...] -> [L, B, ...]
     lora_xs = {} if not lora else {
         k2: jnp.swapaxes(v2, 0, 1) for k2, v2 in lora.items()
         if k2 != "scale"}
+    kinds = cfg.layer_kinds
+    if lora_xs and len(kinds) > 1:
+        # LLMEngine refuses lora_rank with layer groups; adapters ride
+        # the scan over layers only
+        raise ValueError("adapters are not supported with a layer pattern")
+    places = [cfg.layer_group(j) for j in range(len(kinds))]
+    n_groups = len(cfg.kv_groups)
+
+    per_group = [sum(g == h for g, _ in places) for h in range(n_groups)]
+
+    def at(tree, i):
+        """Layer ``i`` (a traced index) of a stacked tree: the slice a
+        scan over layers would hand its body, taken where it is used."""
+        return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+            a, i, 0, keepdims=False), tree)
 
     def run(x, state, attend, *, positions, valid):
+        block = partial(_block, cfg=cfg, cos=cos, sin=sin,
+                        positions=positions, valid=valid, attend=attend,
+                        experts=experts,
+                        lora_scale=lora["scale"] if lora else None)
+        if len(kinds) == 1:
+            x, (kept, counts) = jax.lax.scan(
+                partial(block, kind=kinds[0]), x,
+                (layers, None if state is None else state[0], lora_xs))
+            return x, (kept,), _total(counts)
+
+        def period(x, p):
+            # every layer takes its own slices by its index: handed the
+            # period's slices as one array, the layers would each copy
+            # theirs out of it (a burst's keys, read twice a step)
+            kept, counts = [[] for _ in range(n_groups)], []
+            for j, (kind, (g, place)) in enumerate(zip(kinds, places)):
+                layer = p * len(kinds) + j
+                x, (rows, n) = block(
+                    x, (at(layers, layer), None if state is None else at(
+                        state[g], p * per_group[g] + place), {}), kind=kind)
+                kept[g].append(rows)
+                counts.append(n)
+            return x, (tuple(jax.tree.map(lambda *a: jnp.stack(a), *rows)
+                             for rows in kept),
+                       None if counts[0] is None else sum(counts))
+
         x, (kept, counts) = jax.lax.scan(
-            partial(_block, cfg=cfg, cos=cos, sin=sin, positions=positions,
-                    valid=valid, attend=attend, experts=experts,
-                    lora_scale=lora["scale"] if lora else None),
-            x, (layers, state, lora_xs))
-        return x, kept, _total(counts)
+            period, x, jnp.arange(cfg.n_layers // len(kinds)))
+        return x, tuple(jax.tree.map(
+            lambda a: a.reshape(-1, *a.shape[2:]), rows)
+            for rows in kept), _total(counts)
 
     return run
+
+
+def _held(table, positions, valid, page_size: int):
+    """``valid`` without the rows whose table entry is the reserved page
+    0: a page a window group's sequence gave back (or never asked for)
+    is written nowhere. table [B, n]; positions, valid [B, S]."""
+    page = jnp.take_along_axis(
+        table, jnp.clip(positions // page_size, 0, table.shape[1] - 1),
+        axis=1)
+    return valid & (page > 0)
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=_POOLS)
@@ -296,7 +398,8 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
     """Process full prompts, fill their pages, return last-token logits.
 
     tokens: [B, S] right-padded; prompt_lens: [B]; block_tables: [B, Pmax];
-    ``lora``: see ``_layers``. The layers hand their K and V rows out of
+    ``lora``: see ``_layers``; the pools and tables one a layer group
+    (``_groups``). The layers hand their K and V rows out of
     the scan ([L, B, S, kvh, hd], in the cache's dtype); padding rows
     (position >= prompt_len) are dropped.
 
@@ -304,20 +407,45 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
     ``_mlp``; None for a dense config).
     """
     B, S = tokens.shape
+    pools = _pools(cache_k, cache_v)
+    tables = _groups(block_tables)
+    page_size = pools[0][0].shape[2]
     x = embed_lookup(params["embed"], tokens, cfg.dtype)
     pos_grid = jnp.arange(S)[None, :].repeat(B, 0)
     valid = pos_grid < prompt_lens[:, None]                    # [B, S]
+    # a window layer hands out the rows that can still be inside the
+    # window at the prompt's end, not the bucket's: ``kept_rows`` of
+    # them, from ``kept_from`` [B] on (the engine holds pages from the
+    # one that position prompt_len - window + 1 lies on)
+    kept_rows = {w: min(S, -(-w // page_size) * page_size + page_size)
+                 for w in cfg.kv_groups if w is not None}
+    kept_from = {w: jnp.clip(
+        jnp.maximum(prompt_lens - w + 1, 0) // page_size * page_size,
+        0, S - n) for w, n in kept_rows.items()}
 
-    def attend(q, k, v, _):
+    def attend(q, k, v, _, window):
         # right padding is safe under the causal mask: a real position
         # only attends to earlier (real) positions
-        return attention(q, k, v, causal=True), (
-            k.astype(cache_k.dtype), v.astype(cache_v.dtype))
+        o = attention(q, k, v, causal=True, window=window)
+        if window is not None and kept_rows[window] < S:
+            k, v = (jax.vmap(lambda rows, at: jax.lax.dynamic_slice_in_dim(
+                rows, at, kept_rows[window], 0))(rows, kept_from[window])
+                for rows in (k, v))
+        return o, (k.astype(pools[0][0].dtype), v.astype(pools[0][1].dtype))
 
     x, rows, counts = _layers(params, cfg, cos, sin, lora)(
         x, None, attend, positions=None, valid=valid)
-    cache_k, cache_v = _write_rows((cache_k, cache_v), rows, block_tables,
-                                   pos_grid, valid)
+    written = []
+    for window, pool, table, kept in zip(cfg.kv_groups, pools, tables,
+                                         rows):
+        if window is None:
+            written.append(_write_rows(pool, kept, table, pos_grid, valid))
+            continue
+        at = kept_from[window][:, None] + jnp.arange(kept_rows[window])
+        written.append(_write_rows(
+            pool, kept, table, at,
+            _held(table, at, at < prompt_lens[:, None], page_size)))
+    cache_k, cache_v = _ungrouped(written, block_tables)
     x_last = jnp.take_along_axis(
         x, jnp.maximum(prompt_lens - 1, 0)[:, None, None], axis=1)[:, 0]
     return _head(x_last, params, cfg), cache_k, cache_v, counts
@@ -345,7 +473,10 @@ def prefill_chunk(params, cache_k, cache_v, tokens, start_pos, chunk_len,
     cache_k, cache_v, expert counts as ``prefill``).
     """
     B, C = tokens.shape
-    Spast = block_tables.shape[1] * cache_k.shape[2]
+    pools = _pools(cache_k, cache_v)
+    tables = dict(zip(cfg.kv_groups, _groups(block_tables)))
+    page_size = pools[0][0].shape[2]
+    Spast = _groups(block_tables)[0].shape[1] * page_size
     x = embed_lookup(params["embed"], tokens, cfg.dtype)
     pos_grid = start_pos + jnp.arange(C)[None, :]          # [1, C]
     valid = jnp.arange(C)[None, :] < chunk_len
@@ -354,13 +485,21 @@ def prefill_chunk(params, cache_k, cache_v, tokens, start_pos, chunk_len,
     chunk_mask = (jnp.arange(C)[None, :, None]
                   >= jnp.arange(C)[None, None, :]) & valid[:, None, :]
 
-    def attend(q, k, v, pools):
-        pools = _write_rows(pools, (k, v), block_tables, pos_grid, valid)
-        pk, pv = (_take_span(pool, block_tables) for pool in pools)
-        return _attend(q, (pk, pv, past_mask), (k, v, chunk_mask)), pools
+    def attend(q, k, v, pools, window):
+        table, past, own, rows = tables[window], past_mask, chunk_mask, valid
+        if window is not None:
+            rows = _held(table, pos_grid, valid, page_size)
+            past = past & (jnp.arange(Spast)[None, None, :]
+                           > pos_grid[:, :, None] - window)
+            own = own & (jnp.arange(C)[None, :, None]
+                         - jnp.arange(C)[None, None, :] < window)
+        pools = _write_rows(pools, (k, v), table, pos_grid, rows)
+        pk, pv = (_take_span(pool, table) for pool in pools)
+        return _attend(q, (pk, pv, past), (k, v, own)), pools
 
-    x, (cache_k, cache_v), counts = _layers(params, cfg, cos, sin)(
-        x, (cache_k, cache_v), attend, positions=pos_grid, valid=valid)
+    x, pools, counts = _layers(params, cfg, cos, sin)(
+        x, pools, attend, positions=pos_grid, valid=valid)
+    cache_k, cache_v = _ungrouped(pools, block_tables)
     idx = jnp.broadcast_to(jnp.maximum(chunk_len - 1, 0).reshape(1, 1, 1),
                            (B, 1, 1))
     x_last = jnp.take_along_axis(x, idx, axis=1)[:, 0]
@@ -387,7 +526,10 @@ def verify_step(params, cache_k, cache_v, tokens, positions, block_tables,
     window position j, sampled position-0 token [B] for rows that
     aren't greedy, cache_k, cache_v, expert counts as ``prefill``).
     """
-    Sall = block_tables.shape[1] * cache_k.shape[2]
+    pools = _pools(cache_k, cache_v)
+    tables = dict(zip(cfg.kv_groups, _groups(block_tables)))
+    page_size = pools[0][0].shape[2]
+    Sall = _groups(block_tables)[0].shape[1] * page_size
     x = embed_lookup(params["embed"], tokens, cfg.dtype)
     valid = positions >= 0
     qpos = jnp.maximum(positions, 0)                       # [B, S]
@@ -396,13 +538,19 @@ def verify_step(params, cache_k, cache_v, tokens, positions, block_tables,
     kmask = (jnp.arange(Sall)[None, None, :]
              <= qpos[:, :, None])                          # [B, S, Sall]
 
-    def attend(q, k, v, pools):
-        pools = _write_rows(pools, (k, v), block_tables, positions, valid)
-        pk, pv = (_take_span(pool, block_tables) for pool in pools)
-        return _attend(q, (pk, pv, kmask)), pools
+    def attend(q, k, v, pools, window):
+        table, seen, rows = tables[window], kmask, valid
+        if window is not None:
+            rows = _held(table, qpos, valid, page_size)
+            seen = seen & (jnp.arange(Sall)[None, None, :]
+                           > qpos[:, :, None] - window)
+        pools = _write_rows(pools, (k, v), table, positions, rows)
+        pk, pv = (_take_span(pool, table) for pool in pools)
+        return _attend(q, (pk, pv, seen)), pools
 
-    x, (cache_k, cache_v), counts = _layers(params, cfg, cos, sin)(
-        x, (cache_k, cache_v), attend, positions=qpos, valid=valid)
+    x, pools, counts = _layers(params, cfg, cos, sin)(
+        x, pools, attend, positions=qpos, valid=valid)
+    cache_k, cache_v = _ungrouped(pools, block_tables)
     logits = _head(x, params, cfg)
     tgt = jnp.argmax(logits, axis=-1)                      # [B, S]
     samp0 = tgt[:, 0] if greedy else sample_from_logits(
@@ -449,6 +597,11 @@ def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
     accumulate in a [L, B, n_steps] scratch and scatter once at the end,
     rows of inactive slots and of steps not run dropped.
 
+    The pools, ``block_tables`` and ``gather`` are one a layer group
+    (``_groups``): a window group's list holds only the pages still
+    inside the window, so a burst neither copies nor reads a key that
+    has left it, and its masks keep a step's query to its window.
+
     ``gather``: int32 [3, T], ONE flat list of the LIVE pages, those that
     hold old context of decoding slots: each one's (page, owner slot,
     first position); a page two slots share is listed once for each, an
@@ -475,60 +628,74 @@ def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
         raise ValueError("decode_burst has one attention path: the paged "
                          "kernel was deleted (PR 31)")
     B, K = tokens.shape[0], n_steps
-    page_size = cache_k.shape[2]
+    pools = _pools(cache_k, cache_v)
+    tables = _groups(block_tables)
+    gathers = (None,) * len(pools) if gather is None else _groups(gather)
+    page_size = pools[0][0].shape[2]
     # old context copied ONCE a burst (read-only during it), and who may
     # score it: [L, B, n * page, kvh, hd] for the table's rectangle, or
     # [L, kvh, T * page, hd] for one flat list; the burst's own rows are
-    # [L, B, K, kvh, hd]
-    if gather is None:
-        pages = block_tables
-        old_mask = (jnp.arange(pages.shape[1] * page_size)[None, :]
-                    < positions[:, None])                      # [B, S]
-    else:
-        pages, owner, first = gather
-        key_pos = (first[:, None] + jnp.arange(page_size)).reshape(-1)
-        old_mask = ((jnp.repeat(owner, page_size)[None, :]
+    # [L, B, K, kvh, hd]; all of it a layer group
+    old, old_mask, key_pos = [], {}, {}
+    for window, pool, table, listed in zip(cfg.kv_groups, pools, tables,
+                                           gathers):
+        if listed is None:
+            pages = table
+            at = jnp.arange(pages.shape[1] * page_size)[None, :]
+            mask = at < positions[:, None]                     # [B, S]
+        else:
+            pages, owner, first = listed
+            at = (first[:, None] + jnp.arange(page_size)).reshape(-1)[None]
+            mask = ((jnp.repeat(owner, page_size)[None, :]
                      == jnp.arange(B)[:, None])
-                    & (key_pos[None, :] < positions[:, None]))  # [B, S]
-    old_k, old_v = (_gather_span(c, pages) for c in (cache_k, cache_v))
-    scratch_k, scratch_v = (
-        jnp.zeros((cfg.n_layers, B, K, *c.shape[3:]), c.dtype)
-        for c in (cache_k, cache_v))
+                    & (at < positions[:, None]))               # [B, S]
+        old.append(tuple(_gather_span(c, pages) for c in pool))
+        old_mask[window], key_pos[window] = mask, at
+    scratch = tuple(tuple(
+        jnp.zeros((c.shape[0], B, K, *c.shape[3:]), c.dtype) for c in pool)
+        for pool in pools)
     layers = _layers(params, cfg, cos, sin, lora)
     n_run = K if steps is None else steps
 
     def step(i, carry):
-        toks, sk, sv, out, total = carry
+        toks, scratch, out, total = carry
         x = embed_lookup(params["embed"], toks, cfg.dtype)[:, None, :]
         new_mask = jnp.arange(K)[None, :] <= i                 # [1, K]
 
-        def attend(q, k, v, state):
+        def attend(q, k, v, state, window):
             ok, ov, nk, nv = state
             nk = jax.lax.dynamic_update_slice_in_dim(
                 nk, k.astype(nk.dtype), i, 1)
             nv = jax.lax.dynamic_update_slice_in_dim(
                 nv, v.astype(nv.dtype), i, 1)
+            seen, own = old_mask[window], new_mask
+            if window is not None:
+                # step i's query sits at positions + i
+                seen = seen & (key_pos[window]
+                               > (positions + i - window)[:, None])
+                own = own & (i - jnp.arange(K)[None, :] < window)
             # one query a slot: attend without the length-1 axis
-            o = _attend(q[:, 0], (ok, ov, old_mask), (nk, nv, new_mask))
+            o = _attend(q[:, 0], (ok, ov, seen), (nk, nv, own))
             return o[:, None], (nk, nv)
 
-        x, (sk, sv), counts = layers(
-            x, (old_k, old_v, sk, sv), attend,
+        x, scratch, counts = layers(
+            x, tuple(o + s for o, s in zip(old, scratch)), attend,
             positions=(positions + i)[:, None], valid=active[:, None])
         newt = _pick(_head(x[:, 0], params, cfg), greedy, seed + i,
                      temperature, top_k, top_p)
         newt = jnp.where(active, newt, toks).astype(out.dtype)
         out = jax.lax.dynamic_update_slice_in_dim(out, newt[None], i, 0)
-        return (newt, sk, sv, out,
+        return (newt, scratch, out,
                 None if counts is None else total + counts)
 
-    _, scratch_k, scratch_v, out, counts = jax.lax.fori_loop(
+    _, scratch, out, counts = jax.lax.fori_loop(
         0, n_run, step,
-        (tokens, scratch_k, scratch_v, jnp.zeros((K, B), tokens.dtype),
+        (tokens, scratch, jnp.zeros((K, B), tokens.dtype),
          jnp.zeros(2, jnp.int32) if cfg.n_experts else None))
     # one scatter of the whole burst into the paged cache
     p_grid = positions[:, None] + jnp.arange(K)[None, :]       # [B, K]
-    cache_k, cache_v = _write_rows(
-        (cache_k, cache_v), (scratch_k, scratch_v), block_tables, p_grid,
-        active[:, None] & (jnp.arange(K)[None, :] < n_run))
+    written = active[:, None] & (jnp.arange(K)[None, :] < n_run)
+    cache_k, cache_v = _ungrouped(
+        [_write_rows(pool, rows, table, p_grid, written)
+         for pool, rows, table in zip(pools, scratch, tables)], block_tables)
     return out, cache_k, cache_v, counts

@@ -36,7 +36,8 @@ class LLMServer:
                  engine_config: Optional[dict] = None,
                  tokenizer: Optional[str] = None, seed: int = 0,
                  quantize: Optional[str] = None,
-                 speculation: Optional[dict] = None):
+                 speculation: Optional[dict] = None,
+                 seed_gains: Optional[dict] = None):
         import jax
 
         from .. import get_tpu_chip_ids
@@ -96,8 +97,11 @@ class LLMServer:
                 params = pickle.load(f)
             params = jax.device_put(params)
         elif init == "random":
+            # seed_gains: a matrix's name -> a factor on its seeded scale
+            # (models.llama.init_params)
             if quantize is None:
-                params = init_params(jax.random.PRNGKey(seed), cfg)
+                params = init_params(jax.random.PRNGKey(seed), cfg,
+                                     seed_gains)
             elif quantize == "int8":
                 # seeded int8 weights made on the device: the only way a
                 # machine without a checkpoint holds Llama-3-8B on one
@@ -105,7 +109,7 @@ class LLMServer:
                 from ..ops.quant import init_params_quantized
 
                 params = init_params_quantized(jax.random.PRNGKey(seed),
-                                               cfg)
+                                               cfg, seed_gains)
             else:
                 raise ValueError(f"unknown quantize {quantize!r}")
         else:
@@ -179,6 +183,11 @@ class LLMServer:
         self._m_kv_util = metrics.Gauge(
             "llm_kv_page_utilization", "Fraction of KV-cache pages in use",
             tag_keys=("model", "pool")).set_default_tags(tags)
+        self._m_kv_free = metrics.Gauge(
+            "llm_kv_free_pages", "Free KV-cache pages of a layer group "
+            "(full: layers that see the whole sequence; window: layers "
+            "that give back what has left their window)",
+            tag_keys=("model", "pool", "group")).set_default_tags(tags)
         self._m_cache_hit = metrics.Counter(
             "llm_prefix_cache_hit_tokens_total",
             "Prompt tokens served from the prefix cache",
@@ -227,6 +236,12 @@ class LLMServer:
         before it reports ready: a replica that decodes loads every shape
         a decode round can take here (lone warm-up requests reach only
         the smallest page lists); a prefill replica never runs one."""
+        if pool in ("prefill", "decode") and len(self.engine.windows) > 1:
+            raise ValueError(
+                f"pool={pool!r} (prefill and decode replicas that hand KV "
+                f"pages over) is not supported with "
+                f"{len(self.engine.windows)} layer groups: a KV payload is "
+                f"one stack of pages for all layers")
         self._pool = pool or "mono"
         self._dep_name = deployment_name
         if pool != "prefill":
@@ -234,7 +249,8 @@ class LLMServer:
         tags = {"model": self.model_name, "pool": self._pool}
         for m in (self._m_ttft, self._m_tpot, self._m_queue_wait,
                   self._m_preemptions, self._m_e2e, self._m_queue,
-                  self._m_occupancy, self._m_kv_util, self._m_cache_hit,
+                  self._m_occupancy, self._m_kv_util, self._m_kv_free,
+                  self._m_cache_hit,
                   self._m_prompt, self._m_generated, self._m_spec_drafted,
                   self._m_spec_accepted, self._m_spec_ratio,
                   self._m_spec_verify):
@@ -435,6 +451,9 @@ class LLMServer:
                 self._m_kv_util.set(
                     1.0 - stats["free_pages"]
                     / max(1, stats["total_pages"]))
+                for name, group in stats["counters"]["groups"].items():
+                    self._m_kv_free.set(group["free_pages"],
+                                        tags={"group": name})
             if not outs:
                 with tracing.span("rt.pump.idle"):
                     await asyncio.sleep(0.002)

@@ -34,6 +34,18 @@ from ..parallel.sharding import (DEFAULT_RULES, logical_sharding,
                                  with_sharding_constraint_logical)
 
 
+# a layer's kind (``LlamaConfig.layer_pattern``)
+LAYER_KINDS = ("full", "full_nope", "window", "window_nope")
+
+
+def windowed(kind: str) -> bool:
+    return kind.startswith("window")
+
+
+def rotated(kind: str) -> bool:
+    return not kind.endswith("_nope")
+
+
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     vocab: int = 128256
@@ -63,10 +75,77 @@ class LlamaConfig:
     # "dots": save matmul outputs, recompute elementwise only — the right
     # trade when HBM fits it (ref: jax checkpoint_policies)
     remat_policy: str = "full"
+    # a head's width where it is not dim // n_heads (SmallThinker: 28
+    # heads of 128 on a hidden size of 2560). None: dim // n_heads
+    head_size: Optional[int] = None
+    # one period of the layers' kinds, repeated down the stack; a kind is
+    # "full" or "window" (a query sees the ``window`` newest keys, its
+    # own among them), with "_nope" behind it where the layer has no
+    # rotary embedding. None: every layer full and rotated. The serving
+    # path (llm/) runs a pattern; the training forward below refuses one
+    layer_pattern: Optional[Tuple[str, ...]] = None
+    window: Optional[int] = None
+    # what the router reads: "mlp", the feed-forward's normalised input,
+    # or "attention", the attention's (the logits are known a whole
+    # attention before the experts need them)
+    router_input: str = "mlp"
+    # the gate's activation in an expert: act(gate) * up
+    expert_act: str = "silu"
+
+    def __post_init__(self):
+        kinds = self.layer_kinds
+        unknown = set(kinds) - set(LAYER_KINDS)
+        if unknown:
+            raise ValueError(f"layer_pattern: unknown kinds "
+                             f"{sorted(unknown)}; one of {LAYER_KINDS}")
+        if self.n_layers % len(kinds):
+            raise ValueError(
+                f"n_layers={self.n_layers} is no whole number of periods "
+                f"of layer_pattern {kinds}")
+        if any(map(windowed, kinds)) and not self.window:
+            raise ValueError("layer_pattern has window layers and "
+                             "window is not set")
+        if self.router_input not in ("mlp", "attention"):
+            raise ValueError(f"router_input {self.router_input!r}: "
+                             f"'mlp' or 'attention'")
+        if self.expert_act not in ("silu", "relu"):
+            raise ValueError(f"expert_act {self.expert_act!r}: "
+                             f"'silu' or 'relu'")
 
     @property
     def head_dim(self) -> int:
-        return self.dim // self.n_heads
+        return self.head_size or self.dim // self.n_heads
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """One period of the stack's kinds (``("full",)`` without a
+        pattern)."""
+        return self.layer_pattern or ("full",)
+
+    @property
+    def kv_groups(self) -> Tuple[Optional[int], ...]:
+        """The live spans the layers' keys have, one entry a group of
+        layers that share a page pool (llm/cache.py): None for the whole
+        sequence, else the window. Full layers first."""
+        spans = {self._span(k) for k in self.layer_kinds}
+        return tuple(sorted(spans, key=lambda s: s is not None))
+
+    def _span(self, kind: str) -> Optional[int]:
+        return self.window if windowed(kind) else None
+
+    def group_layers(self, group: int) -> int:
+        """Layers of the whole stack in ``kv_groups[group]``."""
+        return sum(self.layer_group(j)[0] == group
+                   for j in range(len(self.layer_kinds))) \
+            * (self.n_layers // len(self.layer_kinds))
+
+    def layer_group(self, j: int) -> Tuple[int, int]:
+        """(group, place among the period's layers of that group) of the
+        period's j-th layer."""
+        kinds = self.layer_kinds
+        span = self._span(kinds[j])
+        return (self.kv_groups.index(span),
+                sum(self._span(k) == span for k in kinds[:j]))
 
     def n_params(self) -> int:
         d, L = self.dim, self.n_layers
@@ -137,15 +216,18 @@ def param_logical_axes(cfg: LlamaConfig):
     }
 
 
-def init_params(key, cfg: LlamaConfig):
-    """Scaled-normal init (1/sqrt(fan_in)); bf16 storage."""
+def init_params(key, cfg: LlamaConfig, gains=None):
+    """Scaled-normal init (1/sqrt(fan_in)); bf16 storage. ``gains``: a
+    matrix's name ("embed", "wq", "w_down", ...) -> a factor on its
+    seeded scale; None: every matrix at 1/sqrt(fan_in)."""
     L, d, hd = cfg.n_layers, cfg.dim, cfg.head_dim
     h, hkv, m = cfg.n_heads, cfg.n_kv_heads, cfg.mlp_dim
     ks = jax.random.split(key, 9)
+    gains = dict(gains or {})
 
-    def norm(k, shape, fan_in):
+    def norm(k, shape, fan_in, name):
         return (jax.random.normal(k, shape, jnp.float32)
-                * (fan_in ** -0.5)).astype(cfg.dtype)
+                * (gains.pop(name, 1.0) * fan_in ** -0.5)).astype(cfg.dtype)
 
     if cfg.n_experts:
         E = cfg.n_experts
@@ -155,33 +237,37 @@ def init_params(key, cfg: LlamaConfig):
             # decisions are precision-sensitive
             "router": jax.random.normal(kr[0], (L, d, E), jnp.float32)
             * (d ** -0.5),
-            "w_gate": norm(kr[1], (L, E, d, m), d),
-            "w_up": norm(kr[2], (L, E, d, m), d),
-            "w_down": norm(kr[3], (L, E, m, d), m),
+            "w_gate": norm(kr[1], (L, E, d, m), d, "w_gate"),
+            "w_up": norm(kr[2], (L, E, d, m), d, "w_up"),
+            "w_down": norm(kr[3], (L, E, m, d), m, "w_down"),
         }
     else:
         mlp_params = {
-            "w_gate": norm(ks[5], (L, d, m), d),
-            "w_up": norm(ks[6], (L, d, m), d),
-            "w_down": norm(ks[7], (L, m, d), m),
+            "w_gate": norm(ks[5], (L, d, m), d, "w_gate"),
+            "w_up": norm(ks[6], (L, d, m), d, "w_up"),
+            "w_down": norm(ks[7], (L, m, d), m, "w_down"),
         }
     if cfg.qk_norm:
         mlp_params.update(q_norm=jnp.ones((L, h * hd), cfg.dtype),
                           k_norm=jnp.ones((L, hkv * hd), cfg.dtype))
-    return {
-        "embed": norm(ks[0], (cfg.vocab, d), d),
+    params = {
+        "embed": norm(ks[0], (cfg.vocab, d), d, "embed"),
         "layers": {
             "attn_norm": jnp.ones((L, d), cfg.dtype),
-            "wq": norm(ks[1], (L, d, h, hd), d),
-            "wk": norm(ks[2], (L, d, hkv, hd), d),
-            "wv": norm(ks[3], (L, d, hkv, hd), d),
-            "wo": norm(ks[4], (L, h, hd, d), h * hd),
+            "wq": norm(ks[1], (L, d, h, hd), d, "wq"),
+            "wk": norm(ks[2], (L, d, hkv, hd), d, "wk"),
+            "wv": norm(ks[3], (L, d, hkv, hd), d, "wv"),
+            "wo": norm(ks[4], (L, h, hd, d), h * hd, "wo"),
             "mlp_norm": jnp.ones((L, d), cfg.dtype),
             **mlp_params,
         },
         "final_norm": jnp.ones((d,), cfg.dtype),
-        "lm_head": norm(ks[8], (d, cfg.vocab), d),
+        "lm_head": norm(ks[8], (d, cfg.vocab), d, "lm_head"),
     }
+    if gains:
+        raise ValueError(f"gains for matrices that are not seeded: "
+                         f"{sorted(gains)}")
+    return params
 
 
 # ---------------------------------------------------------------------------
@@ -291,6 +377,13 @@ def forward(params, tokens, cfg: LlamaConfig, *,
     """tokens (B, S) int32 → logits (B, S, vocab) in f32.
 
     ``return_aux``: also return the summed MoE load-balancing loss."""
+    if (cfg.layer_pattern or cfg.router_input != "mlp"
+            or cfg.expert_act != "silu"):
+        raise ValueError(
+            "the training forward runs one kind of layer (full, rotated, "
+            "the router on the feed-forward's input, silu experts); a "
+            "layer_pattern, router_input='attention' or expert_act="
+            "'relu' is served by llm/runner.py only")
     csl = partial(with_sharding_constraint_logical, rules=rules, mesh=mesh)
     cos, sin = rope_frequencies(cfg.head_dim, tokens.shape[1],
                                 cfg.rope_theta, dtype=jnp.float32)

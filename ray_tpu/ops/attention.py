@@ -31,6 +31,8 @@ except Exception:  # pragma: no cover
     pltpu = None
 
 NEG_INF = -1e30
+# the window flash kernel's name in a device trace
+WINDOW_KERNEL = "flash_window_fwd"
 
 
 def _repeat_kv(k, n_rep: int):
@@ -42,8 +44,11 @@ def _repeat_kv(k, n_rep: int):
 
 
 def naive_attention(q, k, v, *, causal: bool = True,
-                    scale: Optional[float] = None):
-    """Reference O(S^2)-memory attention (correctness oracle for tests)."""
+                    scale: Optional[float] = None,
+                    window: Optional[int] = None):
+    """Reference O(S^2)-memory attention (correctness oracle for tests).
+    ``window``: a query sees its ``window`` newest keys, its own among
+    them (needs ``causal``)."""
     b, sq, hq, d = q.shape
     skv, hkv = k.shape[1], k.shape[2]
     scale = scale if scale is not None else d ** -0.5
@@ -54,7 +59,10 @@ def naive_attention(q, k, v, *, causal: bool = True,
     if causal:
         qi = jnp.arange(sq)[:, None] + (skv - sq)
         ki = jnp.arange(skv)[None, :]
-        logits = jnp.where(ki <= qi, logits, NEG_INF)
+        seen = ki <= qi
+        if window is not None:
+            seen &= qi - ki < window
+        logits = jnp.where(seen, logits, NEG_INF)
     # Masked softmax with all-masked rows producing zeros (not uniform).
     m = logits.max(axis=-1, keepdims=True)
     p = jnp.exp(logits - m)
@@ -72,8 +80,15 @@ def naive_attention(q, k, v, *, causal: bool = True,
 
 def blockwise_attention(q, k, v, *, causal: bool = True,
                         scale: Optional[float] = None,
-                        kv_block: int = 512):
-    """FlashAttention recurrence in jax: scan kv blocks, track (m, l, acc)."""
+                        kv_block: int = 512,
+                        window: Optional[int] = None):
+    """FlashAttention recurrence in jax: scan kv blocks, track (m, l, acc).
+    ``window`` (with ``causal``): a query sees its ``window`` newest
+    keys, its own among them. Every key block is scanned for all the
+    queries at once, so the window is a mask here and skips nothing:
+    this is the CPU's path."""
+    if window is not None and not causal:
+        raise ValueError("a window needs causal attention")
     b, sq, hq, d = q.shape
     skv, hkv = k.shape[1], k.shape[2]
     scale = scale if scale is not None else d ** -0.5
@@ -105,6 +120,8 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
         mask = k_pos < skv  # padding mask, shape (1, kv_block)
         if causal:
             mask = mask & (k_pos <= q_pos)  # (sq, kv_block)
+        if window is not None:
+            mask = mask & (q_pos - k_pos < window)
         logits = jnp.where(mask[None, None], logits, NEG_INF)
         m_new = jnp.maximum(m, logits.max(axis=-1))
         p = jnp.exp(logits - m_new[..., None])
@@ -131,7 +148,8 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref, *,
-                      scale, causal, block_q, block_k, seq_q, seq_k):
+                      scale, causal, block_q, block_k, seq_q, seq_k,
+                      window=None):
     # grid = (batch*heads_q, q_blocks, kv_blocks); kv innermost/sequential.
     i = pl.program_id(1)
     j = pl.program_id(2)
@@ -148,6 +166,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     if causal:
         # Whole block above the diagonal → skip all compute.
         run = (j * block_k) <= (i * block_q + block_q - 1 + q_off)
+    if window is not None:
+        # ... and a block whose newest key lies a window or more behind
+        # the q block's oldest query
+        run &= (j * block_k + block_k - 1) > (i * block_q + q_off - window)
 
     @pl.when(run)
     def _():
@@ -164,7 +186,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 jnp.int32, (block_q, block_k), 0) + q_off
             ki = j * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            logits = jnp.where(ki <= qi, logits, NEG_INF)
+            seen = ki <= qi
+            if window is not None:
+                seen &= qi - ki < window
+            logits = jnp.where(seen, logits, NEG_INF)
         m_prev = m_ref[:, 0]
         m_new = jnp.maximum(m_prev, logits.max(axis=-1))
         p = jnp.exp(logits - m_new[:, None])
@@ -205,9 +230,18 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True,
                         scale: Optional[float] = None,
                         block_q: int = 512, block_k: int = 512,
                         interpret: bool = False,
-                        return_lse: bool = False):
+                        return_lse: bool = False,
+                        window: Optional[int] = None):
     """Pallas flash-attention forward (TPU). No autodiff — use
     ``attention`` for a differentiable entry point.
+
+    ``window`` (with ``causal``): a query sees its ``window`` newest
+    keys, its own among them. Key blocks wholly behind the window are
+    skipped like those above the diagonal, and not fetched either (the
+    index map holds at the nearest block that is read, and a block that
+    does not change is not copied again); the block on the window's edge
+    is masked. ``None`` is the causal kernel as it was, unnamed; the
+    window kernel is named ``WINDOW_KERNEL`` in a trace.
 
     ``interpret=True`` runs the kernel in the Pallas interpreter (works on
     CPU) so the kernel body is testable without TPU hardware."""
@@ -226,17 +260,29 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True,
     kt = jnp.moveaxis(k, 2, 1).reshape(b * hkv, skv, d)
     vt = jnp.moveaxis(v, 2, 1).reshape(b * hkv, skv, d)
 
+    if window is not None and not causal:
+        raise ValueError("a window needs causal attention")
+
     def kv_index(bh, i, j):
         hb = bh // hq  # batch
         h = bh % hq
+        if window is not None:
+            first = i * block_q + (skv - sq)      # the q block's oldest
+            j = jnp.clip(j, jnp.maximum(first - window + 1, 0) // block_k,
+                         (first + block_q - 1) // block_k)
         return (hb * hkv + h // n_rep, j, 0)
 
     grid = (b * hq, sq // block_q, skv // block_k)
+    named = {}
     kernel = functools.partial(
         _flash_fwd_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, seq_q=sq, seq_k=skv)
+    if window is not None:
+        kernel = functools.partial(kernel, window=window)
+        named = {"name": WINDOW_KERNEL}
     out, lse = pl.pallas_call(
         kernel,
+        **named,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
@@ -516,11 +562,21 @@ _attention_tpu.defvjp(_attn_fwd, _attn_bwd)
 
 
 def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
-              use_pallas: Optional[bool] = None):
-    """Differentiable attention with TPU pallas fast path."""
+              use_pallas: Optional[bool] = None,
+              window: Optional[int] = None):
+    """Differentiable attention with TPU pallas fast path. ``window``: a
+    query sees its ``window`` newest keys (see ``flash_attention_tpu``);
+    the window kernel is forward only, which is all serving asks of it
+    (the blockwise path differentiates either way)."""
     if use_pallas is None:
         use_pallas = attention_path(q.shape[1], k.shape[1], q.shape[-1],
                                     _on_tpu(q)) == "pallas"
+    if window is not None:
+        if use_pallas:
+            return flash_attention_tpu(q, k, v, causal=causal, scale=scale,
+                                       window=window)
+        return blockwise_attention(q, k, v, causal=causal, scale=scale,
+                                   window=window)
     if use_pallas:
         return _attention_tpu(q, k, v, causal, scale)
     return blockwise_attention(q, k, v, causal=causal, scale=scale)

@@ -295,9 +295,23 @@ def grouped_matmul(lhs, w, row_expert, group_sizes, layer=None):
     return jax.lax.platform_dependent(*operands, tpu=tpu, default=xla)
 
 
+_EXPERT_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def router_logits(x, router_w):
+    """The router's product, float32 at full precision: a tie between
+    the k-th and the next expert decides everything after it. x [..., D],
+    router_w [D, E] -> [..., E]."""
+    with jax.named_scope("rt.moe.route"):
+        return jnp.einsum("...d,de->...e", x.astype(jnp.float32),
+                          router_w.astype(jnp.float32),
+                          precision=jax.lax.Precision.HIGHEST)
+
+
 def moe_mlp_routed(x, router_w, w_gate, w_up, w_down, *, top_k: int,
-                   norm_topk_prob: bool = True, valid=None, layer=None):
-    """Dropless top-k SwiGLU expert layer, the serving path's one.
+                   norm_topk_prob: bool = True, valid=None, layer=None,
+                   logits=None, activation: str = "silu"):
+    """Dropless top-k gated expert layer, the serving path's one.
 
     x (B, S, D); router_w (D, E) float32; w_gate/w_up (E, D, M) and
     w_down (E, M, D), raw or int8 (``ops/quant.py``); ``valid`` (B, S)
@@ -305,22 +319,25 @@ def moe_mlp_routed(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     ``layer`` the three expert weights are whole stacks with a leading
     layers axis (see ``grouped_matmul``). The
     chosen experts' probabilities are used as the router gave them, or
-    renormalised to sum to 1 where ``norm_topk_prob``.
+    renormalised to sum to 1 where ``norm_topk_prob``. ``logits``
+    (B, S, E) float32: the router's logits where they were computed
+    earlier and from another tensor than ``x`` (``router_logits`` of the
+    attention's input, for a model whose router sits there); the rows
+    are routed by them and ``router_w`` is not read. ``activation``: an
+    expert is ``act(gate) * up``, "silu" or "relu".
 
     Returns (out (B, S, D), zero at rows that are not tokens; counts
     int32 [2]: the (token, expert) rows the expert products were given,
     and the experts with at least one row).
     """
     B, S, D = x.shape
-    T, E = B * S, router_w.shape[-1]
+    T = B * S
     xt = x.reshape(T, D)
+    if logits is None:
+        logits = router_logits(xt, router_w)
+    E = logits.shape[-1]
     with jax.named_scope("rt.moe.route"):
-        # the router in float32 at full precision: a tie between the
-        # k-th and the next expert decides everything after it
-        probs = jax.nn.softmax(jnp.einsum(
-            "td,de->te", xt.astype(jnp.float32),
-            router_w.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST), axis=-1)
+        probs = jax.nn.softmax(logits.reshape(T, E), axis=-1)
         weight, chosen = jax.lax.top_k(probs, top_k)            # [T, k]
         if norm_topk_prob:
             weight = weight / jnp.maximum(
@@ -339,7 +356,7 @@ def moe_mlp_routed(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     with jax.named_scope("rt.moe.experts"):
         gate = grouped_matmul(rows, w_gate, row_expert, group_sizes, layer)
         up = grouped_matmul(rows, w_up, row_expert, group_sizes, layer)
-        hidden = (jax.nn.silu(gate.astype(jnp.float32))
+        hidden = (_EXPERT_ACTS[activation](gate.astype(jnp.float32))
                   * up.astype(jnp.float32)).astype(x.dtype)
         down = grouped_matmul(hidden, w_down, row_expert, group_sizes,
                               layer)

@@ -148,7 +148,7 @@ def quantize_params(params: Dict, cfg=None) -> Dict:
     }
 
 
-def init_params_quantized(key, cfg) -> Dict:
+def init_params_quantized(key, cfg, gains=None) -> Dict:
     """Random int8 params DIRECTLY on device — the benchmarking path
     for configs whose bf16 init cannot exist on one chip (8B: 16.1 GB
     bf16 vs 8.0 GB int8). ``jax.random.bits`` emits uint8 natively so
@@ -157,22 +157,30 @@ def init_params_quantized(key, cfg) -> Dict:
     1/sqrt(fan_in) init (uniform int8 has RMS ≈ 74, so
     s = fan_in**-0.5 / 74 gives unit-variance-scaled projections).
 
+    ``gains``: a matrix's name ("embed", "wq", "w_down", ...) -> a factor
+    on its seeded scale (``models.llama.init_params`` takes the same);
+    None: every matrix at 1/sqrt(fan_in).
+
     The whole init is ONE jitted program: eagerly it would dispatch and
     load ~50 single-op executables."""
-    return _init_params_quantized_jit(key, cfg)
+    return _init_params_quantized_jit(
+        key, cfg, tuple(sorted((gains or {}).items())))
 
 
-@partial(jax.jit, static_argnums=(1,))
-def _init_params_quantized_jit(key, cfg) -> Dict:
+@partial(jax.jit, static_argnums=(1, 2))
+def _init_params_quantized_jit(key, cfg, gains=()) -> Dict:
     L, d, hd = cfg.n_layers, cfg.dim, cfg.head_dim
     h, hkv, m = cfg.n_heads, cfg.n_kv_heads, cfg.mlp_dim
     ks = iter(jax.random.split(key, 16))
+    gains = dict(gains)
 
-    def qrand(shape, fan_in, out_dims: Tuple[int, ...], spread=False):
+    def qrand(shape, fan_in, out_dims: Tuple[int, ...], spread=False,
+              name=None):
         bits = jax.random.bits(next(ks), shape, jnp.uint8)
         q = jax.lax.bitcast_convert_type(bits, jnp.int8)
         s_shape = tuple(shape[i] for i in out_dims)
-        s = jnp.full(s_shape, (fan_in ** -0.5) / 74.0, jnp.float32)
+        s = jnp.full(s_shape, gains.pop(name, 1.0) * (fan_in ** -0.5) / 74.0,
+                     jnp.float32)
         if spread:
             # scales that differ by expert and channel (x 0.5 to 1.5):
             # a product scaled by another expert's scales shows
@@ -182,13 +190,13 @@ def _init_params_quantized_jit(key, cfg) -> Dict:
 
     # made in this order: each qrand takes the next key, and a dense
     # config's weights for a seed are what they always were
-    embed = qrand((cfg.vocab, d), d, (0,))
+    embed = qrand((cfg.vocab, d), d, (0,), name="embed")
     layers = {
         "attn_norm": jnp.ones((L, d), jnp.bfloat16),
-        "wq": qrand((L, d, h, hd), d, (0, 2, 3)),
-        "wk": qrand((L, d, hkv, hd), d, (0, 2, 3)),
-        "wv": qrand((L, d, hkv, hd), d, (0, 2, 3)),
-        "wo": qrand((L, h, hd, d), h * hd, (0, 3)),
+        "wq": qrand((L, d, h, hd), d, (0, 2, 3), name="wq"),
+        "wk": qrand((L, d, hkv, hd), d, (0, 2, 3), name="wk"),
+        "wv": qrand((L, d, hkv, hd), d, (0, 2, 3), name="wv"),
+        "wo": qrand((L, h, hd, d), h * hd, (0, 3), name="wo"),
         "mlp_norm": jnp.ones((L, d), jnp.bfloat16),
     }
     if cfg.n_experts:
@@ -197,14 +205,14 @@ def _init_params_quantized_jit(key, cfg) -> Dict:
         layers["router"] = jax.random.normal(
             next(ks), (L, d, E), jnp.float32) * (d ** -0.5)
         layers.update(
-            w_gate=qrand((L, E, d, m), d, (0, 1, 3), spread=True),
-            w_up=qrand((L, E, d, m), d, (0, 1, 3), spread=True),
-            w_down=qrand((L, E, m, d), m, (0, 1, 3), spread=True))
+            w_gate=qrand((L, E, d, m), d, (0, 1, 3), True, "w_gate"),
+            w_up=qrand((L, E, d, m), d, (0, 1, 3), True, "w_up"),
+            w_down=qrand((L, E, m, d), m, (0, 1, 3), True, "w_down"))
     else:
         layers.update(
-            w_gate=qrand((L, d, m), d, (0, 2)),
-            w_up=qrand((L, d, m), d, (0, 2)),
-            w_down=qrand((L, m, d), m, (0, 2)))
+            w_gate=qrand((L, d, m), d, (0, 2), name="w_gate"),
+            w_up=qrand((L, d, m), d, (0, 2), name="w_up"),
+            w_down=qrand((L, m, d), m, (0, 2), name="w_down"))
     if cfg.qk_norm:
         # learned gains scattered about 1, so that a norm over the wrong
         # width or with the wrong weight shows against a reference
@@ -213,9 +221,13 @@ def _init_params_quantized_jit(key, cfg) -> Dict:
                 next(ks), (L, width), jnp.float32)).astype(jnp.bfloat16)
 
         layers.update(q_norm=gain(h * hd), k_norm=gain(hkv * hd))
-    return {
+    params = {
         "embed": embed,
         "layers": layers,
         "final_norm": jnp.ones((d,), jnp.bfloat16),
-        "lm_head": qrand((d, cfg.vocab), d, (1,)),
+        "lm_head": qrand((d, cfg.vocab), d, (1,), name="lm_head"),
     }
+    if gains:
+        raise ValueError(f"gains for matrices that are not seeded: "
+                         f"{sorted(gains)}")
+    return params

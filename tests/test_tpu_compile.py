@@ -95,6 +95,28 @@ def test_flash_forward_compiles_for_v5e(one_chip, shape):
     assert _custom_calls(fwd, *_qkv(shape, one_chip)) == 1
 
 
+@pytest.mark.parametrize("shape, window", [
+    ((1, 8192, 28, 4), 4096), ((1, 12288, 28, 4), 4096),
+    ((1, 512, 28, 4), 4096), ((2, 2048, 8, 4), 300)],
+    ids=["28x4-heads-8k", "28x4-heads-12k", "shorter-than-the-window",
+         "edge-inside-a-block"])
+def test_flash_window_forward_compiles_for_v5e(one_chip, shape, window):
+    """The window kernel (key blocks behind the window neither computed
+    nor fetched: the index map holds at the nearest block that is read)
+    is one Mosaic kernel under its own name; without a window the causal
+    kernel is what it was, unnamed."""
+    from ray_tpu.ops.attention import WINDOW_KERNEL, flash_attention_tpu
+
+    args = _qkv(shape, one_chip)
+    text = jax.jit(functools.partial(
+        flash_attention_tpu, causal=True, window=window)).lower(
+            *args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and WINDOW_KERNEL in text
+    plain = jax.jit(functools.partial(
+        flash_attention_tpu, causal=True)).lower(*args).compile().as_text()
+    assert WINDOW_KERNEL not in plain
+
+
 @pytest.mark.parametrize("shape", FLASH_SHAPES.values(), ids=FLASH_SHAPES)
 def test_flash_forward_backward_compiles_for_v5e(one_chip, shape):
     from ray_tpu.ops.attention import attention
