@@ -385,6 +385,63 @@ def _kernel_parity(seed: int) -> dict:
     return errors
 
 
+def _expert_kernel_parity(seed: int) -> dict:
+    """The grouped expert kernel against ``lax.ragged_dot`` on the device
+    at SmallThinker's widths (64 int8 experts of 768 on 2,560: gate/up
+    and down), with a decode step's 48 rows and the longest prefill
+    bucket's 12,288 x 6, groups as a uniform router fills them and the
+    last rows behind every group. Both round one float32 sum to bf16, so
+    they may differ by one bf16 step at the output's largest value and
+    by no more. Runs in the gang worker, which already holds the chip."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import moe
+
+    experts, hidden, width, top_k = 64, 2560, 768, 6
+    rng = np.random.default_rng(seed)
+    report = {}
+    for k, n in ((hidden, width), (width, hidden)):
+        key = jax.random.fold_in(jax.random.PRNGKey(seed), k)
+        w = {"q": jax.random.randint(key, (2, experts, k, n), -127, 128,
+                                     jnp.int8),
+             "s": (0.5 + jax.random.uniform(key, (2, experts, n)))
+             / (127 * k ** 0.5)}
+        for tokens in (8, 12288):
+            m = tokens * top_k
+            chosen = np.argsort(rng.random((tokens, experts)))[:, :top_k]
+            chosen[tokens - tokens // 16:] = experts    # rows of no token
+            row_expert = jnp.asarray(np.sort(chosen.reshape(m)), jnp.int32)
+            sizes = jnp.bincount(row_expert, length=experts + 1)[:experts]
+            total = int(sizes.sum())
+            lhs = jax.random.normal(jax.random.fold_in(key, m), (m, k),
+                                    jnp.bfloat16)
+            got = jax.jit(moe.grouped_matmul)(
+                lhs, w, row_expert, sizes, jnp.int32(1))[:total]
+            want = jax.jit(moe._gmm_xla, static_argnums=6)(
+                lhs, w["q"], w["s"], 1, row_expert, sizes,
+                jnp.bfloat16)[:total]
+            tiles = moe.chosen_tiles(hidden, width)[f"{m}x{k}x{n}:int8"]
+            if tiles != [min(m, 128), k, n]:
+                raise AssertionError(f"{m}x{k}x{n}: tiles {tiles}, not "
+                                     f"the whole widths")
+            got, want = (np.asarray(x, np.float32) for x in (got, want))
+            if not np.isfinite(got).all():
+                raise AssertionError(f"{m}x{k}x{n}: not finite")
+            largest = float(np.abs(want).max())
+            step = 2.0 ** (np.floor(np.log2(largest)) - 7)
+            diff = float(np.abs(got - want).max())
+            if diff > step:
+                raise AssertionError(
+                    f"{m}x{k}x{n}: the kernel is {diff} off lax.ragged_dot, "
+                    f"over one bf16 step ({step}) at {largest}")
+            report[f"{m}x{k}x{n}"] = {"max_diff": diff,
+                                      "largest_value": largest,
+                                      "bf16_step": step, "tiles": tiles}
+    return report
+
+
 def _run_steps(cfg, mesh, spec: dict, seed: int, on_step=None) -> dict:
     """``spec['steps']`` adamw steps of ``cfg`` on ``mesh`` over one seeded
     batch. Returns losses, timings, each device's bytes in use and the
@@ -454,6 +511,8 @@ def train_fn(config: dict) -> None:
     report = {"device": _device_report()}
     if config["kernel_parity"]:
         report["kernel_parity"] = _kernel_parity(config["seed"])
+        report["expert_kernel_parity"] = _expert_kernel_parity(
+            config["seed"])
     cfg = LLAMA_CONFIGS[config["model"]]
     mesh = build_mesh(MeshSpec(), jax.devices()[:1])
     report.update(_run_steps(
@@ -524,6 +583,10 @@ def phase_train(seed: int, spec: dict) -> dict:
     if spec["kernel_parity"]:
         emit("kernel_parity", shape="1x512, 8/4 heads of 128, causal, bf16",
              max_error_over_range=m["kernel_parity"])
+        emit("expert_kernel_parity",
+             shape="64 int8 experts of 768 on 2560, 48 and 73728 rows, "
+                   "against lax.ragged_dot, bf16",
+             by_product=m["expert_kernel_parity"])
     _check_losses(m["losses"])
     if m["tpu_custom_calls"] < 1:
         raise AssertionError("the compiled train step holds no "

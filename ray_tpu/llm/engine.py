@@ -43,6 +43,7 @@ import numpy as np
 
 from ..models.llama import LlamaConfig
 from ..ops import rope_frequencies
+from ..ops.moe import chosen_tiles
 from ..util import tracing
 from .cache import (KVCache, PageAllocator, PrefixCache, SequenceTable,
                     init_kv_cache, window_group_pages)
@@ -1445,7 +1446,13 @@ class LLMEngine:
         }
         if self.spec is not None:
             out["spec"] = self.spec.stats()
+        # the grouped expert kernel's tiles for this configuration's
+        # product shapes, chosen where its programs were traced; none
+        # without experts
+        expert_tiles = (chosen_tiles(self.cfg.dim, self.cfg.mlp_dim)
+                        if self.cfg.n_experts else {})
         out["counters"] = {**self._counters,
+                           "expert_tiles": expert_tiles,
                            "width_hist": list(self._counters["width_hist"]),
                            "host_s": dict(self._counters["host_s"]),
                            "gather_hist": dict(

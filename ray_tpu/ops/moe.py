@@ -18,7 +18,7 @@ net-new TPU substrate, required natively by BASELINE.
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -149,24 +149,56 @@ def moe_mlp_oracle(x, router_w, w_gate, w_up, w_down, *, top_k=2,
 # scales applied to the product by each row's expert.
 
 
-# The grouped kernel's tiles: rows, contracted width, output width (the
-# last two the largest of these that divide the matrix). Read on a v5e at
-# OLMoE's widths (PR 28, PERF.md section 6): a row tile no larger than a
-# group (a 1,024-token prompt gives an expert 128 rows) is not computed
-# again for every group that shares it, and a whole contracted width in
-# one step needs no second pass over the accumulator.
-_TILE_M, _TILE_K, _TILE_N = 128, 2048, 1024
+# The grouped kernel's tiles, chosen from the shapes it is handed. Rows:
+# a row tile no larger than a group (a 1,024-token prompt gives one of
+# OLMoE's experts 128 rows) is not computed again for every group that
+# shares it (PR 28), and 256 rows were slower or no faster than 128 at
+# every bucket from 1,024 to 12,288 tokens at both served families'
+# widths (PR 34, PERF.md section 6). Widths: the weight block may hold
+# as many elements as the block this kernel runs on a v5e at OLMoE's
+# widths, 2,048 x 1,024 (2 MB of int8, fetched twice over and widened in
+# VMEM); within that the whole contracted width comes first (one pass
+# over the accumulator, and the row tiles of one group ask for the same
+# weight block one after another, so it is fetched once a group), then
+# the widest output tile (the sorted rows are read once).
+_TILE_M, _TILE_ELEMENTS = 128, 2048 * 1024
+
+# the kernel's name in a compiled program and a device trace
+GROUPED_KERNEL = "rt_moe_gmm"
+
+# the tiles chosen so far, by product shape: (m, k, n, weight dtype) ->
+# [tm, tk, tn], or [] where ``lax.ragged_dot`` runs the product
+_chosen_tiles: Dict[Tuple[int, int, int, str], List[int]] = {}
 
 
-def _pick_tile(size: int, largest: int) -> Optional[int]:
-    """The largest power-of-two multiple of 128, at most ``largest``,
-    that divides ``size``; None where 128 does not."""
-    tile = largest
-    while tile >= 128:
-        if size % tile == 0:
-            return tile
-        tile //= 2
-    return None
+def _pick_tiles(m: int, k: int, n: int) -> Optional[Tuple[int, int, int]]:
+    """(tm, tk, tn) for an [m, k] x [k, n] grouped product: tk and tn
+    divisors of k and n that are multiples of 128, tk the largest that
+    leaves room for a tn inside ``_TILE_ELEMENTS``, tn the largest beside
+    it. None where the kernel cannot run the shape (a width 128 does not
+    divide, rows that are no whole row tiles or no multiple of bf16's
+    sublane packing)."""
+    tm = min(m, _TILE_M)
+    if k % 128 or n % 128 or m % 16 or m % tm:
+        return None
+
+    def lane_divisors(size):
+        return [t for t in range(128, size + 1, 128) if size % t == 0]
+
+    tk, tn = max((tk, tn) for tk in lane_divisors(k)
+                 for tn in lane_divisors(n) if tk * tn <= _TILE_ELEMENTS)
+    return tm, tk, tn
+
+
+def chosen_tiles(dim: int, width: int) -> Dict[str, List[int]]:
+    """The tiles ``grouped_matmul`` chose in this process for the
+    products of an expert layer of these widths ([dim, width] and
+    [width, dim]), by shape: "<rows>x<k>x<n>:<weight dtype>" ->
+    [tm, tk, tn], or [] for a product left to ``lax.ragged_dot``. Chosen
+    where a program is traced, so reading them costs a step nothing."""
+    return {f"{m}x{k}x{n}:{dtype}": list(tiles)
+            for (m, k, n, dtype), tiles in _chosen_tiles.items()
+            if (k, n) in ((dim, width), (width, dim))}
 
 
 def _gmm_xla(lhs, w, scale, layer, row_expert, group_sizes, out_dtype):
@@ -202,8 +234,7 @@ def _gmm_tpu(lhs, w, scale, layer, group_sizes, out_dtype,
 
     m, k = lhs.shape
     n_layers, groups, _, n = w.shape
-    tm = min(m, _TILE_M)
-    tk, tn = _pick_tile(k, _TILE_K), _pick_tile(n, _TILE_N)
+    tm, tk, tn = _pick_tiles(m, k, n)
     tiles_k = k // tk
     (offsets, group_ids, m_tile_ids), active_tiles = make_group_metadata(
         group_sizes=group_sizes, m=m, tm=tm, start_group=jnp.int32(0),
@@ -258,7 +289,7 @@ def _gmm_tpu(lhs, w, scale, layer, group_sizes, out_dtype,
             scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret, name=GROUPED_KERNEL,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), offsets, group_ids,
       m_tile_ids, lhs, w, scale[:, :, None, :])
 
@@ -285,8 +316,9 @@ def grouped_matmul(lhs, w, row_expert, group_sizes, layer=None):
         return _gmm_xla(lhs, q, scale, layer, row_expert, group_sizes,
                         lhs.dtype)
 
-    if (_pick_tile(k, _TILE_K) is None or _pick_tile(n, _TILE_N) is None
-            or m % 16 or m % min(m, _TILE_M)):
+    tiles = _pick_tiles(m, k, n)
+    _chosen_tiles[m, k, n, str(q.dtype)] = list(tiles or ())
+    if tiles is None:
         return xla(*operands)
 
     def tpu(lhs, q, scale, layer, row_expert, group_sizes):

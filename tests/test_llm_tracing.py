@@ -5,6 +5,7 @@ and round counters (ISSUE 24). Traces are read with ``ProfileData``, as
 ``benchmarks/harness/trace.py`` reads the chip's."""
 
 import asyncio
+import dataclasses
 import glob
 import json
 import os
@@ -224,6 +225,50 @@ def test_counters_are_a_copy(served, tiny_params):
     # the keys the harness and the pump read stay where they were
     assert {"running", "waiting", "free_pages", "total_pages",
             "counters"} <= set(engine.stats())
+
+
+# a configuration, and the tiles of its expert products at a prefill
+# bucket of 16 rows and a decode step of 2 slots, by top_k rows each
+EXPERT_TILES = {
+    "dense": (CFG, {}),
+    # widths 128 does not divide: lax.ragged_dot runs the products
+    "routed-64x128": (dataclasses.replace(CFG, n_experts=4, top_k=2), {
+        "32x64x128:float32": [], "32x128x64:float32": [],
+        "4x64x128:float32": [], "4x128x64:float32": []}),
+    # lane-aligned widths: whole-width tiles for whole row tiles; a
+    # decode step's 4 rows are no multiple of 16
+    "routed-128x256": (dataclasses.replace(
+        CFG, n_experts=4, top_k=2, dim=128, mlp_dim=256), {
+        "32x128x256:float32": [32, 128, 256],
+        "32x256x128:float32": [32, 256, 128],
+        "4x128x256:float32": [], "4x256x128:float32": []}),
+}
+
+
+@pytest.mark.parametrize("cfg, tiles", EXPERT_TILES.values(),
+                         ids=EXPERT_TILES)
+def test_expert_tiles_list_the_product_shapes_of_a_routed_configuration(
+        cfg, tiles):
+    """``ops/moe.py`` records a product's tiles where it chooses them (at
+    trace time, whichever path the platform lowers), and the engine lists
+    those of its own configuration's widths."""
+    engine = LLMEngine(init_params(jax.random.PRNGKey(0), cfg), cfg,
+                       EngineConfig(max_num_seqs=2, page_size=4,
+                                    num_pages=32, max_seq_len=32,
+                                    decode_burst=2))
+    assert engine.generate([[5, 17, 99, 3]], SamplingParams(
+        temperature=0.0, max_tokens=3))
+    listed = engine.stats()["counters"]["expert_tiles"]
+    # the record is the process's: an engine of the same widths earlier
+    # in this process may have left other row counts beside these
+    assert tiles.items() <= listed.items()
+    assert bool(listed) == bool(cfg.n_experts)
+    widths = {f"{cfg.dim}x{cfg.mlp_dim}", f"{cfg.mlp_dim}x{cfg.dim}"}
+    assert all(key.split(":")[0].split("x", 1)[1] in widths
+               for key in listed)
+    # a copy of the record
+    listed["x"] = [1]
+    assert "x" not in engine.stats()["counters"]["expert_tiles"]
 
 
 def test_forced_preemption_is_counted(tiny_params):

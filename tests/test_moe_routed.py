@@ -79,13 +79,27 @@ def test_oracle_unnormalised_weights_are_the_router_probabilities():
     np.testing.assert_allclose(unnorm, norm * top, rtol=1e-5, atol=1e-6)
 
 
+# widths and rows of the kernel's interpret-mode cases: today's one tile,
+# widths that are no powers of two in both orders (whole-width tiles of
+# 384 and 640), and SmallThinker's own expert at a decode step's rows
+GROUPS = [100, 0, 130, 17, 0, 60, 1, 150]
+GMM_CASES = {
+    "128x128": (512, 128, 128, GROUPS),
+    "384x640": (512, 384, 640, GROUPS),
+    "640x384": (512, 640, 384, GROUPS),
+    "2560x768-decode": (48, 2560, 768, [3, 0, 1, 20, 0, 7, 1, 9]),
+}
+
+
 @pytest.mark.parametrize("quantized", [False, True])
-def test_grouped_kernel_in_interpret_mode_matches_plain_jax(quantized):
+@pytest.mark.parametrize("m, k, n, sizes", GMM_CASES.values(),
+                         ids=GMM_CASES)
+def test_grouped_kernel_in_interpret_mode_matches_plain_jax(
+        m, k, n, sizes, quantized):
     """The Pallas kernel's arithmetic and group bookkeeping, on the CPU
     in interpret mode: empty groups, a group inside one row tile, groups
     across tiles, rows behind the last group."""
-    m, k, n, groups = 512, 128, 128, 8
-    sizes = [100, 0, 130, 17, 0, 60, 1, 150]
+    groups = len(sizes)
     keys = jax.random.split(jax.random.PRNGKey(2), 3)
     lhs = jax.random.normal(keys[0], (m, k), jnp.float32)
     w = jax.random.normal(keys[1], (groups, k, n), jnp.float32)
@@ -100,12 +114,54 @@ def test_grouped_kernel_in_interpret_mode_matches_plain_jax(quantized):
     # a stack of two layers, the second one asked for
     w = jnp.stack([jnp.zeros_like(w), w])
     scale = None if scale is None else jnp.stack([scale, scale])
+    assert moe._pick_tiles(m, k, n) == (min(m, 128), k, n)
     got = moe._gmm_tpu(lhs, w, scale, jnp.int32(1), group_sizes,
                        jnp.float32, interpret=True)
     want = moe._gmm_xla(lhs, w, scale, 1, row_group, group_sizes,
                         jnp.float32)
     total = sum(sizes)
-    np.testing.assert_allclose(got[:total], want[:total], atol=1e-4)
+    assert total < m
+    np.testing.assert_allclose(got[:total], want[:total],
+                               atol=1e-4 * k / 128)
+
+
+# the tile rule alone: (rows, contracted width, output width) -> tiles
+TILE_CASES = {
+    # OLMoE's products keep the tiles they ran with before the rule read
+    # the shapes (PR 28), so its programs do not change
+    "olmoe-gate": ((16384, 2048, 1024), (128, 2048, 1024)),
+    "olmoe-down": ((16384, 1024, 2048), (128, 1024, 2048)),
+    "olmoe-decode": ((64, 2048, 1024), (64, 2048, 1024)),
+    # SmallThinker's 2,560 = 5 x 512 and 768 = 3 x 256: whole widths
+    "smallthinker-gate": ((73728, 2560, 768), (128, 2560, 768)),
+    "smallthinker-down": ((73728, 768, 2560), (128, 768, 2560)),
+    "smallthinker-decode": ((48, 768, 2560), (48, 768, 2560)),
+    # a width 128 does not divide, and rows that are no whole tiles or
+    # no multiple of 16: lax.ragged_dot
+    "unaligned-k": ((512, 200, 1024), None),
+    "unaligned-n": ((512, 1024, 96), None),
+    "ragged-rows": ((200, 1024, 1024), None),
+    "odd-rows": ((24, 1024, 1024), None),
+    # over the budget of 2,048 x 1,024 elements: the whole contracted
+    # width first, then a divisor of the output width under the budget
+    "wide-n": ((512, 4096, 14336), (128, 4096, 512)),
+    "wide-n-odd": ((512, 2560, 7680), (128, 2560, 768)),
+    "wide-k": ((512, 32768, 1024), (128, 16384, 128)),
+    "wide-k-odd": ((512, 3 * 16384, 640), (128, 16384, 128)),
+}
+
+
+@pytest.mark.parametrize("shape, tiles", TILE_CASES.values(),
+                         ids=TILE_CASES)
+def test_tile_rule_reads_the_shapes(shape, tiles):
+    got = moe._pick_tiles(*shape)
+    assert got == tiles
+    if got is not None:
+        m, k, n = shape
+        tm, tk, tn = got
+        assert m % tm == 0 and k % tk == 0 and n % tn == 0
+        assert tk % 128 == 0 and tn % 128 == 0
+        assert tk * tn <= 2048 * 1024
 
 
 MOE = dataclasses.replace(LLAMA_CONFIGS["tiny"], n_experts=8, top_k=2,

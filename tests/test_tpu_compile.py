@@ -271,18 +271,32 @@ def test_sharded_train_step_keeps_flash_kernels(topo):
     assert per_device < 0.3 * whole, (per_device, whole)
 
 
-@pytest.mark.parametrize("rows", [64, 16384], ids=["decode", "prefill"])
+# experts, hidden width, expert width, experts a token; a decode step's
+# rows (8 slots) and the longest prefill bucket's
+ROUTED_WIDTHS = {
+    "olmoe": (64, 2048, 1024, 8, (64, 16384)),
+    "smallthinker": (64, 2560, 768, 6, (48, 73728)),
+}
+
+
+@pytest.mark.parametrize("regime", [0, 1], ids=["decode", "prefill"])
+@pytest.mark.parametrize("widths", ROUTED_WIDTHS.values(),
+                         ids=ROUTED_WIDTHS)
 def test_routed_expert_layer_reads_int8_experts_without_a_wide_copy(
-        one_chip, rows):
-    """The dropless layer at OLMoE's widths (64 experts of 1024 on 2048,
-    8 a token): three grouped Pallas products, and the experts stay the
-    int8 stacks they are stored as: no bf16 or float32 tensor of a
-    layer's experts, and no int8 copy of one layer, in the program."""
+        one_chip, widths, regime):
+    """The dropless layer at the served families' widths (OLMoE: 64
+    experts of 1024 on 2048, 8 a token; SmallThinker: 64 of 768 on 2560,
+    6 a token, whole-width tiles of 2,560 x 768): three grouped Pallas
+    products under the kernel's own name, and the experts stay the int8
+    stacks they are stored as: no bf16 or float32 tensor of a layer's
+    experts, and no int8 copy of one layer, in the program."""
     import re
 
-    from ray_tpu.ops.moe import moe_mlp_routed
+    from ray_tpu.ops.moe import GROUPED_KERNEL, moe_mlp_routed
 
-    L, E, d, m, k = 2, 64, 2048, 1024, 8
+    L = 2
+    E, d, m, k, rows = widths
+    rows = rows[regime]
 
     def quantized(shape):
         return {"q": _sds(shape, jnp.int8, one_chip),
@@ -301,7 +315,8 @@ def test_routed_expert_layer_reads_int8_experts_without_a_wide_copy(
         _sds((1, tokens), jnp.bool_, one_chip),
         _sds((), jnp.int32, one_chip)).compile().as_text()
     assert text.count("tpu_custom_call") == 3
+    assert GROUPED_KERNEL in text
     experts = r"\[(?:%d,)?%d,(?:%d,%d|%d,%d)\]" % (L, E, d, m, m, d)
     assert not re.findall(r"(?:bf16|f32)" + experts, text)
     assert set(re.findall(r"s8" + experts, text)) == {
-        "s8[2,64,2048,1024]", "s8[2,64,1024,2048]"}
+        f"s8[{L},{E},{d},{m}]", f"s8[{L},{E},{m},{d}]"}
