@@ -24,6 +24,15 @@ its oldest pages back as they leave the window
 whose entry is 0 is written nowhere. The window group's pool is sized by
 what can be live at once (``window_group_pages``), not by the longest
 sequence.
+
+Latent attention (``LlamaConfig.latent``). A token keeps ONE
+row a layer, the compressed keys and values and the rotary key all heads
+share (512 + 64 values, in a slot of ``cfg.latent_row`` = 640, which
+models/llama.py explains), not a key and a value a head: ``KVCache.k``
+is the one pool [layers, pages, page_size, latent_row] and ``KVCache.v``
+is None: no V pool is allocated, copied or written, the values are the
+first 512 columns of the same row. Allocator, tables and the reserved
+page 0 are what they are for every configuration.
 """
 
 from __future__ import annotations
@@ -44,7 +53,7 @@ class KVCache:
     them (one array a group), the form the runner's programs take."""
 
     k: Any  # [L, num_pages, page_size, kv_heads, head_dim] (a group)
-    v: Any
+    v: Any  # None for a latent configuration: k holds its rows
 
     @property
     def num_pages(self) -> int:
@@ -67,6 +76,11 @@ def init_kv_cache(cfg, num_pages, page_size: int, dtype=None) -> KVCache:
     """``num_pages``: an int for a one-group configuration, else one
     number a group of ``cfg.kv_groups``."""
     dtype = dtype or cfg.dtype
+    if cfg.latent:
+        if not isinstance(num_pages, int):
+            raise ValueError("a latent configuration has one layer group")
+        return KVCache(jnp.zeros((cfg.n_layers, num_pages, page_size,
+                                  cfg.latent_row), dtype), None)
 
     def pools(layers: int, pages: int):
         return jnp.zeros((layers, pages, page_size, cfg.n_kv_heads,

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import collections
 import itertools
+import logging
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
@@ -43,6 +44,7 @@ import numpy as np
 
 from ..models.llama import LlamaConfig
 from ..ops import rope_frequencies
+from ..ops.attention import attention_path
 from ..ops.moe import chosen_tiles
 from ..util import tracing
 from .cache import (KVCache, PageAllocator, PrefixCache, SequenceTable,
@@ -50,6 +52,9 @@ from .cache import (KVCache, PageAllocator, PrefixCache, SequenceTable,
 from .runner import (decode_burst, prefill_bucket, prefill_sample,
                      verify_step)
 from .sampling import SamplingParams
+
+
+logger = logging.getLogger(__name__)
 
 
 @dataclass
@@ -188,6 +193,8 @@ class LLMEngine:
         grouped = len(self.windows) > 1
         if grouped:
             self._refuse_with_groups()
+        if cfg.latent:
+            self._refuse_with_latent()
         pool_pages = [
             self.ecfg.num_pages if w is None else window_group_pages(
                 self.ecfg.max_num_seqs, w, self.ecfg.page_size,
@@ -238,8 +245,9 @@ class LLMEngine:
         self.seq_tables = [SequenceTable(self.ecfg.max_num_seqs, max_pages)
                            for _ in self.windows]
         self.seq_table = self.seq_tables[0]
-        cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq,
-                                    cfg.rope_theta)
+        cos, sin = rope_frequencies(cfg.rope_dim, cfg.max_seq,
+                                    cfg.rope_theta,
+                                    scaling=cfg.rope_scaling)
         self.cos, self.sin = jax.device_put(cos), jax.device_put(sin)
         # speculative decoding (drafter + verify window; spec_decode.py)
         self.spec = None
@@ -293,9 +301,44 @@ class LLMEngine:
             # layers and programs: the (token, expert) rows the expert
             # products were given, and the experts with at least one row
             self._counters.update(expert_rows=0, experts_touched=0)
+            if cfg.experts_held is not None:
+                # tokens' rows the router gave to experts that are not
+                # on this chip (one chip's share of the experts)
+                self._counters["expert_rows_elsewhere"] = 0
+        # what one cached position holds, all layers
+        self._counters["kv_bytes_per_token"] = int(sum(
+            a.size // (a.shape[1] * a.shape[2]) * a.dtype.itemsize
+            for a in jax.tree.leaves((self.cache.k, self.cache.v))))
         # expert counts of chunked-prefill dispatches nobody waited for
         # yet: read back with the next sampled tokens
         self._pending_counts: List[Any] = []
+        paths = self.attention_paths()
+        logger.log(
+            logging.WARNING if jax.default_backend() == "tpu"
+            and "blockwise" in paths["prefill"] else logging.INFO,
+            "attention paths (heads of %d, values of %d): %s",
+            cfg.head_dim, cfg.value_dim, paths)
+
+    def attention_paths(self) -> Dict[str, str]:
+        """Which implementation each program's attention takes on this
+        backend, by ``ops.attention.attention_path`` at the largest
+        prefill bucket: logged once when the engine is made (a warning
+        where a TPU's whole-prompt prefill falls to plain jax: a head
+        size the flash kernels cannot tile). What was really compiled is
+        in the program's text (``compile_prefill``)."""
+        on_tpu = jax.default_backend() == "tpu"
+        top = prefill_bucket(self.ecfg.max_seq_len, self.ecfg.max_seq_len)
+        prefill = attention_path(top, top, self.cfg.head_dim, on_tpu,
+                                 self.cfg.value_dim)
+        if self.cfg.latent:
+            gathered = "xla (absorbed, over the gathered rows)"
+            return {"prefill": prefill + " (expanded)",
+                    "prefill_chunk": gathered, "verify_step": gathered,
+                    "decode_burst": "pallas rt_mla_decode (absorbed, each "
+                    "slot's own pages)" if on_tpu else gathered}
+        listed = "xla (over the gathered pages)"
+        return {"prefill": prefill, "prefill_chunk": listed,
+                "verify_step": listed, "decode_burst": listed}
 
     def _refuse_with_groups(self) -> None:
         """What a cache of several layer groups cannot do yet (ROADMAP
@@ -318,7 +361,24 @@ class LLMEngine:
                     f"{len(self.windows)} layer groups (full and window "
                     f"layers side by side): {why}")
 
+    def _refuse_with_latent(self) -> None:
+        """What a cache of latent rows cannot do yet, refused by the
+        option's name before anything is built (``speculation``: by
+        ``enable_speculation``, whoever calls it; KV hand-over: by
+        ``_refuse_kv_transfer``)."""
+        if self.ecfg.lora_rank > 0:
+            raise ValueError(
+                "EngineConfig.lora_rank is not supported with latent "
+                "attention: adapters are deltas on wq and wv, and a latent "
+                "layer has neither (its queries pass a low-rank bottleneck "
+                "and its values are expanded from the cached row)")
+
     def _refuse_kv_transfer(self, what: str) -> None:
+        if self.cfg.latent:
+            raise ValueError(
+                f"{what} is not supported with latent attention: a KV "
+                f"payload is a K and a V stack of pages, and a latent "
+                f"cache is one pool of rows with no V")
         if len(self.windows) > 1:
             raise ValueError(
                 f"{what} is not supported with {len(self.windows)} layer "
@@ -337,9 +397,11 @@ class LLMEngine:
         if counts is not None:
             pending.append(counts)
         sampled, pending = jax.device_get((toks, pending))
-        for rows, touched in pending:
+        for rows, touched, *elsewhere in pending:
             self._counters["expert_rows"] += int(rows)
             self._counters["experts_touched"] += int(touched)
+            if elsewhere and "expert_rows_elsewhere" in self._counters:
+                self._counters["expert_rows_elsewhere"] += int(elsewhere[0])
         return sampled
 
     @contextmanager
@@ -382,6 +444,11 @@ class LLMEngine:
         drafter's random init (a trained 400m draft checkpoint)."""
         from .spec_decode import SpecDecoder
 
+        if self.cfg.latent:
+            raise ValueError(
+                "EngineConfig.speculation is not supported with latent "
+                "attention: the drafter mirrors a K and a V pool, and a "
+                "latent cache has one pool of rows")
         if len(self.windows) > 1:
             raise ValueError(
                 "EngineConfig.speculation is not supported with "
@@ -692,9 +759,26 @@ class LLMEngine:
         return prefill_bucket(pages, self._listable_pages(g),
                               self._FLAT_PAGES)
 
+    # a latent burst's table span, smallest bucket in pages: the kernel's
+    # grid covers the span whatever the slots hold, a step past a slot's
+    # length costs a third of a microsecond, and every bucket is a
+    # program to load before the replica is ready
+    _LATENT_SPAN_PAGES = 32
+
+    def _latent_span(self, pages: int) -> int:
+        """Power-of-2 bucket of a latent burst's block tables (pages a
+        slot), capped at the table's width."""
+        return prefill_bucket(pages, self.seq_table.block_tables.shape[1],
+                              self._LATENT_SPAN_PAGES)
+
     def _ladder(self, g: int):
-        top = self._listable_pages(g)
-        buckets = [min(self._FLAT_PAGES, top)]
+        """Every bucket a burst's list of group ``g`` can take (a latent
+        burst: its table span)."""
+        top, lowest = self._listable_pages(g), self._FLAT_PAGES
+        if self.cfg.latent:
+            top = self.seq_table.block_tables.shape[1]
+            lowest = self._LATENT_SPAN_PAGES
+        buckets = [min(lowest, top)]
         while buckets[-1] < top:
             buckets.append(min(2 * buckets[-1], top))
         return buckets
@@ -745,10 +829,13 @@ class LLMEngine:
             lora = self.lora_pool.select([0] * B)
         buckets = self.decode_buckets()
         for shape in buckets:
-            lists = tuple(jnp.asarray(burst_gather(
-                t.block_tables, self.ecfg.page_size, bucket, ()))
-                for t, bucket in zip(self.seq_tables, (
-                    shape if isinstance(shape, tuple) else (shape,))))
+            if self.cfg.latent:
+                lists = (jnp.zeros((B, shape), jnp.int32),)
+            else:
+                lists = tuple(jnp.asarray(burst_gather(
+                    t.block_tables, self.ecfg.page_size, bucket, ()))
+                    for t, bucket in zip(self.seq_tables, (
+                        shape if isinstance(shape, tuple) else (shape,))))
             _toks, ck, cv, _counts = decode_burst(
                 self.params, self.cache.k, self.cache.v, zi, zi,
                 self._tables(), jnp.zeros(B, bool), self.cos, self.sin, 0,
@@ -1002,21 +1089,34 @@ class LLMEngine:
             # a group's list: the pages that hold old context its layers
             # can still see
             page, lists, shape = self.ecfg.page_size, [], []
-            for g, window in enumerate(self.windows):
-                held = []
-                for s in active_states:
-                    first = 0 if window is None else \
-                        self._first_live_page(s.ctx_len, window)
-                    held.append((s.slot, -(-s.ctx_len // page) - first,
-                                 first))
-                live = sum(n for _slot, n, _first in held)
-                bucket = self._flat_bucket(live, g)
-                lists.append(jnp.asarray(burst_gather(
-                    self.seq_tables[g].block_tables, page, bucket, held)))
+            if self.cfg.latent:
+                # nothing is copied: the burst reads each slot's own
+                # pages through its table, cut to the longest's bucket
+                pages = [-(-s.ctx_len // page) for s in active_states]
+                bucket = self._latent_span(max(pages))
+                lists.append(self._bt(bucket))
                 shape.append(bucket)
-                for c in (counters, counters["groups"][self.group_names[g]]):
-                    c["live_pages"] += live
-                    c["gathered_pages"] += bucket
+                for c in (counters, counters["groups"][self.group_names[0]]):
+                    c["live_pages"] += sum(pages)
+                    c["gathered_pages"] += sum(pages)
+            else:
+                for g, window in enumerate(self.windows):
+                    held = []
+                    for s in active_states:
+                        first = 0 if window is None else \
+                            self._first_live_page(s.ctx_len, window)
+                        held.append((s.slot, -(-s.ctx_len // page) - first,
+                                     first))
+                    live = sum(n for _slot, n, _first in held)
+                    bucket = self._flat_bucket(live, g)
+                    lists.append(jnp.asarray(burst_gather(
+                        self.seq_tables[g].block_tables, page, bucket,
+                        held)))
+                    shape.append(bucket)
+                    for c in (counters,
+                              counters["groups"][self.group_names[g]]):
+                        c["live_pages"] += live
+                        c["gathered_pages"] += bucket
             hist = counters["gather_hist"]
             shape = shape[0] if len(shape) == 1 else "/".join(
                 map(str, shape))

@@ -28,9 +28,11 @@ that rides a layer scan is rewritten whole: ``prefill`` and
 still take it through (ROADMAP S10: a change to their two closures).
 
 One scatter, one convention. ``_write_rows`` is the only place a page is
-written. A row that is not a token (bucket padding, a chunk's tail, a
-window's -1 positions, an inactive slot) carries the out-of-range page
-index ``num_pages`` and ``mode="drop"`` writes nothing for it. Page 0
+written (a latent configuration's rows: ``_write_latent``, the same
+convention as a loop of slices, which says why). A row that is not a
+token (bucket padding, a chunk's tail, a window's -1 positions, an
+inactive slot) carries the out-of-range page index ``num_pages`` and
+``mode="drop"`` writes nothing for it. Page 0
 stays reserved: block tables and page lists are padded with 0 and the
 gathers read it under a mask; nothing writes to it. A burst's gather,
 ``_gather_span``, copies the listed pages of all layers once, straight
@@ -52,6 +54,24 @@ A window layer's mask has a lower bound (key position > query position -
 window); a window group's row whose table entry is the reserved page 0
 (a page that left the window and was given back) is written nowhere.
 
+Latent attention (``LlamaConfig.latent``). The block's
+attention half projects queries through their low-rank bottleneck and
+ONE row a token for the cache (``_latent``): the compressed keys and
+values and the rotary key all heads share. There is one pool of such
+rows and no V pool (``cache_v`` is None everywhere). ``attend(q, row,
+(W_UK, W_UV), state, None)`` chooses the form (``ops/mla.py``): whole-
+prompt ``prefill`` expands every head's keys and values from the rows
+and runs the flash kernel; ``prefill_chunk`` and ``verify_step`` score
+the gathered rows in the absorbed form; ``decode_burst`` copies nothing:
+each step's absorbed queries go to ``mla.decode_attention``, which walks
+each slot's own pages in the pool (a Pallas kernel on a TPU), and are
+joined with the burst's own rows by their log-sum-exp.
+
+Leading dense layers (``LlamaConfig.n_dense_layers``) are their own
+stack ``params["dense_layers"]``: ``_layers`` scans them first, with
+the same block and the dense feed-forward, then the expert layers; the
+cache's layers are in that order.
+
 Static shapes throughout: prefill pads a prompt to a power-of-2 bucket
 (one executable a bucket), decode runs the whole slot batch every step
 with inactive slots masked over a page list padded to a power-of-2
@@ -70,7 +90,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.llama import LlamaConfig, qk_norm, rotated, windowed
-from ..ops import apply_rotary, attention, rms_norm
+from ..ops import apply_rotary, attention, mla, rms_norm
 from ..ops.moe import router_logits
 from ..ops.quant import embed_lookup, is_quantized, weight_einsum
 from .lora import lora_delta
@@ -90,7 +110,7 @@ def _split_layers(layers, cfg: LlamaConfig):
     if not cfg.n_experts:
         return layers, None
     sliced = {k: v for k, v in layers.items() if k not in _EXPERT_STACKS}
-    sliced["layer"] = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+    sliced["layer"] = jnp.arange(cfg.n_moe_layers, dtype=jnp.int32)
     return sliced, {k: layers[k] for k in _EXPERT_STACKS}
 
 
@@ -101,17 +121,24 @@ def _groups(x):
 
 
 def _pools(cache_k, cache_v):
-    """The (K, V) pair of pools of each layer group."""
+    """The pools of each layer group: the (K, V) pair, or, where there
+    is no V pool (``cache_v`` None: a latent configuration), the one
+    pool of rows alone."""
+    if cache_v is None:
+        return tuple((k,) for k in _groups(cache_k))
     return tuple(zip(_groups(cache_k), _groups(cache_v)))
 
 
 def _ungrouped(pools, like):
-    """(cache_k, cache_v) out of the groups' (K, V) pairs, in the form
-    ``like`` came in: tuples a group, or the one group's bare arrays."""
-    cache_k, cache_v = zip(*pools)
+    """(cache_k, cache_v) out of the groups' pools, in the form ``like``
+    came in: tuples a group, or the one group's bare arrays; ``cache_v``
+    None where the groups have one pool each."""
+    cache_k, *cache_v = zip(*pools)
+    cache_v = cache_v[0] if cache_v else None
     if isinstance(like, tuple):
         return cache_k, cache_v
-    return cache_k[0], cache_v[0]
+    return cache_k[0], None if cache_v is None else cache_v[0]
+
 
 
 def _mlp(h, lp, cfg: LlamaConfig, valid=None, experts=None, logits=None):
@@ -121,17 +148,22 @@ def _mlp(h, lp, cfg: LlamaConfig, valid=None, experts=None, logits=None):
     not tokens (``valid`` [B, S] False: bucket padding, inactive slots)
     are given to no expert; ``lp`` and ``experts`` are ``_split_layers``'
     two halves; ``logits``: the router's, where ``_block`` computed them
-    before attention (``cfg.router_input``). Returns (out, counts): the
-    layer's (expert rows, experts touched) int32 [2], None for a dense
-    config."""
-    if cfg.n_experts:
+    before attention (``cfg.router_input``); ``experts`` None: a dense
+    layer (a dense config's, or a leading dense layer). Returns (out,
+    counts): the layer's (expert rows, experts touched, rows routed to
+    experts that are not here) int32 [3], None for a dense layer."""
+    if experts is not None:
         from ..ops.moe import moe_mlp_routed
 
+        shared = (lp["ws_gate"], lp["ws_up"], lp["ws_down"]) \
+            if cfg.n_shared_experts else None
         return moe_mlp_routed(
             h, lp["router"], experts["w_gate"], experts["w_up"],
             experts["w_down"], top_k=cfg.top_k,
             norm_topk_prob=cfg.norm_topk_prob, valid=valid,
-            layer=lp["layer"], logits=logits, activation=cfg.expert_act)
+            layer=lp["layer"], logits=logits, activation=cfg.expert_act,
+            n_group=cfg.n_group, topk_group=cfg.topk_group,
+            scale=cfg.routed_scale, held=cfg.experts_held, shared=shared)
     g = weight_einsum("bsd,dm->bsm", h, lp["w_gate"])
     u = weight_einsum("bsd,dm->bsm", h, lp["w_up"])
     return weight_einsum("bsm,md->bsd", jax.nn.silu(g) * u,
@@ -186,6 +218,66 @@ def _write_rows(pools, rows, block_tables, positions, valid):
             r.reshape(*r.shape[:-4], -1, *r.shape[-2:]).astype(pool.dtype),
             mode="drop")
         for pool, r in zip(pools, rows))
+
+
+def _write_latent(pool, rows, block_tables, positions, valid):
+    """``_write_rows`` for the ONE pool of a latent configuration, a row
+    at a time. pool [..., P, page, row]; rows [..., B, S, row] with the
+    pool's leading dimensions; the rest as ``_write_rows``. A scatter
+    would not do: XLA's scatter on a TPU wants the two fastest
+    dimensions of the pool inside the window it writes, and with one row
+    a position the second fastest is the position itself, so it turns
+    the whole pool into another layout and back (two copies of 1.5 GB a
+    burst, read from the compiled text). A loop of one row's slice of
+    one layer updated in place has no such wish. A row that is not a
+    token is written nowhere: its place is page 0's first row, its value
+    what is there already. Returns the 1-tuple of the pool, as
+    ``_write_rows``."""
+    page_size, width = pool.shape[-2:]
+    lead = pool.shape[:-3]
+    page = jnp.take_along_axis(block_tables, positions // page_size, axis=1)
+    ok = jnp.broadcast_to(valid, positions.shape).reshape(-1)
+    fp = jnp.where(ok, page.reshape(-1), 0)
+    fo = jnp.where(ok, (positions % page_size).reshape(-1), 0)
+    flat = rows.reshape(-1, fp.size, width).astype(pool.dtype)
+    whole = pool.reshape(-1, *pool.shape[-3:])      # the layers in front
+
+    def write(i, whole):
+        # one row of one layer: a slice over the layers as well would
+        # make XLA turn the pool layers-inward for the loop, and back
+        layer, t = i // fp.size, i % fp.size
+        at = (layer, fp[t], fo[t], 0)
+        new = jax.lax.dynamic_slice(flat, (layer, t, 0), (1, 1, width))
+        old = jax.lax.dynamic_slice(whole, at, (1, 1, 1, width))
+        return jax.lax.dynamic_update_slice(
+            whole, jnp.where(ok[t], new[:, None], old), at)
+
+    whole = jax.lax.fori_loop(0, whole.shape[0] * fp.size, write, whole)
+    return (whole.reshape(*lead, *pool.shape[-3:]),)
+
+
+def _write_latent_pages(pool, rows, table, prompt_lens):
+    """A whole prompt's latent rows into its pages, a PAGE at a time:
+    pool [L, P, page, row]; rows [L, 1, S, row], position 0 first; table
+    [1, max_pages]; prompt_lens [1]. The rows behind the prompt's end on
+    its last page are written too (their positions are masked until a
+    decode step writes them); a page wholly behind it is written
+    nowhere (page 0 keeps what it holds)."""
+    L, _, page_size, width = pool.shape
+    S = rows.shape[2]
+    pad = (-S) % page_size
+    pages = jnp.pad(rows[:, 0], ((0, 0), (0, pad), (0, 0))).reshape(
+        L, -1, page_size, width).astype(pool.dtype)
+
+    def write(j, pool):
+        ok = j * page_size < prompt_lens[0]
+        at = (0, jnp.where(ok, table[0, j], 0), 0, 0)
+        new = jax.lax.dynamic_slice_in_dim(pages, j, 1, 1)
+        old = jax.lax.dynamic_slice(pool, at, new.shape)
+        return jax.lax.dynamic_update_slice(
+            pool, jnp.where(ok, new, old), at)
+
+    return (jax.lax.fori_loop(0, pages.shape[1], write, pool),)
 
 
 def _take_span(pool, block_tables):
@@ -270,25 +362,33 @@ def _attend(q, *segments):
     return sum(outs[1:], outs[0]).reshape(q.shape)
 
 
-def _block(x, inputs, *, cfg: LlamaConfig, kind, cos, sin, positions, valid,
-           attend, experts, lora_scale):
-    """The decoder layer, once, as the body of a scan over layers.
+def _latent(h, lp, cfg: LlamaConfig, cos, sin, positions):
+    """A latent layer's projections of the normalised input h [B, S, d]:
+    (q [B, S, heads, nope + rope], the rotary part rotated; the row the
+    cache keeps [B, S, ``cfg.latent_row``]: the normalised compressed
+    keys and values, the rotated rotary key all heads share, zeros)."""
+    rank, rope = cfg.kv_lora_rank, cfg.qk_rope_dim
+    c_q = rms_norm(weight_einsum("bsd,dr->bsr", h, lp["wq_a"]),
+                   lp["q_a_norm"], cfg.norm_eps)
+    q = weight_einsum("bsr,rn->bsn", c_q, lp["wq_b"])
+    q = q.reshape(*q.shape[:2], cfg.n_heads, cfg.head_dim)
+    q = jnp.concatenate([
+        q[..., :cfg.qk_nope_dim],
+        apply_rotary(q[..., cfg.qk_nope_dim:], cos, sin,
+                     positions=positions)], -1)
+    kv = weight_einsum("bsd,dr->bsr", h, lp["wkv_a"])
+    c_kv = rms_norm(kv[..., :rank], lp["kv_a_norm"], cfg.norm_eps)
+    k_r = apply_rotary(kv[..., None, rank:], cos, sin,
+                       positions=positions)[..., 0, :]
+    pad = jnp.zeros((*kv.shape[:-1], cfg.latent_row - rank - rope), kv.dtype)
+    return q, jnp.concatenate([c_kv, k_r, pad], -1)
 
-    x: [B, S, d]; inputs: (the layer's weights, the layer's slice of the
-    program's own state, the layer's per-slot adapter rows: low-rank
-    deltas on wq/wv, llm/lora.py, empty = base model); ``kind``: the
-    layer's (``LlamaConfig.layer_pattern``: rotated or not, windowed or
-    not); positions: [B, S] rotary positions, None = 0..S-1; valid:
-    [B, S], the rows that are tokens; ``attend(q, k, v, state, window)
-    -> (o [B, S, heads, hd], kept)``, ``window`` the layer's or None.
-    Returns (x, (kept, expert counts: see ``_mlp``)).
-    """
-    lp, state, lr = inputs
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    # a router that reads the attention's input: its logits are known a
-    # whole attention before the experts need them
-    logits = router_logits(h, lp["router"]) if (
-        cfg.n_experts and cfg.router_input == "attention") else None
+
+def _heads(h, lp, lr, state, *, cfg: LlamaConfig, kind, cos, sin, positions,
+           attend, lora_scale):
+    """A layer of heads' attention half on the normalised input h: the
+    three projections (plus a slot's LoRA deltas), QK-norm, rotary,
+    ``attend`` under the layer's span. Returns (o, kept)."""
     q = weight_einsum("bsd,dhk->bshk", h, lp["wq"])
     k = weight_einsum("bsd,dhk->bshk", h, lp["wk"])
     v = weight_einsum("bsd,dhk->bshk", h, lp["wv"])
@@ -303,8 +403,58 @@ def _block(x, inputs, *, cfg: LlamaConfig, kind, cos, sin, positions, valid,
         k = apply_rotary(k, cos, sin, positions=positions)
     with jax.named_scope("rt.attn.window" if windowed(kind)
                          else "rt.attn.full"):
-        o, kept = attend(q, k, v, state,
-                         cfg.window if windowed(kind) else None)
+        return attend(q, k, v, state,
+                      cfg.window if windowed(kind) else None)
+
+
+def _attend_latent_pages(q, row, w, pools, table, positions, written, cfg,
+                         past, own=None):
+    """A latent layer's ``attend`` where the pool rides the layer scan
+    (``prefill_chunk``, ``verify_step``): write the rows into the
+    layer's pages, gather the table's span, and attend in the absorbed
+    form over (the span under ``past``; with ``own``, the rows
+    themselves under it). Returns (o [B, S, heads, v], the 1-tuple of
+    the layer's pool)."""
+    with jax.named_scope("rt.attn.mla.decode"):
+        pools = _write_latent(pools[0], row, table, positions, written)
+        segments = [(_take_span(pools[0], table), past)]
+        if own is not None:
+            segments.append((row.astype(pools[0].dtype), own))
+        o = mla.attend_rows(mla.absorb_query(q, w[0], cfg.latent_row),
+                            cfg.softmax_scale, cfg.kv_lora_rank, *segments)
+        return mla.expand_output(o.astype(q.dtype), w[1]), pools
+
+
+def _block(x, inputs, *, cfg: LlamaConfig, kind, cos, sin, positions, valid,
+           attend, experts, lora_scale):
+    """The decoder layer, once, as the body of a scan over layers.
+
+    x: [B, S, d]; inputs: (the layer's weights, the layer's slice of the
+    program's own state, the layer's per-slot adapter rows: low-rank
+    deltas on wq/wv, llm/lora.py, empty = base model); ``kind``: the
+    layer's (``LlamaConfig.layer_pattern``: rotated or not, windowed or
+    not); positions: [B, S] rotary positions, None = 0..S-1; valid:
+    [B, S], the rows that are tokens; ``attend(q, k, v, state, window)
+    -> (o [B, S, heads, hd], kept)``, ``window`` the layer's or None; a
+    latent layer hands it ``(q, the row to keep, (W_UK, W_UV), state,
+    None)``. ``experts`` None: the layer's feed-forward is dense.
+    Returns (x, (kept, expert counts: see ``_mlp``)).
+    """
+    lp, state, lr = inputs
+    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    # a router that reads the attention's input: its logits are known a
+    # whole attention before the experts need them
+    logits = router_logits(h, lp["router"]) if (
+        experts is not None and cfg.router_input == "attention") else None
+    if cfg.latent:
+        q, row = _latent(h, lp, cfg, cos, sin, positions)
+        # the program's closure opens the span: rt.attn.mla.prefill (the
+        # expanded form) or rt.attn.mla.decode (the absorbed one)
+        o, kept = attend(q, row, (lp["w_uk"], lp["w_uv"]), state, None)
+    else:
+        o, kept = _heads(h, lp, lr, state, cfg=cfg, kind=kind, cos=cos,
+                         sin=sin, positions=positions, attend=attend,
+                         lora_scale=lora_scale)
     x = x + weight_einsum("bshk,hkd->bsd", o.astype(x.dtype), lp["wo"])
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     m, counts = _mlp(h, lp, cfg, valid, experts, logits)
@@ -326,15 +476,18 @@ def _layers(params, cfg: LlamaConfig, cos, sin, lora=None):
     each with its kind, its weights and its place in its group's state,
     all taken by the layer's index."""
     layers, experts = _split_layers(params["layers"], cfg)
+    n_dense, dense = cfg.n_dense_layers, params.get("dense_layers")
     # adapters ride the layer scan as xs: [B, L, ...] -> [L, B, ...]
     lora_xs = {} if not lora else {
         k2: jnp.swapaxes(v2, 0, 1) for k2, v2 in lora.items()
         if k2 != "scale"}
     kinds = cfg.layer_kinds
-    if lora_xs and len(kinds) > 1:
-        # LLMEngine refuses lora_rank with layer groups; adapters ride
-        # the scan over layers only
-        raise ValueError("adapters are not supported with a layer pattern")
+    if lora_xs and (len(kinds) > 1 or cfg.latent or n_dense):
+        # LLMEngine refuses lora_rank with these; adapters ride the one
+        # scan over layers and add to wq and wv
+        raise ValueError("adapters are not supported with a layer "
+                         "pattern, latent attention or leading dense "
+                         "layers")
     places = [cfg.layer_group(j) for j in range(len(kinds))]
     n_groups = len(cfg.kv_groups)
 
@@ -352,9 +505,21 @@ def _layers(params, cfg: LlamaConfig, cos, sin, lora=None):
                         experts=experts,
                         lora_scale=lora["scale"] if lora else None)
         if len(kinds) == 1:
+            mine = None if state is None else state[0]
+            if n_dense:
+                # the leading dense layers first: the same block, the
+                # dense feed-forward, the cache's first layers
+                x, (first, _) = jax.lax.scan(
+                    partial(block, kind=kinds[0], experts=None), x,
+                    (dense, None if mine is None else jax.tree.map(
+                        lambda a: a[:n_dense], mine), {}))
+                if mine is not None:
+                    mine = jax.tree.map(lambda a: a[n_dense:], mine)
             x, (kept, counts) = jax.lax.scan(
-                partial(block, kind=kinds[0]), x,
-                (layers, None if state is None else state[0], lora_xs))
+                partial(block, kind=kinds[0]), x, (layers, mine, lora_xs))
+            if n_dense:
+                kept = jax.tree.map(
+                    lambda a, b: jnp.concatenate([a, b], 0), first, kept)
             return x, (kept,), _total(counts)
 
         def period(x, p):
@@ -407,6 +572,11 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
     ``_mlp``; None for a dense config).
     """
     B, S = tokens.shape
+    if cfg.latent and B != 1:
+        raise ValueError("a latent configuration's prefill writes ONE "
+                         "prompt's rows a page at a time "
+                         "(_write_latent_pages), as the engine asks: "
+                         f"B == 1, not {B}")
     pools = _pools(cache_k, cache_v)
     tables = _groups(block_tables)
     page_size = pools[0][0].shape[2]
@@ -424,6 +594,15 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
         0, S - n) for w, n in kept_rows.items()}
 
     def attend(q, k, v, _, window):
+        if cfg.latent:
+            # the expanded form: every head's keys and values multiplied
+            # out of the rows, which alone leave the layer scan
+            with jax.named_scope("rt.attn.mla.prefill"):
+                keys, values = mla.expand(k, *v, cfg.n_heads,
+                                          cfg.qk_rope_dim)
+                o = attention(q, keys, values, causal=True,
+                              scale=cfg.softmax_scale)
+            return o, (k.astype(pools[0][0].dtype),)
         # right padding is safe under the causal mask: a real position
         # only attends to earlier (real) positions
         o = attention(q, k, v, causal=True, window=window)
@@ -438,6 +617,10 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
     written = []
     for window, pool, table, kept in zip(cfg.kv_groups, pools, tables,
                                          rows):
+        if cfg.latent:
+            written.append(
+                _write_latent_pages(pool[0], kept[0], table, prompt_lens))
+            continue
         if window is None:
             written.append(_write_rows(pool, kept, table, pos_grid, valid))
             continue
@@ -487,6 +670,9 @@ def prefill_chunk(params, cache_k, cache_v, tokens, start_pos, chunk_len,
 
     def attend(q, k, v, pools, window):
         table, past, own, rows = tables[window], past_mask, chunk_mask, valid
+        if cfg.latent:
+            return _attend_latent_pages(q, k, v, pools, table, pos_grid,
+                                        rows, cfg, past, own)
         if window is not None:
             rows = _held(table, pos_grid, valid, page_size)
             past = past & (jnp.arange(Spast)[None, None, :]
@@ -540,6 +726,9 @@ def verify_step(params, cache_k, cache_v, tokens, positions, block_tables,
 
     def attend(q, k, v, pools, window):
         table, seen, rows = tables[window], kmask, valid
+        if cfg.latent:
+            return _attend_latent_pages(q, k, v, pools, table, qpos, rows,
+                                        cfg, seen)
         if window is not None:
             rows = _held(table, qpos, valid, page_size)
             seen = seen & (jnp.arange(Sall)[None, None, :]
@@ -612,6 +801,13 @@ def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
     Either way a slot's keys are those it owns at positions below its
     own: one softmax over them and the burst's rows.
 
+    A latent configuration copies nothing: ``gather`` is int32 [B, n],
+    the block tables cut to the pages that can hold old context (None:
+    the whole of ``block_tables``), and every step's attention reads
+    each slot's own pages of it straight from the pool, up to the slot's
+    own length (``ops/mla.py`` ``decode_attention``), so a step's
+    attention costs what the slot's context costs.
+
     ``steps``: int32 scalar, the steps to run (<= n_steps, which is only
     the capacity: scratch rows and the returned [n_steps, B]); None runs
     them all. A width is an operand, not a program.
@@ -639,6 +835,11 @@ def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
     old, old_mask, key_pos = [], {}, {}
     for window, pool, table, listed in zip(cfg.kv_groups, pools, tables,
                                            gathers):
+        if cfg.latent:
+            # no copy: a layer's state is its index into the pool
+            old.append((jnp.arange(cfg.n_layers, dtype=jnp.int32),))
+            span = table if listed is None else listed
+            continue
         if listed is None:
             pages = table
             at = jnp.arange(pages.shape[1] * page_size)[None, :]
@@ -663,6 +864,22 @@ def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
         new_mask = jnp.arange(K)[None, :] <= i                 # [1, K]
 
         def attend(q, k, v, state, window):
+            if cfg.latent:
+                # absorbed: the slot's cached rows where they lie, then
+                # the burst's own rows up to this step, one softmax
+                layer, rows = state
+                rows = jax.lax.dynamic_update_slice_in_dim(
+                    rows, k.astype(rows.dtype), i, 1)
+                with jax.named_scope("rt.attn.mla.decode"):
+                    ql = mla.absorb_query(q[:, 0], v[0], cfg.latent_row)
+                    seen = dict(scale=cfg.softmax_scale,
+                                rank=cfg.kv_lora_rank)
+                    o, lse = mla.decode_attention(
+                        ql, pools[0][0], layer, span, positions, **seen)
+                    o = mla.join_new_rows(o, lse, ql, rows, new_mask,
+                                          **seen)
+                    o = mla.expand_output(o.astype(q.dtype), v[1])
+                return o[:, None], (rows,)
             ok, ov, nk, nv = state
             nk = jax.lax.dynamic_update_slice_in_dim(
                 nk, k.astype(nk.dtype), i, 1)
@@ -691,11 +908,12 @@ def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
     _, scratch, out, counts = jax.lax.fori_loop(
         0, n_run, step,
         (tokens, scratch, jnp.zeros((K, B), tokens.dtype),
-         jnp.zeros(2, jnp.int32) if cfg.n_experts else None))
+         jnp.zeros(3, jnp.int32) if cfg.n_experts else None))
     # one scatter of the whole burst into the paged cache
     p_grid = positions[:, None] + jnp.arange(K)[None, :]       # [B, K]
     written = active[:, None] & (jnp.arange(K)[None, :] < n_run)
     cache_k, cache_v = _ungrouped(
-        [_write_rows(pool, rows, table, p_grid, written)
+        [_write_latent(pool[0], rows[0], table, p_grid, written)
+         if cfg.latent else _write_rows(pool, rows, table, p_grid, written)
          for pool, rows, table in zip(pools, scratch, tables)], block_tables)
     return out, cache_k, cache_v, counts

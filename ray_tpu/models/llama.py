@@ -91,6 +91,45 @@ class LlamaConfig:
     router_input: str = "mlp"
     # the gate's activation in an expert: act(gate) * up
     expert_act: str = "silu"
+    # ``kv_lora_rank`` 0: queries, keys and values of one head size, K
+    # and V pools (GQA). Over 0: latent attention (DeepSeek-V2), the
+    # other attention kind (``latent``): queries through a low-rank
+    # bottleneck (``q_lora_rank``), keys and values expanded from ONE
+    # compressed row a token (``kv_lora_rank`` values and a rotary part of
+    # ``qk_rope_dim`` that all heads share): that row is all the cache
+    # holds (llm/cache.py), and a head scores ``qk_nope_dim`` +
+    # ``qk_rope_dim`` wide and returns ``v_head_dim``. Served only
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # the softmax's scale where it is not head_dim ** -0.5 (YaRN's
+    # ``mscale_all_dim`` squared rides on it)
+    attn_scale: Optional[float] = None
+    # ``ops.rotary.rope_frequencies``' ``scaling``: the published
+    # ``rope_scaling`` as sorted (key, value) pairs (hashable). None:
+    # plain frequencies of ``rope_theta``
+    rope_scaling: Optional[Tuple[Tuple[str, Any], ...]] = None
+    # the first layers' feed-forward is dense, of its own width, before
+    # the expert layers begin (their weights: ``params["dense_layers"]``)
+    n_dense_layers: int = 0
+    dense_mlp_dim: int = 0
+    # experts every token passes through, beside the routed ones: one
+    # SwiGLU of width ``n_shared_experts * mlp_dim``
+    n_shared_experts: int = 0
+    # group-limited routing: the experts are ``n_group`` groups, a
+    # group's score is its best expert's, only the ``topk_group`` best
+    # groups' experts can be chosen; the chosen weights x ``routed_scale``
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scale: float = 1.0
+    # (first, count): the experts this chip holds of ``n_experts`` (one
+    # chip's share of an expert-parallel deployment): the router keeps
+    # ``n_experts`` outputs, the expert matrices have ``count`` experts,
+    # and a row routed to an expert that is not here adds nothing. None:
+    # all of them
+    experts_held: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
         kinds = self.layer_kinds
@@ -111,10 +150,85 @@ class LlamaConfig:
         if self.expert_act not in ("silu", "relu"):
             raise ValueError(f"expert_act {self.expert_act!r}: "
                              f"'silu' or 'relu'")
+        widths = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_dim,
+                  self.qk_rope_dim, self.v_head_dim)
+        if any(widths) and not all(widths):
+            raise ValueError(
+                "latent attention needs q_lora_rank, kv_lora_rank, "
+                "qk_nope_dim, qk_rope_dim and v_head_dim, all of them")
+        if self.latent and (len(kinds) > 1 or self.qk_norm):
+            raise ValueError("latent attention runs one kind of layer "
+                             "(full, rotated) without qk_norm")
+        if self.n_dense_layers and (len(kinds) > 1 or not self.n_experts
+                                    or not self.dense_mlp_dim
+                                    or self.n_dense_layers >= self.n_layers):
+            raise ValueError(
+                "n_dense_layers: leading dense layers (of dense_mlp_dim) "
+                "come before the expert layers of a configuration with "
+                "experts and one kind of layer")
+        if self.n_experts and self.n_experts % self.n_group:
+            raise ValueError(f"n_experts={self.n_experts} is no whole "
+                             f"number of n_group={self.n_group} groups")
+        if self.experts_held is not None:
+            first, count = self.experts_held
+            if not (0 <= first and 0 < count
+                    and first + count <= self.n_experts):
+                raise ValueError(
+                    f"experts_held {self.experts_held} is no part of "
+                    f"{self.n_experts} experts")
+
+    @property
+    def latent(self) -> bool:
+        """Latent attention, not GQA: see ``kv_lora_rank``."""
+        return self.kv_lora_rank > 0
 
     @property
     def head_dim(self) -> int:
+        """A query's (and key's) width in one head."""
+        if self.latent:
+            return self.qk_nope_dim + self.qk_rope_dim
         return self.head_size or self.dim // self.n_heads
+
+    @property
+    def value_dim(self) -> int:
+        """What a head returns."""
+        return self.v_head_dim if self.latent else self.head_dim
+
+    @property
+    def rope_dim(self) -> int:
+        """The width the rotary embedding turns: a whole head, or a
+        latent layer's rotary part."""
+        return self.qk_rope_dim if self.latent else self.head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of the ONE row a token keeps in a latent layer's cache:
+        the compressed keys and values, then the shared rotary key."""
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def latent_row(self) -> int:
+        """Width of a latent row's slot in the page pool: ``latent_dim``
+        rounded up to whole lanes (576 -> 640, the rest zero). A TPU
+        array whose last dimension is 576 is given a default layout with
+        the PAGES as the fastest dimension (read from the compiled text
+        of a program that takes a [9, 2049, 64, 576] pool: {1,3,2,0}), so
+        that no page is contiguous, every scatter and gather of a page
+        strides the whole pool and a kernel that wants row-major pages
+        gets a copy of the pool first; 640 is row-major."""
+        return -(-self.latent_dim // 128) * 128
+
+    @property
+    def softmax_scale(self) -> float:
+        return self.attn_scale or self.head_dim ** -0.5
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held[1] if self.experts_held else self.n_experts
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
@@ -219,34 +333,86 @@ def param_logical_axes(cfg: LlamaConfig):
 def init_params(key, cfg: LlamaConfig, gains=None):
     """Scaled-normal init (1/sqrt(fan_in)); bf16 storage. ``gains``: a
     matrix's name ("embed", "wq", "w_down", ...) -> a factor on its
-    seeded scale; None: every matrix at 1/sqrt(fan_in)."""
-    L, d, hd = cfg.n_layers, cfg.dim, cfg.head_dim
-    h, hkv, m = cfg.n_heads, cfg.n_kv_heads, cfg.mlp_dim
+    seeded scale; None: every matrix at 1/sqrt(fan_in).
+
+    A configuration with leading dense layers has them as their own
+    stack ``params["dense_layers"]`` (attention and a dense feed-forward
+    of ``dense_mlp_dim``), before ``params["layers"]``, the expert
+    layers. A latent configuration's attention leaves are ``wq_a``
+    [d, q_lora], ``q_a_norm``, ``wq_b`` [q_lora, h * (nope + rope)]
+    (the heads flattened: a last dimension of 192 is no whole number of
+    lanes, and a decode burst copied the matrix into another layout),
+    ``wkv_a`` [d, kv_lora + rope], ``kv_a_norm``, ``w_uk`` and ``w_uv``
+    (the two halves of the published ``kv_b_proj``, each [kv_lora, h,
+    nope or v]) and ``wo`` [h, v, d]. Shared experts: ``ws_gate``,
+    ``ws_up``, ``ws_down``, one SwiGLU of n_shared_experts * mlp_dim."""
+    d, hd = cfg.dim, cfg.head_dim
+    h, hkv = cfg.n_heads, cfg.n_kv_heads
     ks = jax.random.split(key, 9)
     gains = dict(gains or {})
+    used = set()
 
     def norm(k, shape, fan_in, name):
+        used.add(name)
         return (jax.random.normal(k, shape, jnp.float32)
-                * (gains.pop(name, 1.0) * fan_in ** -0.5)).astype(cfg.dtype)
+                * (gains.get(name, 1.0) * fan_in ** -0.5)).astype(cfg.dtype)
 
+    def scattered(k, shape):
+        # learned gains scattered about 1: a norm over the wrong width
+        # or with the wrong weight shows against a reference
+        return (1.0 + 0.25 * jax.random.normal(k, shape, jnp.float32)
+                ).astype(cfg.dtype)
+
+    def attention_leaves(k4, L):
+        if not cfg.latent:
+            return {
+                "wq": norm(k4[0], (L, d, h, hd), d, "wq"),
+                "wk": norm(k4[1], (L, d, hkv, hd), d, "wk"),
+                "wv": norm(k4[2], (L, d, hkv, hd), d, "wv"),
+                "wo": norm(k4[3], (L, h, hd, d), h * hd, "wo"),
+            }
+        rq, rkv, v = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.v_head_dim
+        ka = jax.random.split(k4[0], 7)
+        return {
+            "wq_a": norm(ka[0], (L, d, rq), d, "wq_a"),
+            "q_a_norm": scattered(ka[1], (L, rq)),
+            "wq_b": norm(ka[2], (L, rq, h * hd), rq, "wq_b"),
+            "wkv_a": norm(ka[3], (L, d, cfg.latent_dim), d, "wkv_a"),
+            "kv_a_norm": scattered(ka[4], (L, rkv)),
+            "w_uk": norm(ka[5], (L, rkv, h, cfg.qk_nope_dim), rkv, "w_uk"),
+            "w_uv": norm(ka[6], (L, rkv, h, v), rkv, "w_uv"),
+            "wo": norm(k4[3], (L, h, v, d), h * v, "wo"),
+        }
+
+    def dense_mlp(k3, L, m):
+        return {
+            "w_gate": norm(k3[0], (L, d, m), d, "w_gate"),
+            "w_up": norm(k3[1], (L, d, m), d, "w_up"),
+            "w_down": norm(k3[2], (L, m, d), m, "w_down"),
+        }
+
+    L, m = cfg.n_moe_layers, cfg.mlp_dim
     if cfg.n_experts:
-        E = cfg.n_experts
+        E, held = cfg.n_experts, cfg.n_experts_held
         kr = jax.random.split(ks[5], 4)
         mlp_params = {
             # router stays genuinely f32 (no bf16 round trip): routing
             # decisions are precision-sensitive
             "router": jax.random.normal(kr[0], (L, d, E), jnp.float32)
             * (d ** -0.5),
-            "w_gate": norm(kr[1], (L, E, d, m), d, "w_gate"),
-            "w_up": norm(kr[2], (L, E, d, m), d, "w_up"),
-            "w_down": norm(kr[3], (L, E, m, d), m, "w_down"),
+            "w_gate": norm(kr[1], (L, held, d, m), d, "w_gate"),
+            "w_up": norm(kr[2], (L, held, d, m), d, "w_up"),
+            "w_down": norm(kr[3], (L, held, m, d), m, "w_down"),
         }
+        if cfg.n_shared_experts:
+            ms = cfg.n_shared_experts * m
+            kt = jax.random.split(ks[6], 3)
+            mlp_params.update(
+                ws_gate=norm(kt[0], (L, d, ms), d, "ws_gate"),
+                ws_up=norm(kt[1], (L, d, ms), d, "ws_up"),
+                ws_down=norm(kt[2], (L, ms, d), ms, "ws_down"))
     else:
-        mlp_params = {
-            "w_gate": norm(ks[5], (L, d, m), d, "w_gate"),
-            "w_up": norm(ks[6], (L, d, m), d, "w_up"),
-            "w_down": norm(ks[7], (L, m, d), m, "w_down"),
-        }
+        mlp_params = dense_mlp(ks[5:8], L, m)
     if cfg.qk_norm:
         mlp_params.update(q_norm=jnp.ones((L, h * hd), cfg.dtype),
                           k_norm=jnp.ones((L, hkv * hd), cfg.dtype))
@@ -254,19 +420,26 @@ def init_params(key, cfg: LlamaConfig, gains=None):
         "embed": norm(ks[0], (cfg.vocab, d), d, "embed"),
         "layers": {
             "attn_norm": jnp.ones((L, d), cfg.dtype),
-            "wq": norm(ks[1], (L, d, h, hd), d, "wq"),
-            "wk": norm(ks[2], (L, d, hkv, hd), d, "wk"),
-            "wv": norm(ks[3], (L, d, hkv, hd), d, "wv"),
-            "wo": norm(ks[4], (L, h, hd, d), h * hd, "wo"),
+            **attention_leaves(ks[1:5], L),
             "mlp_norm": jnp.ones((L, d), cfg.dtype),
             **mlp_params,
         },
         "final_norm": jnp.ones((d,), cfg.dtype),
         "lm_head": norm(ks[8], (d, cfg.vocab), d, "lm_head"),
     }
-    if gains:
+    if cfg.n_dense_layers:
+        n = cfg.n_dense_layers
+        kd = jax.random.split(jax.random.fold_in(key, 1), 7)
+        params["dense_layers"] = {
+            "attn_norm": jnp.ones((n, d), cfg.dtype),
+            **attention_leaves(kd[:4], n),
+            "mlp_norm": jnp.ones((n, d), cfg.dtype),
+            **dense_mlp(kd[4:], n, cfg.dense_mlp_dim),
+        }
+    unknown = set(gains) - used
+    if unknown:
         raise ValueError(f"gains for matrices that are not seeded: "
-                         f"{sorted(gains)}")
+                         f"{sorted(unknown)}")
     return params
 
 
@@ -378,12 +551,16 @@ def forward(params, tokens, cfg: LlamaConfig, *,
 
     ``return_aux``: also return the summed MoE load-balancing loss."""
     if (cfg.layer_pattern or cfg.router_input != "mlp"
-            or cfg.expert_act != "silu"):
+            or cfg.expert_act != "silu" or cfg.latent or cfg.n_dense_layers
+            or cfg.n_shared_experts or cfg.n_group > 1
+            or cfg.experts_held is not None):
         raise ValueError(
             "the training forward runs one kind of layer (full, rotated, "
-            "the router on the feed-forward's input, silu experts); a "
-            "layer_pattern, router_input='attention' or expert_act="
-            "'relu' is served by llm/runner.py only")
+            "GQA, the router on the feed-forward's input, ungrouped, silu "
+            "experts, all held, none shared, no leading dense layer); a "
+            "layer_pattern, router_input='attention', expert_act='relu', "
+            "latent attention, n_dense_layers, n_shared_experts, n_group "
+            "or experts_held is served by llm/runner.py only")
     csl = partial(with_sharding_constraint_logical, rules=rules, mesh=mesh)
     cos, sin = rope_frequencies(cfg.head_dim, tokens.shape[1],
                                 cfg.rope_theta, dtype=jnp.float32)

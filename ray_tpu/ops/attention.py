@@ -33,6 +33,9 @@ except Exception:  # pragma: no cover
 NEG_INF = -1e30
 # the window flash kernel's name in a device trace
 WINDOW_KERNEL = "flash_window_fwd"
+# the flash kernel's name where a head's values are not as wide as its
+# queries and keys (latent attention's expanded form: 192 and 128)
+LATENT_KERNEL = "flash_mla_fwd"
 
 
 def _repeat_kv(k, n_rep: int):
@@ -105,7 +108,7 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
 
     qf = q.astype(jnp.float32) * scale
     kf = k.astype(jnp.float32).reshape(b, n_blocks, kv_block, hq, d)
-    vf = v.astype(jnp.float32).reshape(b, n_blocks, kv_block, hq, d)
+    vf = v.astype(jnp.float32).reshape(b, n_blocks, kv_block, hq, -1)
     # scan over blocks: move block axis to front
     kf = jnp.moveaxis(kf, 1, 0)
     vf = jnp.moveaxis(vf, 1, 0)
@@ -135,7 +138,7 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
 
     m0 = jnp.full((b, hq, sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, hq, sq), jnp.float32)
-    acc0 = jnp.zeros((b, hq, sq, d), jnp.float32)
+    acc0 = jnp.zeros((b, hq, sq, v.shape[-1]), jnp.float32)
     (m, l, acc, _), _ = jax.lax.scan(step, (m0, l0, acc0, 0), (kf, vf))
     out = acc / jnp.maximum(l[..., None], 1e-30)
     return jnp.moveaxis(out, 1, 2).astype(q.dtype)
@@ -243,10 +246,14 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True,
     is masked. ``None`` is the causal kernel as it was, unnamed; the
     window kernel is named ``WINDOW_KERNEL`` in a trace.
 
+    Values may be narrower or wider than queries and keys (latent
+    attention's expanded heads score 192 wide and return 128): the
+    output has the values' width, and that kernel is ``LATENT_KERNEL``.
+
     ``interpret=True`` runs the kernel in the Pallas interpreter (works on
     CPU) so the kernel body is testable without TPU hardware."""
     b, sq, hq, d = q.shape
-    skv, hkv = k.shape[1], k.shape[2]
+    skv, hkv, dv = k.shape[1], k.shape[2], v.shape[3]
     scale = scale if scale is not None else d ** -0.5
     n_rep = hq // hkv
     block_q = _pick_block(sq, block_q)
@@ -258,7 +265,7 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True,
     # (B, S, H, D) -> (B*H, S, D); kv head index = q head index // n_rep.
     qt = jnp.moveaxis(q, 2, 1).reshape(b * hq, sq, d)
     kt = jnp.moveaxis(k, 2, 1).reshape(b * hkv, skv, d)
-    vt = jnp.moveaxis(v, 2, 1).reshape(b * hkv, skv, d)
+    vt = jnp.moveaxis(v, 2, 1).reshape(b * hkv, skv, dv)
 
     if window is not None and not causal:
         raise ValueError("a window needs causal attention")
@@ -280,6 +287,8 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True,
     if window is not None:
         kernel = functools.partial(kernel, window=window)
         named = {"name": WINDOW_KERNEL}
+    elif dv != d:
+        named = {"name": LATENT_KERNEL}
     out, lse = pl.pallas_call(
         kernel,
         **named,
@@ -287,24 +296,24 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((1, block_k, d), kv_index),
-            pl.BlockSpec((1, block_k, d), kv_index),
+            pl.BlockSpec((1, block_k, dv), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * hq, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b * hq, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((b * hq, 1, sq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
     )(qt, kt, vt)
-    out = jnp.moveaxis(out.reshape(b, hq, sq, d), 1, 2)
+    out = jnp.moveaxis(out.reshape(b, hq, sq, dv), 1, 2)
     if return_lse:
         return out, lse.reshape(b, hq, sq)
     return out
@@ -527,14 +536,25 @@ def _on_tpu(x) -> bool:
         return jax.default_backend() == "tpu"
 
 
-def attention_path(sq: int, skv: int, head_dim: int, on_tpu: bool) -> str:
+def attention_path(sq: int, skv: int, head_dim: int, on_tpu: bool,
+                   value_dim: Optional[int] = None) -> str:
     """The implementation ``attention`` picks for these shapes:
     ``"pallas"`` (the flash kernels) or ``"blockwise"`` (plain jax).
-    The kernels need a TPU, an MXU-wide head and lane-aligned blocks.
+    The kernels need a TPU, lane-aligned blocks and a head they can
+    tile: queries and keys a multiple of 128 wide, or, where the values
+    have a width of their own (``value_dim``: latent attention's 192 and
+    128), queries and keys a multiple of 64 and values of 128 (the
+    forward kernel takes the two widths; compiled for a v5e in
+    tests/test_tpu_compile.py). Any other head goes to ``blockwise``:
+    ``LLMEngine`` logs the path its programs take at start.
     The multi-device model path asks here too, with the platform of its
     mesh (models/llama.py). What a program was really compiled to is
     read from its text (``tpu_custom_call``), not from this predicate."""
-    if (on_tpu and head_dim % 128 == 0
+    if value_dim in (None, head_dim):
+        head_ok = head_dim % 128 == 0
+    else:
+        head_ok = head_dim % 64 == 0 and value_dim % 128 == 0
+    if (on_tpu and head_ok
             and _pick_block(sq, 512) is not None
             and _pick_block(skv, 512) is not None):
         return "pallas"
@@ -570,7 +590,12 @@ def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
     (the blockwise path differentiates either way)."""
     if use_pallas is None:
         use_pallas = attention_path(q.shape[1], k.shape[1], q.shape[-1],
-                                    _on_tpu(q)) == "pallas"
+                                    _on_tpu(q), v.shape[-1]) == "pallas"
+    if v.shape[-1] != q.shape[-1] and window is None:
+        # values of their own width: the forward kernel only (serving)
+        if use_pallas:
+            return flash_attention_tpu(q, k, v, causal=causal, scale=scale)
+        return blockwise_attention(q, k, v, causal=causal, scale=scale)
     if window is not None:
         if use_pallas:
             return flash_attention_tpu(q, k, v, causal=causal, scale=scale,

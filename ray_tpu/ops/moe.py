@@ -23,6 +23,8 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .quant import weight_einsum
+
 
 def _top_k_mask(probs: jnp.ndarray, k: int) -> jnp.ndarray:
     """(T, E) probs → (T, E) 0/1 mask of each token's top-k experts."""
@@ -340,9 +342,34 @@ def router_logits(x, router_w):
                           precision=jax.lax.Precision.HIGHEST)
 
 
+def route(probs, top_k: int, *, norm_topk_prob: bool = True,
+          n_group: int = 1, topk_group: int = 1, scale: float = 1.0):
+    """The router's choice: probs [T, E] float32 -> (weight [T, k],
+    chosen [T, k]). Group-limited (``n_group`` > 1, DeepSeek-V2's
+    ``group_limited_greedy``): the experts are ``n_group`` groups of
+    neighbours, a group's score is its best expert's probability, every
+    expert outside the ``topk_group`` best groups is given probability
+    0, and the ``top_k`` largest of what is left are chosen. The chosen
+    probabilities are used as the router gave them, or renormalised to
+    sum to 1 where ``norm_topk_prob``; then x ``scale``."""
+    T, E = probs.shape
+    if n_group > 1:
+        best = probs.reshape(T, n_group, E // n_group).max(-1)
+        _, groups = jax.lax.top_k(best, topk_group)             # [T, g]
+        kept = jnp.zeros((T, n_group), bool).at[
+            jnp.arange(T)[:, None], groups].set(True)
+        probs = jnp.where(jnp.repeat(kept, E // n_group, axis=1), probs, 0.0)
+    weight, chosen = jax.lax.top_k(probs, top_k)                # [T, k]
+    if norm_topk_prob:
+        weight = weight / jnp.maximum(weight.sum(-1, keepdims=True), 1e-9)
+    return weight * scale if scale != 1.0 else weight, chosen
+
+
 def moe_mlp_routed(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                    norm_topk_prob: bool = True, valid=None, layer=None,
-                   logits=None, activation: str = "silu"):
+                   logits=None, activation: str = "silu",
+                   n_group: int = 1, topk_group: int = 1,
+                   scale: float = 1.0, held=None, shared=None):
     """Dropless top-k gated expert layer, the serving path's one.
 
     x (B, S, D); router_w (D, E) float32; w_gate/w_up (E, D, M) and
@@ -356,11 +383,25 @@ def moe_mlp_routed(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     earlier and from another tensor than ``x`` (``router_logits`` of the
     attention's input, for a model whose router sits there); the rows
     are routed by them and ``router_w`` is not read. ``activation``: an
-    expert is ``act(gate) * up``, "silu" or "relu".
+    expert is ``act(gate) * up``, "silu" or "relu". ``n_group``,
+    ``topk_group``, ``scale``: see ``route``.
 
-    Returns (out (B, S, D), zero at rows that are not tokens; counts
-    int32 [2]: the (token, expert) rows the expert products were given,
-    and the experts with at least one row).
+    ``held`` (first, count): the experts that are HERE, one chip's share
+    of an expert-parallel layer. The router keeps its E outputs and
+    chooses among all of them; the expert matrices hold ``count``
+    experts, E's ``first`` .. ``first + count - 1``; a (token, expert)
+    row whose expert is elsewhere sorts behind every group, exactly as
+    a row that is not a token, and adds nothing: the output is this
+    chip's part of the layer's result. Nothing stands in for the other
+    chips or the exchange with them. None: every expert is here.
+
+    ``shared`` (w_gate (D, Ms), w_up, w_down (Ms, D)): a dense SwiGLU
+    every token passes through, added to the routed part.
+
+    Returns (out (B, S, D), the routed part zero at rows that are not
+    tokens; counts int32 [3]: the (token, expert) rows the expert
+    products were given, the experts with at least one row, and the
+    tokens' rows routed to experts that are not here).
     """
     B, S, D = x.shape
     T = B * S
@@ -368,21 +409,29 @@ def moe_mlp_routed(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     if logits is None:
         logits = router_logits(xt, router_w)
     E = logits.shape[-1]
+    first, count = held or (0, E)
     with jax.named_scope("rt.moe.route"):
         probs = jax.nn.softmax(logits.reshape(T, E), axis=-1)
-        weight, chosen = jax.lax.top_k(probs, top_k)            # [T, k]
-        if norm_topk_prob:
-            weight = weight / jnp.maximum(
-                weight.sum(-1, keepdims=True), 1e-9)
-        expert = chosen.reshape(T * top_k)
-        if valid is not None:
-            # rows that are not tokens sort behind every group
-            expert = jnp.where(jnp.repeat(valid.reshape(T), top_k),
-                               expert, E)
+        weight, chosen = route(probs, top_k, norm_topk_prob=norm_topk_prob,
+                               n_group=n_group, topk_group=topk_group,
+                               scale=scale)
+        expert = chosen.reshape(T * top_k) - first
+        given = None if valid is None else jnp.repeat(valid.reshape(T),
+                                                      top_k)
+        elsewhere = jnp.int32(0)
+        if held is not None:
+            here = (expert >= 0) & (expert < count)
+            elsewhere = jnp.sum(~here if given is None else given & ~here,
+                                dtype=jnp.int32)
+            given = here if given is None else given & here
+        if given is not None:
+            # rows that are not tokens, and rows whose expert is
+            # elsewhere, sort behind every group
+            expert = jnp.where(given, expert, count)
         order = jnp.argsort(expert, stable=True)
         row_expert = expert[order]
         group_sizes = jnp.sum(
-            expert[:, None] == jnp.arange(E, dtype=expert.dtype)[None, :],
+            expert[:, None] == jnp.arange(count, dtype=expert.dtype)[None, :],
             axis=0, dtype=jnp.int32)
         rows = xt[order // top_k]                               # [T*k, D]
     with jax.named_scope("rt.moe.experts"):
@@ -396,11 +445,19 @@ def moe_mlp_routed(x, router_w, w_gate, w_up, w_down, *, top_k: int,
         # rows behind the last group were never written (they may hold
         # anything, NaN too): mask them, which also zeroes the output of
         # rows that are not tokens
-        down = jnp.where((row_expert < E)[:, None], down, 0)
+        down = jnp.where((row_expert < count)[:, None], down, 0)
         back = jnp.zeros_like(order).at[order].set(
             jnp.arange(T * top_k, dtype=order.dtype), unique_indices=True)
         out = jnp.einsum("tk,tkd->td", weight,
                          down[back].reshape(T, top_k, D).astype(jnp.float32))
+    if shared is not None:
+        with jax.named_scope("rt.moe.shared"):
+            g = weight_einsum("td,dm->tm", xt, shared[0])
+            u = weight_einsum("td,dm->tm", xt, shared[1])
+            out = out + weight_einsum(
+                "tm,md->td", jax.nn.silu(g) * u, shared[2]
+            ).astype(jnp.float32)
     counts = jnp.stack([group_sizes.sum(),
-                        jnp.sum(group_sizes > 0, dtype=jnp.int32)])
+                        jnp.sum(group_sizes > 0, dtype=jnp.int32),
+                        elsewhere])
     return out.reshape(B, S, D).astype(x.dtype), counts

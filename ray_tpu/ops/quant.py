@@ -116,6 +116,16 @@ _LLAMA_LAYER_CONTRACT = {
     "w_gate": (1,),  # (L, d, m)       contract d
     "w_up": (1,),
     "w_down": (1,),  # (L, m, d)       contract m
+    # latent attention (models/llama.py, ``cfg.latent``)
+    "wq_a": (1,),    # (L, d, rq)      contract d
+    "wq_b": (1,),    # (L, rq, h * hd) contract rq
+    "wkv_a": (1,),   # (L, d, rkv + rope)
+    "w_uk": (1,),    # (L, rkv, h, nope): per (head, column) scales; the
+    "w_uv": (1,),    # absorbed form scales the query by w_uk's instead
+    # shared experts: a dense SwiGLU beside the routed ones
+    "ws_gate": (1,),
+    "ws_up": (1,),
+    "ws_down": (1,),
 }
 # expert configs: the feed-forward has an "expert" axis after "layers",
 # never contracted either: per-expert per-output-channel scales (L, E, n),
@@ -133,19 +143,25 @@ def quantize_params(params: Dict, cfg=None) -> Dict:
     expert config's float32 router: its ties decide everything after
     it) stay as-is (tiny, precision-sensitive). Expert matrices get
     per-expert per-output-channel scales."""
-    layers = dict(params["layers"])
-    contract = dict(_LLAMA_LAYER_CONTRACT)
-    if "router" in layers:
-        contract.update(_EXPERT_LAYER_CONTRACT)
-    for name, axes in contract.items():
-        if name in layers:
-            layers[name] = quantize_weight(layers[name], axes)
-    return {
+    def stack(layers):
+        layers = dict(layers)
+        contract = dict(_LLAMA_LAYER_CONTRACT)
+        if "router" in layers:
+            contract.update(_EXPERT_LAYER_CONTRACT)
+        for name, axes in contract.items():
+            if name in layers:
+                layers[name] = quantize_weight(layers[name], axes)
+        return layers
+
+    out = {
         "embed": quantize_weight(params["embed"], (1,)),   # per-row
-        "layers": layers,
+        "layers": stack(params["layers"]),
         "final_norm": params["final_norm"],
         "lm_head": quantize_weight(params["lm_head"], (0,)),
     }
+    if "dense_layers" in params:
+        out["dense_layers"] = stack(params["dense_layers"])
+    return out
 
 
 def init_params_quantized(key, cfg, gains=None) -> Dict:
@@ -169,17 +185,24 @@ def init_params_quantized(key, cfg, gains=None) -> Dict:
 
 @partial(jax.jit, static_argnums=(1, 2))
 def _init_params_quantized_jit(key, cfg, gains=()) -> Dict:
-    L, d, hd = cfg.n_layers, cfg.dim, cfg.head_dim
-    h, hkv, m = cfg.n_heads, cfg.n_kv_heads, cfg.mlp_dim
-    ks = iter(jax.random.split(key, 16))
+    d, hd = cfg.dim, cfg.head_dim
+    h, hkv = cfg.n_heads, cfg.n_kv_heads
+    # what a configuration of the older kinds draws from these 16 keys
+    # stays what it was; latent attention, shared experts and leading
+    # dense layers need more and draw from a second set
+    plain = not (cfg.latent or cfg.n_shared_experts or cfg.n_dense_layers)
+    ks = iter(jax.random.split(key, 16) if plain else jax.random.split(
+        jax.random.fold_in(key, 1), 64))
     gains = dict(gains)
+    used = set()
 
     def qrand(shape, fan_in, out_dims: Tuple[int, ...], spread=False,
               name=None):
+        used.add(name)
         bits = jax.random.bits(next(ks), shape, jnp.uint8)
         q = jax.lax.bitcast_convert_type(bits, jnp.int8)
         s_shape = tuple(shape[i] for i in out_dims)
-        s = jnp.full(s_shape, gains.pop(name, 1.0) * (fan_in ** -0.5) / 74.0,
+        s = jnp.full(s_shape, gains.get(name, 1.0) * (fan_in ** -0.5) / 74.0,
                      jnp.float32)
         if spread:
             # scales that differ by expert and channel (x 0.5 to 1.5):
@@ -188,46 +211,86 @@ def _init_params_quantized_jit(key, cfg, gains=()) -> Dict:
                                        0.5, 1.5)
         return {"q": q, "s": s}
 
-    # made in this order: each qrand takes the next key, and a dense
-    # config's weights for a seed are what they always were
-    embed = qrand((cfg.vocab, d), d, (0,), name="embed")
-    layers = {
-        "attn_norm": jnp.ones((L, d), jnp.bfloat16),
-        "wq": qrand((L, d, h, hd), d, (0, 2, 3), name="wq"),
-        "wk": qrand((L, d, hkv, hd), d, (0, 2, 3), name="wk"),
-        "wv": qrand((L, d, hkv, hd), d, (0, 2, 3), name="wv"),
-        "wo": qrand((L, h, hd, d), h * hd, (0, 3), name="wo"),
-        "mlp_norm": jnp.ones((L, d), jnp.bfloat16),
-    }
-    if cfg.n_experts:
-        E = cfg.n_experts
-        # the router stays float32, as init_params makes it
-        layers["router"] = jax.random.normal(
-            next(ks), (L, d, E), jnp.float32) * (d ** -0.5)
-        layers.update(
-            w_gate=qrand((L, E, d, m), d, (0, 1, 3), True, "w_gate"),
-            w_up=qrand((L, E, d, m), d, (0, 1, 3), True, "w_up"),
-            w_down=qrand((L, E, m, d), m, (0, 1, 3), True, "w_down"))
-    else:
-        layers.update(
+    def gain(L, width):
+        # learned gains scattered about 1, so that a norm over the wrong
+        # width or with the wrong weight shows against a reference
+        return (1.0 + 0.25 * jax.random.normal(
+            next(ks), (L, width), jnp.float32)).astype(jnp.bfloat16)
+
+    def attention_leaves(L):
+        if not cfg.latent:
+            return {
+                "wq": qrand((L, d, h, hd), d, (0, 2, 3), name="wq"),
+                "wk": qrand((L, d, hkv, hd), d, (0, 2, 3), name="wk"),
+                "wv": qrand((L, d, hkv, hd), d, (0, 2, 3), name="wv"),
+                "wo": qrand((L, h, hd, d), h * hd, (0, 3), name="wo"),
+            }
+        rq, rkv, v = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.v_head_dim
+        return {
+            "wq_a": qrand((L, d, rq), d, (0, 2), name="wq_a"),
+            "q_a_norm": gain(L, rq),
+            "wq_b": qrand((L, rq, h * hd), rq, (0, 2), name="wq_b"),
+            "wkv_a": qrand((L, d, cfg.latent_dim), d, (0, 2), name="wkv_a"),
+            "kv_a_norm": gain(L, rkv),
+            # scales that differ by head and column: an absorbed product
+            # that forgot w_uk's scales shows
+            "w_uk": qrand((L, rkv, h, cfg.qk_nope_dim), rkv, (0, 2, 3),
+                          True, "w_uk"),
+            "w_uv": qrand((L, rkv, h, v), rkv, (0, 2, 3), True, "w_uv"),
+            "wo": qrand((L, h, v, d), h * v, (0, 3), name="wo"),
+        }
+
+    def dense_mlp(L, m):
+        return dict(
             w_gate=qrand((L, d, m), d, (0, 2), name="w_gate"),
             w_up=qrand((L, d, m), d, (0, 2), name="w_up"),
             w_down=qrand((L, m, d), m, (0, 2), name="w_down"))
-    if cfg.qk_norm:
-        # learned gains scattered about 1, so that a norm over the wrong
-        # width or with the wrong weight shows against a reference
-        def gain(width):
-            return (1.0 + 0.25 * jax.random.normal(
-                next(ks), (L, width), jnp.float32)).astype(jnp.bfloat16)
 
-        layers.update(q_norm=gain(h * hd), k_norm=gain(hkv * hd))
+    # made in this order: each qrand takes the next key, and a dense
+    # config's weights for a seed are what they always were
+    embed = qrand((cfg.vocab, d), d, (0,), name="embed")
+    L, m = cfg.n_moe_layers, cfg.mlp_dim
+    layers = {
+        "attn_norm": jnp.ones((L, d), jnp.bfloat16),
+        **attention_leaves(L),
+        "mlp_norm": jnp.ones((L, d), jnp.bfloat16),
+    }
+    if cfg.n_experts:
+        E, held = cfg.n_experts, cfg.n_experts_held
+        # the router stays float32, as init_params makes it; all E
+        # outputs, whatever part of the experts is held here
+        layers["router"] = jax.random.normal(
+            next(ks), (L, d, E), jnp.float32) * (d ** -0.5)
+        layers.update(
+            w_gate=qrand((L, held, d, m), d, (0, 1, 3), True, "w_gate"),
+            w_up=qrand((L, held, d, m), d, (0, 1, 3), True, "w_up"),
+            w_down=qrand((L, held, m, d), m, (0, 1, 3), True, "w_down"))
+        if cfg.n_shared_experts:
+            ms = cfg.n_shared_experts * m
+            layers.update(
+                ws_gate=qrand((L, d, ms), d, (0, 2), name="ws_gate"),
+                ws_up=qrand((L, d, ms), d, (0, 2), name="ws_up"),
+                ws_down=qrand((L, ms, d), ms, (0, 2), name="ws_down"))
+    else:
+        layers.update(dense_mlp(L, m))
+    if cfg.qk_norm:
+        layers.update(q_norm=gain(L, h * hd), k_norm=gain(L, hkv * hd))
     params = {
         "embed": embed,
         "layers": layers,
         "final_norm": jnp.ones((d,), jnp.bfloat16),
         "lm_head": qrand((d, cfg.vocab), d, (1,), name="lm_head"),
     }
-    if gains:
+    if cfg.n_dense_layers:
+        n = cfg.n_dense_layers
+        params["dense_layers"] = {
+            "attn_norm": jnp.ones((n, d), jnp.bfloat16),
+            **attention_leaves(n),
+            "mlp_norm": jnp.ones((n, d), jnp.bfloat16),
+            **dense_mlp(n, cfg.dense_mlp_dim),
+        }
+    unknown = set(gains) - used
+    if unknown:
         raise ValueError(f"gains for matrices that are not seeded: "
-                         f"{sorted(gains)}")
+                         f"{sorted(unknown)}")
     return params

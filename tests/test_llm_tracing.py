@@ -13,6 +13,7 @@ import subprocess
 import sys
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from ray_tpu.llm import EngineConfig, LLMEngine, SamplingParams
@@ -495,3 +496,79 @@ def test_timeline_phases_are_train_spans(tmp_path, phase):
     assert outer[2] <= inner[2] and inner[3] <= outer[3]
     _start, _end, phases, _intervals = timeline.close()
     assert set(phases) >= {"checkpoint_save"}
+
+
+# --- latent attention and the chip's share of the experts: spans in the
+# programs, counters in stats() (readers: benchmarks/layer_metrics/
+# mla_decode_roofline.py, mla_prefill_roofline.py) ---
+def _latent_engine():
+    from ray_tpu.models.llama import LlamaConfig
+
+    cfg = LlamaConfig(
+        vocab=128, dim=64, n_layers=3, n_heads=4, n_kv_heads=4, mlp_dim=32,
+        max_seq=256, dtype=jnp.float32, remat=False, rope_theta=10000.0,
+        n_experts=8, top_k=3, norm_topk_prob=False,
+        q_lora_rank=24, kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8,
+        v_head_dim=16, n_dense_layers=1, dense_mlp_dim=96,
+        n_shared_experts=2, n_group=4, topk_group=2, routed_scale=4.0,
+        experts_held=(2, 4))
+    return LLMEngine(init_params(jax.random.PRNGKey(0), cfg), cfg,
+                     EngineConfig(max_num_seqs=2, page_size=4, num_pages=33,
+                                  max_seq_len=64, decode_burst=2))
+
+
+@pytest.mark.parametrize("program, spans", [
+    ("prefill", ("rt.attn.mla.prefill", "rt.moe.shared", "rt.moe.route",
+                 "rt.moe.experts", "rt.moe.combine")),
+    ("decode", ("rt.attn.mla.decode", "rt.moe.shared", "rt.moe.route")),
+])
+def test_latent_programs_carry_their_spans(program, spans):
+    """The scopes are in the programs' own text (what a device trace
+    attributes operations to), prefill's expanded form under one name
+    and the absorbed form under another."""
+    from ray_tpu.llm.runner import decode_burst
+
+    engine = _latent_engine()
+    if program == "prefill":
+        text = engine.compile_prefill(20)[1].as_text()
+    else:
+        B = engine.ecfg.max_num_seqs
+        zi, zf = jnp.zeros(B, jnp.int32), jnp.ones(B, jnp.float32)
+        text = decode_burst.lower(
+            engine.params, engine.cache.k, engine.cache.v, zi, zi,
+            engine._tables(), jnp.zeros(B, bool), engine.cos, engine.sin,
+            0, zf, zi, zf, None, engine._bt(16), jnp.int32(1),
+            cfg=engine.cfg, n_steps=2, greedy=True).as_text(
+                debug_info=True)
+    for span in spans:
+        assert span in text, span
+    assert ("rt.attn.mla.decode" in text) == (program == "decode")
+    # the kernels' names the readers repeat
+    from ray_tpu.ops import mla
+    from ray_tpu.ops.attention import LATENT_KERNEL
+
+    assert (mla.DECODE_KERNEL, LATENT_KERNEL) == ("rt_mla_decode",
+                                                  "flash_mla_fwd")
+
+
+def test_rows_routed_elsewhere_and_the_latent_bytes_are_counted():
+    engine = _latent_engine()
+    assert engine.generate([[5, 17, 99, 3, 8, 21, 40]], SamplingParams(
+        temperature=0.0, max_tokens=5))
+    counters = engine.stats()["counters"]
+    # 7 prompt tokens and 4 decode steps through 2 expert layers, 3
+    # experts each: every row is given to an expert here or counted as
+    # elsewhere
+    assert (counters["expert_rows"] + counters["expert_rows_elsewhere"]
+            == (7 + 4) * 2 * 3)
+    assert counters["expert_rows_elsewhere"] > 0
+    # ONE row of 128 float32 values a layer a token (40 of them values)
+    assert counters["kv_bytes_per_token"] == 3 * 128 * 4
+    # a configuration that holds all its experts counts none elsewhere
+    plain = dataclasses.replace(CFG, n_experts=4, top_k=2)
+    other = LLMEngine(init_params(jax.random.PRNGKey(0), plain), plain,
+                      EngineConfig(max_num_seqs=2, page_size=4, num_pages=32,
+                                   max_seq_len=32, decode_burst=2))
+    assert "expert_rows_elsewhere" not in other.stats()["counters"]
+    assert other.stats()["counters"]["kv_bytes_per_token"] == (
+        2 * plain.n_layers * plain.n_kv_heads * plain.head_dim * 4)

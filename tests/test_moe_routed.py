@@ -56,7 +56,7 @@ def test_routed_layer_matches_oracle_and_ignores_rows_that_are_no_tokens(
     got, counts = routed(x, router, *served)
     # float32 on both sides: summation order alone differs
     np.testing.assert_allclose(got, want, atol=2e-5)
-    assert counts.tolist() == [B * S * K, E]
+    assert counts.tolist() == [B * S * K, E, 0]     # none is elsewhere
     # padding at the end of two rows, and one row that is no sequence at
     # all: the real rows' outputs do not move, the others' are zero
     valid = jnp.arange(S)[None, :] < jnp.array([5, S, 0])[:, None]
@@ -89,6 +89,153 @@ GMM_CASES = {
     "640x384": (512, 640, 384, GROUPS),
     "2560x768-decode": (48, 2560, 768, [3, 0, 1, 20, 0, 7, 1, 9]),
 }
+
+
+# --- group-limited choice, the scaling factor, shared experts, and one
+# chip's share of the experts (DeepSeek-V2's expert layer) ---
+GROUPS, KEPT, FACTOR = 4, 1, 16.0
+
+
+def _plain_layer(x, router, weights, *, held=(0, E), shared=None,
+                 n_group=GROUPS, topk_group=KEPT, scale=FACTOR, top_k=K):
+    """The layer by a plain loop over tokens in float64: a group's score
+    is its best expert's probability, every expert outside the best
+    ``topk_group`` groups is given 0, the ``top_k`` largest of what is
+    left are chosen with weights p x ``scale``; only the experts ``held``
+    (first, count) are computed, and ``shared`` once for every token."""
+    silu = lambda a: a / (1 + np.exp(-a))                      # noqa: E731
+    x = np.asarray(x, np.float64).reshape(-1, D)
+    w = [np.asarray(a, np.float64) for a in weights]
+    first, count = held
+    out = np.zeros_like(x)
+    for t, row in enumerate(x):
+        z = row @ np.asarray(router, np.float64)
+        p = np.exp(z - z.max())
+        p /= p.sum()
+        groups = p.reshape(n_group, -1)
+        best = np.argsort(-groups.max(-1), kind="stable")[:topk_group]
+        kept = np.zeros_like(groups)
+        kept[best] = groups[best]
+        kept = kept.reshape(-1)
+        for e in np.argsort(-kept, kind="stable")[:top_k]:
+            if first <= e < first + count:
+                i = e - first
+                out[t] += kept[e] * scale * (
+                    (silu(row @ w[0][i]) * (row @ w[1][i])) @ w[2][i])
+    if shared is not None:
+        g, u, d = (np.asarray(a, np.float64) for a in shared)
+        out += (silu(x @ g) * (x @ u)) @ d
+    return out.reshape(B, S, D)
+
+
+def _shared_weights(seed=9, width=2 * M):
+    k = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(k[0], (D, width)) * D ** -0.5,
+            jax.random.normal(k[1], (D, width)) * D ** -0.5,
+            jax.random.normal(k[2], (width, D)) * width ** -0.5)
+
+
+def test_group_limited_choice_the_factor_and_the_shared_expert():
+    x, router, served, _ = _layer_weights(4)
+    shared = _shared_weights()
+    grouped = functools.partial(
+        moe.moe_mlp_routed, top_k=K, norm_topk_prob=False, n_group=GROUPS,
+        topk_group=KEPT, scale=FACTOR)
+    got, counts = jax.jit(functools.partial(grouped, shared=shared))(
+        x, router, *served)
+    np.testing.assert_allclose(
+        got, _plain_layer(x, router, served, shared=shared), atol=2e-4)
+    assert counts.tolist()[::2] == [B * S * K, 0]
+    # the group limit changes choices here: ungrouped top-k differs
+    plain, _ = jax.jit(functools.partial(
+        moe.moe_mlp_routed, top_k=K, norm_topk_prob=False, scale=FACTOR))(
+            x, router, *served)
+    routed_only, _ = jax.jit(grouped)(x, router, *served)
+    assert np.abs(np.asarray(plain) - np.asarray(routed_only)).max() > 1e-2
+    # every chosen expert lies in a token's best group
+    probs = jax.nn.softmax(
+        jnp.einsum("bsd,de->bse", x, router).reshape(-1, E), -1)
+    weight, chosen = moe.route(probs, K, norm_topk_prob=False,
+                               n_group=GROUPS, topk_group=KEPT,
+                               scale=FACTOR)
+    best = np.argsort(-np.asarray(probs).reshape(-1, GROUPS, E // GROUPS)
+                      .max(-1), -1, kind="stable")[:, :KEPT]
+    assert all(c // (E // GROUPS) in best[t]
+               for t, row in enumerate(np.asarray(chosen)) for c in row)
+    np.testing.assert_allclose(
+        weight, FACTOR * np.take_along_axis(np.asarray(probs),
+                                            np.asarray(chosen), -1),
+        rtol=1e-6)
+    # renormalised first, then the factor
+    normed, _ = moe.route(probs, K, norm_topk_prob=True, n_group=GROUPS,
+                          topk_group=KEPT, scale=FACTOR)
+    np.testing.assert_allclose(normed.sum(-1), FACTOR, rtol=1e-5)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["plain", "int8"])
+def test_the_four_shares_of_the_experts_add_up_to_the_uncut_layer(quantized):
+    """The share tied to the model. Four chips hold two experts each of
+    the eight; every chip routes over all eight and computes its own
+    experts' part; the shared expert is on every chip. The routed parts
+    of the four shares, and the shared expert counted ONCE, are the
+    uncut layer; every (token, expert) row is given to exactly one
+    chip's experts."""
+    x, router, served, plain = _layer_weights(5, quantized)
+    shared = _shared_weights()
+    layer = functools.partial(
+        moe.moe_mlp_routed, top_k=K, norm_topk_prob=False, n_group=GROUPS,
+        topk_group=KEPT, scale=FACTOR)
+    whole, whole_counts = jax.jit(functools.partial(layer, shared=shared))(
+        x, router, *served)
+    np.testing.assert_allclose(
+        whole, _plain_layer(x, router, plain, shared=shared), atol=5e-4)
+    per = E // 4
+    parts, given, elsewhere = [], 0, []
+    for chip in range(4):
+        held = (chip * per, per)
+        mine = [jax.tree.map(lambda a: a[held[0]:held[0] + per], w)
+                for w in served]
+        part, counts = jax.jit(functools.partial(layer, held=held))(
+            x, router, *mine)
+        np.testing.assert_allclose(
+            part, _plain_layer(x, router, [
+                np.asarray(w)[held[0]:held[0] + per] for w in plain],
+                held=held), atol=5e-4)
+        parts.append(np.asarray(part, np.float64))
+        given += int(counts[0])
+        elsewhere.append(int(counts[2]))
+        assert int(counts[0]) + int(counts[2]) == B * S * K
+        assert int(counts[1]) <= per
+    only_shared = _plain_layer(x, router, plain, held=(0, 0), shared=shared)
+    np.testing.assert_allclose(sum(parts) + only_shared, whole, atol=1e-3)
+    assert given == B * S * K == int(whole_counts[0])
+    assert sum(elsewhere) == 3 * B * S * K
+
+
+def test_a_row_routed_elsewhere_adds_nothing_and_is_counted():
+    x, router, served, _ = _layer_weights(6)
+    held = (2, 3)
+    mine = [w[2:5] for w in served]
+    layer = jax.jit(functools.partial(
+        moe.moe_mlp_routed, top_k=K, norm_topk_prob=False, held=held))
+    got, counts = layer(x, router, *mine)
+    probs = np.asarray(jax.nn.softmax(
+        jnp.einsum("bsd,de->bse", x, router), -1)).reshape(-1, E)
+    chosen = np.argsort(-probs, -1, kind="stable")[:, :K]
+    here = (chosen >= 2) & (chosen < 5)
+    assert counts.tolist() == [int(here.sum()), len(set(chosen[here])),
+                               int((~here).sum())]
+    # a token whose experts are all elsewhere gets exactly nothing
+    nowhere = ~here.any(-1)
+    assert nowhere.any()
+    assert not np.asarray(got).reshape(-1, D)[nowhere].any()
+    # rows that are no tokens are neither given nor counted elsewhere
+    valid = jnp.arange(S)[None, :] < jnp.array([5, S, 0])[:, None]
+    _, masked = layer(x, router, *mine, valid=valid)
+    tokens = np.asarray(valid).reshape(-1)
+    assert masked.tolist()[::2] == [int(here[tokens].sum()),
+                                    int((~here[tokens]).sum())]
+
 
 
 @pytest.mark.parametrize("quantized", [False, True])
@@ -304,7 +451,7 @@ def test_engine_logits_match_a_plain_float32_forward_pass():
                                         jnp.int32),
             jnp.int32(pos), jnp.int32(1), tables, cos, sin, cfg=MOE)
         np.testing.assert_allclose(logits[0], want[pos], atol=1e-3)
-        assert counts.tolist() == [MOE.top_k * MOE.n_layers] * 2
+        assert counts.tolist() == [MOE.top_k * MOE.n_layers] * 2 + [0]
     # the control: the same pass with a renormalised router is far off
     off, _ = _plain_forward(params, jnp.asarray(tokens),
                             dataclasses.replace(MOE, norm_topk_prob=True))

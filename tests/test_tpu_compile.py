@@ -320,3 +320,124 @@ def test_routed_expert_layer_reads_int8_experts_without_a_wide_copy(
     assert not re.findall(r"(?:bf16|f32)" + experts, text)
     assert set(re.findall(r"s8" + experts, text)) == {
         f"s8[{L},{E},{d},{m}]", f"s8[{L},{E},{m},{d}]"}
+
+
+# DeepSeek-V2's widths as the benchmark's configuration runs them (one
+# chip's share: 40 of 160 experts, a quarter of the vocabulary), at two
+# layers: the leading dense one and one expert layer
+LATENT_WIDTHS = dict(
+    vocab=25600, dim=5120, n_layers=2, n_heads=128, n_kv_heads=128,
+    mlp_dim=1536, max_seq=163840, rope_theta=1e4, norm_eps=1e-6,
+    n_experts=160, top_k=6, norm_topk_prob=False,
+    q_lora_rank=1536, kv_lora_rank=512, qk_nope_dim=128, qk_rope_dim=64,
+    v_head_dim=128, attn_scale=0.114721, n_dense_layers=1,
+    dense_mlp_dim=12288, n_shared_experts=2, n_group=8, topk_group=3,
+    routed_scale=16.0, experts_held=(0, 40),
+    rope_scaling=(("beta_fast", 32), ("beta_slow", 1), ("factor", 40),
+                  ("mscale", 0.707), ("mscale_all_dim", 0.707),
+                  ("original_max_position_embeddings", 4096),
+                  ("type", "yarn")))
+
+
+@pytest.fixture
+def latent(one_chip, monkeypatch):
+    """The programs' operands on the described chip. ``attention`` asks
+    the default backend whether it is a TPU, and here that is the CPU:
+    these compiles ARE for a TPU, so the dispatcher is told so."""
+    import sys
+
+    import ray_tpu.ops  # noqa: F401  (the package's ``attention`` is the function)
+    from ray_tpu.models import LlamaConfig
+    from ray_tpu.ops import rope_frequencies
+    from ray_tpu.ops.quant import init_params_quantized
+
+    monkeypatch.setattr(sys.modules["ray_tpu.ops.attention"], "_on_tpu",
+                        lambda x: True)
+    cfg = LlamaConfig(**LATENT_WIDTHS)
+    params = _on_chip(jax.eval_shape(
+        lambda: init_params_quantized(jax.random.PRNGKey(0), cfg)), one_chip)
+    cos, sin = _on_chip(jax.eval_shape(lambda: rope_frequencies(
+        cfg.rope_dim, 16384, cfg.rope_theta, scaling=cfg.rope_scaling)),
+        one_chip)
+    pool = _sds((cfg.n_layers, 2049, 64, cfg.latent_row), cfg.dtype,
+                one_chip)
+    return cfg, params, cos, sin, pool
+
+
+def test_latent_prefill_is_on_the_flash_kernel_and_writes_one_pool(
+        latent, one_chip):
+    """The served prefill of the latent family provably runs the flash
+    kernel: heads that score 192 wide and return 128 are no multiple of
+    128, and ``attention_path`` used to send such a head to plain jax in
+    silence. Read from the compiled program's text: a ``flash_mla_fwd``
+    custom call a layer scan (the dense layer's and the expert
+    layers'), the three grouped expert products, the ONE donated pool
+    aliased to its output, and no temporary of the pool's size (its
+    rows are written a page at a time, in place)."""
+    from ray_tpu.llm.runner import prefill_sample
+
+    cfg, params, cos, sin, pool = latent
+    one_i = _sds((1,), jnp.int32, one_chip)
+    one_f = _sds((1,), jnp.float32, one_chip)
+    compiled = prefill_sample.lower(
+        params, pool, None, _sds((1, 2048), jnp.int32, one_chip), one_i,
+        _sds((1, 256), jnp.int32, one_chip), cos, sin, 0, one_f, one_i,
+        one_f, None, cfg=cfg, greedy=True).compile()
+    text = compiled.as_text()
+    assert text.count("%flash_mla_fwd") >= 2, "prefill is not on the kernel"
+    assert text.count("%rt_moe_gmm") >= 3
+    assert text.count("tpu_custom_call") == 5
+    mem = compiled.memory_analysis()
+    size = pool.size * pool.dtype.itemsize
+    assert mem.alias_size_in_bytes >= size, mem
+    pooled = f"bf16[{cfg.n_layers},2049,64,{cfg.latent_row}]"
+    assert not [line for line in text.splitlines()
+                if f" = {pooled}" in line and " copy(" in line]
+
+
+def test_latent_decode_burst_reads_the_pool_where_it_lies(latent, one_chip):
+    """A burst copies no page: its attention is the decode kernel over
+    each slot's own pages (one a layer scan), the pool is neither copied
+    nor turned into another layout (a scatter of single rows did both:
+    3 GB of temporaries at nine layers), and the query's bottleneck
+    matrix is the only weight the compiler lays out anew."""
+    from ray_tpu.llm.runner import decode_burst
+
+    cfg, params, cos, sin, pool = latent
+    B = 8
+    i32, f32 = _sds((B,), jnp.int32, one_chip), _sds((B,), jnp.float32,
+                                                     one_chip)
+    compiled = decode_burst.lower(
+        params, pool, None, i32, i32, _sds((B, 256), jnp.int32, one_chip),
+        _sds((B,), jnp.bool_, one_chip), cos, sin, 0, f32, i32, f32, None,
+        _sds((B, 128), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
+        cfg=cfg, n_steps=8, greedy=True).compile()
+    text = compiled.as_text()
+    assert text.count("%rt_mla_decode") >= 2
+    pooled = f"bf16[{cfg.n_layers},2049,64,{cfg.latent_row}]"
+    assert not [line for line in text.splitlines()
+                if f" = {pooled}" in line and " copy(" in line]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool.size * 2, mem
+    assert mem.temp_size_in_bytes < 0.5 * 1024**3, mem
+
+
+def test_latent_flash_and_decode_kernels_compile_alone(one_chip):
+    """Mosaic takes the two kernels at the published widths: the flash
+    forward at queries and keys of 192 and values of 128, and the decode
+    kernel with 128 heads as the rows of each product over 8 pages of 64
+    rows of 640."""
+    from ray_tpu.ops import mla
+    from ray_tpu.ops.attention import flash_attention_tpu
+
+    q = _sds((1, 2048, 128, 192), jnp.bfloat16, one_chip)
+    v = _sds((1, 2048, 128, 128), jnp.bfloat16, one_chip)
+    assert _custom_calls(functools.partial(
+        flash_attention_tpu, causal=True, scale=0.114721), q, q, v) == 1
+    assert _custom_calls(
+        functools.partial(mla.decode_attention_tpu, scale=0.114721,
+                          rank=512),
+        _sds((8, 128, 640), jnp.bfloat16, one_chip),
+        _sds((9, 2049, 64, 640), jnp.bfloat16, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((8, 256), jnp.int32, one_chip),
+        _sds((8,), jnp.int32, one_chip)) == 1
