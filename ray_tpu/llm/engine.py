@@ -283,6 +283,10 @@ class LLMEngine:
             "rounds": 0, "decode_steps": 0,
             "width_hist": [0] * (self.ecfg.decode_burst + 1),
             "active_slot_steps": 0, "prefills": 0, "prefill_tokens": 0,
+            # the rows those tokens were padded to (a whole prompt's
+            # bucket, a chunk's width): tokens over these is the share
+            # of prefill's rows that are tokens
+            "prefill_bucket_tokens": 0,
             "preemptions": 0, "host_s": dict.fromkeys(PHASES, 0.0),
             # the bursts' page lists, summed over rounds: pages that hold
             # old context of decoding slots, pages the burst copied
@@ -901,6 +905,7 @@ class LLMEngine:
         state.ctx_len = L
         self._counters["prefills"] += 1
         self._counters["prefill_tokens"] += L
+        self._counters["prefill_bucket_tokens"] += bucket
         with self._phase("prefill.sync"):
             tok = int(self._read_back(toks, counts)[0])
         if not state.output:
@@ -957,6 +962,7 @@ class LLMEngine:
         state.prefill_pos = start + n
         self._counters["prefills"] += 1
         self._counters["prefill_tokens"] += n
+        self._counters["prefill_bucket_tokens"] += C
         if state.prefill_pos < L:
             return []  # more chunks to go; decode interleaves meanwhile
         with self._phase("prefill.dispatch"):

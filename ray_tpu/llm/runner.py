@@ -566,7 +566,10 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
     ``lora``: see ``_layers``; the pools and tables one a layer group
     (``_groups``). The layers hand their K and V rows out of
     the scan ([L, B, S, kvh, hd], in the cache's dtype); padding rows
-    (position >= prompt_len) are dropped.
+    (position >= prompt_len) are dropped. Attention is told the prompts'
+    lengths: on the TPU the flash kernel leaves out the query blocks
+    that hold no token (``flash_attention_tpu``), everything else in a
+    layer still runs the bucket's rows.
 
     Returns (logits [B, vocab], cache_k, cache_v, expert counts: see
     ``_mlp``; None for a dense config).
@@ -601,11 +604,14 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
                 keys, values = mla.expand(k, *v, cfg.n_heads,
                                           cfg.qk_rope_dim)
                 o = attention(q, keys, values, causal=True,
-                              scale=cfg.softmax_scale)
+                              scale=cfg.softmax_scale, lengths=prompt_lens)
             return o, (k.astype(pools[0][0].dtype),)
-        # right padding is safe under the causal mask: a real position
-        # only attends to earlier (real) positions
-        o = attention(q, k, v, causal=True, window=window)
+        # right padding is safe under the causal mask (a real position
+        # only attends to earlier, real, positions) and, told where the
+        # prompt ends, costs the kernel only the rest of the prompt's
+        # last block: the blocks behind it come back as zeros
+        o = attention(q, k, v, causal=True, window=window,
+                      lengths=prompt_lens)
         if window is not None and kept_rows[window] < S:
             k, v = (jax.vmap(lambda rows, at: jax.lax.dynamic_slice_in_dim(
                 rows, at, kept_rows[window], 0))(rows, kept_from[window])

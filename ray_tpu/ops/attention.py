@@ -149,11 +149,13 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
 # ---------------------------------------------------------------------------
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
+def _flash_fwd_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref, *,
                       scale, causal, block_q, block_k, seq_q, seq_k,
-                      window=None):
+                      heads, window=None):
     # grid = (batch*heads_q, q_blocks, kv_blocks); kv innermost/sequential.
+    # len_ref: the rows' lengths, prefetched as scalars, or None (bound
+    # by the caller: the kernel then has no such operand)
     i = pl.program_id(1)
     j = pl.program_id(2)
     nj = pl.num_programs(2)
@@ -173,6 +175,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         # ... and a block whose newest key lies a window or more behind
         # the q block's oldest query
         run &= (j * block_k + block_k - 1) > (i * block_q + q_off - window)
+    if len_ref is not None:
+        # ... and a q block that starts at or behind its row's end: no
+        # step of it runs, so the last one writes zeros (acc 0 over l
+        # 1e-30) and a finite LSE
+        run &= i * block_q < len_ref[pl.program_id(0) // heads]
 
     @pl.when(run)
     def _():
@@ -234,7 +241,8 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True,
                         block_q: int = 512, block_k: int = 512,
                         interpret: bool = False,
                         return_lse: bool = False,
-                        window: Optional[int] = None):
+                        window: Optional[int] = None,
+                        lengths=None):
     """Pallas flash-attention forward (TPU). No autodiff — use
     ``attention`` for a differentiable entry point.
 
@@ -249,6 +257,17 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True,
     Values may be narrower or wider than queries and keys (latent
     attention's expanded heads score 192 wide and return 128): the
     output has the values' width, and that kernel is ``LATENT_KERNEL``.
+
+    ``lengths`` (int32 ``[B]``): the first ``lengths[b]`` query rows of
+    row ``b`` are tokens, the rest right padding. It reaches the same
+    kernel, under the same name, as a prefetched scalar operand. A query
+    block that starts at or behind its row's end computes nothing, is
+    written as zeros (a finite LSE), and fetches nothing: the index maps
+    hold at the blocks the row's last live query block left. Every other
+    query block runs exactly the steps it runs without ``lengths``, so
+    a token's output is the same to the bit; only padding rows that
+    share a block with tokens are still computed. ``None`` lowers to the
+    kernel as it was, with no such operand (training's forward).
 
     ``interpret=True`` runs the kernel in the Pallas interpreter (works on
     CPU) so the kernel body is testable without TPU hardware."""
@@ -270,49 +289,73 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True,
     if window is not None and not causal:
         raise ValueError("a window needs causal attention")
 
-    def kv_index(bh, i, j):
+    grid = (b * hq, sq // block_q, skv // block_k)
+
+    # the index maps take the prefetched lengths last, where there are any
+    def last_live(bh, lens):
+        """The row's last query block that holds a token."""
+        return jnp.maximum(lens[bh // hq] - 1, 0) // block_q
+
+    def q_index(bh, i, j, *lens):
+        if lens:
+            i = jnp.minimum(i, last_live(bh, *lens))
+        return (bh, i, 0)
+
+    def kv_index(bh, i, j, *lens):
         hb = bh // hq  # batch
         h = bh % hq
+        if lens:
+            # behind the row's end: the block its last live step left
+            last = last_live(bh, *lens)
+            j = jnp.where(i > last, grid[2] - 1, j)
+            i = jnp.minimum(i, last)
         if window is not None:
             first = i * block_q + (skv - sq)      # the q block's oldest
             j = jnp.clip(j, jnp.maximum(first - window + 1, 0) // block_k,
                          (first + block_q - 1) // block_k)
         return (hb * hkv + h // n_rep, j, 0)
 
-    grid = (b * hq, sq // block_q, skv // block_k)
     named = {}
     kernel = functools.partial(
         _flash_fwd_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, seq_q=sq, seq_k=skv)
+        block_q=block_q, block_k=block_k, seq_q=sq, seq_k=skv, heads=hq)
     if window is not None:
         kernel = functools.partial(kernel, window=window)
         named = {"name": WINDOW_KERNEL}
     elif dv != d:
         named = {"name": LATENT_KERNEL}
-    out, lse = pl.pallas_call(
-        kernel,
-        **named,
+    spec = dict(
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, block_q, d), q_index),
             pl.BlockSpec((1, block_k, d), kv_index),
             pl.BlockSpec((1, block_k, dv), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * hq, sq, dv), q.dtype),
-            jax.ShapeDtypeStruct((b * hq, 1, sq), jnp.float32),
+            pl.BlockSpec((1, block_q, dv), lambda bh, i, j, *_: (bh, i, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda bh, i, j, *_: (bh, 0, i)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
+        ])
+    if lengths is None:
+        kernel, operands = functools.partial(kernel, None), (qt, kt, vt)
+    else:
+        spec = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **spec))
+        operands = (lengths.astype(jnp.int32), qt, kt, vt)
+    out, lse = pl.pallas_call(
+        kernel,
+        **named,
+        **spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((b * hq, sq, dv), q.dtype),
+            jax.ShapeDtypeStruct((b * hq, 1, sq), jnp.float32),
         ],
         interpret=interpret,
-    )(qt, kt, vt)
+    )(*operands)
     out = jnp.moveaxis(out.reshape(b, hq, sq, dv), 1, 2)
     if return_lse:
         return out, lse.reshape(b, hq, sq)
@@ -583,25 +626,24 @@ _attention_tpu.defvjp(_attn_fwd, _attn_bwd)
 
 def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
               use_pallas: Optional[bool] = None,
-              window: Optional[int] = None):
+              window: Optional[int] = None, lengths=None):
     """Differentiable attention with TPU pallas fast path. ``window``: a
     query sees its ``window`` newest keys (see ``flash_attention_tpu``);
     the window kernel is forward only, which is all serving asks of it
-    (the blockwise path differentiates either way)."""
+    (the blockwise path differentiates either way). ``lengths`` (int32
+    ``[B]``, right padding behind them): the kernel skips the query
+    blocks that hold no token and writes them as zeros, forward only
+    too; the blockwise path computes every row, as it did. A token's row
+    is the same with and without it, what a padding row holds is not."""
     if use_pallas is None:
         use_pallas = attention_path(q.shape[1], k.shape[1], q.shape[-1],
                                     _on_tpu(q), v.shape[-1]) == "pallas"
-    if v.shape[-1] != q.shape[-1] and window is None:
-        # values of their own width: the forward kernel only (serving)
-        if use_pallas:
-            return flash_attention_tpu(q, k, v, causal=causal, scale=scale)
-        return blockwise_attention(q, k, v, causal=causal, scale=scale)
-    if window is not None:
-        if use_pallas:
-            return flash_attention_tpu(q, k, v, causal=causal, scale=scale,
-                                       window=window)
+    if not use_pallas:
         return blockwise_attention(q, k, v, causal=causal, scale=scale,
                                    window=window)
-    if use_pallas:
-        return _attention_tpu(q, k, v, causal, scale)
-    return blockwise_attention(q, k, v, causal=causal, scale=scale)
+    if v.shape[-1] != q.shape[-1] or window is not None or lengths is not None:
+        # the forward kernel only (serving): values of their own width,
+        # a window, rows that end before the bucket does
+        return flash_attention_tpu(q, k, v, causal=causal, scale=scale,
+                                   window=window, lengths=lengths)
+    return _attention_tpu(q, k, v, causal, scale)

@@ -121,6 +121,25 @@ def test_round_counters(served):
     assert c["host_s"]["decode.dispatch"] > 0.0
 
 
+@pytest.mark.parametrize("chunk, rows", [
+    # whole prompts of 4, 6 and 3 tokens, each in the smallest bucket;
+    # chunks of 4: one, two and one
+    (0, 3 * 16), (4, 4 * 4)], ids=["whole-prompt", "chunked"])
+def test_prefill_counts_the_rows_it_padded_to(tiny_params, chunk, rows):
+    """``prefill_bucket_tokens`` beside ``prefill_tokens``: their ratio
+    is the share of prefill's rows that are tokens."""
+    engine = LLMEngine(tiny_params, CFG, EngineConfig(
+        max_num_seqs=2, page_size=4, num_pages=64, max_seq_len=64,
+        decode_burst=4, prefill_chunk=chunk))
+    for p in PROMPTS:
+        engine.add_request(p, SamplingParams(temperature=0.0, max_tokens=2))
+    while engine.has_unfinished():
+        engine.step()
+    c = engine.stats()["counters"]
+    assert c["prefill_tokens"] == sum(len(p) for p in PROMPTS)
+    assert c["prefill_bucket_tokens"] == rows
+
+
 @pytest.mark.parametrize("slots, num_pages, prompts, rounds, bucket", [
     # (prompt tokens, max_tokens) a request; a round: the old context of
     # each decoding slot, whose pages of 4 the burst lists

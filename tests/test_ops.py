@@ -1,5 +1,7 @@
 """Kernel correctness vs naive oracles on the CPU mesh (SURVEY §4.4)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -166,6 +168,55 @@ def test_pallas_flash_interpret_bf16_and_uneven():
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
         rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("lengths", [
+    (1,), (200,), (256,), (512,), (130, 384)],
+    ids=["one", "inside_a_block", "whole_blocks", "the_bucket", "two_rows"])
+@pytest.mark.parametrize("variant", ["causal", "window", "own_value_width"])
+def test_pallas_flash_interpret_with_lengths(variant, lengths):
+    """Told where each row's tokens end, the kernel leaves out the query
+    blocks behind the end: a token's row is the row without ``lengths``
+    to the bit (and the plain reference's to its tolerance), a skipped
+    block is zeros with a finite LSE, and a NaN behind the end reaches
+    no token."""
+    from ray_tpu.ops.attention import flash_attention_tpu
+
+    S, block = 512, 128
+    d, dv = (192, 128) if variant == "own_value_width" else (128, 128)
+    window = 200 if variant == "window" else None
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+    B = len(lengths)
+    q = jax.random.normal(keys[0], (B, S, 4, d), jnp.float32)
+    k = jax.random.normal(keys[1], (B, S, 2, d), jnp.float32)
+    v = jax.random.normal(keys[2], (B, S, 2, dv), jnp.float32)
+    flash = functools.partial(flash_attention_tpu, window=window,
+                              block_q=block, block_k=block, interpret=True)
+    lens = jnp.asarray(lengths, jnp.int32)
+    out, lse = flash(q, k, v, lengths=lens, return_lse=True)
+    assert out.shape == (B, S, 4, dv)
+    assert np.isfinite(np.asarray(lse)).all()
+    without = flash(q, k, v)
+    want = naive_attention(q, k, v, window=window)
+    for b, n in enumerate(lengths):
+        np.testing.assert_array_equal(out[b, :n], without[b, :n])
+        np.testing.assert_allclose(out[b, :n], want[b, :n], atol=2e-5)
+        behind = -(-n // block) * block     # the first skipped block
+        assert not np.asarray(out[b, behind:]).any()
+        if behind < S:
+            assert np.abs(np.asarray(without[b, behind:])).max() > 1e-2
+    # NaN in every padding row of q and k, and in v's behind the block
+    # that holds the end (a padding row of THAT block is multiplied by a
+    # weight of 0, with and without lengths: in a prefill it is finite)
+    rows = jnp.arange(S)[None, :, None, None]
+    pad = rows >= lens[:, None, None, None]
+    skipped = rows >= -(-lens // block)[:, None, None, None] * block
+    poisoned = flash(jnp.where(pad, jnp.nan, q), jnp.where(pad, jnp.nan, k),
+                     jnp.where(skipped, jnp.nan, v), lengths=lens)
+    for b, n in enumerate(lengths):
+        np.testing.assert_array_equal(poisoned[b, :n], out[b, :n])
+    np.testing.assert_array_equal(np.asarray(poisoned)[np.asarray(
+        jnp.broadcast_to(skipped, poisoned.shape))], 0.0)
 
 
 @pytest.mark.parametrize("causal", [True, False])

@@ -15,6 +15,7 @@ find the library taken.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -115,6 +116,47 @@ def test_flash_window_forward_compiles_for_v5e(one_chip, shape, window):
     plain = jax.jit(functools.partial(
         flash_attention_tpu, causal=True)).lower(*args).compile().as_text()
     assert WINDOW_KERNEL not in plain
+
+
+def _kernel_calls(text):
+    """[(instruction name, operand count)] of a compiled program's
+    Mosaic kernels."""
+    return [(m.group(1), len(m.group(2).split(",")))
+            for m in re.finditer(
+                r"%([\w.\-]+) = [^\n]*? custom-call\(([^)]*)\), "
+                r'custom_call_target="tpu_custom_call"', text)]
+
+
+@pytest.mark.parametrize("widths, window, name", [
+    ((128, 128), None, None), ((128, 128), 4096, "flash_window_fwd"),
+    ((192, 128), None, "flash_mla_fwd")],
+    ids=["causal", "window", "own-value-width"])
+def test_flash_forward_with_lengths_is_the_same_kernel(
+        one_chip, widths, window, name):
+    """Told the rows' lengths, the forward kernel is still ONE Mosaic
+    kernel under the name it had, with the lengths as a fourth (scalar)
+    operand; not told, it takes its three operands as it always did."""
+    from ray_tpu.ops.attention import flash_attention_tpu
+
+    d, dv = widths
+    q = _sds((2, 8192, 8, d), jnp.bfloat16, one_chip)
+    k = _sds((2, 8192, 4, d), jnp.bfloat16, one_chip)
+    v = _sds((2, 8192, 4, dv), jnp.bfloat16, one_chip)
+    fwd = functools.partial(flash_attention_tpu, causal=True, window=window)
+
+    def told(q, k, v, lengths):
+        return fwd(q, k, v, lengths=lengths)
+
+    for fn, args, operands in (
+            (told, (q, k, v, _sds((2,), jnp.int32, one_chip)), 4),
+            (fwd, (q, k, v), 3)):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        (called, n), = _kernel_calls(text)
+        assert n == operands
+        if name is None:
+            assert "flash_" not in called
+        else:
+            assert called.startswith(name)
 
 
 @pytest.mark.parametrize("shape", FLASH_SHAPES.values(), ids=FLASH_SHAPES)
@@ -290,7 +332,6 @@ def test_routed_expert_layer_reads_int8_experts_without_a_wide_copy(
     products under the kernel's own name, and the experts stay the int8
     stacks they are stored as: no bf16 or float32 tensor of a layer's
     experts, and no int8 copy of one layer, in the program."""
-    import re
 
     from ray_tpu.ops.moe import GROUPED_KERNEL, moe_mlp_routed
 
@@ -393,6 +434,61 @@ def test_latent_prefill_is_on_the_flash_kernel_and_writes_one_pool(
     pooled = f"bf16[{cfg.n_layers},2049,64,{cfg.latent_row}]"
     assert not [line for line in text.splitlines()
                 if f" = {pooled}" in line and " copy(" in line]
+
+
+# SmallThinker's widths as its cell runs them, at one period of its
+# layer pattern: a full layer without rotary, three window layers
+WINDOW_WIDTHS = dict(
+    vocab=151936, dim=2560, n_layers=4, n_heads=28, n_kv_heads=4,
+    head_size=128, mlp_dim=768, max_seq=16384, rope_theta=1.5e6,
+    norm_eps=1e-6, n_experts=64, top_k=6, norm_topk_prob=True,
+    layer_pattern=("full_nope", "window", "window", "window"), window=4096,
+    router_input="attention", expert_act="relu")
+
+
+@pytest.mark.parametrize("family", ["latent", "window"])
+def test_prefill_sample_hands_its_flash_kernels_the_prompt_length(
+        latent, one_chip, family):
+    """The served prefill of the latent family and of the family with
+    window layers, told where the prompt ends: every flash kernel of the
+    program is still found under the name a trace's reader asks for
+    (``flash_mla_fwd``; ``flash_window_fwd`` and the full layer's span)
+    and takes the lengths as its fourth operand."""
+    from ray_tpu.llm.cache import window_group_pages
+    from ray_tpu.llm.runner import prefill_sample
+    from ray_tpu.models import LlamaConfig
+    from ray_tpu.ops import rope_frequencies
+    from ray_tpu.ops.quant import init_params_quantized
+
+    one_i = _sds((1,), jnp.int32, one_chip)
+    one_f = _sds((1,), jnp.float32, one_chip)
+    if family == "latent":
+        cfg, params, cos, sin, pool = latent
+        bucket, pools, tables = 2048, (pool, None), _sds(
+            (1, 256), jnp.int32, one_chip)
+        names = {"flash_mla_fwd"}
+    else:
+        cfg = LlamaConfig(**WINDOW_WIDTHS)   # ``latent`` patched _on_tpu
+        params = _on_chip(jax.eval_shape(lambda: init_params_quantized(
+            jax.random.PRNGKey(0), cfg)), one_chip)
+        cos, sin = _on_chip(jax.eval_shape(lambda: rope_frequencies(
+            cfg.head_dim, cfg.max_seq, cfg.rope_theta)), one_chip)
+        bucket = 8192
+        group = tuple(_sds(
+            (cfg.group_layers(g), 129 if w is None else window_group_pages(
+                1, w, 64, 8), 64, cfg.n_kv_heads, cfg.head_dim), cfg.dtype,
+            one_chip) for g, w in enumerate(cfg.kv_groups))
+        pools = (group, group)
+        tables = tuple(_sds((1, 128), jnp.int32, one_chip) for _ in group)
+        names = {"flash_window_fwd", "rt.attn.full"}
+    text = prefill_sample.lower(
+        params, *pools, _sds((1, bucket), jnp.int32, one_chip), one_i,
+        tables, cos, sin, 0, one_f, one_i, one_f, None, cfg=cfg,
+        greedy=True).compile().as_text()
+    flash = [(name, n) for name, n in _kernel_calls(text)
+             if "rt_moe_gmm" not in name]
+    assert {re.sub(r"[.\d]+$", "", name) for name, _ in flash} == names
+    assert {n for _, n in flash} == {4}
 
 
 def test_latent_decode_burst_reads_the_pool_where_it_lies(latent, one_chip):
