@@ -132,9 +132,16 @@ class RequestState:
 
 
 # the phases that partition a round: the last parts of the span names
-# (rt.engine.<phase>) and the keys of stats()["counters"]["host_s"]
-PHASES = ("schedule", "prefill.dispatch", "prefill.sync",
-          "decode.dispatch", "release", "decode.sync", "append")
+# (rt.engine.<phase>) and the keys of stats()["counters"]["host_s"].
+# A dispatch is two of them: ``build`` is what the host makes before it
+# can call (shapes, pages, the numpy rows, the sampling arrays),
+# ``dispatch`` the jitted call with the uploads in its arguments. A
+# speculative round has ``decode.draft`` before them: the window's
+# pages and the drafter's own calls of the device. Each decode name is
+# opened at most once a round.
+PHASES = ("schedule", "prefill.build", "prefill.dispatch", "prefill.sync",
+          "decode.draft", "decode.build", "decode.dispatch", "release",
+          "decode.sync", "append")
 _SPAN_NAMES = {phase: "rt.engine." + phase for phase in PHASES}
 
 
@@ -885,7 +892,7 @@ class LLMEngine:
         C = self.ecfg.prefill_chunk
         if C > 0:
             return self._run_prefill_chunk(state, seq, L, C)
-        with self._phase("prefill.dispatch"):
+        with self._phase("prefill.build"):
             bucket = prefill_bucket(L, self.ecfg.max_seq_len)
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :L] = seq
@@ -895,6 +902,7 @@ class LLMEngine:
             if self.lora_pool is not None:
                 lora = self.lora_pool.select(
                     [self.lora_pool.slot_of(state.model_id)])
+        with self._phase("prefill.dispatch"):
             toks, ck, cv, counts = prefill_sample(
                 self.params, self.cache.k, self.cache.v,
                 jnp.asarray(tokens), jnp.asarray([L], jnp.int32),
@@ -940,7 +948,7 @@ class LLMEngine:
                            L: int, C: int) -> List[StepOutput]:
         from .runner import prefill_chunk, sample_logits
 
-        with self._phase("prefill.dispatch"):
+        with self._phase("prefill.build"):
             start = state.prefill_pos
             n = min(C, L - start)
             tokens = np.zeros((1, C), np.int32)
@@ -949,6 +957,7 @@ class LLMEngine:
             # a handful of executables serve every prompt length
             span = self._span_bucket(
                 -(-(start + n) // self.ecfg.page_size))
+        with self._phase("prefill.dispatch"):
             bt = tuple(jnp.asarray(t.block_tables[
                 state.slot:state.slot + 1, :span]) for t in self.seq_tables)
             bt = bt if len(bt) > 1 else bt[0]
@@ -965,7 +974,7 @@ class LLMEngine:
         self._counters["prefill_bucket_tokens"] += C
         if state.prefill_pos < L:
             return []  # more chunks to go; decode interleaves meanwhile
-        with self._phase("prefill.dispatch"):
+        with self._phase("prefill.build"):
             if self.prefix_cache is not None and state.prompt_page_keys:
                 # prompt pages are now fully written: publish them for
                 # future requests sharing the prefix
@@ -975,6 +984,7 @@ class LLMEngine:
                     [int(p) for p in table[:len(state.prompt_page_keys)]])
             seed, temp, top_k, top_p, _greedy = self._sampling_arrays(
                 [state])
+        with self._phase("prefill.dispatch"):
             toks = sample_logits(logits, seed, temp, top_k, top_p)
         with self._phase("prefill.sync"):
             tok = int(self._read_back(toks)[0])
@@ -1057,7 +1067,7 @@ class LLMEngine:
                 return outs
         B = self.ecfg.max_num_seqs
         counters = self._counters
-        with self._phase("decode.dispatch"):
+        with self._phase("decode.build"):
             # the ONE call of _burst_width a round: the benchmark's
             # replica wraps it on the instance to watch the rounds
             K = self._burst_width()
@@ -1181,10 +1191,12 @@ class LLMEngine:
                     and s.ctx_len + kd <= self.ecfg.max_seq_len - 1
                     and s.params.max_tokens - len(s.output) >= 2)
 
-        with self._phase("decode.dispatch"):
-            if not any(s is not None and s.ctx_len > 0 and can_draft(s)
-                       for s in self.slots):
-                return None
+        # no phase is open yet: the plain round that takes over opens
+        # its own
+        if not any(s is not None and s.ctx_len > 0 and can_draft(s)
+                   for s in self.slots):
+            return None
+        with self._phase("decode.draft"):
             # provision BEFORE array assembly — may preempt victims, so
             # drafted/active sets are derived again afterwards
             for s in [s for s in self.slots
@@ -1232,6 +1244,7 @@ class LLMEngine:
                         res = None
                     if res is not None:
                         remote[s.slot] = [int(t) for t in res]
+        with self._phase("decode.build"):
             B = self.ecfg.max_num_seqs
             S = kd + 1
             tok = np.zeros((B, S), np.int32)
@@ -1246,6 +1259,7 @@ class LLMEngine:
                         s.ctx_len + 1 + np.arange(len(d)))
             seed, temp, top_k, top_p, greedy = self._sampling_arrays(
                 self.slots, advance=1)
+        with self._phase("decode.dispatch"):
             t0 = time.perf_counter()
             tgt, samp0, ck, cv, counts = verify_step(
                 self.params, self.cache.k, self.cache.v, jnp.asarray(tok),

@@ -27,6 +27,12 @@ from ..util import tracing
 if TYPE_CHECKING:  # this module stays importable without jax (llm/__init__)
     from .sampling import SamplingParams
 
+# The longest piece of ``rt.pump.lull``. A wait for a request can last
+# minutes, and an annotation that was open when a profiler started is
+# lost to it; the benchmark's trace reduction also drops a host event
+# over 20 times as long as the device gap it is held against.
+LULL_PIECE_S = 0.05
+
 
 class LLMServer:
     """Serve deployment class hosting one engine replica."""
@@ -123,6 +129,11 @@ class LLMServer:
             self.tokenizer = AutoTokenizer.from_pretrained(tokenizer)
         self._queues: Dict[str, asyncio.Queue] = {}
         self._pump_task: Optional[asyncio.Task] = None
+        # the open piece of a lull: an arrival (_ensure_pump) or the
+        # piece's own timer resolves it
+        self._lull_wake: Optional[asyncio.Future] = None
+        # seconds from the pump's own spans (stats()["pump"])
+        self._pump_stats = {"lull_s": 0.0, "lulls": 0}
         # per-tenant accounting: request id -> tenant, stashed at submit
         # (the serve tenant contextvar is gone by the time the pump
         # thread observes the finished request) and popped on finish
@@ -224,6 +235,7 @@ class LLMServer:
         self._verify_handle = None
         if speculation:
             self.configure_speculation(speculation)
+        self._mark_lulls()
 
     # --- serve replica hooks (fleet KV plane) ---
 
@@ -277,6 +289,25 @@ class LLMServer:
                 "serve_kv_handoff_retries_total",
                 "KV handoffs retried against another decode replica",
                 tag_keys=("model",)).set_default_tags(mtags)
+        self._mark_lulls()
+
+    def _mark_lulls(self) -> None:
+        """A replica without a request is in a lull from here on. The
+        pump is a task of the event loop; where this thread runs none
+        (``serve``'s ``Replica`` makes its callable before the actor's
+        loop exists) the first call that reaches the loop starts it: the
+        controller's ``check_health`` or a request."""
+        try:
+            asyncio.get_running_loop()
+        except RuntimeError:
+            return
+        self._ensure_pump()
+
+    async def check_health(self) -> None:
+        """Replica hook (``Replica.health_check``, the controller's
+        probe). It runs on the replica's loop, so a replica that has not
+        had a request marks its lulls from its first probe."""
+        self._ensure_pump()
 
     def prefix_cache_summary(self):
         """Replica hook: publish this engine's cached prefix pages for
@@ -404,9 +435,19 @@ class LLMServer:
     # --- engine pump: one thread-hop per step, fan-out to request queues ---
 
     def _ensure_pump(self) -> None:
+        """Called on the loop's thread once a request is in the engine's
+        hands: start the pump where none runs, and end its lull at once
+        (the pump resumes on the loop's next turn, as a new task would
+        start)."""
         if self._pump_task is None or self._pump_task.done():
             self._pump_task = asyncio.get_event_loop().create_task(
                 self._pump())
+        self._end_lull_piece()
+
+    def _end_lull_piece(self) -> None:
+        wake = self._lull_wake
+        if wake is not None and not wake.done():
+            wake.set_result(None)
 
     def _step_engine(self):
         # prefill replicas never decode: exported requests finish with
@@ -416,6 +457,44 @@ class LLMServer:
                 skip_decode=(self._pool == "prefill"))
 
     async def _pump(self) -> None:
+        """The replica's one long-lived task: rounds while the engine
+        has a request, a lull while it has none."""
+        while True:
+            await self._run_rounds()
+            await self._lull()
+
+    async def _lull(self) -> None:
+        """``rt.pump.lull``: no request is in the engine, and the device
+        waits for want of traffic. ``rt.pump.idle`` is the other wait:
+        requests ARE unfinished, a round returned nothing, and the pump
+        sleeps 2 ms before the next. The lull is cut into pieces of at
+        most ``LULL_PIECE_S`` and waits on a future, not on a poll: an
+        arrival resolves it. The pieces are annotations; the JSONL sink
+        gets one record for the whole lull."""
+        loop = asyncio.get_event_loop()
+        stats = self._pump_stats
+        wall0 = time.time() if tracing.tracing_enabled() else 0.0
+        waited_before = stats["lull_s"]
+        stats["lulls"] += 1
+        try:
+            while not self.engine.has_unfinished():
+                self._lull_wake = loop.create_future()
+                timer = loop.call_later(LULL_PIECE_S, self._end_lull_piece)
+                piece = tracing.piece_span("rt.pump.lull")
+                try:
+                    with piece:
+                        await self._lull_wake
+                finally:
+                    timer.cancel()
+                    stats["lull_s"] += piece.seconds
+        finally:
+            self._lull_wake = None
+            if wall0:
+                tracing.record_lane_event(
+                    "pump", "rt.pump.lull", wall0,
+                    wall0 + stats["lull_s"] - waited_before)
+
+    async def _run_rounds(self) -> None:
         loop = asyncio.get_event_loop()
         requests = self.engine.requests
         while self.engine.has_unfinished():
@@ -862,8 +941,13 @@ class LLMServer:
         return await self.completions(body)
 
     async def stats(self, _payload=None) -> Dict[str, Any]:
+        """The engine's ``stats()``, the replica's role, and the pump's
+        wait for want of traffic: ``lull_s`` seconds without a request
+        in ``lulls`` waits (the piece in progress, at most
+        ``LULL_PIECE_S``, is not in yet)."""
         out = self.engine.stats()
         out["pool"] = self._pool
+        out["pump"] = dict(self._pump_stats)
         return out
 
     @staticmethod

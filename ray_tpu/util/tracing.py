@@ -13,9 +13,10 @@ clock, beside the device's operations), and, with ``RAY_TPU_TRACING=1``,
 into the JSONL sink that ``timeline()`` renders. Names are fixed
 (``rt.<lane>.<phase>``); the benchmark's readers match on them:
 
-    rt.engine.{schedule,prefill.dispatch,prefill.sync,
-               decode.dispatch,decode.sync,append}     llm/engine.py
-    rt.pump.{fanout,idle}                              llm/serve.py
+    rt.engine.{schedule,prefill.build,prefill.dispatch,prefill.sync,
+               decode.draft,decode.build,decode.dispatch,release,
+               decode.sync,append}                     llm/engine.py
+    rt.pump.{fanout,idle,lull}                         llm/serve.py
     rt.train.step, rt.train.<phase>, rt.train.report   train/
 
 This module never imports jax: the benchmark's driver imports ray_tpu
@@ -313,6 +314,7 @@ class span:
     their own. Names are constants; nothing is formatted per call."""
 
     __slots__ = ("name", "args", "seconds", "_t0", "_wall0", "_annotation")
+    _lane_record = True     # piece_span: the caller writes the record
 
     def __init__(self, name: str, **args):
         self.name = name
@@ -329,7 +331,8 @@ class span:
         if hasattr(profiler, "TraceAnnotation"):
             self._annotation = self._annotate(profiler)
             self._annotation.__enter__()
-        self._wall0 = time.time() if tracing_enabled() else 0.0
+        self._wall0 = (time.time() if self._lane_record
+                       and tracing_enabled() else 0.0)
         self._t0 = time.perf_counter()
         return self
 
@@ -357,3 +360,14 @@ class step_span(span):
     def _annotate(self, profiler):
         return profiler.StepTraceAnnotation(self.name,
                                             step_num=self.args["step"])
+
+
+class piece_span(span):
+    """``span`` for one piece of a long wait that is cut into pieces (an
+    annotation that was open when the profiler started is lost, so a
+    wait of seconds is many short ones): an annotation and ``seconds``,
+    no lane record. The caller adds the pieces up and writes ONE
+    ``record_lane_event`` for the whole wait."""
+
+    __slots__ = ()
+    _lane_record = False

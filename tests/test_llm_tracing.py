@@ -5,12 +5,14 @@ and round counters (ISSUE 24). Traces are read with ``ProfileData``, as
 ``benchmarks/harness/trace.py`` reads the chip's."""
 
 import asyncio
+import collections
 import dataclasses
 import glob
 import json
 import os
 import subprocess
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -23,9 +25,12 @@ from ray_tpu.train.telemetry import StepTimeline
 from ray_tpu.util import tracing
 
 CFG = LLAMA_CONFIGS["tiny"]
-ENGINE_SPANS = ["rt.engine." + phase for phase in PHASES]
-PUMP_SPANS = ["rt.pump.fanout", "rt.pump.idle"]
+# what a plain round opens; ``decode.draft`` is a speculative round's
+ENGINE_SPANS = ["rt.engine." + phase for phase in PHASES
+                if phase != "decode.draft"]
+PUMP_SPANS = ["rt.pump.fanout", "rt.pump.idle", "rt.pump.lull"]
 PROMPTS = [[5, 17, 99, 3], [7, 8, 9, 10, 11, 12], [1, 2, 3]]
+IDLE_S = 0.3    # a served replica left without a request: six lull pieces
 
 
 @pytest.fixture(scope="module")
@@ -118,7 +123,10 @@ def test_round_counters(served):
     assert c["preemptions"] == 0
     assert list(c["host_s"]) == list(PHASES)
     assert all(v >= 0.0 for v in c["host_s"].values())
-    assert c["host_s"]["decode.dispatch"] > 0.0
+    # a dispatch is two phases: what the host builds, what it hands over
+    for half in ("prefill.build", "prefill.dispatch", "decode.build",
+                 "decode.dispatch"):
+        assert half in PHASES and c["host_s"][half] > 0.0
 
 
 @pytest.mark.parametrize("chunk, rows", [
@@ -322,6 +330,9 @@ def engine_trace(tiny_params, tmp_path_factory):
     while engine.has_unfinished():
         engine.step()
 
+    rounds = _watch_burst_width(engine)
+    warm_up = engine.stats()["counters"]["prefills"]
+
     def work():
         for p in PROMPTS:
             engine.add_request(p, SamplingParams(temperature=0.0,
@@ -329,32 +340,90 @@ def engine_trace(tiny_params, tmp_path_factory):
         while engine.has_unfinished():
             engine.step()
 
-    return _trace(tmp_path_factory.mktemp("engine_trace"), work)
+    events = _trace(tmp_path_factory.mktemp("engine_trace"), work)
+    return (events, engine.stats()["counters"]["prefills"] - warm_up,
+            len(rounds))
 
 
 @pytest.mark.parametrize("name", ENGINE_SPANS)
 def test_engine_phase_is_in_the_profiler_trace(engine_trace, name):
-    assert any(e[1] == name for e in engine_trace)
+    events, _prefills, _rounds = engine_trace
+    assert any(e[1] == name for e in events)
+
+
+def test_a_dispatch_opens_each_of_its_halves_once(engine_trace):
+    """``build`` and ``dispatch`` once a decode round and once a prefill:
+    a name that opened twice a round would say nothing about which half
+    the device waited for."""
+    events, prefills, rounds = engine_trace
+    count = collections.Counter(e[1] for e in events)
+    assert rounds > 0 and prefills == len(PROMPTS)
+    for phase in ("build", "dispatch", "sync"):
+        assert count["rt.engine.decode." + phase] == rounds, phase
+        assert count["rt.engine.prefill." + phase] == prefills, phase
+
+
+@pytest.mark.parametrize("options, rounds_of", [
+    (dict(prefill_chunk=4), lambda engine, c: c["rounds"]),
+    (dict(speculation={"draft_config": "tiny", "num_draft_tokens": 3,
+                       "draft_seed": 0}),
+     # a verify a speculative round, a burst where no slot could draft
+     lambda engine, c: len(engine.spec.verify_times) + c["rounds"])],
+    ids=["chunked-prefill", "speculative-decode"])
+def test_the_other_dispatch_paths_split_the_same_way(tiny_params, options,
+                                                     rounds_of):
+    """``_run_prefill_chunk`` and ``_run_spec_decode`` open the same four
+    names as the whole-prompt prefill and the burst: ``decode.dispatch``
+    once a round (a burst's or a verify's), ``prefill.dispatch`` once a
+    chunk and once more where the last chunk's logits are sampled. The
+    drafter's calls of the device are ``decode.draft``'s, once a
+    speculative round, so that ``build`` is the host's work alone."""
+    engine = LLMEngine(tiny_params, CFG, EngineConfig(
+        max_num_seqs=2, page_size=4, num_pages=64, max_seq_len=64,
+        **options))
+    opened = collections.Counter()
+    phase = engine._phase
+    engine._phase = lambda name: opened.update([name]) or phase(name)
+    assert engine.generate(PROMPTS, SamplingParams(temperature=0.0,
+                                                   max_tokens=6))
+    c = engine.stats()["counters"]
+    assert set(opened) <= set(PHASES)
+    assert opened["decode.dispatch"] == opened["decode.sync"] \
+        == rounds_of(engine, c) > 0
+    chunked = bool(options.get("prefill_chunk"))
+    if chunked:     # a round of slots that are all mid-prompt builds nothing
+        assert opened["decode.build"] >= opened["decode.dispatch"]
+        assert "decode.draft" not in opened
+    else:
+        assert opened["decode.build"] == opened["decode.dispatch"]
+        assert opened["decode.draft"] == len(engine.spec.verify_times) > 0
+    assert opened["prefill.dispatch"] == opened["prefill.build"] \
+        == c["prefills"] + chunked * len(PROMPTS)
+    assert all(c["host_s"][name] > 0.0 for name in opened)
 
 
 def test_no_span_encloses_a_round(engine_trace):
-    """The phases are siblings: every ``rt.`` event is one of the six,
+    """The phases are siblings: every ``rt.`` event is one of ``PHASES``,
     and none of them holds another (a parent over the round would take
     the name of every device idle gap from its children)."""
-    ours = sorted((e for e in engine_trace if e[1].startswith("rt.")),
+    ours = sorted((e for e in engine_trace[0] if e[1].startswith("rt.")),
                   key=lambda e: e[2])
     assert {e[1] for e in ours} == set(ENGINE_SPANS)
     for (_, a, _, a_end), (_, b, b_start, _) in zip(ours, ours[1:]):
         assert a_end <= b_start, f"{a} overlaps {b}"
 
 
-@pytest.fixture(scope="module")
-def pump_trace(tmp_path_factory):
+def _tiny_server(**engine_config):
     from ray_tpu.llm.serve import LLMServer
 
-    server = LLMServer("tiny", engine_config={
+    return LLMServer("tiny", engine_config={
         "max_num_seqs": 2, "page_size": 4, "num_pages": 64,
-        "max_seq_len": 64})
+        "max_seq_len": 64, **engine_config})
+
+
+@pytest.fixture(scope="module")
+def pump_trace(tmp_path_factory):
+    server = _tiny_server()
     states = []
     observe = server._observe_finished
 
@@ -365,6 +434,9 @@ def pump_trace(tmp_path_factory):
     server._observe_finished = keep
 
     async def serve_some():
+        # the replica sits without a request before the burst and after
+        await server.check_health()
+        await asyncio.sleep(IDLE_S)
         real_step = server.engine.step
         # one empty round, so that the pump idles once under the trace
         server.engine.step = lambda **kw: (
@@ -372,6 +444,7 @@ def pump_trace(tmp_path_factory):
         await asyncio.gather(*[server.completions(
             {"prompt_ids": p, "temperature": 0.0, "max_tokens": 4})
             for p in PROMPTS])
+        await asyncio.sleep(IDLE_S)
         return await server.stats()
 
     asyncio.run(serve_some())       # compile outside the trace
@@ -390,6 +463,34 @@ def test_pump_phase_is_in_the_profiler_trace(pump_trace, name):
         <= set(ENGINE_SPANS + PUMP_SPANS)
 
 
+def test_a_lull_is_cut_into_pieces_and_ends_before_the_round(pump_trace):
+    """The wait for a request is many short annotations (one that was
+    open when a profiler started would be lost to it), and none is open
+    while the engine has a request: by the order of events, every piece
+    lies wholly before or wholly behind the burst's rounds, and the last
+    piece before them ends before their first ``rt.engine.schedule``."""
+    from ray_tpu.llm import serve
+
+    events, _states, stats = pump_trace
+    pieces = sorted((e[2], e[3]) for e in events if e[1] == "rt.pump.lull")
+    rounds = [(e[2], e[3]) for e in events
+              if e[1].startswith("rt.engine.") or e[1] == "rt.pump.fanout"]
+    # the bound is the code's own; the slack is for a loaded CPU, which
+    # can only wake the loop late
+    assert serve.LULL_PIECE_S <= 0.05
+    assert all(end - start <= (serve.LULL_PIECE_S + 0.5) * 1e9
+               for start, end in pieces)
+    first, last = min(s for s, _ in rounds), max(e for _, e in rounds)
+    before = [p for p in pieces if p[1] <= first]
+    behind = [p for p in pieces if p[0] >= last]
+    assert len(before) >= 2 and len(behind) >= 2        # cut, both times
+    assert len(before) + len(behind) == len(pieces)     # none overlaps
+    schedule = min(e[2] for e in events if e[1] == "rt.engine.schedule")
+    assert before[-1][1] <= schedule
+    # two waits in each of the fixture's two runs, however many pieces
+    assert stats["pump"]["lulls"] == 4 < len(pieces) + 2
+
+
 def test_pump_stamps_the_hand_over_and_passes_counters(pump_trace):
     _events, states, stats = pump_trace
     assert len(states) == len(PROMPTS)
@@ -397,6 +498,130 @@ def test_pump_stamps_the_hand_over_and_passes_counters(pump_trace):
         assert s.first_token_t <= s.emit_t
     assert stats["pool"] == "mono"
     assert stats["counters"]["prefills"] == 2 * len(PROMPTS)
+
+
+def test_lull_seconds_grow_while_idle_and_stand_still_in_flight():
+    """``stats()["pump"]``: ``lull_s`` is fed by the pieces' own seconds,
+    so it grows while the replica sits without a request and does not
+    move while one is in flight; ``lulls`` counts waits, not pieces."""
+    from ray_tpu.llm import serve
+
+    server = _tiny_server(decode_burst=1)   # a round a token: 8 slow steps
+
+    async def go():
+        await server.completions(       # compile, and start the pump
+            {"prompt_ids": PROMPTS[0], "temperature": 0.0, "max_tokens": 8})
+        idle = [await server.stats()]
+        await asyncio.sleep(IDLE_S)
+        idle.append(await server.stats())
+        step = server.engine.step
+
+        def slow_step(**kw):
+            time.sleep(0.03)
+            return step(**kw)
+
+        server.engine.step = slow_step
+        request = asyncio.ensure_future(server.completions(
+            {"prompt_ids": PROMPTS[1], "temperature": 0.0,
+             "max_tokens": 8}))
+        await asyncio.sleep(0.02)
+        flight = [await server.stats()]
+        await asyncio.sleep(0.04)
+        flight.append(await server.stats())
+        assert server.engine.has_unfinished()
+        await request
+        await asyncio.sleep(IDLE_S)
+        return idle, flight, await server.stats()
+
+    idle, flight, after = asyncio.run(go())
+    (a, b), (c, d) = ([s["pump"] for s in pair] for pair in (idle, flight))
+    assert a["lulls"] == b["lulls"] == 1
+    assert b["lull_s"] - a["lull_s"] >= 2 * serve.LULL_PIECE_S
+    assert c["lull_s"] == d["lull_s"] >= b["lull_s"]
+    assert c["lulls"] == d["lulls"] == 1
+    assert after["pump"]["lulls"] == 2
+    assert after["pump"]["lull_s"] - d["lull_s"] >= 2 * serve.LULL_PIECE_S
+    assert set(after["pump"]) == {"lull_s", "lulls"}
+
+
+@pytest.mark.parametrize("how", ["made-on-the-loop", "configure_pool",
+                                 "configure_pool-prefill", "check_health"])
+def test_a_replica_that_never_got_a_request_marks_its_lulls(how):
+    """From its construction or ``configure_pool``'s end where a loop
+    runs there, else from the first call that reaches the loop (the
+    controller's health probe): no request is needed to start the pump,
+    and a prefill-pool replica marks lulls like any other."""
+    server = None if how == "made-on-the-loop" else _tiny_server()
+
+    async def go():
+        replica = server or _tiny_server()
+        if how.startswith("configure_pool"):
+            replica.configure_pool(
+                "prefill" if how.endswith("prefill") else None, "llm")
+        elif how == "check_health":
+            assert replica._pump_task is None   # no loop ran where it was made
+            await replica.check_health()
+        await asyncio.sleep(IDLE_S)
+        assert not replica._pump_task.done()
+        return (await replica.stats())["pump"]
+
+    pump = asyncio.run(go())
+    assert pump["lulls"] == 1 and pump["lull_s"] > 0.0
+
+
+def test_a_lull_is_one_record_in_the_sink(tmp_path, monkeypatch):
+    """With RAY_TPU_TRACING=1 every span writes a lane record; twenty a
+    second from every idle replica would bury the ``pump`` lane, so the
+    pieces are annotations only and the lull's end writes one record."""
+    monkeypatch.setenv("RAY_TPU_TRACING", "1")
+    monkeypatch.setattr(tracing, "_sink", None)
+    monkeypatch.setattr(tracing, "_span_dir", lambda: str(tmp_path))
+    with tracing.piece_span("rt.pump.lull") as piece:
+        pass
+    assert piece.seconds >= 0.0 and tracing._sink is None
+    server = _tiny_server()
+
+    async def go():
+        await server.completions(
+            {"prompt_ids": PROMPTS[0], "temperature": 0.0, "max_tokens": 2})
+        await asyncio.sleep(IDLE_S)     # the loop's end ends the lull
+
+    asyncio.run(go())
+    tracing._sink.close()
+    (path,) = glob.glob(str(tmp_path / "spans-*.jsonl"))
+    with open(path) as f:
+        pump = [r for r in map(json.loads, f) if r["lane"] == "pump"]
+    (lull,) = [r for r in pump if r["name"] == "rt.pump.lull"]
+    assert lull["end"] - lull["start"] >= IDLE_S / 2
+    assert lull["start"] >= max(r["end"] for r in pump
+                                if r["name"] == "rt.pump.fanout") - 1e-3
+
+
+def test_an_arrival_ends_the_lull_at_once():
+    """The wait is on a future the arrival resolves, not on a poll: a
+    request that arrives in a lull is in a round on the loop's next
+    turns, far inside one piece."""
+    from ray_tpu.llm import serve
+
+    server = _tiny_server()
+
+    async def go():
+        await server.completions(       # compile
+            {"prompt_ids": PROMPTS[0], "temperature": 0.0, "max_tokens": 2})
+        waits = []
+        for _ in range(5):
+            await asyncio.sleep(serve.LULL_PIECE_S * 0.4)   # mid-piece
+            rid, queue = await server._submit(
+                PROMPTS[0], SamplingParams(temperature=0.0, max_tokens=2))
+            state = server.engine.requests[rid]
+            while not (await queue.get()).finished:
+                pass
+            waits.append(state.admit_t - state.arrival_t)
+        return waits
+
+    waits = asyncio.run(go())
+    # a poll would admit after the rest of the piece (30 ms) every time
+    assert sorted(waits)[len(waits) // 2] < serve.LULL_PIECE_S * 0.4
 
 
 def test_finished_requests_feed_metrics_and_the_llm_lane(
