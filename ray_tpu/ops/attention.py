@@ -5,12 +5,24 @@ Layouts: q (B, Sq, Hq, D); k/v (B, Skv, Hkv, D). GQA when Hkv < Hq.
 
 Dispatch policy (``attention``):
   * TPU → Pallas flash kernels for BOTH directions: forward (MXU-tiled,
-    VMEM online-softmax accumulation, causal blocks skipped, LSE saved)
-    and backward (dq + dkv kernels rebuilding softmax from the LSE —
-    ~4x the throughput of a blockwise-recompute VJP).
+    VMEM online-softmax accumulation, LSE saved) and backward (dq + dkv
+    kernels rebuilding softmax from the LSE — ~4x the throughput of a
+    blockwise-recompute VJP).
   * everywhere else (CPU tests, unaligned shapes) → blockwise jax
     implementation; XLA fuses it well and autodiff gives a
     memory-efficient backward when wrapped in jax.checkpoint.
+
+The forward kernel's grid is (batch*heads, query blocks, key chunks). A
+grid step holds a chunk of its head's keys and values in VMEM, the whole
+head wherever ``_pick_chunk`` finds room for it (every served bucket),
+and walks the chunk's key blocks itself, ascending, from the first block
+its query block can see (0, or the window's far edge) to its diagonal
+block: blocks above the diagonal, behind the window, or under a query
+block that holds no token are no step of anything. Only the blocks that
+can hold a hidden pair (the diagonal's, the one on a window's edge)
+build a mask; the others run a body without one. ``flash_forward_steps``
+counts the steps walked and the blocks computed. The backward kernels
+keep a grid of single blocks.
 
 The reference has no attention of its own (tensors are torch's problem —
 SURVEY §2.3/§5.7); these kernels are net-new TPU substrate.
@@ -19,10 +31,12 @@ SURVEY §2.3/§5.7); these kernels are net-new TPU substrate.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 try:  # pallas TPU backend is importable even on CPU-only processes
@@ -145,82 +159,224 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
 
 
 # ---------------------------------------------------------------------------
-# Pallas TPU flash-attention forward.
+# Pallas TPU flash-attention forward: grid (batch*heads_q, query blocks,
+# key chunks), the key blocks of a chunk walked inside the kernel (module
+# docstring).
 # ---------------------------------------------------------------------------
+
+# VMEM the kernel had before it held a chunk: Mosaic's default scoped
+# limit, which holds the query and output blocks, the accumulators and
+# the (block_q, block_k) float32 intermediates
+_VMEM_BASE_BYTES = 16 * 2**20
+# ... and what a chunk's keys and values may take on top, with both of
+# the pipeline's buffers counted: a quarter of the 128 MiB a v5e core has
+KV_VMEM_BYTES = 32 * 2**20
+# A query block goes through a key block in bands of this many rows, every
+# band's scores first, then every band's softmax, then every band's
+# values: rows do not meet, so the bits are those of the whole block, and
+# the scheduler can put one band's softmax (vector unit) beside another's
+# product (matrix unit). On a v5e, 16,384 rows x 128 heads of 192/128:
+# 146 ms in one band of 512, 130 in two, 96 in four (PERF.md, PR 40).
+_BAND_ROWS = 128
+
+
+def _pick_chunk(skv: int, block_k: int, d: int, dv: int,
+                itemsize: int) -> tuple[int, int]:
+    """``(key blocks a grid step holds, the VMEM limit the kernel asks
+    for)``.
+
+    A row of keys takes ``d`` lanes rounded up to 128 and a row of values
+    ``dv`` lanes likewise, ``itemsize`` bytes each, and the pipeline
+    keeps two buffers of each operand. The chunk is the largest divisor
+    of the head's ``skv // block_k`` key blocks whose two buffers fit in
+    ``KV_VMEM_BYTES``: 16,384 bf16 keys of a latent head (192 -> 256
+    lanes, values 128) are 16,384 x 384 x 2 B = 12.6 MB, 25.2 MB in two
+    buffers, so the whole head is resident and fetched once a head;
+    32,768 of them are two chunks of 16,384. A chunk of ONE block is the
+    kernel as it was before it walked its own key blocks. The limit is
+    ``_VMEM_BASE_BYTES`` plus the chunk's two buffers (Mosaic's default
+    of 16 MiB would refuse the first example)."""
+    nk = skv // block_k
+    block = 2 * block_k * (-(-d // 128) + -(-dv // 128)) * 128 * itemsize
+    chunk = max(c for c in range(1, nk + 1)
+                if nk % c == 0 and (c == 1 or c * block <= KV_VMEM_BYTES))
+    return chunk, _VMEM_BASE_BYTES + chunk * block
+
+
+def _key_block_bounds(i, live, *, block_q, block_k, seq_q, seq_k, causal,
+                      window, xp=jnp):
+    """The key blocks query block ``i`` walks, as four block indices
+    ``first <= full_lo <= full_hi <= end``: it computes ``[first, end)``
+    in ascending order, and of those ``[full_lo, full_hi)`` hold no
+    hidden pair and need no mask (every key at or under every query of
+    the block, and inside the newest query's window). ``live`` False (a
+    query block behind its row's end) empties all of them. ``xp`` is
+    ``jnp`` inside the kernel and its index maps and ``numpy`` for
+    plain integers (``flash_forward_steps``): one arithmetic for both."""
+    nk = seq_k // block_k
+    q_first = i * block_q + (seq_k - seq_q)   # the block's oldest query
+    q_last = q_first + block_q - 1
+    if causal:
+        # blocks that start at or under the newest query ...
+        end = xp.minimum((xp.maximum(q_last + 1, 0) + block_k - 1)
+                         // block_k, nk)
+        # ... and those that end at or under the oldest
+        full_hi = xp.minimum(xp.maximum(q_first + 1, 0) // block_k, end)
+    else:
+        end = full_hi = nk
+    if window is None:
+        first = full_lo = 0
+    else:
+        # the first block with a key in the oldest query's window, and
+        # the first whose every key is in the newest query's
+        first = xp.maximum(q_first - window + 1, 0) // block_k
+        full_lo = xp.maximum(q_last - window + block_k, 0) // block_k
+    if live is not True:
+        end = xp.where(live, end, 0)
+    full_hi = xp.minimum(full_hi, end)
+    full_lo = xp.minimum(full_lo, full_hi)
+    first = xp.minimum(first, full_lo)
+    return first, full_lo, full_hi, end
+
+
+class FlashForwardSteps(NamedTuple):
+    """What one head of the forward kernel walks: grid ``steps``, key
+    ``blocks`` computed, and how many of those build a ``masked``."""
+    steps: int
+    blocks: int
+    masked: int
+
+
+def flash_forward_steps(sq: int, skv: int, block_q: int, block_k: int,
+                        chunk: int, *, causal: bool = True,
+                        window: Optional[int] = None,
+                        length: Optional[int] = None) -> FlashForwardSteps:
+    """The grid steps a head of ``flash_attention_tpu`` walks and the key
+    blocks it computes, by the kernel's own arithmetic
+    (``_key_block_bounds``). ``chunk`` is ``_pick_chunk``'s: a head walks
+    ``(sq / block_q) x (skv / block_k / chunk)`` steps whatever it
+    computes (the kernel builds its grid from this), and computes, for
+    each query block that starts under ``length`` (None: the bucket),
+    the key blocks from its window's far edge to its diagonal. 16,384
+    rows in blocks of 512: 32 steps and 528 blocks, 32 of them masked,
+    where a grid of single blocks walked 1,024."""
+    nq, nk = sq // block_q, skv // block_k
+    blocks = masked = 0
+    for i in range(nq):
+        live = length is None or i * block_q < length
+        first, full_lo, full_hi, end = _key_block_bounds(
+            i, bool(live), block_q=block_q, block_k=block_k, seq_q=sq,
+            seq_k=skv, causal=causal, window=window, xp=np)
+        blocks += int(end - first)
+        masked += int(end - first) - int(full_hi - full_lo)
+    return FlashForwardSteps(nq * (nk // chunk), blocks, masked)
 
 
 def _flash_fwd_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref, *,
                       scale, causal, block_q, block_k, seq_q, seq_k,
-                      heads, window=None):
-    # grid = (batch*heads_q, q_blocks, kv_blocks); kv innermost/sequential.
+                      heads, chunk, window=None):
     # len_ref: the rows' lengths, prefetched as scalars, or None (bound
     # by the caller: the kernel then has no such operand)
     i = pl.program_id(1)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
+    c = pl.program_id(2)
 
-    @pl.when(j == 0)
+    last = c == pl.num_programs(2) - 1
+    # a q block that starts at or behind its row's end walks nothing and
+    # is written as what an empty walk leaves: zeros (acc 0 over l
+    # 1e-30) and a finite LSE
+    live = True if len_ref is None else (
+        i * block_q < len_ref[pl.program_id(0) // heads])
+    if live is not True:
+        @pl.when(last & ~live)
+        def _():
+            o_ref[0] = jnp.zeros_like(o_ref[0])
+            lse_ref[0, 0] = jnp.full_like(
+                lse_ref[0, 0], NEG_INF + math.log(1e-30))
+
+    @pl.when((c == 0) & live)
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
+    first, full_lo, full_hi, end = _key_block_bounds(
+        i, live, block_q=block_q, block_k=block_k, seq_q=seq_q,
+        seq_k=seq_k, causal=causal, window=window)
     q_off = seq_k - seq_q  # causal alignment for self-attn with cache
-    run = True
-    if causal:
-        # Whole block above the diagonal → skip all compute.
-        run = (j * block_k) <= (i * block_q + block_q - 1 + q_off)
+
+    sub = min(_BAND_ROWS, block_q)       # rows of a band
+
+    def walk(lo, hi, masked):
+        """The chunk's share of key blocks ``[lo, hi)``, ascending."""
+        if masked:
+            # row - column of a (sub, block_k) tile
+            distance = (
+                jax.lax.broadcasted_iota(jnp.int32, (sub, block_k), 0)
+                - jax.lax.broadcasted_iota(jnp.int32, (sub, block_k), 1))
+
+        def block(j, _):
+            # matmuls run in the INPUT dtype (bf16 on the MXU at full
+            # rate) with f32 accumulation — an f32 upcast before the dot
+            # would halve MXU throughput on the kernel's dominant FLOPs
+            rows = pl.ds(pl.multiple_of((j - c * chunk) * block_k, block_k),
+                         block_k)
+            k = k_ref[0, rows, :]                        # (bk, d)
+            v = v_ref[0, rows, :]                        # (bk, dv)
+            parts = [pl.ds(r * sub, sub) for r in range(block_q // sub)]
+            logits = []
+            for r, part in enumerate(parts):
+                lg = jax.lax.dot_general(
+                    q_ref[0, part, :], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale  # (sub, bk)
+                seen = None
+                if masked:
+                    # a query's distance to a key: the pair is seen from
+                    # 0 (the key is the query's own) to under the window
+                    ahead = (i * block_q + r * sub + q_off - j * block_k
+                             ) + distance
+                    seen = ahead >= 0
+                    if window is not None:
+                        seen &= ahead < window
+                    lg = jnp.where(seen, lg, NEG_INF)
+                logits.append((lg, seen))
+            weights = []
+            for part, (lg, seen) in zip(parts, logits):
+                # the row statistics stay (rows, 1): a column broadcasts
+                # over lanes as it lies, a 1-D vector is laid out anew
+                m_prev = m_ref[part, :]
+                m_new = jnp.maximum(m_prev, lg.max(axis=-1, keepdims=True))
+                p = jnp.exp(lg - m_new)
+                if masked:
+                    # a row that has seen no key yet has m_new NEG_INF
+                    # and exp(0) for every hidden one
+                    p = jnp.where(seen, p, 0.0)
+                corr = jnp.exp(m_prev - m_new)
+                l_ref[part, :] = (l_ref[part, :] * corr
+                                  + p.sum(axis=-1, keepdims=True))
+                m_ref[part, :] = m_new
+                weights.append((p.astype(v.dtype), corr))
+            for part, (p, corr) in zip(parts, weights):
+                pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+                acc_ref[part, :] = acc_ref[part, :] * corr + pv
+
+        jax.lax.fori_loop(jnp.maximum(lo, c * chunk),
+                          jnp.minimum(hi, (c + 1) * chunk), block, None)
+
     if window is not None:
-        # ... and a block whose newest key lies a window or more behind
-        # the q block's oldest query
-        run &= (j * block_k + block_k - 1) > (i * block_q + q_off - window)
-    if len_ref is not None:
-        # ... and a q block that starts at or behind its row's end: no
-        # step of it runs, so the last one writes zeros (acc 0 over l
-        # 1e-30) and a finite LSE
-        run &= i * block_q < len_ref[pl.program_id(0) // heads]
+        walk(first, full_lo, True)       # the window's edge
+    walk(full_lo, full_hi, False)        # no pair hidden: no mask built
+    if causal:
+        walk(full_hi, end, True)         # the diagonal
 
-    @pl.when(run)
+    @pl.when(last & live)
     def _():
-        # matmuls run in the INPUT dtype (bf16 on the MXU at full rate)
-        # with f32 accumulation — an f32 upcast before the dot would halve
-        # MXU throughput on the kernel's dominant FLOPs
-        q = q_ref[0]                                     # (bq, d)
-        k = k_ref[0]                                     # (bk, d)
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (bq, bk) f32
-        if causal:
-            qi = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0) + q_off
-            ki = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            seen = ki <= qi
-            if window is not None:
-                seen &= qi - ki < window
-            logits = jnp.where(seen, logits, NEG_INF)
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, logits.max(axis=-1))
-        p = jnp.exp(logits - m_new[:, None])
-        if causal:
-            p = jnp.where(logits > NEG_INF * 0.5, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[:, 0] = l_ref[:, 0] * corr + p.sum(axis=-1)
-        m_ref[:, 0] = m_new
-        v = v_ref[0]                                     # (bk, d)
-        pv = jax.lax.dot_general(p.astype(v.dtype), v,
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_ref[:] = acc_ref[:] * corr[:, None] + pv
-
-    @pl.when(j == nj - 1)
-    def _():
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0] = (acc_ref[:] / l[:, None]).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[:], 1e-30)
+        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
         # log-sum-exp per query row: the backward kernels rebuild softmax
         # probabilities as exp(s - lse) without the online max recurrence
-        lse_ref[0, 0] = m_ref[:, 0] + jnp.log(l)
+        lse_ref[0, 0] = (m_ref[:] + jnp.log(l))[:, 0]
 
 
 def _pick_block(seq: int, target: int) -> Optional[int]:
@@ -246,13 +402,28 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True,
     """Pallas flash-attention forward (TPU). No autodiff — use
     ``attention`` for a differentiable entry point.
 
+    The grid is ``(batch*heads, query blocks, key chunks)``. A grid step
+    holds a chunk of its head's keys and values in VMEM, as many whole
+    key blocks as ``_pick_chunk`` finds room for (the whole head for
+    every served bucket: fetched once a head, and once for all the query
+    heads of a GQA group), and runs a ``fori_loop`` over the chunk's key
+    blocks from the first one its query block can see to its diagonal
+    block, in ascending order. The blocks strictly under the diagonal and
+    strictly inside the window run a body with no mask; the diagonal
+    block (and the block on a window's edge) builds the mask. A mask that
+    hides nothing is the identity, so the result is the same to the bit
+    whatever the chunk. Within a key block the query block's rows go in
+    bands of ``_BAND_ROWS`` (scores of every band, then softmax, then
+    values), which changes the schedule and not a bit.
+    ``flash_forward_steps`` counts the steps and blocks, and the grid is
+    built from it.
+
     ``window`` (with ``causal``): a query sees its ``window`` newest
     keys, its own among them. Key blocks wholly behind the window are
-    skipped like those above the diagonal, and not fetched either (the
-    index map holds at the nearest block that is read, and a block that
-    does not change is not copied again); the block on the window's edge
-    is masked. ``None`` is the causal kernel as it was, unnamed; the
-    window kernel is named ``WINDOW_KERNEL`` in a trace.
+    outside the loop like those above the diagonal, and a chunk none of
+    whose blocks is walked is not fetched either (the index map holds at
+    the nearest chunk that is). ``None`` is the causal kernel, unnamed;
+    the window kernel is named ``WINDOW_KERNEL`` in a trace.
 
     Values may be narrower or wider than queries and keys (latent
     attention's expanded heads score 192 wide and return 128): the
@@ -261,13 +432,13 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True,
     ``lengths`` (int32 ``[B]``): the first ``lengths[b]`` query rows of
     row ``b`` are tokens, the rest right padding. It reaches the same
     kernel, under the same name, as a prefetched scalar operand. A query
-    block that starts at or behind its row's end computes nothing, is
+    block that starts at or behind its row's end has an empty loop, is
     written as zeros (a finite LSE), and fetches nothing: the index maps
     hold at the blocks the row's last live query block left. Every other
-    query block runs exactly the steps it runs without ``lengths``, so
-    a token's output is the same to the bit; only padding rows that
+    query block walks exactly the blocks it walks without ``lengths``,
+    so a token's output is the same to the bit; only padding rows that
     share a block with tokens are still computed. ``None`` lowers to the
-    kernel as it was, with no such operand (training's forward).
+    kernel with no such operand (training's forward).
 
     ``interpret=True`` runs the kernel in the Pallas interpreter (works on
     CPU) so the kernel body is testable without TPU hardware."""
@@ -289,51 +460,57 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True,
     if window is not None and not causal:
         raise ValueError("a window needs causal attention")
 
-    grid = (b * hq, sq // block_q, skv // block_k)
+    chunk, vmem_bytes = _pick_chunk(skv, block_k, d, dv, k.dtype.itemsize)
+    nq = sq // block_q
+    steps = flash_forward_steps(sq, skv, block_q, block_k, chunk,
+                                causal=causal, window=window).steps
+    grid = (b * hq, nq, steps // nq)
+    shape = dict(block_q=block_q, block_k=block_k, seq_q=sq, seq_k=skv,
+                 causal=causal, window=window)
 
     # the index maps take the prefetched lengths last, where there are any
     def last_live(bh, lens):
         """The row's last query block that holds a token."""
         return jnp.maximum(lens[bh // hq] - 1, 0) // block_q
 
-    def q_index(bh, i, j, *lens):
+    def q_index(bh, i, c, *lens):
         if lens:
             i = jnp.minimum(i, last_live(bh, *lens))
         return (bh, i, 0)
 
-    def kv_index(bh, i, j, *lens):
+    def kv_index(bh, i, c, *lens):
         hb = bh // hq  # batch
         h = bh % hq
-        if lens:
-            # behind the row's end: the block its last live step left
-            last = last_live(bh, *lens)
-            j = jnp.where(i > last, grid[2] - 1, j)
-            i = jnp.minimum(i, last)
-        if window is not None:
-            first = i * block_q + (skv - sq)      # the q block's oldest
-            j = jnp.clip(j, jnp.maximum(first - window + 1, 0) // block_k,
-                         (first + block_q - 1) // block_k)
-        return (hb * hkv + h // n_rep, j, 0)
+        if grid[2] > 1:
+            if lens:
+                # behind the row's end: the chunk its last live step left
+                last = last_live(bh, *lens)
+                c = jnp.where(i > last, grid[2] - 1, c)
+                i = jnp.minimum(i, last)
+            # a chunk that is not walked is not fetched: hold at the
+            # nearest one that is
+            first, _, _, end = _key_block_bounds(i, True, **shape)
+            c = jnp.clip(c, first // chunk,
+                         jnp.maximum(end - 1, first) // chunk)
+        return (hb * hkv + h // n_rep, c, 0)
 
     named = {}
-    kernel = functools.partial(
-        _flash_fwd_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, seq_q=sq, seq_k=skv, heads=hq)
     if window is not None:
-        kernel = functools.partial(kernel, window=window)
         named = {"name": WINDOW_KERNEL}
     elif dv != d:
         named = {"name": LATENT_KERNEL}
+    kernel = functools.partial(_flash_fwd_kernel, scale=scale, heads=hq,
+                               chunk=chunk, **shape)
     spec = dict(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), q_index),
-            pl.BlockSpec((1, block_k, d), kv_index),
-            pl.BlockSpec((1, block_k, dv), kv_index),
+            pl.BlockSpec((1, chunk * block_k, d), kv_index),
+            pl.BlockSpec((1, chunk * block_k, dv), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda bh, i, j, *_: (bh, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda bh, i, j, *_: (bh, 0, i)),
+            pl.BlockSpec((1, block_q, dv), lambda bh, i, c, *_: (bh, i, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda bh, i, c, *_: (bh, 0, i)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, dv), jnp.float32),
@@ -354,6 +531,7 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True,
             jax.ShapeDtypeStruct((b * hq, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((b * hq, 1, sq), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_bytes),
         interpret=interpret,
     )(*operands)
     out = jnp.moveaxis(out.reshape(b, hq, sq, dv), 1, 2)

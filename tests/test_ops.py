@@ -1,6 +1,7 @@
 """Kernel correctness vs naive oracles on the CPU mesh (SURVEY §4.4)."""
 
 import functools
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -217,6 +218,147 @@ def test_pallas_flash_interpret_with_lengths(variant, lengths):
         np.testing.assert_array_equal(poisoned[b, :n], out[b, :n])
     np.testing.assert_array_equal(np.asarray(poisoned)[np.asarray(
         jnp.broadcast_to(skipped, poisoned.shape))], 0.0)
+
+
+def _unfused(fn, *args):
+    """``fn(*args)`` compiled with the CPU compiler's fusion pass off.
+    Fused, the interpreter's multiply-adds are contracted one way or
+    another with the program's shape, and a chunk of one key block then
+    differs from a chunk of four in the last bit; unfused, every
+    operation rounds by itself, which is what the kernel's claim of
+    equal bits is about (the chip's own bits are compared on the chip:
+    PERF.md section 6, PR 40)."""
+    return jax.jit(fn).lower(*args).compile(
+        compiler_options={"xla_disable_hlo_passes": "fusion"})(*args)
+
+
+# (q heads, kv heads, q/k width, v width, causal, window)
+CHUNKED = {
+    "causal": (2, 2, 128, 128, True, None),
+    "gqa": (4, 1, 128, 128, True, None),
+    "window": (2, 1, 128, 128, True, 200),
+    "window_of_whole_blocks": (2, 1, 128, 128, True, 256),
+    "own_value_width": (2, 1, 192, 128, True, None),
+    "not_causal": (2, 1, 128, 128, False, None),
+}
+
+
+@pytest.mark.parametrize("lengths", [None, (512, 512), (200, 385), (1, 128)],
+                         ids=["untold", "full", "mid_block", "one_token"])
+@pytest.mark.parametrize("variant", CHUNKED.values(), ids=CHUNKED)
+def test_pallas_flash_bits_do_not_depend_on_the_chunk(
+        monkeypatch, variant, lengths):
+    """However many key blocks a grid step holds (one: the kernel as it
+    was; two; the whole head), a token's output and LSE are the same to
+    the bit: the blocks are walked in the same order and the body
+    without a mask runs only where a mask would hide nothing."""
+    A = importlib.import_module("ray_tpu.ops.attention")
+    hq, hkv, d, dv, causal, window = variant
+    B, S, block = 2, 512, 128
+    keys = jax.random.split(jax.random.PRNGKey(12), 3)
+    q = jax.random.normal(keys[0], (B, S, hq, d), jnp.float32)
+    k = jax.random.normal(keys[1], (B, S, hkv, d), jnp.float32)
+    v = jax.random.normal(keys[2], (B, S, hkv, dv), jnp.float32)
+    kw = dict(causal=causal, window=window, block_q=block, block_k=block,
+              interpret=True, return_lse=True)
+    if lengths is None:
+        fn, args = (lambda q, k, v: A.flash_attention_tpu(q, k, v, **kw)), ()
+    else:
+        fn = lambda q, k, v, n: A.flash_attention_tpu(
+            q, k, v, lengths=n, **kw)
+        args = (jnp.asarray(lengths, jnp.int32),)
+    one_block = 2 * block * (-(-d // 128) + 1) * 128 * 4  # two buffers
+    got = {}
+    for room in (1, 2 * one_block, A.KV_VMEM_BYTES):
+        monkeypatch.setattr(A, "KV_VMEM_BYTES", room)
+        chunk, limit = A._pick_chunk(S, block, d, dv, 4)
+        assert limit == A._VMEM_BASE_BYTES + chunk * one_block
+        got[chunk] = _unfused(fn, q, k, v, *args)
+    assert sorted(got) == [1, 2, 4]
+    want = naive_attention(q, k, v, causal=causal, window=window)
+    out, lse = got[1]
+    assert np.isfinite(np.asarray(lse)).all()
+    for b, n in enumerate(lengths or (S, S)):
+        for o, l in (got[2], got[4]):
+            np.testing.assert_array_equal(o[b, :n], out[b, :n])
+            np.testing.assert_array_equal(l[b, :, :n], lse[b, :, :n])
+            behind = -(-n // block) * block
+            assert not np.asarray(o[b, behind:]).any()
+        np.testing.assert_allclose(out[b, :n], want[b, :n], atol=2e-5)
+
+
+def _blocks_by_the_mask(sq, skv, bq, bk, causal, window, length):
+    """(computed, masked) key blocks of one head, counted from the mask
+    itself: a block is computed if one of its pairs is seen (and its
+    query block starts under ``length``), masked if one is hidden."""
+    qi = np.arange(sq)[:, None] + (skv - sq)
+    ki = np.arange(skv)[None, :]
+    seen = np.ones((sq, skv), bool)
+    if causal:
+        seen &= ki <= qi
+    if window is not None:
+        seen &= qi - ki < window
+    tiles = seen.reshape(sq // bq, bq, skv // bk, bk)
+    some, every = tiles.any(axis=(1, 3)), tiles.all(axis=(1, 3))
+    if length is not None:
+        some &= (np.arange(sq // bq) * bq < length)[:, None]
+    return int(some.sum()), int((some & ~every).sum())
+
+
+@pytest.mark.parametrize("sq, skv, bq, bk, causal, window, length", [
+    # the served buckets' block structure at a quarter of their rows
+    (4096, 4096, 128, 128, True, None, None),
+    (4096, 4096, 128, 128, True, None, 2663),
+    (4096, 4096, 128, 128, True, None, 2268),
+    (3072, 3072, 128, 128, True, 1024, None),
+    (3072, 3072, 128, 128, True, 1024, 2075),
+    (2048, 2048, 512, 512, True, None, None),
+    (1024, 1024, 512, 512, True, None, 768),
+    (1024, 2048, 256, 512, True, None, None),
+    (2048, 1024, 256, 128, True, None, None),
+    (2048, 2048, 256, 128, True, 300, 1500),
+    (2048, 2048, 128, 256, True, 1, None),
+    (1024, 1024, 128, 128, True, 5000, 1),
+    (1024, 2048, 256, 256, False, None, None),
+], ids=lambda v: str(v))
+def test_flash_forward_steps_counts_what_the_mask_says(
+        sq, skv, bq, bk, causal, window, length):
+    """The blocks the kernel's loop bounds walk are exactly the blocks
+    that hold a seen pair, the ones it masks exactly those that hold a
+    hidden one too, and the steps are the grid's: query blocks times
+    key chunks."""
+    from ray_tpu.ops.attention import flash_forward_steps
+
+    nq, nk = sq // bq, skv // bk
+    for chunk in (c for c in (1, 2, nk) if nk % c == 0):
+        steps, blocks, masked = flash_forward_steps(
+            sq, skv, bq, bk, chunk, causal=causal, window=window,
+            length=length)
+        assert steps == nq * (nk // chunk)
+        assert (blocks, masked) == _blocks_by_the_mask(
+            sq, skv, bq, bk, causal, window, length)
+
+
+def test_flash_forward_steps_of_the_served_buckets():
+    """The numbers PERF.md states: a latent head at 16,384 rows is
+    resident whole (32 grid steps a head where single blocks walked
+    1,024) and computes 528 blocks, 32 of them masked."""
+    from ray_tpu.ops.attention import (
+        _VMEM_BASE_BYTES, _pick_chunk, flash_forward_steps)
+
+    chunk, limit = _pick_chunk(16384, 512, 192, 128, 2)
+    assert (chunk, limit) == (32, _VMEM_BASE_BYTES + 16384 * 384 * 2 * 2)
+    assert flash_forward_steps(16384, 16384, 512, 512, chunk) == (32, 528, 32)
+    assert flash_forward_steps(16384, 16384, 512, 512, 1) == (1024, 528, 32)
+    assert flash_forward_steps(16384, 16384, 512, 512, chunk,
+                               length=10654) == (32, 231, 21)
+    # twice the rows: two chunks of 16,384 keys
+    assert _pick_chunk(32768, 512, 192, 128, 2)[0] == 32
+    # SmallThinker's bucket: whole, and the window bounds a row's blocks
+    chunk, _ = _pick_chunk(12288, 512, 128, 128, 2)
+    assert chunk == 24
+    assert flash_forward_steps(12288, 12288, 512, 512, chunk,
+                               window=4096) == (24, 180, 40)
 
 
 @pytest.mark.parametrize("causal", [True, False])

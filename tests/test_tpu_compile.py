@@ -127,6 +127,31 @@ def _kernel_calls(text):
                 r'custom_call_target="tpu_custom_call"', text)]
 
 
+_NO_EVENT = {"parameter", "get-tuple-element", "tuple", "constant", "bitcast"}
+
+
+def _device_op_kinds(text):
+    """The kinds (a trace's: an instruction's name without its number)
+    of the instructions a compiled program runs as device operations:
+    those of its entry computation and of every loop body and branch."""
+    run = set(re.findall(
+        r"(?:body|condition|true_computation|false_computation)="
+        r"%([\w.\-]+)", text))
+    for group in re.findall(r"branch_computations=\{([^}]*)\}", text):
+        run |= {c.strip().lstrip("%") for c in group.split(",")}
+    kinds, inside = set(), False
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            inside = bool(head.group(1)) or head.group(2) in run
+            continue
+        op = inside and re.match(
+            r"^\s+(?:ROOT )?%([\w.\-]+) = .*? ([\w\-]+)\(", line)
+        if op and op.group(2) not in _NO_EVENT:
+            kinds.add(re.sub(r"[.\d]+$", "", op.group(1)) or op.group(1))
+    return kinds
+
+
 @pytest.mark.parametrize("widths, window, name", [
     ((128, 128), None, None), ((128, 128), 4096, "flash_window_fwd"),
     ((192, 128), None, "flash_mla_fwd")],
@@ -157,6 +182,114 @@ def test_flash_forward_with_lengths_is_the_same_kernel(
             assert "flash_" not in called
         else:
             assert called.startswith(name)
+
+
+def _mosaic_calls(text):
+    """[(instruction name, grid, VMEM limit asked, VMEM used)] of a
+    compiled program's Mosaic kernels (a limit is None where the kernel
+    asks for none); the grid is read from the kernel's own module, which
+    the custom call carries as bytecode."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    calls = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        name = re.search(r"%([\w.\-]+) = ", line).group(1)
+        asked, used = (int(m.group(1)) if m else None for m in (re.search(
+            key + r'":\[\{"memory_space":"1","offset":"0","size":"(\d+)"',
+            line) for key in (
+                '"scoped_memory_configs', '"used_scoped_memory_configs')))
+        ctx = mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = str(ir.Module.parse(base64.b64decode(
+                re.search(r'"body":"([^"]*)"', line).group(1))))
+        grid = tuple(int(n) for n in re.search(
+            r"iteration_bounds = array<i64: ([-\d, ]+)>", module).group(1)
+            .split(","))
+        calls.append((name, grid, asked, used))
+    return calls
+
+
+# (batch, rows, q heads, kv heads, q/k width, v width), window, the
+# kernel's name in a trace: the prefill buckets of the four serve cells
+CELL_FLASH = {
+    "deepseekv2-longdoc-16384": ((1, 16384, 128, 128, 192, 128), None,
+                                 "flash_mla_fwd"),
+    "smallthinker-window-12288": ((1, 12288, 28, 4, 128, 128), 4096,
+                                  "flash_window_fwd"),
+    "smallthinker-full-12288": ((1, 12288, 28, 4, 128, 128), None, None),
+    "olmoe-docqa-2048": ((1, 2048, 16, 16, 128, 128), None, None),
+    "mistral7b-chat-1024": ((1, 1024, 32, 8, 128, 128), None, None),
+}
+
+
+@pytest.mark.parametrize("shape, window, name", CELL_FLASH.values(),
+                         ids=CELL_FLASH)
+def test_flash_forward_walks_its_key_blocks_at_a_cells_bucket(
+        one_chip, shape, window, name):
+    """At each serve cell's longest bucket, told the prompt's length,
+    the forward kernel is one Mosaic kernel under the name it had, whose
+    grid is what ``flash_forward_steps`` says (the whole head's keys a
+    grid step: one key chunk) and which compiles inside the VMEM limit
+    ``_pick_chunk`` asks for."""
+    from ray_tpu.ops.attention import (
+        _pick_chunk, flash_attention_tpu, flash_forward_steps)
+
+    b, s, hq, hkv, d, dv = shape
+
+    def told(q, k, v, lengths):
+        return flash_attention_tpu(q, k, v, causal=True, window=window,
+                                   lengths=lengths)
+
+    text = jax.jit(told).lower(
+        _sds((b, s, hq, d), jnp.bfloat16, one_chip),
+        _sds((b, s, hkv, d), jnp.bfloat16, one_chip),
+        _sds((b, s, hkv, dv), jnp.bfloat16, one_chip),
+        _sds((b,), jnp.int32, one_chip)).compile().as_text()
+    (called, grid, asked, used), = _mosaic_calls(text)
+    if name is None:
+        assert "flash_" not in called
+    else:
+        assert called.startswith(name)
+    block = min(s, 512)
+    chunk, limit = _pick_chunk(s, block, d, dv, 2)
+    assert chunk == s // block              # the whole head is resident
+    steps = flash_forward_steps(s, s, block, block, chunk, window=window)
+    assert grid == (b * hq, s // block, steps.steps // (s // block))
+    assert used <= asked == limit <= 64 * 2**20
+
+
+def test_flash_forward_with_lse_under_shard_map(topo):
+    """The train cell's forward (fsdp=2 x tp=2: 16 of 32 query heads
+    and 4 of 8 key heads a chip, 2,048 rows, the LSE kept for the
+    backward) under ``shard_map``: one kernel a chip, unnamed, its grid
+    ``flash_forward_steps``'s."""
+    from jax import shard_map
+
+    from ray_tpu.ops.attention import (
+        _pick_chunk, flash_attention_tpu, flash_forward_steps)
+    from ray_tpu.parallel import MeshSpec, build_mesh
+
+    mesh = build_mesh(MeshSpec(fsdp=2, tp=2), topo.devices)
+    heads = P("fsdp", None, "tp", None)
+    fwd = shard_map(
+        functools.partial(flash_attention_tpu, causal=True, return_lse=True),
+        mesh=mesh, in_specs=(heads,) * 3,
+        out_specs=(heads, P("fsdp", "tp", None)), check_vma=False)
+    args = [_sds((4, 2048, h, 128), jnp.bfloat16, NamedSharding(mesh, heads))
+            for h in (32, 8, 8)]
+    (called, grid, asked, used), = _mosaic_calls(
+        jax.jit(fwd).lower(*args).compile().as_text())
+    assert "flash_" not in called
+    chunk, limit = _pick_chunk(2048, 512, 128, 128, 2)
+    steps = flash_forward_steps(2048, 2048, 512, 512, chunk)
+    assert steps == (4, 10, 4)
+    assert grid == (2 * 16, 4, 1) and used <= asked == limit
 
 
 @pytest.mark.parametrize("shape", FLASH_SHAPES.values(), ids=FLASH_SHAPES)
@@ -448,12 +581,14 @@ WINDOW_WIDTHS = dict(
 
 @pytest.mark.parametrize("family", ["latent", "window"])
 def test_prefill_sample_hands_its_flash_kernels_the_prompt_length(
-        latent, one_chip, family):
+        latent, one_chip, monkeypatch, family):
     """The served prefill of the latent family and of the family with
     window layers, told where the prompt ends: every flash kernel of the
     program is still found under the name a trace's reader asks for
     (``flash_mla_fwd``; ``flash_window_fwd`` and the full layer's span)
     and takes the lengths as its fourth operand."""
+    import sys
+
     from ray_tpu.llm.cache import window_group_pages
     from ray_tpu.llm.runner import prefill_sample
     from ray_tpu.models import LlamaConfig
@@ -481,14 +616,33 @@ def test_prefill_sample_hands_its_flash_kernels_the_prompt_length(
         pools = (group, group)
         tables = tuple(_sds((1, 128), jnp.int32, one_chip) for _ in group)
         names = {"flash_window_fwd", "rt.attn.full"}
-    text = prefill_sample.lower(
-        params, *pools, _sds((1, bucket), jnp.int32, one_chip), one_i,
-        tables, cos, sin, 0, one_f, one_i, one_f, None, cfg=cfg,
-        greedy=True).compile().as_text()
+
+    def compiled(program):
+        return program.lower(
+            params, *pools, _sds((1, bucket), jnp.int32, one_chip), one_i,
+            tables, cos, sin, 0, one_f, one_i, one_f, None, cfg=cfg,
+            greedy=True).compile().as_text()
+
+    text = compiled(prefill_sample)
     flash = [(name, n) for name, n in _kernel_calls(text)
              if "rt_moe_gmm" not in name]
     assert {re.sub(r"[.\d]+$", "", name) for name, _ in flash} == names
     assert {n for _, n in flash} == {4}
+    # the kernel that walks its own key blocks is surrounded by what
+    # surrounded the kernel of single blocks (a chunk of ONE block is
+    # that kernel's grid): no kind of device operation a trace would
+    # count is added round the call, no new copy or transposition of
+    # q, k, v
+    attention_module = sys.modules["ray_tpu.ops.attention"]
+    monkeypatch.setattr(attention_module, "KV_VMEM_BYTES", 1)
+    jax.clear_caches()      # the program and its layer scans are traced
+    single = compiled(prefill_sample)
+    jax.clear_caches()
+    assert {grid[2] for name, grid, _, _ in _mosaic_calls(single)
+            if "rt_moe_gmm" not in name} == {bucket // 512}
+    assert {grid[2] for name, grid, _, _ in _mosaic_calls(text)
+            if "rt_moe_gmm" not in name} == {1}
+    assert _device_op_kinds(text) == _device_op_kinds(single)
 
 
 def test_latent_decode_burst_reads_the_pool_where_it_lies(latent, one_chip):
