@@ -98,6 +98,23 @@ def _warm_up(url: str, handle, engine: dict, mix: dict, seed: int,
             "cache_misses": after["misses"] - before["misses"]}
 
 
+def _traced_stretch(schedule: List[traffic.Request], mix: dict, seed: int,
+                    seconds: float) -> dict:
+    """Where the profiler opens in this seed's window, and what the
+    schedule puts there (``notes.traced_stretch``)."""
+    for_s = max(0.5, min(4.0, 0.3 * seconds))
+    opens_s = traffic.traced_stretch(schedule, seconds, for_s)
+    inside = traffic.due_in_middle(schedule, opens_s, for_s)
+    entry = traffic.cycle_entry(mix, seed)
+    return {"opens_s": opens_s, "for_s": for_s, "cycle_entry": entry,
+            # of the first request due in the stretch's middle
+            "cycle_position": (entry + inside[0].index)
+            % int(mix["cycle_requests"]) if inside else None,
+            # [index in the schedule, due_s, prompt tokens]
+            "due_inside": [[r.index, r.due_s, r.prompt_tokens]
+                           for r in inside]}
+
+
 class ServeCell:
     """One deployment, held for one or more measured windows (a cell's
     run measures one; the rate sweep measures one per rate)."""
@@ -139,15 +156,22 @@ class ServeCell:
         cell, mix, seed, vocab = self.cell, self.mix, self.seed, self.vocab
         lead_in = float(cell.get("lead_in_s", 5))
         drain = float(cell.get("drain_s", 20))
-        start_payload = {"op": "start"}
-        if trace:
-            start_payload.update(
-                trace_dir=os.path.join(self.scratch, "trace"),
-                trace_after_s=lead_in + 0.25 * seconds,
-                trace_for_s=max(0.5, min(4.0, 0.3 * seconds)))
         schedule = traffic.open_loop_schedule(
             mix, float(rate_rps or cell["rate_rps"]), seconds, lead_in,
             drain, seed, vocab)
+        start_payload, stretch = {"op": "start"}, None
+        if trace:
+            stretch = _traced_stretch(schedule, mix, seed, seconds)
+            # the client sends the schedule's first request at once, so
+            # the window opens that request's lead after the start call:
+            # up to a gap of the cycle under ``lead_in`` (5 s in the
+            # sparsest cell), which a stretch placed to the second may
+            # not be late by
+            start_payload.update(
+                trace_dir=os.path.join(self.scratch, "trace"),
+                trace_after_s=stretch["opens_s"] - min(0.0,
+                                                       schedule[0].due_s),
+                trace_for_s=stretch["for_s"])
         t_a = time.perf_counter()
         replica_t = ray_tpu.get(self._window.remote(start_payload),
                                 timeout=60)["t"]
@@ -200,7 +224,8 @@ class ServeCell:
             "notes": {"probes": self.probes, "warm_up": self.warm,
                       "ready_s": self.ready_s,
                       "run_ended_s": driven["ended"],
-                      "first_failures": [r["error"] for r in failed[:3]]},
+                      "first_failures": [r["error"] for r in failed[:3]],
+                      **({"traced_stretch": stretch} if stretch else {})},
         }
 
     def close(self) -> float:

@@ -21,7 +21,7 @@ import math
 import random
 from dataclasses import dataclass
 from statistics import NormalDist
-from typing import List
+from typing import List, Sequence
 
 # a request due within this many seconds of the window's edge is put on
 # the side the cycle's arithmetic means: sums of floats end 1e-13 off
@@ -36,6 +36,21 @@ class Request:
     prompt_ids: tuple
     max_tokens: int
     temperature: float
+
+    @property
+    def prompt_tokens(self) -> int:
+        return len(self.prompt_ids)
+
+
+@dataclass(frozen=True)
+class Arrival:
+    """A request of the schedule without its token ids: what the cycle's
+    gaps and sizes alone say of it."""
+    index: int
+    due_s: float
+    counted: bool
+    prompt_tokens: int    # the shared prefix counted in
+    max_tokens: int
 
 
 def _quantiles(n: int) -> List[float]:
@@ -94,26 +109,22 @@ def _token_ids(rng: random.Random, n: int, vocab: int) -> tuple:
     return tuple(rng.randrange(1, vocab) for _ in range(n))
 
 
-def open_loop_schedule(mix: dict, rate_rps: float, window_s: float,
-                       lead_in_s: float, tail_s: float, seed: int,
-                       vocab: int) -> List[Request]:
-    """Requests due from ``-lead_in_s`` to ``window_s + tail_s``, with
-    ``due_s`` counted from the start of the window (so lead-in requests
-    have negative due times). The system meets one periodic stream: the
-    mix's cycle, entered where ``seed`` says, one turn every
-    ``cycle_requests / rate_rps`` seconds. A window of just that length
-    holds every request of the cycle once, whatever the seed; the cells
-    are sized so (``rate_rps`` x ``run_seconds`` = ``cycle_requests``).
-    """
+def cycle_entry(mix: dict, seed: int) -> int:
+    """Where in the mix's cycle ``seed`` opens the window: the first draw
+    of the schedule's generator."""
+    return random.Random(seed).randrange(int(mix["cycle_requests"]))
+
+
+def arrivals(mix: dict, rate_rps: float, window_s: float, lead_in_s: float,
+             tail_s: float, start: int) -> List[Arrival]:
+    """Due times and sizes of the requests from ``-lead_in_s`` to
+    ``window_s + tail_s`` when the window enters the mix's cycle at
+    ``start``; ``due_s`` counts from the start of the window. No token
+    id is drawn: every entry of a cycle can be replayed in no time."""
     cycle = request_cycle(mix)
     n = len(cycle)
-    rng = random.Random(seed)
-    start = rng.randrange(n)
     prefix = mix.get("shared_prefix") or None
-    prefixes = []
-    if prefix:
-        prefixes = [_token_ids(rng, int(prefix["tokens"]), vocab)
-                    for _ in range(int(prefix["count"]))]
+    shared = int(prefix["tokens"]) if prefix else 0
 
     def entry(k: int) -> tuple:
         gap, n_prompt, n_out = cycle[(start + k) % n]
@@ -125,21 +136,91 @@ def open_loop_schedule(mix: dict, rate_rps: float, window_s: float,
     while due - entry(k)[0] > -lead_in_s:
         due -= entry(k)[0]
         k -= 1
-    requests: List[Request] = []
+    out: List[Arrival] = []
     while due <= window_s + tail_s:
         _gap, n_prompt, n_out = entry(k)
-        body = _token_ids(rng, n_prompt, vocab)
-        if prefixes:
-            shared = prefixes[rng.randrange(len(prefixes))]
-            body = shared + body
-        requests.append(Request(
-            index=k, due_s=due,
-            counted=EDGE_S < due <= window_s + EDGE_S, prompt_ids=body,
-            max_tokens=n_out,
-            temperature=float(mix.get("temperature", 0.0))))
+        out.append(Arrival(index=k, due_s=due,
+                           counted=EDGE_S < due <= window_s + EDGE_S,
+                           prompt_tokens=shared + n_prompt,
+                           max_tokens=n_out))
         k += 1
         due += entry(k)[0]
+    return out
+
+
+def open_loop_schedule(mix: dict, rate_rps: float, window_s: float,
+                       lead_in_s: float, tail_s: float, seed: int,
+                       vocab: int) -> List[Request]:
+    """Requests due from ``-lead_in_s`` to ``window_s + tail_s``, with
+    ``due_s`` counted from the start of the window (so lead-in requests
+    have negative due times). The system meets one periodic stream: the
+    mix's cycle, entered where ``seed`` says, one turn every
+    ``cycle_requests / rate_rps`` seconds. A window of just that length
+    holds every request of the cycle once, whatever the seed; the cells
+    are sized so (``rate_rps`` x ``run_seconds`` = ``cycle_requests``).
+    """
+    rng = random.Random(seed)
+    start = rng.randrange(int(mix["cycle_requests"]))   # = cycle_entry
+    prefix = mix.get("shared_prefix") or None
+    prefixes = []
+    if prefix:
+        prefixes = [_token_ids(rng, int(prefix["tokens"]), vocab)
+                    for _ in range(int(prefix["count"]))]
+    shared_tokens = len(prefixes[0]) if prefixes else 0
+    temperature = float(mix.get("temperature", 0.0))
+    requests: List[Request] = []
+    for due in arrivals(mix, rate_rps, window_s, lead_in_s, tail_s, start):
+        body = _token_ids(rng, due.prompt_tokens - shared_tokens, vocab)
+        if prefixes:
+            body = prefixes[rng.randrange(len(prefixes))] + body
+        requests.append(Request(
+            index=due.index, due_s=due.due_s, counted=due.counted,
+            prompt_ids=body, max_tokens=due.max_tokens,
+            temperature=temperature))
     return requests
+
+
+# The traced stretch (``--trace 1``): the profiler is given a second to
+# start before the requests it is opened for and a second to stop behind
+# them, and is kept two seconds clear of the window's edges.
+STRETCH_MARGIN_S = 1.0
+STRETCH_EDGE_S = 2.0
+STRETCH_LEAST_S = 3.0
+
+
+def due_in_middle(schedule: Sequence, opens_s: float, for_s: float) -> list:
+    """The requests due in a stretch's middle: from a second after it
+    opens to a second before it closes."""
+    lo = opens_s + STRETCH_MARGIN_S - EDGE_S
+    hi = opens_s + for_s - STRETCH_MARGIN_S + EDGE_S
+    return [r for r in schedule if lo <= r.due_s <= hi]
+
+
+def traced_stretch(schedule: Sequence, window_s: float,
+                   for_s: float) -> float:
+    """Seconds after the window opens at which a traced stretch of
+    ``for_s`` seconds should open, so that it holds work: a second before
+    some counted request is due, no nearer than two seconds to either
+    edge of the window, where most requests are due in its middle (ties:
+    most prompt tokens due there, then the earliest). The schedule is
+    the seed's, so a seed's stretch is fixed before anything runs; the
+    cycle's densest arrivals are few, so most seeds read the same
+    requests. A stretch under three seconds (a rehearsal) or a window
+    without such a place opens a quarter into the window, as every
+    stretch did before PR 42."""
+    best, best_key = 0.25 * window_s, None
+    if for_s < STRETCH_LEAST_S:
+        return best
+    for request in schedule:
+        opens_s = request.due_s - STRETCH_MARGIN_S
+        if not (request.counted and STRETCH_EDGE_S <= opens_s
+                and opens_s + for_s <= window_s - STRETCH_EDGE_S):
+            continue
+        inside = due_in_middle(schedule, opens_s, for_s)
+        key = (len(inside), sum(r.prompt_tokens for r in inside), -opens_s)
+        if best_key is None or key > best_key:
+            best, best_key = opens_s, key
+    return best
 
 
 def warmup_requests(engine: dict, mix: dict, seed: int,

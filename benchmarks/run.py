@@ -40,14 +40,17 @@ def load_json(*parts: str) -> dict:
         raise SystemExit(f"no such file: {path}") from None
 
 
-def load_readers(kind: str) -> list:
-    """Every per-layer reader under ``layer_metrics/`` that serves this
+def load_readers(kind: str, declared=None) -> list:
+    """The per-layer readers under ``layer_metrics/`` that serve this
     kind of cell, found by listing the directory: a later PR adds a file
-    and edits none."""
+    and edits none. For a cell of ``BENCHMARK.json`` only those it
+    declares are loaded: another cell's reader, which may not know this
+    cell's family or program, is never called on it."""
     readers = []
     directory = os.path.join(HERE, "layer_metrics")
     for filename in sorted(os.listdir(directory)):
-        if not filename.endswith(".py"):
+        if not filename.endswith(".py") or (
+                declared is not None and filename[:-3] not in declared):
             continue
         spec = importlib.util.spec_from_file_location(
             "benchmarks.layer_metrics." + filename[:-3].replace(".", "_"),
@@ -75,6 +78,32 @@ def declared_metrics(cell_name: str, group: str):
         return None
     return {m["name"] for m in bench[group]
             if cell_name in m.get("workloads", [cell_name])}
+
+
+def per_layer(run: dict, kind: str, declared=None) -> dict:
+    """What each reader of this cell found in the run; a reader that
+    found nothing is left out."""
+    metrics = {}
+    for reader in load_readers(kind, declared):
+        value = reader.compute(run)
+        if value is not None:
+            metrics[reader.NAME] = {"value": value, "unit": reader.UNIT}
+    return metrics
+
+
+def held_to_declared(cell_name: str, metrics: dict, declared,
+                     rehearsal: bool) -> dict:
+    """A declared cell prints the metrics declared for it and no other.
+    A reader that found nothing to read (a renamed seam, a trace without
+    the program) may not drop a declared metric in silence: no result,
+    and the names of what is missing."""
+    if declared is None:
+        return metrics
+    missing = sorted(declared - set(metrics))
+    if missing and not rehearsal:
+        raise SystemExit(f"cell {cell_name}: BENCHMARK.json declares "
+                         f"{missing}, and this run could not read them")
+    return {k: v for k, v in metrics.items() if k in declared}
 
 
 def breakdown_of(trace: dict) -> dict:
@@ -152,26 +181,15 @@ def main(argv=None) -> int:
     device = run["device"]
     if not rehearsal and device["platform"] != "tpu":
         raise SystemExit(f"not a TPU run: {device}")
-    metrics = {}
-    if args.trace:
-        for reader in load_readers(cell["kind"]):
-            value = reader.compute(run)
-            if value is not None:
-                metrics[reader.NAME] = {"value": value, "unit": reader.UNIT}
-    else:
-        for name, value in kind.end_to_end(run).items():
-            if value is not None:
-                metrics[name] = {"value": value[0], "unit": value[1]}
     declared = declared_metrics(
         cell["name"], "per_layer" if args.trace else "end_to_end")
-    if declared is not None:
-        # a reader that found nothing to read (a renamed seam, a trace
-        # without the program) may not drop a declared metric in silence
-        missing = sorted(declared - set(metrics))
-        if missing and not rehearsal:
-            raise SystemExit(f"cell {cell['name']}: BENCHMARK.json declares "
-                             f"{missing}, and this run could not read them")
-        metrics = {k: v for k, v in metrics.items() if k in declared}
+    if args.trace:
+        metrics = per_layer(run, cell["kind"], declared)
+    else:
+        metrics = {name: {"value": value[0], "unit": value[1]}
+                   for name, value in kind.end_to_end(run).items()
+                   if value is not None}
+    metrics = held_to_declared(cell["name"], metrics, declared, rehearsal)
     result = {
         "correct": run["correct"], "attempted": run["attempted"],
         "failed": run["failed"], "metrics": metrics,

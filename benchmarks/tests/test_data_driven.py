@@ -10,6 +10,8 @@ import shutil
 import subprocess
 import sys
 
+import pytest
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
@@ -73,6 +75,128 @@ def test_new_files_are_found_and_run(tmp_path):
     assert result["notes"]["probes"]["margin_limit"] == 0.125
     # nothing that was there was edited
     assert all(p.read_bytes() == data for p, data in before.items())
+
+
+RAISING_READER = '''"""Another family's reader: it knows nothing of this cell's run."""
+NAME, UNIT, SOURCE = "other_cells_share", "%", "program_counter"
+LAYER, MOVES, KINDS = "LLM replica and engine", "serve_tok_s", ("serve",)
+
+
+def compute(run):
+    raise KeyError("this run has no such counter")
+'''
+
+SILENT_READER = '''"""A reader whose seam was renamed: nothing to read."""
+NAME, UNIT, SOURCE = "renamed_seam_ms", "ms", "program_span"
+LAYER, MOVES, KINDS = "LLM replica and engine", "serve_tok_s", ("serve",)
+
+
+def compute(run):
+    return None
+'''
+
+
+def _declare(root, cell_name, per_layer):
+    """A BENCHMARK.json beside the copy that lists one cell and the
+    per-layer metrics it declares (only the keys ``run.py`` reads)."""
+    (root / "BENCHMARK.json").write_text(json.dumps({
+        "workloads": [{"name": cell_name}],
+        "end_to_end": [{"name": "setup_s"}],
+        "per_layer": [{"name": name, "workloads": [cell_name]}
+                      for name in per_layer]
+        + [{"name": "other_cells_share", "workloads": ["another-cell"]}]}))
+
+
+def _load_run(copy):
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "copied_benchmarks_run", copy / "run.py")
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def test_a_declared_cell_computes_only_its_own_readers(tmp_path):
+    """A later PR's reader that raises on another family's run does not
+    stop a cell that does not declare it (the parent is run under the
+    PR's files); a rehearsal, which declares nothing, calls every reader
+    of its kind and meets the error."""
+    copy = tmp_path / "benchmarks"
+    shutil.copytree(BENCH, copy, ignore=shutil.ignore_patterns(
+        "__pycache__", "tests"))
+    (copy / "layer_metrics" / "finished_requests.py").write_text(NEW_READER)
+    (copy / "layer_metrics" / "other_cells_share.py").write_text(
+        RAISING_READER)
+    _declare(tmp_path, "tiny-chat", ["finished_requests", "window_compiles"])
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=ROOT + os.pathsep + os.environ.get("PYTHONPATH", ""))
+    out = subprocess.run(
+        [sys.executable, str(copy / "run.py"), "--workload", "tiny-chat",
+         "--seed", str(2**31 + 9), "--seconds", "3", "--trace", "1"],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    result = json.loads(out.stdout.strip().splitlines()[-1])
+    assert result["correct"] and result["failed"] == 0
+    assert sorted(result["metrics"]) == ["finished_requests",
+                                         "window_compiles"]
+    # a window of 3 s traces a quarter in, as before, and says so
+    stretch = result["notes"]["traced_stretch"]
+    assert stretch["opens_s"] == 0.75 and 0.89 < stretch["for_s"] < 0.91
+    run = _load_run(copy)
+    assert [r.NAME for r in run.load_readers(
+        "serve", {"finished_requests", "window_compiles"})] == [
+        "finished_requests", "window_compiles"]
+    names = [r.NAME for r in run.load_readers("serve")]
+    assert "other_cells_share" in names and len(names) > 10
+    with pytest.raises(KeyError):      # declared, it is called
+        run.per_layer({"engine": {"finished": []}}, "serve",
+                      {"other_cells_share"})
+
+
+def test_a_declared_reader_that_finds_nothing_stops_the_run_by_name(
+        tmp_path):
+    copy = tmp_path / "benchmarks"
+    shutil.copytree(BENCH, copy, ignore=shutil.ignore_patterns(
+        "__pycache__", "tests"))
+    (copy / "layer_metrics" / "finished_requests.py").write_text(NEW_READER)
+    (copy / "layer_metrics" / "renamed_seam_ms.py").write_text(SILENT_READER)
+    (copy / "layer_metrics" / "other_cells_share.py").write_text(
+        RAISING_READER)
+    run = _load_run(copy)
+    declared = {"finished_requests", "renamed_seam_ms"}
+    found = run.per_layer({"engine": {"finished": [1, 2]}}, "serve", declared)
+    assert found == {"finished_requests": {"value": 2.0,
+                                           "unit": "requests"}}
+    with pytest.raises(SystemExit) as stopped:
+        run.held_to_declared("a-cell", found, declared, rehearsal=False)
+    said = str(stopped.value)     # a string: the exit code is 1
+    assert "renamed_seam_ms" in said and "a-cell" in said
+    assert "finished_requests" not in said
+    # a rehearsal on the CPU cannot read a share of a TPU's peak: it
+    # prints what it has; a cell that declares nothing prints everything
+    assert run.held_to_declared("a-cell", found, declared,
+                                rehearsal=True) == found
+    extra = dict(found, other={"value": 1.0, "unit": "x"})
+    assert run.held_to_declared("a-cell", extra, None, False) == extra
+    assert run.held_to_declared("a-cell", extra,
+                                {"finished_requests"}, False) == {
+        "finished_requests": found["finished_requests"]}
+
+
+def test_the_breakdown_prints_ten_entries_whatever_the_trace_keeps():
+    from pathlib import Path
+
+    run = _load_run(Path(BENCH))
+    kept = [[f"op{i}", 100.0 - i] for i in range(10)] + [
+        ["flash_mla_fwd", 0.03], ["rt_mla_decode", 0.02]]
+    trace = {"programs": {"jit_decode_burst": {"seconds": 2.0, "runs": 9},
+                          "jit_prefill_sample": {"seconds": 1.0, "runs": 3}},
+             "device_ops": kept, "idle_gaps": [["rt.pump.lull", 1.0]]}
+    out = run.breakdown_of(trace)
+    assert out["device_ops"] == [["program:jit_decode_burst", 2.0],
+                                 ["program:jit_prefill_sample", 1.0]] + kept[:8]
+    assert out["idle_gaps"] == [["rt.pump.lull", 1.0]]
 
 
 def test_a_family_that_is_not_there_is_named(tmp_path):

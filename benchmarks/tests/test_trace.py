@@ -92,6 +92,35 @@ def test_a_stretch_recorded_on_the_chip():
                                                                rel=0.01)
 
 
+def test_a_named_kernel_is_kept_whatever_its_rank():
+    """Fourteen kinds of operation, the program's own kernels twelfth to
+    fourteenth: the ten largest come first and unchanged, the named ones
+    behind them in order of seconds, and nothing else."""
+    plain = [f"op{chr(97 + i)}" for i in range(11)]      # opa .. opk
+    kinds = ([(name, 1000 - 10 * i) for i, name in enumerate(plain)]
+             + [("rt_mla_decode", 21), ("flash_mla_fwd", 30),
+                ("rt.moe.route", 5), ("convert", 4)])
+    ops, cursor = [], 0
+    for name, length in kinds:
+        ops.append([f"%{name}.{cursor} = bf16[8]", cursor, length])
+        cursor += length + 5
+    out = trace.reduce_events({"devices": [
+        {"name": "/device:TPU:0", "modules": [], "ops": ops}], "host": []})
+    listed = out["device_ops"]
+    assert [k for k, _ in listed[:10]] == plain[:10]
+    assert [k for k, _ in listed[10:]] == [
+        "flash_mla_fwd", "rt_mla_decode", "rt.moe.route"]
+    assert dict(listed)["rt_mla_decode"] == pytest.approx(21e-9)
+    # a named kernel among the ten largest is listed once, in its place
+    ops.append(["%flash_mla_fwd.9 = bf16[8]", cursor, 5000])
+    listed = trace.reduce_events({"devices": [
+        {"name": "/device:TPU:0", "modules": [], "ops": ops}],
+        "host": []})["device_ops"]
+    assert listed[0] == ["flash_mla_fwd", pytest.approx(5030e-9)]
+    assert [k for k, _ in listed[1:10]] == plain[:9]
+    assert [k for k, _ in listed[10:]] == ["rt_mla_decode", "rt.moe.route"]
+
+
 def test_interval_arithmetic():
     assert trace._union([(5, 7), (0, 2), (1, 3)]) == [(0, 3), (5, 7)]
     assert trace._subtract([(0, 10)], [(2, 3), (5, 12)]) == [(0, 2), (3, 5)]
