@@ -442,6 +442,71 @@ def _expert_kernel_parity(seed: int) -> dict:
     return report
 
 
+def _sparse_kernel_parity(seed: int) -> dict:
+    """The indexer's three kernels (``ops/sparse_attention.py``) against
+    their plain-jax twins on the device at the published widths: 512
+    queries of 16 heads against 8,192 keys as wide as the pool's slot,
+    the 2,048 best of each row, and the product over that mask at 32
+    query and 4 KV heads of 128; then a decode step's 8 rows with the
+    burst's own rows behind a gap. The scores are float32 sums of the
+    same bf16 products, the choice is exact (the same mask), the product
+    rounds one float32 sum to bf16. Runs in the gang worker."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import sparse_attention as sparse
+
+    T, S, top = 512, 8192, 2048
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    qi = jax.random.normal(ks[0], (1, T, 16, 128), jnp.bfloat16)
+    w = jax.random.normal(ks[1], (1, T, 16), jnp.float32)
+    ki = jax.random.normal(ks[2], (1, S, 128), jnp.bfloat16)
+    q = jax.random.normal(ks[3], (T, 32, 128), jnp.bfloat16)
+    kt, vt = (jax.random.normal(k, (4, S, 128), jnp.bfloat16)
+              for k in ks[4:])
+    last = (S - T + jnp.arange(T) + 1).astype(jnp.int32)
+    seen = np.arange(S)[None] < np.asarray(last)[:, None]
+    scores = jax.jit(sparse.index_scores_tpu)(qi, w, ki, last[None])[0]
+    want = sparse.index_scores_xla(qi, w, ki)[0]
+    report = {"scores_max_diff": float(np.abs(np.where(
+        seen, np.asarray(scores) - np.asarray(want), 0.0)).max())}
+    lim = jnp.stack([last, jnp.zeros_like(last)], -1)
+    mask = jax.jit(lambda s, l: sparse.choose_tpu(
+        s, l, k=top, start_b=None))(scores, lim)
+    report["masks_differ"] = int((np.asarray(mask) != np.asarray(
+        sparse.choose_xla(scores, lim, k=top, start_b=None))).sum())
+    report["chosen_a_row"] = int(np.asarray(mask)[-1].sum())
+    out = jax.jit(lambda *a: sparse.masked_attention_tpu(
+        *a, scale=128 ** -0.5))(q, kt, vt, mask, last)
+    twin = sparse.masked_attention_xla(q[:128], kt, vt, mask[:128],
+                                       scale=128 ** -0.5)
+    report["product_max_diff"] = float(np.abs(
+        np.asarray(out[:128], np.float32) - np.asarray(twin, np.float32)
+    ).max())
+    # a decode step: 8 slots, 4,096 cached keys and 3 of the burst's
+    lengths = jnp.asarray([4096, 3000, 2049, 100, 0, 4000, 2048, 1],
+                          jnp.int32)
+    rows = jax.random.normal(ks[0], (8, 4096 + 128), jnp.float32)
+    lim = jnp.stack([lengths, jnp.full_like(lengths, 3)], -1)
+    step = jax.jit(lambda s, l: sparse.choose_tpu(
+        s, l, k=top, start_b=4096))(rows, lim)
+    report["decode_masks_differ"] = int((np.asarray(step) != np.asarray(
+        sparse.choose_xla(rows, lim, k=top, start_b=4096))).sum())
+    idx, ok = jax.jit(lambda m: sparse.chosen_rows(m[:, :4096], top))(step)
+    for b in range(8):
+        chosen = np.nonzero(np.asarray(step)[b, :4096])[0]
+        if (int(np.asarray(ok)[b].sum()) != len(chosen) or (
+                np.asarray(idx)[b, :len(chosen)] != chosen).any()):
+            raise AssertionError(f"chosen_rows: slot {b} is not its mask")
+    if (report["masks_differ"] or report["decode_masks_differ"]
+            or report["chosen_a_row"] != top
+            or report["scores_max_diff"] > 1e-3
+            or report["product_max_diff"] > 2.0 ** -6):
+        raise AssertionError(f"sparse kernels off their twins: {report}")
+    return report
+
+
 def _run_steps(cfg, mesh, spec: dict, seed: int, on_step=None) -> dict:
     """``spec['steps']`` adamw steps of ``cfg`` on ``mesh`` over one seeded
     batch. Returns losses, timings, each device's bytes in use and the
@@ -512,6 +577,8 @@ def train_fn(config: dict) -> None:
     if config["kernel_parity"]:
         report["kernel_parity"] = _kernel_parity(config["seed"])
         report["expert_kernel_parity"] = _expert_kernel_parity(
+            config["seed"])
+        report["sparse_kernel_parity"] = _sparse_kernel_parity(
             config["seed"])
     cfg = LLAMA_CONFIGS[config["model"]]
     mesh = build_mesh(MeshSpec(), jax.devices()[:1])
@@ -587,6 +654,10 @@ def phase_train(seed: int, spec: dict) -> dict:
              shape="64 int8 experts of 768 on 2560, 48 and 73728 rows, "
                    "against lax.ragged_dot, bf16",
              by_product=m["expert_kernel_parity"])
+        emit("sparse_kernel_parity",
+             shape="512 queries x 8192 keys, 16 indexer heads, top 2048, "
+                   "32/4 heads of 128; a decode step of 8 slots",
+             **m["sparse_kernel_parity"])
     _check_losses(m["losses"])
     if m["tpu_custom_calls"] < 1:
         raise AssertionError("the compiled train step holds no "

@@ -33,6 +33,13 @@ is the one pool [layers, pages, page_size, latent_row] and ``KVCache.v``
 is None: no V pool is allocated, copied or written, the values are the
 first 512 columns of the same row. Allocator, tables and the reserved
 page 0 are what they are for every configuration.
+
+An indexer (``LlamaConfig.sparse_top_k``). Beside its K and V rows a
+token keeps the indexer's ONE key a layer (64 values, in a slot of
+``cfg.indexer_row`` = 128 for ``latent_row``'s reason): ``KVCache.i`` is
+a third pool [layers, pages, page_size, indexer_row] that shares the
+page ids of K and V: the same allocator, the same tables, a page's rows
+written, shared and released together. None everywhere else.
 """
 
 from __future__ import annotations
@@ -54,6 +61,7 @@ class KVCache:
 
     k: Any  # [L, num_pages, page_size, kv_heads, head_dim] (a group)
     v: Any  # None for a latent configuration: k holds its rows
+    i: Any = None  # [L, num_pages, page_size, indexer_row]: an indexer's
 
     @property
     def num_pages(self) -> int:
@@ -90,8 +98,10 @@ def init_kv_cache(cfg, num_pages, page_size: int, dtype=None) -> KVCache:
         if len(cfg.kv_groups) > 1:
             raise ValueError(f"{len(cfg.kv_groups)} layer groups need a "
                              f"number of pages each")
-        return KVCache(pools(cfg.n_layers, num_pages),
-                       pools(cfg.n_layers, num_pages))
+        return KVCache(
+            pools(cfg.n_layers, num_pages), pools(cfg.n_layers, num_pages),
+            jnp.zeros((cfg.n_layers, num_pages, page_size, cfg.indexer_row),
+                      dtype) if cfg.sparse_top_k else None)
     sizes = [(cfg.group_layers(g), n) for g, n in enumerate(num_pages)]
     return KVCache(tuple(pools(*s) for s in sizes),
                    tuple(pools(*s) for s in sizes))

@@ -202,6 +202,8 @@ class LLMEngine:
             self._refuse_with_groups()
         if cfg.latent:
             self._refuse_with_latent()
+        if cfg.sparse_top_k:
+            self._refuse_with_indexer()
         pool_pages = [
             self.ecfg.num_pages if w is None else window_group_pages(
                 self.ecfg.max_num_seqs, w, self.ecfg.page_size,
@@ -316,10 +318,17 @@ class LLMEngine:
                 # tokens' rows the router gave to experts that are not
                 # on this chip (one chip's share of the experts)
                 self._counters["expert_rows_elsewhere"] = 0
+        if cfg.sparse_top_k:
+            # summed over queries (prefilled and decoded tokens), from
+            # their positions: the keys a query could see and its
+            # indexer scored, and the keys it attended over (at most
+            # sparse_top_k of them)
+            self._counters.update(scored_keys=0, attended_keys=0)
         # what one cached position holds, all layers
         self._counters["kv_bytes_per_token"] = int(sum(
             a.size // (a.shape[1] * a.shape[2]) * a.dtype.itemsize
-            for a in jax.tree.leaves((self.cache.k, self.cache.v))))
+            for a in jax.tree.leaves(
+                (self.cache.k, self.cache.v, self.cache.i))))
         # expert counts of chunked-prefill dispatches nobody waited for
         # yet: read back with the next sampled tokens
         self._pending_counts: List[Any] = []
@@ -329,6 +338,12 @@ class LLMEngine:
             and "blockwise" in paths["prefill"] else logging.INFO,
             "attention paths (heads of %d, values of %d): %s",
             cfg.head_dim, cfg.value_dim, paths)
+
+    @property
+    def _reads_own_pages(self) -> bool:
+        """A burst copies no page: it reads each slot's own pages through
+        its table (latent rows; an indexer's rows and the chosen K, V)."""
+        return self.cfg.latent or self.cfg.sparse_top_k > 0
 
     def attention_paths(self) -> Dict[str, str]:
         """Which implementation each program's attention takes on this
@@ -348,6 +363,19 @@ class LLMEngine:
                     "decode_burst": "pallas rt_mla_decode (absorbed, each "
                     "slot's own pages)" if on_tpu else gathered}
         listed = "xla (over the gathered pages)"
+        if self.cfg.sparse_top_k:
+            chosen = ("pallas rt_sparse_index, rt_sparse_select, "
+                      "flash_sparse_fwd (the indexer's choice, over the %s)"
+                      if on_tpu else "xla (the indexer's choice, over the %s)")
+            return {"prefill": prefill + " up to sparse_top_k keys, then "
+                    + chosen % "prompt's rows",
+                    "prefill_chunk": chosen % "gathered pages",
+                    "verify_step": chosen % "gathered pages",
+                    "decode_burst": (
+                        "pallas rt_sparse_index_decode, rt_sparse_select_"
+                        "decode" if on_tpu else "xla") + " (each slot's own "
+                    "indexer rows), xla (the chosen K and V rows gathered "
+                    "from the pool)"}
         return {"prefill": prefill, "prefill_chunk": listed,
                 "verify_step": listed, "decode_burst": listed}
 
@@ -384,7 +412,67 @@ class LLMEngine:
                 "layer has neither (its queries pass a low-rank bottleneck "
                 "and its values are expanded from the cached row)")
 
+    def _refuse_with_indexer(self) -> None:
+        """What a cache with an indexer's third pool cannot do yet
+        (ROADMAP M7), refused by the option's name before anything is
+        built (``speculation``: by ``enable_speculation``, whoever calls
+        it; KV hand-over: by ``_refuse_kv_transfer``)."""
+        reasons = {
+            "enable_prefix_caching": (
+                self.ecfg.enable_prefix_caching,
+                "a cached page's indexer rows are shared with it by page "
+                "id, but no test runs a resumed prompt through the "
+                "selection yet"),
+            "lora_rank": (
+                self.ecfg.lora_rank > 0,
+                "adapters are deltas on wq and wv, the indexer chooses "
+                "keys from projections of its own, and no test runs both"),
+        }
+        for option, (asked, why) in reasons.items():
+            if asked:
+                raise ValueError(
+                    f"EngineConfig.{option} is not supported with an "
+                    f"indexer (sparse_top_k={self.cfg.sparse_top_k}): {why}")
+
+    def _count_keys(self, start: int, end: int) -> None:
+        """The queries at positions [start, end) of one sequence, for
+        ``scored_keys`` and ``attended_keys``: the query at position t
+        sees t + 1 keys and attends over at most ``sparse_top_k``."""
+        k = self.cfg.sparse_top_k
+        if not k:
+            return
+
+        def upto(n: int) -> int:        # 1 + 2 + .. + n
+            return n * (n + 1) // 2
+
+        lo, hi = min(start, k), min(end, k)
+        self._counters["scored_keys"] += upto(end) - upto(start)
+        self._counters["attended_keys"] += (
+            upto(hi) - upto(lo) + k * ((end - start) - (hi - lo)))
+
+    def _run(self, program, *args, **kwargs):
+        """A runner program on this engine's pools, which come back as
+        the cache: (what the program returns before its pools ..., its
+        expert counts)."""
+        cache_i = self.cache.i
+        if cache_i is None:
+            # what every other configuration's programs were always handed
+            out = program(self.params, self.cache.k, self.cache.v, *args,
+                          **kwargs)
+        else:
+            # behind the counts: the indexer's pool
+            *out, cache_i = program(self.params, self.cache.k, self.cache.v,
+                                    *args, cache_i=cache_i, **kwargs)
+        *out, cache_k, cache_v, counts = out
+        self.cache = KVCache(cache_k, cache_v, cache_i)
+        return (*out, counts)
+
     def _refuse_kv_transfer(self, what: str) -> None:
+        if self.cfg.sparse_top_k:
+            raise ValueError(
+                f"{what} is not supported with an indexer: a KV payload "
+                f"is a K and a V stack of pages, and a page here has a "
+                f"third row a token, the indexer's key")
         if self.cfg.latent:
             raise ValueError(
                 f"{what} is not supported with latent attention: a KV "
@@ -460,6 +548,13 @@ class LLMEngine:
                 "EngineConfig.speculation is not supported with latent "
                 "attention: the drafter mirrors a K and a V pool, and a "
                 "latent cache has one pool of rows")
+        if self.cfg.sparse_top_k:
+            raise ValueError(
+                "EngineConfig.speculation is not supported with an "
+                "indexer: verify_step selects over the pages "
+                "(llm/runner.py), but the drafter mirrors a K and a V "
+                "pool and no test runs a speculative round through the "
+                "third pool")
         if len(self.windows) > 1:
             raise ValueError(
                 "EngineConfig.speculation is not supported with "
@@ -786,7 +881,7 @@ class LLMEngine:
         """Every bucket a burst's list of group ``g`` can take (a latent
         burst: its table span)."""
         top, lowest = self._listable_pages(g), self._FLAT_PAGES
-        if self.cfg.latent:
+        if self._reads_own_pages:
             top = self.seq_table.block_tables.shape[1]
             lowest = self._LATENT_SPAN_PAGES
         buckets = [min(lowest, top)]
@@ -840,20 +935,19 @@ class LLMEngine:
             lora = self.lora_pool.select([0] * B)
         buckets = self.decode_buckets()
         for shape in buckets:
-            if self.cfg.latent:
+            if self._reads_own_pages:
                 lists = (jnp.zeros((B, shape), jnp.int32),)
             else:
                 lists = tuple(jnp.asarray(burst_gather(
                     t.block_tables, self.ecfg.page_size, bucket, ()))
                     for t, bucket in zip(self.seq_tables, (
                         shape if isinstance(shape, tuple) else (shape,))))
-            _toks, ck, cv, _counts = decode_burst(
-                self.params, self.cache.k, self.cache.v, zi, zi,
+            self._run(
+                decode_burst, zi, zi,
                 self._tables(), jnp.zeros(B, bool), self.cos, self.sin, 0,
                 zf, zi, zf, lora,
                 lists if len(lists) > 1 else lists[0], jnp.int32(1),
                 cfg=self.cfg, n_steps=self.ecfg.decode_burst, greedy=True)
-            self.cache = KVCache(ck, cv)
         jax.block_until_ready(self.cache.k)
         return len(buckets)
 
@@ -903,13 +997,13 @@ class LLMEngine:
                 lora = self.lora_pool.select(
                     [self.lora_pool.slot_of(state.model_id)])
         with self._phase("prefill.dispatch"):
-            toks, ck, cv, counts = prefill_sample(
-                self.params, self.cache.k, self.cache.v,
+            toks, counts = self._run(
+                prefill_sample,
                 jnp.asarray(tokens), jnp.asarray([L], jnp.int32),
                 self._rows(state.slot),
                 self.cos, self.sin, seed, temp, top_k, top_p, lora,
                 cfg=self.cfg, greedy=greedy)
-        self.cache = KVCache(ck, cv)
+        self._count_keys(0, L)
         state.ctx_len = L
         self._counters["prefills"] += 1
         self._counters["prefill_tokens"] += L
@@ -935,14 +1029,14 @@ class LLMEngine:
             return jax.ShapeDtypeStruct((1,), dtype)
 
         bucket = prefill_bucket(prompt_len, self.ecfg.max_seq_len)
-        params, ck, cv, cos, sin = jax.tree.map(
+        params, ck, cv, ci, cos, sin = jax.tree.map(
             abstract, (self.params, self.cache.k, self.cache.v,
-                       self.cos, self.sin))
+                       self.cache.i, self.cos, self.sin))
         return bucket, prefill_sample.lower(
             params, ck, cv, jax.ShapeDtypeStruct((1, bucket), jnp.int32),
             row(jnp.int32), jax.tree.map(abstract, self._rows(0)),
             cos, sin, 0, row(jnp.float32), row(jnp.int32),
-            row(jnp.float32), None, cfg=self.cfg, greedy=True).compile()
+            row(jnp.float32), None, ci, cfg=self.cfg, greedy=True).compile()
 
     def _run_prefill_chunk(self, state: RequestState, seq: List[int],
                            L: int, C: int) -> List[StepOutput]:
@@ -961,11 +1055,11 @@ class LLMEngine:
             bt = tuple(jnp.asarray(t.block_tables[
                 state.slot:state.slot + 1, :span]) for t in self.seq_tables)
             bt = bt if len(bt) > 1 else bt[0]
-            logits, ck, cv, counts = prefill_chunk(
-                self.params, self.cache.k, self.cache.v,
+            logits, counts = self._run(
+                prefill_chunk,
                 jnp.asarray(tokens), jnp.int32(start), jnp.int32(n), bt,
                 self.cos, self.sin, cfg=self.cfg)
-        self.cache = KVCache(ck, cv)
+        self._count_keys(start, start + n)
         if counts is not None:
             self._pending_counts.append(counts)
         state.prefill_pos = start + n
@@ -1105,7 +1199,7 @@ class LLMEngine:
             # a group's list: the pages that hold old context its layers
             # can still see
             page, lists, shape = self.ecfg.page_size, [], []
-            if self.cfg.latent:
+            if self._reads_own_pages:
                 # nothing is copied: the burst reads each slot's own
                 # pages through its table, cut to the longest's bucket
                 pages = [-(-s.ctx_len // page) for s in active_states]
@@ -1138,15 +1232,16 @@ class LLMEngine:
                 map(str, shape))
             hist[shape] = hist.get(shape, 0) + 1
         with self._phase("decode.dispatch"):
-            toks, ck, cv, counts = decode_burst(
-                self.params, self.cache.k, self.cache.v,
+            toks, counts = self._run(
+                decode_burst,
                 jnp.asarray(tokens), jnp.asarray(positions), self._tables(),
                 jnp.asarray(active), self.cos, self.sin,
                 seed, temp, top_k, top_p, lora,
                 tuple(lists) if len(lists) > 1 else lists[0],
                 jnp.int32(K), cfg=self.cfg,
                 n_steps=self.ecfg.decode_burst, greedy=greedy)
-        self.cache = KVCache(ck, cv)
+        for s in active_states:
+            self._count_keys(s.ctx_len, s.ctx_len + K)
         with self._phase("decode.sync"):
             sampled = self._read_back(toks, counts)  # [K, B]
         outs = []
@@ -1261,11 +1356,10 @@ class LLMEngine:
                 self.slots, advance=1)
         with self._phase("decode.dispatch"):
             t0 = time.perf_counter()
-            tgt, samp0, ck, cv, counts = verify_step(
-                self.params, self.cache.k, self.cache.v, jnp.asarray(tok),
+            tgt, samp0, counts = self._run(
+                verify_step, jnp.asarray(tok),
                 jnp.asarray(pos), bt, self.cos, self.sin, seed, temp,
                 top_k, top_p, cfg=self.cfg, greedy=greedy)
-        self.cache = KVCache(ck, cv)
         with self._phase("decode.sync"):
             tgt = self._read_back(tgt, counts)
             samp0 = np.asarray(samp0)
@@ -1337,11 +1431,10 @@ class LLMEngine:
             self.slots, advance=1)
         span = self._span_bucket(int(self.seq_table.n_pages[state.slot]))
         t0 = time.perf_counter()
-        tgt, _s0, ck, cv, counts = verify_step(
-            self.params, self.cache.k, self.cache.v, jnp.asarray(tok),
+        tgt, _s0, counts = self._run(
+            verify_step, jnp.asarray(tok),
             jnp.asarray(pos), self._bt(span), self.cos, self.sin,
             seed, temp, top_k, top_p, cfg=self.cfg, greedy=True)
-        self.cache = KVCache(ck, cv)
         row = self._read_back(tgt, counts)[state.slot].tolist()
         if self.spec is not None:
             self.spec.verify_times.append(time.perf_counter() - t0)

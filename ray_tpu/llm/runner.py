@@ -67,6 +67,24 @@ each step's absorbed queries go to ``mla.decode_attention``, which walks
 each slot's own pages in the pool (a Pallas kernel on a TPU), and are
 joined with the burst's own rows by their log-sum-exp.
 
+An indexer (``LlamaConfig.sparse_top_k``; ``ops/sparse_attention.py``).
+The block's attention half also projects the indexer's queries, its one
+key a token and a weight a head (``_index``), and ``attend`` is handed
+them (``index=``): a query attends over the ``sparse_top_k`` visible
+keys the indexer scores highest. The indexer's keys live in a THIRD pool
+beside K and V (``cache_i``: every program takes it as a keyword, donates
+it and returns it behind its expert counts), written where K and V are
+written and addressed by the same tables. ``prefill`` scores, chooses
+and multiplies a tile of queries at a time over the prompt's own rows
+(a bucket of at most ``sparse_top_k`` keys takes the dense path, whose
+result it is); ``prefill_chunk`` and ``verify_step`` over the gathered
+span (and the chunk's own rows); ``decode_burst`` copies no K or V:
+every step gathers the indexer's rows of each slot's own pages, scores
+them and the burst's own, chooses, and fetches ONLY the chosen K and V
+rows from the pool. Whole prompts and bursts are written into the three
+pools by loops of slices (``_write_latent_pages``, ``_write_slices``),
+not by the scatter, which says why.
+
 Leading dense layers (``LlamaConfig.n_dense_layers``) are their own
 stack ``params["dense_layers"]``: ``_layers`` scans them first, with
 the same block and the dense feed-forward, then the expert layers; the
@@ -91,6 +109,7 @@ import jax.numpy as jnp
 
 from ..models.llama import LlamaConfig, qk_norm, rotated, windowed
 from ..ops import apply_rotary, attention, mla, rms_norm
+from ..ops import sparse_attention as sparse
 from ..ops.moe import router_logits
 from ..ops.quant import embed_lookup, is_quantized, weight_einsum
 from .lora import lora_delta
@@ -98,7 +117,8 @@ from .sampling import sample_from_logits
 
 
 _EXPERT_STACKS = ("w_gate", "w_up", "w_down")
-_POOLS = ("cache_k", "cache_v")       # donated: the scatters run in place
+# donated: the scatters run in place
+_POOLS = ("cache_k", "cache_v", "cache_i")
 
 
 def _split_layers(layers, cfg: LlamaConfig):
@@ -120,24 +140,28 @@ def _groups(x):
     return x if isinstance(x, tuple) else (x,)
 
 
-def _pools(cache_k, cache_v):
-    """The pools of each layer group: the (K, V) pair, or, where there
-    is no V pool (``cache_v`` None: a latent configuration), the one
-    pool of rows alone."""
+def _pools(cache_k, cache_v, cache_i=None):
+    """The pools of each layer group: the (K, V) pair; where there is no
+    V pool (``cache_v`` None: a latent configuration), the one pool of
+    rows alone; with an indexer (``cache_i``), its pool third."""
     if cache_v is None:
         return tuple((k,) for k in _groups(cache_k))
+    if cache_i is not None:
+        return tuple(zip(_groups(cache_k), _groups(cache_v),
+                         _groups(cache_i)))
     return tuple(zip(_groups(cache_k), _groups(cache_v)))
 
 
 def _ungrouped(pools, like):
-    """(cache_k, cache_v) out of the groups' pools, in the form ``like``
-    came in: tuples a group, or the one group's bare arrays; ``cache_v``
-    None where the groups have one pool each."""
-    cache_k, *cache_v = zip(*pools)
-    cache_v = cache_v[0] if cache_v else None
-    if isinstance(like, tuple):
-        return cache_k, cache_v
-    return cache_k[0], None if cache_v is None else cache_v[0]
+    """(cache_k, cache_v, the rest) out of the groups' pools, in the
+    form ``like`` came in: tuples a group, or the one group's bare
+    arrays; ``cache_v`` None where the groups have one pool each; the
+    rest: ``(cache_i,)`` where they have three, else ``()``: what a
+    program returns behind its expert counts."""
+    cache_k, *more = zip(*pools)
+    if not isinstance(like, tuple):
+        cache_k, more = cache_k[0], [m[0] for m in more]
+    return cache_k, more[0] if more else None, tuple(more[1:])
 
 
 
@@ -220,6 +244,17 @@ def _write_rows(pools, rows, block_tables, positions, valid):
         for pool, r in zip(pools, rows))
 
 
+def _write(pools, rows, block_tables, positions, valid):
+    """``_write_rows`` for the pools of a layer group with an indexer
+    inside a layer scan (``prefill_chunk``, ``verify_step``), where the
+    scatter's window is one layer's: K and V by the scatter, the
+    indexer's pool (one row a position, as a latent configuration's) by
+    ``_write_latent``."""
+    return _write_rows(pools[:2], rows[:2], block_tables, positions,
+                       valid) + _write_latent(
+                           pools[2], rows[2], block_tables, positions, valid)
+
+
 def _write_latent(pool, rows, block_tables, positions, valid):
     """``_write_rows`` for the ONE pool of a latent configuration, a row
     at a time. pool [..., P, page, row]; rows [..., B, S, row] with the
@@ -233,27 +268,42 @@ def _write_latent(pool, rows, block_tables, positions, valid):
     token is written nowhere: its place is page 0's first row, its value
     what is there already. Returns the 1-tuple of the pool, as
     ``_write_rows``."""
-    page_size, width = pool.shape[-2:]
-    lead = pool.shape[:-3]
+    return (_write_slices(pool, rows, block_tables, positions, valid, 1),)
+
+
+def _write_slices(pool, rows, block_tables, positions, valid, tail: int):
+    """``_write_latent``'s loop. ``tail``: the dimensions a position's
+    row has (1: a latent row, an indexer's key; 2: the heads and their
+    width, for the K and V pools of a configuration with an indexer,
+    whose burst is written here too: a window of all layers x 4 heads x
+    128 made the scatter turn each 4 GB pool layers-inward and back,
+    read from a compile for a v5e). Every other configuration's burst
+    keeps ``_write_rows``: the accepted serve cells' ``tpot_p95_ms``
+    was measured with the scatter, and whether this loop would serve
+    them as well has not been measured (PERF.md section 7). Returns the
+    pool."""
+    page_size, *row = pool.shape[-tail - 1:]
+    lead = pool.shape[:-tail - 2]
     page = jnp.take_along_axis(block_tables, positions // page_size, axis=1)
     ok = jnp.broadcast_to(valid, positions.shape).reshape(-1)
     fp = jnp.where(ok, page.reshape(-1), 0)
     fo = jnp.where(ok, (positions % page_size).reshape(-1), 0)
-    flat = rows.reshape(-1, fp.size, width).astype(pool.dtype)
-    whole = pool.reshape(-1, *pool.shape[-3:])      # the layers in front
+    flat = rows.reshape(-1, fp.size, *row).astype(pool.dtype)
+    whole = pool.reshape(-1, *pool.shape[-tail - 2:])   # the layers in front
+    zeros = (0,) * tail
 
     def write(i, whole):
         # one row of one layer: a slice over the layers as well would
         # make XLA turn the pool layers-inward for the loop, and back
         layer, t = i // fp.size, i % fp.size
-        at = (layer, fp[t], fo[t], 0)
-        new = jax.lax.dynamic_slice(flat, (layer, t, 0), (1, 1, width))
-        old = jax.lax.dynamic_slice(whole, at, (1, 1, 1, width))
+        at = (layer, fp[t], fo[t], *zeros)
+        new = jax.lax.dynamic_slice(flat, (layer, t, *zeros), (1, 1, *row))
+        old = jax.lax.dynamic_slice(whole, at, (1, 1, 1, *row))
         return jax.lax.dynamic_update_slice(
             whole, jnp.where(ok[t], new[:, None], old), at)
 
     whole = jax.lax.fori_loop(0, whole.shape[0] * fp.size, write, whole)
-    return (whole.reshape(*lead, *pool.shape[-3:]),)
+    return whole.reshape(*lead, *pool.shape[-tail - 2:])
 
 
 def _write_latent_pages(pool, rows, table, prompt_lens):
@@ -262,16 +312,19 @@ def _write_latent_pages(pool, rows, table, prompt_lens):
     [1, max_pages]; prompt_lens [1]. The rows behind the prompt's end on
     its last page are written too (their positions are masked until a
     decode step writes them); a page wholly behind it is written
-    nowhere (page 0 keeps what it holds)."""
-    L, _, page_size, width = pool.shape
+    nowhere (page 0 keeps what it holds). A position's row may have
+    dimensions of its own (a K or V pool's [kvh, hd], for a
+    configuration with an indexer)."""
+    L, _, page_size, *row = pool.shape
     S = rows.shape[2]
     pad = (-S) % page_size
-    pages = jnp.pad(rows[:, 0], ((0, 0), (0, pad), (0, 0))).reshape(
-        L, -1, page_size, width).astype(pool.dtype)
+    zeros = (0,) * len(row)
+    pages = jnp.pad(rows[:, 0], ((0, 0), (0, pad)) + ((0, 0),) * len(row)
+                    ).reshape(L, -1, page_size, *row).astype(pool.dtype)
 
     def write(j, pool):
         ok = j * page_size < prompt_lens[0]
-        at = (0, jnp.where(ok, table[0, j], 0), 0, 0)
+        at = (0, jnp.where(ok, table[0, j], 0), 0, *zeros)
         new = jax.lax.dynamic_slice_in_dim(pages, j, 1, 1)
         old = jax.lax.dynamic_slice(pool, at, new.shape)
         return jax.lax.dynamic_update_slice(
@@ -384,11 +437,52 @@ def _latent(h, lp, cfg: LlamaConfig, cos, sin, positions):
     return q, jnp.concatenate([c_kv, k_r, pad], -1)
 
 
+def _index(h, lp, cfg: LlamaConfig, positions):
+    """A layer's indexer on the normalised input h [B, S, d]: (qI [B, S,
+    J, ``cfg.indexer_row``], rotated, then zeros; w float32 [B, S, J];
+    the row the third pool keeps [B, S, ``cfg.indexer_row``]: the one
+    key a token has, LayerNorm'd and rotated, then zeros). Its rotary
+    embedding turns the whole of ``indexer_dim`` at the model's theta."""
+    di = cfg.indexer_dim
+    with jax.named_scope("rt.attn.index"):
+        # float32 out of the products and through the norm and the
+        # rotation, rounded ONCE: a score that is off by a rounding swaps
+        # keys across the top_k-th place
+        qi, ki, w = (weight_einsum(eq, h, lp[name],
+                                   preferred_element_type=jnp.float32)
+                     for eq, name in (("bsd,djk->bsjk", "wi_q"),
+                                      ("bsd,dk->bsk", "wi_k"),
+                                      ("bsd,dj->bsj", "wi_w")))
+        ki = ki - ki.mean(-1, keepdims=True)
+        ki = (ki * jax.lax.rsqrt(jnp.square(ki).mean(-1, keepdims=True)
+                                 + cfg.norm_eps)
+              * lp["wi_k_norm"].astype(jnp.float32)
+              + lp["wi_k_bias"].astype(jnp.float32))
+        at = jnp.arange(h.shape[1])[None] if positions is None else positions
+        angle = at[..., None].astype(jnp.float32) * cfg.rope_theta ** (
+            -jnp.arange(0, di, 2, dtype=jnp.float32) / di)
+        cos, sin = jnp.cos(angle), jnp.sin(angle)          # [B, S, di / 2]
+
+        def turned(x, cos, sin):
+            x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+            return jnp.concatenate([x1 * cos - x2 * sin,
+                                    x2 * cos + x1 * sin], -1).astype(h.dtype)
+
+        # both as wide as the pool's slot (zeros behind ``di``): a query
+        # scores the rows as they are stored, whole lanes, nothing sliced
+        pad = ((0, cfg.indexer_row - di),)
+        qi = jnp.pad(turned(qi, cos[..., None, :], sin[..., None, :]),
+                     ((0, 0),) * 3 + pad)
+        ki = jnp.pad(turned(ki, cos, sin), ((0, 0),) * 2 + pad)
+        return qi, w, ki
+
+
 def _heads(h, lp, lr, state, *, cfg: LlamaConfig, kind, cos, sin, positions,
            attend, lora_scale):
     """A layer of heads' attention half on the normalised input h: the
-    three projections (plus a slot's LoRA deltas), QK-norm, rotary,
-    ``attend`` under the layer's span. Returns (o, kept)."""
+    three projections (plus a slot's LoRA deltas), QK-norm, rotary, with
+    an indexer its three (``_index``), ``attend`` under the layer's
+    span. Returns (o, kept)."""
     q = weight_einsum("bsd,dhk->bshk", h, lp["wq"])
     k = weight_einsum("bsd,dhk->bshk", h, lp["wk"])
     v = weight_einsum("bsd,dhk->bshk", h, lp["wv"])
@@ -401,6 +495,10 @@ def _heads(h, lp, lr, state, *, cfg: LlamaConfig, kind, cos, sin, positions,
     if rotated(kind):
         q = apply_rotary(q, cos, sin, positions=positions)
         k = apply_rotary(k, cos, sin, positions=positions)
+    if cfg.sparse_top_k:
+        # the closure opens rt.attn.select and rt.attn.sparse
+        return attend(q, k, v, state, None,
+                      index=_index(h, lp, cfg, positions))
     with jax.named_scope("rt.attn.window" if windowed(kind)
                          else "rt.attn.full"):
         return attend(q, k, v, state,
@@ -559,7 +657,7 @@ def _held(table, positions, valid, page_size: int):
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=_POOLS)
 def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
-            cos, sin, lora=None, *, cfg: LlamaConfig):
+            cos, sin, lora=None, cache_i=None, *, cfg: LlamaConfig):
     """Process full prompts, fill their pages, return last-token logits.
 
     tokens: [B, S] right-padded; prompt_lens: [B]; block_tables: [B, Pmax];
@@ -572,15 +670,16 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
     layer still runs the bucket's rows.
 
     Returns (logits [B, vocab], cache_k, cache_v, expert counts: see
-    ``_mlp``; None for a dense config).
+    ``_mlp``; None for a dense config), and behind them ``cache_i``
+    where the configuration has an indexer (every program does).
     """
     B, S = tokens.shape
-    if cfg.latent and B != 1:
-        raise ValueError("a latent configuration's prefill writes ONE "
-                         "prompt's rows a page at a time "
-                         "(_write_latent_pages), as the engine asks: "
-                         f"B == 1, not {B}")
-    pools = _pools(cache_k, cache_v)
+    if (cfg.latent or cfg.sparse_top_k) and B != 1:
+        raise ValueError("a latent configuration's prefill, and one's "
+                         "with an indexer, writes ONE prompt's rows a "
+                         "page at a time (_write_latent_pages), as the "
+                         f"engine asks: B == 1, not {B}")
+    pools = _pools(cache_k, cache_v, cache_i)
     tables = _groups(block_tables)
     page_size = pools[0][0].shape[2]
     x = embed_lookup(params["embed"], tokens, cfg.dtype)
@@ -596,7 +695,20 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
         jnp.maximum(prompt_lens - w + 1, 0) // page_size * page_size,
         0, S - n) for w, n in kept_rows.items()}
 
-    def attend(q, k, v, _, window):
+    def attend(q, k, v, _, window, index=None):
+        if index is not None:
+            # at most top_k keys in the bucket: every visible key is
+            # chosen, the dense path; else the indexer's choice a query
+            qi, w, ki = index
+            if S <= cfg.sparse_top_k:
+                o = attention(q, k, v, causal=True, lengths=prompt_lens)
+            else:
+                o = sparse.attend(
+                    q, k, v, qi, w, ki,
+                    jnp.where(valid, pos_grid + 1, 0),
+                    top_k=cfg.sparse_top_k, scale=cfg.softmax_scale)
+            return o, tuple(r.astype(c.dtype)
+                            for r, c in zip((k, v, ki), pools[0]))
         if cfg.latent:
             # the expanded form: every head's keys and values multiplied
             # out of the rows, which alone leave the layer scan
@@ -628,16 +740,24 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
                 _write_latent_pages(pool[0], kept[0], table, prompt_lens))
             continue
         if window is None:
-            written.append(_write_rows(pool, kept, table, pos_grid, valid))
+            if len(pool) == 2:
+                written.append(_write_rows(pool, kept, table, pos_grid,
+                                           valid))
+            else:
+                # with an indexer the ONE prompt's rows go a page at a
+                # time into all three pools (``_write_slices`` says why)
+                written.append(sum((_write_latent_pages(
+                    c, r, table, prompt_lens)
+                    for c, r in zip(pool, kept)), ()))
             continue
         at = kept_from[window][:, None] + jnp.arange(kept_rows[window])
         written.append(_write_rows(
             pool, kept, table, at,
             _held(table, at, at < prompt_lens[:, None], page_size)))
-    cache_k, cache_v = _ungrouped(written, block_tables)
+    cache_k, cache_v, rest = _ungrouped(written, block_tables)
     x_last = jnp.take_along_axis(
         x, jnp.maximum(prompt_lens - 1, 0)[:, None, None], axis=1)[:, 0]
-    return _head(x_last, params, cfg), cache_k, cache_v, counts
+    return (_head(x_last, params, cfg), cache_k, cache_v, counts, *rest)
 
 
 def prefill_bucket(seq_len: int, max_seq: int, floor: int = 16) -> int:
@@ -650,7 +770,8 @@ def prefill_bucket(seq_len: int, max_seq: int, floor: int = 16) -> int:
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=_POOLS)
 def prefill_chunk(params, cache_k, cache_v, tokens, start_pos, chunk_len,
-                  block_tables, cos, sin, *, cfg: LlamaConfig):
+                  block_tables, cos, sin, cache_i=None, *,
+                  cfg: LlamaConfig):
     """One CHUNK of a long prompt (vLLM's chunked prefill, rebuilt for
     static shapes): tokens [1, C] are positions [start_pos,
     start_pos+chunk_len), attending causally within the chunk AND over
@@ -662,7 +783,7 @@ def prefill_chunk(params, cache_k, cache_v, tokens, start_pos, chunk_len,
     cache_k, cache_v, expert counts as ``prefill``).
     """
     B, C = tokens.shape
-    pools = _pools(cache_k, cache_v)
+    pools = _pools(cache_k, cache_v, cache_i)
     tables = dict(zip(cfg.kv_groups, _groups(block_tables)))
     page_size = pools[0][0].shape[2]
     Spast = _groups(block_tables)[0].shape[1] * page_size
@@ -674,11 +795,25 @@ def prefill_chunk(params, cache_k, cache_v, tokens, start_pos, chunk_len,
     chunk_mask = (jnp.arange(C)[None, :, None]
                   >= jnp.arange(C)[None, None, :]) & valid[:, None, :]
 
-    def attend(q, k, v, pools, window):
+    def attend(q, k, v, pools, window, index=None):
         table, past, own, rows = tables[window], past_mask, chunk_mask, valid
         if cfg.latent:
             return _attend_latent_pages(q, k, v, pools, table, pos_grid,
                                         rows, cfg, past, own)
+        if index is not None:
+            # the chunk's queries score the cached rows below its start
+            # and the chunk's own rows up to themselves
+            qi, w, ki = index
+            pools = _write(pools, (k, v, ki), table, pos_grid, rows)
+            keys = [jnp.concatenate([_take_span(pool, table),
+                                     new.astype(pool.dtype)], 1)
+                    for pool, new in zip(pools, (k, v, ki))]
+            o = sparse.attend(
+                q, keys[0], keys[1], qi, w, keys[2],
+                jnp.where(valid, start_pos, 0),
+                jnp.where(valid, jnp.arange(C)[None, :] + 1, 0), Spast,
+                top_k=cfg.sparse_top_k, scale=cfg.softmax_scale)
+            return o, pools
         if window is not None:
             rows = _held(table, pos_grid, valid, page_size)
             past = past & (jnp.arange(Spast)[None, None, :]
@@ -691,16 +826,16 @@ def prefill_chunk(params, cache_k, cache_v, tokens, start_pos, chunk_len,
 
     x, pools, counts = _layers(params, cfg, cos, sin)(
         x, pools, attend, positions=pos_grid, valid=valid)
-    cache_k, cache_v = _ungrouped(pools, block_tables)
+    cache_k, cache_v, rest = _ungrouped(pools, block_tables)
     idx = jnp.broadcast_to(jnp.maximum(chunk_len - 1, 0).reshape(1, 1, 1),
                            (B, 1, 1))
     x_last = jnp.take_along_axis(x, idx, axis=1)[:, 0]
-    return _head(x_last, params, cfg), cache_k, cache_v, counts
+    return (_head(x_last, params, cfg), cache_k, cache_v, counts, *rest)
 
 
 @partial(jax.jit, static_argnames=("cfg", "greedy"), donate_argnames=_POOLS)
 def verify_step(params, cache_k, cache_v, tokens, positions, block_tables,
-                cos, sin, seed, temperature, top_k, top_p, *,
+                cos, sin, seed, temperature, top_k, top_p, cache_i=None, *,
                 cfg: LlamaConfig, greedy: bool = False):
     """Batched multi-token verification forward (speculative decoding,
     Leviathan et al. ICML'23 — PAPERS.md): score a whole k-token draft
@@ -718,7 +853,7 @@ def verify_step(params, cache_k, cache_v, tokens, positions, block_tables,
     window position j, sampled position-0 token [B] for rows that
     aren't greedy, cache_k, cache_v, expert counts as ``prefill``).
     """
-    pools = _pools(cache_k, cache_v)
+    pools = _pools(cache_k, cache_v, cache_i)
     tables = dict(zip(cfg.kv_groups, _groups(block_tables)))
     page_size = pools[0][0].shape[2]
     Sall = _groups(block_tables)[0].shape[1] * page_size
@@ -730,11 +865,21 @@ def verify_step(params, cache_k, cache_v, tokens, positions, block_tables,
     kmask = (jnp.arange(Sall)[None, None, :]
              <= qpos[:, :, None])                          # [B, S, Sall]
 
-    def attend(q, k, v, pools, window):
+    def attend(q, k, v, pools, window, index=None):
         table, seen, rows = tables[window], kmask, valid
         if cfg.latent:
             return _attend_latent_pages(q, k, v, pools, table, qpos, rows,
                                         cfg, seen)
+        if index is not None:
+            # the window's own rows are scored through the pages too
+            qi, w, ki = index
+            pools = _write(pools, (k, v, ki), table, positions, rows)
+            pk, pv, pi = (_take_span(pool, table) for pool in pools)
+            o = sparse.attend(
+                q, pk, pv, qi, w, pi,
+                jnp.where(valid, qpos + 1, 0),
+                top_k=cfg.sparse_top_k, scale=cfg.softmax_scale)
+            return o, pools
         if window is not None:
             rows = _held(table, qpos, valid, page_size)
             seen = seen & (jnp.arange(Sall)[None, None, :]
@@ -745,12 +890,12 @@ def verify_step(params, cache_k, cache_v, tokens, positions, block_tables,
 
     x, pools, counts = _layers(params, cfg, cos, sin)(
         x, pools, attend, positions=qpos, valid=valid)
-    cache_k, cache_v = _ungrouped(pools, block_tables)
+    cache_k, cache_v, rest = _ungrouped(pools, block_tables)
     logits = _head(x, params, cfg)
     tgt = jnp.argmax(logits, axis=-1)                      # [B, S]
     samp0 = tgt[:, 0] if greedy else sample_from_logits(
         logits[:, 0], seed, temperature, top_k, top_p)
-    return tgt, samp0, cache_k, cache_v, counts
+    return (tgt, samp0, cache_k, cache_v, counts, *rest)
 
 
 @jax.jit
@@ -766,20 +911,21 @@ def sample_logits(logits, seed, temperature, top_k, top_p):
 @partial(jax.jit, static_argnames=("cfg", "greedy"), donate_argnames=_POOLS)
 def prefill_sample(params, cache_k, cache_v, tokens, prompt_lens,
                    block_tables, cos, sin, seed, temperature, top_k, top_p,
-                   lora=None, *, cfg: LlamaConfig, greedy: bool = False):
+                   lora=None, cache_i=None, *, cfg: LlamaConfig,
+                   greedy: bool = False):
     """``prefill`` with the sampler behind it (``greedy``: see ``_pick``)."""
-    logits, cache_k, cache_v, counts = prefill.__wrapped__(
+    logits, *rest = prefill.__wrapped__(
         params, cache_k, cache_v, tokens, prompt_lens, block_tables,
-        cos, sin, lora, cfg=cfg)
-    toks = _pick(logits, greedy, seed, temperature, top_k, top_p)
-    return toks, cache_k, cache_v, counts
+        cos, sin, lora, cache_i, cfg=cfg)
+    return (_pick(logits, greedy, seed, temperature, top_k, top_p), *rest)
 
 
 @partial(jax.jit, donate_argnames=_POOLS,
          static_argnames=("cfg", "n_steps", "paged_kernel", "greedy"))
 def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
                  active, cos, sin, seed, temperature, top_k, top_p,
-                 lora=None, gather=None, steps=None, *, cfg: LlamaConfig,
+                 lora=None, gather=None, steps=None, cache_i=None, *,
+                 cfg: LlamaConfig,
                  n_steps: int, paged_kernel: bool = None,
                  greedy: bool = False):
     """Up to n_steps fused decode+sample steps, sampled tokens fed back
@@ -814,6 +960,12 @@ def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
     own length (``ops/mla.py`` ``decode_attention``), so a step's
     attention costs what the slot's context costs.
 
+    A configuration with an indexer copies no K or V either, and takes
+    the same ``gather``: every step gathers the INDEXER's rows of each
+    slot's own pages (a sixteenth of its K and V), scores them and the
+    burst's own, chooses, and fetches only the chosen K and V rows from
+    the pool (``ops/sparse_attention.py`` ``decode_chosen``).
+
     ``steps``: int32 scalar, the steps to run (<= n_steps, which is only
     the capacity: scratch rows and the returned [n_steps, B]); None runs
     them all. A width is an operand, not a program.
@@ -830,7 +982,7 @@ def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
         raise ValueError("decode_burst has one attention path: the paged "
                          "kernel was deleted (PR 31)")
     B, K = tokens.shape[0], n_steps
-    pools = _pools(cache_k, cache_v)
+    pools = _pools(cache_k, cache_v, cache_i)
     tables = _groups(block_tables)
     gathers = (None,) * len(pools) if gather is None else _groups(gather)
     page_size = pools[0][0].shape[2]
@@ -841,7 +993,7 @@ def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
     old, old_mask, key_pos = [], {}, {}
     for window, pool, table, listed in zip(cfg.kv_groups, pools, tables,
                                            gathers):
-        if cfg.latent:
+        if cfg.latent or cfg.sparse_top_k:
             # no copy: a layer's state is its index into the pool
             old.append((jnp.arange(cfg.n_layers, dtype=jnp.int32),))
             span = table if listed is None else listed
@@ -869,7 +1021,25 @@ def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
         x = embed_lookup(params["embed"], toks, cfg.dtype)[:, None, :]
         new_mask = jnp.arange(K)[None, :] <= i                 # [1, K]
 
-        def attend(q, k, v, state, window):
+        def attend(q, k, v, state, window, index=None):
+            if index is not None:
+                # the slot's indexer rows where they lie and the burst's
+                # own, scored; of the chosen, the cached K and V rows
+                # fetched from the pool, the burst's taken from scratch
+                layer, nk, nv, ni = state
+                qi, w, ki = index
+                nk, nv, ni = (jax.lax.dynamic_update_slice_in_dim(
+                    rows, new.astype(rows.dtype), i, 1)
+                    for rows, new in ((nk, k), (nv, v), (ni, ki)))
+                at, ok, own = sparse.decode_chosen(
+                    qi[:, 0], w[:, 0], pools[0][2], layer, span, positions,
+                    ni, i + 1, top_k=cfg.sparse_top_k)
+                with jax.named_scope("rt.attn.sparse"):
+                    gk, gv = (sparse.gather_rows(pool, layer, span, at,
+                                                 page_size)
+                              for pool in pools[0][:2])
+                    o = _attend(q[:, 0], (gk, gv, ok), (nk, nv, own))
+                return o[:, None], (nk, nv, ni)
             if cfg.latent:
                 # absorbed: the slot's cached rows where they lie, then
                 # the burst's own rows up to this step, one softmax
@@ -918,8 +1088,18 @@ def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
     # one scatter of the whole burst into the paged cache
     p_grid = positions[:, None] + jnp.arange(K)[None, :]       # [B, K]
     written = active[:, None] & (jnp.arange(K)[None, :] < n_run)
-    cache_k, cache_v = _ungrouped(
-        [_write_latent(pool[0], rows[0], table, p_grid, written)
-         if cfg.latent else _write_rows(pool, rows, table, p_grid, written)
-         for pool, rows, table in zip(pools, scratch, tables)], block_tables)
-    return out, cache_k, cache_v, counts
+
+    def burst_rows(pool, rows, table):
+        if cfg.latent:
+            return _write_latent(pool[0], rows[0], table, p_grid, written)
+        if cfg.sparse_top_k:
+            # K and V by slices too: ``_write_slices`` says why
+            return tuple(_write_slices(c, r, table, p_grid, written,
+                                       c.ndim - 3)
+                         for c, r in zip(pool, rows))
+        return _write_rows(pool, rows, table, p_grid, written)
+
+    cache_k, cache_v, rest = _ungrouped(
+        [burst_rows(*group) for group in zip(pools, scratch, tables)],
+        block_tables)
+    return (out, cache_k, cache_v, counts, *rest)

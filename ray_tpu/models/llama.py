@@ -130,6 +130,18 @@ class LlamaConfig:
     # and a row routed to an expert that is not here adds nothing. None:
     # all of them
     experts_held: Optional[Tuple[int, int]] = None
+    # ``qk_norm`` a head: RMSNorm of every query and key head over its
+    # own width, one learned ``head_dim`` vector each (Qwen3), not one
+    # over the whole projected width (OLMoE)
+    qk_norm_by_head: bool = False
+    # an indexer beside every attention layer (ops/sparse_attention.py):
+    # ``indexer_heads`` query heads of ``indexer_dim`` over ONE key of
+    # that width a token, and a query attends over the ``sparse_top_k``
+    # keys it scores highest. 0: none, every visible key attended.
+    # Served only
+    indexer_heads: int = 0
+    indexer_dim: int = 0
+    sparse_top_k: int = 0
 
     def __post_init__(self):
         kinds = self.layer_kinds
@@ -169,6 +181,17 @@ class LlamaConfig:
         if self.n_experts and self.n_experts % self.n_group:
             raise ValueError(f"n_experts={self.n_experts} is no whole "
                              f"number of n_group={self.n_group} groups")
+        sizes = (self.indexer_heads, self.indexer_dim, self.sparse_top_k)
+        if any(sizes) and not all(sizes):
+            raise ValueError("an indexer needs indexer_heads, indexer_dim "
+                             "and sparse_top_k, all of them")
+        if self.sparse_top_k and (len(kinds) > 1 or self.latent):
+            raise ValueError("an indexer sits beside one kind of layer "
+                             "(full, rotated, GQA): no layer_pattern, no "
+                             "latent attention")
+        if self.qk_norm_by_head and not self.qk_norm:
+            raise ValueError("qk_norm_by_head says how qk_norm "
+                             "normalises: set qk_norm too")
         if self.experts_held is not None:
             first, count = self.experts_held
             if not (0 <= first and 0 < count
@@ -217,6 +240,15 @@ class LlamaConfig:
         strides the whole pool and a kernel that wants row-major pages
         gets a copy of the pool first; 640 is row-major."""
         return -(-self.latent_dim // 128) * 128
+
+    @property
+    def indexer_row(self) -> int:
+        """Width of an indexer key's slot in its page pool:
+        ``indexer_dim`` rounded up to whole lanes (64 -> 128, the rest
+        zero), for ``latent_row``'s reason: a pool whose last dimension
+        is 64 gets the pages as its fastest dimension (read from a
+        compile for a v5e: bf16[L,P,64,64]{1,3,2,0})."""
+        return -(-self.indexer_dim // 128) * 128
 
     @property
     def softmax_scale(self) -> float:
@@ -269,7 +301,11 @@ class LlamaConfig:
         if self.n_experts:
             mlp = self.n_experts * mlp + d * self.n_experts  # experts+router
         if self.qk_norm:
-            attn += (self.n_heads + self.n_kv_heads) * self.head_dim
+            attn += 2 * self.head_dim if self.qk_norm_by_head else \
+                (self.n_heads + self.n_kv_heads) * self.head_dim
+        if self.sparse_top_k:
+            attn += d * (self.indexer_heads * (self.indexer_dim + 1)
+                         + self.indexer_dim) + 2 * self.indexer_dim
         return self.vocab * d * 2 + L * (attn + mlp + 2 * d) + d
 
 
@@ -414,8 +450,25 @@ def init_params(key, cfg: LlamaConfig, gains=None):
     else:
         mlp_params = dense_mlp(ks[5:8], L, m)
     if cfg.qk_norm:
-        mlp_params.update(q_norm=jnp.ones((L, h * hd), cfg.dtype),
-                          k_norm=jnp.ones((L, hkv * hd), cfg.dtype))
+        by_head = cfg.qk_norm_by_head
+        used.update(("q_norm", "k_norm"))
+        mlp_params.update(
+            q_norm=jnp.full((L, hd if by_head else h * hd),
+                            gains.get("q_norm", 1.0), cfg.dtype),
+            k_norm=jnp.full((L, hd if by_head else hkv * hd),
+                            gains.get("k_norm", 1.0), cfg.dtype))
+    if cfg.sparse_top_k:
+        # the indexer: query heads, the one key, a weight a head, and the
+        # key's LayerNorm (gains scattered about 1, biases about 0)
+        J, di = cfg.indexer_heads, cfg.indexer_dim
+        ki = jax.random.split(jax.random.fold_in(key, 2), 5)
+        mlp_params.update(
+            wi_q=norm(ki[0], (L, d, J, di), d, "wi_q"),
+            wi_k=norm(ki[1], (L, d, di), d, "wi_k"),
+            wi_w=norm(ki[2], (L, d, J), d, "wi_w"),
+            wi_k_norm=scattered(ki[3], (L, di)),
+            wi_k_bias=(0.25 * jax.random.normal(ki[4], (L, di), jnp.float32)
+                       ).astype(cfg.dtype))
     params = {
         "embed": norm(ks[0], (cfg.vocab, d), d, "embed"),
         "layers": {
@@ -494,6 +547,10 @@ def qk_norm(q, k, lp, cfg: LlamaConfig):
     embedding. The one seam every copy of the block calls."""
     if not cfg.qk_norm:
         return q, k
+    if cfg.qk_norm_by_head:
+        # every head over its own width, one ``head_dim`` vector for all
+        return (rms_norm(q, lp["q_norm"], cfg.norm_eps),
+                rms_norm(k, lp["k_norm"], cfg.norm_eps))
 
     def whole(x, weight):
         flat = x.reshape(*x.shape[:-2], x.shape[-2] * x.shape[-1])
@@ -553,14 +610,15 @@ def forward(params, tokens, cfg: LlamaConfig, *,
     if (cfg.layer_pattern or cfg.router_input != "mlp"
             or cfg.expert_act != "silu" or cfg.latent or cfg.n_dense_layers
             or cfg.n_shared_experts or cfg.n_group > 1
-            or cfg.experts_held is not None):
+            or cfg.experts_held is not None or cfg.sparse_top_k):
         raise ValueError(
             "the training forward runs one kind of layer (full, rotated, "
             "GQA, the router on the feed-forward's input, ungrouped, silu "
             "experts, all held, none shared, no leading dense layer); a "
             "layer_pattern, router_input='attention', expert_act='relu', "
-            "latent attention, n_dense_layers, n_shared_experts, n_group "
-            "or experts_held is served by llm/runner.py only")
+            "latent attention, n_dense_layers, n_shared_experts, n_group, "
+            "experts_held or an indexer (sparse_top_k) is served by "
+            "llm/runner.py only")
     csl = partial(with_sharding_constraint_logical, rules=rules, mesh=mesh)
     cos, sin = rope_frequencies(cfg.head_dim, tokens.shape[1],
                                 cfg.rope_theta, dtype=jnp.float32)

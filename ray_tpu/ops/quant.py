@@ -126,6 +126,10 @@ _LLAMA_LAYER_CONTRACT = {
     "ws_gate": (1,),
     "ws_up": (1,),
     "ws_down": (1,),
+    # the indexer (models/llama.py, ``cfg.sparse_top_k``)
+    "wi_q": (1,),    # (L, d, J, di)   contract d
+    "wi_k": (1,),    # (L, d, di)
+    "wi_w": (1,),    # (L, d, J)
 }
 # expert configs: the feed-forward has an "expert" axis after "layers",
 # never contracted either: per-expert per-output-channel scales (L, E, n),
@@ -190,7 +194,8 @@ def _init_params_quantized_jit(key, cfg, gains=()) -> Dict:
     # what a configuration of the older kinds draws from these 16 keys
     # stays what it was; latent attention, shared experts and leading
     # dense layers need more and draw from a second set
-    plain = not (cfg.latent or cfg.n_shared_experts or cfg.n_dense_layers)
+    plain = not (cfg.latent or cfg.n_shared_experts or cfg.n_dense_layers
+                 or cfg.sparse_top_k)
     ks = iter(jax.random.split(key, 16) if plain else jax.random.split(
         jax.random.fold_in(key, 1), 64))
     gains = dict(gains)
@@ -211,11 +216,13 @@ def _init_params_quantized_jit(key, cfg, gains=()) -> Dict:
                                        0.5, 1.5)
         return {"q": q, "s": s}
 
-    def gain(L, width):
-        # learned gains scattered about 1, so that a norm over the wrong
+    def gain(L, width, name=None):
+        # learned gains scattered about 1 (about ``gains[name]``, for a
+        # norm that has a name there), so that a norm over the wrong
         # width or with the wrong weight shows against a reference
-        return (1.0 + 0.25 * jax.random.normal(
-            next(ks), (L, width), jnp.float32)).astype(jnp.bfloat16)
+        used.add(name)
+        return (gains.get(name, 1.0) * (1.0 + 0.25 * jax.random.normal(
+            next(ks), (L, width), jnp.float32))).astype(jnp.bfloat16)
 
     def attention_leaves(L):
         if not cfg.latent:
@@ -274,7 +281,19 @@ def _init_params_quantized_jit(key, cfg, gains=()) -> Dict:
     else:
         layers.update(dense_mlp(L, m))
     if cfg.qk_norm:
-        layers.update(q_norm=gain(L, h * hd), k_norm=gain(L, hkv * hd))
+        by_head = cfg.qk_norm_by_head
+        layers.update(q_norm=gain(L, hd if by_head else h * hd, "q_norm"),
+                      k_norm=gain(L, hd if by_head else hkv * hd, "k_norm"))
+    if cfg.sparse_top_k:
+        # the indexer's three projections, int8 like their neighbours,
+        # and its key's LayerNorm: gains about 1, biases about 0
+        J, di = cfg.indexer_heads, cfg.indexer_dim
+        layers.update(
+            wi_q=qrand((L, d, J, di), d, (0, 2, 3), name="wi_q"),
+            wi_k=qrand((L, d, di), d, (0, 2), name="wi_k"),
+            wi_w=qrand((L, d, J), d, (0, 2), name="wi_w"),
+            wi_k_norm=gain(L, di),
+            wi_k_bias=(gain(L, di) - 1.0).astype(jnp.bfloat16))
     params = {
         "embed": embed,
         "layers": layers,

@@ -1,0 +1,366 @@
+"""The indexer's scores, the choice of keys and the product over them
+(ops/sparse_attention.py), held to plain float32 formulas at tiny sizes
+(top_k 16, 48 to 96 keys, pages of 8), on the CPU; the TPU kernels'
+bodies run interpreted beside their plain-jax twins."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import sparse_attention as sparse
+
+J, D, H, KVH, HD, TOP = 4, 16, 4, 2, 16, 16
+
+
+def _inputs(seed, T, S, batch=1):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    return dict(
+        q=jax.random.normal(ks[0], (batch, T, H, HD), jnp.float32),
+        k=jax.random.normal(ks[1], (batch, S, KVH, HD), jnp.float32),
+        v=jax.random.normal(ks[2], (batch, S, KVH, HD), jnp.float32),
+        qi=jax.random.normal(ks[3], (batch, T, J, D), jnp.float32),
+        w=jax.random.normal(ks[4], (batch, T, J), jnp.float32),
+        ki=jax.random.normal(ks[5], (batch, S, D), jnp.float32))
+
+
+def _plain_scores(qi, w, ki):
+    """I(t, s) = sum_j w[j] relu(qi[j] . ki_s), in numpy float32."""
+    s = np.einsum("btjd,bsd->btjs", np.asarray(qi), np.asarray(ki))
+    return (np.maximum(s, 0.0) * np.asarray(w)[..., None]).sum(2)
+
+
+def _plain_chosen(scores, seen, k):
+    """The k seen keys with the largest scores, ties to the earlier one,
+    by a stable sort a row."""
+    out = np.zeros(scores.shape, bool)
+    for r, (row, ok) in enumerate(zip(scores, seen)):
+        order = np.argsort(-np.where(ok, row, -np.inf), kind="stable")
+        out[r, order[:min(k, int(ok.sum()))]] = True
+    return out & seen
+
+
+def _plain_attention(q, k, v, mask):
+    """One softmax a (row, head) over the keys the mask keeps."""
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    group = q.shape[1] // k.shape[1]
+    k, v = np.repeat(k, group, 1), np.repeat(v, group, 1)
+    s = np.einsum("thd,shd->hts", q, k) * q.shape[-1] ** -0.5
+    s = np.where(mask[None], s, -np.inf)
+    with np.errstate(invalid="ignore"):       # a row that keeps no key
+        p = np.exp(s - s.max(-1, keepdims=True))
+        p = p / p.sum(-1, keepdims=True)
+    return np.einsum("hts,shd->thd", p, v)
+
+
+@pytest.mark.parametrize("T, S", [(48, 48), (5, 96), (1, 64)])
+def test_scores_equal_the_plain_sum(T, S):
+    x = _inputs(0, T, S, batch=2)
+    got = sparse.index_scores(x["qi"], x["w"], x["ki"])
+    np.testing.assert_allclose(got, _plain_scores(x["qi"], x["w"], x["ki"]),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("T, S, last", [(48, 256, None), (8, 128, 70),
+                                        (3, 128, 128)])
+def test_index_kernel_is_its_plain_twin(T, S, last):
+    """The kernel's body, interpreted; with ``last`` the blocks no row
+    sees are skipped and compared nowhere."""
+    x = _inputs(1, T, S, batch=2)
+    limit = None if last is None else jnp.full((2, T), last, jnp.int32)
+    got = sparse.index_scores_tpu(x["qi"], x["w"], x["ki"], limit,
+                                  interpret=True)
+    want = sparse.index_scores_xla(x["qi"], x["w"], x["ki"])
+    upto = S if last is None else last
+    np.testing.assert_allclose(got[..., :upto], want[..., :upto],
+                               rtol=1e-5, atol=1e-5)
+
+
+def _limits(T, S, first=0):
+    """Causal: the query at row t sees keys 0 .. first + t."""
+    return jnp.minimum(first + jnp.arange(T) + 1, S).astype(jnp.int32)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_the_chosen_set_is_the_plain_one(seed):
+    T = S = 96
+    scores = jax.random.normal(jax.random.PRNGKey(seed), (T, S), jnp.float32)
+    lim = _limits(T, S)
+    got = np.asarray(sparse.choose(scores, lim, top_k=TOP)) > 0
+    seen = np.arange(S)[None] < np.asarray(lim)[:, None]
+    want = _plain_chosen(np.asarray(scores), seen, TOP)
+    assert (got == want).all()
+    # a row that sees at most top_k keys keeps them all
+    assert (got[:TOP] == seen[:TOP]).all()
+    assert (got.sum(-1) == np.minimum(np.arange(T) + 1, TOP)).all()
+
+
+def test_ties_go_to_the_earlier_position():
+    """Scores of a few values only (zeros among them, as a ReLU makes):
+    far more tie across the 16th place than are needed."""
+    rng = np.random.default_rng(0)
+    scores = rng.integers(-1, 3, (40, 64)).astype(np.float32)
+    scores[3] = 0.0                               # one value throughout
+    scores[4, ::2] = -0.0                         # -0.0 ties with 0.0
+    lim = jnp.full((40,), 64, jnp.int32).at[5].set(10).at[6].set(0)
+    got = np.asarray(sparse.choose(jnp.asarray(scores), lim, top_k=TOP)) > 0
+    seen = np.arange(64)[None] < np.asarray(lim)[:, None]
+    want = _plain_chosen(np.where(scores == 0, 0.0, scores), seen, TOP)
+    assert (got == want).all()
+    assert got[3, :TOP].all() and not got[3, TOP:].any()
+    assert got[5].sum() == 10 and got[6].sum() == 0
+
+
+def test_two_segments_are_one_order_of_positions():
+    """A chunk's queries: the cached span below the chunk's start, then
+    the chunk's own rows up to themselves, behind a gap of padding."""
+    T, past, start, S = 24, 40, 64, 64 + 24
+    scores = jax.random.normal(jax.random.PRNGKey(3), (T, S), jnp.float32)
+    lim_a = jnp.full((T,), past, jnp.int32)
+    lim_b = jnp.arange(T, dtype=jnp.int32) + 1
+    got = np.asarray(sparse.choose(scores, lim_a, lim_b, start,
+                                   top_k=TOP)) > 0
+    idx = np.arange(S)[None]
+    seen = (idx < past) | ((idx >= start)
+                           & (idx < start + np.asarray(lim_b)[:, None]))
+    assert (got == _plain_chosen(np.asarray(scores), seen, TOP)).all()
+
+
+@pytest.mark.parametrize("start_b", [None, 128])
+def test_select_kernel_is_its_plain_twin(start_b):
+    rng = np.random.default_rng(1)
+    R, S = 40, 256
+    scores = jnp.asarray(rng.integers(-3, 4, (R, S)).astype(np.float32)
+                         + (rng.random((R, S)) < 0.5)
+                         * rng.standard_normal((R, S)).astype(np.float32))
+    lim = jnp.stack([jnp.asarray(rng.integers(0, 129, R), jnp.int32),
+                     jnp.asarray(rng.integers(0, 60, R), jnp.int32)
+                     * (start_b is not None)], -1)
+    got = sparse.choose_tpu(scores, lim, k=TOP, start_b=start_b,
+                            interpret=True)
+    want = sparse.choose_xla(scores, lim, k=TOP, start_b=start_b)
+    assert (np.asarray(got) == np.asarray(want)).all()
+    assert np.asarray(want).sum() > 0
+
+
+def test_at_most_top_k_visible_keys_is_the_dense_path():
+    """With no more keys than top_k nothing is scored or counted and the
+    output is causal attention's."""
+    from ray_tpu.ops.attention import naive_attention
+
+    x = _inputs(4, TOP, TOP)
+    got = sparse.attend(x["q"], x["k"], x["v"], x["qi"], x["w"], x["ki"],
+                        _limits(TOP, TOP)[None], top_k=TOP, scale=HD ** -0.5)
+    want = naive_attention(x["q"], x["k"], x["v"], causal=True)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("T, tile", [(96, 512), (96, 32)])
+def test_attend_is_one_softmax_over_the_chosen_keys(T, tile):
+    """Whole and a tile of queries at a time (a tile without a token is
+    skipped and comes back as zeros)."""
+    x = _inputs(5, T, T)
+    length = 70
+    lim = jnp.where(jnp.arange(T) < length, jnp.arange(T) + 1, 0)[None]
+    got = np.asarray(sparse.attend(
+        x["q"], x["k"], x["v"], x["qi"], x["w"], x["ki"],
+        lim.astype(jnp.int32), top_k=TOP, scale=HD ** -0.5, tile=tile))[0]
+    scores = _plain_scores(x["qi"], x["w"], x["ki"])[0]
+    seen = np.arange(T)[None] < np.asarray(lim)[0][:, None]
+    mask = _plain_chosen(scores, seen, TOP)
+    want = _plain_attention(x["q"][0], x["k"][0], x["v"][0],
+                            mask)[:length]
+    np.testing.assert_allclose(got[:length], want, rtol=1e-4, atol=1e-5)
+    assert not got[length:].any()
+
+
+def test_prefill_kernel_is_its_plain_twin():
+    T, S = 128, 256
+    x = _inputs(6, T, S)
+    rng = np.random.default_rng(2)
+    mask = jnp.asarray(rng.random((T, S)) < 0.2, jnp.int8).at[7].set(0)
+    last = jnp.full((T,), 200, jnp.int32)
+    mask = mask * (jnp.arange(S)[None] < 200)
+    kt, vt = jnp.swapaxes(x["k"][0], 0, 1), jnp.swapaxes(x["v"][0], 0, 1)
+    got = sparse.masked_attention_tpu(x["q"][0], kt, vt, mask, last,
+                                      scale=HD ** -0.5, interpret=True)
+    want = sparse.masked_attention_xla(x["q"][0], kt, vt, mask,
+                                       scale=HD ** -0.5)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    assert not np.asarray(got[7]).any()        # a row that keeps no key
+
+
+def test_chosen_rows_counts_the_mask_out():
+    rng = np.random.default_rng(3)
+    mask = np.zeros((3, 200), np.int8)
+    mask[0, rng.choice(200, TOP, replace=False)] = 1
+    mask[1, [0, 5, 199]] = 1                    # fewer than top_k
+    idx, ok = sparse.chosen_rows(jnp.asarray(mask), TOP)
+    for row in range(3):
+        want = np.nonzero(mask[row])[0]
+        assert np.asarray(ok[row]).sum() == len(want)
+        assert (np.asarray(idx[row])[:len(want)] == want).all()
+
+
+def _paged(rows, tables, pages, page):
+    """rows [B, S, ...] -> a pool [1, pages, page, ...] under ``tables``."""
+    B, S = rows.shape[:2]
+    pool = np.zeros((1, pages, page, *rows.shape[2:]), np.float32)
+    for b in range(B):
+        for s in range(S):
+            pool[0, tables[b, s // page], s % page] = rows[b, s]
+    return jnp.asarray(pool)
+
+
+@pytest.mark.parametrize("new_rows", [1, 3])
+def test_decode_over_pages_is_prefills_last_row(new_rows):
+    """One query a slot over its own pages (listed out of order), the
+    last ``new_rows`` keys not yet in the cache but in the burst's
+    scratch: the row whole-prompt attention gives that query."""
+    from ray_tpu.llm.runner import _attend
+
+    page, S, B = 8, 64, 2
+    x = _inputs(7, S, S, batch=B)
+    lim = jnp.broadcast_to(_limits(S, S)[None], (B, S))
+    want = np.asarray(sparse.attend(
+        x["q"], x["k"], x["v"], x["qi"], x["w"], x["ki"], lim, top_k=TOP,
+        scale=HD ** -0.5))[:, -1]
+    cached = S - new_rows
+    rng = np.random.default_rng(4)
+    tables = rng.permutation(np.arange(1, 1 + B * S // page)).reshape(
+        B, -1).astype(np.int32)
+    pools = [_paged(np.asarray(a)[:, :cached], tables, 1 + B * S // page,
+                    page) for a in (x["k"], x["v"], jnp.pad(
+                        x["ki"], ((0, 0), (0, 0), (0, 128 - D))))]
+    K = 4
+    scratch = [jnp.pad(a[:, cached:], ((0, 0), (0, K - new_rows))
+                       + ((0, 0),) * (a.ndim - 2))
+               for a in (x["k"], x["v"], jnp.pad(
+                   x["ki"], ((0, 0), (0, 0), (0, 128 - D))))]
+    idx, ok, own = sparse.decode_chosen(
+        jnp.pad(x["qi"][:, -1], ((0, 0), (0, 0), (0, 128 - D))),
+        x["w"][:, -1], pools[2], jnp.int32(0), jnp.asarray(tables),
+        jnp.full((B,), cached, jnp.int32), scratch[2], jnp.int32(new_rows),
+        top_k=TOP)
+    assert (np.asarray(ok).sum(-1) + np.asarray(own).sum(-1) == TOP).all()
+    gk, gv = (sparse.gather_rows(pool, jnp.int32(0), jnp.asarray(tables),
+                                 idx, page) for pool in pools[:2])
+    got = _attend(x["q"][:, -1], (gk, gv, ok),
+                  (scratch[0], scratch[1], own))
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+
+
+# --- the programs: llm/runner.py through the three pools ---
+@functools.cache
+def _model():
+    from ray_tpu.models import init_params
+    from ray_tpu.models.llama import LlamaConfig
+    from ray_tpu.ops import rope_frequencies
+
+    cfg = LlamaConfig(
+        vocab=128, dim=64, n_layers=2, n_heads=4, n_kv_heads=2, mlp_dim=96,
+        max_seq=256, dtype=jnp.float32, remat=False, head_size=16,
+        qk_norm=True, qk_norm_by_head=True, indexer_heads=J, indexer_dim=D,
+        sparse_top_k=TOP)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    cos, sin = rope_frequencies(cfg.rope_dim, cfg.max_seq, cfg.rope_theta)
+    return cfg, params, cos, sin
+
+
+def _whole_prefill(tokens, length, bucket, page=8):
+    from ray_tpu.llm import runner
+    from ray_tpu.llm.cache import init_kv_cache
+
+    cfg, params, cos, sin = _model()
+    cache = init_kv_cache(cfg, 1 + 128 // page, page)
+    padded = np.zeros((1, bucket), np.int32)
+    padded[0, :length] = tokens[:length]
+    table = jnp.arange(1, 1 + 128 // page, dtype=jnp.int32)[None]
+    logits, k, v, _counts, i = runner.prefill(
+        params, cache.k, cache.v, jnp.asarray(padded),
+        jnp.asarray([length], jnp.int32), table, cos, sin, None, cache.i,
+        cfg=cfg)
+    return np.asarray(logits)[0], (k, v, i), table
+
+
+TOKENS = np.random.default_rng(5).integers(0, 128, 128).astype(np.int32)
+
+
+@pytest.mark.parametrize("chunk", [16, 32])
+def test_a_chunked_prefill_is_the_whole_one(chunk):
+    """The chunks' queries score the cached rows and their own, and the
+    three pools hold what the whole prompt's prefill wrote."""
+    from ray_tpu.llm import runner
+    from ray_tpu.llm.cache import init_kv_cache
+
+    cfg, params, cos, sin = _model()
+    length = 90
+    want, pools, table = _whole_prefill(TOKENS, length, 128)
+    cache = init_kv_cache(cfg, 17, 8)
+    k, v, i = cache.k, cache.v, cache.i
+    for start in range(0, length, chunk):
+        n = min(chunk, length - start)
+        tokens = np.zeros((1, chunk), np.int32)
+        tokens[0, :n] = TOKENS[start:start + n]
+        logits, k, v, _counts, i = runner.prefill_chunk(
+            params, k, v, jnp.asarray(tokens), jnp.int32(start),
+            jnp.int32(n), table, cos, sin, i, cfg=cfg)
+    np.testing.assert_allclose(np.asarray(logits)[0], want, rtol=1e-4,
+                               atol=1e-4)
+    rows = length // 8            # whole pages of the prompt
+    for got, whole in zip((k, v, i), pools):
+        np.testing.assert_allclose(got[:, 1:1 + rows], whole[:, 1:1 + rows],
+                                   rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("length", [60, 61])
+def test_a_burst_crossing_a_page_decodes_prefills_tokens(length):
+    """Four fused steps from a position whose burst ends on another page:
+    every step scores the slot's pages and the rows the burst has written
+    so far; the tokens are those a whole prefill of the longer sequence
+    would choose, step by step."""
+    from ray_tpu.llm import runner
+
+    cfg, params, cos, sin = _model()
+    logits, (k, v, i), table = _whole_prefill(TOKENS, length, 64)
+    first = int(np.argmax(logits))
+    out, k, v, _counts, i = runner.decode_burst(
+        params, k, v, jnp.asarray([first], jnp.int32),
+        jnp.asarray([length], jnp.int32), table, jnp.asarray([True]), cos,
+        sin, 0, jnp.ones(1), jnp.zeros(1, jnp.int32), jnp.ones(1), None,
+        table[:, :8], jnp.int32(4), i, cfg=cfg, n_steps=4, greedy=True)
+    got = np.asarray(out)[:, 0].tolist()
+    sequence = list(TOKENS[:length]) + [first]
+    for step in range(4):
+        want, _pools, _table = _whole_prefill(
+            np.asarray(sequence, np.int32), len(sequence), 128)
+        assert got[step] == int(np.argmax(want)), step
+        sequence.append(got[step])
+    # the burst's rows lie where a prefill of the longer sequence puts them
+    _logits, pools, _table = _whole_prefill(
+        np.asarray(sequence, np.int32), length + 4, 128)
+    for mine, whole in zip((k, v, i), pools):
+        np.testing.assert_allclose(
+            mine[:, 1:9].reshape(2, 64, -1)[:, :length + 4],
+            whole[:, 1:9].reshape(2, 64, -1)[:, :length + 4],
+            rtol=1e-4, atol=1e-5)
+
+
+def test_verify_step_selects_through_the_pages():
+    """A speculative window's tokens are written, then scored and chosen
+    from through the pages: its predictions are a whole prefill's."""
+    from ray_tpu.llm import runner
+
+    cfg, params, cos, sin = _model()
+    length, window = 70, 5
+    _logits, (k, v, i), table = _whole_prefill(TOKENS, length, 128)
+    positions = (length + np.arange(window))[None].astype(np.int32)
+    tgt, _s0, *_ = runner.verify_step(
+        params, k, v, jnp.asarray(TOKENS[None, length:length + window]),
+        jnp.asarray(positions), table, cos, sin, 0, jnp.ones(1),
+        jnp.zeros(1, jnp.int32), jnp.ones(1), i, cfg=cfg, greedy=True)
+    for j in range(window):
+        want, _pools, _table = _whole_prefill(TOKENS, length + j + 1, 128)
+        assert int(tgt[0, j]) == int(np.argmax(want)), j

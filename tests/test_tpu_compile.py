@@ -691,3 +691,78 @@ def test_latent_flash_and_decode_kernels_compile_alone(one_chip):
         _sds((9, 2049, 64, 640), jnp.bfloat16, one_chip),
         _sds((), jnp.int32, one_chip), _sds((8, 256), jnp.int32, one_chip),
         _sds((8,), jnp.int32, one_chip)) == 1
+
+
+# --- an indexer beside attention (ops/sparse_attention.py): the three
+# kernels at the published widths, and a burst that copies no pool ---
+def test_sparse_kernels_compile_alone(one_chip):
+    """Mosaic takes the scores (16 heads over keys as wide as the pool's
+    slot), the choice (32 rows of 8k scores counted over in VMEM, and a
+    decode step's 8 rows with the burst's own behind a gap) and the
+    product over a mask (32 query heads, 4 KV heads of 128)."""
+    from ray_tpu.ops import sparse_attention as sparse
+
+    T, S = 512, 8192
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    assert _custom_calls(
+        sparse.index_scores_tpu, _sds((1, T, 16, 128), bf16, one_chip),
+        _sds((1, T, 16), jnp.float32, one_chip),
+        _sds((1, S, 128), bf16, one_chip), _sds((1, T), i32, one_chip)) == 1
+    for rows, keys, start_b in ((T, S, None), (8, 2048 + 128, 2048)):
+        assert _custom_calls(
+            functools.partial(sparse.choose_tpu, k=2048, start_b=start_b),
+            _sds((rows, keys), jnp.float32, one_chip),
+            _sds((rows, 2), i32, one_chip)) == 1
+    assert _custom_calls(
+        functools.partial(sparse.masked_attention_tpu, scale=128 ** -0.5),
+        _sds((T, 32, 128), bf16, one_chip), _sds((4, S, 128), bf16, one_chip),
+        _sds((4, S, 128), bf16, one_chip), _sds((T, S), jnp.int8, one_chip),
+        _sds((T,), i32, one_chip)) == 1
+
+
+def test_sparse_decode_burst_reads_the_pools_where_they_lie(one_chip):
+    """A burst of a configuration with an indexer copies no K or V page
+    and turns no pool into another layout: the scores and the choice are
+    the two decode kernels, the chosen rows one gather out of each pool
+    as it lies, the burst's rows written by slices (the scatter over all
+    16 layers x 4 heads x 128 copied each pool layers-inward and back)."""
+    import json
+    import os
+
+    from benchmarks.harness.families import family_of
+    from ray_tpu.llm.cache import init_kv_cache
+    from ray_tpu.llm.runner import decode_burst
+    from ray_tpu.ops import rope_frequencies
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "keye-vl-2.0-30b-a3b-ep4-int8-16l.json")) as f:
+        config = dict(json.load(f), vocab_size=4096)
+    family = family_of(config)
+    cfg = family.program_config(config)
+    params = _on_chip(jax.eval_shape(
+        lambda: family.served_params(jax.random.PRNGKey(0), config)),
+        one_chip)
+    cos, sin = _on_chip(jax.eval_shape(lambda: rope_frequencies(
+        cfg.rope_dim, 8192, cfg.rope_theta)), one_chip)
+    pools = _on_chip(jax.eval_shape(lambda: (lambda c: (c.k, c.v, c.i))(
+        init_kv_cache(cfg, 513, 64))), one_chip)
+    B = 8
+    i32, f32 = _sds((B,), jnp.int32, one_chip), _sds((B,), jnp.float32,
+                                                     one_chip)
+    table = _sds((B, 128), jnp.int32, one_chip)
+    compiled = decode_burst.lower(
+        params, pools[0], pools[1], i32, i32, table,
+        _sds((B,), jnp.bool_, one_chip), cos, sin, 0, f32, i32, f32, None,
+        table, _sds((), jnp.int32, one_chip), pools[2], cfg=cfg, n_steps=8,
+        greedy=True).compile()
+    text = compiled.as_text()
+    for kernel in ("rt_sparse_index_decode", "rt_sparse_select_decode"):
+        assert "%" + kernel in text, kernel
+    for pool in pools:
+        shape = "bf16[" + ",".join(map(str, pool.shape)) + "]"
+        assert not [line for line in text.splitlines()
+                    if f" = {shape}" in line and " copy(" in line], shape
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(p.size * 2 for p in pools), mem
+    assert mem.temp_size_in_bytes < 0.6 * 1024**3, mem
