@@ -443,12 +443,13 @@ def _expert_kernel_parity(seed: int) -> dict:
 
 
 def _sparse_kernel_parity(seed: int) -> dict:
-    """The indexer's three kernels (``ops/sparse_attention.py``) against
+    """The indexer's four kernels (``ops/sparse_attention.py``) against
     their plain-jax twins on the device at the published widths: 512
     queries of 16 heads against 8,192 keys as wide as the pool's slot,
     the 2,048 best of each row, and the product over that mask at 32
     query and 4 KV heads of 128; then a decode step's 8 rows with the
-    burst's own rows behind a gap. The scores are float32 sums of the
+    burst's own rows behind a gap, and the decode product over their
+    choice through a block table. The scores are float32 sums of the
     same bf16 products, the choice is exact (the same mask), the product
     rounds one float32 sum to bf16. Runs in the gang worker."""
     import jax
@@ -493,16 +494,28 @@ def _sparse_kernel_parity(seed: int) -> dict:
         s, l, k=top, start_b=4096))(rows, lim)
     report["decode_masks_differ"] = int((np.asarray(step) != np.asarray(
         sparse.choose_xla(rows, lim, k=top, start_b=4096))).sum())
-    idx, ok = jax.jit(lambda m: sparse.chosen_rows(m[:, :4096], top))(step)
-    for b in range(8):
-        chosen = np.nonzero(np.asarray(step)[b, :4096])[0]
-        if (int(np.asarray(ok)[b].sum()) != len(chosen) or (
-                np.asarray(idx)[b, :len(chosen)] != chosen).any()):
-            raise AssertionError(f"chosen_rows: slot {b} is not its mask")
+    # the product over that choice, each slot's own pages of 64 where
+    # they lie (listed out of order) in the second layer of two
+    pages = 1 + 8 * 64
+    pool_k, pool_v = (jax.random.normal(k, (2, pages, 64, 4, 128),
+                                        jnp.bfloat16) for k in ks[4:])
+    tables = jax.random.permutation(ks[1], jnp.arange(
+        1, pages, dtype=jnp.int32)).reshape(8, 64)
+    operands = (q[:8], pool_k, pool_v, jnp.int32(1), tables, lengths,
+                step[:, :4096])
+    o, lse = jax.jit(lambda *a: sparse.decode_attention_tpu(
+        *a, scale=128 ** -0.5))(*operands)
+    o_twin, lse_twin = sparse.decode_attention_xla(*operands,
+                                                   scale=128 ** -0.5)
+    report["decode_product_max_diff"] = float(max(
+        np.abs(np.asarray(o) - np.asarray(o_twin)).max(),
+        np.abs(np.asarray(lse) - np.asarray(lse_twin))[
+            np.asarray(lengths) > 0].max()))
     if (report["masks_differ"] or report["decode_masks_differ"]
             or report["chosen_a_row"] != top
             or report["scores_max_diff"] > 1e-3
-            or report["product_max_diff"] > 2.0 ** -6):
+            or report["product_max_diff"] > 2.0 ** -6
+            or report["decode_product_max_diff"] > 2.0 ** -6):
         raise AssertionError(f"sparse kernels off their twins: {report}")
     return report
 

@@ -324,6 +324,9 @@ class LLMEngine:
             # indexer scored, and the keys it attended over (at most
             # sparse_top_k of them)
             self._counters.update(scored_keys=0, attended_keys=0)
+            # the pages of K (and as many of V) the bursts' steps walked:
+            # every decoding slot's cached pages, once a step run
+            self._counters["sparse_decode_pages"] = 0
         # what one cached position holds, all layers
         self._counters["kv_bytes_per_token"] = int(sum(
             a.size // (a.shape[1] * a.shape[2]) * a.dtype.itemsize
@@ -342,7 +345,8 @@ class LLMEngine:
     @property
     def _reads_own_pages(self) -> bool:
         """A burst copies no page: it reads each slot's own pages through
-        its table (latent rows; an indexer's rows and the chosen K, V)."""
+        its table (latent rows; an indexer's rows, and K and V under its
+        choice)."""
         return self.cfg.latent or self.cfg.sparse_top_k > 0
 
     def attention_paths(self) -> Dict[str, str]:
@@ -373,9 +377,10 @@ class LLMEngine:
                     "verify_step": chosen % "gathered pages",
                     "decode_burst": (
                         "pallas rt_sparse_index_decode, rt_sparse_select_"
-                        "decode" if on_tpu else "xla") + " (each slot's own "
-                    "indexer rows), xla (the chosen K and V rows gathered "
-                    "from the pool)"}
+                        "decode (each slot's own indexer rows), rt_sparse_"
+                        "attend_decode" if on_tpu else "xla") + " (each "
+                    "slot's own K and V pages where they lie, under the "
+                    "choice)"}
         return {"prefill": prefill, "prefill_chunk": listed,
                 "verify_step": listed, "decode_burst": listed}
 
@@ -1209,6 +1214,8 @@ class LLMEngine:
                 for c in (counters, counters["groups"][self.group_names[0]]):
                     c["live_pages"] += sum(pages)
                     c["gathered_pages"] += sum(pages)
+                if self.cfg.sparse_top_k:
+                    counters["sparse_decode_pages"] += K * sum(pages)
             else:
                 for g, window in enumerate(self.windows):
                     held = []

@@ -80,10 +80,14 @@ and multiplies a tile of queries at a time over the prompt's own rows
 result it is); ``prefill_chunk`` and ``verify_step`` over the gathered
 span (and the chunk's own rows); ``decode_burst`` copies no K or V:
 every step gathers the indexer's rows of each slot's own pages, scores
-them and the burst's own, chooses, and fetches ONLY the chosen K and V
-rows from the pool. Whole prompts and bursts are written into the three
-pools by loops of slices (``_write_latent_pages``, ``_write_slices``),
-not by the scatter, which says why.
+them and the burst's own, chooses, and attends over each slot's own K
+and V pages where they lie in the pools, under that choice
+(``sparse.decode_attention``, a Pallas kernel on a TPU: it reads every
+page up to the slot's length, which costs less than finding the chosen
+rows did: 0.12 to 0.18 ms a layer against 0.81 to 0.91 for the counting
+and the two gathers, PR 44). Whole prompts and bursts are written into
+the three pools by loops of slices (``_write_latent_pages``,
+``_write_slices``), not by the scatter, which says why.
 
 Leading dense layers (``LlamaConfig.n_dense_layers``) are their own
 stack ``params["dense_layers"]``: ``_layers`` scans them first, with
@@ -963,8 +967,13 @@ def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
     A configuration with an indexer copies no K or V either, and takes
     the same ``gather``: every step gathers the INDEXER's rows of each
     slot's own pages (a sixteenth of its K and V), scores them and the
-    burst's own, chooses, and fetches only the chosen K and V rows from
-    the pool (``ops/sparse_attention.py`` ``decode_chosen``).
+    burst's own and chooses (``ops/sparse_attention.py``
+    ``decode_chosen``), then reads each slot's own K and V pages straight
+    from the pools up to the slot's length and attends under the choice
+    (``decode_attention``), joined with the burst's own chosen rows by
+    the log-sum-exp: the pages' bytes (1.2 ms a step at the cell's
+    spans) cost less than counting the chosen rows out and gathering
+    them did (13 to 15 ms a step; PR 44).
 
     ``steps``: int32 scalar, the steps to run (<= n_steps, which is only
     the capacity: scratch rows and the returned [n_steps, B]); None runs
@@ -1023,22 +1032,23 @@ def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
 
         def attend(q, k, v, state, window, index=None):
             if index is not None:
-                # the slot's indexer rows where they lie and the burst's
-                # own, scored; of the chosen, the cached K and V rows
-                # fetched from the pool, the burst's taken from scratch
+                # the slot's indexer rows and the burst's own, scored and
+                # chosen from; the slot's K and V pages read where they
+                # lie under the choice, the burst's rows joined from
+                # scratch: one softmax
                 layer, nk, nv, ni = state
                 qi, w, ki = index
                 nk, nv, ni = (jax.lax.dynamic_update_slice_in_dim(
                     rows, new.astype(rows.dtype), i, 1)
                     for rows, new in ((nk, k), (nv, v), (ni, ki)))
-                at, ok, own = sparse.decode_chosen(
+                chosen, own = sparse.decode_chosen(
                     qi[:, 0], w[:, 0], pools[0][2], layer, span, positions,
                     ni, i + 1, top_k=cfg.sparse_top_k)
-                with jax.named_scope("rt.attn.sparse"):
-                    gk, gv = (sparse.gather_rows(pool, layer, span, at,
-                                                 page_size)
-                              for pool in pools[0][:2])
-                    o = _attend(q[:, 0], (gk, gv, ok), (nk, nv, own))
+                o, lse = sparse.decode_attention(
+                    q[:, 0], *pools[0][:2], layer, span, positions, chosen,
+                    scale=cfg.softmax_scale)
+                o = sparse.join_new_rows(o, lse, q[:, 0], nk, nv, own,
+                                         scale=cfg.softmax_scale)
                 return o[:, None], (nk, nv, ni)
             if cfg.latent:
                 # absorbed: the slot's cached rows where they lie, then

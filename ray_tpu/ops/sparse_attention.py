@@ -12,8 +12,9 @@ and attends, in all its heads, over the ``top_k`` (2,048) visible keys
 with the largest scores (ties to the earlier position; all of them where
 fewer are visible): one softmax over the chosen keys, nothing else.
 
-Three steps, each under its own ``jax.named_scope`` and, on a TPU, its
-own named Pallas kernel (``harness/trace.py`` keeps those names):
+Three steps where the keys lie side by side, each under its own
+``jax.named_scope`` and, on a TPU, its own named Pallas kernel
+(``harness/trace.py`` keeps those names):
 
   * ``index_scores`` (``rt.attn.index``; ``rt_sparse_index``): the
     scores of a tile of queries against the keys, a block at a time: the
@@ -39,12 +40,26 @@ own named Pallas kernel (``harness/trace.py`` keeps those names):
 [L, L] array: at 32k a layer's scores are 4 GB in float32), for every
 program that has the keys side by side: whole-prompt ``prefill``,
 ``prefill_chunk`` (the cached span, then the chunk's own rows),
-``verify_step``. ``decode_chosen`` is a decode step's: the indexer's
-rows of each slot's own pages are gathered (128 bytes a position, not
-the 2 KB of its K and V), scored and chosen from, the chosen positions
-are counted out of the mask (``chosen_rows``: cumulative counts, no
-sort, no scatter) and ONLY those K and V rows are fetched from the pool
-(``gather_rows``): a slot's K and V span is never copied.
+``verify_step``.
+
+A decode step has one query a slot and the keys in pages.
+``decode_chosen`` gathers the indexer's rows of each slot's own pages
+(128 bytes a position, not the 2 KB of its K and V), scores them and the
+burst's own and chooses, as a mask. ``decode_attention`` (``rt.attn.
+sparse``; ``rt_sparse_attend_decode``) then reads each slot's K and V
+pages WHERE THEY LIE, all of them up to the slot's length, and attends
+under the mask: a slot's program copies 8 pages of K and of V a step
+from the pools into VMEM (the next step's on their way meanwhile), one
+online softmax, and a slot that does not decode copies nothing;
+``join_new_rows`` joins the burst's own chosen rows by the log-sum-exp.
+Reading every page costs less than finding the 2,048 rows: until PR 44
+the chosen positions were counted out of the mask and ONLY those K and V
+rows gathered (16,384 rows of 1 KB a pool for 8 slots, whether they
+decode or not), 0.81 to 0.91 ms a layer whatever the lengths (the
+counting 0.15 to 0.20, a pool's gather 0.27 to 0.32, the product 0.06);
+the kernel walks 44 to 89 MB of pages (3 to 5 slots of 3k to 22k) in
+0.12 to 0.18 ms with its mask's widening, and 8 x 32k (537 MB) in 0.78
+(TPU v5e, PR 44, chip calls 1 and 3: ``PERF.md`` section 6).
 
 Where a query sees at most ``top_k`` keys every visible key is chosen
 and the result is plain causal attention's.
@@ -56,6 +71,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 NEG_INF = -1e30
 INT_MIN = -2 ** 31
@@ -65,6 +81,7 @@ INDEX_KERNEL = "rt_sparse_index"
 SELECT_KERNEL = "rt_sparse_select"
 PREFILL_KERNEL = "flash_sparse_fwd"
 DECODE = "_decode"
+DECODE_KERNEL = "rt_sparse_attend_decode"
 # queries ``attend`` takes at a time: their scores are [tile, S] float32
 QUERY_TILE = 512
 _VMEM = 100 * 1024 * 1024
@@ -478,52 +495,206 @@ def attend(q, k, v, qi, w, ki, lim_a, lim_b=None, start_b=None, *,
 
 
 # ------------------------------------------------------------ a decode step
-_COUNT = 128        # keys a group of ``chosen_rows``' counts holds
+def decode_attention_xla(q, pool_k, pool_v, layer, tables, lengths, mask, *,
+                         scale):
+    """The same in plain jax (the CPU's path, and the kernel's oracle):
+    the table's rectangle of the layer's pages gathered, one softmax a
+    slot and head over the keys the mask keeps below the slot's length."""
+    B, H, hd = q.shape
+    k, v = (jnp.take(jax.lax.dynamic_index_in_dim(pool, layer, 0, False),
+                     tables, axis=0).reshape(B, -1, *pool.shape[3:])
+            for pool in (pool_k, pool_v))
+    kvh = k.shape[2]
+    s = jnp.einsum("bkgd,bskd->bkgs", q.reshape(B, kvh, H // kvh, hd), k,
+                   preferred_element_type=jnp.float32) * scale
+    seen = ((mask > 0) & (jnp.arange(k.shape[1])[None, :]
+                          < lengths[:, None]))[:, None, None, :]
+    s = jnp.where(seen, s, NEG_INF)
+    m = s.max(-1)
+    p = jnp.where(seen, jnp.exp(s - m[..., None]), 0.0)
+    l = jnp.maximum(p.sum(-1), 1e-30)
+    o = jnp.einsum("bkgs,bskd->bkgd", p.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    return ((o / l[..., None]).reshape(B, H, hd),
+            (m + jnp.log(l)).reshape(B, H))
 
 
-def chosen_rows(mask, top_k: int):
-    """mask int8 [B, S] with at most ``top_k`` ones a row -> (idx int32
-    [B, top_k], the chosen indices in rising
-    order, then anything; ok bool [B, top_k], which of them are). By
-    counting, in dense products that the MXU runs exactly (0s and 1s in
-    bfloat16, sums in float32): the ones a group of 128 keys holds, the
-    groups' running total, the group an output slot falls in, and inside
-    it the key at which the running count reaches the slot's."""
-    B, S = mask.shape
-    G = -(-S // _COUNT)
-    m = jnp.pad(mask > 0, ((0, 0), (0, G * _COUNT - S))).reshape(
-        B, G, _COUNT).astype(jnp.bfloat16)
-    counts = m.astype(jnp.float32).sum(-1)                       # [B, G]
-    ends = jnp.cumsum(counts, -1)
-    slot = jnp.arange(top_k, dtype=jnp.float32)[None, :, None]   # [1, k, 1]
-    group = (ends[:, None, :] <= slot).sum(-1)                   # [B, k]
-    ok = slot[..., 0] < ends[:, -1:]
-    group = jnp.minimum(group, G - 1)
-    pick = jax.nn.one_hot(group, G, dtype=jnp.bfloat16)          # [B, k, G]
-    inside = jnp.einsum("bkg,bgc->bkc", pick, m,
-                        preferred_element_type=jnp.float32)
-    lower = jnp.tril(jnp.ones((_COUNT, _COUNT), jnp.bfloat16)).T
-    running = jnp.einsum("bkc,cd->bkd", inside.astype(jnp.bfloat16), lower,
-                         preferred_element_type=jnp.float32)
-    first = jnp.take_along_axis(ends - counts, group, axis=1)
-    rank = slot[..., 0] - first                                  # 0-based
-    at = (running <= rank[..., None]).sum(-1)
-    idx = group * _COUNT + jnp.minimum(at, _COUNT - 1)
-    return idx.astype(jnp.int32), ok
+def _decode_kernel(layer, tables, lengths, q_ref, seen_ref, k_hbm, v_hbm,
+                   o_ref, lse_ref, k_buf, v_buf, sems, *, scale, page, pages,
+                   group):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    length = lengths[b]
+    rows = k_hbm.shape[2]               # a page's (position, KV head) rows
+    steps = pl.cdiv(length, pages * page)
+    last = jnp.maximum(length - 1, 0) // page
+
+    def copies(j, half):
+        """Step j's pages of K and V into buffer ``half``; past the
+        slot's last page that page again (read, never counted)."""
+        for g in range(pages):
+            at = tables[b, jnp.minimum(j * pages + g, last)]
+            for pool, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                yield pltpu.make_async_copy(
+                    pool.at[layer[0], at],
+                    buf.at[half, pl.ds(g * rows, rows)], sems.at[half])
+
+    @pl.when(steps > 0)
+    def _():
+        for copy in copies(0, 0):
+            copy.start()
+
+    q = q_ref[...]
+    head = jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], 1), 0) // group
+
+    def step(j, carry):
+        m_prev, l, acc = carry
+        half = j % 2
+
+        @pl.when(j + 1 < steps)
+        def _():
+            for copy in copies(j + 1, 1 - half):
+                copy.start()
+
+        for copy in copies(j, half):
+            copy.wait()
+        # a page's rows are (position, KV head): EVERY query head against
+        # every row in one product, and a head keeps the rows of its own
+        # KV head (``seen_ref``: a chosen row's KV head, else -1)
+        s = jax.lax.dot_general(q, k_buf[half], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        seen = jnp.concatenate([seen_ref[pl.ds(j * pages + g, 1), :]
+                                for g in range(pages)], axis=1) == head
+        s = jnp.where(seen, s, NEG_INF)
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        # the rows of other KV heads weigh 0: one product again
+        values = v_buf[half]
+        return (m_new, l * corr + p.sum(-1, keepdims=True),
+                acc * corr + jax.lax.dot_general(
+                    p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+
+    m, l, acc = jax.lax.fori_loop(
+        0, steps, step,
+        (jnp.full((q.shape[0], 1), NEG_INF, jnp.float32),
+         jnp.zeros((q.shape[0], 1), jnp.float32),
+         jnp.zeros(o_ref.shape, jnp.float32)))
+    l = jnp.maximum(l, 1e-30)
+    o_ref[...] = acc / l
+    lse_ref[...] = m + jnp.log(l)
 
 
-def gather_rows(pool, layer, tables, idx, page: int):
-    """The rows at positions ``idx`` [B, n] of each slot's own pages:
-    pool [L, P, page, ...]; tables int32 [B, pages] -> [B, n, ...]. ONE
-    gather out of the pool as it lies: no layer is sliced out, no page
-    copied whole."""
-    L, P = pool.shape[:2]
-    pages = jnp.take_along_axis(tables, idx // page, axis=1)
-    # rows of the pool as one list: indexed by layer, page and place at
-    # once XLA wants the layers inward and copies the whole pool there
-    # (read from a compile for a v5e at 16 layers: 4 GB a pool)
-    return jnp.take(pool.reshape(L * P * page, *pool.shape[3:]),
-                    (layer * P + pages) * page + idx % page, axis=0)
+# K bytes (and as many of V) a step of the decode kernel takes
+_STEP_BYTES = 512 * 1024
+
+
+def decode_attention_tpu(q, pool_k, pool_v, layer, tables, lengths, mask, *,
+                         scale, interpret=False):
+    """The kernel: grid (slot,); a slot's program walks the steps its
+    length needs and no more. A step copies as many of the slot's pages
+    of K and of V as hold ``_STEP_BYTES`` straight from the pools where
+    they lie (the page's index from the block table, prefetched as
+    scalars; the next step's pages are on their way while this one's are
+    multiplied), reads the mask's row for them and keeps a float32
+    online softmax. A slot that does not decode (length 0) copies
+    nothing and computes nothing."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, hd = q.shape
+    page, kvh = pool_k.shape[2:4]
+    n = tables.shape[1]
+    # a power of two of pages, so that it divides the table's bucket
+    most = max(1, _STEP_BYTES // (page * kvh * hd * pool_k.dtype.itemsize))
+    pages = _block(n, 1 << most.bit_length() - 1, 1)
+    # a chosen row's KV head beside every (position, KV head) row of the
+    # pages, a page's in a row; -1 where the position is not chosen or
+    # not the slot's. Spread by a product (a position's 0 or 1 times its
+    # KV heads' numbers from 1, exact in any precision): as a broadcast
+    # XLA interleaves lanes, 0.06 to 0.10 ms a layer where the kernel
+    # takes 0.08 to 0.14 (PR 44, chip call 2)
+    live = (mask > 0) & (jnp.arange(n * page)[None, :] < lengths[:, None])
+    spread = np.kron(np.eye(page, dtype=np.float32),
+                     np.arange(1.0, kvh + 1.0, dtype=np.float32)[None, :])
+    seen = (live.astype(jnp.float32).reshape(B * n, page) @ spread).astype(
+        jnp.int32).reshape(B, n, page * kvh) - 1
+    # (position, KV head) as the rows of one matrix a page: the same
+    # bytes as they lie
+    pool_k, pool_v = (pool.reshape(*pool.shape[:2], page * kvh, hd)
+                      for pool in (pool_k, pool_v))
+    o, lse = pl.pallas_call(
+        functools.partial(_decode_kernel, scale=scale, page=page,
+                          pages=pages, group=H // kvh),
+        out_shape=[jax.ShapeDtypeStruct((B, H, hd), jnp.float32),
+                   jax.ShapeDtypeStruct((B, H, 1), jnp.float32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            in_specs=[pl.BlockSpec((None, H, hd), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec((None, *seen.shape[1:]),
+                                   lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec((None, H, hd), lambda b, *_: (b, 0, 0)),
+                       pl.BlockSpec((None, H, 1), lambda b, *_: (b, 0, 0))],
+            grid=(B,),
+            scratch_shapes=[
+                pltpu.VMEM((2, pages * page * kvh, hd), pool_k.dtype),
+                pltpu.VMEM((2, pages * page * kvh, hd), pool_v.dtype),
+                pltpu.SemaphoreType.DMA((2,))]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=_VMEM),
+        interpret=interpret, name=DECODE_KERNEL,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), tables, lengths, q, seen,
+      pool_k, pool_v)
+    return o, lse[..., 0]
+
+
+def decode_attention(q, pool_k, pool_v, layer, tables, lengths, mask, *,
+                     scale: float):
+    """One query a slot over the keys ``mask`` keeps of the slot's own
+    cached pages, read where they lie.
+
+    q [B, H, hd]; pool_k, pool_v [L, P, page, kvh, hd], the whole
+    stacks, and ``layer`` (a traced index) picks its layer in the page's
+    address: no layer is sliced out, no page copied; tables int32 [B, n],
+    a slot's pages in order (unused entries 0: the reserved page, never
+    scored); lengths int32 [B], the positions a slot has cached (0:
+    nothing, and the slot's output is 0 with a log-sum-exp of about
+    -1e30); mask int8 [B, n * page], 1 at the cached keys the slot
+    attends over (``decode_chosen``'s).
+
+    Returns (o float32 [B, H, hd], the softmax's output over the chosen
+    cached keys alone; lse float32 [B, H], its log-sum-exp, by which
+    ``join_new_rows`` joins it with rows the cache does not hold yet)."""
+    with jax.named_scope("rt.attn.sparse"):
+        return jax.lax.platform_dependent(
+            q, pool_k, pool_v, layer, tables, lengths, mask,
+            tpu=functools.partial(decode_attention_tpu, scale=scale),
+            default=functools.partial(decode_attention_xla, scale=scale))
+
+
+def join_new_rows(o_old, lse_old, q, k, v, mask, *, scale: float):
+    """One softmax over (what ``decode_attention`` saw; the rows k, v
+    [B, K, kvh, hd] the cache does not hold yet, under ``mask`` [B, K]).
+    Returns o float32 [B, H, hd]."""
+    B, H, hd = q.shape
+    kvh = k.shape[2]
+    seen = mask[:, None, None, :]
+    with jax.named_scope("rt.attn.sparse"):
+        s = jnp.einsum("bkgd,bnkd->bkgn", q.reshape(B, kvh, H // kvh, hd),
+                       k, preferred_element_type=jnp.float32) * scale
+        s = jnp.where(seen, s, NEG_INF)
+        m = jnp.maximum(lse_old.reshape(B, kvh, -1), s.max(-1))
+        w_old = jnp.exp(lse_old.reshape(m.shape) - m)
+        p = jnp.where(seen, jnp.exp(s - m[..., None]), 0.0)
+        new = jnp.einsum("bkgn,bnkd->bkgd", p, v.astype(jnp.float32))
+        o = (w_old[..., None] * o_old.reshape(B, kvh, -1, hd) + new) / (
+            jnp.maximum(w_old + p.sum(-1), 1e-30)[..., None])
+        return o.reshape(B, H, hd)
 
 
 def decode_chosen(qi, w, pool_i, layer, tables, lengths, new_rows, n_new,
@@ -535,8 +706,8 @@ def decode_chosen(qi, w, pool_i, layer, tables, lengths, new_rows, n_new,
     cached; new_rows [B, K, row], the burst's own indexer rows, of which
     the first ``n_new`` (this step's among them) are visible.
 
-    Returns (idx int32 [B, top_k], ok bool [B, top_k]: the chosen cached
-    positions; own bool [B, K]: the chosen rows of the burst)."""
+    Returns (mask int8 [B, n * page], 1 at the chosen cached positions;
+    own bool [B, K]: the chosen rows of the burst)."""
     B, n = tables.shape
     page = pool_i.shape[2]
     K = new_rows.shape[1]
@@ -550,11 +721,10 @@ def decode_chosen(qi, w, pool_i, layer, tables, lengths, new_rows, n_new,
         qi[:, :, None], w[:, :, None], old,
         jnp.broadcast_to(lengths[:, None], qi.shape[:2]),
         name=INDEX_KERNEL + DECODE).sum(1)
-    pad = (-K) % _COUNT
+    pad = (-K) % 128        # the burst's scores: whole lanes
     new = jnp.pad(new_rows, ((0, 0), (0, pad), (0, 0)))
     s_new = index_scores_xla(qi[:, None], w[:, None], new)[:, 0]
     mask = choose(jnp.concatenate([s_old, s_new], -1), lengths,
                   jnp.broadcast_to(n_new, lengths.shape), n * page,
                   top_k=top_k, name=SELECT_KERNEL + DECODE)
-    idx, ok = chosen_rows(mask[:, :n * page], top_k)
-    return idx, ok, mask[:, n * page:n * page + K] > 0
+    return mask[:, :n * page], mask[:, n * page:n * page + K] > 0

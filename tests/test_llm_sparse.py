@@ -160,6 +160,11 @@ def test_prefill_then_decode_through_the_three_pools(params, chunk):
         n * (n + 1) // 2 for _p, n in spans)
     assert counters["attended_keys"] == sum(
         16 * 17 // 2 + 16 * (n - 16) for _p, n in spans)
+    # a step of a burst walks every page that held a key when the burst
+    # began: bursts of 4 from the prompt's length on, 12 steps in all
+    assert counters["sparse_decode_pages"] == sum(
+        4 * -(-(p + first) // PAGE) for p, _n in spans
+        for first in (0, 4, 8))
 
 
 def test_a_sequence_alone_and_in_a_batch_choose_the_same(params):
@@ -268,7 +273,7 @@ def test_the_seeded_int8_weights_have_the_indexers_leaves():
 def test_the_engine_says_which_attention_each_program_takes(params):
     paths = _engine(params).attention_paths()
     assert "the indexer's choice" in paths["prefill"]
-    assert "the chosen K and V rows gathered" in paths["decode_burst"]
+    assert "own K and V pages where they lie" in paths["decode_burst"]
 
 
 @pytest.mark.parametrize("program, spans", [

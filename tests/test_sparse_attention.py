@@ -192,16 +192,100 @@ def test_prefill_kernel_is_its_plain_twin():
     assert not np.asarray(got[7]).any()        # a row that keeps no key
 
 
-def test_chosen_rows_counts_the_mask_out():
-    rng = np.random.default_rng(3)
-    mask = np.zeros((3, 200), np.int8)
-    mask[0, rng.choice(200, TOP, replace=False)] = 1
-    mask[1, [0, 5, 199]] = 1                    # fewer than top_k
-    idx, ok = sparse.chosen_rows(jnp.asarray(mask), TOP)
-    for row in range(3):
-        want = np.nonzero(mask[row])[0]
-        assert np.asarray(ok[row]).sum() == len(want)
-        assert (np.asarray(idx[row])[:len(want)] == want).all()
+def _softmax_over(q, keys, values, scale):
+    """One softmax a head over ``keys`` [m, KVH, HD], written out in
+    NumPy: (o [H, HD], lse [H]); no key: zeros and -inf."""
+    q, keys, values = (np.asarray(a, np.float64) for a in (q, keys, values))
+    o, lse = np.zeros(q.shape), np.full(q.shape[0], -np.inf)
+    for h in range(q.shape[0] if len(keys) else 0):
+        s = keys[:, h // (H // KVH)] @ q[h] * scale
+        lse[h] = s.max() + np.log(np.exp(s - s.max()).sum())
+        o[h] = np.exp(s - lse[h]) @ values[:, h // (H // KVH)]
+    return o, lse
+
+
+DECODE_PAGE, DECODE_SPAN = 8, 8         # 8 pages of 8 positions a slot
+
+
+def _random_mask(rng, lengths, ones):
+    mask = np.zeros((len(lengths), DECODE_PAGE * DECODE_SPAN), np.int8)
+    for b, n in enumerate(lengths):
+        mask[b, rng.choice(n, min(ones, n), replace=False)] = 1
+    return mask
+
+
+def _whole_pages_skipped(rng, lengths, ones):
+    """Ones in the slot's pages 1 and 4 alone (and never past its end)."""
+    mask = np.zeros((len(lengths), DECODE_PAGE * DECODE_SPAN), np.int8)
+    mask[:, 8:16] = mask[:, 32:40] = 1
+    return mask * (np.arange(mask.shape[1])[None] < np.asarray(
+        lengths)[:, None])
+
+
+@pytest.mark.parametrize("lengths, mask_of, layer, own", [
+    ((37, 50, 21), _random_mask, 0, 0),         # ends inside a page
+    ((32, 64, 8), _random_mask, 0, 0),          # on a page's edge
+    ((45, 0, 0), _random_mask, 0, 0),           # slots that do not decode
+    ((10, 64, 5), _random_mask, 0, 0),          # fewer than top_k: all seen
+    ((64, 47, 12), _whole_pages_skipped, 0, 0),
+    ((37, 50, 21), _random_mask, 1, 0),         # a traced layer other than 0
+    ((37, 50, 0), _random_mask, 1, 1),          # joined with the burst's rows
+    ((64, 29, 9), _whole_pages_skipped, 0, 3),
+])
+def test_decode_kernel_is_its_plain_twin_and_a_softmax_written_out(
+        monkeypatch, lengths, mask_of, layer, own):
+    """The decode product over each slot's own pages where they lie, the
+    kernel's body interpreted (two pages a step: four steps a slot),
+    against its plain twin and against a softmax written out in NumPy
+    over the mask's ones. A table's unused entries are page 0, whose
+    rows are random here and must not be read into any sum; with ``own``
+    burst rows the join by the log-sum-exp, one of them not chosen."""
+    monkeypatch.setattr(sparse, "_STEP_BYTES",
+                        2 * DECODE_PAGE * KVH * HD * 4)
+    rng = np.random.default_rng(sum(lengths) + layer + own)
+    B, span = len(lengths), DECODE_PAGE * DECODE_SPAN
+    P = 1 + B * DECODE_SPAN
+    pool_k, pool_v = (jnp.asarray(rng.standard_normal(
+        (2, P, DECODE_PAGE, KVH, HD)), jnp.float32) for _ in range(2))
+    tables = rng.permutation(np.arange(1, P)).reshape(B, -1).astype(np.int32)
+    for b, n in enumerate(lengths):
+        tables[b, -(-n // DECODE_PAGE):] = 0
+    mask = mask_of(rng, lengths, TOP)
+    q = jnp.asarray(rng.standard_normal((B, H, HD)), jnp.float32)
+    args = (q, pool_k, pool_v, jnp.int32(layer), jnp.asarray(tables),
+            jnp.asarray(lengths, jnp.int32), jnp.asarray(mask))
+    scale = HD ** -0.5
+    got = jax.jit(functools.partial(
+        sparse.decode_attention_tpu, scale=scale, interpret=True))(*args)
+    twin = sparse.decode_attention_xla(*args, scale=scale)
+    new_k, new_v = (rng.standard_normal((B, 4, KVH, HD)).astype(np.float32)
+                    for _ in range(2))
+    chosen = np.zeros((B, 4), bool)
+    chosen[:, :own] = True
+    chosen[:, own // 2] = False                 # one of three is not chosen
+    if own:
+        got, twin = (sparse.join_new_rows(
+            o, lse, q, jnp.asarray(new_k), jnp.asarray(new_v),
+            jnp.asarray(chosen), scale=scale) for o, lse in (got, twin))
+    for b, n in enumerate(lengths):
+        at = np.nonzero(mask[b, :n])[0]
+        keys, values = (np.concatenate([
+            np.asarray(pool)[layer, tables[b, at // DECODE_PAGE],
+                             at % DECODE_PAGE], new[b, chosen[b]]])
+            for pool, new in ((pool_k, new_k), (pool_v, new_v)))
+        o, lse = _softmax_over(q[b], keys, values, scale)
+        if own:
+            np.testing.assert_allclose(got[b], o, rtol=1e-5, atol=1e-5)
+            np.testing.assert_allclose(twin[b], o, rtol=1e-5, atol=1e-5)
+            continue
+        for have in (got, twin):
+            np.testing.assert_allclose(have[0][b], o, rtol=1e-5, atol=1e-5)
+            if len(at):
+                np.testing.assert_allclose(have[1][b], lse, rtol=1e-5)
+            else:           # a slot that does not decode
+                assert not np.asarray(have[0][b]).any()
+                assert (np.asarray(have[1][b]) < -1e29).all()
+    assert mask[:, :span].sum() > 0
 
 
 def _paged(rows, tables, pages, page):
@@ -219,8 +303,6 @@ def test_decode_over_pages_is_prefills_last_row(new_rows):
     """One query a slot over its own pages (listed out of order), the
     last ``new_rows`` keys not yet in the cache but in the burst's
     scratch: the row whole-prompt attention gives that query."""
-    from ray_tpu.llm.runner import _attend
-
     page, S, B = 8, 64, 2
     x = _inputs(7, S, S, batch=B)
     lim = jnp.broadcast_to(_limits(S, S)[None], (B, S))
@@ -239,16 +321,18 @@ def test_decode_over_pages_is_prefills_last_row(new_rows):
                        + ((0, 0),) * (a.ndim - 2))
                for a in (x["k"], x["v"], jnp.pad(
                    x["ki"], ((0, 0), (0, 0), (0, 128 - D))))]
-    idx, ok, own = sparse.decode_chosen(
+    lengths = jnp.full((B,), cached, jnp.int32)
+    chosen, own = sparse.decode_chosen(
         jnp.pad(x["qi"][:, -1], ((0, 0), (0, 0), (0, 128 - D))),
         x["w"][:, -1], pools[2], jnp.int32(0), jnp.asarray(tables),
-        jnp.full((B,), cached, jnp.int32), scratch[2], jnp.int32(new_rows),
-        top_k=TOP)
-    assert (np.asarray(ok).sum(-1) + np.asarray(own).sum(-1) == TOP).all()
-    gk, gv = (sparse.gather_rows(pool, jnp.int32(0), jnp.asarray(tables),
-                                 idx, page) for pool in pools[:2])
-    got = _attend(x["q"][:, -1], (gk, gv, ok),
-                  (scratch[0], scratch[1], own))
+        lengths, scratch[2], jnp.int32(new_rows), top_k=TOP)
+    assert (np.asarray(chosen).sum(-1) + np.asarray(own).sum(-1)
+            == TOP).all()
+    o, lse = sparse.decode_attention(
+        x["q"][:, -1], pools[0], pools[1], jnp.int32(0),
+        jnp.asarray(tables), lengths, chosen, scale=HD ** -0.5)
+    got = sparse.join_new_rows(o, lse, x["q"][:, -1], scratch[0],
+                               scratch[1], own, scale=HD ** -0.5)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 

@@ -720,12 +720,36 @@ def test_sparse_kernels_compile_alone(one_chip):
         _sds((T,), i32, one_chip)) == 1
 
 
+@pytest.mark.parametrize("span", [32, 512])
+def test_sparse_decode_product_compiles_alone(one_chip, span):
+    """Mosaic takes the decode product at the cell's pools (pages of 64
+    positions x 4 KV heads of 128, 8 pages of K and of V a step, copied
+    by the kernel itself) at the engine's smallest and largest table
+    span, and the pools reach it as they lie: their reshape to (position,
+    KV head) rows is a bitcast, nothing of a pool's size is made."""
+    from ray_tpu.ops import sparse_attention as sparse
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    pool = _sds((16, 3585, 64, 4, 128), bf16, one_chip)
+    compiled = jax.jit(functools.partial(
+        sparse.decode_attention, scale=128 ** -0.5)).lower(
+        _sds((8, 32, 128), bf16, one_chip), pool, pool,
+        _sds((), i32, one_chip), _sds((8, span), i32, one_chip),
+        _sds((8,), i32, one_chip),
+        _sds((8, span * 64), jnp.int8, one_chip)).compile()
+    text = compiled.as_text()
+    assert "%" + sparse.DECODE_KERNEL in text
+    assert not [line for line in text.splitlines()
+                if " = bf16[16,3585," in line and " copy(" in line]
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 1024**2
+
+
 def test_sparse_decode_burst_reads_the_pools_where_they_lie(one_chip):
     """A burst of a configuration with an indexer copies no K or V page
-    and turns no pool into another layout: the scores and the choice are
-    the two decode kernels, the chosen rows one gather out of each pool
-    as it lies, the burst's rows written by slices (the scatter over all
-    16 layers x 4 heads x 128 copied each pool layers-inward and back)."""
+    and turns no pool into another layout: the scores, the choice and
+    the product over each slot's own pages are the three decode kernels,
+    the burst's rows written by slices (the scatter over all 16 layers x
+    4 heads x 128 copied each pool layers-inward and back)."""
     import json
     import os
 
@@ -757,7 +781,8 @@ def test_sparse_decode_burst_reads_the_pools_where_they_lie(one_chip):
         table, _sds((), jnp.int32, one_chip), pools[2], cfg=cfg, n_steps=8,
         greedy=True).compile()
     text = compiled.as_text()
-    for kernel in ("rt_sparse_index_decode", "rt_sparse_select_decode"):
+    for kernel in ("rt_sparse_index_decode", "rt_sparse_select_decode",
+                   "rt_sparse_attend_decode"):
         assert "%" + kernel in text, kernel
     for pool in pools:
         shape = "bf16[" + ",".join(map(str, pool.shape)) + "]"
