@@ -46,6 +46,7 @@ from __future__ import annotations
 
 from collections import OrderedDict
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -62,6 +63,11 @@ class KVCache:
     k: Any  # [L, num_pages, page_size, kv_heads, head_dim] (a group)
     v: Any  # None for a latent configuration: k holds its rows
     i: Any = None  # [L, num_pages, page_size, indexer_row]: an indexer's
+    # [L, num_pages, strides a page, kv_heads, head_dim] float32: the
+    # block layers' sums of keys (compressed keys' halves)
+    c: Any = None
+    # [linear layers, slots, heads, head_dim, head_dim] float32
+    s: Any = None
 
     @property
     def num_pages(self) -> int:
@@ -80,10 +86,43 @@ def window_group_pages(slots: int, window: int, page_size: int,
     return slots * -(-(window + page_size + burst) // page_size) + 1
 
 
-def init_kv_cache(cfg, num_pages, page_size: int, dtype=None) -> KVCache:
+@partial(jax.jit, donate_argnums=(0,))
+def zero_slot_state(pool, slot):
+    """The state pool with ``slot``'s states of every layer zeroed, in
+    place (the pool is donated)."""
+    return jax.lax.dynamic_update_slice_in_dim(
+        pool, jnp.zeros((pool.shape[0], 1, *pool.shape[2:]), pool.dtype),
+        slot, 1)
+
+
+def init_kv_cache(cfg, num_pages, page_size: int, dtype=None,
+                  slots: int = 0) -> KVCache:
     """``num_pages``: an int for a one-group configuration, else one
-    number a group of ``cfg.kv_groups``."""
+    number a group of ``cfg.kv_groups``. ``slots``: the rows of the
+    state pool, for a configuration with linear layers."""
     dtype = dtype or cfg.dtype
+    if cfg.own_weights:
+        if page_size != cfg.block_size:
+            raise ValueError(
+                f"page_size={page_size} with block_size={cfg.block_size}: "
+                f"a block that is chosen is a page that is read, so they "
+                f"are equal")
+        if not isinstance(num_pages, int) or slots < 1:
+            raise ValueError("a configuration with linear layers has one "
+                             "layer group of pages, and a state a slot")
+        L, hd = cfg.n_kv_layers, cfg.head_dim
+        # a page's (position, KV head) rows as ONE matrix, the form
+        # ``rt_sparse_attend_decode`` multiplies: with 2 KV heads a
+        # [.., page, 2, hd] pool is tiled (2, 128) and its reshape to
+        # rows is a copy of the whole pool in every layer of every step
+        # (read from a compile for a v5e, PR 46)
+        shape = (L, num_pages, page_size * cfg.n_kv_heads, hd)
+        return KVCache(
+            jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
+            c=jnp.zeros((L, num_pages, page_size // cfg.block_stride,
+                         cfg.n_kv_heads, hd), jnp.float32),
+            s=jnp.zeros((cfg.n_linear_layers, slots, cfg.linear_heads,
+                         hd, hd), jnp.float32))
     if cfg.latent:
         if not isinstance(num_pages, int):
             raise ValueError("a latent configuration has one layer group")

@@ -48,6 +48,7 @@ from ..ops.attention import attention_path
 from ..ops.moe import chosen_tiles
 from ..util import tracing
 from .cache import (KVCache, PageAllocator, PrefixCache, SequenceTable,
+                    zero_slot_state,
                     init_kv_cache, window_group_pages)
 from .runner import (decode_burst, prefill_bucket, prefill_sample,
                      verify_step)
@@ -204,13 +205,16 @@ class LLMEngine:
             self._refuse_with_latent()
         if cfg.sparse_top_k:
             self._refuse_with_indexer()
+        if cfg.own_weights:
+            self._refuse_with_state_layers()
         pool_pages = [
             self.ecfg.num_pages if w is None else window_group_pages(
                 self.ecfg.max_num_seqs, w, self.ecfg.page_size,
                 self.ecfg.decode_burst) for w in self.windows]
         self.cache = init_kv_cache(
             cfg, pool_pages if grouped else pool_pages[0],
-            self.ecfg.page_size, self.ecfg.kv_dtype)
+            self.ecfg.page_size, self.ecfg.kv_dtype,
+            slots=self.ecfg.max_num_seqs)
         self.allocators = [PageAllocator(n, self.ecfg.page_size)
                            for n in pool_pages]
         self.allocator = self.allocators[0]
@@ -327,11 +331,24 @@ class LLMEngine:
             # the pages of K (and as many of V) the bursts' steps walked:
             # every decoding slot's cached pages, once a step run
             self._counters["sparse_decode_pages"] = 0
-        # what one cached position holds, all layers
+        if cfg.own_weights:
+            # slots whose state was zeroed at an admission; bytes of state
+            # the bursts' steps read and wrote (every decoding slot's,
+            # once each a step); queries, prefilled and decoded, below
+            # ``block_dense_len`` (every visible key attended); for the
+            # rest the blocks a query could see and scored and the blocks
+            # it attended over (a block layer and KV head); the pages of
+            # K (and as many of V) the bursts' steps read (a block layer
+            # and KV head)
+            self._counters.update(
+                state_slots_reset=0, state_bytes_step=0, dense_queries=0,
+                scored_blocks=0, chosen_blocks=0, block_decode_pages=0)
+        # what one cached position holds, all layers (a position's share
+        # of its page's sums of strides among it)
         self._counters["kv_bytes_per_token"] = int(sum(
-            a.size // (a.shape[1] * a.shape[2]) * a.dtype.itemsize
+            a.size // (a.shape[1] * self.ecfg.page_size) * a.dtype.itemsize
             for a in jax.tree.leaves(
-                (self.cache.k, self.cache.v, self.cache.i))))
+                (self.cache.k, self.cache.v, self.cache.i, self.cache.c))))
         # expert counts of chunked-prefill dispatches nobody waited for
         # yet: read back with the next sampled tokens
         self._pending_counts: List[Any] = []
@@ -347,7 +364,8 @@ class LLMEngine:
         """A burst copies no page: it reads each slot's own pages through
         its table (latent rows; an indexer's rows, and K and V under its
         choice)."""
-        return self.cfg.latent or self.cfg.sparse_top_k > 0
+        return (self.cfg.latent or self.cfg.sparse_top_k > 0
+                or self.cfg.own_weights)
 
     def attention_paths(self) -> Dict[str, str]:
         """Which implementation each program's attention takes on this
@@ -367,6 +385,20 @@ class LLMEngine:
                     "decode_burst": "pallas rt_mla_decode (absorbed, each "
                     "slot's own pages)" if on_tpu else gathered}
         listed = "xla (over the gathered pages)"
+        if self.cfg.own_weights:
+            return {
+                "prefill": f"linear layers: "
+                f"{'pallas rt_linear_prefill' if on_tpu else 'xla'} (chunked "
+                f"form); block layers: {prefill} below block_dense_len, then "
+                f"{'pallas rt_block_score, rt_sparse_select, flash_block_sparse_fwd' if on_tpu else 'xla'}"
+                " (the chosen blocks of the prompt's rows)",
+                "prefill_chunk": "the same, over the gathered pages; the "
+                "state carried through its pool",
+                "verify_step": "refused (a state cannot be rolled back)",
+                "decode_burst": (
+                    "pallas rt_linear_decode (each live slot's state, in "
+                    "place), rt_block_score, rt_sparse_attend_decode (the "
+                    "chosen pages where they lie)" if on_tpu else "xla")}
         if self.cfg.sparse_top_k:
             chosen = ("pallas rt_sparse_index, rt_sparse_select, "
                       "flash_sparse_fwd (the indexer's choice, over the %s)"
@@ -439,6 +471,53 @@ class LLMEngine:
                     f"EngineConfig.{option} is not supported with an "
                     f"indexer (sparse_top_k={self.cfg.sparse_top_k}): {why}")
 
+    def _refuse_with_state_layers(self) -> None:
+        """What a cache with a state a slot beside its pages cannot do
+        yet (ROADMAP M3), refused by the option's name before anything is
+        built (``speculation``: by ``enable_speculation``, whoever calls
+        it; KV hand-over: by ``_refuse_kv_transfer``)."""
+        reasons = {
+            "enable_prefix_caching": (
+                self.ecfg.enable_prefix_caching,
+                "a cached page says nothing of a linear layer's state at "
+                "its end (snapshots of the state at page boundaries: "
+                "ROADMAP M3)"),
+            "lora_rank": (
+                self.ecfg.lora_rank > 0,
+                "adapters ride ONE scan over layers of one stack, and the "
+                "linear and block layers have a stack each"),
+        }
+        for option, (asked, why) in reasons.items():
+            if asked:
+                raise ValueError(
+                    f"EngineConfig.{option} is not supported with state "
+                    f"layers (linear_heads={self.cfg.linear_heads}): {why}")
+
+    def _count_blocks(self, start: int, end: int, decode: bool) -> None:
+        """The queries at positions [start, end) of one sequence, for
+        ``dense_queries``, ``scored_blocks``, ``chosen_blocks`` and, in a
+        burst, ``block_decode_pages``: a query at t below
+        ``block_dense_len`` attends over every visible key; another sees
+        t // block + 1 blocks and attends over at most ``block_topk``, in
+        every block layer and KV head."""
+        cfg = self.cfg
+        if not cfg.own_weights:
+            return
+        each = cfg.n_kv_layers * cfg.n_kv_heads
+        dense = max(0, min(end, cfg.block_dense_len) - start)
+        self._counters["dense_queries"] += dense
+        seen = np.arange(start + dense, end) // cfg.block_size + 1
+        self._counters["scored_blocks"] += each * int(seen.sum())
+        self._counters["chosen_blocks"] += each * int(
+            np.minimum(seen, cfg.block_topk).sum())
+        if decode:
+            # the pages that hold the slot's cached positions: all of them
+            # below dense_len, then at most block_topk
+            cached = -(-start // cfg.block_size)
+            self._counters["block_decode_pages"] += each * (
+                dense * cached + (end - start - dense) * min(
+                    cached, cfg.block_topk))
+
     def _count_keys(self, start: int, end: int) -> None:
         """The queries at positions [start, end) of one sequence, for
         ``scored_keys`` and ``attended_keys``: the query at position t
@@ -459,20 +538,28 @@ class LLMEngine:
         """A runner program on this engine's pools, which come back as
         the cache: (what the program returns before its pools ..., its
         expert counts)."""
-        cache_i = self.cache.i
-        if cache_i is None:
-            # what every other configuration's programs were always handed
-            out = program(self.params, self.cache.k, self.cache.v, *args,
-                          **kwargs)
-        else:
-            # behind the counts: the indexer's pool
-            *out, cache_i = program(self.params, self.cache.k, self.cache.v,
-                                    *args, cache_i=cache_i, **kwargs)
-        *out, cache_k, cache_v, counts = out
-        self.cache = KVCache(cache_k, cache_v, cache_i)
+        cache = self.cache
+        # behind the counts: the indexer's pool; the sums of strides and
+        # the state pool. None: what every other configuration's programs
+        # were always handed
+        more = {name: pool for name, pool in (
+            ("cache_i", cache.i), ("cache_c", cache.c), ("cache_s", cache.s))
+            if pool is not None}
+        out = list(program(self.params, cache.k, cache.v, *args, **more,
+                           **kwargs))
+        back = dict(zip(more, out[len(out) - len(more):]))
+        *out, cache_k, cache_v, counts = out[:len(out) - len(more)]
+        self.cache = KVCache(cache_k, cache_v, back.get("cache_i"),
+                             back.get("cache_c"), back.get("cache_s"))
         return (*out, counts)
 
     def _refuse_kv_transfer(self, what: str) -> None:
+        if self.cfg.own_weights:
+            raise ValueError(
+                f"{what} is not supported with state layers: a KV payload "
+                f"is a K and a V stack of pages for all layers, and the "
+                f"linear layers' memory is a state a slot that no page "
+                f"holds")
         if self.cfg.sparse_top_k:
             raise ValueError(
                 f"{what} is not supported with an indexer: a KV payload "
@@ -548,6 +635,12 @@ class LLMEngine:
         drafter's random init (a trained 400m draft checkpoint)."""
         from .spec_decode import SpecDecoder
 
+        if self.cfg.own_weights:
+            raise ValueError(
+                "EngineConfig.speculation is not supported with state "
+                "layers: verify_step would have to roll a slot's state "
+                "back behind a rejected window, and the state keeps no "
+                "token apart")
         if self.cfg.latent:
             raise ValueError(
                 "EngineConfig.speculation is not supported with latent "
@@ -756,6 +849,11 @@ class LLMEngine:
         if not state.admit_t:
             state.admit_t = time.perf_counter()
         self.slots[slot] = state
+        if self.cache.s is not None:
+            # the slot's state of every linear layer starts from zero
+            # (a resumed request too: it prefills again)
+            self.cache.s = zero_slot_state(self.cache.s, jnp.int32(slot))
+            self._counters["state_slots_reset"] += 1
         self.seq_table.assign(slot, pages)
         for allocator, table, first in list(zip(
                 self.allocators, self.seq_tables, firsts))[1:]:
@@ -1007,8 +1105,9 @@ class LLMEngine:
                 jnp.asarray(tokens), jnp.asarray([L], jnp.int32),
                 self._rows(state.slot),
                 self.cos, self.sin, seed, temp, top_k, top_p, lora,
-                cfg=self.cfg, greedy=greedy)
+                **self._slot_of(state), cfg=self.cfg, greedy=greedy)
         self._count_keys(0, L)
+        self._count_blocks(0, L, False)
         state.ctx_len = L
         self._counters["prefills"] += 1
         self._counters["prefill_tokens"] += L
@@ -1019,6 +1118,13 @@ class LLMEngine:
             state.first_token_t = time.perf_counter()
         with self._phase("append"):
             return [self._append_token(state, tok)]
+
+    def _slot_of(self, state: RequestState) -> Dict[str, Any]:
+        """The keyword by which a prefill learns whose state it writes;
+        nothing for a configuration without state layers."""
+        if self.cache.s is None:
+            return {}
+        return {"slots": jnp.asarray([state.slot], jnp.int32)}
 
     def compile_prefill(self, prompt_len: int):
         """``(bucket, compiled)``: the whole-prompt prefill program a
@@ -1034,14 +1140,17 @@ class LLMEngine:
             return jax.ShapeDtypeStruct((1,), dtype)
 
         bucket = prefill_bucket(prompt_len, self.ecfg.max_seq_len)
-        params, ck, cv, ci, cos, sin = jax.tree.map(
+        params, ck, cv, ci, cc, cs, cos, sin = jax.tree.map(
             abstract, (self.params, self.cache.k, self.cache.v,
-                       self.cache.i, self.cos, self.sin))
+                       self.cache.i, self.cache.c, self.cache.s, self.cos,
+                       self.sin))
         return bucket, prefill_sample.lower(
             params, ck, cv, jax.ShapeDtypeStruct((1, bucket), jnp.int32),
             row(jnp.int32), jax.tree.map(abstract, self._rows(0)),
             cos, sin, 0, row(jnp.float32), row(jnp.int32),
-            row(jnp.float32), None, ci, cfg=self.cfg, greedy=True).compile()
+            row(jnp.float32), None, ci, cc, cs,
+            None if cs is None else row(jnp.int32), cfg=self.cfg,
+            greedy=True).compile()
 
     def _run_prefill_chunk(self, state: RequestState, seq: List[int],
                            L: int, C: int) -> List[StepOutput]:
@@ -1063,8 +1172,9 @@ class LLMEngine:
             logits, counts = self._run(
                 prefill_chunk,
                 jnp.asarray(tokens), jnp.int32(start), jnp.int32(n), bt,
-                self.cos, self.sin, cfg=self.cfg)
+                self.cos, self.sin, **self._slot_of(state), cfg=self.cfg)
         self._count_keys(start, start + n)
+        self._count_blocks(start, start + n, False)
         if counts is not None:
             self._pending_counts.append(counts)
         state.prefill_pos = start + n
@@ -1216,6 +1326,11 @@ class LLMEngine:
                     c["gathered_pages"] += sum(pages)
                 if self.cfg.sparse_top_k:
                     counters["sparse_decode_pages"] += K * sum(pages)
+                if self.cfg.own_weights:
+                    # a step reads and writes every decoding slot's state
+                    counters["state_bytes_step"] += (
+                        2 * K * len(active_states)
+                        * self.cfg.state_bytes_per_slot)
             else:
                 for g, window in enumerate(self.windows):
                     held = []
@@ -1249,6 +1364,7 @@ class LLMEngine:
                 n_steps=self.ecfg.decode_burst, greedy=greedy)
         for s in active_states:
             self._count_keys(s.ctx_len, s.ctx_len + K)
+            self._count_blocks(s.ctx_len, s.ctx_len + K, True)
         with self._phase("decode.sync"):
             sampled = self._read_back(toks, counts)  # [K, B]
         outs = []
@@ -1613,6 +1729,11 @@ class LLMEngine:
             state.admit_t = state.prefill_start_t = state.first_token_t = (
                 time.perf_counter())
         self.slots[slot] = state
+        if self.cache.s is not None:
+            # the slot's state of every linear layer starts from zero
+            # (a resumed request too: it prefills again)
+            self.cache.s = zero_slot_state(self.cache.s, jnp.int32(slot))
+            self._counters["state_slots_reset"] += 1
         self.seq_table.assign(slot, pages)
         if self.prefix_cache is not None:
             # shipped pages double as prefix-cache warmth: register the
@@ -1666,6 +1787,11 @@ class LLMEngine:
         }
         if self.spec is not None:
             out["spec"] = self.spec.stats()
+        if self.cfg.own_weights:
+            # what a cached position holds in the block layers' pools,
+            # and what a slot holds in the linear layers' state pool
+            out["kv_bytes_per_token"] = self._counters["kv_bytes_per_token"]
+            out["state_bytes_per_slot"] = self.cfg.state_bytes_per_slot
         # the grouped expert kernel's tiles for this configuration's
         # product shapes, chosen where its programs were traced; none
         # without experts

@@ -89,6 +89,33 @@ and the two gathers, PR 44). Whole prompts and bursts are written into
 the three pools by loops of slices (``_write_latent_pages``,
 ``_write_slices``), not by the scatter, which says why.
 
+State layers (``LlamaConfig.linear_heads``: a ``layer_pattern`` that
+lists every layer, of the kinds "linear" and "block_nope", each with a
+stack of weights of its own: ``params["linear_layers"]``,
+``params["layers"]``). ``_layers`` runs such a pattern a layer at a
+time, each with its weights and its state by its place IN ITS KIND, and
+``attend`` is told the kind (``linear=``, ``block=``). A linear layer
+keeps no key: its memory is a float32 state a slot
+(``ops/linear_attention.py``), a pool ``cache_s`` [linear layers, slots,
+heads, hd, hd] that every program takes as a donated keyword and
+returns last: ``prefill`` runs the chunked form from zeros and puts the
+end state at the slot's place (``slots``), ``prefill_chunk`` carries the
+slot's state through the pool from chunk to chunk, ``decode_burst``
+keeps the pool in the step loop's carry and every step updates the live
+slots' states in place. A block layer's K and V pools are page MATRICES
+[layers, pages, page x kv_heads, hd] (``_pair_rows``: a row's
+"position" is ``position * kv_heads + head``, so the writers serve them
+as they are), and beside them ``cache_c`` holds the float32 sum of the
+keys of every ``block_stride`` positions, of which a compressed key is
+the mean of two neighbours: a query below ``block_dense_len`` attends
+over every visible key (the flash forward), one above over the tokens
+of the blocks it chooses (``sparse.block_attend``); a decode step scores
+the sums of its slot's pages (gathered once a burst) and the burst's own
+keys, lists the pages chosen, a row a (slot, KV head), and reads those
+pages and no other where they lie (``sparse.block_decode_attention``).
+``verify_step`` refuses the kinds by name: a rejected window would have
+to roll a state back.
+
 Leading dense layers (``LlamaConfig.n_dense_layers``) are their own
 stack ``params["dense_layers"]``: ``_layers`` scans them first, with
 the same block and the dense feed-forward, then the expert layers; the
@@ -111,8 +138,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ..models.llama import LlamaConfig, qk_norm, rotated, windowed
+from ..models.llama import LlamaConfig, linear, qk_norm, rotated, windowed
 from ..ops import apply_rotary, attention, mla, rms_norm
+from ..ops import linear_attention
 from ..ops import sparse_attention as sparse
 from ..ops.moe import router_logits
 from ..ops.quant import embed_lookup, is_quantized, weight_einsum
@@ -122,7 +150,7 @@ from .sampling import sample_from_logits
 
 _EXPERT_STACKS = ("w_gate", "w_up", "w_down")
 # donated: the scatters run in place
-_POOLS = ("cache_k", "cache_v", "cache_i")
+_POOLS = ("cache_k", "cache_v", "cache_i", "cache_c", "cache_s")
 
 
 def _split_layers(layers, cfg: LlamaConfig):
@@ -198,6 +226,14 @@ def _mlp(h, lp, cfg: LlamaConfig, valid=None, experts=None, logits=None):
                          lp["w_down"]), None
 
 
+def _embed(params, tokens, cfg: LlamaConfig):
+    """The tokens' rows of the table, times ``cfg.embed_scale``."""
+    x = embed_lookup(params["embed"], tokens, cfg.dtype)
+    if cfg.embed_scale != 1.0:
+        x = (x.astype(jnp.float32) * cfg.embed_scale).astype(x.dtype)
+    return x
+
+
 def _total(counts):
     """Per-layer (or per-step) expert counts stacked by a scan -> their
     sum; None stays None (a dense config counts nothing)."""
@@ -209,6 +245,8 @@ def _head(x, params, cfg: LlamaConfig):
     int8 lm_head. bf16 operands on the MXU with f32 accumulation either
     way."""
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    if cfg.logit_divisor != 1.0:
+        x = (x.astype(jnp.float32) / cfg.logit_divisor).astype(x.dtype)
     lm = params["lm_head"]
     if not is_quantized(lm):
         lm = lm.astype(cfg.dtype)
@@ -335,6 +373,46 @@ def _write_latent_pages(pool, rows, table, prompt_lens):
             pool, jnp.where(ok, new, old), at)
 
     return (jax.lax.fori_loop(0, pages.shape[1], write, pool),)
+
+
+def _pair_rows(rows):
+    """K or V rows [..., S, kvh, hd] as the rows of the page matrices a
+    configuration with state layers keeps (llm/cache.py): [..., S * kvh,
+    hd], a (position, KV head) pair a row, position-major. Such a row's
+    "position" is ``position * kvh + head`` and a page holds ``page_size
+    * kvh`` of them, so the writers below serve them as they are."""
+    return rows.reshape(*rows.shape[:-3], -1, rows.shape[-1])
+
+
+def _pair_positions(positions, valid, kvh: int):
+    """(positions, valid) [B, S] of tokens -> those of their (position,
+    KV head) rows [B, S * kvh] (``_pair_rows``)."""
+    at = positions[..., None] * kvh + jnp.arange(kvh)
+    ok = jnp.broadcast_to(jnp.broadcast_to(valid, positions.shape)[..., None],
+                          at.shape)
+    return at.reshape(*positions.shape[:-1], -1), ok.reshape(
+        *positions.shape[:-1], -1)
+
+
+def _add_to_sums(pool, rows, table, positions, written, stride: int):
+    """A burst's keys added to the sums of the strides they fall in.
+    pool float32 [L, P, per, kvh, hd] (llm/cache.py ``KVCache.c``); rows
+    [L, B, K, kvh, hd], the burst's keys, row r of slot b at position
+    ``positions[b] + r``; written bool [B, K]. A stride's sum is of the
+    positions that are cached: one that begins at or behind the slot's
+    old length starts from nothing, whatever the page held before."""
+    K, per = written.shape[1], pool.shape[2]
+    touched = (K - 1) // stride + 2
+    m = (positions // stride)[:, None] + jnp.arange(touched)[None, :]
+    at = positions[:, None] + jnp.arange(K)[None, :]
+    mine = ((at // stride)[:, :, None] == m[:, None, :]) & written[..., None]
+    add = jnp.einsum("bkn,lbkgd->lbngd", mine.astype(jnp.float32),
+                     rows.astype(jnp.float32), precision="highest")
+    page = jnp.take_along_axis(
+        table, jnp.clip(m // per, 0, table.shape[1] - 1), axis=1)
+    old = jnp.where((m * stride < positions[:, None])[None, ..., None, None],
+                    pool[:, page, m % per], 0.0)
+    return _write_rows((pool,), (old + add,), table, m, mine.any(1))[0]
 
 
 def _take_span(pool, block_tables):
@@ -499,6 +577,19 @@ def _heads(h, lp, lr, state, *, cfg: LlamaConfig, kind, cos, sin, positions,
     if rotated(kind):
         q = apply_rotary(q, cos, sin, positions=positions)
         k = apply_rotary(k, cos, sin, positions=positions)
+    if cfg.own_weights:
+        if linear(kind):
+            # the closure opens rt.attn.linear; ``state`` is the layer's
+            # place among the linear layers. The output norm over all heads
+            o, kept = attend(q, k, v, state, None, linear=True)
+            o = rms_norm(o.reshape(*o.shape[:2], -1), lp["o_norm"],
+                         cfg.norm_eps).reshape(o.shape)
+        else:
+            # rt.attn.block.score, rt.attn.select and rt.attn.sparse
+            o, kept = attend(q, k, v, state, None, block=True)
+        gate = weight_einsum("bsd,dhk->bshk", h, lp["wg"],
+                             preferred_element_type=jnp.float32)
+        return o * jax.nn.sigmoid(gate), kept
     if cfg.sparse_top_k:
         # the closure opens rt.attn.select and rt.attn.sparse
         return attend(q, k, v, state, None,
@@ -557,10 +648,17 @@ def _block(x, inputs, *, cfg: LlamaConfig, kind, cos, sin, positions, valid,
         o, kept = _heads(h, lp, lr, state, cfg=cfg, kind=kind, cos=cos,
                          sin=sin, positions=positions, attend=attend,
                          lora_scale=lora_scale)
-    x = x + weight_einsum("bshk,hkd->bsd", o.astype(x.dtype), lp["wo"])
+    def added(a):
+        # what a layer's two halves add to the stream, times the scale
+        if cfg.residual_scale == 1.0:
+            return a
+        return (a.astype(jnp.float32) * cfg.residual_scale).astype(x.dtype)
+
+    x = x + added(weight_einsum("bshk,hkd->bsd", o.astype(x.dtype),
+                                lp["wo"]))
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     m, counts = _mlp(h, lp, cfg, valid, experts, logits)
-    return x + m, (kept, counts)
+    return x + added(m), (kept, counts)
 
 
 def _layers(params, cfg: LlamaConfig, cos, sin, lora=None):
@@ -606,6 +704,24 @@ def _layers(params, cfg: LlamaConfig, cos, sin, lora=None):
                         positions=positions, valid=valid, attend=attend,
                         experts=experts,
                         lora_scale=lora["scale"] if lora else None)
+        if cfg.own_weights:
+            # kinds with weights of their own: the layers one after the
+            # other, each with its weights and its state by its place IN
+            # ITS KIND. A linear layer's state is the program's (a pool a
+            # slot, reached through ``attend``), which is told the place
+            kept, place = [], {True: 0, False: 0}
+            for kind in kinds:
+                lin = linear(kind)
+                i = place[lin]
+                place[lin] += 1
+                lp = jax.tree.map(lambda a: a[i], params[
+                    "linear_layers" if lin else "layers"])
+                mine = i if lin or state is None else jax.tree.map(
+                    lambda a: a[i], state[0])
+                x, (rows, _) = block(x, (lp, mine, {}), kind=kind)
+                if not lin:
+                    kept.append(rows)
+            return x, (jax.tree.map(lambda *a: jnp.stack(a), *kept),), None
         if len(kinds) == 1:
             mine = None if state is None else state[0]
             if n_dense:
@@ -661,7 +777,8 @@ def _held(table, positions, valid, page_size: int):
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=_POOLS)
 def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
-            cos, sin, lora=None, cache_i=None, *, cfg: LlamaConfig):
+            cos, sin, lora=None, cache_i=None, cache_c=None, cache_s=None,
+            slots=None, *, cfg: LlamaConfig):
     """Process full prompts, fill their pages, return last-token logits.
 
     tokens: [B, S] right-padded; prompt_lens: [B]; block_tables: [B, Pmax];
@@ -678,15 +795,19 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
     where the configuration has an indexer (every program does).
     """
     B, S = tokens.shape
-    if (cfg.latent or cfg.sparse_top_k) and B != 1:
+    if (cfg.latent or cfg.sparse_top_k or cfg.own_weights) and B != 1:
         raise ValueError("a latent configuration's prefill, and one's "
-                         "with an indexer, writes ONE prompt's rows a "
-                         "page at a time (_write_latent_pages), as the "
+                         "with an indexer or with state layers, writes ONE "
+                         "prompt's rows a page at a time "
+                         "(_write_latent_pages), as the "
                          f"engine asks: B == 1, not {B}")
     pools = _pools(cache_k, cache_v, cache_i)
+    # the state pool, as the linear layers leave it one after the other
+    box = {"s": cache_s}
+    sizes = cfg.block_sizes
     tables = _groups(block_tables)
-    page_size = pools[0][0].shape[2]
-    x = embed_lookup(params["embed"], tokens, cfg.dtype)
+    page_size = pools[0][0].shape[2]     # read by the window groups alone
+    x = _embed(params, tokens, cfg)
     pos_grid = jnp.arange(S)[None, :].repeat(B, 0)
     valid = pos_grid < prompt_lens[:, None]                    # [B, S]
     # a window layer hands out the rows that can still be inside the
@@ -699,7 +820,32 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
         jnp.maximum(prompt_lens - w + 1, 0) // page_size * page_size,
         0, S - n) for w, n in kept_rows.items()}
 
-    def attend(q, k, v, _, window, index=None):
+    def attend(q, k, v, place, window, index=None, linear=False,
+               block=False):
+        if linear:
+            # from a zero state, whatever the slot held: the state behind
+            # the prompt's last token goes to the slot's place in the pool
+            o, end = linear_attention.prefill(
+                q, k, v, cfg.linear_decay, prompt_lens,
+                scale=cfg.softmax_scale)
+            box["s"] = jax.lax.dynamic_update_slice(
+                box["s"], end[None], (place, slots[0], 0, 0, 0))
+            return o, None
+        if block:
+            # queries below dense_len (all of them in a bucket that
+            # short) attend over every visible key: the flash forward;
+            # the rest over the blocks they choose
+            sums = sparse.stride_sums(k, valid, sizes.stride)
+            D = sizes.dense_len
+            o = attention(q[:, :D], k[:, :D], v[:, :D], causal=True,
+                          lengths=jnp.minimum(prompt_lens, D))
+            if S > D:
+                o = jnp.concatenate([o, sparse.block_attend(
+                    q[0, D:], k[0], v[0], sums[0],
+                    jnp.where(valid[0, D:], pos_grid[0, D:], -1), sizes,
+                    scale=cfg.softmax_scale)[None].astype(o.dtype)], 1)
+            return o, (k.astype(pools[0][0].dtype),
+                       v.astype(pools[0][1].dtype), sums)
         if index is not None:
             # at most top_k keys in the bucket: every visible key is
             # chosen, the dense path; else the indexer's choice a query
@@ -739,6 +885,15 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
     written = []
     for window, pool, table, kept in zip(cfg.kv_groups, pools, tables,
                                          rows):
+        if cfg.own_weights:
+            # the ONE prompt's K and V rows a page at a time, and the
+            # strides' sums likewise (a page of them is ``per`` rows)
+            cache_c, = _write_latent_pages(
+                cache_c, kept[2], table, -(-prompt_lens // sizes.stride))
+            written.append(sum((_write_latent_pages(
+                c, _pair_rows(r), table, prompt_lens * cfg.n_kv_heads)
+                for c, r in zip(pool, kept[:2])), ()))
+            continue
         if cfg.latent:
             written.append(
                 _write_latent_pages(pool[0], kept[0], table, prompt_lens))
@@ -761,6 +916,8 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
     cache_k, cache_v, rest = _ungrouped(written, block_tables)
     x_last = jnp.take_along_axis(
         x, jnp.maximum(prompt_lens - 1, 0)[:, None, None], axis=1)[:, 0]
+    if cfg.own_weights:
+        rest = (cache_c, box["s"])
     return (_head(x_last, params, cfg), cache_k, cache_v, counts, *rest)
 
 
@@ -774,8 +931,8 @@ def prefill_bucket(seq_len: int, max_seq: int, floor: int = 16) -> int:
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=_POOLS)
 def prefill_chunk(params, cache_k, cache_v, tokens, start_pos, chunk_len,
-                  block_tables, cos, sin, cache_i=None, *,
-                  cfg: LlamaConfig):
+                  block_tables, cos, sin, cache_i=None, cache_c=None,
+                  cache_s=None, slots=None, *, cfg: LlamaConfig):
     """One CHUNK of a long prompt (vLLM's chunked prefill, rebuilt for
     static shapes): tokens [1, C] are positions [start_pos,
     start_pos+chunk_len), attending causally within the chunk AND over
@@ -787,11 +944,17 @@ def prefill_chunk(params, cache_k, cache_v, tokens, start_pos, chunk_len,
     cache_k, cache_v, expert counts as ``prefill``).
     """
     B, C = tokens.shape
-    pools = _pools(cache_k, cache_v, cache_i)
+    # the sums of strides ride as a third pool of the block layers' group
+    pools = _pools(cache_k, cache_v, cache_c if cfg.own_weights
+                   else cache_i)
+    box = {"s": cache_s}
+    sizes = cfg.block_sizes
     tables = dict(zip(cfg.kv_groups, _groups(block_tables)))
     page_size = pools[0][0].shape[2]
+    if cfg.own_weights:         # a page's rows are (position, KV head) pairs
+        page_size //= cfg.n_kv_heads
     Spast = _groups(block_tables)[0].shape[1] * page_size
-    x = embed_lookup(params["embed"], tokens, cfg.dtype)
+    x = _embed(params, tokens, cfg)
     pos_grid = start_pos + jnp.arange(C)[None, :]          # [1, C]
     valid = jnp.arange(C)[None, :] < chunk_len
     # past pages hold positions < start_pos (written by earlier chunks)
@@ -799,8 +962,40 @@ def prefill_chunk(params, cache_k, cache_v, tokens, start_pos, chunk_len,
     chunk_mask = (jnp.arange(C)[None, :, None]
                   >= jnp.arange(C)[None, None, :]) & valid[:, None, :]
 
-    def attend(q, k, v, pools, window, index=None):
+    def attend(q, k, v, pools, window, index=None, linear=False,
+               block=False):
+        if linear:
+            # the slot's state (zeroed when the request was admitted)
+            # carried from chunk to chunk through the pool
+            at = (pools, slots[0], 0, 0, 0)
+            o, end = linear_attention.prefill(
+                q, k, v, cfg.linear_decay, chunk_len.reshape(1),
+                jax.lax.dynamic_slice(
+                    box["s"], at, (1, 1, *box["s"].shape[2:]))[0],
+                scale=cfg.softmax_scale)
+            box["s"] = jax.lax.dynamic_update_slice(box["s"], end[None], at)
+            return o, None
         table, past, own, rows = tables[window], past_mask, chunk_mask, valid
+        if block:
+            # the chunk's rows into the pages, then the span through the
+            # pages, the chunk's own rows among it; the strides' sums of
+            # the whole span from its keys, and back into their pool
+            at, ok = _pair_positions(pos_grid, rows, cfg.n_kv_heads)
+            pk, pv = (_write_slices(pool, _pair_rows(new), table, at, ok, 1)
+                      for pool, new in zip(pools[:2], (k, v)))
+            sk, sv = (_take_span(pool, table).reshape(B, Spast, *k.shape[2:])
+                      for pool in (pk, pv))
+            cached = jnp.arange(Spast)[None, :] < start_pos + chunk_len
+            sums = sparse.stride_sums(sk, cached, sizes.stride)
+            strides = jnp.arange(Spast // sizes.stride)[None, :]
+            pc, = _write_rows(
+                pools[2:], (sums,), table, strides,
+                strides * sizes.stride < start_pos + chunk_len)
+            o = sparse.block_attend(
+                q[0], sk[0], sv[0], sums[0],
+                jnp.where(valid[0], pos_grid[0], -1), sizes,
+                scale=cfg.softmax_scale)[None]
+            return o, (pk, pv, pc)
         if cfg.latent:
             return _attend_latent_pages(q, k, v, pools, table, pos_grid,
                                         rows, cfg, past, own)
@@ -834,13 +1029,16 @@ def prefill_chunk(params, cache_k, cache_v, tokens, start_pos, chunk_len,
     idx = jnp.broadcast_to(jnp.maximum(chunk_len - 1, 0).reshape(1, 1, 1),
                            (B, 1, 1))
     x_last = jnp.take_along_axis(x, idx, axis=1)[:, 0]
+    if cfg.own_weights:
+        rest = (*rest, box["s"])
     return (_head(x_last, params, cfg), cache_k, cache_v, counts, *rest)
 
 
 @partial(jax.jit, static_argnames=("cfg", "greedy"), donate_argnames=_POOLS)
 def verify_step(params, cache_k, cache_v, tokens, positions, block_tables,
-                cos, sin, seed, temperature, top_k, top_p, cache_i=None, *,
-                cfg: LlamaConfig, greedy: bool = False):
+                cos, sin, seed, temperature, top_k, top_p, cache_i=None,
+                cache_c=None, cache_s=None, *, cfg: LlamaConfig,
+                greedy: bool = False):
     """Batched multi-token verification forward (speculative decoding,
     Leviathan et al. ICML'23 — PAPERS.md): score a whole k-token draft
     window in ONE dispatch, like a short prefill over the paged cache.
@@ -857,11 +1055,17 @@ def verify_step(params, cache_k, cache_v, tokens, positions, block_tables,
     window position j, sampled position-0 token [B] for rows that
     aren't greedy, cache_k, cache_v, expert counts as ``prefill``).
     """
+    if cfg.own_weights:
+        raise ValueError(
+            "verify_step is not written for linear layers: a window that "
+            "is rejected would have to roll a slot's state back, and the "
+            "state keeps no token apart (LLMEngine refuses speculation "
+            "with them)")
     pools = _pools(cache_k, cache_v, cache_i)
     tables = dict(zip(cfg.kv_groups, _groups(block_tables)))
     page_size = pools[0][0].shape[2]
     Sall = _groups(block_tables)[0].shape[1] * page_size
-    x = embed_lookup(params["embed"], tokens, cfg.dtype)
+    x = _embed(params, tokens, cfg)
     valid = positions >= 0
     qpos = jnp.maximum(positions, 0)                       # [B, S]
     # unused table slots are 0 (the reserved page) but sit past the row's
@@ -915,12 +1119,12 @@ def sample_logits(logits, seed, temperature, top_k, top_p):
 @partial(jax.jit, static_argnames=("cfg", "greedy"), donate_argnames=_POOLS)
 def prefill_sample(params, cache_k, cache_v, tokens, prompt_lens,
                    block_tables, cos, sin, seed, temperature, top_k, top_p,
-                   lora=None, cache_i=None, *, cfg: LlamaConfig,
-                   greedy: bool = False):
+                   lora=None, cache_i=None, cache_c=None, cache_s=None,
+                   slots=None, *, cfg: LlamaConfig, greedy: bool = False):
     """``prefill`` with the sampler behind it (``greedy``: see ``_pick``)."""
     logits, *rest = prefill.__wrapped__(
         params, cache_k, cache_v, tokens, prompt_lens, block_tables,
-        cos, sin, lora, cache_i, cfg=cfg)
+        cos, sin, lora, cache_i, cache_c, cache_s, slots, cfg=cfg)
     return (_pick(logits, greedy, seed, temperature, top_k, top_p), *rest)
 
 
@@ -928,8 +1132,8 @@ def prefill_sample(params, cache_k, cache_v, tokens, prompt_lens,
          static_argnames=("cfg", "n_steps", "paged_kernel", "greedy"))
 def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
                  active, cos, sin, seed, temperature, top_k, top_p,
-                 lora=None, gather=None, steps=None, cache_i=None, *,
-                 cfg: LlamaConfig,
+                 lora=None, gather=None, steps=None, cache_i=None,
+                 cache_c=None, cache_s=None, *, cfg: LlamaConfig,
                  n_steps: int, paged_kernel: bool = None,
                  greedy: bool = False):
     """Up to n_steps fused decode+sample steps, sampled tokens fed back
@@ -1000,8 +1204,26 @@ def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
     # [L, kvh, T * page, hd] for one flat list; the burst's own rows are
     # [L, B, K, kvh, hd]; all of it a layer group
     old, old_mask, key_pos = [], {}, {}
+    sizes = cfg.block_sizes
     for window, pool, table, listed in zip(cfg.kv_groups, pools, tables,
                                            gathers):
+        if cfg.own_weights:
+            # no K or V is copied; the strides' sums of each slot's own
+            # pages are, ONCE a burst for all block layers (a sixteenth
+            # of K): [L, B, n * per, kvh, hd]
+            span = table if listed is None else listed
+            L, P = cache_c.shape[:2]
+            sums = jnp.take(
+                cache_c.reshape(L * P, *cache_c.shape[2:]),
+                jnp.arange(L)[:, None, None] * P + span[None],
+                axis=0).reshape(L, B, -1, *cache_c.shape[3:])
+            # a stride that holds no cached position holds what the page
+            # held before: nothing of this sequence
+            held = (jnp.arange(sums.shape[2])[None, :] * sizes.stride
+                    < positions[:, None])
+            old.append((jnp.arange(L, dtype=jnp.int32), jnp.where(
+                held[None, ..., None, None], sums, 0.0)))
+            continue
         if cfg.latent or cfg.sparse_top_k:
             # no copy: a layer's state is its index into the pool
             old.append((jnp.arange(cfg.n_layers, dtype=jnp.int32),))
@@ -1020,17 +1242,55 @@ def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
         old.append(tuple(_gather_span(c, pages) for c in pool))
         old_mask[window], key_pos[window] = mask, at
     scratch = tuple(tuple(
-        jnp.zeros((c.shape[0], B, K, *c.shape[3:]), c.dtype) for c in pool)
+        jnp.zeros((c.shape[0], B, K, *((cfg.n_kv_heads, cfg.head_dim)
+                                       if cfg.own_weights else c.shape[3:])),
+                  c.dtype) for c in pool)
         for pool in pools)
     layers = _layers(params, cfg, cos, sin, lora)
     n_run = K if steps is None else steps
 
+    box = {}
+    # the slots that decode, in the order the state kernel walks them:
+    # the same for every layer and step
+    live_slots = linear_attention.live_order(active) if cfg.own_weights \
+        else None
+
     def step(i, carry):
-        toks, scratch, out, total = carry
-        x = embed_lookup(params["embed"], toks, cfg.dtype)[:, None, :]
+        toks, scratch, out, total, box["s"] = carry
+        x = _embed(params, toks, cfg)[:, None, :]
         new_mask = jnp.arange(K)[None, :] <= i                 # [1, K]
 
-        def attend(q, k, v, state, window, index=None):
+        def attend(q, k, v, state, window, index=None, linear=False,
+                   block=False):
+            if linear:
+                # every live slot's state of the layer advanced by the
+                # step's token, in place in the pool (``state``: the
+                # layer's place in it)
+                o, box["s"] = linear_attention.decode_step(
+                    q[:, 0], k[:, 0], v[:, 0], box["s"], state, active,
+                    cfg.linear_decay, scale=cfg.softmax_scale,
+                    order=live_slots)
+                return o[:, None], None
+            if block:
+                # the slot's pages chosen by the scores of its strides'
+                # sums and the burst's own keys; the chosen pages' K and
+                # V read where they lie, the burst's rows (always of the
+                # newest blocks, always chosen) joined from scratch
+                layer, sums, nk, nv = state
+                nk, nv = (jax.lax.dynamic_update_slice_in_dim(
+                    rows, new.astype(rows.dtype), i, 1)
+                    for rows, new in ((nk, k), (nv, v)))
+                listed = sparse.block_decode_pages(
+                    q[:, 0], sums, nk, i + 1, span, positions, sizes,
+                    scale=cfg.softmax_scale)
+                o, lse = sparse.block_decode_attention(
+                    q[:, 0], *pools[0][:2], layer, *listed,
+                    kvh=cfg.n_kv_heads, scale=cfg.softmax_scale)
+                o = sparse.join_new_rows(
+                    o, lse, q[:, 0], nk, nv,
+                    jnp.broadcast_to(new_mask, nk.shape[:2]),
+                    scale=cfg.softmax_scale)
+                return o[:, None], (nk, nv)
             if index is not None:
                 # the slot's indexer rows and the burst's own, scored and
                 # chosen from; the slot's K and V pages read where they
@@ -1089,17 +1349,21 @@ def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
         newt = jnp.where(active, newt, toks).astype(out.dtype)
         out = jax.lax.dynamic_update_slice_in_dim(out, newt[None], i, 0)
         return (newt, scratch, out,
-                None if counts is None else total + counts)
+                None if counts is None else total + counts, box["s"])
 
-    _, scratch, out, counts = jax.lax.fori_loop(
+    _, scratch, out, counts, cache_s = jax.lax.fori_loop(
         0, n_run, step,
         (tokens, scratch, jnp.zeros((K, B), tokens.dtype),
-         jnp.zeros(3, jnp.int32) if cfg.n_experts else None))
+         jnp.zeros(3, jnp.int32) if cfg.n_experts else None, cache_s))
     # one scatter of the whole burst into the paged cache
     p_grid = positions[:, None] + jnp.arange(K)[None, :]       # [B, K]
     written = active[:, None] & (jnp.arange(K)[None, :] < n_run)
 
     def burst_rows(pool, rows, table):
+        if cfg.own_weights:
+            at, ok = _pair_positions(p_grid, written, cfg.n_kv_heads)
+            return tuple(_write_slices(c, _pair_rows(r), table, at, ok, 1)
+                         for c, r in zip(pool, rows))
         if cfg.latent:
             return _write_latent(pool[0], rows[0], table, p_grid, written)
         if cfg.sparse_top_k:
@@ -1112,4 +1376,7 @@ def decode_burst(params, cache_k, cache_v, tokens, positions, block_tables,
     cache_k, cache_v, rest = _ungrouped(
         [burst_rows(*group) for group in zip(pools, scratch, tables)],
         block_tables)
+    if cfg.own_weights:
+        rest = (_add_to_sums(cache_c, scratch[0][0], tables[0], positions,
+                             written, sizes.stride), cache_s)
     return (out, cache_k, cache_v, counts, *rest)

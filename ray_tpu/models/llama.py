@@ -34,8 +34,14 @@ from ..parallel.sharding import (DEFAULT_RULES, logical_sharding,
                                  with_sharding_constraint_logical)
 
 
-# a layer's kind (``LlamaConfig.layer_pattern``)
-LAYER_KINDS = ("full", "full_nope", "window", "window_nope")
+# a layer's kind (``LlamaConfig.layer_pattern``). "linear": a layer of
+# linear attention, whose memory is a state a slot and no key or value
+# (ops/linear_attention.py); "block_nope": softmax attention without a
+# rotary embedding over the tokens of the blocks a query chooses
+# (ops/sparse_attention.py, "selection by blocks"). Both have weights of
+# their own widths: a stack a kind (``init_params``)
+LAYER_KINDS = ("full", "full_nope", "window", "window_nope", "linear",
+               "block_nope")
 
 
 def windowed(kind: str) -> bool:
@@ -44,6 +50,10 @@ def windowed(kind: str) -> bool:
 
 def rotated(kind: str) -> bool:
     return not kind.endswith("_nope")
+
+
+def linear(kind: str) -> bool:
+    return kind == "linear"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -142,6 +152,35 @@ class LlamaConfig:
     indexer_heads: int = 0
     indexer_dim: int = 0
     sparse_top_k: int = 0
+    # "linear" layers (lightning attention): ``linear_heads`` query, key
+    # and value heads of ``head_dim`` each, no grouping; a head's memory
+    # is a float32 ``head_dim`` x ``head_dim`` state a slot that decays
+    # by ``exp(-2 ** (-8 (h + 1) / linear_heads))`` a token. Their
+    # weights are ``params["linear_layers"]``, a stack of their own.
+    # Served only
+    linear_heads: int = 0
+    # "block_nope" layers (InfLLM-V2): a query from position
+    # ``block_dense_len`` on attends over the tokens of ``block_topk``
+    # blocks of ``block_size``: the first ``block_init``, those that
+    # touch the ``block_window`` newest tokens, and the best by the
+    # scores of compressed keys (the mean of ``block_kernel`` keys every
+    # ``block_stride``); one choice a KV head. A page is a block
+    # (llm/engine.py refuses another page_size). 0: none. Served only
+    block_size: int = 0
+    block_topk: int = 0
+    block_kernel: int = 0
+    block_stride: int = 0
+    block_init: int = 0
+    block_window: int = 0
+    block_dense_len: int = 0
+    # attention's output times sigmoid(W_g h) before ``wo`` (``wg``)
+    attn_output_gate: bool = False
+    # MiniCPM's scalings: the embedding times ``embed_scale``, what a
+    # layer's two halves add to the stream times ``residual_scale``, the
+    # final norm's output divided by ``logit_divisor``. 1.0: untouched
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_divisor: float = 1.0
 
     def __post_init__(self):
         kinds = self.layer_kinds
@@ -153,6 +192,54 @@ class LlamaConfig:
             raise ValueError(
                 f"n_layers={self.n_layers} is no whole number of periods "
                 f"of layer_pattern {kinds}")
+        blocks = (self.block_size, self.block_topk, self.block_kernel,
+                  self.block_stride, self.block_init, self.block_window,
+                  self.block_dense_len)
+        if self.own_weights:
+            # kinds with weights of their own: the pattern lists every
+            # layer, the stacks are a kind's
+            if len(kinds) != self.n_layers:
+                raise ValueError(
+                    f"layer_pattern with 'linear' or 'block_nope' layers "
+                    f"lists every layer: {len(kinds)} entries for "
+                    f"n_layers={self.n_layers}")
+            others = sorted(set(kinds) - {"linear", "block_nope"})
+            if others:
+                raise ValueError("'linear' and 'block_nope' layers stand "
+                                 f"beside each other only, not beside {others}")
+            if ("linear" in kinds) != bool(self.linear_heads):
+                raise ValueError("'linear' layers need linear_heads, and "
+                                 "linear_heads needs 'linear' layers")
+            if ("block_nope" in kinds) != all(blocks) or (
+                    any(blocks) and not all(blocks)):
+                raise ValueError(
+                    "'block_nope' layers need block_size, block_topk, "
+                    "block_kernel, block_stride, block_init, block_window "
+                    "and block_dense_len, all of them, and they need "
+                    "'block_nope' layers")
+            if "block_nope" not in kinds:
+                raise ValueError("a layer_pattern of 'linear' layers alone "
+                                 "has no page pool: one 'block_nope' layer "
+                                 "at least")
+            if all(blocks) and (
+                    self.block_size % self.block_stride
+                    or self.block_kernel != 2 * self.block_stride
+                    or self.block_window % self.block_size
+                    or self.block_dense_len % self.block_size):
+                raise ValueError(
+                    "block selection is written for block_kernel = 2 x "
+                    "block_stride, a block a whole number of strides, and "
+                    "block_window and block_dense_len whole blocks")
+            if (self.n_experts or self.latent or self.sparse_top_k
+                    or not (self.qk_norm and self.qk_norm_by_head)):
+                raise ValueError(
+                    "'linear' and 'block_nope' layers are dense GQA layers "
+                    "with a QK-norm a head: no experts, no latent "
+                    "attention, no indexer")
+        elif any(blocks) or self.linear_heads:
+            raise ValueError("linear_heads and the block_* sizes belong to "
+                             "'linear' and 'block_nope' layers in "
+                             "layer_pattern")
         if any(map(windowed, kinds)) and not self.window:
             raise ValueError("layer_pattern has window layers and "
                              "window is not set")
@@ -169,15 +256,17 @@ class LlamaConfig:
                 "latent attention needs q_lora_rank, kv_lora_rank, "
                 "qk_nope_dim, qk_rope_dim and v_head_dim, all of them")
         if self.latent and (len(kinds) > 1 or self.qk_norm):
-            raise ValueError("latent attention runs one kind of layer "
-                             "(full, rotated) without qk_norm")
+            raise ValueError("latent attention has no layer_pattern (every "
+                             "layer full and rotated, ONE stack of weights) "
+                             "and no qk_norm")
         if self.n_dense_layers and (len(kinds) > 1 or not self.n_experts
                                     or not self.dense_mlp_dim
                                     or self.n_dense_layers >= self.n_layers):
             raise ValueError(
                 "n_dense_layers: leading dense layers (of dense_mlp_dim) "
                 "come before the expert layers of a configuration with "
-                "experts and one kind of layer")
+                "experts and no layer_pattern (the expert layers are ONE "
+                "stack of weights)")
         if self.n_experts and self.n_experts % self.n_group:
             raise ValueError(f"n_experts={self.n_experts} is no whole "
                              f"number of n_group={self.n_group} groups")
@@ -186,9 +275,10 @@ class LlamaConfig:
             raise ValueError("an indexer needs indexer_heads, indexer_dim "
                              "and sparse_top_k, all of them")
         if self.sparse_top_k and (len(kinds) > 1 or self.latent):
-            raise ValueError("an indexer sits beside one kind of layer "
-                             "(full, rotated, GQA): no layer_pattern, no "
-                             "latent attention")
+            raise ValueError("an indexer sits beside every layer of ONE "
+                             "stack of full, rotated GQA layers: no "
+                             "layer_pattern (whose kinds may have weights "
+                             "of their own), no latent attention")
         if self.qk_norm_by_head and not self.qk_norm:
             raise ValueError("qk_norm_by_head says how qk_norm "
                              "normalises: set qk_norm too")
@@ -204,6 +294,49 @@ class LlamaConfig:
     def latent(self) -> bool:
         """Latent attention, not GQA: see ``kv_lora_rank``."""
         return self.kv_lora_rank > 0
+
+    @property
+    def own_weights(self) -> bool:
+        """The pattern has kinds with weights of their own ("linear",
+        "block_nope"): it lists every layer, and a layer takes its
+        weights by its place in its kind (``params["linear_layers"]``,
+        ``params["layers"]``)."""
+        return any(k in ("linear", "block_nope") for k in self.layer_kinds)
+
+    @property
+    def n_linear_layers(self) -> int:
+        return sum(map(linear, self.layer_kinds)) if self.own_weights else 0
+
+    @property
+    def n_kv_layers(self) -> int:
+        """Layers that keep keys and values in pages."""
+        return self.n_layers - self.n_linear_layers
+
+    @property
+    def linear_decay(self) -> Tuple[float, ...]:
+        """``-log`` of a linear head's decay a token: Lightning
+        Attention-2's slopes ``2 ** (-8 (h + 1) / heads)``."""
+        from ..ops.linear_attention import slopes_of
+
+        return tuple(float(s) for s in slopes_of(self.linear_heads))
+
+    @property
+    def block_sizes(self):
+        """The block layers' sizes as ``ops/sparse_attention.py`` takes
+        them; None without such layers."""
+        if not self.block_size:
+            return None
+        from ..ops.sparse_attention import BlockSizes
+
+        return BlockSizes(self.block_size, self.block_topk,
+                          self.block_stride, self.block_init,
+                          self.block_window, self.block_dense_len)
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Bytes of float32 state a slot holds in the linear layers."""
+        return (self.n_linear_layers * self.linear_heads
+                * self.head_dim * self.head_dim * 4)
 
     @property
     def head_dim(self) -> int:
@@ -273,7 +406,7 @@ class LlamaConfig:
         """The live spans the layers' keys have, one entry a group of
         layers that share a page pool (llm/cache.py): None for the whole
         sequence, else the window. Full layers first."""
-        spans = {self._span(k) for k in self.layer_kinds}
+        spans = {self._span(k) for k in self.layer_kinds if not linear(k)}
         return tuple(sorted(spans, key=lambda s: s is not None))
 
     def _span(self, kind: str) -> Optional[int]:
@@ -281,6 +414,8 @@ class LlamaConfig:
 
     def group_layers(self, group: int) -> int:
         """Layers of the whole stack in ``kv_groups[group]``."""
+        if self.own_weights:
+            return self.n_kv_layers
         return sum(self.layer_group(j)[0] == group
                    for j in range(len(self.layer_kinds))) \
             * (self.n_layers // len(self.layer_kinds))
@@ -306,6 +441,16 @@ class LlamaConfig:
         if self.sparse_top_k:
             attn += d * (self.indexer_heads * (self.indexer_dim + 1)
                          + self.indexer_dim) + 2 * self.indexer_dim
+        if self.attn_output_gate:
+            attn += d * self.n_heads * self.head_dim
+        if self.own_weights:
+            # a linear layer: five square matrices, two head norms and
+            # the output norm over all heads
+            wide = self.linear_heads * self.head_dim
+            lin = 5 * d * wide + 2 * self.head_dim + wide
+            return (self.vocab * d * 2 + d
+                    + self.n_kv_layers * (attn + mlp + 2 * d)
+                    + self.n_linear_layers * (lin + mlp + 2 * d))
         return self.vocab * d * 2 + L * (attn + mlp + 2 * d) + d
 
 
@@ -428,6 +573,8 @@ def init_params(key, cfg: LlamaConfig, gains=None):
         }
 
     L, m = cfg.n_moe_layers, cfg.mlp_dim
+    if cfg.own_weights:
+        L = cfg.n_kv_layers          # ``layers``: the layers with pages
     if cfg.n_experts:
         E, held = cfg.n_experts, cfg.n_experts_held
         kr = jax.random.split(ks[5], 4)
@@ -480,6 +627,34 @@ def init_params(key, cfg: LlamaConfig, gains=None):
         "final_norm": jnp.ones((d,), cfg.dtype),
         "lm_head": norm(ks[8], (d, cfg.vocab), d, "lm_head"),
     }
+    if cfg.attn_output_gate:
+        kg = jax.random.split(jax.random.fold_in(key, 3), 2)
+        params["layers"]["wg"] = norm(kg[0], (L, d, h, hd), d, "wg")
+    if cfg.own_weights:
+        # the linear layers' stack: heads of their own number, an output
+        # norm over all heads, the gate, and a feed-forward as every layer
+        n, lh = cfg.n_linear_layers, cfg.linear_heads
+        kl = jax.random.split(jax.random.fold_in(key, 4), 11)
+        used.update(("q_norm", "k_norm", "o_norm"))
+        params["linear_layers"] = {
+            "attn_norm": jnp.ones((n, d), cfg.dtype),
+            "wq": norm(kl[0], (n, d, lh, hd), d, "wq"),
+            "wk": norm(kl[1], (n, d, lh, hd), d, "wk"),
+            "wv": norm(kl[2], (n, d, lh, hd), d, "wv"),
+            "wo": norm(kl[3], (n, lh, hd, d), lh * hd, "wo"),
+            "wg": norm(kl[4], (n, d, lh, hd), d, "wg"),
+            "q_norm": gains.get("q_norm", 1.0) * scattered(kl[5], (n, hd)),
+            "k_norm": gains.get("k_norm", 1.0) * scattered(kl[6], (n, hd)),
+            "o_norm": gains.get("o_norm", 1.0) * scattered(
+                kl[7], (n, lh * hd)),
+            "mlp_norm": jnp.ones((n, d), cfg.dtype),
+            **dense_mlp(kl[8:], n, m),
+        }
+        params["layers"].update(
+            q_norm=gains.get("q_norm", 1.0) * scattered(
+                jax.random.fold_in(key, 5), (L, hd)),
+            k_norm=gains.get("k_norm", 1.0) * scattered(
+                jax.random.fold_in(key, 6), (L, hd)))
     if cfg.n_dense_layers:
         n = cfg.n_dense_layers
         kd = jax.random.split(jax.random.fold_in(key, 1), 7)
@@ -610,15 +785,20 @@ def forward(params, tokens, cfg: LlamaConfig, *,
     if (cfg.layer_pattern or cfg.router_input != "mlp"
             or cfg.expert_act != "silu" or cfg.latent or cfg.n_dense_layers
             or cfg.n_shared_experts or cfg.n_group > 1
-            or cfg.experts_held is not None or cfg.sparse_top_k):
+            or cfg.experts_held is not None or cfg.sparse_top_k
+            or cfg.attn_output_gate or cfg.embed_scale != 1.0
+            or cfg.residual_scale != 1.0 or cfg.logit_divisor != 1.0):
         raise ValueError(
-            "the training forward runs one kind of layer (full, rotated, "
-            "GQA, the router on the feed-forward's input, ungrouped, silu "
-            "experts, all held, none shared, no leading dense layer); a "
-            "layer_pattern, router_input='attention', expert_act='relu', "
-            "latent attention, n_dense_layers, n_shared_experts, n_group, "
-            "experts_held or an indexer (sparse_top_k) is served by "
-            "llm/runner.py only")
+            "the training forward runs ONE stack of layers of one kind "
+            "(full, rotated, GQA, the router on the feed-forward's input, "
+            "ungrouped, silu experts, all held, none shared, no leading "
+            "dense layer, no output gate, no scalings); a layer_pattern "
+            "(of kinds that share a stack, or of 'linear' and 'block_nope' "
+            "layers with weights of their own), router_input='attention', "
+            "expert_act='relu', latent attention, n_dense_layers, "
+            "n_shared_experts, n_group, experts_held, an indexer "
+            "(sparse_top_k), attn_output_gate, embed_scale, residual_scale "
+            "or logit_divisor is served by llm/runner.py only")
     csl = partial(with_sharding_constraint_logical, rules=rules, mesh=mesh)
     cos, sin = rope_frequencies(cfg.head_dim, tokens.shape[1],
                                 cfg.rope_theta, dtype=jnp.float32)

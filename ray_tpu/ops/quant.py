@@ -130,6 +130,8 @@ _LLAMA_LAYER_CONTRACT = {
     "wi_q": (1,),    # (L, d, J, di)   contract d
     "wi_k": (1,),    # (L, d, di)
     "wi_w": (1,),    # (L, d, J)
+    # attention's output gate (``cfg.attn_output_gate``)
+    "wg": (1,),      # (L, d, h, hd)   contract d
 }
 # expert configs: the feed-forward has an "expert" axis after "layers",
 # never contracted either: per-expert per-output-channel scales (L, E, n),
@@ -163,8 +165,11 @@ def quantize_params(params: Dict, cfg=None) -> Dict:
         "final_norm": params["final_norm"],
         "lm_head": quantize_weight(params["lm_head"], (0,)),
     }
-    if "dense_layers" in params:
-        out["dense_layers"] = stack(params["dense_layers"])
+    # stacks beside ``layers``: leading dense layers; the linear layers
+    # of a pattern whose kinds have weights of their own
+    for name in ("dense_layers", "linear_layers"):
+        if name in params:
+            out[name] = stack(params[name])
     return out
 
 
@@ -195,7 +200,7 @@ def _init_params_quantized_jit(key, cfg, gains=()) -> Dict:
     # stays what it was; latent attention, shared experts and leading
     # dense layers need more and draw from a second set
     plain = not (cfg.latent or cfg.n_shared_experts or cfg.n_dense_layers
-                 or cfg.sparse_top_k)
+                 or cfg.sparse_top_k or cfg.own_weights)
     ks = iter(jax.random.split(key, 16) if plain else jax.random.split(
         jax.random.fold_in(key, 1), 64))
     gains = dict(gains)
@@ -257,6 +262,8 @@ def _init_params_quantized_jit(key, cfg, gains=()) -> Dict:
     # config's weights for a seed are what they always were
     embed = qrand((cfg.vocab, d), d, (0,), name="embed")
     L, m = cfg.n_moe_layers, cfg.mlp_dim
+    if cfg.own_weights:
+        L = cfg.n_kv_layers          # ``layers``: the layers with pages
     layers = {
         "attn_norm": jnp.ones((L, d), jnp.bfloat16),
         **attention_leaves(L),
@@ -294,12 +301,30 @@ def _init_params_quantized_jit(key, cfg, gains=()) -> Dict:
             wi_w=qrand((L, d, J), d, (0, 2), name="wi_w"),
             wi_k_norm=gain(L, di),
             wi_k_bias=(gain(L, di) - 1.0).astype(jnp.bfloat16))
+    if cfg.attn_output_gate:
+        layers["wg"] = qrand((L, d, h, hd), d, (0, 2, 3), name="wg")
     params = {
         "embed": embed,
         "layers": layers,
         "final_norm": jnp.ones((d,), jnp.bfloat16),
         "lm_head": qrand((d, cfg.vocab), d, (1,), name="lm_head"),
     }
+    if cfg.own_weights:
+        # the linear layers' stack (``models.llama.init_params``)
+        n, lh = cfg.n_linear_layers, cfg.linear_heads
+        params["linear_layers"] = {
+            "attn_norm": jnp.ones((n, d), jnp.bfloat16),
+            "wq": qrand((n, d, lh, hd), d, (0, 2, 3), name="wq"),
+            "wk": qrand((n, d, lh, hd), d, (0, 2, 3), name="wk"),
+            "wv": qrand((n, d, lh, hd), d, (0, 2, 3), name="wv"),
+            "wo": qrand((n, lh, hd, d), lh * hd, (0, 3), name="wo"),
+            "wg": qrand((n, d, lh, hd), d, (0, 2, 3), name="wg"),
+            "q_norm": gain(n, hd, "q_norm"),
+            "k_norm": gain(n, hd, "k_norm"),
+            "o_norm": gain(n, lh * hd, "o_norm"),
+            "mlp_norm": jnp.ones((n, d), jnp.bfloat16),
+            **dense_mlp(n, m),
+        }
     if cfg.n_dense_layers:
         n = cfg.n_dense_layers
         params["dense_layers"] = {

@@ -68,6 +68,7 @@ and the result is plain causal attention's.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -80,6 +81,10 @@ INT_MIN = -2 ** 31
 INDEX_KERNEL = "rt_sparse_index"
 SELECT_KERNEL = "rt_sparse_select"
 PREFILL_KERNEL = "flash_sparse_fwd"
+# selection by blocks (``block_attend``): the scores of compressed keys,
+# and the flash forward that leaves out the key blocks nobody chose
+BLOCK_SCORE_KERNEL = "rt_block_score"
+BLOCK_PREFILL_KERNEL = "flash_block_sparse_fwd"
 DECODE = "_decode"
 DECODE_KERNEL = "rt_sparse_attend_decode"
 # queries ``attend`` takes at a time: their scores are [tile, S] float32
@@ -344,11 +349,18 @@ def masked_attention_xla(q, kt, vt, mask, last=None, *, scale):
     return o.reshape(T, H, hd).astype(q.dtype)
 
 
-def _prefill_kernel(need_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, acc,
-                    m_ref, l_ref, *, scale, kvh, group):
+def _prefill_kernel(need_ref, *refs, scale, kvh, group, blocks=False):
     from jax.experimental import pallas as pl
 
     i, j = pl.program_id(0), pl.program_id(1)
+    wanted = j < need_ref[i]
+    if blocks:
+        # a second table: the key blocks in which the query block's mask
+        # keeps anything at all (selection by blocks); the rest are
+        # fetched and not computed
+        live_ref, *refs = refs
+        wanted = wanted & (live_ref[i, j] > 0)
+    q_ref, k_ref, v_ref, mask_ref, o_ref, acc, m_ref, l_ref = refs
 
     @pl.when(j == 0)
     def _():
@@ -356,7 +368,7 @@ def _prefill_kernel(need_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, acc,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(j < need_ref[i])
+    @pl.when(wanted)
     def _():
         seen = mask_ref[...].astype(jnp.float32) > 0.0        # [tq, tk]
         for h in range(kvh):
@@ -387,12 +399,15 @@ _PREFILL_ROWS = 128
 
 
 def masked_attention_tpu(q, kt, vt, mask, last=None, *, scale,
-                         interpret=False):
+                         blocks=False, interpret=False):
     """The kernel: grid (query blocks of 128, key blocks of 512); a step
     holds every head's queries of the block, the key block's keys and
     values of every KV head and the mask's block, and keeps an online
     softmax a head. Key blocks from ``last`` on (no row of the query
-    block sees them) are neither fetched nor computed."""
+    block sees them) are neither fetched nor computed. ``blocks``: the
+    mask was chosen by blocks (``block_attend``): a key block in which
+    the query block keeps nothing is not computed either, under the
+    kernel's other name (``BLOCK_PREFILL_KERNEL``)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -403,24 +418,28 @@ def masked_attention_tpu(q, kt, vt, mask, last=None, *, scale,
         need = jnp.full((T // tq,), S // tk, jnp.int32)
     else:
         need = -(-last.reshape(T // tq, tq).max(-1) // tk)
+    tables = (need.astype(jnp.int32),)
+    if blocks:
+        tables += ((mask.reshape(T // tq, tq, S // tk, tk) > 0).any(
+            (1, 3)).astype(jnp.int32),)
 
-    def keys_at(i, j, need):
+    def keys_at(i, j, need, *_):
         return 0, jnp.minimum(j, jnp.maximum(need[i] - 1, 0)), 0
 
-    def mask_at(i, j, need):
+    def mask_at(i, j, need, *_):
         return i, jnp.minimum(j, jnp.maximum(need[i] - 1, 0))
 
     o = pl.pallas_call(
         functools.partial(_prefill_kernel, scale=scale, kvh=kvh,
-                          group=H // kvh),
+                          group=H // kvh, blocks=blocks),
         out_shape=jax.ShapeDtypeStruct((H, T, hd), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            in_specs=[pl.BlockSpec((H, tq, hd), lambda i, j, _: (0, i, 0)),
+            num_scalar_prefetch=len(tables),
+            in_specs=[pl.BlockSpec((H, tq, hd), lambda i, j, *_: (0, i, 0)),
                       pl.BlockSpec((kvh, tk, hd), keys_at),
                       pl.BlockSpec((kvh, tk, hd), keys_at),
                       pl.BlockSpec((tq, tk), mask_at)],
-            out_specs=pl.BlockSpec((H, tq, hd), lambda i, j, _: (0, i, 0)),
+            out_specs=pl.BlockSpec((H, tq, hd), lambda i, j, *_: (0, i, 0)),
             grid=(T // tq, S // tk),
             scratch_shapes=[pltpu.VMEM((H, tq, hd), jnp.float32),
                             pltpu.VMEM((H, tq, 1), jnp.float32),
@@ -428,18 +447,21 @@ def masked_attention_tpu(q, kt, vt, mask, last=None, *, scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM),
-        interpret=interpret, name=PREFILL_KERNEL,
-    )(need.astype(jnp.int32), jnp.swapaxes(q, 0, 1), kt, vt, mask)
+        interpret=interpret,
+        name=BLOCK_PREFILL_KERNEL if blocks else PREFILL_KERNEL,
+    )(*tables, jnp.swapaxes(q, 0, 1), kt, vt, mask)
     return jnp.swapaxes(o, 0, 1)
 
 
-def masked_attention(q, kt, vt, mask, last=None, *, scale: float):
+def masked_attention(q, kt, vt, mask, last=None, *, scale: float,
+                     blocks: bool = False):
     """q [T, H, hd]; kt, vt [kvh, S, hd] (KV heads first); mask int8
     [T, S]; last int32 [T] (a row sees no key from ``last`` on) ->
     [T, H, hd] in q's dtype: one softmax a row and head over the keys
     the mask keeps; a row that keeps none comes back as zeros. The
     kernel takes whole query blocks of 128; fewer rows (a speculative
-    window, a bucket under 128) go to plain jax on every platform."""
+    window, a bucket under 128) go to plain jax on every platform.
+    ``blocks``: see ``masked_attention_tpu``."""
     with jax.named_scope("rt.attn.sparse"):
         if last is None:
             last = jnp.full(q.shape[:1], kt.shape[1], jnp.int32)
@@ -448,7 +470,8 @@ def masked_attention(q, kt, vt, mask, last=None, *, scale: float):
             return xla(q, kt, vt, mask, last)
         return jax.lax.platform_dependent(
             q, kt, vt, mask, last,
-            tpu=functools.partial(masked_attention_tpu, scale=scale),
+            tpu=functools.partial(masked_attention_tpu, scale=scale,
+                                  blocks=blocks),
             default=xla)
 
 
@@ -622,13 +645,33 @@ def decode_attention_tpu(q, pool_k, pool_v, layer, tables, lengths, mask, *,
                      np.arange(1.0, kvh + 1.0, dtype=np.float32)[None, :])
     seen = (live.astype(jnp.float32).reshape(B * n, page) @ spread).astype(
         jnp.int32).reshape(B, n, page * kvh) - 1
-    # (position, KV head) as the rows of one matrix a page: the same
-    # bytes as they lie
-    pool_k, pool_v = (pool.reshape(*pool.shape[:2], page * kvh, hd)
-                      for pool in (pool_k, pool_v))
+    return _decode_call(q, pool_k, pool_v, layer, tables, lengths, seen,
+                        pages=pages, scale=scale, interpret=interpret)
+
+
+def _decode_call(q, pool_k, pool_v, layer, tables, lengths, seen, *, pages,
+                 scale, group=None, kvh=None, interpret=False):
+    """``decode_attention_tpu``'s ``pallas_call``. seen int32 [B, n,
+    page * kvh]: beside every (position, KV head) row of a listed page
+    the number of the group of ``group`` query rows (``H // kvh``
+    unless given) that keeps it, else -1. ``kvh``: the pools are [L, P,
+    page * kvh, hd] already (a configuration with state layers keeps
+    them so)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, hd = q.shape
+    if kvh is None:
+        page, kvh = pool_k.shape[2:4]
+        # (position, KV head) as the rows of one matrix a page: the same
+        # bytes as they lie
+        pool_k, pool_v = (pool.reshape(*pool.shape[:2], page * kvh, hd)
+                          for pool in (pool_k, pool_v))
+    else:
+        page = pool_k.shape[2] // kvh
     o, lse = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, page=page,
-                          pages=pages, group=H // kvh),
+                          pages=pages, group=group or H // kvh),
         out_shape=[jax.ShapeDtypeStruct((B, H, hd), jnp.float32),
                    jax.ShapeDtypeStruct((B, H, 1), jnp.float32)],
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -728,3 +771,317 @@ def decode_chosen(qi, w, pool_i, layer, tables, lengths, new_rows, n_new,
                   jnp.broadcast_to(n_new, lengths.shape), n * page,
                   top_k=top_k, name=SELECT_KERNEL + DECODE)
     return mask[:, :n * page], mask[:, n * page:n * page + K] > 0
+
+
+# ------------------------------------------------------ selection by blocks
+class BlockSizes(NamedTuple):
+    """InfLLM-V2's sizes (``LlamaConfig.block_*``): a block of ``size``
+    tokens; ``topk`` blocks a query; compressed keys, the mean of
+    ``2 * stride`` keys every ``stride``; the first ``init`` blocks and
+    the blocks of the ``window`` newest tokens always chosen; a query
+    below ``dense_len`` attends over every visible key."""
+    size: int
+    topk: int
+    stride: int
+    init: int
+    window: int
+    dense_len: int
+
+    @property
+    def per(self) -> int:
+        """Compressed keys that begin in a block."""
+        return self.size // self.stride
+
+
+def stride_sums(k, valid, stride: int):
+    """float32 sums of the keys of every ``stride`` positions: k [...,
+    S, kvh, hd], valid bool [..., S] (a row that is no token adds
+    nothing) -> [..., S // stride, kvh, hd]. What the pool of compressed
+    keys holds: a compressed key is the mean of two neighbours."""
+    k = jnp.where(valid[..., None, None], k, 0).astype(jnp.float32)
+    return k.reshape(*k.shape[:-3], k.shape[-3] // stride, stride,
+                     *k.shape[-2:]).sum(-3)
+
+
+def compressed_keys(sums, sizes: BlockSizes, dtype):
+    """sums float32 [..., N, kvh, hd] (``stride_sums``) -> the compressed
+    keys [..., N, kvh, hd] in ``dtype``: c_j the mean of the keys of
+    strides j and j + 1 (the last one, which has no neighbour, is never
+    valid)."""
+    nxt = jnp.concatenate([sums[..., 1:, :, :],
+                           jnp.zeros_like(sums[..., :1, :, :])], -3)
+    return ((sums + nxt) / (2.0 * sizes.stride)).astype(dtype)
+
+
+def valid_compressed(q_pos, sizes: BlockSizes):
+    """How many compressed keys a query at ``q_pos`` may score: those
+    whose ``2 * stride`` tokens all lie at or before it."""
+    return jnp.maximum((q_pos + 1 - 2 * sizes.stride) // sizes.stride + 1, 0)
+
+
+def block_scores_xla(q, c, n_valid, *, scale):
+    B, H, T, hd = q.shape
+    kvh = c.shape[1]
+    s = jnp.einsum("bkgtd,bknd->bkgtn", q.reshape(B, kvh, H // kvh, T, hd),
+                   c, preferred_element_type=jnp.float32) * scale
+    seen = (jnp.arange(c.shape[2])[None, None, :]
+            < n_valid[..., None])[:, None, None]
+    s = jnp.where(seen, s, NEG_INF)
+    p = jnp.where(seen, jnp.exp(s - s.max(-1, keepdims=True)), 0.0)
+    return (p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)).sum(2)
+
+
+def _block_score_kernel(q_ref, c_ref, n_ref, o_ref, *, scale, group):
+    keys = c_ref[...]
+    seen = jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 1) < n_ref[...]
+    acc = jnp.zeros(o_ref.shape, jnp.float32)
+    for r in range(group):
+        s = jax.lax.dot_general(q_ref[r], keys, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(seen, s, NEG_INF)
+        p = jnp.where(seen, jnp.exp(s - s.max(-1, keepdims=True)), 0.0)
+        acc = acc + p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
+    o_ref[...] = acc
+
+
+def block_scores_tpu(q, c, n_valid, *, scale, interpret=False):
+    """The kernel: grid (row, KV head, query block); a step holds the
+    group's heads of a block of queries and ALL of the KV head's
+    compressed keys (2,048 of 128 at 32k tokens: 0.5 MB), one softmax a
+    head over them, and writes the heads' sum."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, T, hd = q.shape
+    kvh, N = c.shape[1:3]
+    group = H // kvh
+    pad = (-T) % 8
+    if pad:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        n_valid = jnp.pad(n_valid, ((0, 0), (0, pad)))
+    Tp = T + pad
+    tq = _block(Tp, 128, 8)
+    out = pl.pallas_call(
+        functools.partial(_block_score_kernel, scale=scale, group=group),
+        out_shape=jax.ShapeDtypeStruct((B, kvh, Tp, N), jnp.float32),
+        grid=(B, kvh, Tp // tq),
+        in_specs=[pl.BlockSpec((None, group, tq, hd),
+                               lambda b, g, i: (b, g, i, 0)),
+                  pl.BlockSpec((None, None, N, hd),
+                               lambda b, g, i: (b, g, 0, 0)),
+                  pl.BlockSpec((None, tq, 1), lambda b, g, i: (b, i, 0))],
+        out_specs=pl.BlockSpec((None, None, tq, N),
+                               lambda b, g, i: (b, g, i, 0)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=_VMEM),
+        interpret=interpret, name=BLOCK_SCORE_KERNEL,
+    )(q, c, n_valid[..., None].astype(jnp.int32))
+    return out[:, :, :T] if pad else out
+
+
+def block_scores(q, c, q_pos, sizes: BlockSizes, *, scale: float):
+    """The blocks' scores. q [B, H, T, hd] (heads first); c [B, kvh, N,
+    hd], the compressed keys of the positions side by side from 0; q_pos
+    int32 [B, T] (-1: no token). For every query head one softmax over
+    the compressed keys it may score; summed over a KV head's group of
+    heads (``rt_block_score``); a block's score is the largest among the
+    compressed keys whose tokens touch it (j from ``per * b - 1`` to
+    ``per * b + per - 1``). Returns float32 [B, kvh, T, N // per]; 0
+    where a block has no compressed key the query may score."""
+    with jax.named_scope("rt.attn.block.score"):
+        n_valid = valid_compressed(q_pos, sizes)
+        s = jax.lax.platform_dependent(
+            q, c, n_valid,
+            tpu=functools.partial(block_scores_tpu, scale=scale),
+            default=functools.partial(block_scores_xla, scale=scale))
+        per, N = sizes.per, c.shape[2]
+        shifted = jnp.pad(s, ((0, 0),) * 3 + ((1, 0),))     # s_{j - 1} at j
+        inside = shifted[..., :N].reshape(*s.shape[:3], N // per, per).max(-1)
+        return jnp.maximum(inside, shifted[..., per::per])
+
+
+_FORCED = 1e30
+
+
+def _ranked(scores, q_pos, sizes: BlockSizes):
+    """The scores as the choice ranks them: a block that is always
+    chosen (the first ``init``, those of the ``window`` newest tokens;
+    for a query below ``dense_len`` every visible one) above everything,
+    a block the query cannot see below everything. scores [..., T, NB];
+    q_pos [..., T] (broadcastable). Returns (ranked, own block + 1)."""
+    idx = jax.lax.broadcasted_iota(jnp.int32, scores.shape, scores.ndim - 1)
+    own = (jnp.maximum(q_pos, 0) // sizes.size)[..., None]
+    forced = ((idx < sizes.init) | (idx > own - sizes.window // sizes.size)
+              | (q_pos < sizes.dense_len)[..., None])
+    seen = (idx <= own) & (q_pos >= 0)[..., None]
+    return jnp.where(seen, jnp.where(forced, _FORCED, scores),
+                     -jnp.inf), jnp.where(q_pos >= 0, own[..., 0] + 1, 0)
+
+
+def block_choice(scores, q_pos, sizes: BlockSizes):
+    """Which blocks a query attends over, as a mask: scores float32
+    [B, kvh, T, NB] (``block_scores``); q_pos [B, T] -> int8 [B, kvh, T,
+    NB]. ``topk`` blocks a query and KV head, ties to the earlier block;
+    every visible block where the query lies below ``dense_len`` or sees
+    at most ``topk``."""
+    ranked, lim = _ranked(scores, q_pos[:, None], sizes)
+    lim = jnp.broadcast_to(lim, scores.shape[:-1])
+    mask = choose(ranked, lim, top_k=sizes.topk, name=SELECT_KERNEL)
+    idx = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 3)
+    dense = (q_pos < sizes.dense_len)[:, None, :, None]
+    return jnp.where(dense, (idx < lim[..., None]).astype(jnp.int8), mask)
+
+
+def block_attend(q, k, v, sums, q_pos, sizes: BlockSizes, *, scale: float,
+                 tile: int = QUERY_TILE):
+    """Attention by blocks where the keys lie side by side from position
+    0: q [T, H, hd]; k, v [S, kvh, hd]; sums float32 [S // stride, kvh,
+    hd] (``stride_sums`` of k); q_pos int32 [T], a query's position (-1:
+    no token, zeros come back) -> [T, H, hd] in q's dtype. A tile of
+    queries at a time: the blocks' scores, the choice, and the flash
+    forward under the chosen blocks' tokens up to the query's own, a KV
+    head after the other (the choice is a KV head's); a tile without a
+    token is skipped."""
+    T, H, hd = q.shape
+    S, kvh = k.shape[:2]
+    group = H // kvh
+    kt, vt = jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1)
+    c = jnp.swapaxes(compressed_keys(sums, sizes, q.dtype), 0, 1)[None]
+    key_at = jnp.arange(S, dtype=jnp.int32)
+
+    def one(rows):
+        q_t, at = rows
+        scores = block_scores(jnp.swapaxes(q_t, 0, 1)[None], c, at[None],
+                              sizes, scale=scale)
+        chosen_blocks = block_choice(scores, at[None], sizes)[0]
+        outs = []
+        for g in range(kvh):
+            with jax.named_scope("rt.attn.select"):
+                mask = (jnp.repeat(chosen_blocks[g], sizes.size, axis=-1)
+                        * (key_at[None, :] <= at[:, None])).astype(jnp.int8)
+            outs.append(masked_attention(
+                q_t[:, g * group:(g + 1) * group], kt[g:g + 1], vt[g:g + 1],
+                mask, jnp.maximum(at + 1, 0), scale=scale, blocks=True))
+        return jnp.concatenate(outs, 1)
+
+    tile = tile if T % tile == 0 else T
+    if tile == T:
+        return one((q, q_pos))
+    tiles = (q.reshape(T // tile, tile, H, hd), q_pos.reshape(-1, tile))
+    out = jax.lax.map(
+        lambda t: jax.lax.cond(t[1].max() >= 0, one,
+                               lambda t: jnp.zeros(t[0].shape, q.dtype), t),
+        tiles)
+    return out.reshape(T, H, hd)
+
+
+def block_decode_pages(q, old_sums, new_k, n_new, tables, lengths,
+                       sizes: BlockSizes, *, scale: float):
+    """A decode step's choice of pages, one query a slot (a page is a
+    block). q [B, H, hd]; old_sums float32 [B, n * per, kvh, hd], the
+    pool's sums of the slot's listed pages (``tables`` int32 [B, n]);
+    zeros where a stride holds no cached position;
+    lengths int32 [B], the positions a slot has cached; new_k [B, K, kvh,
+    hd], the burst's own keys, of which the first ``n_new`` (this step's
+    among them) are visible: the query sits at ``lengths + n_new - 1``.
+
+    Returns, a row a (slot, KV head) pair (the choice is a KV head's),
+    what ``block_decode_attention`` takes: (pages int32 [B * kvh, W], the
+    chosen pages' ids; lengths int32 [B * kvh], the positions listed;
+    seen int32 [B * kvh, W, size * kvh]: 0 beside a (position, KV head)
+    row that is cached, the pair's own KV head's and on a chosen page,
+    else -1)."""
+    B, H, hd = q.shape
+    n = tables.shape[1]
+    K, kvh = new_k.shape[1:3]
+    size, stride, per = sizes.size, sizes.stride, sizes.per
+    q_pos = jnp.where(lengths > 0, lengths + n_new - 1, -1)
+    with jax.named_scope("rt.attn.block.score"):
+        # the strides' sums as the query sees them: the pool's, where a
+        # stride holds a cached position, and the burst's own rows
+        at = jnp.arange(n * per)
+        row_at = lengths[:, None] + jnp.arange(K)[None, :]       # [B, K]
+        mine = ((row_at // stride)[..., None] == at[None, None, :]) & (
+            jnp.arange(K)[None, :, None] < n_new)
+        sums = old_sums + jnp.einsum("bkn,bkgd->bngd",
+                                     mine.astype(jnp.float32),
+                                new_k.astype(jnp.float32),
+                                precision="highest")
+        c = jnp.swapaxes(compressed_keys(sums, sizes, q.dtype), 1, 2)
+    scores = block_scores(q[:, :, None], c, q_pos[:, None], sizes,
+                          scale=scale)[:, :, 0]                 # [B, kvh, n]
+    with jax.named_scope("rt.attn.select"):
+        ranked, visible = _ranked(scores, q_pos[:, None], sizes)
+        W = min(n, max(sizes.topk, sizes.dense_len // size))
+        _, idx = jax.lax.top_k(ranked, W)                   # [B, kvh, W]
+        most = jnp.where(q_pos < sizes.dense_len, W, sizes.topk)
+        n_chosen = jnp.minimum(visible, most[:, None])          # [B, 1]
+        kept = jnp.arange(W)[None, None, :] < n_chosen[..., None]
+        pages = jnp.where(kept, jnp.take_along_axis(
+            tables[:, None, :], idx, axis=2), 0)
+        # a listed page's positions that are cached, and whose rows are
+        # the pair's own KV head's
+        cached = kept[..., None] & (
+            idx[..., None] * size + jnp.arange(size) < lengths[
+                :, None, None, None])                      # [B, kvh, W, size]
+        head = jnp.arange(kvh)
+        seen = jnp.where(
+            cached[..., None] & (head[None, :, None, None, None]
+                                 == head[None, None, None, None, :]), 0, -1)
+        return (pages.reshape(B * kvh, W).astype(jnp.int32),
+                jnp.broadcast_to(n_chosen * size, (B, kvh)).reshape(
+                    -1).astype(jnp.int32),
+                seen.reshape(B * kvh, W, size * kvh).astype(jnp.int32))
+
+
+def block_decode_attention_xla(q, pool_k, pool_v, layer, pages, lengths,
+                               seen, *, scale):
+    del lengths
+    R, G, hd = q.shape
+    rows_k, rows_v = (
+        jnp.take(jax.lax.dynamic_index_in_dim(pool, layer, 0, False), pages,
+                 axis=0).reshape(R, -1, hd) for pool in (pool_k, pool_v))
+    keep = (seen.reshape(R, -1) >= 0)[:, None, :]
+    s = jnp.einsum("rgd,rsd->rgs", q, rows_k,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(keep, s, NEG_INF)
+    m = s.max(-1)
+    p = jnp.where(keep, jnp.exp(s - m[..., None]), 0.0)
+    l = jnp.maximum(p.sum(-1), 1e-30)
+    o = jnp.einsum("rgs,rsd->rgd", p.astype(rows_v.dtype), rows_v,
+                   preferred_element_type=jnp.float32)
+    return o / l[..., None], m + jnp.log(l)
+
+
+def block_decode_attention_tpu(q, pool_k, pool_v, layer, pages, lengths,
+                               seen, *, kvh, scale, interpret=False):
+    most = max(1, _STEP_BYTES // (pool_k.shape[2] * q.shape[-1]
+                                  * pool_k.dtype.itemsize))
+    return _decode_call(
+        q, pool_k, pool_v, layer, pages, lengths, seen,
+        pages=_block(pages.shape[1], 1 << most.bit_length() - 1, 1),
+        scale=scale, group=q.shape[1], kvh=kvh, interpret=interpret)
+
+
+def block_decode_attention(q, pool_k, pool_v, layer, pages, lengths, seen,
+                           *, kvh: int, scale: float):
+    """One query a slot over the cached tokens of the pages it chose, a
+    KV head's group of heads a row: q [B, H, hd]; pool_k, pool_v [L, P,
+    page * kvh, hd], a page ONE matrix of its (position, KV head) rows
+    (llm/cache.py keeps them so for these layers); ``pages``,
+    ``lengths`` and ``seen`` from ``block_decode_pages``.
+    ``rt_sparse_attend_decode`` walks the LISTED pages and no other: a
+    page that was not chosen is not read. Returns (o float32 [B, H, hd],
+    lse float32 [B, H]) for ``join_new_rows``."""
+    B, H, hd = q.shape
+    rows = pages.shape[0]
+    with jax.named_scope("rt.attn.sparse"):
+        o, lse = jax.lax.platform_dependent(
+            q.reshape(rows, -1, hd), pool_k, pool_v,
+            jnp.asarray(layer, jnp.int32), pages, lengths, seen,
+            tpu=functools.partial(block_decode_attention_tpu, kvh=kvh,
+                                  scale=scale),
+            default=functools.partial(block_decode_attention_xla,
+                                      scale=scale))
+        return o.reshape(B, H, hd), lse.reshape(B, H)

@@ -86,7 +86,7 @@ def test_the_configuration_is_the_shape_of_the_problem():
     assert CFG.indexer_row == 128 and CFG.kv_groups == (None,)
     with pytest.raises(ValueError, match="all of them"):
         dataclasses.replace(CFG, indexer_dim=0)
-    with pytest.raises(ValueError, match="one kind of layer"):
+    with pytest.raises(ValueError, match="ONE stack of full, rotated GQA layers"):
         dataclasses.replace(CFG, layer_pattern=("full", "window"), window=8,
                             n_layers=4)
     with pytest.raises(ValueError, match="set qk_norm too"):

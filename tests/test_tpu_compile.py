@@ -791,3 +791,71 @@ def test_sparse_decode_burst_reads_the_pools_where_they_lie(one_chip):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= sum(p.size * 2 for p in pools), mem
     assert mem.temp_size_in_bytes < 0.6 * 1024**3, mem
+
+
+# --- state layers (ops/linear_attention.py) and selection by blocks
+# (ops/sparse_attention.py), at MiniCPM-SALA's widths
+def test_state_layer_kernels_compile_alone(one_chip):
+    """Each of the new kernels at the cell's shapes: Mosaic takes their
+    tiles, and the decode step updates the state pool in place."""
+    from ray_tpu.ops import linear_attention as la
+    from ray_tpu.ops import sparse_attention as sparse
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    slopes = la.slopes_of(32)
+    rows = _sds((1, 16384, 32, 128), bf16, one_chip)
+    assert _custom_calls(
+        lambda q, k, v, n, s: la.prefill_tpu(q, k, v, slopes, n, s,
+                                             scale=128 ** -0.5),
+        rows, rows, rows, _sds((1,), jnp.int32, one_chip),
+        _sds((1, 32, 128, 128), f32, one_chip)) == 1
+    step = _sds((16, 32, 128), bf16, one_chip)
+    pool = _sds((9, 16, 32, 128, 128), f32, one_chip)
+    compiled = jax.jit(
+        lambda q, k, v, p, a: la.decode_step_tpu(
+            q, k, v, p, jnp.int32(4), a, *la.live_order(a), slopes,
+            scale=128 ** -0.5),
+        donate_argnums=(3,)).lower(
+            step, step, step, pool,
+            _sds((16,), jnp.bool_, one_chip)).compile()
+    assert "%" + la.DECODE_KERNEL in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # the 302 MB pool is aliased, not copied
+    assert mem.alias_size_in_bytes >= 9 * 16 * 32 * 128 * 128 * 4, mem
+    assert mem.temp_size_in_bytes < 16 * 1024**2, mem
+    # the scores of 2,048 compressed keys, a tile of 512 queries
+    assert _custom_calls(
+        functools.partial(sparse.block_scores_tpu, scale=128 ** -0.5),
+        _sds((1, 32, 512, 128), bf16, one_chip),
+        _sds((1, 2, 2048, 128), bf16, one_chip),
+        _sds((1, 512), jnp.int32, one_chip)) == 1
+    # the flash forward under a mask chosen by blocks, a KV head's group
+    text = jax.jit(functools.partial(
+        sparse.masked_attention_tpu, scale=128 ** -0.5, blocks=True)).lower(
+            _sds((512, 16, 128), bf16, one_chip),
+            _sds((1, 32768, 128), bf16, one_chip),
+            _sds((1, 32768, 128), bf16, one_chip),
+            _sds((512, 32768), jnp.int8, one_chip),
+            _sds((512,), jnp.int32, one_chip)).compile().as_text()
+    assert "%" + sparse.BLOCK_PREFILL_KERNEL in text
+
+
+def test_block_decode_reads_the_listed_pages_where_they_lie(one_chip):
+    """A decode step's attention over the chosen pages: the pools are
+    page matrices of (position, KV head) rows and go to the kernel as
+    they are: no copy of a pool, whatever the number of KV heads."""
+    from ray_tpu.ops import sparse_attention as sparse
+
+    pool = _sds((3, 8193, 128, 128), jnp.bfloat16, one_chip)
+    compiled = jax.jit(functools.partial(
+        sparse.block_decode_attention_tpu, kvh=2, scale=128 ** -0.5)).lower(
+            _sds((32, 16, 128), jnp.bfloat16, one_chip), pool, pool,
+            _sds((), jnp.int32, one_chip),
+            _sds((32, 128), jnp.int32, one_chip),
+            _sds((32,), jnp.int32, one_chip),
+            _sds((32, 128, 128), jnp.int32, one_chip)).compile()
+    text = compiled.as_text()
+    assert "%" + sparse.DECODE_KERNEL in text
+    assert not [line for line in text.splitlines()
+                if " = bf16[3,8193,128,128]" in line and " copy(" in line]
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024**2
