@@ -301,6 +301,10 @@ class LLMEngine:
             # of prefill's rows that are tokens
             "prefill_bucket_tokens": 0,
             "preemptions": 0, "host_s": dict.fromkeys(PHASES, 0.0),
+            # what ``load_decode_programs`` did before the replica was
+            # ready: the programs it ran once, and the seconds that took
+            # (trace, lowering, compile or the cache's read, one run)
+            "loaded_programs": 0, "load_s": 0.0,
             # the bursts' page lists, summed over rounds: pages that hold
             # old context of decoding slots, pages the burst copied
             # (their ratio: the share of the copy that was needed), and
@@ -1030,28 +1034,35 @@ class LLMEngine:
         request compiles its sampler on first use, as before. For a
         server to call before it reports ready, not for ``__init__``: a
         bare engine (the tests build dozens) compiles what it meets.
-        Returns the number of programs."""
+        One span, ``rt.engine.load``; its seconds and the programs are
+        added to the counters ``load_s`` and ``loaded_programs``, which
+        nothing else moves. Returns the number of programs."""
         B = self.ecfg.max_num_seqs
         zi, zf = jnp.zeros(B, jnp.int32), jnp.ones(B, jnp.float32)
         lora = None
         if self.lora_pool is not None:
             lora = self.lora_pool.select([0] * B)
         buckets = self.decode_buckets()
-        for shape in buckets:
-            if self._reads_own_pages:
-                lists = (jnp.zeros((B, shape), jnp.int32),)
-            else:
-                lists = tuple(jnp.asarray(burst_gather(
-                    t.block_tables, self.ecfg.page_size, bucket, ()))
-                    for t, bucket in zip(self.seq_tables, (
-                        shape if isinstance(shape, tuple) else (shape,))))
-            self._run(
-                decode_burst, zi, zi,
-                self._tables(), jnp.zeros(B, bool), self.cos, self.sin, 0,
-                zf, zi, zf, lora,
-                lists if len(lists) > 1 else lists[0], jnp.int32(1),
-                cfg=self.cfg, n_steps=self.ecfg.decode_burst, greedy=True)
-        jax.block_until_ready(self.cache.k)
+        with tracing.span("rt.engine.load") as sp:
+            for shape in buckets:
+                if self._reads_own_pages:
+                    lists = (jnp.zeros((B, shape), jnp.int32),)
+                else:
+                    lists = tuple(jnp.asarray(burst_gather(
+                        t.block_tables, self.ecfg.page_size, bucket, ()))
+                        for t, bucket in zip(self.seq_tables, (
+                            shape if isinstance(shape, tuple)
+                            else (shape,))))
+                self._run(
+                    decode_burst, zi, zi,
+                    self._tables(), jnp.zeros(B, bool), self.cos, self.sin,
+                    0, zf, zi, zf, lora,
+                    lists if len(lists) > 1 else lists[0], jnp.int32(1),
+                    cfg=self.cfg, n_steps=self.ecfg.decode_burst,
+                    greedy=True)
+            jax.block_until_ready(self.cache.k)
+        self._counters["loaded_programs"] += len(buckets)
+        self._counters["load_s"] += sp.seconds
         return len(buckets)
 
     def _sampling_arrays(self, row_states, advance: int = 1):

@@ -205,15 +205,43 @@ def prefill_tpu(q, k, v, slopes, lengths, state, *, scale, chunk=CHUNK,
     return jnp.swapaxes(o, 1, 2), state
 
 
+# ``prefill`` and ``decode_step`` are jitted on their own: the layers of a
+# program whose layers run one after the other (weights a layer kind) call
+# them with the same shapes, so a program holds ONE traced and lowered body
+# of each however many layers call it. The compiler inlines the calls; the
+# kernels, the scope and every precision are what they were. The slopes
+# are constants of the body (the kernels are handed them as an array made
+# while tracing), so they are static: a tuple of floats, which is what
+# ``LlamaConfig.linear_decay`` is.
+def _static_slopes(slopes, where: str) -> None:
+    """Refuse, before jit hashes it or a trace holds it, a ``slopes``
+    that is not a tuple of floats (an array cannot key a jitted
+    function, and a tracer would key a new body every call)."""
+    if not (isinstance(slopes, tuple)
+            and all(isinstance(s, float) for s in slopes)):
+        raise TypeError(
+            f"{where}: slopes is static to the jitted function and has to "
+            f"be a tuple of floats (LlamaConfig.linear_decay, or "
+            f"tuple(map(float, slopes_of(heads)))), not "
+            f"{type(slopes).__name__}")
+
+
 def prefill(q, k, v, slopes, lengths=None, state=None, *, scale: float):
     """The rows' outputs and the state behind their last token. q, k, v
-    [B, S, H, hd]; slopes [H] (``-log`` of a head's decay a token);
+    [B, S, H, hd]; slopes, a tuple of H floats (``-log`` of a head's
+    decay a token; static);
     lengths int32 [B]: the rows that are tokens are the first
     ``lengths`` (None: all); state float32 [B, H, hd, hd], the state
     before the first row (None: zeros). Returns (o float32 [B, S, H,
     hd]; state float32 [B, H, hd, hd]). Rows that are no whole number of
     chunks of 128 (a short bucket, a speculative window) take the
     recurrence itself."""
+    _static_slopes(slopes, "linear_attention.prefill")
+    return _prefill(q, k, v, slopes, lengths, state, scale=scale)
+
+
+@functools.partial(jax.jit, static_argnames=("slopes", "scale"))
+def _prefill(q, k, v, slopes, lengths, state, *, scale):
     B, S, H, hd = q.shape
     with jax.named_scope("rt.attn.linear"):
         if lengths is None:
@@ -338,7 +366,15 @@ def decode_step(q, k, v, pool, layer, active, slopes, *, scale: float,
     address; active bool [B]; ``order``: ``live_order(active)``, where
     the caller has it already. Returns (o float32 [B, H, hd], zeros for
     a slot that is not active; the pool, the active slots' states of the
-    layer advanced by the token, everything else as it was)."""
+    layer advanced by the token, everything else as it was). ``slopes``
+    as ``prefill`` takes them."""
+    _static_slopes(slopes, "linear_attention.decode_step")
+    return _decode_step(q, k, v, pool, layer, active, slopes, scale=scale,
+                        order=order)
+
+
+@functools.partial(jax.jit, static_argnames=("slopes", "scale"))
+def _decode_step(q, k, v, pool, layer, active, slopes, *, scale, order):
     with jax.named_scope("rt.attn.linear"):
         order, live = live_order(active) if order is None else order
         return jax.lax.platform_dependent(

@@ -933,6 +933,24 @@ def block_choice(scores, q_pos, sizes: BlockSizes):
     return jnp.where(dense, (idx < lim[..., None]).astype(jnp.int8), mask)
 
 
+# ``block_attend``, ``block_decode_pages`` and ``block_decode_attention``
+# are jitted on their own: the layers of a program whose layers run one
+# after the other (weights a layer kind) call them with the same shapes,
+# so a program holds ONE traced and lowered body of each however many
+# layers call it (``ops/linear_attention.py`` does the same for the
+# linear form). The compiler inlines the calls; the kernels, the scopes
+# and every precision are what they were. The sizes shape the body, so
+# they are static.
+def _static_sizes(sizes, where: str) -> None:
+    """Refuse, before jit hashes it or a trace holds it, ``sizes`` that
+    are no ``BlockSizes`` (an array cannot key a jitted function)."""
+    if not isinstance(sizes, BlockSizes):
+        raise TypeError(
+            f"{where}: sizes is static to the jitted function and has to "
+            f"be a BlockSizes (LlamaConfig.block_sizes), not "
+            f"{type(sizes).__name__}")
+
+
 def block_attend(q, k, v, sums, q_pos, sizes: BlockSizes, *, scale: float,
                  tile: int = QUERY_TILE):
     """Attention by blocks where the keys lie side by side from position
@@ -943,6 +961,12 @@ def block_attend(q, k, v, sums, q_pos, sizes: BlockSizes, *, scale: float,
     forward under the chosen blocks' tokens up to the query's own, a KV
     head after the other (the choice is a KV head's); a tile without a
     token is skipped."""
+    _static_sizes(sizes, "sparse_attention.block_attend")
+    return _block_attend(q, k, v, sums, q_pos, sizes, scale=scale, tile=tile)
+
+
+@functools.partial(jax.jit, static_argnames=("sizes", "scale", "tile"))
+def _block_attend(q, k, v, sums, q_pos, sizes, *, scale, tile):
     T, H, hd = q.shape
     S, kvh = k.shape[:2]
     group = H // kvh
@@ -992,6 +1016,14 @@ def block_decode_pages(q, old_sums, new_k, n_new, tables, lengths,
     seen int32 [B * kvh, W, size * kvh]: 0 beside a (position, KV head)
     row that is cached, the pair's own KV head's and on a chosen page,
     else -1)."""
+    _static_sizes(sizes, "sparse_attention.block_decode_pages")
+    return _block_decode_pages(q, old_sums, new_k, n_new, tables, lengths,
+                               sizes, scale=scale)
+
+
+@functools.partial(jax.jit, static_argnames=("sizes", "scale"))
+def _block_decode_pages(q, old_sums, new_k, n_new, tables, lengths, sizes, *,
+                        scale):
     B, H, hd = q.shape
     n = tables.shape[1]
     K, kvh = new_k.shape[1:3]
@@ -1064,6 +1096,7 @@ def block_decode_attention_tpu(q, pool_k, pool_v, layer, pages, lengths,
         scale=scale, group=q.shape[1], kvh=kvh, interpret=interpret)
 
 
+@functools.partial(jax.jit, static_argnames=("kvh", "scale"))
 def block_decode_attention(q, pool_k, pool_v, layer, pages, lengths, seen,
                            *, kvh: int, scale: float):
     """One query a slot over the cached tokens of the pages it chose, a

@@ -7,6 +7,8 @@ gathered rectangle.
 float32; the kernels run in Pallas's interpret mode here.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -266,3 +268,90 @@ def test_the_decode_steps_pages_are_the_gathered_rectangle(rows, form):
                 rtol=2e-4, atol=2e-5)
     # the idle slot lists nothing
     assert int(lens[2]) == 0 and int(lens[3]) == 0
+
+
+# ------------------------------------------------- jitted on their own
+def _decode_rows(rows):
+    """A decode step's operands: 4 slots of 16 pages, one of them idle."""
+    q, _, _ = rows
+    B, P, n, K = 4, 70, 16, 4
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    pools = tuple(jax.random.normal(key, (2, P, 64 * KVH, HD), jnp.float32)
+                  for key in ks[:2])
+    tables = jnp.asarray(np.random.default_rng(1).permutation(
+        np.arange(1, P))[:B * n].reshape(B, n), jnp.int32)
+    lengths = jnp.asarray([700, 0, 1000, 200], jnp.int32)
+    keys = jnp.take(pools[0][1], tables, axis=0).reshape(B, -1, KVH, HD)
+    old = sp.stride_sums(keys, jnp.arange(n * 64)[None, :] < lengths[:, None],
+                         SIZES.stride)
+    new_k = jax.random.normal(ks[2], (B, K, KVH, HD), jnp.float32)
+    return q[:B], pools, tables, lengths, old, new_k
+
+
+def _attend_case(rows, sizes):
+    q, k, v = rows
+    sums, _ = _compressed(k)
+    # two tiles of 64 queries, the second without a token: skipped
+    q_pos = jnp.arange(S - 128, S).at[64:].set(-1).at[0].set(130)
+    return (lambda f, *a: f(*a, sizes, scale=SCALE, tile=64),
+            (q[:128], k, v, sums, q_pos))
+
+
+def _pages_case(rows, sizes):
+    q, _, tables, lengths, old, new_k = _decode_rows(rows)
+    return (lambda f, *a: f(*a, sizes, scale=SCALE),
+            (q, old, new_k, jnp.int32(2), tables, lengths))
+
+
+def _attention_case(rows, sizes):
+    q, pools, tables, lengths, old, new_k = _decode_rows(rows)
+    listed = sp.block_decode_pages(q, old, new_k, 2, tables, lengths, sizes,
+                                   scale=SCALE)
+    return (lambda f, *a: f(*a, kvh=KVH, scale=SCALE),
+            (q, *pools, jnp.int32(1), *listed))
+
+
+CASES = {"block_attend": (sp._block_attend, _attend_case),
+         "block_decode_pages": (sp._block_decode_pages, _pages_case),
+         "block_decode_attention": (sp.block_decode_attention,
+                                    _attention_case)}
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_a_program_that_calls_the_jitted_function_has_its_bodys_bits(
+        rows, name):
+    """Each of the three is jitted on its own (the block layers of a
+    program then share ONE traced body of it). A program that calls it
+    computes the same bits as one that holds the undecorated body
+    inline, as every program did, on the plain-jax twins the CPU takes.
+    (Both sides are a program: run op by op the body's sums are not
+    fused and differ in the last bit.)"""
+    jitted, case = CASES[name]
+    call, operands = case(rows, SIZES)
+    got = jax.jit(functools.partial(call, getattr(sp, name)))(*operands)
+    want = jax.jit(functools.partial(call, jitted.__wrapped__))(*operands)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                    strict=True):
+        assert a.dtype == b.dtype and bool((a == b).all())
+
+
+@pytest.mark.parametrize("name", ["block_attend", "block_decode_pages"])
+@pytest.mark.parametrize("kind", ["numpy", "jax", "tuple", "tracer"])
+def test_sizes_that_are_no_block_sizes_are_refused_by_name(rows, name, kind):
+    """The sizes key the jitted function: an array cannot, and a tracer
+    would key a new body every call. The public function refuses both
+    by name, before the jitted function is asked for anything."""
+    jitted, case = CASES[name]
+
+    def call(sizes):
+        run, operands = case(rows, sizes)
+        return run(getattr(sp, name), *operands)
+
+    before = jitted._cache_size()
+    with pytest.raises(TypeError, match=f"{name}: sizes .* BlockSizes"):
+        if kind == "tracer":
+            jax.jit(call)(jnp.asarray(SIZES))
+        else:
+            call({"numpy": np.asarray(SIZES), "jax": jnp.asarray(SIZES),
+                  "tuple": tuple(SIZES)}[kind])
+    assert jitted._cache_size() == before

@@ -9,6 +9,8 @@ about 1. The kernels run in Pallas's interpret mode here; what Mosaic
 makes of them is ``tests/test_tpu_compile.py``'s and the chip's.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,6 +21,9 @@ from ray_tpu.ops import linear_attention as la
 H, HD = 4, 128
 SCALE = HD ** -0.5
 TOLERANCE = 5e-5
+# the slopes as ``prefill`` and ``decode_step`` take them (static to the
+# jitted functions): what ``LlamaConfig.linear_decay`` is
+DECAY = tuple(map(float, la.slopes_of(H)))
 
 
 def _rows(seed, batch, seq):
@@ -64,7 +69,7 @@ def test_chunked_form_is_the_recurrence(form, lengths):
 def test_rows_that_are_no_whole_chunks_take_the_recurrence():
     q, k, v, s0 = _rows(2, 1, 40)
     slopes = la.slopes_of(H)
-    o, s = la.prefill(q, k, v, slopes, jnp.asarray([33], jnp.int32), s0,
+    o, s = la.prefill(q, k, v, DECAY, jnp.asarray([33], jnp.int32), s0,
                       scale=SCALE)
     want_o, want_s = la.recurrence(q, k, v, slopes,
                                    jnp.asarray([33], jnp.int32), s0,
@@ -140,11 +145,72 @@ def test_prefill_then_decode_is_one_recurrence():
     q, k, v, _ = _rows(8, 1, 256 + 3)
     slopes = la.slopes_of(H)
     want_o, want_s = la.recurrence(q, k, v, slopes, scale=SCALE)
-    _, s = la.prefill(q[:, :256], k[:, :256], v[:, :256], slopes,
+    _, s = la.prefill(q[:, :256], k[:, :256], v[:, :256], DECAY,
                       scale=SCALE)
     pool = jnp.zeros((1, 1, H, HD, HD), jnp.float32).at[0].set(s)
     for t in range(256, 259):
         o, pool = la.decode_step(q[:, t], k[:, t], v[:, t], pool, 0,
-                                 jnp.ones(1, bool), slopes, scale=SCALE)
+                                 jnp.ones(1, bool), DECAY, scale=SCALE)
         assert _close(o, want_o[:, t]) < TOLERANCE
     assert _close(pool[0], want_s) < TOLERANCE
+
+
+# ------------------------------------------------- jitted on their own
+def _prefill_case(slopes):
+    q, k, v, s0 = _rows(9, 1, 256)
+    return (lambda f, q, k, v, lens, s0: f(q, k, v, slopes, lens, s0,
+                                           scale=SCALE),
+            (q, k, v, jnp.asarray([200], jnp.int32), s0))
+
+
+def _decode_case(slopes):
+    q, k, v, _ = _rows(10, 4, 1)
+    pool = jax.random.normal(jax.random.PRNGKey(11), (2, 4, H, HD, HD),
+                             jnp.float32)
+    return (lambda f, q, k, v, pool, active: f(
+                q, k, v, pool, 1, active, slopes, scale=SCALE,
+                order=la.live_order(active)),
+            (q[:, 0], k[:, 0], v[:, 0], pool,
+             jnp.asarray([True, False, True, True])))
+
+
+CASES = {"prefill": _prefill_case, "decode_step": _decode_case}
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_a_program_that_calls_the_jitted_function_has_its_bodys_bits(name):
+    """The public function hands its arguments to a function jitted on
+    its own (the layers of a program then share ONE traced body of it).
+    A program that calls it computes the same bits as one that holds
+    that function's undecorated body inline, as every program did, on
+    the plain-jax twin the CPU takes. (Both sides are a program: run op
+    by op the body's sums are not fused and differ in the last bit.)"""
+    call, rows = CASES[name](DECAY)
+    got = jax.jit(functools.partial(call, getattr(la, name)))(*rows)
+    want = jax.jit(functools.partial(
+        call, getattr(la, "_" + name).__wrapped__))(*rows)
+    for a, b in zip(got, want, strict=True):
+        assert a.dtype == b.dtype and bool((a == b).all())
+
+
+@pytest.mark.parametrize("name", CASES)
+@pytest.mark.parametrize("kind", ["numpy", "jax", "list", "tracer"])
+def test_slopes_that_are_no_tuple_of_floats_are_refused_by_name(name, kind):
+    """The slopes key the jitted function: an array cannot, and a tracer
+    (a program that made its slopes an operand) would key a new body
+    every call. The public function refuses both by name, before the
+    jitted function is asked for anything."""
+    jitted, array = getattr(la, "_" + name), jnp.asarray(la.slopes_of(H))
+
+    def call(slopes):
+        run, rows = CASES[name](slopes)
+        return run(getattr(la, name), *rows)
+
+    before = jitted._cache_size()
+    with pytest.raises(TypeError, match=f"{name}: slopes .* tuple of floats"):
+        if kind == "tracer":
+            jax.jit(call)(array)
+        else:
+            call({"numpy": la.slopes_of(H), "jax": array,
+                  "list": list(DECAY)}[kind])
+    assert jitted._cache_size() == before

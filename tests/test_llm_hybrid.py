@@ -353,6 +353,58 @@ def test_the_programs_carry_their_spans(params):
         assert span in text, span
 
 
+def _calls(jaxpr, found):
+    """name -> the bodies of the jitted functions a jaxpr calls."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("pjit", "jit"):
+            found.setdefault(eqn.params["name"], []).append(
+                id(eqn.params["jaxpr"]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _calls(sub, found)
+    return found
+
+
+def test_the_layers_functions_are_traced_once_a_program(params):
+    """24 linear layers one after the other call the linear form with
+    the same shapes and the 8 sparse ones the selection by blocks: each
+    is a jitted function of its own, and a program holds ONE traced body
+    of it, lowered to one function, however many layers call it (a
+    program is loaded before a replica is ready: tracing the same
+    function a layer was most of what that cost)."""
+    from ray_tpu.llm.runner import decode_burst
+
+    kinds = CFG.layer_kinds
+    n_linear = sum(k == "linear" for k in kinds)
+    n_sparse = len(kinds) - n_linear
+    assert (n_linear, n_sparse) == (24, 8)
+    cos, sin = rope_frequencies(CFG.rope_dim, CFG.max_seq, CFG.rope_theta)
+    cache = init_kv_cache(CFG, 33, PAGE, slots=2)
+    table = jnp.arange(1, 17, dtype=jnp.int32).reshape(1, 16)
+    z, f = jnp.zeros(2, jnp.int32), jnp.ones(2, jnp.float32)
+    tables = jnp.zeros((2, 16), jnp.int32)
+    programs = {
+        "prefill": (prefill.trace(
+            params, cache.k, cache.v, jnp.zeros((1, 64), jnp.int32),
+            jnp.asarray([60], jnp.int32), table, cos, sin, None, None,
+            cache.c, cache.s, jnp.asarray([0], jnp.int32), cfg=CFG),
+            {"_prefill": n_linear, "_block_attend": n_sparse}),
+        "decode_burst": (decode_burst.trace(
+            params, cache.k, cache.v, z, z, tables, jnp.zeros(2, bool), cos,
+            sin, 0, f, z, f, None, tables, jnp.int32(1), None, cache.c,
+            cache.s, cfg=CFG, n_steps=BURST, greedy=True),
+            {"_decode_step": n_linear, "_block_decode_pages": n_sparse,
+             "block_decode_attention": n_sparse})}
+    for program, (traced, expected) in programs.items():
+        found = _calls(traced.jaxpr.jaxpr, {})
+        text = traced.lower().as_text()
+        for name, layers in expected.items():
+            assert len(found[name]) == layers, (program, name)
+            assert len(set(found[name])) == 1, (program, name)
+            assert text.count(f"func.func private @{name}(") == 1, (
+                program, name)
+            assert text.count(f"call @{name}(") == layers, (program, name)
+
+
 # ---------------------------------------------------------------- the others
 # The five accepted families' seeded weights and a fixed prompt's prefill
 # logits as the tree BEFORE the state layers made them (PR 44's, read

@@ -185,7 +185,9 @@ def test_gather_counters_by_hand(tiny_params, slots, num_pages, prompts,
 def test_a_loaded_engine_compiles_no_decode_program(tiny_params):
     """``load_decode_programs`` (what ``LLMServer`` calls before it is
     ready) runs every bucket a page list can take, with no slot active:
-    no page changes, no counter moves, ``_burst_width`` is not called;
+    no page changes, no counter moves but its own two (``loaded_programs``
+    and ``load_s``, which nothing else moves), ``_burst_width`` is not
+    called;
     and a mixed run after it (contexts of 3 to 40 tokens, 1 to 3 slots
     decoding, bursts of 1 to 4) adds no entry to ``decode_burst``'s
     compile cache: a width is an operand, a bucket is loaded. Three
@@ -202,8 +204,13 @@ def test_a_loaded_engine_compiles_no_decode_program(tiny_params):
     buckets = engine.decode_buckets()
     assert buckets == [16, 32, 36]
     assert {engine._flat_bucket(n) for n in range(1, 37)} == set(buckets)
+    assert (before[1]["loaded_programs"], before[1]["load_s"]) == (0, 0.0)
     assert engine.load_decode_programs() == len(buckets)
-    assert not calls and engine.stats()["counters"] == before[1]
+    loaded = engine.stats()["counters"]
+    assert loaded["loaded_programs"] == len(buckets)
+    assert isinstance(loaded["load_s"], float) and loaded["load_s"] > 0.0
+    own = {"loaded_programs": 0, "load_s": 0.0}
+    assert not calls and {**loaded, **own} == before[1]
     np.testing.assert_array_equal(np.asarray(engine.cache.k), before[0])
     size = decode_burst._cache_size()
     for i, n in enumerate([40, 38, 36, 3, 17, 9, 5, 24]):
@@ -215,6 +222,13 @@ def test_a_loaded_engine_compiles_no_decode_program(tiny_params):
     c = engine.stats()["counters"]
     assert c["rounds"] == len(calls) > 3 and len(set(calls)) > 1
     assert decode_burst._cache_size() == size
+    # serving moved neither; a second load adds to both
+    assert (c["loaded_programs"], c["load_s"]) == (
+        len(buckets), loaded["load_s"])
+    engine.load_decode_programs()
+    c = engine.stats()["counters"]
+    assert c["loaded_programs"] == 2 * len(buckets)
+    assert c["load_s"] > loaded["load_s"]
     assert set(c["gather_hist"]) <= set(buckets)
     assert len(c["gather_hist"]) > 1
 
@@ -343,6 +357,27 @@ def engine_trace(tiny_params, tmp_path_factory):
     events = _trace(tmp_path_factory.mktemp("engine_trace"), work)
     return (events, engine.stats()["counters"]["prefills"] - warm_up,
             len(rounds))
+
+
+def test_loading_the_decode_programs_is_one_span(tiny_params, tmp_path):
+    """``rt.engine.load`` is a span on the profiler's clock, one a call
+    of ``load_decode_programs``, and ``load_s`` is its length; serving
+    requests opens none."""
+    engine = LLMEngine(tiny_params, CFG, EngineConfig(
+        max_num_seqs=2, page_size=4, num_pages=64, max_seq_len=64))
+
+    def work():
+        engine.load_decode_programs()
+        engine.add_request(PROMPTS[0], SamplingParams(temperature=0.0,
+                                                      max_tokens=4))
+        while engine.has_unfinished():
+            engine.step()
+
+    events = _trace(tmp_path, work)
+    loads = [e for e in events if e[1] == "rt.engine.load"]
+    assert len(loads) == 1
+    assert (loads[0][3] - loads[0][2]) / 1e9 == pytest.approx(
+        engine.stats()["counters"]["load_s"], abs=0.05)
 
 
 @pytest.mark.parametrize("name", ENGINE_SPANS)
