@@ -520,6 +520,148 @@ def _sparse_kernel_parity(seed: int) -> dict:
     return report
 
 
+def _bucket_parity(seed: int, prompt: int = 12000,
+                   rows=(12288, 16384, 32768), dense_len: int = 8192,
+                   top: int = 2048) -> dict:
+    """A prompt of 12,000 tokens in the 12,288-row bucket (a rung,
+    ``runner.PREFILL_RUNGS``) and in the 16,384-row one beside it: the
+    rows behind the prompt are masked, so the prompt's own rows come out
+    the same. Held for the five prefill kernels that have rows to pad, at
+    the served widths: ``rt_linear_prefill`` (32 heads of 128, and the
+    state), ``flash_block_sparse_fwd`` with ``rt_sparse_select``'s choice
+    of 64 blocks of 64 for the queries past 8,192 (32 heads over 2 KV
+    heads), ``flash_sparse_fwd`` with ``rt_sparse_select``'s choice of
+    2,048 keys (16 indexer heads; 32 heads over 4 KV heads), and
+    ``flash_mla_fwd`` (16 heads scoring 192 wide, values of 128). A
+    kernel is held to the bit where the two programs of powers of two
+    (16,384 and 32,768 rows) agree to the bit. Then the whole program:
+    two layers of ``minicpm-sala-int8-12l`` (a sparse and a linear one,
+    seeded int8 weights) give the prompt the same first token in both
+    buckets. Runs in the gang worker. The sizes are arguments so that the
+    CPU can rehearse the case at a few hundred rows (float32 there)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks.harness import families
+    from ray_tpu.llm.engine import EngineConfig, LLMEngine
+    from ray_tpu.llm.sampling import SamplingParams
+    from ray_tpu.ops import linear_attention
+    from ray_tpu.ops import sparse_attention as sparse
+    from ray_tpu.ops.attention import attention
+
+    L, top_rows = prompt, max(rows)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+
+    def normal(key, *shape, dtype=jnp.bfloat16):
+        return jax.random.normal(key, (top_rows, *shape), dtype)
+
+    def padded(S):
+        at = jnp.arange(S, dtype=jnp.int32)
+        return at, at < L
+
+    scale = 128 ** -0.5
+    slopes = tuple(map(float, linear_attention.slopes_of(32)))
+    wide = [normal(k, 32, 128) for k in ks[:3]]             # q, k, v
+
+    def linear(S, q, k, v):
+        o, state = linear_attention.prefill(
+            q[None, :S], k[None, :S], v[None, :S], slopes,
+            jnp.asarray([L], jnp.int32), scale=scale)
+        return o[0, :L], state
+
+    sizes = sparse.BlockSizes(64, 64, 16, 1, 2048, dense_len)
+    few = [normal(k, 2, 128) for k in ks[3:5]]              # k, v: 2 KV heads
+
+    def blocks(S, q, k, v):
+        at, valid = padded(S)
+        sums = sparse.stride_sums(k[:S], valid, sizes.stride)
+        q_pos = jnp.where(valid, at, -1)[dense_len:]
+        out = sparse.block_attend(q[dense_len:S], k[:S], v[:S], sums, q_pos,
+                                  sizes, scale=scale)
+        # and the choice alone, of the prompt's last 512 queries
+        tile = slice(L - 512, L)
+        c = jnp.swapaxes(sparse.compressed_keys(sums, sizes, q.dtype),
+                         0, 1)[None]
+        scores = sparse.block_scores(
+            jnp.swapaxes(q[tile], 0, 1)[None], c, at[None, tile], sizes,
+            scale=scale)
+        chosen = sparse.block_choice(scores, at[None, tile], sizes)
+        return out[:L - dense_len], chosen[..., :-(-L // sizes.size)]
+
+    four = [normal(k, 4, 128) for k in ks[3:5]]             # k, v: 4 KV heads
+    index = [normal(ks[5], 16, 128), normal(ks[7], 16, dtype=jnp.float32),
+             normal(ks[6], 128)]                            # qi, w, ki
+
+    def indexer(S, q, k, v, qi, w, ki):
+        at, valid = padded(S)
+        out = sparse.attend(
+            q[None, :S], k[None, :S], v[None, :S], qi[None, :S], w[None, :S],
+            ki[None, :S], jnp.where(valid, at + 1, 0)[None], top_k=top,
+            scale=scale)
+        tile = slice(L - 512, L)
+        last = at[tile] + 1
+        scores = sparse.index_scores(qi[None, tile], w[None, tile],
+                                     ki[None, :S], last[None])[0]
+        return out[0, :L], sparse.choose(scores, last, top_k=top)[:, :L]
+
+    mla = [normal(ks[0], 16, 192), normal(ks[1], 16, 192),
+           normal(ks[2], 16, 128)]
+
+    def latent(S, q, k, v):
+        return attention(q[None, :S], k[None, :S], v[None, :S], causal=True,
+                         scale=192 ** -0.5,
+                         lengths=jnp.asarray([L], jnp.int32))[0, :L],
+
+    report = {}
+    for name, run, operands in (
+            ("rt_linear_prefill", linear, wide),
+            ("flash_block_sparse_fwd", blocks, [wide[0], *few]),
+            ("flash_sparse_fwd", indexer, [wide[0], *four, *index]),
+            ("flash_mla_fwd", latent, mla)):
+        run = jax.jit(run, static_argnums=0)
+        got = {S: [np.asarray(x) for x in run(S, *operands)] for S in rows}
+
+        def differ(a, b):
+            return int(sum((x != y).sum() for x, y in zip(got[a], got[b])))
+
+        if not all(np.isfinite(x.astype(np.float32)).all()
+                   for x in got[rows[0]]):
+            raise AssertionError(f"{name}: not finite at {rows[0]} rows")
+        report[name] = {"rung_differs": differ(rows[0], rows[1]),
+                        "powers_differ": differ(rows[1], rows[2])}
+    # the whole program, through the engine's own call
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmarks", "configs",
+                           "minicpm-sala-int8-12l.json")) as f:
+        config = {**json.load(f), "num_hidden_layers": 2,
+                  "mixer_types": ["minicpm4", "lightning-attn"]}
+    family = families.family_of(config)
+    params = family.served_params(jax.random.PRNGKey(seed), config)
+    tokens = [1 + t % (config["vocab_size"] - 1)
+              for t in seeded_prompt(seed, 0, L)]
+    first = {}
+    for label, rungs in (("power", ()), ("rung", (rows[0],))):
+        engine = LLMEngine(params, family.program_config(config), EngineConfig(
+            max_num_seqs=1, page_size=64, num_pages=2 + rows[1] // 64,
+            max_seq_len=rows[1], decode_burst=8))
+        engine._PREFILL_RUNGS = rungs
+        rid = engine.add_request(tokens, SamplingParams(temperature=0.0,
+                                                        max_tokens=1))
+        while engine.has_unfinished():
+            engine.step()
+        first[label] = (engine.stats()["counters"]["prefill_bucket_tokens"],
+                        int(engine.requests[rid].output[0]))
+    report["first_token"] = first
+    wrong = [name for name, r in report.items() if name != "first_token"
+             and r["rung_differs"] and not r["powers_differ"]]
+    if (wrong or first["power"][1] != first["rung"][1]
+            or [first["rung"][0], first["power"][0]] != list(rows[:2])):
+        raise AssertionError(f"a rung's rows are not its neighbour's: "
+                             f"{report}")
+    return report
+
+
 def _run_steps(cfg, mesh, spec: dict, seed: int, on_step=None) -> dict:
     """``spec['steps']`` adamw steps of ``cfg`` on ``mesh`` over one seeded
     batch. Returns losses, timings, each device's bytes in use and the
@@ -593,6 +735,7 @@ def train_fn(config: dict) -> None:
             config["seed"])
         report["sparse_kernel_parity"] = _sparse_kernel_parity(
             config["seed"])
+        report["bucket_parity"] = _bucket_parity(config["seed"])
     cfg = LLAMA_CONFIGS[config["model"]]
     mesh = build_mesh(MeshSpec(), jax.devices()[:1])
     report.update(_run_steps(
@@ -671,6 +814,11 @@ def phase_train(seed: int, spec: dict) -> dict:
              shape="512 queries x 8192 keys, 16 indexer heads, top 2048, "
                    "32/4 heads of 128; a decode step of 8 slots",
              **m["sparse_kernel_parity"])
+        emit("bucket_parity",
+             shape="a 12000-token prompt in 12288, 16384 and 32768 rows: "
+                   "entries of its own rows that differ, by kernel; (rows, "
+                   "first token) of two layers of minicpm-sala-int8-12l",
+             **m["bucket_parity"])
     _check_losses(m["losses"])
     if m["tpu_custom_calls"] < 1:
         raise AssertionError("the compiled train step holds no "

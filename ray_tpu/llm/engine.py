@@ -10,7 +10,8 @@ TPU-first:
     bucket (a handful: ``decode_buckets``) serves the engine's whole
     lifetime: continuous batching = host-side slot assignment and the
     list of the live pages, not shape changes;
-  * prefills are bucketed (power-of-2 padding) and run one request per
+  * prefills are bucketed (power-of-2 padding, and the rungs between
+    that ``runner.PREFILL_RUNGS`` names) and run one request per
     step between decode steps (chunked-prefill-lite: bounded TTFT impact
     on running streams);
   * all paging is host-side (PageAllocator); the device never sees an
@@ -50,8 +51,8 @@ from ..util import tracing
 from .cache import (KVCache, PageAllocator, PrefixCache, SequenceTable,
                     zero_slot_state,
                     init_kv_cache, window_group_pages)
-from .runner import (decode_burst, prefill_bucket, prefill_sample,
-                     verify_step)
+from .runner import (PREFILL_RUNGS, decode_burst, page_bucket,
+                     prefill_bucket, prefill_sample, verify_step)
 from .sampling import SamplingParams
 
 
@@ -301,9 +302,10 @@ class LLMEngine:
             # of prefill's rows that are tokens
             "prefill_bucket_tokens": 0,
             "preemptions": 0, "host_s": dict.fromkeys(PHASES, 0.0),
-            # what ``load_decode_programs`` did before the replica was
-            # ready: the programs it ran once, and the seconds that took
-            # (trace, lowering, compile or the cache's read, one run)
+            # what ``load_decode_programs`` and ``load_prefill_programs``
+            # did before the replica was ready: the programs they ran
+            # once, and the seconds that took (trace, lowering, compile
+            # or the cache's read, one run)
             "loaded_programs": 0, "load_s": 0.0,
             # the bursts' page lists, summed over rounds: pages that hold
             # old context of decoding slots, pages the burst copied
@@ -379,7 +381,7 @@ class LLMEngine:
         size the flash kernels cannot tile). What was really compiled is
         in the program's text (``compile_prefill``)."""
         on_tpu = jax.default_backend() == "tpu"
-        top = prefill_bucket(self.ecfg.max_seq_len, self.ecfg.max_seq_len)
+        top = self._prefill_rows(self.ecfg.max_seq_len)
         prefill = attention_path(top, top, self.cfg.head_dim, on_tpu,
                                  self.cfg.value_dim)
         if self.cfg.latent:
@@ -918,8 +920,8 @@ class LLMEngine:
 
     def _span_bucket(self, pages: int) -> int:
         """Power-of-2 page-span bucket, capped at the table width."""
-        return prefill_bucket(pages, self.seq_table.block_tables.shape[1],
-                              self._SPAN_PAGES)
+        return page_bucket(pages, self.seq_table.block_tables.shape[1],
+                           self._SPAN_PAGES)
 
     def _active_span(self) -> int:
         """Pages covering the longest DECODING sequence, bucketed (a
@@ -969,8 +971,8 @@ class LLMEngine:
     def _flat_bucket(self, pages: int, g: int = 0) -> int:
         """Power-of-2 bucket of a flat list, capped at what can be
         listed (a pool of 384 pages tops out there, not at 512)."""
-        return prefill_bucket(pages, self._listable_pages(g),
-                              self._FLAT_PAGES)
+        return page_bucket(pages, self._listable_pages(g),
+                           self._FLAT_PAGES)
 
     # a latent burst's table span, smallest bucket in pages: the kernel's
     # grid covers the span whatever the slots hold, a step past a slot's
@@ -981,8 +983,8 @@ class LLMEngine:
     def _latent_span(self, pages: int) -> int:
         """Power-of-2 bucket of a latent burst's block tables (pages a
         slot), capped at the table's width."""
-        return prefill_bucket(pages, self.seq_table.block_tables.shape[1],
-                              self._LATENT_SPAN_PAGES)
+        return page_bucket(pages, self.seq_table.block_tables.shape[1],
+                           self._LATENT_SPAN_PAGES)
 
     def _ladder(self, g: int):
         """Every bucket a burst's list of group ``g`` can take (a latent
@@ -1036,7 +1038,8 @@ class LLMEngine:
         bare engine (the tests build dozens) compiles what it meets.
         One span, ``rt.engine.load``; its seconds and the programs are
         added to the counters ``load_s`` and ``loaded_programs``, which
-        nothing else moves. Returns the number of programs."""
+        only this and ``load_prefill_programs`` move. Returns the number
+        of programs."""
         B = self.ecfg.max_num_seqs
         zi, zf = jnp.zeros(B, jnp.int32), jnp.ones(B, jnp.float32)
         lora = None
@@ -1061,6 +1064,53 @@ class LLMEngine:
                     cfg=self.cfg, n_steps=self.ecfg.decode_burst,
                     greedy=True)
             jax.block_until_ready(self.cache.k)
+        self._counters["loaded_programs"] += len(buckets)
+        self._counters["load_s"] += sp.seconds
+        return len(buckets)
+
+    # the rungs of a whole prompt's rows (``runner.prefill_bucket``)
+    _PREFILL_RUNGS = PREFILL_RUNGS
+
+    def _prefill_rows(self, prompt_len: int) -> int:
+        """The rows a whole prompt of ``prompt_len`` tokens runs."""
+        return prefill_bucket(prompt_len, self.ecfg.max_seq_len,
+                              rungs=self._PREFILL_RUNGS)
+
+    def load_prefill_programs(self) -> int:
+        """Run ``prefill_sample`` once at every rung this engine pads a
+        whole prompt to (``_PREFILL_RUNGS`` below ``max_seq_len``), with
+        a prompt of no tokens, through the call ``_run_prefill`` makes
+        (``_dispatch_prefill``), so the entry the jit cache then holds is
+        the one a greedy request finds. Every row is dropped: no page
+        changes, slot 0's state is put back as it was, no counter of
+        served work moves and the sampler's seed stays. For a server to
+        call before it reports ready, as ``load_decode_programs``: a lone
+        request of a warm-up ladder meets every power of two (and
+        ``max_seq_len``, where that caps the bucket) on first use, as
+        before, but a rung only by chance, and a first use inside a
+        measured window stalls the engine's thread for the whole load. An
+        engine that prefills in chunks, or has no rung, loads nothing.
+        The span and the counters are ``load_decode_programs``'. Returns
+        the number of programs."""
+        if self.ecfg.prefill_chunk > 0:
+            return 0
+        buckets = [r for r in self._PREFILL_RUNGS
+                   if r < self.ecfg.max_seq_len]
+        if not buckets:
+            return 0
+        lora = None
+        if self.lora_pool is not None:
+            lora = self.lora_pool.select([0])
+        state = None if self.cache.s is None else self.cache.s[:, :1]
+        with tracing.span("rt.engine.load") as sp:
+            for bucket in buckets:
+                toks, _counts = self._dispatch_prefill(
+                    np.zeros((1, bucket), np.int32), 0, 0,
+                    self._sampling_arrays([None], advance=0), lora)
+            jax.block_until_ready(toks)
+        if state is not None:
+            self.cache.s = jax.lax.dynamic_update_slice_in_dim(
+                self.cache.s, state, 0, 1)
         self._counters["loaded_programs"] += len(buckets)
         self._counters["load_s"] += sp.seconds
         return len(buckets)
@@ -1101,22 +1151,17 @@ class LLMEngine:
         if C > 0:
             return self._run_prefill_chunk(state, seq, L, C)
         with self._phase("prefill.build"):
-            bucket = prefill_bucket(L, self.ecfg.max_seq_len)
+            bucket = self._prefill_rows(L)
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :L] = seq
-            seed, temp, top_k, top_p, greedy = self._sampling_arrays(
-                [state])
+            sampling = self._sampling_arrays([state])
             lora = None
             if self.lora_pool is not None:
                 lora = self.lora_pool.select(
                     [self.lora_pool.slot_of(state.model_id)])
         with self._phase("prefill.dispatch"):
-            toks, counts = self._run(
-                prefill_sample,
-                jnp.asarray(tokens), jnp.asarray([L], jnp.int32),
-                self._rows(state.slot),
-                self.cos, self.sin, seed, temp, top_k, top_p, lora,
-                **self._slot_of(state), cfg=self.cfg, greedy=greedy)
+            toks, counts = self._dispatch_prefill(tokens, L, state.slot,
+                                                  sampling, lora)
         self._count_keys(0, L)
         self._count_blocks(0, L, False)
         state.ctx_len = L
@@ -1130,12 +1175,27 @@ class LLMEngine:
         with self._phase("append"):
             return [self._append_token(state, tok)]
 
-    def _slot_of(self, state: RequestState) -> Dict[str, Any]:
+    def _dispatch_prefill(self, tokens, prompt_len: int, slot: int,
+                          sampling, lora):
+        """THE call of ``prefill_sample``: a request's (``_run_prefill``)
+        and the loader's (``load_prefill_programs``), so that both key one
+        entry of the jit cache (the same operands, types, weak types and
+        static arguments). tokens: numpy int32 [1, bucket]; sampling:
+        what ``_sampling_arrays`` returns. Returns (tokens, counts)."""
+        seed, temp, top_k, top_p, greedy = sampling
+        return self._run(
+            prefill_sample,
+            jnp.asarray(tokens), jnp.asarray([prompt_len], jnp.int32),
+            self._rows(slot),
+            self.cos, self.sin, seed, temp, top_k, top_p, lora,
+            **self._slot_of(slot), cfg=self.cfg, greedy=greedy)
+
+    def _slot_of(self, slot: int) -> Dict[str, Any]:
         """The keyword by which a prefill learns whose state it writes;
         nothing for a configuration without state layers."""
         if self.cache.s is None:
             return {}
-        return {"slots": jnp.asarray([state.slot], jnp.int32)}
+        return {"slots": jnp.asarray([slot], jnp.int32)}
 
     def compile_prefill(self, prompt_len: int):
         """``(bucket, compiled)``: the whole-prompt prefill program a
@@ -1150,7 +1210,7 @@ class LLMEngine:
         def row(dtype):
             return jax.ShapeDtypeStruct((1,), dtype)
 
-        bucket = prefill_bucket(prompt_len, self.ecfg.max_seq_len)
+        bucket = self._prefill_rows(prompt_len)
         params, ck, cv, ci, cc, cs, cos, sin = jax.tree.map(
             abstract, (self.params, self.cache.k, self.cache.v,
                        self.cache.i, self.cache.c, self.cache.s, self.cos,
@@ -1183,7 +1243,8 @@ class LLMEngine:
             logits, counts = self._run(
                 prefill_chunk,
                 jnp.asarray(tokens), jnp.int32(start), jnp.int32(n), bt,
-                self.cos, self.sin, **self._slot_of(state), cfg=self.cfg)
+                self.cos, self.sin, **self._slot_of(state.slot),
+                cfg=self.cfg)
         self._count_keys(start, start + n)
         self._count_blocks(start, start + n, False)
         if counts is not None:

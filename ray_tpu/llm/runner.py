@@ -122,10 +122,11 @@ the same block and the dense feed-forward, then the expert layers; the
 cache's layers are in that order.
 
 Static shapes throughout: prefill pads a prompt to a power-of-2 bucket
-(one executable a bucket), decode runs the whole slot batch every step
-with inactive slots masked over a page list padded to a power-of-2
-bucket (one executable a bucket, whatever the burst's width), and the
-cache buffers are donated, so the scatters update pages in place.
+or a rung between two (``prefill_bucket``; one executable a bucket),
+decode runs the whole slot batch every step with inactive slots masked
+over a page list padded to a power-of-2 bucket (one executable a bucket,
+whatever the burst's width), and the cache buffers are donated, so the
+scatters update pages in place.
 Reference analog: the vLLM
 paged-attention CUDA kernels behind ray.llm's vllm_engine (SURVEY §2.4),
 rebuilt natively since the reference delegates all device work to vLLM.
@@ -921,12 +922,35 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
     return (_head(x_last, params, cfg), cache_k, cache_v, counts, *rest)
 
 
-def prefill_bucket(seq_len: int, max_seq: int, floor: int = 16) -> int:
-    """Power-of-2 padding bucket — one compiled prefill per bucket."""
+def page_bucket(pages: int, most: int, floor: int = 16) -> int:
+    """Power-of-2 bucket of a list of pages (a table's span, a burst's
+    flat list), capped at the most there can be: one compiled decode
+    program a bucket."""
     b = floor
-    while b < seq_len:
+    while b < pages:
         b *= 2
-    return min(b, max_seq)
+    return min(b, most)
+
+
+# Rows between two powers of two that a whole prompt may be padded to.
+# Every layer's projections, norms and expert products run the bucket's
+# rows, not the prompt's tokens, so a rung takes a quarter off the prefill
+# of every prompt it catches; it costs a program more (one the engine
+# loads before it is ready: ``LLMEngine.load_prefill_programs``), so a
+# rung is added where a cell's prompts crowd: 12,288 is a whole number of
+# every tile the prefill kernels use (24 x 512). The next rung is one
+# entry here.
+PREFILL_RUNGS = (12288,)
+
+
+def prefill_bucket(seq_len: int, max_seq: int, floor: int = 16,
+                   rungs=PREFILL_RUNGS) -> int:
+    """The rows a whole prompt of ``seq_len`` tokens is padded to, one
+    compiled prefill a bucket: the next power of two, or a rung of
+    ``rungs`` that holds the prompt and lies below it; never above
+    ``max_seq``."""
+    return min([page_bucket(seq_len, max_seq, floor)]
+               + [r for r in rungs if seq_len <= r])
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=_POOLS)

@@ -247,7 +247,10 @@ class LLMServer:
         TTFT/TPOT split by pool. Called in the replica's constructor, so
         before it reports ready: a replica that decodes loads every shape
         a decode round can take here (lone warm-up requests reach only
-        the smallest page lists); a prefill replica never runs one."""
+        the smallest page lists); a prefill replica never runs one. A
+        replica that prefills loads the whole-prompt programs no ladder of
+        lone requests meets (``LLMEngine.load_prefill_programs``); a
+        decode replica is handed its prompts' KV and never runs one."""
         if pool in ("prefill", "decode") and len(self.engine.windows) > 1:
             raise ValueError(
                 f"pool={pool!r} (prefill and decode replicas that hand KV "
@@ -258,6 +261,8 @@ class LLMServer:
         self._dep_name = deployment_name
         if pool != "prefill":
             self.engine.load_decode_programs()
+        if pool != "decode":
+            self.engine.load_prefill_programs()
         tags = {"model": self.model_name, "pool": self._pool}
         for m in (self._m_ttft, self._m_tpot, self._m_queue_wait,
                   self._m_preemptions, self._m_e2e, self._m_queue,

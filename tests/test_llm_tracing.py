@@ -186,8 +186,8 @@ def test_a_loaded_engine_compiles_no_decode_program(tiny_params):
     """``load_decode_programs`` (what ``LLMServer`` calls before it is
     ready) runs every bucket a page list can take, with no slot active:
     no page changes, no counter moves but its own two (``loaded_programs``
-    and ``load_s``, which nothing else moves), ``_burst_width`` is not
-    called;
+    and ``load_s``, which only the two loaders move), ``_burst_width`` is
+    not called;
     and a mixed run after it (contexts of 3 to 40 tokens, 1 to 3 slots
     decoding, bursts of 1 to 4) adds no entry to ``decode_burst``'s
     compile cache: a width is an operand, a bucket is loaded. Three
