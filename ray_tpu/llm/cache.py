@@ -2,44 +2,18 @@
 
 Reference analog: the vLLM engine the reference wraps for LLM serving
 (python/ray/llm/_internal/serve/deployments/llm/vllm/vllm_engine.py) keeps
-its paged KV cache in CUDA; here the cache is a pair of jax arrays of
-STATIC shape [layers, pages, page_size, kv_heads, head_dim] living in HBM
-— XLA-friendly (no dynamic allocation inside jit) with all paging
-decisions made host-side by a free-list allocator.
+its paged KV cache in CUDA; here the cache is jax arrays of STATIC shape
+living in HBM, XLA-friendly (no dynamic allocation inside jit), with all
+paging decisions made host-side by a free-list allocator. What the arrays
+are is the configuration's cache kind's affair (``llm/kinds``: K and V
+rows in pages, one pair a layer group; one latent row; a third pool of an
+indexer's keys; a state a slot beside pages and sums); the allocator, the
+tables and the prefix cache here serve every kind.
 
 Page 0 is reserved and never handed out: block tables are padded with 0,
 so the gathers read it (under a mask). Nothing writes to it: rows that are
-not tokens are dropped by the one scatter (``runner._write_rows``).
-
-Layer groups. Layers whose keys live equally long share a pool, an
-allocator and a table (``LlamaConfig.kv_groups``: the layers that see the
-whole sequence; the layers that see a window). A configuration with one
-kind of layer has one group and everything here is what it was: one pair
-of arrays, one allocator, one table. With two, ``KVCache.k`` and ``.v``
-are tuples of arrays, one a group, each with its group's layers in front
-and its own number of pages; the tables are indexed by a position's page
-all the same (position // page_size), and a window group's sequence gives
-its oldest pages back as they leave the window
-(``SequenceTable.release_front``): their entries go back to 0, and a row
-whose entry is 0 is written nowhere. The window group's pool is sized by
-what can be live at once (``window_group_pages``), not by the longest
-sequence.
-
-Latent attention (``LlamaConfig.latent``). A token keeps ONE
-row a layer, the compressed keys and values and the rotary key all heads
-share (512 + 64 values, in a slot of ``cfg.latent_row`` = 640, which
-models/llama.py explains), not a key and a value a head: ``KVCache.k``
-is the one pool [layers, pages, page_size, latent_row] and ``KVCache.v``
-is None: no V pool is allocated, copied or written, the values are the
-first 512 columns of the same row. Allocator, tables and the reserved
-page 0 are what they are for every configuration.
-
-An indexer (``LlamaConfig.sparse_top_k``). Beside its K and V rows a
-token keeps the indexer's ONE key a layer (64 values, in a slot of
-``cfg.indexer_row`` = 128 for ``latent_row``'s reason): ``KVCache.i`` is
-a third pool [layers, pages, page_size, indexer_row] that shares the
-page ids of K and V: the same allocator, the same tables, a page's rows
-written, shared and released together. None everywhere else.
+not tokens are dropped by the scatter or written nowhere by the loops of
+slices (``kinds/paged.py``, ``kinds/latent.py``).
 """
 
 from __future__ import annotations
@@ -56,9 +30,8 @@ import numpy as np
 
 @dataclass
 class KVCache:
-    """Device-side paged cache: one pair of stacked-layer arrays, or,
-    for a configuration with several layer groups, a pair of tuples of
-    them (one array a group), the form the runner's programs take."""
+    """Device-side paged cache, the five pools the runner's programs
+    take and return; a kind fills the fields its format has."""
 
     k: Any  # [L, num_pages, page_size, kv_heads, head_dim] (a group)
     v: Any  # None for a latent configuration: k holds its rows
@@ -97,53 +70,14 @@ def zero_slot_state(pool, slot):
 
 def init_kv_cache(cfg, num_pages, page_size: int, dtype=None,
                   slots: int = 0) -> KVCache:
-    """``num_pages``: an int for a one-group configuration, else one
-    number a group of ``cfg.kv_groups``. ``slots``: the rows of the
-    state pool, for a configuration with linear layers."""
-    dtype = dtype or cfg.dtype
-    if cfg.own_weights:
-        if page_size != cfg.block_size:
-            raise ValueError(
-                f"page_size={page_size} with block_size={cfg.block_size}: "
-                f"a block that is chosen is a page that is read, so they "
-                f"are equal")
-        if not isinstance(num_pages, int) or slots < 1:
-            raise ValueError("a configuration with linear layers has one "
-                             "layer group of pages, and a state a slot")
-        L, hd = cfg.n_kv_layers, cfg.head_dim
-        # a page's (position, KV head) rows as ONE matrix, the form
-        # ``rt_sparse_attend_decode`` multiplies: with 2 KV heads a
-        # [.., page, 2, hd] pool is tiled (2, 128) and its reshape to
-        # rows is a copy of the whole pool in every layer of every step
-        # (read from a compile for a v5e, PR 46)
-        shape = (L, num_pages, page_size * cfg.n_kv_heads, hd)
-        return KVCache(
-            jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
-            c=jnp.zeros((L, num_pages, page_size // cfg.block_stride,
-                         cfg.n_kv_heads, hd), jnp.float32),
-            s=jnp.zeros((cfg.n_linear_layers, slots, cfg.linear_heads,
-                         hd, hd), jnp.float32))
-    if cfg.latent:
-        if not isinstance(num_pages, int):
-            raise ValueError("a latent configuration has one layer group")
-        return KVCache(jnp.zeros((cfg.n_layers, num_pages, page_size,
-                                  cfg.latent_row), dtype), None)
+    """The pools of the configuration's kind. ``num_pages``: an int for
+    a one-group configuration, else one number a group of
+    ``cfg.kv_groups``. ``slots``: the rows of the state pool, for a
+    configuration with linear layers."""
+    from . import kinds      # the kinds name KVCache
 
-    def pools(layers: int, pages: int):
-        return jnp.zeros((layers, pages, page_size, cfg.n_kv_heads,
-                          cfg.head_dim), dtype)
-
-    if isinstance(num_pages, int):
-        if len(cfg.kv_groups) > 1:
-            raise ValueError(f"{len(cfg.kv_groups)} layer groups need a "
-                             f"number of pages each")
-        return KVCache(
-            pools(cfg.n_layers, num_pages), pools(cfg.n_layers, num_pages),
-            jnp.zeros((cfg.n_layers, num_pages, page_size, cfg.indexer_row),
-                      dtype) if cfg.sparse_top_k else None)
-    sizes = [(cfg.group_layers(g), n) for g, n in enumerate(num_pages)]
-    return KVCache(tuple(pools(*s) for s in sizes),
-                   tuple(pools(*s) for s in sizes))
+    return kinds.of(cfg).init_pools(cfg, num_pages, page_size,
+                                    dtype or cfg.dtype, slots)
 
 
 class PageAllocator:

@@ -16,13 +16,17 @@ TPU-first:
     on running streams);
   * all paging is host-side (PageAllocator); the device never sees an
     allocation decision, only block tables;
+  * what the cache's arrays hold is the configuration's cache kind's
+    affair (``llm/kinds``); the engine asks the kind (``self.kind``) what
+    a burst lists, which counters its queries move, which path its
+    attention takes and which features it refuses (``_refuse``);
   * layers whose keys live equally long share a pool, an allocator and a
-    table (``cache.py``, "Layer groups"): a configuration of full and
-    window layers has two of each, a request is admitted when every
+    table (``kinds/paged.py``, "Layer groups"): a configuration of full
+    and window layers has two of each, a request is admitted when every
     group can hold it, and a window group's sequence gives its pages
     back as they leave the window (``_release``), after its prefill and
-    after every burst. With one kind of layer there is one group and
-    ``allocator``, ``seq_table`` and ``cache`` are what they were.
+    after every burst. With one kind of layer there is one group:
+    ``allocator``, ``seq_table`` and ``cache`` are its.
 
 The engine is synchronous and single-threaded by design — an actor wraps
 it for serving (ray_tpu.llm.serve) the way vLLM's AsyncLLMEngine wraps
@@ -48,9 +52,9 @@ from ..ops import rope_frequencies
 from ..ops.attention import attention_path
 from ..ops.moe import chosen_tiles
 from ..util import tracing
+from . import kinds
 from .cache import (KVCache, PageAllocator, PrefixCache, SequenceTable,
-                    zero_slot_state,
-                    init_kv_cache, window_group_pages)
+                    init_kv_cache, window_group_pages, zero_slot_state)
 from .runner import (PREFILL_RUNGS, decode_burst, page_bucket,
                      prefill_bucket, prefill_sample, verify_step)
 from .sampling import SamplingParams
@@ -195,19 +199,15 @@ class LLMEngine:
                 f"max_seq_len={self.ecfg.max_seq_len} sequence "
                 f"({need} pages needed, {usable} usable)")
         self.params = params
-        # the layer groups (cache.py): their windows, names and pools
+        # the layer groups (kinds/paged.py): their windows, names, pools
         self.windows = cfg.kv_groups
         self.group_names = ["full" if w is None else "window"
                             for w in self.windows]
         grouped = len(self.windows) > 1
-        if grouped:
-            self._refuse_with_groups()
-        if cfg.latent:
-            self._refuse_with_latent()
-        if cfg.sparse_top_k:
-            self._refuse_with_indexer()
-        if cfg.own_weights:
-            self._refuse_with_state_layers()
+        if self.ecfg.enable_prefix_caching:
+            self._refuse("enable_prefix_caching")
+        if self.ecfg.lora_rank > 0:
+            self._refuse("lora_rank")
         pool_pages = [
             self.ecfg.num_pages if w is None else window_group_pages(
                 self.ecfg.max_num_seqs, w, self.ecfg.page_size,
@@ -328,27 +328,8 @@ class LLMEngine:
                 # tokens' rows the router gave to experts that are not
                 # on this chip (one chip's share of the experts)
                 self._counters["expert_rows_elsewhere"] = 0
-        if cfg.sparse_top_k:
-            # summed over queries (prefilled and decoded tokens), from
-            # their positions: the keys a query could see and its
-            # indexer scored, and the keys it attended over (at most
-            # sparse_top_k of them)
-            self._counters.update(scored_keys=0, attended_keys=0)
-            # the pages of K (and as many of V) the bursts' steps walked:
-            # every decoding slot's cached pages, once a step run
-            self._counters["sparse_decode_pages"] = 0
-        if cfg.own_weights:
-            # slots whose state was zeroed at an admission; bytes of state
-            # the bursts' steps read and wrote (every decoding slot's,
-            # once each a step); queries, prefilled and decoded, below
-            # ``block_dense_len`` (every visible key attended); for the
-            # rest the blocks a query could see and scored and the blocks
-            # it attended over (a block layer and KV head); the pages of
-            # K (and as many of V) the bursts' steps read (a block layer
-            # and KV head)
-            self._counters.update(
-                state_slots_reset=0, state_bytes_step=0, dense_queries=0,
-                scored_blocks=0, chosen_blocks=0, block_decode_pages=0)
+        # the kind's own, which its ``count`` moves
+        self._counters.update(dict.fromkeys(self.kind.COUNTERS, 0))
         # what one cached position holds, all layers (a position's share
         # of its page's sums of strides among it)
         self._counters["kv_bytes_per_token"] = int(sum(
@@ -366,188 +347,44 @@ class LLMEngine:
             cfg.head_dim, cfg.value_dim, paths)
 
     @property
-    def _reads_own_pages(self) -> bool:
-        """A burst copies no page: it reads each slot's own pages through
-        its table (latent rows; an indexer's rows, and K and V under its
-        choice)."""
-        return (self.cfg.latent or self.cfg.sparse_top_k > 0
-                or self.cfg.own_weights)
+    def kind(self):
+        """The configuration's cache kind (``llm/kinds``)."""
+        return kinds.of(self.cfg)
 
     def attention_paths(self) -> Dict[str, str]:
         """Which implementation each program's attention takes on this
-        backend, by ``ops.attention.attention_path`` at the largest
-        prefill bucket: logged once when the engine is made (a warning
-        where a TPU's whole-prompt prefill falls to plain jax: a head
-        size the flash kernels cannot tile). What was really compiled is
-        in the program's text (``compile_prefill``)."""
+        backend (``ops.attention.attention_path`` at the largest prefill
+        bucket, and the kind's account): logged once when the engine is
+        made (a warning where a TPU's whole-prompt prefill falls to plain
+        jax: a head size the flash kernels cannot tile). What was really
+        compiled is in the program's text (``compile_prefill``)."""
         on_tpu = jax.default_backend() == "tpu"
         top = self._prefill_rows(self.ecfg.max_seq_len)
-        prefill = attention_path(top, top, self.cfg.head_dim, on_tpu,
-                                 self.cfg.value_dim)
-        if self.cfg.latent:
-            gathered = "xla (absorbed, over the gathered rows)"
-            return {"prefill": prefill + " (expanded)",
-                    "prefill_chunk": gathered, "verify_step": gathered,
-                    "decode_burst": "pallas rt_mla_decode (absorbed, each "
-                    "slot's own pages)" if on_tpu else gathered}
-        listed = "xla (over the gathered pages)"
-        if self.cfg.own_weights:
-            return {
-                "prefill": f"linear layers: "
-                f"{'pallas rt_linear_prefill' if on_tpu else 'xla'} (chunked "
-                f"form); block layers: {prefill} below block_dense_len, then "
-                f"{'pallas rt_block_score, rt_sparse_select, flash_block_sparse_fwd' if on_tpu else 'xla'}"
-                " (the chosen blocks of the prompt's rows)",
-                "prefill_chunk": "the same, over the gathered pages; the "
-                "state carried through its pool",
-                "verify_step": "refused (a state cannot be rolled back)",
-                "decode_burst": (
-                    "pallas rt_linear_decode (each live slot's state, in "
-                    "place), rt_block_score, rt_sparse_attend_decode (the "
-                    "chosen pages where they lie)" if on_tpu else "xla")}
-        if self.cfg.sparse_top_k:
-            chosen = ("pallas rt_sparse_index, rt_sparse_select, "
-                      "flash_sparse_fwd (the indexer's choice, over the %s)"
-                      if on_tpu else "xla (the indexer's choice, over the %s)")
-            return {"prefill": prefill + " up to sparse_top_k keys, then "
-                    + chosen % "prompt's rows",
-                    "prefill_chunk": chosen % "gathered pages",
-                    "verify_step": chosen % "gathered pages",
-                    "decode_burst": (
-                        "pallas rt_sparse_index_decode, rt_sparse_select_"
-                        "decode (each slot's own indexer rows), rt_sparse_"
-                        "attend_decode" if on_tpu else "xla") + " (each "
-                    "slot's own K and V pages where they lie, under the "
-                    "choice)"}
-        return {"prefill": prefill, "prefill_chunk": listed,
-                "verify_step": listed, "decode_burst": listed}
+        return self.kind.attention_paths(self.cfg, attention_path(
+            top, top, self.cfg.head_dim, on_tpu, self.cfg.value_dim), on_tpu)
 
-    def _refuse_with_groups(self) -> None:
-        """What a cache of several layer groups cannot do yet (ROADMAP
-        M4), refused by the option's name before anything is built
-        (``speculation``: by ``enable_speculation``, whoever calls it)."""
-        reasons = {
-            "enable_prefix_caching": (
-                self.ecfg.enable_prefix_caching,
-                "a cached prompt page would have to be shared in every "
-                "group, and a window group gives its pages back"),
-            "lora_rank": (
-                self.ecfg.lora_rank > 0,
-                "adapters ride a scan over layers, not over periods of "
-                "a layer pattern, and no test runs both"),
-        }
-        for option, (asked, why) in reasons.items():
-            if asked:
-                raise ValueError(
-                    f"EngineConfig.{option} is not supported with "
-                    f"{len(self.windows)} layer groups (full and window "
-                    f"layers side by side): {why}")
-
-    def _refuse_with_latent(self) -> None:
-        """What a cache of latent rows cannot do yet, refused by the
-        option's name before anything is built (``speculation``: by
-        ``enable_speculation``, whoever calls it; KV hand-over: by
-        ``_refuse_kv_transfer``)."""
-        if self.ecfg.lora_rank > 0:
+    def _refuse(self, feature: str, what: Optional[str] = None) -> None:
+        """What the configuration's cache kind cannot do yet, refused by
+        the option's name (or ``what``: the hand-over call's) before
+        anything is built: THE reader of the kinds' ``refuses`` tables."""
+        named, reasons = self.kind.refuses(self.cfg)
+        if feature in reasons:
             raise ValueError(
-                "EngineConfig.lora_rank is not supported with latent "
-                "attention: adapters are deltas on wq and wv, and a latent "
-                "layer has neither (its queries pass a low-rank bottleneck "
-                "and its values are expanded from the cached row)")
+                f"{what or 'EngineConfig.' + feature} is not supported "
+                f"with {named}: {reasons[feature]}")
 
-    def _refuse_with_indexer(self) -> None:
-        """What a cache with an indexer's third pool cannot do yet
-        (ROADMAP M7), refused by the option's name before anything is
-        built (``speculation``: by ``enable_speculation``, whoever calls
-        it; KV hand-over: by ``_refuse_kv_transfer``)."""
-        reasons = {
-            "enable_prefix_caching": (
-                self.ecfg.enable_prefix_caching,
-                "a cached page's indexer rows are shared with it by page "
-                "id, but no test runs a resumed prompt through the "
-                "selection yet"),
-            "lora_rank": (
-                self.ecfg.lora_rank > 0,
-                "adapters are deltas on wq and wv, the indexer chooses "
-                "keys from projections of its own, and no test runs both"),
-        }
-        for option, (asked, why) in reasons.items():
-            if asked:
-                raise ValueError(
-                    f"EngineConfig.{option} is not supported with an "
-                    f"indexer (sparse_top_k={self.cfg.sparse_top_k}): {why}")
-
-    def _refuse_with_state_layers(self) -> None:
-        """What a cache with a state a slot beside its pages cannot do
-        yet (ROADMAP M3), refused by the option's name before anything is
-        built (``speculation``: by ``enable_speculation``, whoever calls
-        it; KV hand-over: by ``_refuse_kv_transfer``)."""
-        reasons = {
-            "enable_prefix_caching": (
-                self.ecfg.enable_prefix_caching,
-                "a cached page says nothing of a linear layer's state at "
-                "its end (snapshots of the state at page boundaries: "
-                "ROADMAP M3)"),
-            "lora_rank": (
-                self.ecfg.lora_rank > 0,
-                "adapters ride ONE scan over layers of one stack, and the "
-                "linear and block layers have a stack each"),
-        }
-        for option, (asked, why) in reasons.items():
-            if asked:
-                raise ValueError(
-                    f"EngineConfig.{option} is not supported with state "
-                    f"layers (linear_heads={self.cfg.linear_heads}): {why}")
-
-    def _count_blocks(self, start: int, end: int, decode: bool) -> None:
-        """The queries at positions [start, end) of one sequence, for
-        ``dense_queries``, ``scored_blocks``, ``chosen_blocks`` and, in a
-        burst, ``block_decode_pages``: a query at t below
-        ``block_dense_len`` attends over every visible key; another sees
-        t // block + 1 blocks and attends over at most ``block_topk``, in
-        every block layer and KV head."""
-        cfg = self.cfg
-        if not cfg.own_weights:
-            return
-        each = cfg.n_kv_layers * cfg.n_kv_heads
-        dense = max(0, min(end, cfg.block_dense_len) - start)
-        self._counters["dense_queries"] += dense
-        seen = np.arange(start + dense, end) // cfg.block_size + 1
-        self._counters["scored_blocks"] += each * int(seen.sum())
-        self._counters["chosen_blocks"] += each * int(
-            np.minimum(seen, cfg.block_topk).sum())
-        if decode:
-            # the pages that hold the slot's cached positions: all of them
-            # below dense_len, then at most block_topk
-            cached = -(-start // cfg.block_size)
-            self._counters["block_decode_pages"] += each * (
-                dense * cached + (end - start - dense) * min(
-                    cached, cfg.block_topk))
-
-    def _count_keys(self, start: int, end: int) -> None:
-        """The queries at positions [start, end) of one sequence, for
-        ``scored_keys`` and ``attended_keys``: the query at position t
-        sees t + 1 keys and attends over at most ``sparse_top_k``."""
-        k = self.cfg.sparse_top_k
-        if not k:
-            return
-
-        def upto(n: int) -> int:        # 1 + 2 + .. + n
-            return n * (n + 1) // 2
-
-        lo, hi = min(start, k), min(end, k)
-        self._counters["scored_keys"] += upto(end) - upto(start)
-        self._counters["attended_keys"] += (
-            upto(hi) - upto(lo) + k * ((end - start) - (hi - lo)))
+    def _count(self, start: int, end: int, decode: bool = False) -> None:
+        """The queries at positions [start, end) of one sequence, for the
+        kind's counters (``decode``: a burst's steps)."""
+        self.kind.count(self.cfg, self._counters, self.ecfg.page_size,
+                        start, end, decode)
 
     def _run(self, program, *args, **kwargs):
         """A runner program on this engine's pools, which come back as
         the cache: (what the program returns before its pools ..., its
         expert counts)."""
         cache = self.cache
-        # behind the counts: the indexer's pool; the sums of strides and
-        # the state pool. None: what every other configuration's programs
-        # were always handed
+        # the kind's other pools: keywords in, behind the counts out
         more = {name: pool for name, pool in (
             ("cache_i", cache.i), ("cache_c", cache.c), ("cache_s", cache.s))
             if pool is not None}
@@ -558,30 +395,6 @@ class LLMEngine:
         self.cache = KVCache(cache_k, cache_v, back.get("cache_i"),
                              back.get("cache_c"), back.get("cache_s"))
         return (*out, counts)
-
-    def _refuse_kv_transfer(self, what: str) -> None:
-        if self.cfg.own_weights:
-            raise ValueError(
-                f"{what} is not supported with state layers: a KV payload "
-                f"is a K and a V stack of pages for all layers, and the "
-                f"linear layers' memory is a state a slot that no page "
-                f"holds")
-        if self.cfg.sparse_top_k:
-            raise ValueError(
-                f"{what} is not supported with an indexer: a KV payload "
-                f"is a K and a V stack of pages, and a page here has a "
-                f"third row a token, the indexer's key")
-        if self.cfg.latent:
-            raise ValueError(
-                f"{what} is not supported with latent attention: a KV "
-                f"payload is a K and a V stack of pages, and a latent "
-                f"cache is one pool of rows with no V")
-        if len(self.windows) > 1:
-            raise ValueError(
-                f"{what} is not supported with {len(self.windows)} layer "
-                f"groups: a KV payload is one stack of pages for all "
-                f"layers, and a window group holds only the pages inside "
-                f"its window")
 
     def _read_back(self, toks, counts=None):
         """The sampled tokens on the host (the round's one sync). An
@@ -641,29 +454,7 @@ class LLMEngine:
         drafter's random init (a trained 400m draft checkpoint)."""
         from .spec_decode import SpecDecoder
 
-        if self.cfg.own_weights:
-            raise ValueError(
-                "EngineConfig.speculation is not supported with state "
-                "layers: verify_step would have to roll a slot's state "
-                "back behind a rejected window, and the state keeps no "
-                "token apart")
-        if self.cfg.latent:
-            raise ValueError(
-                "EngineConfig.speculation is not supported with latent "
-                "attention: the drafter mirrors a K and a V pool, and a "
-                "latent cache has one pool of rows")
-        if self.cfg.sparse_top_k:
-            raise ValueError(
-                "EngineConfig.speculation is not supported with an "
-                "indexer: verify_step selects over the pages "
-                "(llm/runner.py), but the drafter mirrors a K and a V "
-                "pool and no test runs a speculative round through the "
-                "third pool")
-        if len(self.windows) > 1:
-            raise ValueError(
-                "EngineConfig.speculation is not supported with "
-                f"{len(self.windows)} layer groups: the drafter mirrors "
-                "ONE page pool and one block table")
+        self._refuse("speculation")
         if self.lora_pool is not None:
             raise ValueError("speculation is incompatible with "
                              "lora_rank > 0 (drafter has no adapters)")
@@ -936,15 +727,10 @@ class LLMEngine:
 
     # --- the burst's page list (``burst_gather``) ---
 
-    # A plain burst always lists flat: alone on the chip at fixed shapes
-    # ONE list of the live pages costs what a rectangle of as many pages
-    # (a row a slot at the longest's span) costs, or less (Mistral-7B, 16
-    # slots: 103.4 against 103.8 ms a burst at 128 pages, 122.4 against
-    # 122.5 at 256; OLMoE, 8 slots: 50.4 against 53.2 and 65.5 against
-    # 70.9; PERF.md, PR 32), and the live pages are never more than that
-    # rectangle's. Smallest bucket, in pages: below it a list is a few
-    # hundred KB a layer and a finer bucket buys nothing
-    _FLAT_PAGES = 16
+    # smallest buckets, in pages, of a flat list and of a burst's table
+    # span where it reads each slot's own pages: the kinds say why
+    _FLAT_PAGES = kinds.paged.LOWEST_BUCKET
+    _LATENT_SPAN_PAGES = kinds.latent.LOWEST_BUCKET
 
     def _slot_pages(self, g: int):
         """(fewest, most) pages of group ``g`` that can hold old context
@@ -974,25 +760,19 @@ class LLMEngine:
         return page_bucket(pages, self._listable_pages(g),
                            self._FLAT_PAGES)
 
-    # a latent burst's table span, smallest bucket in pages: the kernel's
-    # grid covers the span whatever the slots hold, a step past a slot's
-    # length costs a third of a microsecond, and every bucket is a
-    # program to load before the replica is ready
-    _LATENT_SPAN_PAGES = 32
-
     def _latent_span(self, pages: int) -> int:
-        """Power-of-2 bucket of a latent burst's block tables (pages a
-        slot), capped at the table's width."""
+        """Power-of-2 bucket of the block tables (pages a slot) of a
+        burst that reads each slot's own pages, capped at the table's
+        width."""
         return page_bucket(pages, self.seq_table.block_tables.shape[1],
                            self._LATENT_SPAN_PAGES)
 
     def _ladder(self, g: int):
-        """Every bucket a burst's list of group ``g`` can take (a latent
-        burst: its table span)."""
-        top, lowest = self._listable_pages(g), self._FLAT_PAGES
-        if self._reads_own_pages:
+        """Every bucket a burst's list of group ``g`` can take (a burst
+        that reads each slot's own pages: its table span)."""
+        top, lowest = self._listable_pages(g), self.kind.LOWEST_BUCKET
+        if self.kind.OWN_PAGES:
             top = self.seq_table.block_tables.shape[1]
-            lowest = self._LATENT_SPAN_PAGES
         buckets = [min(lowest, top)]
         while buckets[-1] < top:
             buckets.append(min(2 * buckets[-1], top))
@@ -1048,7 +828,7 @@ class LLMEngine:
         buckets = self.decode_buckets()
         with tracing.span("rt.engine.load") as sp:
             for shape in buckets:
-                if self._reads_own_pages:
+                if self.kind.OWN_PAGES:
                     lists = (jnp.zeros((B, shape), jnp.int32),)
                 else:
                     lists = tuple(jnp.asarray(burst_gather(
@@ -1162,8 +942,7 @@ class LLMEngine:
         with self._phase("prefill.dispatch"):
             toks, counts = self._dispatch_prefill(tokens, L, state.slot,
                                                   sampling, lora)
-        self._count_keys(0, L)
-        self._count_blocks(0, L, False)
+        self._count(0, L)
         state.ctx_len = L
         self._counters["prefills"] += 1
         self._counters["prefill_tokens"] += L
@@ -1245,8 +1024,7 @@ class LLMEngine:
                 jnp.asarray(tokens), jnp.int32(start), jnp.int32(n), bt,
                 self.cos, self.sin, **self._slot_of(state.slot),
                 cfg=self.cfg)
-        self._count_keys(start, start + n)
-        self._count_blocks(start, start + n, False)
+        self._count(start, start + n)
         if counts is not None:
             self._pending_counts.append(counts)
         state.prefill_pos = start + n
@@ -1386,7 +1164,7 @@ class LLMEngine:
             # a group's list: the pages that hold old context its layers
             # can still see
             page, lists, shape = self.ecfg.page_size, [], []
-            if self._reads_own_pages:
+            if self.kind.OWN_PAGES:
                 # nothing is copied: the burst reads each slot's own
                 # pages through its table, cut to the longest's bucket
                 pages = [-(-s.ctx_len // page) for s in active_states]
@@ -1396,13 +1174,6 @@ class LLMEngine:
                 for c in (counters, counters["groups"][self.group_names[0]]):
                     c["live_pages"] += sum(pages)
                     c["gathered_pages"] += sum(pages)
-                if self.cfg.sparse_top_k:
-                    counters["sparse_decode_pages"] += K * sum(pages)
-                if self.cfg.own_weights:
-                    # a step reads and writes every decoding slot's state
-                    counters["state_bytes_step"] += (
-                        2 * K * len(active_states)
-                        * self.cfg.state_bytes_per_slot)
             else:
                 for g, window in enumerate(self.windows):
                     held = []
@@ -1435,8 +1206,7 @@ class LLMEngine:
                 jnp.int32(K), cfg=self.cfg,
                 n_steps=self.ecfg.decode_burst, greedy=greedy)
         for s in active_states:
-            self._count_keys(s.ctx_len, s.ctx_len + K)
-            self._count_blocks(s.ctx_len, s.ctx_len + K, True)
+            self._count(s.ctx_len, s.ctx_len + K, True)
         with self._phase("decode.sync"):
             sampled = self._read_back(toks, counts)  # [K, B]
         outs = []
@@ -1689,7 +1459,7 @@ class LLMEngine:
         finishes the request locally (reason "handoff" — its slot and
         pages free immediately for the next prompt) and returns a
         payload :meth:`inject_request` accepts on the decode engine."""
-        self._refuse_kv_transfer("export_kv_request")
+        self._refuse("kv_transfer", "export_kv_request")
         payload = self.snapshot_kv_request(request_id)
         self._finish(self.requests[request_id], "handoff")
         return payload
@@ -1700,7 +1470,7 @@ class LLMEngine:
         snapshots to a prefill-class verifier while local decode
         continues — both compute the identical emission (spec_decode.py
         module docstring), so nothing is handed off."""
-        self._refuse_kv_transfer("snapshot_kv_request")
+        self._refuse("kv_transfer", "snapshot_kv_request")
         state = self.requests.get(request_id)
         if state is None:
             raise ValueError(f"unknown request {request_id!r}")
@@ -1731,7 +1501,7 @@ class LLMEngine:
         page-size mismatch, pool pressure, malformed/missing arrays)
         the request joins the waiting queue and recomputes its prefill
         locally (recompute-preemption semantics): slower, never wrong."""
-        self._refuse_kv_transfer("inject_request")
+        self._refuse("kv_transfer", "inject_request")
         prompt = [int(t) for t in payload["prompt"]]
         output = [int(t) for t in payload.get("output") or ()]
         ctx_len = int(payload["ctx_len"])
@@ -1859,7 +1629,7 @@ class LLMEngine:
         }
         if self.spec is not None:
             out["spec"] = self.spec.stats()
-        if self.cfg.own_weights:
+        if self.cache.s is not None:
             # what a cached position holds in the block layers' pools,
             # and what a slot holds in the linear layers' state pool
             out["kv_bytes_per_token"] = self._counters["kv_bytes_per_token"]

@@ -1102,7 +1102,7 @@ def block_decode_attention(q, pool_k, pool_v, layer, pages, lengths, seen,
     """One query a slot over the cached tokens of the pages it chose, a
     KV head's group of heads a row: q [B, H, hd]; pool_k, pool_v [L, P,
     page * kvh, hd], a page ONE matrix of its (position, KV head) rows
-    (llm/cache.py keeps them so for these layers); ``pages``,
+    (llm/kinds/state.py keeps them so for these layers); ``pages``,
     ``lengths`` and ``seen`` from ``block_decode_pages``.
     ``rt_sparse_attend_decode`` walks the LISTED pages and no other: a
     page that was not chosen is not read. Returns (o float32 [B, H, hd],
