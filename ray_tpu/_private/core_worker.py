@@ -37,6 +37,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import cloudpickle
 
 from .config import global_config
+from . import gcs as gcs_states
 from . import locking
 from .ids import ActorID, JobID, NodeID, ObjectID, TaskID, WorkerID
 from .object_ref import ObjectRef, ObjectRefGenerator, _set_ref_registry
@@ -2398,6 +2399,7 @@ class CoreWorker:
             if info is not None:
                 state.state, state.address = info.state, info.address
                 state.death_cause = info.death_cause
+        waited = 0.0
         while state.state != "ALIVE":
             if state.state == "DEAD":
                 # covers the borrow-after-death path, where no DEAD
@@ -2406,7 +2408,31 @@ class CoreWorker:
                 raise exc.ActorDiedError(actor_id, state.death_cause)
             fut = asyncio.get_event_loop().create_future()
             state.waiters.append(fut)
-            await asyncio.wait_for(fut, timeout)
+            try:
+                await asyncio.wait_for(fut, timeout)
+            except asyncio.TimeoutError:
+                # a constructor may run longer than ``timeout`` (a serve
+                # replica that compiles its programs before it is ready,
+                # with an empty compile cache): while the GCS says a
+                # worker runs it the call waits behind it, as long as the
+                # serve controller's health check does (``gcs.py
+                # CONSTRUCTOR_TIMEOUT_S``); an actor no worker was leased
+                # for, or any other state that stays silent, is an actor
+                # nobody will bring up, and the timeout is raised
+                waited += timeout
+                if fut in state.waiters:
+                    state.waiters.remove(fut)
+                info = await self.gcs.call(
+                    "get_actor", {"actor_id": actor_id}, timeout=30)
+                if info is not None:
+                    state.state, state.address = info.state, info.address
+                    state.death_cause = info.death_cause
+                constructing = (
+                    info is not None and info.constructor_running
+                    and info.state in gcs_states.CONSTRUCTING
+                    and waited < gcs_states.CONSTRUCTOR_TIMEOUT_S)
+                if state.state not in ("ALIVE", "DEAD") and not constructing:
+                    raise
         return state
 
     def submit_actor_task(self, actor_id: ActorID, method_name: str, args: tuple,

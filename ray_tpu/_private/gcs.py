@@ -34,6 +34,14 @@ PENDING_CREATION = "PENDING_CREATION"
 ALIVE = "ALIVE"
 RESTARTING = "RESTARTING"
 DEAD = "DEAD"
+# the states in which an actor's constructor has not returned yet. An
+# actor no worker has been leased for is in them too:
+# ``ActorInfo.constructor_running`` tells the two apart. Who waits behind
+# a running constructor (a call, in core_worker._wait_actor_alive; the
+# serve controller's health check) gives it this long: weights made on a
+# chip plus cold compiles take minutes, never this long
+CONSTRUCTING = (PENDING_CREATION, RESTARTING)
+CONSTRUCTOR_TIMEOUT_S = 1800.0
 
 
 @dataclass
@@ -83,6 +91,8 @@ class ActorInfo:
     num_restarts: int = 0
     death_cause: str = ""
     creation_spec: Any = None         # pickled TaskSpec for restarts
+    # a leased worker has begun the constructor (handle_actor_constructing)
+    constructor_running: bool = False
 
 
 class GcsServer:
@@ -1461,10 +1471,21 @@ class GcsServer:
                     class_name=info.class_name, name=info.name)
         return True
 
+    async def handle_actor_constructing(self, payload, conn):
+        """The worker leased for the actor begins its constructor: from
+        here on a silent PENDING_CREATION or RESTARTING is a constructor
+        that runs, not a lease that is waited for."""
+        actor = self.actors.get(payload["actor_id"])
+        if actor is None or actor.state not in CONSTRUCTING:
+            return False
+        actor.constructor_running = True
+        return True
+
     async def handle_actor_alive(self, payload, conn):
         actor = self.actors.get(payload["actor_id"])
         if actor is None:
             return False
+        actor.constructor_running = False
         if actor.state == DEAD:
             # killed while still creating (driver exited, explicit kill):
             # do NOT resurrect — put the late-arriving worker down instead
@@ -1496,6 +1517,7 @@ class GcsServer:
                 and actor.num_restarts < actor.max_restarts):
             cause += " (creating driver exited; restart impossible)"
             actor.num_restarts = actor.max_restarts
+        actor.constructor_running = False
         if actor.num_restarts < actor.max_restarts:
             actor.num_restarts += 1
             actor.state = RESTARTING

@@ -821,6 +821,10 @@ async def _amain():
         if spec.actor_creation:
             core.job_id = spec.job_id
             core.current_task_id = spec.task_id
+            # who waits for this actor goes on waiting while its
+            # constructor runs (core_worker._wait_actor_alive)
+            await core.gcs.call("actor_constructing",
+                                {"actor_id": spec.actor_id}, timeout=30)
             reply = await loop.run_in_executor(executor.pool,
                                                executor.execute_actor_creation, spec)
             if reply["error"] is None:

@@ -147,8 +147,9 @@ def heads(h, lp, lr, state, *, cfg, kind, attend, **how):
     q, k, v = runner._heads(h, lp, lr, cfg=cfg, kind=kind, **how)
     with jax.named_scope("rt.attn.window" if windowed(kind)
                          else "rt.attn.full"):
-        return attend(q, k, v, state,
-                      cfg.window if windowed(kind) else None)
+        o, kept = attend(q, k, v, state,
+                         cfg.window if windowed(kind) else None)
+    return (runner._gated(o, h, lp) if cfg.attn_output_gate else o), kept
 
 
 def prefill(cfg, cache, block_tables, prompt_lens, slots, pos_grid, valid):

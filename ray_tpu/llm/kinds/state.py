@@ -35,7 +35,6 @@ import numpy as np
 from ...models.llama import linear
 from ...ops import attention, linear_attention, rms_norm
 from ...ops import sparse_attention as sparse
-from ...ops.quant import weight_einsum
 from .. import runner
 from ..cache import KVCache
 from . import Burst
@@ -188,9 +187,7 @@ def heads(h, lp, lr, state, *, cfg, kind, attend, **how):
         # the output norm over all heads
         o = rms_norm(o.reshape(*o.shape[:2], -1), lp["o_norm"],
                      cfg.norm_eps).reshape(o.shape)
-    gate = weight_einsum("bsd,dhk->bshk", h, lp["wg"],
-                         preferred_element_type=jnp.float32)
-    return o * jax.nn.sigmoid(gate), kept
+    return runner._gated(o, h, lp), kept
 
 
 def _by_kind(of_linear, of_block):

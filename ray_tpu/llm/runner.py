@@ -46,7 +46,10 @@ A layer pattern (``LlamaConfig.layer_pattern``) still has the one
 runs the period's layers in turn, each with its kind string, which
 ``heads`` is told. Leading dense layers (``n_dense_layers``) are their
 own stack ``params["dense_layers"]``: ``_layers`` scans them first, with
-the dense feed-forward; the cache's layers are in that order.
+the dense feed-forward; the cache's layers are in that order. Under a
+pattern they sit INSIDE it: the periods that hold one run before the
+scan, layer by layer, each layer at its place in its group's pool, and
+the scan runs the periods behind them.
 
 Static shapes throughout: prefill pads a prompt to a power-of-2 bucket
 or a rung between two (``prefill_bucket``; one executable a bucket),
@@ -121,7 +124,9 @@ def _mlp(h, lp, cfg: LlamaConfig, valid=None, experts=None, logits=None):
             norm_topk_prob=cfg.norm_topk_prob, valid=valid,
             layer=lp["layer"], logits=logits, activation=cfg.expert_act,
             n_group=cfg.n_group, topk_group=cfg.topk_group,
-            scale=cfg.routed_scale, held=cfg.experts_held, shared=shared)
+            scale=cfg.routed_scale, held=cfg.experts_held, shared=shared,
+            score=cfg.router_score,
+            bias=lp["expert_bias"] if cfg.router_bias else None)
     g = weight_einsum("bsd,dm->bsm", h, lp["w_gate"])
     u = weight_einsum("bsd,dm->bsm", h, lp["w_up"])
     return weight_einsum("bsm,md->bsd", jax.nn.silu(g) * u,
@@ -268,6 +273,14 @@ def _heads(h, lp, lr, *, cfg: LlamaConfig, kind, cos, sin, positions,
     return q, k, v
 
 
+def _gated(o, h, lp):
+    """Attention's output times sigmoid(W_g h), before ``wo``
+    (``cfg.attn_output_gate``): float32, in o's shape."""
+    gate = weight_einsum("bsd,dhk->bshk", h, lp["wg"],
+                         preferred_element_type=jnp.float32)
+    return o * jax.nn.sigmoid(gate)
+
+
 def _chunk_masks(span: int, start_pos, valid):
     """A chunk's two masks: (the cached span's positions below the
     chunk's start [1, 1, span]: earlier chunks wrote them; the chunk's
@@ -309,11 +322,16 @@ def _block(x, inputs, *, cfg: LlamaConfig, kind, cos, sin, positions, valid,
             return a
         return (a.astype(jnp.float32) * cfg.residual_scale).astype(x.dtype)
 
-    x = x + added(weight_einsum("bshk,hkd->bsd", o.astype(x.dtype),
-                                lp["wo"]))
+    def post(a, name):
+        # sandwich norms (``cfg.post_norms``): a norm of its own on what
+        # the half adds; a layer without the weight adds it as it is
+        return rms_norm(a, lp[name], cfg.norm_eps) if name in lp else a
+
+    x = x + added(post(weight_einsum("bshk,hkd->bsd", o.astype(x.dtype),
+                                     lp["wo"]), "post_attn_norm"))
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     m, counts = _mlp(h, lp, cfg, valid, experts, logits)
-    return x + added(m), (kept, counts)
+    return x + added(post(m, "post_mlp_norm")), (kept, counts)
 
 
 def _layers(params, cfg: LlamaConfig, cos, sin, lora=None):
@@ -395,27 +413,46 @@ def _layers(params, cfg: LlamaConfig, cos, sin, lora=None):
                     lambda a, b: jnp.concatenate([a, b], 0), first, kept)
             return x, (kept,), _total(counts)
 
-        def period(x, p):
+        def period(x, p, leading=False):
             # every layer takes its own slices by its index: handed the
             # period's slices as one array, the layers would each copy
-            # theirs out of it (a burst's keys, read twice a step)
+            # theirs out of it (a burst's keys, read twice a step).
+            # ``leading``: ``p`` is a number, not the scan's index, and
+            # the period's first layers may be dense ones
             kept, counts = [[] for _ in range(n_groups)], []
             for j, (kind, (g, place)) in enumerate(zip(pattern, places)):
-                layer = p * len(pattern) + j
+                is_dense = leading and p * len(pattern) + j < n_dense
+                # the expert layers' stack begins behind the dense ones
+                stack, first = (dense, 0) if is_dense else (layers, n_dense)
                 x, (rows, n) = block(
-                    x, (at(layers, layer), None if state is None else at(
-                        state[g], p * per_group[g] + place), {}), kind=kind)
+                    x, (at(stack, p * len(pattern) + (j - first)),
+                        None if state is None else at(
+                            state[g], p * per_group[g] + place), {}),
+                    kind=kind, **({"experts": None} if is_dense else {}))
                 kept[g].append(rows)
-                counts.append(n)
+                if n is not None:
+                    counts.append(n)
             return x, (tuple(jax.tree.map(lambda *a: jnp.stack(a), *rows)
                              for rows in kept),
-                       None if counts[0] is None else sum(counts))
+                       sum(counts) if counts else None)
 
+        # the periods that hold a dense layer, then the scan of the rest
+        n_lead = -(-n_dense // len(pattern))
+        lead = []
+        for p in range(n_lead):
+            x, out = period(x, p, leading=True)
+            lead.append(out)
         x, (kept, counts) = jax.lax.scan(
-            period, x, jnp.arange(cfg.n_layers // len(pattern)))
-        return x, tuple(jax.tree.map(
-            lambda a: a.reshape(-1, *a.shape[2:]), rows)
-            for rows in kept), _total(counts)
+            period, x, jnp.arange(n_lead, cfg.n_layers // len(pattern)))
+        kept = tuple(jax.tree.map(
+            lambda a: a.reshape(-1, *a.shape[2:]), rows) for rows in kept)
+        counts = _total(counts)
+        if lead:
+            kept = tuple(jax.tree.map(
+                lambda *a: jnp.concatenate(a, 0), *rows)
+                for rows in zip(*(k for k, _ in lead), kept))
+            counts = sum((n for _, n in lead if n is not None), counts)
+        return x, kept, counts
 
     return run
 

@@ -44,6 +44,13 @@ LAYER_KINDS = ("full", "full_nope", "window", "window_nope", "linear",
                "block_nope")
 
 
+# deviation of a seeded ``expert_bias`` (``LlamaConfig.router_bias``), in
+# units of a sigmoid score: it moves the choice between experts whose
+# scores lie that close (the 4th and 5th of 256 lie 0.02 apart on
+# average) and leaves every expert about its share of the rows
+EXPERT_BIAS_SCALE = 0.02
+
+
 def windowed(kind: str) -> bool:
     return kind.startswith("window")
 
@@ -181,6 +188,18 @@ class LlamaConfig:
     embed_scale: float = 1.0
     residual_scale: float = 1.0
     logit_divisor: float = 1.0
+    # how the router scores an expert: "softmax" over all the logits, or
+    # "sigmoid", every logit's own (the chosen scores then sum to
+    # anything: ``norm_topk_prob`` makes them a share)
+    router_score: str = "softmax"
+    # a bias an expert (``expert_bias`` [E], float32, never quantized)
+    # that the router's CHOICE sees, top_k of score + bias, and the
+    # chosen experts' weights do not: they are the unbiased scores
+    router_bias: bool = False
+    # sandwich norms: an RMSNorm with its own weight on what each half
+    # of a layer ADDS to the stream (``post_attn_norm``,
+    # ``post_mlp_norm``), beside the two on what the halves read
+    post_norms: bool = False
 
     def __post_init__(self):
         kinds = self.layer_kinds
@@ -259,14 +278,22 @@ class LlamaConfig:
             raise ValueError("latent attention has no layer_pattern (every "
                              "layer full and rotated, ONE stack of weights) "
                              "and no qk_norm")
-        if self.n_dense_layers and (len(kinds) > 1 or not self.n_experts
+        if self.n_dense_layers and (not self.n_experts
                                     or not self.dense_mlp_dim
                                     or self.n_dense_layers >= self.n_layers):
+            # with a layer_pattern they sit INSIDE it (a window layer of
+            # the first period, say), each in its group's pool; a pattern
+            # of kinds with weights of their own has no experts
             raise ValueError(
                 "n_dense_layers: leading dense layers (of dense_mlp_dim) "
                 "come before the expert layers of a configuration with "
-                "experts and no layer_pattern (the expert layers are ONE "
-                "stack of weights)")
+                "experts (the expert layers are ONE stack of weights)")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_score {self.router_score!r}: "
+                             f"'softmax' or 'sigmoid'")
+        if self.router_bias and not self.n_experts:
+            raise ValueError("router_bias is a bias an expert: it needs "
+                             "n_experts")
         if self.n_experts and self.n_experts % self.n_group:
             raise ValueError(f"n_experts={self.n_experts} is no whole "
                              f"number of n_group={self.n_group} groups")
@@ -526,7 +553,10 @@ def init_params(key, cfg: LlamaConfig, gains=None):
     ``wkv_a`` [d, kv_lora + rope], ``kv_a_norm``, ``w_uk`` and ``w_uv``
     (the two halves of the published ``kv_b_proj``, each [kv_lora, h,
     nope or v]) and ``wo`` [h, v, d]. Shared experts: ``ws_gate``,
-    ``ws_up``, ``ws_down``, one SwiGLU of n_shared_experts * mlp_dim."""
+    ``ws_up``, ``ws_down``, one SwiGLU of n_shared_experts * mlp_dim.
+    ``router_bias``: ``expert_bias`` [L, E] float32, seeded NON-zero (a
+    bias added to the weights, or left out of the choice, is another
+    answer). ``post_norms``: ``post_attn_norm`` and ``post_mlp_norm``."""
     d, hd = cfg.dim, cfg.head_dim
     h, hkv = cfg.n_heads, cfg.n_kv_heads
     ks = jax.random.split(key, 9)
@@ -596,14 +626,17 @@ def init_params(key, cfg: LlamaConfig, gains=None):
                 ws_down=norm(kt[2], (L, ms, d), ms, "ws_down"))
     else:
         mlp_params = dense_mlp(ks[5:8], L, m)
-    if cfg.qk_norm:
+    def qk_norms(n):
         by_head = cfg.qk_norm_by_head
         used.update(("q_norm", "k_norm"))
-        mlp_params.update(
-            q_norm=jnp.full((L, hd if by_head else h * hd),
+        return dict(
+            q_norm=jnp.full((n, hd if by_head else h * hd),
                             gains.get("q_norm", 1.0), cfg.dtype),
-            k_norm=jnp.full((L, hd if by_head else hkv * hd),
+            k_norm=jnp.full((n, hd if by_head else hkv * hd),
                             gains.get("k_norm", 1.0), cfg.dtype))
+
+    if cfg.qk_norm:
+        mlp_params.update(qk_norms(L))
     if cfg.sparse_top_k:
         # the indexer: query heads, the one key, a weight a head, and the
         # key's LayerNorm (gains scattered about 1, biases about 0)
@@ -630,6 +663,21 @@ def init_params(key, cfg: LlamaConfig, gains=None):
     if cfg.attn_output_gate:
         kg = jax.random.split(jax.random.fold_in(key, 3), 2)
         params["layers"]["wg"] = norm(kg[0], (L, d, h, hd), d, "wg")
+
+    def post_norms(k, n):
+        used.update(("post_attn_norm", "post_mlp_norm"))
+        return {name: (gains.get(name, 1.0) * scattered(
+            jax.random.fold_in(k, i), (n, d))).astype(cfg.dtype)
+            for i, name in enumerate(("post_attn_norm", "post_mlp_norm"))}
+
+    if cfg.post_norms:
+        params["layers"].update(post_norms(jax.random.fold_in(key, 7), L))
+    if cfg.router_bias:
+        used.add("expert_bias")
+        params["layers"]["expert_bias"] = (
+            gains.get("expert_bias", 1.0) * EXPERT_BIAS_SCALE
+            * jax.random.normal(jax.random.fold_in(key, 8),
+                                (L, cfg.n_experts), jnp.float32))
     if cfg.own_weights:
         # the linear layers' stack: heads of their own number, an output
         # norm over all heads, the gate, and a feed-forward as every layer
@@ -664,6 +712,15 @@ def init_params(key, cfg: LlamaConfig, gains=None):
             "mlp_norm": jnp.ones((n, d), cfg.dtype),
             **dense_mlp(kd[4:], n, cfg.dense_mlp_dim),
         }
+        # what a layer's attention half has beside its projections
+        if cfg.qk_norm:
+            params["dense_layers"].update(qk_norms(n))
+        if cfg.attn_output_gate:
+            params["dense_layers"]["wg"] = norm(
+                jax.random.fold_in(key, 9), (n, d, h, hd), d, "wg")
+        if cfg.post_norms:
+            params["dense_layers"].update(
+                post_norms(jax.random.fold_in(key, 10), n))
     unknown = set(gains) - used
     if unknown:
         raise ValueError(f"gains for matrices that are not seeded: "
@@ -787,7 +844,9 @@ def forward(params, tokens, cfg: LlamaConfig, *,
             or cfg.n_shared_experts or cfg.n_group > 1
             or cfg.experts_held is not None or cfg.sparse_top_k
             or cfg.attn_output_gate or cfg.embed_scale != 1.0
-            or cfg.residual_scale != 1.0 or cfg.logit_divisor != 1.0):
+            or cfg.residual_scale != 1.0 or cfg.logit_divisor != 1.0
+            or cfg.router_score != "softmax" or cfg.router_bias
+            or cfg.post_norms):
         raise ValueError(
             "the training forward runs ONE stack of layers of one kind "
             "(full, rotated, GQA, the router on the feed-forward's input, "
@@ -797,8 +856,9 @@ def forward(params, tokens, cfg: LlamaConfig, *,
             "layers with weights of their own), router_input='attention', "
             "expert_act='relu', latent attention, n_dense_layers, "
             "n_shared_experts, n_group, experts_held, an indexer "
-            "(sparse_top_k), attn_output_gate, embed_scale, residual_scale "
-            "or logit_divisor is served by llm/runner.py only")
+            "(sparse_top_k), attn_output_gate, embed_scale, residual_scale, "
+            "logit_divisor, router_score='sigmoid', router_bias or "
+            "post_norms is served by llm/runner.py only")
     csl = partial(with_sharding_constraint_logical, rules=rules, mesh=mesh)
     cos, sin = rope_frequencies(cfg.head_dim, tokens.shape[1],
                                 cfg.rope_theta, dtype=jnp.float32)

@@ -330,6 +330,8 @@ def grouped_matmul(lhs, w, row_expert, group_sizes, layer=None):
 
 
 _EXPERT_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+_ROUTER_SCORES = {"softmax": lambda logits: jax.nn.softmax(logits, axis=-1),
+                  "sigmoid": jax.nn.sigmoid}
 
 
 def router_logits(x, router_w):
@@ -343,9 +345,13 @@ def router_logits(x, router_w):
 
 
 def route(probs, top_k: int, *, norm_topk_prob: bool = True,
-          n_group: int = 1, topk_group: int = 1, scale: float = 1.0):
-    """The router's choice: probs [T, E] float32 -> (weight [T, k],
-    chosen [T, k]). Group-limited (``n_group`` > 1, DeepSeek-V2's
+          n_group: int = 1, topk_group: int = 1, scale: float = 1.0,
+          bias=None):
+    """The router's choice: probs [T, E] float32 (softmax probabilities
+    or sigmoid scores) -> (weight [T, k], chosen [T, k]). ``bias`` [E]
+    float32: the CHOICE (of groups and of experts) is made by ``probs +
+    bias``, and the chosen experts' weights are their ``probs``, which
+    never see it. Group-limited (``n_group`` > 1, DeepSeek-V2's
     ``group_limited_greedy``): the experts are ``n_group`` groups of
     neighbours, a group's score is its best expert's probability, every
     expert outside the ``topk_group`` best groups is given probability
@@ -353,13 +359,18 @@ def route(probs, top_k: int, *, norm_topk_prob: bool = True,
     probabilities are used as the router gave them, or renormalised to
     sum to 1 where ``norm_topk_prob``; then x ``scale``."""
     T, E = probs.shape
+    by = probs if bias is None else probs + bias
     if n_group > 1:
-        best = probs.reshape(T, n_group, E // n_group).max(-1)
+        best = by.reshape(T, n_group, E // n_group).max(-1)
         _, groups = jax.lax.top_k(best, topk_group)             # [T, g]
         kept = jnp.zeros((T, n_group), bool).at[
             jnp.arange(T)[:, None], groups].set(True)
-        probs = jnp.where(jnp.repeat(kept, E // n_group, axis=1), probs, 0.0)
-    weight, chosen = jax.lax.top_k(probs, top_k)                # [T, k]
+        # a biased score can lie below 0: what is left out lies below all
+        by = jnp.where(jnp.repeat(kept, E // n_group, axis=1), by,
+                       0.0 if bias is None else -jnp.inf)
+    weight, chosen = jax.lax.top_k(by, top_k)                   # [T, k]
+    if bias is not None:
+        weight = jnp.take_along_axis(probs, chosen, axis=-1)
     if norm_topk_prob:
         weight = weight / jnp.maximum(weight.sum(-1, keepdims=True), 1e-9)
     return weight * scale if scale != 1.0 else weight, chosen
@@ -369,7 +380,8 @@ def moe_mlp_routed(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                    norm_topk_prob: bool = True, valid=None, layer=None,
                    logits=None, activation: str = "silu",
                    n_group: int = 1, topk_group: int = 1,
-                   scale: float = 1.0, held=None, shared=None):
+                   scale: float = 1.0, held=None, shared=None,
+                   score: str = "softmax", bias=None):
     """Dropless top-k gated expert layer, the serving path's one.
 
     x (B, S, D); router_w (D, E) float32; w_gate/w_up (E, D, M) and
@@ -384,7 +396,9 @@ def moe_mlp_routed(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     attention's input, for a model whose router sits there); the rows
     are routed by them and ``router_w`` is not read. ``activation``: an
     expert is ``act(gate) * up``, "silu" or "relu". ``n_group``,
-    ``topk_group``, ``scale``: see ``route``.
+    ``topk_group``, ``scale``, ``bias``: see ``route``. ``score``: what
+    ``route`` is handed, the logits' "softmax" over all experts or their
+    "sigmoid", an expert's own.
 
     ``held`` (first, count): the experts that are HERE, one chip's share
     of an expert-parallel layer. The router keeps its E outputs and
@@ -411,10 +425,10 @@ def moe_mlp_routed(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     E = logits.shape[-1]
     first, count = held or (0, E)
     with jax.named_scope("rt.moe.route"):
-        probs = jax.nn.softmax(logits.reshape(T, E), axis=-1)
+        probs = _ROUTER_SCORES[score](logits.reshape(T, E))
         weight, chosen = route(probs, top_k, norm_topk_prob=norm_topk_prob,
                                n_group=n_group, topk_group=topk_group,
-                               scale=scale)
+                               scale=scale, bias=bias)
         expert = chosen.reshape(T * top_k) - first
         given = None if valid is None else jnp.repeat(valid.reshape(T),
                                                       top_k)

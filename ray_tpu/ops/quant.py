@@ -200,7 +200,8 @@ def _init_params_quantized_jit(key, cfg, gains=()) -> Dict:
     # stays what it was; latent attention, shared experts and leading
     # dense layers need more and draw from a second set
     plain = not (cfg.latent or cfg.n_shared_experts or cfg.n_dense_layers
-                 or cfg.sparse_top_k or cfg.own_weights)
+                 or cfg.sparse_top_k or cfg.own_weights or cfg.router_bias
+                 or cfg.post_norms)
     ks = iter(jax.random.split(key, 16) if plain else jax.random.split(
         jax.random.fold_in(key, 1), 64))
     gains = dict(gains)
@@ -287,10 +288,13 @@ def _init_params_quantized_jit(key, cfg, gains=()) -> Dict:
                 ws_down=qrand((L, ms, d), ms, (0, 2), name="ws_down"))
     else:
         layers.update(dense_mlp(L, m))
-    if cfg.qk_norm:
+    def qk_norms(n):
         by_head = cfg.qk_norm_by_head
-        layers.update(q_norm=gain(L, hd if by_head else h * hd, "q_norm"),
-                      k_norm=gain(L, hd if by_head else hkv * hd, "k_norm"))
+        return dict(q_norm=gain(n, hd if by_head else h * hd, "q_norm"),
+                    k_norm=gain(n, hd if by_head else hkv * hd, "k_norm"))
+
+    if cfg.qk_norm:
+        layers.update(qk_norms(L))
     if cfg.sparse_top_k:
         # the indexer's three projections, int8 like their neighbours,
         # and its key's LayerNorm: gains about 1, biases about 0
@@ -303,6 +307,22 @@ def _init_params_quantized_jit(key, cfg, gains=()) -> Dict:
             wi_k_bias=(gain(L, di) - 1.0).astype(jnp.bfloat16))
     if cfg.attn_output_gate:
         layers["wg"] = qrand((L, d, h, hd), d, (0, 2, 3), name="wg")
+
+    def post_norms(n):
+        return dict(post_attn_norm=gain(n, d, "post_attn_norm"),
+                    post_mlp_norm=gain(n, d, "post_mlp_norm"))
+
+    if cfg.post_norms:
+        layers.update(post_norms(L))
+    if cfg.router_bias:
+        # float32 and never quantized, as the router; seeded NON-zero
+        # (``models.llama.init_params``)
+        from ..models.llama import EXPERT_BIAS_SCALE
+
+        used.add("expert_bias")
+        layers["expert_bias"] = (
+            gains.get("expert_bias", 1.0) * EXPERT_BIAS_SCALE
+            * jax.random.normal(next(ks), (L, cfg.n_experts), jnp.float32))
     params = {
         "embed": embed,
         "layers": layers,
@@ -333,6 +353,14 @@ def _init_params_quantized_jit(key, cfg, gains=()) -> Dict:
             "mlp_norm": jnp.ones((n, d), jnp.bfloat16),
             **dense_mlp(n, cfg.dense_mlp_dim),
         }
+        # what a layer's attention half has beside its projections
+        if cfg.qk_norm:
+            params["dense_layers"].update(qk_norms(n))
+        if cfg.attn_output_gate:
+            params["dense_layers"]["wg"] = qrand((n, d, h, hd), d,
+                                                 (0, 2, 3), name="wg")
+        if cfg.post_norms:
+            params["dense_layers"].update(post_norms(n))
     unknown = set(gains) - used
     if unknown:
         raise ValueError(f"gains for matrices that are not seeded: "

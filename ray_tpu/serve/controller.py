@@ -14,17 +14,18 @@ import asyncio
 import time
 from typing import Any, Dict, List, Optional
 
+from .._private.gcs import CONSTRUCTING as _CONSTRUCTING
+from .._private.gcs import CONSTRUCTOR_TIMEOUT_S as REPLICA_STARTUP_TIMEOUT_S
 from .._private.rpc import RpcError
 from ..exceptions import RayTpuError
 
 CONTROLLER_NAME = "SERVE::controller"
 HEALTH_PERIOD_S = 2.0
 HEALTH_TIMEOUT_S = 15.0
-# the longest a replica's constructor may run before it is replaced:
-# weights made on a chip plus cold compiles take minutes, never this long
-REPLICA_STARTUP_TIMEOUT_S = 1800.0
-# GCS actor states in which the constructor has not returned yet
-_CONSTRUCTING = ("PENDING_CREATION", "RESTARTING")
+# REPLICA_STARTUP_TIMEOUT_S: the longest a replica's constructor may run
+# before it is replaced; _CONSTRUCTING: the GCS actor states in which the
+# constructor has not returned yet (both the GCS's own, shared with the
+# wait of a call behind a constructor)
 
 # What best-effort calls against a possibly-dead replica/proxy can
 # raise (transport loss, timeouts, the actor already being gone).

@@ -123,3 +123,49 @@ def test_detached_requires_name(ray_cluster):
 
     with pytest.raises(ValueError, match="must be named"):
         A.options(lifetime="detached").remote()
+
+
+@pytest.mark.parametrize("bound, placed, alive", [
+    (60.0, True, True), (1.0, True, False), (60.0, False, False)],
+    ids=["waits-behind-it", "a-constructor-that-hangs",
+         "a-lease-nobody-grants"])
+def test_a_call_waits_behind_a_constructor_the_gcs_says_is_running(
+        ray_cluster, monkeypatch, bound, placed, alive):
+    """A constructor that outlasts the wait's timeout (a serve replica
+    compiling its programs with an empty cache) is not a dead actor: while
+    the GCS says a worker runs it the wait goes on, up to the bound. An
+    actor no worker can be leased for is PENDING_CREATION too, and the
+    wait's own timeout is raised for it as before."""
+    from ray_tpu._private import gcs
+    from ray_tpu._worker_api import core
+
+    monkeypatch.setattr(gcs, "CONSTRUCTOR_TIMEOUT_S", bound)
+
+    @ray_tpu.remote
+    class Slow:
+        def __init__(self):
+            time.sleep(3.0)
+
+        def ping(self):
+            return "pong"
+
+    actor = (Slow if placed else Slow.options(
+        resources={"a resource no node has": 1})).remote()
+    if placed:
+        # (a worker's start is not the constructor: the wait's timeout is
+        # 120 s in the runtime and half a second here)
+        deadline = time.time() + 60
+        while not core().io.run(core().gcs.call(
+                "get_actor", {"actor_id": actor._actor_id}, timeout=30),
+                timeout=60).constructor_running:
+            assert time.time() < deadline
+            time.sleep(0.05)
+    wait = core()._wait_actor_alive(actor._actor_id, timeout=0.5)
+    if alive:
+        assert core().io.run(wait, timeout=60).state == "ALIVE"
+        assert ray_tpu.get(actor.ping.remote(), timeout=30) == "pong"
+    else:
+        with pytest.raises(asyncio.TimeoutError):
+            core().io.run(wait, timeout=60)
+        if not placed:
+            ray_tpu.kill(actor)

@@ -859,3 +859,73 @@ def test_block_decode_reads_the_listed_pages_where_they_lie(one_chip):
     assert not [line for line in text.splitlines()
                 if " = bf16[3,8193,128,128]" in line and " copy(" in line]
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024**2
+
+
+# Trinity-Large-Preview's widths as the benchmark's configuration runs
+# them (one chip's share of eight: 32 of 256 experts, an eighth of the
+# vocabulary; two periods, the first layer dense INSIDE the pattern)
+TRINITY_WIDTHS = dict(
+    vocab=25024, dim=3072, n_layers=8, n_heads=48, n_kv_heads=8,
+    head_size=128, mlp_dim=3072, max_seq=262144, rope_theta=1e4,
+    norm_eps=1e-5, n_experts=256, top_k=4, norm_topk_prob=True,
+    routed_scale=2.448, router_score="sigmoid", router_bias=True,
+    n_dense_layers=1, dense_mlp_dim=12288, n_shared_experts=1,
+    experts_held=(0, 32), window=4096,
+    layer_pattern=("window", "window", "window", "full_nope"),
+    qk_norm=True, qk_norm_by_head=True, attn_output_gate=True,
+    post_norms=True, embed_scale=3072 ** 0.5)
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_dense_layers_inside_a_pattern_compile_with_their_kernels(
+        latent, one_chip, program):
+    """The served programs of the family whose first layer is dense
+    inside a (window, window, window, full NoPE) pattern, at its
+    published widths: the leading period runs before the period scan
+    (3 + 4 expert layers: 21 grouped products), the window layers are on
+    the window flash kernel and the full ones under their own span, and
+    the held experts stay the int8 stacks they are stored as."""
+    from ray_tpu.llm.cache import window_group_pages
+    from ray_tpu.llm.runner import decode_burst, prefill_sample
+    from ray_tpu.models import LlamaConfig
+    from ray_tpu.ops import rope_frequencies
+    from ray_tpu.ops.moe import GROUPED_KERNEL
+    from ray_tpu.ops.quant import init_params_quantized
+
+    cfg = LlamaConfig(**TRINITY_WIDTHS)     # ``latent`` patched _on_tpu
+    params = _on_chip(jax.eval_shape(lambda: init_params_quantized(
+        jax.random.PRNGKey(0), cfg)), one_chip)
+    assert params["layers"]["w_gate"]["q"].shape == (7, 32, 3072, 3072)
+    assert params["layers"]["expert_bias"].shape == (7, 256)
+    assert params["dense_layers"]["w_gate"]["q"].shape == (1, 3072, 12288)
+    cos, sin = _on_chip(jax.eval_shape(lambda: rope_frequencies(
+        cfg.head_dim, 16384, cfg.rope_theta)), one_chip)
+    B = 1 if program == "prefill" else 8
+    group = tuple(_sds(
+        (cfg.group_layers(g), 129 if w is None else window_group_pages(
+            B, w, 64, 8), 64, cfg.n_kv_heads, cfg.head_dim), cfg.dtype,
+        one_chip) for g, w in enumerate(cfg.kv_groups))
+    assert [p.shape[0] for p in group] == [2, 6]
+    tables = tuple(_sds((B, 64), jnp.int32, one_chip) for _ in group)
+    i32, f32 = _sds((B,), jnp.int32, one_chip), _sds((B,), jnp.float32,
+                                                     one_chip)
+    if program == "prefill":
+        text = prefill_sample.lower(
+            params, group, group, _sds((1, 4096), jnp.int32, one_chip), i32,
+            tables, cos, sin, 0, f32, i32, f32, None, cfg=cfg,
+            greedy=True).compile().as_text()
+        flash = {re.sub(r"[.\d]+$", "", name)
+                 for name, _ in _kernel_calls(text)
+                 if GROUPED_KERNEL not in name}
+        assert flash == {"flash_window_fwd", "rt.attn.full"}
+    else:
+        lists = tuple(_sds((3, 16), jnp.int32, one_chip) for _ in group)
+        text = decode_burst.lower(
+            params, group, group, i32, i32, tables,
+            _sds((B,), jnp.bool_, one_chip), cos, sin, 0, f32, i32, f32,
+            None, lists, _sds((), jnp.int32, one_chip), cfg=cfg, n_steps=8,
+            greedy=True).compile().as_text()
+    assert sum(GROUPED_KERNEL in name
+               for name, _ in _kernel_calls(text)) == 21
+    held = r"\[(?:7,)?32,3072,3072\]"
+    assert not re.findall(r"(?:bf16|f32)" + held, text)
