@@ -4,7 +4,13 @@ Design choices for the TPU/XLA compilation model:
   * **scan over layers** — one compiled layer body, stacked params with a
     leading "layers" axis: compile time stays flat as depth grows.
   * **remat per layer** (``jax.checkpoint``) — trades FLOPs for HBM,
-    standard recipe for long-sequence training.
+    standard recipe for long-sequence training. What a layer's backward
+    pass keeps instead of recomputing is ``LlamaConfig.remat_policy``
+    (``REMAT_POLICIES``); the default, ``"attn"``, keeps the layer's
+    input, the flash forward's output and LSE and the attention output
+    product's result, so that kernel, that product and its ``tp``
+    all-reduce run once a step, not twice; ``"full"`` keeps the input
+    alone.
   * **logical axis names** on every param; the rules table
     (ray_tpu.parallel.sharding) maps them onto the dp/fsdp/tp/sp mesh, so
     FSDP/TP/SP layouts need no model edits (GSPMD inserts collectives).
@@ -26,10 +32,11 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import shard_map
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops import apply_rotary, attention, ring_attention, rms_norm, rope_frequencies
-from ..ops.attention import attention_path
+from ..ops.attention import FLASH_LSE, FLASH_OUT, attention_path
 from ..parallel.sharding import (DEFAULT_RULES, logical_sharding,
                                  with_sharding_constraint_logical)
 
@@ -42,6 +49,20 @@ from ..parallel.sharding import (DEFAULT_RULES, logical_sharding,
 # their own widths: a stack a kind (``init_params``)
 LAYER_KINDS = ("full", "full_nope", "window", "window_nope", "linear",
                "block_nope")
+
+
+# the name ``_attn`` gives the result of its output product
+# ``bshk,hkd->bsd`` (under a ``tp`` mesh axis: after the all-reduce)
+ATTN_OUT = "attn_out"
+
+# what ``jax.checkpoint`` of a layer keeps for the backward pass beside
+# the layer's input (``LlamaConfig.remat_policy``); None keeps nothing
+REMAT_POLICIES = {
+    "attn": jax.checkpoint_policies.save_only_these_names(
+        ATTN_OUT, FLASH_OUT, FLASH_LSE),
+    "full": None,
+    "dots": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+}
 
 
 # deviation of a seeded ``expert_bias`` (``LlamaConfig.router_bias``), in
@@ -88,10 +109,18 @@ class LlamaConfig:
     # RMSNorm of the projected queries and keys over their whole width,
     # before the split into heads and the rotary embedding (OLMoE)
     qk_norm: bool = False
-    # "full": recompute everything (max HBM savings, ~1/3 extra FLOPs);
-    # "dots": save matmul outputs, recompute elementwise only — the right
-    # trade when HBM fits it (ref: jax checkpoint_policies)
-    remat_policy: str = "full"
+    # what a layer's backward pass keeps beside the layer's input, where
+    # ``remat`` (``REMAT_POLICIES``). "attn", the default: the flash
+    # forward's output and LSE (the blockwise path has none to keep) and
+    # the attention output product's result, 1.5 x batch x seq x dim
+    # values a layer: the backward pass repeats neither the kernel nor
+    # that product nor its ``tp`` all-reduce, and recomputes the rest
+    # (norms, q/k/v, rotary, gate and up). "full": nothing, recompute
+    # everything (least HBM, ~1/3 extra FLOPs): for whoever stands at
+    # the memory limit. "dots": every matmul output, recompute
+    # elementwise only — the right trade when HBM fits it (ref: jax
+    # checkpoint_policies)
+    remat_policy: str = "attn"
     # a head's width where it is not dim // n_heads (SmallThinker: 28
     # heads of 128 on a hidden size of 2560). None: dim // n_heads
     head_size: Optional[int] = None
@@ -202,6 +231,9 @@ class LlamaConfig:
     post_norms: bool = False
 
     def __post_init__(self):
+        if self.remat_policy not in REMAT_POLICIES:
+            raise ValueError(f"remat_policy {self.remat_policy!r}: one of "
+                             f"{sorted(REMAT_POLICIES)}")
         kinds = self.layer_kinds
         unknown = set(kinds) - set(LAYER_KINDS)
         if unknown:
@@ -814,7 +846,7 @@ def _attn(x, lp, cfg: LlamaConfig, cos, sin, mesh: Optional[Mesh], rules):
     else:
         out = attention(q, k, v, causal=True)
     out = jnp.einsum("bshk,hkd->bsd", out, lp["wo"])
-    return out
+    return checkpoint_name(out, ATTN_OUT)
 
 
 def _mlp(x, lp, cfg: LlamaConfig, csl):
@@ -885,13 +917,9 @@ def forward(params, tokens, cfg: LlamaConfig, *,
         out = h + mlp_out
         return csl(out, ("batch", "seq", "embed")), aux
 
-    if cfg.remat and cfg.remat_policy == "dots":
-        body = jax.checkpoint(
-            layer, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    elif cfg.remat:
-        body = jax.checkpoint(layer)
-    else:
-        body = layer
+    body = layer
+    if cfg.remat:
+        body = jax.checkpoint(layer, policy=REMAT_POLICIES[cfg.remat_policy])
     x, aux_losses = jax.lax.scan(body, x, params["layers"])
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)

@@ -788,8 +788,8 @@ def _attention_tpu(q, k, v, causal, scale):
 
 
 def _attn_fwd(q, k, v, causal, scale):
-    out, lse = flash_attention_tpu(q, k, v, causal=causal, scale=scale,
-                                   return_lse=True)
+    out, lse = _named(*flash_attention_tpu(
+        q, k, v, causal=causal, scale=scale, return_lse=True))
     return out, (q, k, v, out, lse)
 
 
@@ -825,3 +825,21 @@ def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
         return flash_attention_tpu(q, k, v, causal=causal, scale=scale,
                                    window=window, lengths=lengths)
     return _attention_tpu(q, k, v, causal, scale)
+
+
+# Below every call into a kernel, import and all: a Mosaic kernel's
+# serialized module carries the lines of its call sites, ``attention``'s
+# among them, and a line that moves above one is a cold first run of every
+# program that holds the kernel.
+FLASH_OUT, FLASH_LSE = "flash_out", "flash_lse"
+
+
+def _named(out, lse):
+    """The flash forward's two results under the names a
+    ``jax.checkpoint`` policy can keep them by (models/llama.py
+    ``REMAT_POLICIES``); outside a checkpoint a name is the identity. One
+    kernel gives both, so a policy that keeps one of the two names still
+    runs it again."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    return checkpoint_name(out, FLASH_OUT), checkpoint_name(lse, FLASH_LSE)

@@ -1,5 +1,7 @@
 """Llama model tests on the CPU mesh (SURVEY §4.4 device-count-free path)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,6 +10,7 @@ import pytest
 from ray_tpu.models import (
     LLAMA_CONFIGS, forward, init_params, lm_loss, param_logical_axes,
 )
+from ray_tpu.models.llama import REMAT_POLICIES
 from ray_tpu.parallel import MeshSpec, build_mesh, shard_pytree
 
 CFG = LLAMA_CONFIGS["tiny"]
@@ -69,3 +72,62 @@ def test_sharded_forward_all_layouts(cpu_mesh8, params):
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-4, atol=2e-4,
                                    err_msg=f"layout {spec}")
+
+
+def _named_products(jaxpr, name) -> int:
+    """``dot_general``s of ``jaxpr`` (and of every jaxpr inside it) whose
+    name stack holds ``name``: an einsum's own, its recomputation under a
+    checkpoint and its two transposes all carry the einsum's string."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if (eqn.primitive.name == "dot_general"
+                and name in str(eqn.source_info.name_stack)):
+            n += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _named_products(sub, name)
+    return n
+
+
+@pytest.mark.parametrize("policy", sorted(set(REMAT_POLICIES) - {"full"}))
+def test_remat_policy_keeps_the_loss_and_every_gradient(cpu_mesh8, policy):
+    """What a layer's checkpoint keeps is the value the forward pass
+    computed: on the fsdp=2 x tp=2 CPU mesh (float32, the blockwise
+    attention, which has no LSE to name) the loss and every gradient
+    leaf equal those of ``"full"``, which keeps nothing."""
+    kept = dataclasses.replace(CFG, remat=True, remat_policy=policy)
+    full = dataclasses.replace(kept, remat_policy="full")
+    mesh = build_mesh(MeshSpec(fsdp=2, tp=2), cpu_mesh8[:4])
+    params = init_params(jax.random.PRNGKey(0), kept)
+    params = jax.device_put(
+        params, shard_pytree(params, param_logical_axes(kept), mesh))
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 32),
+                                          0, kept.vocab)}
+
+    def loss_and_grads(cfg):
+        return jax.jit(jax.value_and_grad(
+            lambda p: lm_loss(p, batch, cfg, mesh=mesh)))(params)
+
+    loss, grads = loss_and_grads(kept)
+    loss_full, grads_full = loss_and_grads(full)
+    assert float(loss) == float(loss_full)
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+        np.asarray(a), np.asarray(b)), grads, grads_full)
+
+
+def test_default_remat_policy_runs_the_output_product_once(params):
+    """``remat=True`` by default keeps the attention output product's
+    result: the gradient's jaxpr holds that product in the forward scan
+    and its two transposes, and ``"full"`` a fourth, the recomputation."""
+    kept = dataclasses.replace(CFG, remat=True)
+    assert kept.remat_policy == "attn"
+    batch = {"tokens": jnp.zeros((2, 32), jnp.int32)}
+
+    def products(cfg):
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda p: lm_loss(p, batch, cfg)))(params).jaxpr
+        return _named_products(jaxpr, "bshk,hkd->bsd")
+
+    assert products(kept) == 3
+    assert products(dataclasses.replace(kept, remat_policy="full")) == 4
+    with pytest.raises(ValueError, match="remat_policy"):
+        dataclasses.replace(kept, remat_policy="nothing")

@@ -14,6 +14,7 @@ workers each import every test file, and a second file's fixture would
 find the library taken.
 """
 
+import dataclasses
 import functools
 import re
 
@@ -401,24 +402,21 @@ def test_prefill_sample_keeps_the_page_pool_out_of_its_layer_scan(
     assert mem.temp_size_in_bytes < pool, mem
 
 
-def test_sharded_train_step_keeps_flash_kernels(topo):
-    """fsdp=2 x tp=2 over the four described chips: GSPMD cannot partition
-    a Mosaic kernel, so the model runs attention under shard_map there.
-    The step must compile, keep the kernels, and spread its state."""
+def _sharded_step(topo, cfg, chips=4):
+    """``make_train_step`` over ``cfg`` and 4 x 2,048 tokens on fsdp=2 x
+    tp=2 of the four described chips (or on one of them), lowered on
+    shapes and compiled: ``(compiled, state)``."""
     import optax
 
-    from ray_tpu.models import LlamaConfig, init_params, lm_loss
-    from ray_tpu.models import param_logical_axes
+    from ray_tpu.models import init_params, lm_loss, param_logical_axes
     from ray_tpu.parallel import MeshSpec, build_mesh
     from ray_tpu.parallel.sharding import DEFAULT_RULES, shard_pytree
     from ray_tpu.train import make_train_step
     from ray_tpu.train.step import (TrainState, _batch_sharding,
                                     opt_state_shardings)
 
-    # 1b's head layout (16/8 heads of 128) at a fraction of its depth
-    cfg = LlamaConfig(vocab=32768, dim=2048, n_layers=2, n_heads=16,
-                      n_kv_heads=8, mlp_dim=4096, max_seq=2048)
-    mesh = build_mesh(MeshSpec(fsdp=2, tp=2), topo.devices)
+    mesh = build_mesh(MeshSpec(fsdp=2, tp=2) if chips == 4 else MeshSpec(),
+                      topo.devices[:chips])
     optimizer = optax.adamw(3e-4)
     axes = param_logical_axes(cfg)
     _init, step_fn, _place = make_train_step(
@@ -439,11 +437,100 @@ def test_sharded_train_step_keeps_flash_kernels(topo):
             opt_state_shardings(optimizer, params, param_sh, mesh)))
     batch = {"tokens": _sds((4, 2048), jnp.int32,
                             _batch_sharding(mesh, DEFAULT_RULES))}
-    compiled = step_fn.lower(state, batch).compile()
+    return step_fn.lower(state, batch).compile(), state
+
+
+# 1b's head layout (16/8 heads of 128) at a fraction of its depth
+SHARDED_WIDTHS = dict(vocab=32768, dim=2048, n_layers=2, n_heads=16,
+                      n_kv_heads=8, mlp_dim=4096, max_seq=2048)
+
+
+def test_sharded_train_step_keeps_flash_kernels(topo):
+    """fsdp=2 x tp=2 over the four described chips: GSPMD cannot partition
+    a Mosaic kernel, so the model runs attention under shard_map there.
+    The step must compile, keep the kernels, and spread its state."""
+    from ray_tpu.models import LlamaConfig
+
+    compiled, state = _sharded_step(topo, LlamaConfig(**SHARDED_WIDTHS))
     assert compiled.as_text().count("tpu_custom_call") >= 3
     whole = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
     per_device = compiled.memory_analysis().argument_size_in_bytes
     assert per_device < 0.3 * whole, (per_device, whole)
+
+
+@pytest.mark.parametrize("asked, again, kernels", [
+    ({}, 0, 3), ({"remat_policy": "full"}, 1, 4)], ids=["default", "full"])
+def test_sharded_backward_pass_keeps_what_attention_gave(
+        topo, asked, again, kernels):
+    """What a layer's checkpoint keeps, read from the compiled step:
+    by default (``"attn"``) the backward body holds no all-reduce of the
+    recomputed output product (``rematted_computation/bshk,hkd->bsd``:
+    the product's RESULT is kept, after its all-reduce over ``tp``) and
+    the program three flash kernels (the forward's output and LSE are
+    kept, so the forward kernel is not run again); ``"full"`` holds that
+    all-reduce and the fourth kernel."""
+    from ray_tpu.models import LlamaConfig
+
+    text = _sharded_step(
+        topo, LlamaConfig(**SHARDED_WIDTHS, **asked))[0].as_text()
+    reduced = [line for line in text.splitlines()
+               if re.search(r"= \S+ all-reduce(-start)?\(", line)
+               and "rematted_computation/bshk,hkd->bsd" in line]
+    assert len(reduced) == again, reduced
+    assert ("rematted_computation/bshk,hkd->bsd" in text) == bool(again)
+    assert text.count("tpu_custom_call") == kernels
+
+
+# Mistral-7B-v0.3's widths at the depth the four-chip train cell runs
+# (benchmarks/configs/mistral-7b-v0.3-train-24l.json), 4 x 2,048 tokens
+TRAIN_CELL_WIDTHS = dict(vocab=32768, dim=4096, n_layers=24, n_heads=32,
+                         n_kv_heads=8, mlp_dim=14336, max_seq=32768,
+                         rope_theta=1e6, norm_eps=1e-5)
+
+
+def test_train_cell_step_fits_with_what_its_layers_keep(topo):
+    """The four-chip train cell's step with the default saves, by the two
+    accounts there are. ``memory_analysis()`` counts a stacked residual of
+    the layer scan twice (the layer's input too, in every policy) and
+    still reads under the 15.75 GiB a v5e leaves a program. The compiler's
+    own buffer assignment, which is what refuses a program that does not
+    fit, holds each stack once: it takes the same step FOUR LAYERS DEEPER
+    (2.0 GB more state and stacks a device), and refuses it six layers
+    deeper, which shows the refusal is live in a compile for a described
+    chip."""
+    from ray_tpu.models import LlamaConfig
+
+    cfg = LlamaConfig(**TRAIN_CELL_WIDTHS)
+    compiled = _sharded_step(topo, cfg)[0]
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert live < 15.75 * 1024**3 - 0.5e9, live
+    assert compiled.as_text().count("tpu_custom_call") == 3
+
+    deeper = _sharded_step(topo, dataclasses.replace(cfg, n_layers=28))[0]
+    grown = deeper.memory_analysis().argument_size_in_bytes
+    assert grown - mem.argument_size_in_bytes > 1.3e9
+    with pytest.raises(Exception, match="Ran out of memory in memory "
+                                        "space hbm"):
+        _sharded_step(topo, dataclasses.replace(cfg, n_layers=30))
+
+
+def test_one_chip_1b_step_fits_with_the_input_kept_alone(topo, monkeypatch):
+    """Who stands at the memory limit asks for ``remat_policy="full"``:
+    chip_smoke.py's one-chip step (``LLAMA_CONFIGS["1b"]``, 4 x 2,048,
+    adamw) is taken by the compiler with a layer's input kept alone
+    (15.28 of 15.75 GiB; with the default saves it is refused by 0.3)."""
+    import sys
+
+    from ray_tpu.models import LLAMA_CONFIGS
+
+    # the one-device path asks the default backend, the CPU here
+    monkeypatch.setattr(sys.modules["ray_tpu.ops.attention"], "_on_tpu",
+                        lambda x: True)
+    full = _sharded_step(topo, dataclasses.replace(
+        LLAMA_CONFIGS["1b"], remat_policy="full"), chips=1)[0]
+    assert full.as_text().count("tpu_custom_call") == 4
 
 
 # experts, hidden width, expert width, experts a token; a decode step's
