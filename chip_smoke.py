@@ -64,8 +64,9 @@ MARGIN_LIMIT = 0.15
 # the largest config one 16 GB chip trains with adamw state (batch 8 needs 21 GB)
 TRAIN = dict(model="1b", batch=4, seq=2048, steps=4, kernel_parity=True)
 # ... and it stands at the chip's limit: with a layer's input kept alone
-# the compiler counts 15.28 of 15.75 GiB, with ``LlamaConfig``'s default
-# saves 16.04 and refuses the step. The fsdp x tp mesh keeps the default.
+# the compiler counts 15.28 of 15.75 GiB, with the saves of ``"attn"``
+# 16.04 (``LlamaConfig``'s default keeps more) and refuses the step. The
+# fsdp x tp mesh keeps the default (5.3 of 15.75 GiB).
 ONE_CHIP_REMAT = "full"
 
 # --- four chips (--chips 4) ---------------------------------------------------

@@ -6,11 +6,15 @@ Design choices for the TPU/XLA compilation model:
   * **remat per layer** (``jax.checkpoint``) — trades FLOPs for HBM,
     standard recipe for long-sequence training. What a layer's backward
     pass keeps instead of recomputing is ``LlamaConfig.remat_policy``
-    (``REMAT_POLICIES``); the default, ``"attn"``, keeps the layer's
-    input, the flash forward's output and LSE and the attention output
-    product's result, so that kernel, that product and its ``tp``
-    all-reduce run once a step, not twice; ``"full"`` keeps the input
-    alone.
+    (``REMAT_POLICIES``, ordered by what they cost a device); the
+    default, ``"attn_up"``, keeps the layer's input, the flash forward's
+    output and LSE, the attention output product's result and the up
+    product's result, so that kernel, those two products and the
+    attention output's ``tp`` all-reduce run once a step, not twice;
+    the backward body still runs the norms, q, k and v with their
+    rotary embedding and gate again. ``"attn"`` keeps no up product
+    (1.3 GiB less a device at the four-chip train cell's shape),
+    ``"full"`` the input alone.
   * **logical axis names** on every param; the rules table
     (ray_tpu.parallel.sharding) maps them onto the dp/fsdp/tp/sp mesh, so
     FSDP/TP/SP layouts need no model edits (GSPMD inserts collectives).
@@ -55,13 +59,44 @@ LAYER_KINDS = ("full", "full_nope", "window", "window_nope", "linear",
 # ``bshk,hkd->bsd`` (under a ``tp`` mesh axis: after the all-reduce)
 ATTN_OUT = "attn_out"
 
+# the name ``_mlp``'s dense branch gives the result of its up product
+# ``bsd,dm->bsm`` with ``w_up`` (the expert branch has no such value)
+MLP_UP = "mlp_up"
+
 # what ``jax.checkpoint`` of a layer keeps for the backward pass beside
-# the layer's input (``LlamaConfig.remat_policy``); None keeps nothing
+# the layer's input (``LlamaConfig.remat_policy``), dearest first. What
+# an entry costs is its stacks, a value a layer; in GiB a device at the
+# four-chip train cell's shape (Mistral-7B's widths, 24 layers, batch 2 x
+# 2,048 a device, fsdp=2 x tp=2; the compiler's buffer assignment, of
+# 15.75 GiB: PERF.md section 5):
+#   "dots"     every product's result; the backward pass recomputes the
+#              elementwise work and the flash forward. 15.72 GiB.
+#   "attn_up"  the default: what "attn" keeps and the up product's result
+#              (batch x seq x mlp_dim values: 1.30 GiB). The SwiGLU's
+#              backward needs gate and up: gate is the one product of the
+#              feed-forward that is run again. 14.48 GiB. A layer of
+#              experts (``n_experts``) has no such value to name and
+#              keeps what "attn" keeps.
+#   "attn"     the flash forward's output and LSE (the blockwise path has
+#              none to keep) and the attention output product's result
+#              after its ``tp`` all-reduce, 1.5 x batch x seq x dim
+#              values: neither the kernel nor that product nor its
+#              all-reduce is run again; the norms, q/k/v, the rotary
+#              embedding, gate and up are. 13.17 GiB.
+#   "full"     nothing (None): everything is run again, about a third
+#              more FLOPs, for whoever stands at the memory limit.
+#              12.04 GiB.
+# q, k and v are not named: kept after the rotary embedding they were
+# worth +0.1% of the cell's tokens a second for 0.33 GiB (the backward
+# body then waits for a 58.7 MB weight gather that the recomputation
+# hides), and q and k without v lost 0.7% (PERF.md section 6, PR 54).
 REMAT_POLICIES = {
+    "dots": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    "attn_up": jax.checkpoint_policies.save_only_these_names(
+        ATTN_OUT, FLASH_OUT, FLASH_LSE, MLP_UP),
     "attn": jax.checkpoint_policies.save_only_these_names(
         ATTN_OUT, FLASH_OUT, FLASH_LSE),
     "full": None,
-    "dots": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
 }
 
 
@@ -110,17 +145,13 @@ class LlamaConfig:
     # before the split into heads and the rotary embedding (OLMoE)
     qk_norm: bool = False
     # what a layer's backward pass keeps beside the layer's input, where
-    # ``remat`` (``REMAT_POLICIES``). "attn", the default: the flash
-    # forward's output and LSE (the blockwise path has none to keep) and
-    # the attention output product's result, 1.5 x batch x seq x dim
-    # values a layer: the backward pass repeats neither the kernel nor
-    # that product nor its ``tp`` all-reduce, and recomputes the rest
-    # (norms, q/k/v, rotary, gate and up). "full": nothing, recompute
-    # everything (least HBM, ~1/3 extra FLOPs): for whoever stands at
-    # the memory limit. "dots": every matmul output, recompute
-    # elementwise only — the right trade when HBM fits it (ref: jax
-    # checkpoint_policies)
-    remat_policy: str = "attn"
+    # ``remat`` (``REMAT_POLICIES``: what each entry keeps and what it
+    # costs a device). One value, chosen by the room a device has; the
+    # default keeps the most that the four-chip train cell's step has
+    # room for (14.48 of the 15.75 GiB a v5e leaves a program, by the
+    # compiler's buffer assignment: size a step by that count and not
+    # by ``memory_analysis()``, which reads 18.4 GB there)
+    remat_policy: str = "attn_up"
     # a head's width where it is not dim // n_heads (SmallThinker: 28
     # heads of 128 on a hidden size of 2560). None: dim // n_heads
     head_size: Optional[int] = None
@@ -860,7 +891,7 @@ def _mlp(x, lp, cfg: LlamaConfig, csl):
         return out, aux
     # SwiGLU; gate/up fuse into one pass over x in XLA.
     g = jnp.einsum("bsd,dm->bsm", x, lp["w_gate"])
-    u = jnp.einsum("bsd,dm->bsm", x, lp["w_up"])
+    u = checkpoint_name(jnp.einsum("bsd,dm->bsm", x, lp["w_up"]), MLP_UP)
     out = jnp.einsum("bsm,md->bsd", jax.nn.silu(g) * u, lp["w_down"])
     return out, jnp.zeros((), jnp.float32)
 

@@ -1,6 +1,7 @@
 """Llama model tests on the CPU mesh (SURVEY §4.4 device-count-free path)."""
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -114,20 +115,36 @@ def test_remat_policy_keeps_the_loss_and_every_gradient(cpu_mesh8, policy):
         np.asarray(a), np.asarray(b)), grads, grads_full)
 
 
+def _gradient_products(params, name, policy=None) -> int:
+    """The products named ``name`` in the gradient's jaxpr where a layer's
+    checkpoint keeps ``policy`` (None: ``LlamaConfig``'s default)."""
+    cfg = dataclasses.replace(
+        CFG, remat=True, **({"remat_policy": policy} if policy else {}))
+    batch = {"tokens": jnp.zeros((2, 32), jnp.int32)}
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: lm_loss(p, batch, cfg)))(params).jaxpr
+    return _named_products(jaxpr, name)
+
+
 def test_default_remat_policy_runs_the_output_product_once(params):
     """``remat=True`` by default keeps the attention output product's
     result: the gradient's jaxpr holds that product in the forward scan
     and its two transposes, and ``"full"`` a fourth, the recomputation."""
-    kept = dataclasses.replace(CFG, remat=True)
-    assert kept.remat_policy == "attn"
-    batch = {"tokens": jnp.zeros((2, 32), jnp.int32)}
-
-    def products(cfg):
-        jaxpr = jax.make_jaxpr(jax.grad(
-            lambda p: lm_loss(p, batch, cfg)))(params).jaxpr
-        return _named_products(jaxpr, "bshk,hkd->bsd")
-
-    assert products(kept) == 3
-    assert products(dataclasses.replace(kept, remat_policy="full")) == 4
+    products = partial(_gradient_products, params, "bshk,hkd->bsd")
+    assert products() == products("attn") == 3
+    assert products("full") == 4
     with pytest.raises(ValueError, match="remat_policy"):
-        dataclasses.replace(kept, remat_policy="nothing")
+        dataclasses.replace(CFG, remat_policy="nothing")
+
+
+def test_default_remat_policy_runs_the_up_product_once(params):
+    """The default, ``"attn_up"``, keeps the up product's result beside
+    what ``"attn"`` keeps. Gate and up are each a ``bsd,dm->bsm`` in the
+    forward scan, a recomputation and two transposes, eight products
+    with ``"attn"``; the default's backward body recomputes gate alone.
+    q, k and v (``bsd,dhk->bshk``, twelve) are recomputed by both."""
+    assert CFG.remat_policy == "attn_up"
+    wide = partial(_gradient_products, params, "bsd,dm->bsm")
+    assert (wide("full"), wide("attn"), wide()) == (8, 8, 7)
+    heads = partial(_gradient_products, params, "bsd,dhk->bshk")
+    assert (heads("full"), heads("attn"), heads()) == (12, 12, 12)
