@@ -182,6 +182,21 @@ def burst_gather(tables: np.ndarray, page_size: int, bucket: int,
 
 
 
+def burst_rows(tables: np.ndarray, page_size: int, width: int, held):
+    """``runner.decode_burst``'s ``gather`` of a layer group whose burst
+    gathers a row a slot (``kinds.ROWS``): (first int32 [B], pages int32
+    [B, width]): a decoding slot's pages that hold old context and the
+    position its first one begins at; page 0 elsewhere. ``tables`` and
+    ``held`` as ``burst_gather``'s."""
+    first = np.zeros(tables.shape[0], np.int32)
+    pages = np.zeros((tables.shape[0], width), np.int32)
+    for slot, n, *at in held:
+        at = at[0] if at else 0
+        first[slot] = at * page_size
+        pages[slot, :n] = tables[slot, at:at + n]
+    return first, pages
+
+
 class LLMEngine:
     def __init__(self, params, cfg: LlamaConfig,
                  engine_config: Optional[EngineConfig] = None):
@@ -647,10 +662,10 @@ class LLMEngine:
             state.admit_t = time.perf_counter()
         self.slots[slot] = state
         if self.cache.s is not None:
-            # the slot's state of every linear layer starts from zero
+            # the slot's state of every state layer starts from zero
             # (a resumed request too: it prefills again)
             self.cache.s = zero_slot_state(self.cache.s, jnp.int32(slot))
-            self._counters["state_slots_reset"] += 1
+            self._counters[self.kind.SLOT_RESET] += 1
         self.seq_table.assign(slot, pages)
         for allocator, table, first in list(zip(
                 self.allocators, self.seq_tables, firsts))[1:]:
@@ -767,11 +782,22 @@ class LLMEngine:
         return page_bucket(pages, self.seq_table.block_tables.shape[1],
                            self._LATENT_SPAN_PAGES)
 
+    def _own_pages(self, g: int = 0):
+        """How a burst reads group ``g``'s pages (the kind's
+        ``OWN_PAGES``: one fact, or one a layer group): True, where they
+        lie through each slot's table; False, one flat list;
+        ``kinds.ROWS``, a row a slot."""
+        own = self.kind.OWN_PAGES
+        return own[g] if isinstance(own, tuple) else own
+
     def _ladder(self, g: int):
         """Every bucket a burst's list of group ``g`` can take (a burst
-        that reads each slot's own pages: its table span)."""
+        that reads each slot's own pages: its table span; one that
+        gathers a row a slot: the most pages a slot's row holds)."""
         top, lowest = self._listable_pages(g), self.kind.LOWEST_BUCKET
-        if self.kind.OWN_PAGES:
+        if self._own_pages(g) == kinds.ROWS:
+            return [self._slot_pages(g)[1]]
+        if self._own_pages(g):
             top = self.seq_table.block_tables.shape[1]
         buckets = [min(lowest, top)]
         while buckets[-1] < top:
@@ -787,7 +813,11 @@ class LLMEngine:
         window is full, then the window's, so the groups' lists grow
         together and part only for long slots: with ``F`` pages listed
         in the full group, a window group lists at least what the
-        fewest, longest slots would and at most min(F, all windows)."""
+        fewest, longest slots would and at most min(F, all windows).
+        Where the full group is read through the slots' own tables its
+        bucket is the longest slot's span ``n``: a window group lists at
+        least what that slot alone would and at most every slot's
+        min(n, window)."""
         if len(self.windows) == 1:
             return self._ladder(0)
         top = self.seq_table.block_tables.shape[1]
@@ -797,15 +827,33 @@ class LLMEngine:
             choices = []
             for g in range(1, len(self.windows)):
                 least, most = self._slot_pages(g)
-                fewest = (lowest // top) * least + min(lowest % top, least)
+                if self._own_pages(g) == kinds.ROWS:
+                    choices.append(self._ladder(g))
+                    continue
+                if self._own_pages():
+                    fewest, listed = min(lowest, least), B * min(bucket, most)
+                else:
+                    fewest = (lowest // top) * least + min(lowest % top,
+                                                           least)
+                    listed = min(bucket, B * most)
                 choices.append([b for b in self._ladder(g) if
                                 self._flat_bucket(fewest, g) <= b
-                                <= self._flat_bucket(
-                                    min(bucket, B * most), g)])
+                                <= self._flat_bucket(listed, g)])
             shapes.extend((bucket, *rest)
                           for rest in itertools.product(*choices))
             lowest = bucket + 1
         return shapes
+
+    def _no_pages(self, g: int, table, bucket: int):
+        """Group ``g``'s list of a burst in which no slot decodes."""
+        B = self.ecfg.max_num_seqs
+        if self._own_pages(g) == kinds.ROWS:
+            return tuple(map(jnp.asarray, burst_rows(
+                table.block_tables, self.ecfg.page_size, bucket, ())))
+        if self._own_pages(g):
+            return jnp.zeros((B, bucket), jnp.int32)
+        return jnp.asarray(burst_gather(
+            table.block_tables, self.ecfg.page_size, bucket, ()))
 
     def load_decode_programs(self) -> int:
         """Run ``decode_burst`` once in every shape a plain greedy round
@@ -828,14 +876,10 @@ class LLMEngine:
         buckets = self.decode_buckets()
         with tracing.span("rt.engine.load") as sp:
             for shape in buckets:
-                if self.kind.OWN_PAGES:
-                    lists = (jnp.zeros((B, shape), jnp.int32),)
-                else:
-                    lists = tuple(jnp.asarray(burst_gather(
-                        t.block_tables, self.ecfg.page_size, bucket, ()))
-                        for t, bucket in zip(self.seq_tables, (
-                            shape if isinstance(shape, tuple)
-                            else (shape,))))
+                lists = tuple(
+                    self._no_pages(g, t, bucket)
+                    for g, (t, bucket) in enumerate(zip(self.seq_tables, (
+                        shape if isinstance(shape, tuple) else (shape,)))))
                 self._run(
                     decode_burst, zi, zi,
                     self._tables(), jnp.zeros(B, bool), self.cos, self.sin,
@@ -1164,18 +1208,15 @@ class LLMEngine:
             # a group's list: the pages that hold old context its layers
             # can still see
             page, lists, shape = self.ecfg.page_size, [], []
-            if self.kind.OWN_PAGES:
-                # nothing is copied: the burst reads each slot's own
-                # pages through its table, cut to the longest's bucket
-                pages = [-(-s.ctx_len // page) for s in active_states]
-                bucket = self._latent_span(max(pages))
-                lists.append(self._bt(bucket))
-                shape.append(bucket)
-                for c in (counters, counters["groups"][self.group_names[0]]):
-                    c["live_pages"] += sum(pages)
-                    c["gathered_pages"] += sum(pages)
-            else:
-                for g, window in enumerate(self.windows):
+            for g, window in enumerate(self.windows):
+                if self._own_pages(g) is True:
+                    # nothing is copied: the burst reads each slot's own
+                    # pages through its table, cut to the longest's bucket
+                    pages = [-(-s.ctx_len // page) for s in active_states]
+                    bucket = self._latent_span(max(pages))
+                    lists.append(self._bt(bucket))
+                    live = gathered = sum(pages)
+                else:
                     held = []
                     for s in active_states:
                         first = 0 if window is None else \
@@ -1183,15 +1224,21 @@ class LLMEngine:
                         held.append((s.slot, -(-s.ctx_len // page) - first,
                                      first))
                     live = sum(n for _slot, n, _first in held)
-                    bucket = self._flat_bucket(live, g)
-                    lists.append(jnp.asarray(burst_gather(
-                        self.seq_tables[g].block_tables, page, bucket,
-                        held)))
-                    shape.append(bucket)
-                    for c in (counters,
-                              counters["groups"][self.group_names[g]]):
-                        c["live_pages"] += live
-                        c["gathered_pages"] += bucket
+                    tables = self.seq_tables[g].block_tables
+                    if self._own_pages(g) == kinds.ROWS:
+                        # a row a slot, every slot's copied
+                        bucket, = self._ladder(g)
+                        gathered = bucket * tables.shape[0]
+                        lists.append(tuple(map(jnp.asarray, burst_rows(
+                            tables, page, bucket, held))))
+                    else:
+                        bucket = gathered = self._flat_bucket(live, g)
+                        lists.append(jnp.asarray(burst_gather(
+                            tables, page, bucket, held)))
+                shape.append(bucket)
+                for c in (counters, counters["groups"][self.group_names[g]]):
+                    c["live_pages"] += live
+                    c["gathered_pages"] += gathered
             hist = counters["gather_hist"]
             shape = shape[0] if len(shape) == 1 else "/".join(
                 map(str, shape))
@@ -1572,10 +1619,10 @@ class LLMEngine:
                 time.perf_counter())
         self.slots[slot] = state
         if self.cache.s is not None:
-            # the slot's state of every linear layer starts from zero
+            # the slot's state of every state layer starts from zero
             # (a resumed request too: it prefills again)
             self.cache.s = zero_slot_state(self.cache.s, jnp.int32(slot))
-            self._counters["state_slots_reset"] += 1
+            self._counters[self.kind.SLOT_RESET] += 1
         self.seq_table.assign(slot, pages)
         if self.prefix_cache is not None:
             # shipped pages double as prefix-cache warmth: register the

@@ -9,6 +9,9 @@ format themselves: the next cache shape is one file here, its fields in
   latent   ONE compressed row a token and no V (DeepSeek-V2)
   indexed  K, V and an indexer's key; a choice of keys (Keye-VL-2.0)
   state    a state a slot beside block-chosen pages (MiniCPM-SALA)
+  scan     a selective scan's state and tail a slot beside a window
+           group and ONE full layer's pages that eight layers read
+           (Phi-4-mini-flash-reasoning)
 
 What a kind gives, in the order a new one is written; plain Python called
 while a program is traced, no jit boundary of its own:
@@ -33,9 +36,16 @@ while a program is traced, no jit boundary of its own:
   its writer: ``paged._write_rows`` (THE scatter), ``latent.
       _write_slices`` and ``_write_latent_pages`` (loops of slices), or
       one of its own.
-  the host's facts: ``OWN_PAGES`` (a burst reads each slot's own pages
-      through its table and copies none), ``LOWEST_BUCKET`` (of a burst's
-      lists or table spans, in pages), ``COUNTERS`` and ``count(cfg,
+  the host's facts: ``OWN_PAGES`` (True: a burst reads each slot's own
+      pages through its table and copies none; False: ONE flat list of
+      the live pages, copied once a burst; a tuple where the layer
+      groups differ, one a group, and there a window group's may be
+      ``ROWS``: a row a slot of the pages inside its window, copied once
+      a burst, a slot scoring its own row alone), ``LOWEST_BUCKET`` (of a
+      burst's
+      lists or table spans, in pages), ``SLOT_RESET`` (a kind with a
+      state a slot: the counter an admission's zeroing moves),
+      ``COUNTERS`` and ``count(cfg,
       counters, page_size, start, end, decode)`` (how the queries at
       positions [start, end) of one sequence move them),
       ``attention_paths(cfg, prefill, on_tpu)``, and ``refuses(cfg)`` ->
@@ -47,6 +57,9 @@ while a program is traced, no jit boundary of its own:
 from __future__ import annotations
 
 from typing import Any, Callable, NamedTuple
+
+
+ROWS = "rows"
 
 
 class Burst(NamedTuple):
@@ -62,13 +75,15 @@ class Burst(NamedTuple):
     write: Callable
 
 
-from . import indexed, latent, paged, state  # noqa: E402  (they name Burst)
+from . import indexed, latent, paged, scan, state  # noqa: E402  (they name Burst)
 
 
 def of(cfg):
     """The configuration's kind: a pure function of ``LlamaConfig``."""
     if cfg.own_weights:
         return state
+    if cfg.scan_state:
+        return scan
     if cfg.latent:
         return latent
     return indexed if cfg.sparse_top_k else paged

@@ -152,8 +152,13 @@ def heads(h, lp, lr, state, *, cfg, kind, attend, **how):
     return (runner._gated(o, h, lp) if cfg.attn_output_gate else o), kept
 
 
-def prefill(cfg, cache, block_tables, prompt_lens, slots, pos_grid, valid):
+def prefill(cfg, cache, block_tables, prompt_lens, slots, pos_grid, valid,
+            scale=None, windows=None):
+    """``windows``: the windows of the groups given here, where they are
+    not all of ``cfg.kv_groups`` (a kind that keeps a group its own way
+    hands the others); ``prefill_chunk`` and ``decode_burst`` likewise."""
     pools, tables = _pools(cache), _groups(block_tables)
+    windows = windows or cfg.kv_groups
     S = pos_grid.shape[1]
     page_size = pools[0][0].shape[2]     # read by the window groups alone
     # a window layer hands out the rows that can still be inside the
@@ -161,7 +166,7 @@ def prefill(cfg, cache, block_tables, prompt_lens, slots, pos_grid, valid):
     # them, from ``kept_from`` [B] on (the engine holds pages from the
     # one that position prompt_len - window + 1 lies on)
     kept_rows = {w: min(S, -(-w // page_size) * page_size + page_size)
-                 for w in cfg.kv_groups if w is not None}
+                 for w in windows if w is not None}
     kept_from = {w: jnp.clip(
         jnp.maximum(prompt_lens - w + 1, 0) // page_size * page_size,
         0, S - n) for w, n in kept_rows.items()}
@@ -172,7 +177,7 @@ def prefill(cfg, cache, block_tables, prompt_lens, slots, pos_grid, valid):
         # prompt ends, costs the kernel only the rest of the prompt's
         # last block: the blocks behind it come back as zeros
         o = attention(q, k, v, causal=True, window=window,
-                      lengths=prompt_lens)
+                      lengths=prompt_lens, scale=scale)
         if window is not None and kept_rows[window] < S:
             k, v = (jax.vmap(lambda rows, at: jax.lax.dynamic_slice_in_dim(
                 rows, at, kept_rows[window], 0))(rows, kept_from[window])
@@ -181,8 +186,7 @@ def prefill(cfg, cache, block_tables, prompt_lens, slots, pos_grid, valid):
 
     def write(rows):
         written = []
-        for window, pool, table, kept in zip(cfg.kv_groups, pools, tables,
-                                             rows):
+        for window, pool, table, kept in zip(windows, pools, tables, rows):
             at, ok = pos_grid, valid
             if window is not None:
                 at = kept_from[window][:, None] + jnp.arange(
@@ -199,13 +203,14 @@ def _span(cache, block_tables) -> int:
     return _groups(block_tables)[0].shape[1] * _groups(cache.k)[0].shape[2]
 
 
-def _over_pages(cfg, cache, block_tables, at, qpos, valid, seen, own=None):
+def _over_pages(cfg, cache, block_tables, at, qpos, valid, seen, own=None,
+                scale=None, windows=None):
     """``prefill_chunk``'s and ``verify_step``'s half: write the rows (at
     ``at``) into the layer's pages, gather the table's span, and attend
     over (the span under ``seen``; with ``own``, the rows themselves
     under it), a window layer's queries (at ``qpos``) inside the window."""
     pools = _pools(cache)
-    tables = dict(zip(cfg.kv_groups, _groups(block_tables)))
+    tables = dict(zip(windows or cfg.kv_groups, _groups(block_tables)))
     page_size, S = pools[0][0].shape[2], qpos.shape[1]
     span = _span(cache, block_tables)
 
@@ -221,16 +226,17 @@ def _over_pages(cfg, cache, block_tables, at, qpos, valid, seen, own=None):
         pools = _write_rows(pools, (k, v), table, at, rows)
         pk, pv = (runner._take_span(pool, table) for pool in pools)
         return runner._attend(q, (pk, pv, past), *(
-            () if own is None else ((k, v, mine),))), pools
+            () if own is None else ((k, v, mine),)), scale=scale), pools
 
     return pools, attend, lambda pools: _ungrouped(pools, block_tables)
 
 
 def prefill_chunk(cfg, cache, block_tables, start_pos, chunk_len, slots,
-                  pos_grid, valid):
+                  pos_grid, valid, scale=None, windows=None):
     return _over_pages(cfg, cache, block_tables, pos_grid, pos_grid, valid,
                        *runner._chunk_masks(_span(cache, block_tables),
-                                            start_pos, valid))
+                                            start_pos, valid), scale=scale,
+                       windows=windows)
 
 
 def verify_step(cfg, cache, block_tables, positions, qpos, valid):
@@ -243,7 +249,7 @@ def verify_step(cfg, cache, block_tables, positions, qpos, valid):
 
 
 def decode_burst(cfg, cache, block_tables, gather, positions, active,
-                 K: int) -> Burst:
+                 K: int, scale=None, windows=None) -> Burst:
     """``gather``: int32 [3, T] a group, ONE flat list of the LIVE pages,
     those that hold old context of decoding slots: each one's (page,
     owner slot, first position); a page two slots share is listed once
@@ -254,7 +260,12 @@ def decode_burst(cfg, cache, block_tables, gather, positions, active,
     its row: the worst case. A window group's list holds only the pages
     still inside the window. Either way a slot's keys are those it owns
     at positions below its own: one softmax over them and the burst's
-    rows."""
+    rows. A pair (first int32 [B], pages int32 [B, n]) in a group's
+    place: a row a slot, the slot's pages that hold old context its
+    layers still see and the first of their positions (unused entries
+    page 0); a slot scores its own row alone (``kinds.ROWS``: a window
+    group's row is short, and a list of all of them costs slots x more
+    scores than it holds)."""
     B = positions.shape[0]
     pools, tables = _pools(cache), _groups(block_tables)
     gathers = (None,) * len(pools) if gather is None else _groups(gather)
@@ -264,11 +275,12 @@ def decode_burst(cfg, cache, block_tables, gather, positions, active,
     # [L, kvh, T * page, hd] for one flat list; the burst's own rows are
     # [L, B, K, kvh, hd]; all of it a layer group
     old, old_mask, key_pos = [], {}, {}
-    for window, pool, table, listed in zip(cfg.kv_groups, pools, tables,
-                                           gathers):
-        if listed is None:
-            pages = table
-            at = jnp.arange(pages.shape[1] * page_size)[None, :]
+    for window, pool, table, listed in zip(windows or cfg.kv_groups, pools,
+                                           tables, gathers):
+        if listed is None or isinstance(listed, tuple):
+            first, pages = (0, table) if listed is None else (
+                listed[0][:, None], listed[1])
+            at = first + jnp.arange(pages.shape[1] * page_size)[None, :]
             mask = at < positions[:, None]                     # [B, S]
         else:
             pages, owner, first = listed
@@ -296,7 +308,8 @@ def decode_burst(cfg, cache, block_tables, gather, positions, active,
                                > (positions + i - window)[:, None])
                 own = own & (i - jnp.arange(K)[None, :] < window)
             # one query a slot: attend without the length-1 axis
-            o = runner._attend(q[:, 0], (ok, ov, seen), (nk, nv, own))
+            o = runner._attend(q[:, 0], (ok, ov, seen), (nk, nv, own),
+                               scale=scale)
             return o[:, None], (nk, nv)
 
         return attend, lambda: None

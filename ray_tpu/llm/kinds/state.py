@@ -50,7 +50,8 @@ OWN_PAGES = True
 # blocks a query could see and scored and the blocks it attended over (a
 # block layer and KV head); the pages of K (and as many of V) the bursts'
 # steps read (a block layer and KV head)
-COUNTERS = ("state_slots_reset", "state_bytes_step", "dense_queries",
+SLOT_RESET = "state_slots_reset"
+COUNTERS = (SLOT_RESET, "state_bytes_step", "dense_queries",
             "scored_blocks", "chosen_blocks", "block_decode_pages")
 
 

@@ -69,7 +69,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.llama import LlamaConfig, linear, qk_norm, rotated
-from ..ops import apply_rotary, rms_norm
+from ..ops import apply_rotary, layer_norm, rms_norm
 from ..ops.moe import router_logits
 from ..ops.quant import embed_lookup, is_quantized, weight_einsum
 from . import kinds
@@ -151,14 +151,27 @@ def _head(x, params, cfg: LlamaConfig):
     """Hidden [..., d] -> final norm -> f32 logits [..., vocab], raw or
     int8 lm_head. bf16 operands on the MXU with f32 accumulation either
     way."""
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    x = _norm(x, params, "final_norm", cfg)
     if cfg.logit_divisor != 1.0:
         x = (x.astype(jnp.float32) / cfg.logit_divisor).astype(x.dtype)
+    if "lm_head" not in params:
+        # a tied head: the table's rows are the head's columns
+        return weight_einsum("...d,vd->...v", x.astype(cfg.dtype),
+                             params["embed"],
+                             preferred_element_type=jnp.float32)
     lm = params["lm_head"]
     if not is_quantized(lm):
         lm = lm.astype(cfg.dtype)
     return weight_einsum("...d,dv->...v", x.astype(cfg.dtype), lm,
                          preferred_element_type=jnp.float32)
+
+
+def _norm(x, lp, name: str, cfg: LlamaConfig):
+    """The norm ``name`` of ``lp``: RMSNorm, or where ``lp`` has a bias
+    beside the weight (``name`` + "_bias") LayerNorm with it."""
+    if name + "_bias" in lp:
+        return layer_norm(x, lp[name], lp[name + "_bias"], cfg.norm_eps)
+    return rms_norm(x, lp[name], cfg.norm_eps)
 
 
 def _pick(logits, greedy, seed, temperature, top_k, top_p):
@@ -216,9 +229,10 @@ def _gather_span(pool, pages):
         *lead, pages.shape[0], pages.shape[1] * page, kvh, hd)
 
 
-def _attend(q, *segments):
+def _attend(q, *segments, scale=None):
     """Grouped-query attention over keys that lie in segments (a cached
     span; rows the cache does not hold yet), one softmax over all.
+    ``scale``: the softmax's, where it is not ``hd ** -0.5``.
 
     q: [B, ..., heads, hd], with or without a query axis; a segment:
     (keys [B, S, kvh, hd], values, mask broadcastable to [B, ..., S]).
@@ -227,6 +241,7 @@ def _attend(q, *segments):
     their own dtype with f32 accumulation. Returns f32, in q's shape.
     """
     hd = q.shape[-1]
+    scale = hd ** -0.5 if scale is None else scale
     kvh = segments[0][0].shape[-2 if segments[0][0].ndim == 4 else 0]
     qg = q.reshape(*q.shape[:-2], kvh, q.shape[-2] // kvh, hd)
 
@@ -242,7 +257,7 @@ def _attend(q, *segments):
 
     s = jnp.concatenate([
         jnp.where(mask[..., None, None, :],
-                  product(qg, keys, "ds") * hd ** -0.5, -jnp.inf)
+                  product(qg, keys, "ds") * scale, -jnp.inf)
         for keys, _, mask in segments], axis=-1)
     p = jax.nn.softmax(s, axis=-1).astype(segments[0][0].dtype)
     outs, at = [], 0
@@ -293,7 +308,7 @@ def _chunk_masks(span: int, start_pos, valid):
 
 
 def _block(x, inputs, *, cfg: LlamaConfig, kind, cos, sin, positions, valid,
-           attend, experts, lora_scale):
+           attend, experts, lora_scale, last=None):
     """The decoder layer, once, as the body of a scan over layers.
 
     x: [B, S, d]; inputs: (the layer's weights, the layer's slice of the
@@ -303,18 +318,24 @@ def _block(x, inputs, *, cfg: LlamaConfig, kind, cos, sin, positions, valid,
     positions, None = 0..S-1; valid: [B, S], the rows that are tokens;
     ``attend``: the program's, from the configuration's cache kind, whose
     ``heads`` is the layer's attention half and calls it (``llm/kinds``).
-    ``experts`` None: the layer's feed-forward is dense.
+    ``experts`` None: the layer's feed-forward is dense. ``last`` int32
+    [B]: the layer's ``heads`` sees every row (what it keeps is every
+    row's) and answers for row ``last`` alone, and from there on the
+    stream is that row, [B, 1, d].
     Returns (x, (kept, expert counts: see ``_mlp``)).
     """
     lp, state, lr = inputs
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    h = _norm(x, lp, "attn_norm", cfg)
     # a router that reads the attention's input: its logits are known a
     # whole attention before the experts need them
     logits = router_logits(h, lp["router"]) if (
         experts is not None and cfg.router_input == "attention") else None
     o, kept = kinds.of(cfg).heads(
         h, lp, lr, state, cfg=cfg, kind=kind, cos=cos, sin=sin,
-        positions=positions, attend=attend, lora_scale=lora_scale)
+        positions=positions, attend=attend, lora_scale=lora_scale,
+        **({} if last is None else {"last": last}))
+    if last is not None:
+        x, valid = _rows_at(x, last), None
 
     def added(a):
         # what a layer's two halves add to the stream, times the scale
@@ -327,11 +348,94 @@ def _block(x, inputs, *, cfg: LlamaConfig, kind, cos, sin, positions, valid,
         # the half adds; a layer without the weight adds it as it is
         return rms_norm(a, lp[name], cfg.norm_eps) if name in lp else a
 
-    x = x + added(post(weight_einsum("bshk,hkd->bsd", o.astype(x.dtype),
-                                     lp["wo"]), "post_attn_norm"))
-    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+    out = weight_einsum("bshk,hkd->bsd", o.astype(x.dtype), lp["wo"])
+    if "bo" in lp:
+        out = out + lp["bo"]
+    x = x + added(post(out, "post_attn_norm"))
+    h = _norm(x, lp, "mlp_norm", cfg)
     m, counts = _mlp(h, lp, cfg, valid, experts, logits)
     return x + added(post(m, "post_mlp_norm")), (kept, counts)
+
+
+def _sampled(x, at):
+    """The row a prefill samples: x [B, S, d], at int32 [B, 1, 1] ->
+    [B, d]. A stream of ONE row is that row: a stack that needs its
+    upper layers for the sampled row alone hands back no other
+    (``_hybrid_layers``)."""
+    if x.shape[1] == 1:
+        return x[:, 0]
+    return jnp.take_along_axis(x, at, axis=1)[:, 0]
+
+
+def _rows_at(x, at):
+    """Row ``at[b]`` of every sequence: x [B, S, ...] -> [B, 1, ...]."""
+    return jnp.take_along_axis(
+        x, at.reshape(-1, 1, *(1,) * (x.ndim - 2)), axis=1)
+
+
+def _hybrid_layers(params, cfg: LlamaConfig, block, x, state, last=None):
+    """``_layers``' ``run`` for a decoder-hybrid-decoder stack
+    (``cfg.scan_state``; ``kinds/scan.py``): five kinds of layer and
+    THREE traced bodies, a ``lax.scan`` over the P periods of (scan,
+    window attention), the pair (scan, full attention) by itself, and a
+    ``lax.scan`` over the Q periods of (memory unit, cross-attention).
+    Two things pass from a layer to LATER layers within the step: the
+    last scan layer's output before its gate (``m``, which every memory
+    unit reads at its own row) and what the full layer's attention
+    leaves for the cross layers, which have no key of their own.
+
+    ``state``: (the full group's, the window group's, (what a scan layer
+    is handed by its place, what the scan layers carry from one to the
+    next)), any of them None; what ``run`` returns as kept has that
+    form. ``block``: ``_block`` with the program's arguments. ``last``:
+    from the full layer's attention on the stream is every sequence's
+    row ``last`` alone, and the layers below it leave what they keep for
+    every row."""
+    P, Q = cfg.hybrid_periods
+    attn = params["layers"]
+
+    def at(tree, i):
+        """Layer ``i`` of a stacked tree, taken where it is used: a scan
+        that sliced the first P of P + 1 layers would copy them."""
+        return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+            a, i, 0, keepdims=False), tree)
+
+    full, window, (handed, carried) = state or (None, None, (None, None))
+
+    def period(carry, xs):
+        x, carried = carry
+        win, i = xs
+        x, ((_, left, carried), _) = block(
+            x, (at(params["scan_layers"], i),
+                (i, None if handed is None else at(handed, i), carried), {}),
+            kind="scan")
+        x, (kept, _) = block(x, (at(attn, i), win, {}), kind="window_diff")
+        return (x, carried), (left, kept)
+
+    (x, carried), (left, kept_w) = jax.lax.scan(
+        period, (x, carried), (window, jnp.arange(P)))
+    x, ((m, left_p, carried), _) = block(
+        x, (at(params["scan_layers"], P),
+            (P, None if handed is None else at(handed, P), carried), {}),
+        kind="scan")
+    x, ((kept_f, shared), _) = block(
+        x, (at(attn, P), None if full is None else jax.tree.map(
+            lambda a: a[0], full), {}), kind="full_diff", last=last)
+    if last is not None:
+        m = _rows_at(m, last)
+
+    def cross_period(x, xs):
+        gp, cp = xs
+        x, _ = block(x, (gp, m, {}), kind="gmu")
+        x, _ = block(x, (cp, shared, {}), kind="cross_diff")
+        return x, None
+
+    x, _ = jax.lax.scan(cross_period, x, (params["gmu_layers"],
+                                          params["cross_layers"]))
+    if left is not None:
+        left = jnp.concatenate([left, left_p[None]], 0)
+    return x, (jax.tree.map(lambda a: a[None], kept_f), kept_w,
+               (left, carried)), None
 
 
 def _layers(params, cfg: LlamaConfig, cos, sin, lora=None):
@@ -371,6 +475,19 @@ def _layers(params, cfg: LlamaConfig, cos, sin, lora=None):
         scan over layers would hand its body, taken where it is used."""
         return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
             a, i, 0, keepdims=False), tree)
+
+    if cfg.scan_state:
+        if lora_xs:
+            raise ValueError("adapters are not supported with scan layers")
+
+        def run(x, state, attend, *, positions, valid):
+            # the kind's ``attend`` knows the row a prefill samples
+            return _hybrid_layers(params, cfg, partial(
+                _block, cfg=cfg, cos=cos, sin=sin, positions=positions,
+                valid=valid, attend=attend, experts=None, lora_scale=None),
+                x, state, attend("last"))
+
+        return run
 
     def run(x, state, attend, *, positions, valid):
         block = partial(_block, cfg=cfg, cos=cos, sin=sin,
@@ -487,8 +604,7 @@ def prefill(params, cache_k, cache_v, tokens, prompt_lens, block_tables,
     x, rows, counts = _layers(params, cfg, cos, sin, lora)(
         x, None, attend, positions=None, valid=valid)
     cache = write(rows)
-    x_last = jnp.take_along_axis(
-        x, jnp.maximum(prompt_lens - 1, 0)[:, None, None], axis=1)[:, 0]
+    x_last = _sampled(x, jnp.maximum(prompt_lens - 1, 0)[:, None, None])
     return (_head(x_last, params, cfg), cache.k, cache.v, counts,
             *_rest(cache))
 
@@ -550,8 +666,7 @@ def prefill_chunk(params, cache_k, cache_v, tokens, start_pos, chunk_len,
     cache = done(pools)
     idx = jnp.broadcast_to(jnp.maximum(chunk_len - 1, 0).reshape(1, 1, 1),
                            (B, 1, 1))
-    x_last = jnp.take_along_axis(x, idx, axis=1)[:, 0]
-    return (_head(x_last, params, cfg), cache.k, cache.v, counts,
+    return (_head(_sampled(x, idx), params, cfg), cache.k, cache.v, counts,
             *_rest(cache))
 
 

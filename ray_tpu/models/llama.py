@@ -53,6 +53,14 @@ from ..parallel.sharding import (DEFAULT_RULES, logical_sharding,
 # their own widths: a stack a kind (``init_params``)
 LAYER_KINDS = ("full", "full_nope", "window", "window_nope", "linear",
                "block_nope")
+# the kinds of a decoder-hybrid-decoder stack (``LlamaConfig.scan_state``;
+# models/sambay.py has their weights, llm/kinds/scan.py their cache):
+# "scan", a selective-scan layer whose memory is a state a slot;
+# "window_diff" and "full_diff", differential attention with pages of
+# its own; "gmu", a gated memory unit on the LAST scan layer's output;
+# "cross_diff", differential attention with a query of its own over the
+# pages of the one "full_diff" layer. None of them is rotated
+HYBRID_KINDS = ("scan", "window_diff", "full_diff", "gmu", "cross_diff")
 
 
 # the name ``_attn`` gives the result of its output product
@@ -117,6 +125,11 @@ def rotated(kind: str) -> bool:
 
 def linear(kind: str) -> bool:
     return kind == "linear"
+
+
+def pageless(kind: str) -> bool:
+    """The layer keeps no page of its own."""
+    return kind in ("linear", "scan", "gmu", "cross_diff")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -260,16 +273,36 @@ class LlamaConfig:
     # of a layer ADDS to the stream (``post_attn_norm``,
     # ``post_mlp_norm``), beside the two on what the halves read
     post_norms: bool = False
+    # a decoder-hybrid-decoder stack (SambaY; models/sambay.py): over 0,
+    # ``layer_pattern`` lists every layer as P periods of ("scan",
+    # "window_diff"), one ("scan", "full_diff"), then Q periods of ("gmu",
+    # "cross_diff") (``HYBRID_KINDS``). A scan layer (Mamba-1) has
+    # ``scan_expand * dim`` channels, each with ``scan_state`` float32
+    # state values a slot and the last ``scan_conv - 1`` inputs of a
+    # causal depthwise convolution; ``dt`` comes through a bottleneck of
+    # ``scan_dt_rank``. Every norm is a LayerNorm with a bias, no layer is
+    # rotated, the attention layers pair their heads (differential
+    # attention), and the output head is the embedding table. Served only
+    scan_state: int = 0
+    scan_conv: int = 4
+    scan_expand: int = 2
+    scan_dt_rank: int = 0
 
     def __post_init__(self):
         if self.remat_policy not in REMAT_POLICIES:
             raise ValueError(f"remat_policy {self.remat_policy!r}: one of "
                              f"{sorted(REMAT_POLICIES)}")
         kinds = self.layer_kinds
-        unknown = set(kinds) - set(LAYER_KINDS)
+        unknown = set(kinds) - set(LAYER_KINDS) - set(HYBRID_KINDS)
         if unknown:
             raise ValueError(f"layer_pattern: unknown kinds "
-                             f"{sorted(unknown)}; one of {LAYER_KINDS}")
+                             f"{sorted(unknown)}; one of "
+                             f"{LAYER_KINDS + HYBRID_KINDS}")
+        if bool(self.scan_state) != bool(set(kinds) & set(HYBRID_KINDS)):
+            raise ValueError(f"the kinds {HYBRID_KINDS} need scan_state, "
+                             f"and scan_state needs them")
+        if self.scan_state:
+            self.hybrid_periods      # refuses any other shape of stack
         if self.n_layers % len(kinds):
             raise ValueError(
                 f"n_layers={self.n_layers} is no whole number of periods "
@@ -381,6 +414,38 @@ class LlamaConfig:
                     f"{self.n_experts} experts")
 
     @property
+    def hybrid_periods(self) -> Tuple[int, int]:
+        """(P, Q) of a decoder-hybrid-decoder stack: P periods of (scan,
+        window attention), the pair (scan, full attention), Q periods of
+        (memory unit, cross-attention)."""
+        kinds = self.layer_kinds
+        P = sum(k == "window_diff" for k in kinds)
+        Q = sum(k == "gmu" for k in kinds)
+        if (kinds != ("scan", "window_diff") * P + ("scan", "full_diff")
+                + ("gmu", "cross_diff") * Q or len(kinds) != self.n_layers
+                or not (P and Q and self.scan_dt_rank and self.window)):
+            raise ValueError(
+                "scan_state: layer_pattern lists every layer as periods "
+                "of ('scan', 'window_diff'), one ('scan', 'full_diff'), "
+                "then periods of ('gmu', 'cross_diff'), with window and "
+                "scan_dt_rank set")
+        if (self.n_heads % 2 or self.n_kv_heads % 2
+                or (self.n_heads // self.n_kv_heads) % 2
+                or self.scan_channels % 128
+                or self.n_experts or self.latent or self.sparse_top_k
+                or self.qk_norm):
+            raise ValueError(
+                "scan_state: differential attention pairs the heads (an "
+                "even number of query heads a pair of key-value heads), "
+                "the scan's channels are whole rows of 128 lanes, and the "
+                "layers are dense GQA layers without a QK-norm")
+        return P, Q
+
+    @property
+    def scan_channels(self) -> int:
+        return self.scan_expand * self.dim
+
+    @property
     def latent(self) -> bool:
         """Latent attention, not GQA: see ``kv_lora_rank``."""
         return self.kv_lora_rank > 0
@@ -424,7 +489,12 @@ class LlamaConfig:
 
     @property
     def state_bytes_per_slot(self) -> int:
-        """Bytes of float32 state a slot holds in the linear layers."""
+        """Bytes of float32 state a slot holds in the linear layers, or
+        in the scan layers (the convolution's tail among it)."""
+        if self.scan_state:
+            return (sum(k == "scan" for k in self.layer_kinds)
+                    * (self.scan_state + self.scan_conv - 1)
+                    * self.scan_channels * 4)
         return (self.n_linear_layers * self.linear_heads
                 * self.head_dim * self.head_dim * 4)
 
@@ -496,7 +566,7 @@ class LlamaConfig:
         """The live spans the layers' keys have, one entry a group of
         layers that share a page pool (llm/cache.py): None for the whole
         sequence, else the window. Full layers first."""
-        spans = {self._span(k) for k in self.layer_kinds if not linear(k)}
+        spans = {self._span(k) for k in self.layer_kinds if not pageless(k)}
         return tuple(sorted(spans, key=lambda s: s is not None))
 
     def _span(self, kind: str) -> Optional[int]:
@@ -506,8 +576,8 @@ class LlamaConfig:
         """Layers of the whole stack in ``kv_groups[group]``."""
         if self.own_weights:
             return self.n_kv_layers
-        return sum(self.layer_group(j)[0] == group
-                   for j in range(len(self.layer_kinds))) \
+        return sum(not pageless(k) and self.layer_group(j)[0] == group
+                   for j, k in enumerate(self.layer_kinds)) \
             * (self.n_layers // len(self.layer_kinds))
 
     def layer_group(self, j: int) -> Tuple[int, int]:
@@ -519,6 +589,10 @@ class LlamaConfig:
                 sum(self._span(k) == span for k in kinds[:j]))
 
     def n_params(self) -> int:
+        if self.scan_state:
+            from .sambay import n_params
+
+            return n_params(self)
         d, L = self.dim, self.n_layers
         attn = d * self.n_heads * self.head_dim + 2 * d * self.n_kv_heads * self.head_dim \
             + self.n_heads * self.head_dim * d
@@ -620,6 +694,10 @@ def init_params(key, cfg: LlamaConfig, gains=None):
     ``router_bias``: ``expert_bias`` [L, E] float32, seeded NON-zero (a
     bias added to the weights, or left out of the choice, is another
     answer). ``post_norms``: ``post_attn_norm`` and ``post_mlp_norm``."""
+    if cfg.scan_state:
+        from . import sambay
+
+        return sambay.init_params(key, cfg, gains)
     d, hd = cfg.dim, cfg.head_dim
     h, hkv = cfg.n_heads, cfg.n_kv_heads
     ks = jax.random.split(key, 9)

@@ -6,7 +6,7 @@ parallel attention is entirely absent there). These ops are the compute
 substrate for ray_tpu.models and ray_tpu.serve.
 """
 
-from .norms import rms_norm
+from .norms import layer_norm, rms_norm
 from .rotary import apply_rotary, rope_frequencies
 from .attention import attention, flash_attention_tpu, naive_attention
 from .ring_attention import ring_attention
@@ -16,7 +16,7 @@ from .quant import (
     quantize_params, quantize_weight, weight_einsum)
 
 __all__ = [
-    "rms_norm", "apply_rotary", "rope_frequencies",
+    "rms_norm", "layer_norm", "apply_rotary", "rope_frequencies",
     "attention", "flash_attention_tpu", "naive_attention",
     "ring_attention", "moe_dispatch", "moe_mlp", "moe_mlp_oracle",
     "moe_mlp_routed",

@@ -843,3 +843,57 @@ def _named(out, lse):
     from jax.ad_checkpoint import checkpoint_name
 
     return checkpoint_name(out, FLASH_OUT), checkpoint_name(lse, FLASH_LSE)
+
+
+# ---------------------------------------------------------------------------
+# Differential attention (arXiv:2410.05258) as grouped-query attention.
+# The heads are paired by parity: pair p scores q[2p] against k[2g] and
+# q[2p + 1] against k[2g + 1] (g = p // R, R pairs a pair of KV heads),
+# and both softmaxes weigh ONE value row of twice the width, v[2g] beside
+# v[2g + 1]. Two neighbouring K (or V) heads side by side are one row of
+# 2 hd, which is how they lie in memory already; a query padded with
+# zeros on the other head's half scores its own half of that row alone.
+# So every attention this repo has (the flash forwards, a window, the
+# paged decode's one softmax over segments) serves a differential layer
+# as a GQA layer of h heads over kvh / 2 rows of 2 hd: the cache holds
+# what any GQA layer's holds, and the price is the zeros' half of the
+# score product.
+# ---------------------------------------------------------------------------
+
+
+def differential_rows(x):
+    """k or v [..., kvh, hd] -> [..., kvh / 2, 2 hd]: neighbouring heads
+    side by side (no element moves)."""
+    return x.reshape(*x.shape[:-2], x.shape[-2] // 2, 2 * x.shape[-1])
+
+
+def differential_queries(q, kv_heads: int):
+    """q [..., h, hd] -> [..., h, 2 hd], in the order grouped-query
+    attention over ``kv_heads / 2`` rows wants them: for a row g its R
+    first queries (even heads, zeros behind them), then its R second
+    queries (odd heads, zeros before them)."""
+    h, hd = q.shape[-2:]
+    G = kv_heads // 2
+    q = q.reshape(*q.shape[:-2], G, h // kv_heads, 2, hd)
+    zero = jnp.zeros_like(q[..., 0, :])
+    both = jnp.stack([jnp.concatenate([q[..., 0, :], zero], -1),
+                      jnp.concatenate([zero, q[..., 1, :]], -1)], -3)
+    return both.reshape(*both.shape[:-4], h, 2 * hd)
+
+
+def differential_lambda(lp, lam0: float):
+    """exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_0, float32 scalar."""
+    f = lambda name: lp[name].astype(jnp.float32)  # noqa: E731
+    return (jnp.exp(jnp.sum(f("lam_q1") * f("lam_k1")))
+            - jnp.exp(jnp.sum(f("lam_q2") * f("lam_k2"))) + lam0)
+
+
+def differential_combine(o, lam, kv_heads: int):
+    """What attention returned for ``differential_queries``' heads [...,
+    h, 2 hd] -> the pairs' differences float32 [..., h / 2, 2 hd]:
+    first minus ``lam`` times second."""
+    h = o.shape[-2]
+    o = o.astype(jnp.float32).reshape(
+        *o.shape[:-2], kv_heads // 2, 2, h // kv_heads, o.shape[-1])
+    diff = o[..., 0, :, :] - lam * o[..., 1, :, :]
+    return diff.reshape(*diff.shape[:-3], h // 2, diff.shape[-1])

@@ -18,3 +18,15 @@ def rms_norm(x, weight, eps: float = 1e-5):
     var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
     out = xf * jax.lax.rsqrt(var + eps)
     return (out * weight.astype(jnp.float32)).astype(dtype)
+
+
+def layer_norm(x, weight, bias, eps: float = 1e-5):
+    """LayerNorm with a bias: (x - mean) / sqrt(var + eps) * w + b, f32
+    accumulation."""
+    dtype = x.dtype
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    out = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                             + eps)
+    return (out * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(dtype)

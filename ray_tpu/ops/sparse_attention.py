@@ -68,7 +68,7 @@ and the result is plain causal attention's.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -519,15 +519,15 @@ def attend(q, k, v, qi, w, ki, lim_a, lim_b=None, start_b=None, *,
 
 # ------------------------------------------------------------ a decode step
 def decode_attention_xla(q, pool_k, pool_v, layer, tables, lengths, mask, *,
-                         scale):
+                         scale, kvh=None):
     """The same in plain jax (the CPU's path, and the kernel's oracle):
     the table's rectangle of the layer's pages gathered, one softmax a
     slot and head over the keys the mask keeps below the slot's length."""
     B, H, hd = q.shape
+    kvh = kvh or pool_k.shape[3]
     k, v = (jnp.take(jax.lax.dynamic_index_in_dim(pool, layer, 0, False),
-                     tables, axis=0).reshape(B, -1, *pool.shape[3:])
+                     tables, axis=0).reshape(B, -1, kvh, hd)
             for pool in (pool_k, pool_v))
-    kvh = k.shape[2]
     s = jnp.einsum("bkgd,bskd->bkgs", q.reshape(B, kvh, H // kvh, hd), k,
                    preferred_element_type=jnp.float32) * scale
     seen = ((mask > 0) & (jnp.arange(k.shape[1])[None, :]
@@ -616,7 +616,7 @@ _STEP_BYTES = 512 * 1024
 
 
 def decode_attention_tpu(q, pool_k, pool_v, layer, tables, lengths, mask, *,
-                         scale, interpret=False):
+                         scale, kvh=None, interpret=False):
     """The kernel: grid (slot,); a slot's program walks the steps its
     length needs and no more. A step copies as many of the slot's pages
     of K and of V as hold ``_STEP_BYTES`` straight from the pools where
@@ -629,7 +629,8 @@ def decode_attention_tpu(q, pool_k, pool_v, layer, tables, lengths, mask, *,
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, hd = q.shape
-    page, kvh = pool_k.shape[2:4]
+    flat = kvh is not None
+    page, kvh = (pool_k.shape[2] // kvh, kvh) if flat else pool_k.shape[2:4]
     n = tables.shape[1]
     # a power of two of pages, so that it divides the table's bucket
     most = max(1, _STEP_BYTES // (page * kvh * hd * pool_k.dtype.itemsize))
@@ -646,7 +647,8 @@ def decode_attention_tpu(q, pool_k, pool_v, layer, tables, lengths, mask, *,
     seen = (live.astype(jnp.float32).reshape(B * n, page) @ spread).astype(
         jnp.int32).reshape(B, n, page * kvh) - 1
     return _decode_call(q, pool_k, pool_v, layer, tables, lengths, seen,
-                        pages=pages, scale=scale, interpret=interpret)
+                        pages=pages, scale=scale, kvh=kvh if flat else None,
+                        interpret=interpret)
 
 
 def _decode_call(q, pool_k, pool_v, layer, tables, lengths, seen, *, pages,
@@ -697,7 +699,7 @@ def _decode_call(q, pool_k, pool_v, layer, tables, lengths, seen, *, pages,
 
 
 def decode_attention(q, pool_k, pool_v, layer, tables, lengths, mask, *,
-                     scale: float):
+                     scale: float, kvh: Optional[int] = None):
     """One query a slot over the keys ``mask`` keeps of the slot's own
     cached pages, read where they lie.
 
@@ -708,7 +710,9 @@ def decode_attention(q, pool_k, pool_v, layer, tables, lengths, mask, *,
     scored); lengths int32 [B], the positions a slot has cached (0:
     nothing, and the slot's output is 0 with a log-sum-exp of about
     -1e30); mask int8 [B, n * page], 1 at the cached keys the slot
-    attends over (``decode_chosen``'s).
+    attends over (``decode_chosen``'s). ``kvh``: the pools are [L, P,
+    page * kvh, hd] already, a page ONE matrix of its (position, KV
+    head) rows.
 
     Returns (o float32 [B, H, hd], the softmax's output over the chosen
     cached keys alone; lse float32 [B, H], its log-sum-exp, by which
@@ -716,8 +720,10 @@ def decode_attention(q, pool_k, pool_v, layer, tables, lengths, mask, *,
     with jax.named_scope("rt.attn.sparse"):
         return jax.lax.platform_dependent(
             q, pool_k, pool_v, layer, tables, lengths, mask,
-            tpu=functools.partial(decode_attention_tpu, scale=scale),
-            default=functools.partial(decode_attention_xla, scale=scale))
+            tpu=functools.partial(decode_attention_tpu, scale=scale,
+                                  kvh=kvh),
+            default=functools.partial(decode_attention_xla, scale=scale,
+                                      kvh=kvh))
 
 
 def join_new_rows(o_old, lse_old, q, k, v, mask, *, scale: float):

@@ -35,12 +35,13 @@ CONFIGS = {
     "tiny-rehearsal-deepseek-v2": kinds.latent,
     "tiny-rehearsal-keye-vl2": kinds.indexed,
     "tiny-rehearsal-minicpm-sala": kinds.state,
+    "tiny-rehearsal-sambay": kinds.scan,
 }
 # one configuration a row of the table of refusals (paged: its two)
 OF_KIND = ["tiny-rehearsal", "tiny-rehearsal-smallthinker",
            "tiny-rehearsal-deepseek-v2", "tiny-rehearsal-keye-vl2",
-           "tiny-rehearsal-minicpm-sala"]
-KINDS = [kinds.paged, kinds.latent, kinds.indexed, kinds.state]
+           "tiny-rehearsal-minicpm-sala", "tiny-rehearsal-sambay"]
+KINDS = [kinds.paged, kinds.latent, kinds.indexed, kinds.state, kinds.scan]
 FEATURES = ("enable_prefix_caching", "lora_rank", "speculation",
             "kv_transfer")
 PROGRAMS = ("prefill", "prefill_chunk", "verify_step", "decode_burst")
@@ -93,8 +94,12 @@ def test_a_kind_gives_everything_the_programs_and_the_engine_ask(kind):
     for name in ("init_pools", "heads", *PROGRAMS, "count",
                  "attention_paths", "refuses"):
         assert callable(getattr(kind, name)), name
-    assert isinstance(kind.OWN_PAGES, bool)
-    assert kind.LOWEST_BUCKET in (16, 32)
+    # one fact, or one a layer group (there a window group's may be a
+    # row a slot)
+    own = kind.OWN_PAGES
+    assert all(isinstance(o, bool) or o == kinds.ROWS
+               for o in (own if isinstance(own, tuple) else (own,)))
+    assert kind.LOWEST_BUCKET in (16, 32, 64)
     assert all(isinstance(c, str) for c in kind.COUNTERS)
 
 
@@ -166,9 +171,10 @@ def _lowered(name, program):
 @pytest.mark.parametrize("name", OF_KIND)
 def test_a_kind_gives_the_program_or_refuses_it_by_name(name, program):
     cfg = _family(name)[0]
-    if kinds.of(cfg) is kinds.state and program == "verify_step":
+    stateful = {kinds.state: "linear", kinds.scan: "scan"}
+    if kinds.of(cfg) in stateful and program == "verify_step":
         with pytest.raises(ValueError, match="verify_step is not written "
-                           "for linear layers"):
+                           f"for {stateful[kinds.of(cfg)]} layers"):
             _lowered(name, program)
         return
     text = _lowered(name, program).as_text()
@@ -243,5 +249,5 @@ def test_one_function_refuses_and_no_kind_is_named_in_the_engine():
     source = inspect.getsource(engine)
     assert "_refuse_with" not in source and "_refuse_kv" not in source
     assert source.count("def _refuse(") == 1
-    for flag in ("cfg.latent", "sparse_top_k", "own_weights"):
+    for flag in ("cfg.latent", "sparse_top_k", "own_weights", "scan_state"):
         assert flag not in source, flag

@@ -288,6 +288,37 @@ def test_decode_kernel_is_its_plain_twin_and_a_softmax_written_out(
     assert mask[:, :span].sum() > 0
 
 
+@pytest.mark.parametrize("lengths", [(37, 50, 21), (64, 0, 8)])
+def test_decode_over_pools_kept_as_one_matrix_a_page(monkeypatch, lengths):
+    """``kvh=``: the pools handed over as [L, P, page * kvh, hd] already
+    (a kind that keeps a page as ONE matrix of its (position, KV head)
+    rows) give what the [L, P, page, kvh, hd] pools give, the kernel
+    interpreted and its plain twin."""
+    monkeypatch.setattr(sparse, "_STEP_BYTES",
+                        2 * DECODE_PAGE * KVH * HD * 4)
+    rng = np.random.default_rng(sum(lengths))
+    B, span = len(lengths), DECODE_PAGE * DECODE_SPAN
+    P = 1 + B * DECODE_SPAN
+    pool_k, pool_v = (jnp.asarray(rng.standard_normal(
+        (2, P, DECODE_PAGE, KVH, HD)), jnp.float32) for _ in range(2))
+    tables = rng.permutation(np.arange(1, P)).reshape(B, -1).astype(np.int32)
+    rest = (jnp.int32(1), jnp.asarray(tables),
+            jnp.asarray(lengths, jnp.int32), jnp.ones((B, span), jnp.int8))
+    q = jnp.asarray(rng.standard_normal((B, H, HD)), jnp.float32)
+    scale = HD ** -0.5
+    want = sparse.decode_attention_xla(q, pool_k, pool_v, *rest, scale=scale)
+    flat = tuple(pool.reshape(2, P, DECODE_PAGE * KVH, HD)
+                 for pool in (pool_k, pool_v))
+    for have in (
+            jax.jit(functools.partial(
+                sparse.decode_attention_tpu, scale=scale, kvh=KVH,
+                interpret=True))(q, *flat, *rest),
+            sparse.decode_attention_xla(q, *flat, *rest, scale=scale,
+                                        kvh=KVH)):
+        for a, b in zip(have, want):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
 def _paged(rows, tables, pages, page):
     """rows [B, S, ...] -> a pool [1, pages, page, ...] under ``tables``."""
     B, S = rows.shape[:2]

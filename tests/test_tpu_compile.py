@@ -1082,3 +1082,81 @@ def test_dense_layers_inside_a_pattern_compile_with_their_kernels(
                for name, _ in _kernel_calls(text)) == 21
     held = r"\[(?:7,)?32,3072,3072\]"
     assert not re.findall(r"(?:bf16|f32)" + held, text)
+
+
+# Phi-4-mini-flash-reasoning's published widths (benchmarks/configs/
+# phi-4-mini-flash-reasoning-bf16.json), the whole depth
+SAMBAY_WIDTHS = dict(
+    vocab=200064, dim=2560, n_layers=32, n_heads=40, n_kv_heads=20,
+    mlp_dim=10240, max_seq=262144, norm_eps=1e-5, window=512,
+    layer_pattern=(("scan", "window_diff") * 8 + ("scan", "full_diff")
+                   + ("gmu", "cross_diff") * 7),
+    scan_state=16, scan_conv=4, scan_expand=2, scan_dt_rank=160)
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_a_hybrid_decoder_compiles_with_its_kernels(latent, one_chip,
+                                                    program):
+    """The served programs of the decoder-hybrid-decoder stack at its
+    published widths and depth, bfloat16: the scan layers are on their
+    kernels (three traced bodies: the period scan's, layer 16's), the
+    window layers on the window flash kernel with rows of 128, a burst
+    reads the full layer's pages where they lie (a slot its own, in the
+    full layer and in the cross layers' body), the state pool and the
+    full layer's pool are updated where they lie and no stack of weights
+    is sliced into a copy."""
+    from ray_tpu.llm.cache import init_kv_cache, window_group_pages
+    from ray_tpu.llm.runner import decode_burst, prefill_sample
+    from ray_tpu.models import LlamaConfig, init_params
+    from ray_tpu.ops import rope_frequencies
+
+    cfg = LlamaConfig(**SAMBAY_WIDTHS)      # ``latent`` patched _on_tpu
+    params = _on_chip(jax.eval_shape(lambda: init_params(
+        jax.random.PRNGKey(0), cfg)), one_chip)
+    assert "lm_head" not in params
+    # (``lam0``: a constant a differential layer, no parameter)
+    assert sum(a.size for a in jax.tree.leaves(params)) - 16 == \
+        cfg.n_params() == 3_852_562_944
+    B = 1 if program == "prefill" else 8
+    pages = [129 if w is None else window_group_pages(B, w, 64, 8)
+             for w in cfg.kv_groups]
+    cache_k, cache_v, cache_s = _on_chip(jax.eval_shape(
+        lambda: (lambda c: (c.k, c.v, c.s))(
+            init_kv_cache(cfg, pages, 64, slots=B))), one_chip)
+    assert [p.shape for p in cache_k] == [
+        (1, 129, 640, 128), (8, pages[1], 64, 10, 128)]
+    assert cache_s.shape == (9, B, 19, 40, 128)
+    cos, sin = _on_chip(jax.eval_shape(lambda: rope_frequencies(
+        cfg.rope_dim, 64, cfg.rope_theta)), one_chip)
+    tables = tuple(_sds((B, 64), jnp.int32, one_chip) for _ in pages)
+    i32, f32 = _sds((B,), jnp.int32, one_chip), _sds((B,), jnp.float32,
+                                                     one_chip)
+    if program == "prefill":
+        text = prefill_sample.lower(
+            params, cache_k, cache_v, _sds((1, 4096), jnp.int32, one_chip),
+            i32, tables, cos, sin, 0, f32, i32, f32, None, None, None,
+            cache_s, i32, cfg=cfg, greedy=True).compile().as_text()
+        names = {re.sub(r"[.\d]+$", "", name)
+                 for name, _ in _kernel_calls(text)}
+        assert names == {"rt_scan_prefill", "flash_window_fwd"}
+        assert len(_kernel_calls(text)) == 3
+    else:
+        # the full group's table span, the window group a row a slot
+        lists = (_sds((B, 64), jnp.int32, one_chip),
+                 (_sds((B,), jnp.int32, one_chip),
+                  _sds((B, 9), jnp.int32, one_chip)))
+        text = decode_burst.lower(
+            params, cache_k, cache_v, i32, i32, tables,
+            _sds((B,), jnp.bool_, one_chip), cos, sin, 0, f32, i32, f32,
+            None, lists, _sds((), jnp.int32, one_chip), None, None, cache_s,
+            cfg=cfg, n_steps=8, greedy=True).compile().as_text()
+        names = {re.sub(r"[.\d]+$", "", name)
+                 for name, _ in _kernel_calls(text)}
+        assert names == {"rt_scan_decode", "rt_sparse_attend_decode"}
+        assert len(_kernel_calls(text)) == 4
+    # neither the state pool nor the full layer's is ever copied, and no
+    # scan over the first 8 of 9 layers sliced a stack of weights into a
+    # buffer of its own
+    assert not re.findall(r"f32\[9,%d,19,40,128\][^ ]* copy\(" % B, text)
+    assert not re.findall(r"bf16\[1,129,640,128\][^ ]* copy\(", text)
+    assert not re.findall(r"bf16\[8,(?:2560,10240|10240,2560)\]", text)
