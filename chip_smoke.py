@@ -526,32 +526,29 @@ def _sparse_kernel_parity(seed: int) -> dict:
     return report
 
 
-def _bucket_parity(seed: int, prompt: int = 12000,
-                   rows=(12288, 16384, 32768), dense_len: int = 8192,
-                   top: int = 2048) -> dict:
-    """A prompt of 12,000 tokens in the 12,288-row bucket (a rung,
-    ``runner.PREFILL_RUNGS``) and in the 16,384-row one beside it: the
-    rows behind the prompt are masked, so the prompt's own rows come out
-    the same. Held for the five prefill kernels that have rows to pad, at
-    the served widths: ``rt_linear_prefill`` (32 heads of 128, and the
-    state), ``flash_block_sparse_fwd`` with ``rt_sparse_select``'s choice
-    of 64 blocks of 64 for the queries past 8,192 (32 heads over 2 KV
-    heads), ``flash_sparse_fwd`` with ``rt_sparse_select``'s choice of
-    2,048 keys (16 indexer heads; 32 heads over 4 KV heads), and
-    ``flash_mla_fwd`` (16 heads scoring 192 wide, values of 128). A
-    kernel is held to the bit where the two programs of powers of two
-    (16,384 and 32,768 rows) agree to the bit. Then the whole program:
-    two layers of ``minicpm-sala-int8-12l`` (a sparse and a linear one,
-    seeded int8 weights) give the prompt the same first token in both
-    buckets. Runs in the gang worker. The sizes are arguments so that the
-    CPU can rehearse the case at a few hundred rows (float32 there)."""
+# (prompt tokens, the buckets it is run in: a rung of
+# ``runner.PREFILL_RUNGS``, the power of two above it and, where the chip
+# holds it, the next, the configurations whose first token is compared)
+BUCKET_CASES = (
+    (12000, (12288, 16384, 32768), ("minicpm-sala-int8-12l",)),
+    (22372, (24576, 32768), ("minicpm-sala-int8-12l",
+                             "keye-vl-2.0-30b-a3b-ep4-int8-16l")))
+
+
+def _bucket_kernels(seed: int, prompt: int, rows, dense_len: int,
+                    top: int) -> dict:
+    """The entries of a prompt's own rows that differ between each bucket
+    of ``rows`` and the next, for the five prefill kernels that have rows
+    to pad, at the served widths: ``rt_linear_prefill`` (32 heads of 128,
+    and the state), ``flash_block_sparse_fwd`` with ``rt_sparse_select``'s
+    choice of 64 blocks of 64 for the queries past ``dense_len`` (32 heads
+    over 2 KV heads), ``flash_sparse_fwd`` with ``rt_sparse_select``'s
+    choice of ``top`` keys (16 indexer heads; 32 heads over 4 KV heads),
+    and ``flash_mla_fwd`` (16 heads scoring 192 wide, values of 128)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmarks.harness import families
-    from ray_tpu.llm.engine import EngineConfig, LLMEngine
-    from ray_tpu.llm.sampling import SamplingParams
     from ray_tpu.ops import linear_attention
     from ray_tpu.ops import sparse_attention as sparse
     from ray_tpu.ops.attention import attention
@@ -559,7 +556,10 @@ def _bucket_parity(seed: int, prompt: int = 12000,
     L, top_rows = prompt, max(rows)
     ks = jax.random.split(jax.random.PRNGKey(seed), 8)
 
-    def normal(key, *shape, dtype=jnp.bfloat16):
+    # the CPU's dot takes no bfloat16: a rehearsal there runs float32
+    half = jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
+
+    def normal(key, *shape, dtype=half):
         return jax.random.normal(key, (top_rows, *shape), dtype)
 
     def padded(S):
@@ -634,23 +634,37 @@ def _bucket_parity(seed: int, prompt: int = 12000,
         if not all(np.isfinite(x.astype(np.float32)).all()
                    for x in got[rows[0]]):
             raise AssertionError(f"{name}: not finite at {rows[0]} rows")
-        report[name] = {"rung_differs": differ(rows[0], rows[1]),
-                        "powers_differ": differ(rows[1], rows[2])}
-    # the whole program, through the engine's own call
+        report[name] = [differ(a, b) for a, b in zip(rows, rows[1:])]
+    return report
+
+
+def _bucket_first_tokens(seed: int, prompt: int, rung: int, power: int,
+                         name: str) -> dict:
+    """The whole program, through the engine's own call: two layers of
+    the configuration ``name`` (seeded int8 weights; of
+    ``minicpm-sala-int8-12l`` a sparse and a linear one) give the prompt
+    its first token in the power-of-two bucket and in the rung below it.
+    Returns (rows, token) by bucket."""
+    import jax
+
+    from benchmarks.harness import families
+    from ray_tpu.llm.engine import EngineConfig, LLMEngine
+    from ray_tpu.llm.sampling import SamplingParams
+
     with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                           "benchmarks", "configs",
-                           "minicpm-sala-int8-12l.json")) as f:
-        config = {**json.load(f), "num_hidden_layers": 2,
-                  "mixer_types": ["minicpm4", "lightning-attn"]}
+                           "benchmarks", "configs", name + ".json")) as f:
+        config = {**json.load(f), "num_hidden_layers": 2}
+    if "mixer_types" in config:
+        config["mixer_types"] = ["minicpm4", "lightning-attn"]
     family = families.family_of(config)
     params = family.served_params(jax.random.PRNGKey(seed), config)
     tokens = [1 + t % (config["vocab_size"] - 1)
-              for t in seeded_prompt(seed, 0, L)]
+              for t in seeded_prompt(seed, 0, prompt)]
     first = {}
-    for label, rungs in (("power", ()), ("rung", (rows[0],))):
+    for label, rungs in (("power", ()), ("rung", (rung,))):
         engine = LLMEngine(params, family.program_config(config), EngineConfig(
-            max_num_seqs=1, page_size=64, num_pages=2 + rows[1] // 64,
-            max_seq_len=rows[1], decode_burst=8))
+            max_num_seqs=1, page_size=64, num_pages=2 + power // 64,
+            max_seq_len=power, decode_burst=8))
         engine._PREFILL_RUNGS = rungs
         rid = engine.add_request(tokens, SamplingParams(temperature=0.0,
                                                         max_tokens=1))
@@ -658,13 +672,39 @@ def _bucket_parity(seed: int, prompt: int = 12000,
             engine.step()
         first[label] = (engine.stats()["counters"]["prefill_bucket_tokens"],
                         int(engine.requests[rid].output[0]))
-    report["first_token"] = first
-    wrong = [name for name, r in report.items() if name != "first_token"
-             and r["rung_differs"] and not r["powers_differ"]]
-    if (wrong or first["power"][1] != first["rung"][1]
-            or [first["rung"][0], first["power"][0]] != list(rows[:2])):
-        raise AssertionError(f"a rung's rows are not its neighbour's: "
-                             f"{report}")
+        del engine
+    return first
+
+
+def _bucket_parity(seed: int, cases=BUCKET_CASES, dense_len: int = 8192,
+                   top: int = 2048) -> dict:
+    """A prompt in a rung's bucket (``runner.PREFILL_RUNGS``) and in the
+    power of two beside it: 12,000 tokens in 12,288 and 16,384 rows,
+    22,372 in 24,576 and 32,768. The rows behind the prompt are masked,
+    so the prompt's own rows come out the same. Held for the five prefill
+    kernels (``_bucket_kernels``): a kernel is held to the bit, in every
+    case, where the two programs of powers of two that the first case
+    runs (16,384 and 32,768 rows) agree to the bit. Then the whole
+    program (``_bucket_first_tokens``): the same first token in both
+    buckets. Runs in the gang worker. The sizes are arguments so that the
+    CPU can rehearse the cases at a few hundred rows (float32 there)."""
+    report, unstable = {}, set()
+    for prompt, rows, names in cases:
+        kernels = _bucket_kernels(seed, prompt, rows, dense_len, top)
+        if len(rows) > 2:
+            unstable |= {k for k, differs in kernels.items() if differs[1]}
+        first = {name: _bucket_first_tokens(seed, prompt, rows[0], rows[1],
+                                            name) for name in names}
+        report[str(prompt)] = {"rows": list(rows), "differ": kernels,
+                               "first_token": first}
+        wrong = [k for k, differs in kernels.items()
+                 if differs[0] and k not in unstable]
+        if wrong or any(
+                f["power"][1] != f["rung"][1]
+                or [f["rung"][0], f["power"][0]] != list(rows[:2])
+                for f in first.values()):
+            raise AssertionError(f"a rung's rows are not its neighbour's: "
+                                 f"{report}")
     return report
 
 
@@ -823,9 +863,11 @@ def phase_train(seed: int, spec: dict) -> dict:
                    "32/4 heads of 128; a decode step of 8 slots",
              **m["sparse_kernel_parity"])
         emit("bucket_parity",
-             shape="a 12000-token prompt in 12288, 16384 and 32768 rows: "
-                   "entries of its own rows that differ, by kernel; (rows, "
-                   "first token) of two layers of minicpm-sala-int8-12l",
+             shape="a 12000-token prompt in 12288, 16384 and 32768 rows, "
+                   "a 22372-token one in 24576 and 32768: entries of its "
+                   "own rows that differ between a bucket and the next, by "
+                   "kernel; (rows, first token) of two layers of a "
+                   "configuration in the power of two and in the rung",
              **m["bucket_parity"])
     _check_losses(m["losses"])
     if m["tpu_custom_calls"] < 1:

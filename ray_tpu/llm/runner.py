@@ -622,12 +622,12 @@ def page_bucket(pages: int, most: int, floor: int = 16) -> int:
 # Rows between two powers of two that a whole prompt may be padded to.
 # Every layer's projections, norms and expert products run the bucket's
 # rows, not the prompt's tokens, so a rung takes a quarter off the prefill
-# of every prompt it catches; it costs a program more (one the engine
-# loads before it is ready: ``LLMEngine.load_prefill_programs``), so a
-# rung is added where a cell's prompts crowd: 12,288 is a whole number of
-# every tile the prefill kernels use (24 x 512). The next rung is one
-# entry here.
-PREFILL_RUNGS = (12288,)
+# of every prompt it catches and costs a program more (the engine loads
+# it before it is ready: ``LLMEngine.load_prefill_programs``). Both are
+# whole tiles of every prefill kernel (24 and 48 x 512): 12,288 catches
+# 21 of ``longfile-steady``'s 27 prompts and 6 of ``longctx-steady``'s
+# 32, 24,576 the two longest of each cycle, which are their TTFT tails.
+PREFILL_RUNGS = (12288, 24576)
 
 
 def prefill_bucket(seq_len: int, max_seq: int, floor: int = 16,

@@ -2,11 +2,14 @@
 powers of two and the rungs of ``PREFILL_RUNGS`` between them), the page
 lists' buckets beside them (``runner.page_bucket``: powers of two alone),
 and the loader of the rungs' programs (``LLMEngine.load_prefill_programs``,
-which a replica that prefills calls before it is ready) (ISSUE 49).
+which a replica that prefills calls before it is ready) (ISSUE 49; the
+second rung ISSUE 56).
 
-The loader's tests give an engine a rung of 48 rows so that the bucket
-exists at toy sizes (``LLMEngine._PREFILL_RUNGS``): between the buckets of
-32 and 64, past the toy ``dense_len`` of the state-layer family.
+The loader's tests give an engine rungs of 48 and 96 rows so that the
+buckets exist at toy sizes (``LLMEngine._PREFILL_RUNGS``): three quarters
+of 64 and of 128 as the served rungs are of 16,384 and 32,768, whole
+pages of every toy configuration, past the toy ``dense_len`` of the
+state-layer family.
 """
 
 import json
@@ -28,6 +31,7 @@ from ray_tpu.llm.runner import (PREFILL_RUNGS, page_bucket,  # noqa: E402
 from ray_tpu.llm.sampling import SamplingParams             # noqa: E402
 
 RUNG = 48
+RUNGS = (RUNG, 96)
 MAX_SEQ_LENS = [1024, 2048, 12288, 16384, 32768]
 
 
@@ -41,27 +45,51 @@ def _parent_bucket(seq_len, max_seq, floor=16):
 
 
 # ------------------------------------------------------------------ the rule
-def test_the_one_rung():
-    assert PREFILL_RUNGS == (12288,)
+def test_the_two_rungs():
+    assert PREFILL_RUNGS == (12288, 24576)
+    # three quarters of the power of two above, in whole tiles of 512
+    # rows (the widest the prefill kernels cut) and whole pages of 64
+    assert [r % 512 for r in PREFILL_RUNGS] == [0, 0]
+    assert [4 * r // 3 for r in PREFILL_RUNGS] == [16384, 32768]
 
 
 @pytest.mark.parametrize("max_seq", MAX_SEQ_LENS)
-def test_outside_the_rung_a_prompt_gets_the_parents_bucket(max_seq):
-    for n in [*range(1, 8193), *range(12289, 32769)]:
+def test_outside_the_rungs_a_prompt_gets_the_parents_bucket(max_seq):
+    for n in [*range(1, 8193), *range(12289, 16385), *range(24577, 32769)]:
         assert prefill_bucket(n, max_seq) == _parent_bucket(n, max_seq), n
     if max_seq <= 12288:
         for n in range(8193, 12289):
             assert prefill_bucket(n, max_seq) == _parent_bucket(n, max_seq)
+    if max_seq <= 16384:
+        # no prompt an engine admits reaches the second rung, and past
+        # what it admits the answer is the parent's cap
+        for n in range(16385, 24577):
+            assert prefill_bucket(n, max_seq) == _parent_bucket(n, max_seq)
 
 
-@pytest.mark.parametrize("max_seq", [12288, 12289, 16384, 32768, 131072])
-def test_inside_the_rung_a_prompt_gets_its_rows(max_seq):
-    assert {prefill_bucket(n, max_seq) for n in range(8193, 12289)} == {12288}
-    assert prefill_bucket(8192, max_seq) == 8192
-    assert prefill_bucket(12289, max_seq) == min(16384, max_seq)
+@pytest.mark.parametrize("rung, max_seq", [
+    *[(12288, m) for m in (12288, 12289, 16384, 32768, 131072)],
+    *[(24576, m) for m in (24576, 24577, 32768, 131072)]])
+def test_inside_a_rung_a_prompt_gets_its_rows(rung, max_seq):
+    below = 2 * rung // 3                      # the power of two under it
+    assert {prefill_bucket(n, max_seq)
+            for n in range(below + 1, rung + 1)} == {rung}
+    assert prefill_bucket(below, max_seq) == below
+    assert prefill_bucket(rung + 1, max_seq) == min(2 * below, max_seq)
 
 
-@pytest.mark.parametrize("max_seq", MAX_SEQ_LENS + [9000, 12000, 12289])
+@pytest.mark.parametrize("max_seq", [1024, 2048, 12288, 13312, 16384])
+def test_no_cell_under_16385_positions_meets_the_second_rung(max_seq):
+    """The six serve configurations whose ``max_seq_len`` is at most
+    16,384 pad as one rung padded them: byte for byte the program of the
+    commit before the second rung."""
+    for n in range(1, max_seq + 1):
+        assert (prefill_bucket(n, max_seq)
+                == prefill_bucket(n, max_seq, rungs=PREFILL_RUNGS[:1])), n
+
+
+@pytest.mark.parametrize("max_seq", MAX_SEQ_LENS + [
+    9000, 12000, 12289, 20000, 24576, 24577])
 def test_the_rule_is_monotone_and_holds_the_prompt(max_seq):
     rows = [prefill_bucket(n, max_seq) for n in range(1, max_seq + 1)]
     assert all(a <= b for a, b in zip(rows, rows[1:]))
@@ -72,8 +100,9 @@ def test_the_rule_is_monotone_and_holds_the_prompt(max_seq):
 
 @pytest.mark.parametrize("rungs, want", [
     ((), [16, 32, 64, 64]), ((48,), [16, 32, 48, 64]),
-    ((24, 48), [16, 24, 48, 64]), ((96,), [16, 32, 64, 64])],
-    ids=["none", "one", "two", "past-the-cap"])
+    ((24, 48), [16, 24, 48, 64]), ((96,), [16, 32, 64, 64]),
+    ((48, 96), [16, 32, 48, 64])],
+    ids=["none", "one", "two", "past-the-cap", "one-of-two-past-the-cap"])
 def test_a_rung_is_one_entry(rungs, want):
     """Prompts of 16, 17, 33 and 49 tokens under a cap of 64: a rung
     catches the prompts between it and the power of two below it."""
@@ -123,7 +152,7 @@ def _family(name):
     return _MADE[name]
 
 
-def _engine(name, rungs=(RUNG,), **more):
+def _engine(name, rungs=RUNGS, **more):
     cfg, params, options = _family(name)
     engine = LLMEngine(params, cfg, EngineConfig(**{**options, **more}))
     engine._PREFILL_RUNGS = rungs
@@ -174,24 +203,25 @@ def test_the_page_lists_buckets_are_the_parents(name):
 
 
 # ----------------------------------------------------------------- the loader
+@pytest.mark.parametrize("rungs", [(RUNG,), RUNGS], ids=["one", "two"])
 @pytest.mark.parametrize("name", sorted(FAMILIES))
-def test_loading_moves_nothing_but_its_two_counters(name):
-    """One program, the rung's; pages, state pool (slot 0's too, though
-    a prompt of no tokens ends in the zero state), seed and every counter
-    of served work are what they were."""
-    engine = _engine(name)
+def test_loading_moves_nothing_but_its_two_counters(name, rungs):
+    """A program a rung; pages, state pool (slot 0's too, though a prompt
+    of no tokens ends in the zero state), seed and every counter of
+    served work are what they were."""
+    engine = _engine(name, rungs=rungs)
     if engine.cache.s is not None:
         engine.cache.s = engine.cache.s + 1.5
     before = (_pools(engine), engine.stats(), engine._seed)
-    assert engine.load_prefill_programs() == 1
+    assert engine.load_prefill_programs() == len(rungs)
     after = engine.stats()
     counters = dict(after["counters"])
-    assert counters.pop("loaded_programs") == 1
+    assert counters.pop("loaded_programs") == len(rungs)
     assert counters.pop("load_s") > 0.0
     want = dict(before[1]["counters"])
     del want["loaded_programs"], want["load_s"]
     # no counter: the tiles chosen where this PROCESS traced a program
-    # with experts, by shape; the rung's rows are a shape
+    # with experts, by shape; a rung's rows are a shape
     assert set(counters.pop("expert_tiles")) >= set(want.pop("expert_tiles"))
     assert counters == want
     assert {**after, "counters": None} == {**before[1], "counters": None}
@@ -203,23 +233,39 @@ def test_loading_moves_nothing_but_its_two_counters(name):
 
 @pytest.mark.parametrize("name", sorted(FAMILIES))
 def test_a_request_in_a_loaded_bucket_traces_nothing(name):
-    """A prompt of 40 tokens runs the rung's 48 rows. After loading, it
-    adds no entry to ``prefill_sample``'s jit cache (the loader's call is
-    the request's), and its tokens are those of an engine that never
-    loaded."""
+    """Prompts of 40 and 34 tokens run the first rung's 48 rows, one of
+    70 the second's 96. After loading, they add no entry to
+    ``prefill_sample``'s jit cache (the loader's call is the request's),
+    and their tokens are those of an engine that never loaded."""
     vocab = _family(name)[0].vocab
-    prompts = [_prompt(40, vocab, 1), _prompt(34, vocab, 2)]
+    prompts = [_prompt(40, vocab, 1), _prompt(34, vocab, 2),
+               _prompt(70, vocab, 3)]
     loaded = _engine(name)
     loaded.load_prefill_programs()
     size = prefill_sample._cache_size()
     tokens = _serve(loaded, prompts)
     assert prefill_sample._cache_size() == size
     c = loaded.stats()["counters"]
-    assert c["prefill_bucket_tokens"] == 2 * RUNG
-    assert c["prefill_tokens"] == 74 and c["loaded_programs"] == 1
+    assert c["prefill_bucket_tokens"] == 2 * RUNGS[0] + RUNGS[1]
+    assert c["prefill_tokens"] == 144 and c["loaded_programs"] == 2
     assert tokens == _serve(_engine(name), prompts)
-    # and a rung changes no token: the same prompts in 64 rows
-    assert tokens == _serve(_engine(name, rungs=()), prompts)
+    # and a rung changes no token: the same prompts in 64, 64 and 128 rows
+    plain = _engine(name, rungs=())
+    assert tokens == _serve(plain, prompts)
+    assert plain.stats()["counters"]["prefill_bucket_tokens"] == 256
+
+
+@pytest.mark.parametrize("max_seq_len, programs", [
+    (RUNG, 0), (64, 1), (96, 1), (112, 2), (128, 2)])
+def test_an_engine_loads_only_the_rungs_below_its_cap(max_seq_len, programs):
+    """A cap at a rung is a bucket lone requests meet, as every cap is;
+    a cap between the rungs (the six serve cells under 16,385 positions)
+    loads what one rung loaded."""
+    engine = _engine("tiny-rehearsal", max_seq_len=max_seq_len)
+    assert engine.load_prefill_programs() == programs
+    assert engine.stats()["counters"]["loaded_programs"] == programs
+    assert sorted({engine._prefill_rows(n) for n in range(
+        33, max_seq_len + 1)} - {64, max_seq_len}) == list(RUNGS[:programs])
 
 
 @pytest.mark.parametrize("name, rungs, more", [
@@ -255,15 +301,15 @@ def test_a_replica_loads_its_programs_with_its_role(monkeypatch, pool,
                                                     decode, prefill):
     """``serve``'s ``Replica`` calls ``configure_pool`` in its
     constructor, so before the replica reports ready: a replica that
-    prefills (no pools, or the prefill pool) loads the rung's program
-    there, once; a decode replica, which is handed its prompts' KV, loads
-    none."""
+    prefills (no pools, or the prefill pool) loads the two rungs'
+    programs there, once; a decode replica, which is handed its prompts'
+    KV, loads none."""
     from ray_tpu.llm.serve import LLMServer
 
-    monkeypatch.setattr(LLMEngine, "_PREFILL_RUNGS", (24,))
+    monkeypatch.setattr(LLMEngine, "_PREFILL_RUNGS", (24, 48))
     server = LLMServer("tiny", engine_config={
         "max_num_seqs": 2, "page_size": 4, "num_pages": 32,
-        "max_seq_len": 32, "decode_burst": 4})
+        "max_seq_len": 64, "decode_burst": 4})
     calls = {"decode": [], "prefill": []}
     for kind in calls:
         load = getattr(server.engine, f"load_{kind}_programs")
@@ -271,6 +317,6 @@ def test_a_replica_loads_its_programs_with_its_role(monkeypatch, pool,
                 lambda load=load, kind=kind: calls[kind].append(load()))
     server.configure_pool(pool, "llm")
     assert calls["decode"] == [len(server.engine.decode_buckets())] * decode
-    assert calls["prefill"] == [1] * prefill
+    assert calls["prefill"] == [2] * prefill
     assert server.engine.stats()["counters"]["loaded_programs"] == sum(
         calls["decode"] + calls["prefill"])
