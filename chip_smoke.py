@@ -455,9 +455,11 @@ def _sparse_kernel_parity(seed: int) -> dict:
     the 2,048 best of each row, and the product over that mask at 32
     query and 4 KV heads of 128; then a decode step's 8 rows with the
     burst's own rows behind a gap, and the decode product over their
-    choice through a block table. The scores are float32 sums of the
-    same bf16 products, the choice is exact (the same mask), the product
-    rounds one float32 sum to bf16. Runs in the gang worker."""
+    choice through a block table; then the choice over a 22,372-token
+    prompt's limits in 24,576 rows (``_select_long``). The scores are
+    float32 sums of the same bf16 products, the choice is exact (the
+    same mask), the product rounds one float32 sum to bf16. Runs in the
+    gang worker."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -517,12 +519,80 @@ def _sparse_kernel_parity(seed: int) -> dict:
         np.abs(np.asarray(o) - np.asarray(o_twin)).max(),
         np.abs(np.asarray(lse) - np.asarray(lse_twin))[
             np.asarray(lengths) > 0].max()))
+    report.update(_select_long(seed, sparse, top))
     if (report["masks_differ"] or report["decode_masks_differ"]
+            or report["long_masks_differ"]
             or report["chosen_a_row"] != top
             or report["scores_max_diff"] > 1e-3
             or report["product_max_diff"] > 2.0 ** -6
             or report["decode_product_max_diff"] > 2.0 ** -6):
         raise AssertionError(f"sparse kernels off their twins: {report}")
+    return report
+
+
+def _select_long(seed: int, sparse, top: int, prompt: int = 22372,
+                 S: int = 24576, timed=(2048, 8192, 22016)) -> dict:
+    """``choose_tpu`` against ``choose_xla`` a tile of 512 rows, as
+    ``attend`` hands them over, for a ``prompt``-token prompt's limits
+    in a row of ``S`` columns: ``long_masks_differ`` over every tile
+    (the rows past the prompt see nothing; every other tile's scores
+    are rounded to quarters, so that hundreds tie across the ``top``-th
+    place; the key blocks of 2,048 that no row of a tile sees, which
+    the scores' kernel leaves unwritten, hold NaN), and ``select_ms``:
+    the kernel's milliseconds for the tile that starts at each of
+    ``timed``, by the device's trace (the price at equal lengths,
+    outside any cell)."""
+    import statistics
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    tile = sparse.QUERY_TILE
+    kernel = jax.jit(lambda s, l: sparse.choose_tpu(
+        s, l, k=top, start_b=None).astype(jnp.int8))
+    differ = jax.jit(lambda s, l: (kernel(s, l) != sparse.choose_xla(
+        s, l, k=top, start_b=None)).sum())
+
+    def operands(t0):
+        at = t0 + jnp.arange(tile)
+        lim = jnp.where(at < prompt, at + 1, 0).astype(jnp.int32)
+        scores = jax.random.normal(
+            jax.random.fold_in(jax.random.PRNGKey(seed), t0), (tile, S),
+            jnp.float32)
+        if t0 // tile % 2:
+            scores = jnp.round(scores * 4) / 4
+        unwritten = jnp.arange(S) >= -(-lim.max() // 2048) * 2048
+        return (jnp.where(unwritten[None], jnp.nan, scores),
+                jnp.stack([lim, jnp.zeros_like(lim)], -1))
+
+    report = {"long_masks_differ": sum(
+        int(differ(*operands(t0)))
+        for t0 in range(0, -(-prompt // tile) * tile, tile))}
+    # by the device's own clock: a call's dispatch costs more than the
+    # kernel does
+    from benchmarks.harness import trace
+
+    runs, args = 10, [operands(t0) for t0 in timed]
+    jax.block_until_ready(args)
+    with tempfile.TemporaryDirectory() as where:
+        with jax.profiler.trace(where):
+            for operand in args:
+                for _ in range(runs):
+                    out = kernel(*operand)
+                out.block_until_ready()
+        ops = trace.load_xplane(trace.find_xplane(where))["devices"][0]["ops"]
+    took = [e[2] for e in sorted(ops, key=lambda e: e[1])
+            if sparse.SELECT_KERNEL in e[0]]
+    if len(took) != runs * len(timed):
+        raise AssertionError(
+            f"{len(took)} events of {sparse.SELECT_KERNEL} in the trace, "
+            f"not {runs * len(timed)}")
+    report["select_ms"] = {
+        str(t0): round(statistics.median(
+            took[i * runs:(i + 1) * runs]) / 1e6, 4)
+        for i, t0 in enumerate(timed)}
     return report
 
 
@@ -860,7 +930,8 @@ def phase_train(seed: int, spec: dict) -> dict:
              by_product=m["expert_kernel_parity"])
         emit("sparse_kernel_parity",
              shape="512 queries x 8192 keys, 16 indexer heads, top 2048, "
-                   "32/4 heads of 128; a decode step of 8 slots",
+                   "32/4 heads of 128; a decode step of 8 slots; the "
+                   "choice a tile of a 22372-token prompt in 24576 rows",
              **m["sparse_kernel_parity"])
         emit("bucket_parity",
              shape="a 12000-token prompt in 12288, 16384 and 32768 rows, "

@@ -42,10 +42,13 @@ from .paged import _write_rows
 OWN_PAGES = True
 # summed over queries (prefilled and decoded tokens), from their
 # positions: the keys a query could see and its indexer scored, and the
-# keys it attended over (at most sparse_top_k of them); the pages of K
-# (and as many of V) the bursts' steps walked: every decoding slot's
-# cached pages, once a step run
-COUNTERS = ("scored_keys", "attended_keys", "sparse_decode_pages")
+# keys it attended over (at most sparse_top_k of them), and the columns
+# its choice counted over a pass (``sparse.counted_keys``: the kernel's
+# chunks, against the bucket's row); the pages of K (and as many of V)
+# the bursts' steps walked: every decoding slot's cached pages, once a
+# step run
+COUNTERS = ("scored_keys", "attended_keys", "counted_keys",
+            "sparse_decode_pages")
 
 
 def count(cfg, counters, page_size, start, end, decode) -> None:
@@ -61,6 +64,7 @@ def count(cfg, counters, page_size, start, end, decode) -> None:
     counters["scored_keys"] += upto(end) - upto(start)
     counters["attended_keys"] += (
         upto(hi) - upto(lo) + k * ((end - start) - (hi - lo)))
+    counters["counted_keys"] += sparse.counted_keys(start, end, k, decode)
     if decode:
         counters["sparse_decode_pages"] += (end - start) * -(
             -start // page_size)

@@ -128,13 +128,18 @@ def test_two_segments_are_one_order_of_positions():
     assert (got == _plain_chosen(np.asarray(scores), seen, TOP)).all()
 
 
+def _mixed_scores(rng, R, S):
+    """Whole numbers, so that many tie, with fractions on half of them."""
+    return (rng.integers(-3, 4, (R, S)).astype(np.float32)
+            + (rng.random((R, S)) < 0.5)
+            * rng.standard_normal((R, S)).astype(np.float32))
+
+
 @pytest.mark.parametrize("start_b", [None, 128])
 def test_select_kernel_is_its_plain_twin(start_b):
     rng = np.random.default_rng(1)
     R, S = 40, 256
-    scores = jnp.asarray(rng.integers(-3, 4, (R, S)).astype(np.float32)
-                         + (rng.random((R, S)) < 0.5)
-                         * rng.standard_normal((R, S)).astype(np.float32))
+    scores = jnp.asarray(_mixed_scores(rng, R, S))
     lim = jnp.stack([jnp.asarray(rng.integers(0, 129, R), jnp.int32),
                      jnp.asarray(rng.integers(0, 60, R), jnp.int32)
                      * (start_b is not None)], -1)
@@ -143,6 +148,119 @@ def test_select_kernel_is_its_plain_twin(start_b):
     want = sparse.choose_xla(scores, lim, k=TOP, start_b=start_b)
     assert (np.asarray(got) == np.asarray(want)).all()
     assert np.asarray(want).sum() > 0
+
+
+def _ends_inside_a_chunk(rng):
+    # rows of three blocks that see 130 to 345 keys of 640: their last
+    # chunks of 128 are seen in part, the chunks behind them by nobody
+    R, S = 72, 640
+    return (_mixed_scores(rng, R, S),
+            np.stack([130 + 3 * np.arange(R), np.zeros(R, int)], -1),
+            TOP, None, 128)
+
+
+def _nothing_to_count_beside_a_block_that_counts(rng):
+    # the first block's rows all see at most top_k keys (its mask is
+    # what they see), the second's see more, the third's none
+    R, S = 96, 512
+    seen = np.concatenate([np.arange(32) % (TOP + 1), 200 + np.arange(32),
+                           np.zeros(32, int)])
+    return (_mixed_scores(rng, R, S),
+            np.stack([seen, np.zeros(R, int)], -1), TOP, None, 128)
+
+
+def _a_decode_step(rng):
+    # 8 slots at the served sizes: a span of two chunks and the burst's
+    # own 128 lanes behind it, 3 of them visible; slots that cache
+    # nothing, one key, a chunk, a chunk and one, the whole span
+    lengths = np.array([0, 1, 2048, 2049, 4096, 3000, 2047, 100])
+    return (rng.standard_normal((8, 4096 + 128)).astype(np.float32),
+            np.stack([lengths, np.full(8, 3)], -1), 2048, 4096, 2048)
+
+
+def _a_short_first_segment(rng):
+    # a chunk's queries over a cached span of which under a chunk is
+    # seen, and their own rows behind it (the second segment, counted
+    # whole): nothing cached, under top_k, a part of the first chunk
+    R = 64
+    return (_mixed_scores(rng, R, 256 + 128),
+            np.stack([np.repeat([0, 5, 100, 256], 16),
+                      np.arange(R) % 128 + 1], -1), TOP, 256, 128)
+
+
+def _ties_across_a_chunks_edge(rng):
+    # ten keys above a value that 17 keys share, from column 124 to 140:
+    # the 6 taken are columns 124 to 129, either side of the edge at 128;
+    # and a row of one value throughout
+    R, S = 32, 384
+    scores = rng.random((R, S)).astype(np.float32)
+    scores[:, 124:141] = 2.0
+    scores[:, 300:310] = 3.0 + np.arange(10)
+    scores[1] = 0.5
+    scores[2, ::2] = -0.0
+    scores[2, 1::2] = 0.0
+    return (scores, np.stack([np.full(R, 330), np.zeros(R, int)], -1),
+            TOP, None, 128)
+
+
+def _anything_behind_the_last_visible_chunk(rng):
+    # the key blocks no row of a tile sees are not written by the scores'
+    # kernel: NaN and infinities of both signs there, and in the unseen
+    # part of the last visible chunk
+    R, S = 64, 640
+    scores = _mixed_scores(rng, R, S)
+    seen = 140 + np.arange(R)
+    for r in range(R):
+        scores[r, seen[r]:] = (np.nan, np.inf, -np.inf, -np.nan)[r % 4]
+    return scores, np.stack([seen, np.zeros(R, int)], -1), TOP, None, 128
+
+
+@pytest.mark.parametrize("case", [
+    _ends_inside_a_chunk, _nothing_to_count_beside_a_block_that_counts,
+    _a_decode_step, _a_short_first_segment, _ties_across_a_chunks_edge,
+    _anything_behind_the_last_visible_chunk], ids=lambda f: f.__name__)
+def test_select_kernel_counts_over_what_its_rows_see(case):
+    """Where the first segment is more than one chunk the kernel counts
+    over the chunks some row of a block sees and no other: the same
+    mask as the plain choice over the whole row, to the bit."""
+    scores, lim, k, start_b, chunk = case(np.random.default_rng(2))
+    scores, lim = jnp.asarray(scores), jnp.asarray(lim, jnp.int32)
+    got = sparse.choose_tpu(scores, lim, k=k, start_b=start_b, chunk=chunk,
+                            interpret=True)
+    want = np.asarray(sparse.choose_xla(scores, lim, k=k, start_b=start_b))
+    assert (np.asarray(got) == want).all()
+    idx = np.arange(scores.shape[1])[None]
+    seen = np.asarray(sparse.visible(idx, lim[:, :1], lim[:, 1:], start_b))
+    assert (want.sum(-1) == np.minimum(seen.sum(-1), k)).all()
+    assert want.sum() > 0
+
+
+def test_counted_keys_is_the_kernels_rule_by_hand():
+    """A prompt of 5,000 tokens and two bursts, at the served top_k: rows
+    in blocks of 32, columns in chunks of 2,048, nothing where a block
+    sees at most 2,048 keys."""
+    import types
+
+    from ray_tpu.llm.kinds import indexed
+
+    cfg = types.SimpleNamespace(sparse_top_k=2048)
+    counters = dict.fromkeys(indexed.COUNTERS, 0)
+    indexed.count(cfg, counters, 64, 0, 5000, False)
+    # blocks 64 to 127 see 2,080 to 4,096 keys: 4,096 columns a row;
+    # blocks 128 to 155 and the last block's 8 rows see up to 5,000: 6,144
+    assert counters["counted_keys"] == (64 * 32 * 4096 + 28 * 32 * 6144
+                                        + 8 * 6144) == 13942784
+    # against rows x the bucket's row (10 tiles of 512 x 8,192)
+    assert round(counters["counted_keys"] / (5120 * 8192), 3) == 0.332
+    # a burst of 8 steps behind it: 5,000 cached rounded up, and the
+    # burst's own 128 lanes
+    indexed.count(cfg, counters, 64, 5000, 5008, True)
+    assert counters["counted_keys"] == 13942784 + 8 * (6144 + 128)
+    # a burst across top_k: the steps at positions 2,040 to 2,047 see at
+    # most 2,048 keys and count nothing
+    before = counters["counted_keys"]
+    indexed.count(cfg, counters, 64, 2040, 2056, True)
+    assert counters["counted_keys"] - before == 8 * (2048 + 128)
 
 
 def test_at_most_top_k_visible_keys_is_the_dense_path():

@@ -850,8 +850,10 @@ def test_latent_flash_and_decode_kernels_compile_alone(one_chip):
 # kernels at the published widths, and a burst that copies no pool ---
 def test_sparse_kernels_compile_alone(one_chip):
     """Mosaic takes the scores (16 heads over keys as wide as the pool's
-    slot), the choice (32 rows of 8k scores counted over in VMEM, and a
-    decode step's 8 rows with the burst's own behind a gap) and the
+    slot), the choice (32 rows of 8k scores counted over in VMEM a chunk
+    of 2,048 at a time, as many as a scalar says, at the two rungs' rows
+    too; a decode step's 8 rows with the burst's own behind a gap, over
+    a span of one chunk, which is counted whole, and of four) and the
     product over a mask (32 query heads, 4 KV heads of 128)."""
     from ray_tpu.ops import sparse_attention as sparse
 
@@ -861,7 +863,9 @@ def test_sparse_kernels_compile_alone(one_chip):
         sparse.index_scores_tpu, _sds((1, T, 16, 128), bf16, one_chip),
         _sds((1, T, 16), jnp.float32, one_chip),
         _sds((1, S, 128), bf16, one_chip), _sds((1, T), i32, one_chip)) == 1
-    for rows, keys, start_b in ((T, S, None), (8, 2048 + 128, 2048)):
+    for rows, keys, start_b in ((T, S, None), (T, 12288, None),
+                                (T, 24576, None), (8, 2048 + 128, 2048),
+                                (8, 8192 + 128, 8192)):
         assert _custom_calls(
             functools.partial(sparse.choose_tpu, k=2048, start_b=start_b),
             _sds((rows, keys), jnp.float32, one_chip),
